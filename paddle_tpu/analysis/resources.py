@@ -62,46 +62,45 @@ __all__ = [
 # device peaks — the denominator of the static roofline
 # ---------------------------------------------------------------------------
 
-# (device_kind substring, peak FLOP/s dense bf16, HBM bytes/s
-# practically attainable, HBM capacity bytes).  The v5e row matches
-# ROOFLINE.md's measured basis (197 TFLOP/s peak, ~819 GB/s attainable,
-# 16 GiB); other TPU rows are public datasheet numbers.  The cpu row is
-# a deliberately round smoke-lane placeholder — predictions on CPU are
-# for exercising the machinery, not for believing.
-_DEVICE_PEAKS = (
+# (device_kind substring, peak FLOP/s dense bf16, HBM bytes/s, HBM
+# capacity bytes).  The v5e rows are Google Cloud's published "TPU v5e"
+# numbers (197 TFLOP/s bf16, 819 GB/s, 16 GB); the other TPU rows are
+# public datasheet numbers.
+_TPU_PEAKS = (
     ("v5 lite", 197e12, 819e9, 16 << 30),
     ("v5e", 197e12, 819e9, 16 << 30),
     ("v5p", 459e12, 2765e9, 95 << 30),
     ("v4", 275e12, 1228e9, 32 << 30),
     ("v3", 123e12, 900e9, 32 << 30),
     ("v2", 45e12, 700e9, 8 << 30),
-    ("cpu", 1e11, 20e9, 0),
 )
+# a deliberately round placeholder for platform == "cpu" ONLY:
+# predictions on the CPU exercise the machinery, they are not believed
+_CPU_PEAKS = (1e11, 20e9, 0)
 
 
 def device_peaks(device=None):
     """{kind, peak_flops, hbm_bytes_per_s, hbm_bytes} for `device` (a
-    jax.Device or None for the default device).  Unknown kinds get the
-    cpu placeholder row."""
-    kind = ""
-    if device is not None:
-        kind = "%s %s" % (getattr(device, "platform", ""),
-                          getattr(device, "device_kind", ""))
-    else:
-        try:
-            import jax
-            devs = jax.devices()
-            if devs:
-                kind = "%s %s" % (devs[0].platform, devs[0].device_kind)
-        except Exception:
-            kind = "cpu"
+    jax.Device or None for the default device).  The cpu placeholder is
+    returned for the cpu platform only; an accelerator kind the table
+    does not hold raises — a made-up denominator is worse than none."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    platform = str(getattr(device, "platform", ""))
+    kind = "%s %s" % (platform, getattr(device, "device_kind", ""))
+    if platform == "cpu":
+        flops, bw, mem = _CPU_PEAKS
+        return {"kind": kind, "peak_flops": flops,
+                "hbm_bytes_per_s": bw, "hbm_bytes": mem}
     low = kind.lower()
-    for sub, flops, bw, mem in _DEVICE_PEAKS:
+    for sub, flops, bw, mem in _TPU_PEAKS:
         if sub in low:
             return {"kind": kind, "peak_flops": flops,
                     "hbm_bytes_per_s": bw, "hbm_bytes": mem}
-    return {"kind": kind or "cpu", "peak_flops": _DEVICE_PEAKS[-1][1],
-            "hbm_bytes_per_s": _DEVICE_PEAKS[-1][2], "hbm_bytes": 0}
+    raise ValueError(
+        "no peaks known for device kind %r — add its published numbers "
+        "(with their source) to analysis/resources.py" % kind)
 
 
 def device_memory_bytes(device=None):

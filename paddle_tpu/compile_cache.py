@@ -12,7 +12,9 @@ artifacts are the right unit of reuse; this module makes them a shared,
 crash-safe, cross-process store.
 
 Store layout (root = ``FLAGS.compile_cache_dir``, default
-``$XDG_CACHE_HOME/paddle_tpu`` i.e. ``~/.cache/paddle_tpu``):
+``<checkout>/.cache/paddle_tpu`` — inside the tree, git-ignored, so a
+sealed machine that copies the checkout carries it and nothing is
+written outside it):
 
     <root>/
       aot/
@@ -23,9 +25,13 @@ Store layout (root = ``FLAGS.compile_cache_dir``, default
       tuning/
         <namespace>.json         # kernel-tuning registry, one file per
                                  # kernel family ("flash_attention", ...)
-      xla/                       # jax's own persistent XLA-executable
-                                 # cache, pointed here so a warm boot
-                                 # skips the XLA compile too
+
+jax's own persistent XLA-executable cache is placed from OUTSIDE the
+program (`ensure_jax_cache`): where ``JAX_COMPILATION_CACHE_DIR`` is set
+jax keeps it there and this module touches nothing; where it is unset,
+it goes to the fixed ``<checkout>/.cache/jax`` — for trainers as well as
+predictors.  The directory is part of jax's cache key, so it never
+derives from a temp name, a pid or ``FLAGS.compile_cache_dir``.
 
 A fingerprint is a flat JSON-able dict (program content hash, feed
 shapes/dtypes, fetch names, state shapes/dtypes, device kind, jax +
@@ -50,10 +56,10 @@ CRC32 mismatch, a truncated exec.bin all count as a miss (the entry is
 quarantined and the caller recompiles) — a poisoned cache must never be
 able to crash a server boot.
 
-Eviction: one size-capped LRU over the whole store
+Eviction: one size-capped LRU over the AOT entries
 (``FLAGS.compile_cache_max_mb``).  Last-use is the manifest mtime
 (touched on every hit); the entry just written is never the victim.
-jax's xla/ files ride the same sweep.
+jax bounds its own cache (``jax_compilation_cache_max_size``).
 """
 
 import binascii
@@ -70,14 +76,12 @@ __all__ = [
     "stats", "stats_delta", "reset_stats", "note_compile_ms",
     "note_deserialize_ms", "note_artifact_load",
     "tuning_path", "tuning_lookup", "tuning_record", "tuning_entries",
-    "verify_store", "CHAOS_POINTS",
-    "AOT_SUBDIR", "TUNING_SUBDIR", "XLA_SUBDIR", "MANIFEST_NAME",
-    "EXEC_NAME",
+    "verify_store", "CHAOS_POINTS", "ensure_jax_cache", "checkout_cache_dir",
+    "AOT_SUBDIR", "TUNING_SUBDIR", "MANIFEST_NAME", "EXEC_NAME",
 ]
 
 AOT_SUBDIR = "aot"
 TUNING_SUBDIR = "tuning"
-XLA_SUBDIR = "xla"
 MANIFEST_NAME = "manifest.json"
 EXEC_NAME = "exec.bin"
 SCHEMA_VERSION = 1
@@ -98,15 +102,20 @@ def _ckpt():
 # store location + process-wide counters
 # ---------------------------------------------------------------------------
 
+def checkout_cache_dir(name):
+    """``<checkout>/.cache/<name>``: the fixed, git-ignored home of
+    everything this package caches (checkout root = the directory that
+    holds the ``paddle_tpu`` package, as native/__init__.py computes
+    it)."""
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".cache", name)
+
+
 def cache_root():
     """Absolute store root from FLAGS.compile_cache_dir; empty flag means
-    the XDG default ``~/.cache/paddle_tpu``."""
+    ``<checkout>/.cache/paddle_tpu``."""
     from .flags import FLAGS
-    p = FLAGS.compile_cache_dir
-    if not p:
-        base = os.environ.get("XDG_CACHE_HOME") or \
-            os.path.join(os.path.expanduser("~"), ".cache")
-        p = os.path.join(base, "paddle_tpu")
+    p = FLAGS.compile_cache_dir or checkout_cache_dir("paddle_tpu")
     return os.path.abspath(os.path.expanduser(p))
 
 
@@ -223,35 +232,26 @@ def _spec_sig(arrays):
 # the content-addressed AOT store
 # ---------------------------------------------------------------------------
 
-_xla_cache_dirs = set()
-_xla_cache_lock = threading.Lock()
+def ensure_jax_cache():
+    """Place jax's persistent compilation cache and return the directory
+    in use.  Executor, ParallelExecutor and CompileCache call this when
+    they are built — before their first compile.
 
-
-def _enable_xla_cache(root):
-    """Point jax's persistent compilation cache into the store so the
-    XLA compile of a deserialized module is ALSO a disk hit on warm
-    boots (zero fresh XLA compilations, not just zero retraces).  Best
-    effort: an old jax without the knobs just skips this."""
-    xdir = os.path.join(root, XLA_SUBDIR)
-    with _xla_cache_lock:
-        if xdir in _xla_cache_dirs:
-            return
-        _xla_cache_dirs.add(xdir)
-    try:
-        import jax
-        os.makedirs(xdir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", xdir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        # jax latches its cache object at first compile; a process that
-        # already jitted something (fluid startup programs do) needs an
-        # explicit reset for the new dir to take effect
-        from jax.experimental.compilation_cache import (
-            compilation_cache as jax_cc)
-        jax_cc.reset_cache()
-    except Exception:
-        pass
+    ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and nothing
+    here touches jax's config.  Unset: THE one setter of
+    ``jax_compilation_cache_dir`` points it at ``<checkout>/.cache/jax``
+    — once, since a directory already configured (by an earlier call or
+    by the embedding script) stands.  jax initialises its cache lazily
+    at the first compile that finds a directory configured, so no reset
+    of an already-running process is needed."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir",
+                          checkout_cache_dir("jax"))
+    return jax.config.jax_compilation_cache_dir
 
 
 class CompileCache:
@@ -266,7 +266,7 @@ class CompileCache:
             * (1 << 20))
         self._lock = threading.Lock()
         if xla_cache:
-            _enable_xla_cache(self.root)
+            ensure_jax_cache()
 
     # -- layout ---------------------------------------------------------
 
@@ -411,20 +411,13 @@ class CompileCache:
                     total += os.path.getsize(os.path.join(d, n))
                 except OSError:
                     pass
-        xdir = os.path.join(self.root, XLA_SUBDIR)
-        if os.path.isdir(xdir):
-            for n in os.listdir(xdir):
-                try:
-                    total += os.path.getsize(os.path.join(xdir, n))
-                except OSError:
-                    pass
         return total
 
     def _evict(self, protect=None):
-        """Size-capped LRU over aot entries AND jax's xla/ files; the
-        `protect` key (the entry just written) is never the victim."""
+        """Size-capped LRU over the aot entries; the `protect` key (the
+        entry just written) is never the victim."""
         try:
-            victims = []  # (last_used, nbytes, kind, path)
+            victims = []  # (last_used, nbytes, path)
             total = 0
             for key, d in self.entries():
                 size = sum(os.path.getsize(os.path.join(d, n))
@@ -433,30 +426,14 @@ class CompileCache:
                 if key != protect:
                     victims.append(
                         (os.path.getmtime(os.path.join(d, MANIFEST_NAME)),
-                         size, "aot", d))
-            xdir = os.path.join(self.root, XLA_SUBDIR)
-            if os.path.isdir(xdir):
-                for n in os.listdir(xdir):
-                    p = os.path.join(xdir, n)
-                    try:
-                        size = os.path.getsize(p)
-                    except OSError:
-                        continue
-                    total += size
-                    victims.append((os.path.getmtime(p), size, "xla", p))
+                         size, d))
             if total <= self.max_bytes:
                 return
             victims.sort()
-            for _, size, kind, path in victims:
+            for _, size, path in victims:
                 if total <= self.max_bytes:
                     break
-                if kind == "aot":
-                    shutil.rmtree(path, ignore_errors=True)
-                else:
-                    try:
-                        os.remove(path)
-                    except OSError:
-                        pass
+                shutil.rmtree(path, ignore_errors=True)
                 total -= size
                 _bump("evictions")
         except OSError:

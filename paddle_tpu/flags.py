@@ -170,7 +170,7 @@ DEFINE_int(
     "conv's channel count) is <= this; 0 (default) disables the pass. "
     "The r05 chip measurements set this default: standalone, the Pallas "
     "kernel beats XLA at F=64 (+12%) and F=128 (tune_bottleneck stages, "
-    "BENCH_recovery_r05.json), but IN-GRAPH the custom-call boundary "
+    "ROOFLINE.md round 5), but IN-GRAPH the custom-call boundary "
     "around each fused block costs more than the kernel saves — "
     "end-to-end ResNet-50 serving measured slower at every gate "
     "(F<=128, 7 blocks: 1354 vs 1599 img/s; F<=64, 3 blocks: 1526 vs "
@@ -275,8 +275,8 @@ DEFINE_float(
     "Wall-clock watchdog on each Executor.run/run_loop dispatch: the "
     "device computation runs on a worker thread and a step exceeding "
     "this many seconds raises StepWatchdogTimeout instead of blocking "
-    "forever (generalizes bench.py's subprocess wedge-probe — the r03 "
-    "TPU transport outage hung jax inside C, unkillable from Python). "
+    "forever (a hung XLA dispatch sits inside C, unkillable from "
+    "Python). "
     "0 disables; enabling forces a block_until_ready per step, so this "
     "is a hang-detection mode, not a fast path.")
 DEFINE_int(
@@ -462,17 +462,18 @@ DEFINE_bool(
     "force fresh compilation everywhere.")
 DEFINE_string(
     "compile_cache_dir", "",
-    "Root directory of the persistent compile cache + kernel-tuning "
-    "registry; empty means $XDG_CACHE_HOME/paddle_tpu "
-    "(~/.cache/paddle_tpu). The store is cross-process shared: every "
+    "Root directory of the persistent AOT compile cache + kernel-tuning "
+    "registry; empty means <checkout>/.cache/paddle_tpu (git-ignored, "
+    "inside the tree). The store is cross-process shared: every "
     "commit is atomic and readers verify CRC32s, so concurrent servers "
-    "and a killed writer cannot poison each other.")
+    "and a killed writer cannot poison each other. jax's own persistent "
+    "cache is NOT placed by this flag: it lives where "
+    "JAX_COMPILATION_CACHE_DIR says, else at <checkout>/.cache/jax.")
 DEFINE_int(
     "compile_cache_max_mb", 1024,
     "Size cap (MiB) of the compile cache store; a put past the cap "
-    "evicts least-recently-used entries (manifest mtime, touched on "
-    "every hit) across both the AOT entries and jax's xla/ files. The "
-    "entry just written is never the victim.")
+    "evicts least-recently-used AOT entries (manifest mtime, touched "
+    "on every hit). The entry just written is never the victim.")
 DEFINE_int(
     "quantize_min_weight_elems", 1024,
     "PTQ size floor (inference/quantize.py): a weight with fewer "

@@ -27,7 +27,11 @@ class Place:
     def jax_device(self):
         import jax
         devs = jax.devices(self._backend) if self._backend else jax.devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                "%r names device %d but the %s backend has %d device(s)"
+                % (self, self.device_id, devs[0].platform, len(devs)))
+        return devs[self.device_id]
 
     def __eq__(self, other):
         return type(self) is type(other) and self.device_id == other.device_id
@@ -44,8 +48,12 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The TPU analogue of CUDAPlace (reference place.h:37). Uses the default
-    jax backend so it also works under a forced host-platform topology."""
+    """The TPU analogue of CUDAPlace (reference place.h:37). Resolves to
+    the DEFAULT jax backend, so the same scripts run under a forced
+    host-platform topology (the CPU-mesh tests depend on it) — which also
+    means a TPUPlace on a host without a chip is a CPU device. Code that
+    must be on a chip asserts `jax_device().platform` itself
+    (chip_smoke.py does)."""
     _backend = None
 
 

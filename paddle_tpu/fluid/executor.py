@@ -30,14 +30,13 @@ __all__ = ["Executor", "Scope", "global_scope", "scope_guard", "as_numpy",
 
 class StepWatchdogTimeout(TimeoutError):
     """An executor step exceeded FLAGS.step_watchdog_secs of wall clock.
-    The backend may be wedged (the r03 TPU transport outage blocked jax
-    inside C forever); the hung dispatch keeps its worker thread, but the
+    The backend may be wedged (a hung dispatch blocks jax inside C
+    forever); the hung dispatch keeps its worker thread, but the
     train loop gets an exception it can act on instead of hanging."""
 
 
 def _watchdog_call(call, timeout, what="executor step"):
     """Run `call` on a worker thread and give up after `timeout` seconds
-    — the in-process generalization of bench.py's subprocess wedge-probe
     (a hung XLA dispatch cannot be interrupted from Python, but it CAN be
     abandoned).  Zero overhead path is the caller's: only invoked when
     the watchdog flag is set."""
@@ -264,15 +263,16 @@ class Executor:
 
     def __init__(self, place=None):
         self.place = place if place is not None else core.TPUPlace(0)
+        # trainers ride jax's persistent compilation cache too: a cold
+        # process pays a whole-model XLA compile otherwise
+        from .. import compile_cache
+        compile_cache.ensure_jax_cache()
         self._cache = {}  # key -> jitted (or eager host-path) fn
         self._step_counters = {}  # program cache id -> step
         self._host_op_cache = {}  # (id, version) -> program has host ops
 
     def _device(self):
-        try:
-            return self.place.jax_device()
-        except Exception:
-            return None
+        return self.place.jax_device()
 
     def close(self):
         # reference: notifies pservers a trainer is leaving; collective-DP
@@ -290,10 +290,7 @@ class Executor:
             step_fn = functionalizer.build_step_fn(
                 program, feed_names, fetch_names, state_names,
                 whole_graph_ad=wga, remat_policy=remat)
-            donate = ()
-            dev = self._device()
-            if dev is not None and dev.platform == "tpu":
-                donate = (0,)
+            donate = (0,) if self._device().platform == "tpu" else ()
             fn = jax.jit(step_fn, donate_argnums=donate)
             self._cache[key] = fn
         return fn
@@ -334,7 +331,7 @@ class Executor:
         if not cc.cache_enabled() or not self._aot_cache_eligible(program):
             return None
         dev = self._device()
-        if dev is not None and dev.platform != jax.default_backend():
+        if dev.platform != jax.default_backend():
             return None
         wga, remat = functionalizer.flags_ad_config()
         sig = tuple((n, np.shape(v), str(np.asarray(v).dtype))
@@ -432,7 +429,7 @@ class Executor:
         lax.fori_loop over the jitted step body with a constant feed —
         and return the LAST step's fetches. The TPU-idiomatic device-side
         loop: one host->device dispatch per `steps` steps instead of per
-        step, so throughput is not bounded by host/relay round-trips
+        step, so throughput is not bounded by host round-trips
         (reference analogue: the while_op + reader-op training loops that
         kept the GPU busy without per-step feeds, fluid_benchmark.py
         --use_reader_op).
@@ -494,9 +491,8 @@ class Executor:
             step_fn = functionalizer.build_step_fn(
                 program, feed_key, fetch_ext, persistables,
                 whole_graph_ad=wga, remat_policy=remat)
-            dev = self._device()
             fn = functionalizer.jit_loop(
-                step_fn, dev is not None and dev.platform == "tpu")
+                step_fn, self._device().platform == "tpu")
             self._cache[key] = fn
         # watchdog budget scales with the loop length: wd secs per step
         fetches, new_state = self._dispatch(

@@ -550,7 +550,7 @@ class FuseBottleneckPass(Pass):
         if f1[:2] != (F, F) or f2[1] != F:
             return False
         # measured-geometry gate: the Pallas kernel wins only for
-        # narrow bottlenecks (chip sweep BENCH_recovery_r05.json,
+        # narrow bottlenecks (round-5 chip sweep, ROOFLINE.md;
         # tune_bottleneck: F=64 +12% vs XLA, F=128 parity-plus,
         # F=256/512 LOSE). Fusing the losing geometries made the whole
         # inference graph slower, so wide blocks stay with XLA.

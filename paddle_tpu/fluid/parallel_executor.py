@@ -71,6 +71,8 @@ class ParallelExecutor:
                  build_strategy=None, num_trainers=1, trainer_id=0,
                  scope=None, mesh=None):
         import jax
+        from .. import compile_cache
+        compile_cache.ensure_jax_cache()
         self._main_program = main_program if main_program is not None \
             else default_main_program()
         self._scope = scope if scope is not None else global_scope()

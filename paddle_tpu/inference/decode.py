@@ -110,11 +110,6 @@ __all__ = ["GenerativePredictor", "DecodeSession",
 DECODE_META = "decode_meta.bin"
 _DECODE_STATE = "decode_state.bin"
 
-# shared-map sentinel, same contract as predictor._UNEXPORTABLE: this
-# function cannot ride the export/serialize path — every clone falls
-# back to direct jit without retrying the export
-_UNEXPORTABLE = object()
-
 # chaos hook (tools/chaos.py spec-fallback scenario): once armed, the
 # draft side of every SpeculativeDecodeSession raises after the given
 # number of further draft steps — the in-process stand-in for a dead /
@@ -197,10 +192,12 @@ def save_decode_model(dirname, state, meta):
 
 def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
                             n_heads=2, n_layers=2, max_seq_len=64,
-                            eos_id=0, seed=7):
+                            eos_id=0, seed=7, prefill_buckets=None):
     """Deterministic random-weight tiny causal LM — the CPU-smoke /
     test fixture (the decode analogue of bench_serving's `fc` model).
-    Same seed -> bit-identical artifact."""
+    Same seed -> bit-identical artifact.  `prefill_buckets` pins the
+    artifact's prompt buckets (default: powers of two up to
+    max_seq_len) — every bucket is one warm-up compile."""
     if d_model % n_heads:
         raise ValueError("d_model %d not divisible by n_heads %d"
                          % (d_model, n_heads))
@@ -232,6 +229,8 @@ def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
     meta = {"vocab_size": int(vocab_size), "d_model": int(d_model),
             "n_heads": int(n_heads), "n_layers": int(n_layers),
             "max_seq_len": int(max_seq_len), "eos_id": int(eos_id)}
+    if prefill_buckets:
+        meta["prefill_buckets"] = sorted(int(b) for b in prefill_buckets)
     return save_decode_model(dirname, state, meta)
 
 
@@ -756,10 +755,19 @@ class GenerativePredictor:
 
     def _step_math(self, state, kc, vc, lengths, last_tokens, active,
                    tp=None):
+        """One greedy decode step: `_step_logits` + argmax ->
+        (new_tokens [N] i32, kc', vc')."""
+        import jax.numpy as jnp
+        logits, kc, vc = self._step_logits(state, kc, vc, lengths,
+                                           last_tokens, active, tp=tp)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc
+
+    def _step_logits(self, state, kc, vc, lengths, last_tokens, active,
+                     tp=None):
         """One fixed-shape decode step over the whole slot table.
         kc/vc [L, N, S, H, Dh] (fp32, or int8 under the quantized
         cache), lengths [N] i32 (live cached positions), last_tokens
-        [N] i32, active [N] bool -> (new_tokens [N] i32, kc', vc').
+        [N] i32, active [N] bool -> (logits [N, vocab] f32, kc', vc').
         Cache writes are gated by `active`, so a freed (zeroed) slot
         stays zero and per-slot independence is exact.  Under int8,
         fresh K/V rows quantize in-graph before landing and the
@@ -828,8 +836,7 @@ class GenerativePredictor:
         logits = _ln(x, state["lnf_g"], state["lnf_b"]) @ state["lm_head"]
         if tp is not None:
             logits = tp.all_gather(logits, axis=1)
-        new_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return new_tok, jnp.stack(kcs), jnp.stack(vcs)
+        return logits, jnp.stack(kcs), jnp.stack(vcs)
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
                      tp=None):
@@ -1114,7 +1121,7 @@ class GenerativePredictor:
             # int8 executables from ever colliding (COMPILE_CACHE.md);
             # rev bumps when the phase math itself changes shape
             "kv_dtype": self._kv_dtype,
-            "rev": 2,
+            "rev": 3,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -1140,7 +1147,8 @@ class GenerativePredictor:
                  draft=None):
         """Persistent-cache-first compile of one phase (same order as
         Predictor._get_aot_fn: in-process shared map -> store hit ->
-        fresh export+commit -> legacy jit fallback).  `tp_math` is the
+        fresh export+commit; direct compilation only with the store
+        switched off or for a gather-mode mesh lane).  `tp_math` is the
         per-member tensor-parallel body (math_fn with a bound
         _TPContext); when set and the predictor rides a mesh, the phase
         compiles as ONE shard_map'd partitioned program instead of the
@@ -1287,47 +1295,36 @@ class GenerativePredictor:
             skey = (self._device_kind(), phase_key)
             with self._shared_lock:
                 ent = self._shared_exports.get(skey)
-            if ent is _UNEXPORTABLE:
-                return self._jit_fallback(math_fn, state_spec, arg_specs)
             if ent is not None:
                 return ent
             from jax import export as jax_export
             cache = cc.default_cache()
             fn = None
-            try:
-                fp = self._fingerprint(phase_key, arg_specs,
-                                       extra=fp_extra)
-                blob = cache.get(fp) if cache is not None else None
-                if blob is not None:
-                    try:
-                        t0 = _time.monotonic()
-                        exp = jax_export.deserialize(blob)
-                        fn = jax.jit(exp.call)
-                        cc.note_deserialize_ms(
-                            (_time.monotonic() - t0) * 1000.0)
-                    except Exception:
-                        blob = None
-                if fn is None:
+            fp = self._fingerprint(phase_key, arg_specs, extra=fp_extra)
+            blob = cache.get(fp) if cache is not None else None
+            if blob is not None:
+                try:
                     t0 = _time.monotonic()
-                    exp = jax_export.export(jax.jit(math_fn))(
-                        state_spec, *arg_specs)
-                    cc.note_compile_ms(
-                        (_time.monotonic() - t0) * 1000.0)
-                    if cache is not None:
-                        cache.put(fp, exp.serialize())
+                    exp = jax_export.deserialize(blob)
                     fn = jax.jit(exp.call)
-            except Exception as e:
-                with self._shared_lock:
-                    already = self._shared_exports.get(skey)
-                    self._shared_exports[skey] = _UNEXPORTABLE
-                if already is not _UNEXPORTABLE:
-                    warnings.warn(
-                        "compile cache disabled for decode phase %r "
-                        "(export failed: %s: %s) — falling back to "
-                        "direct compilation"
-                        % (phase_key, type(e).__name__, e),
-                        RuntimeWarning, stacklevel=4)
-                return self._jit_fallback(math_fn, state_spec, arg_specs)
+                    cc.note_deserialize_ms(
+                        (_time.monotonic() - t0) * 1000.0)
+                except Exception:
+                    # a stored blob this jax cannot read back is a miss
+                    # (the store's contract: corruption costs a
+                    # recompile); the fresh export below still raises
+                    fn = None
+            if fn is None:
+                # an export failure RAISES: a phase that cannot be
+                # traced and lowered is broken, not uncacheable, and no
+                # second way of compiling it may hide that
+                t0 = _time.monotonic()
+                exp = jax_export.export(jax.jit(math_fn))(
+                    state_spec, *arg_specs)
+                cc.note_compile_ms((_time.monotonic() - t0) * 1000.0)
+                if cache is not None:
+                    cache.put(fp, exp.serialize())
+                fn = jax.jit(exp.call)
             with self._shared_lock:
                 self._shared_exports[skey] = fn
             return fn
@@ -1351,21 +1348,32 @@ class GenerativePredictor:
     def _cache_np_dtype(self):
         return np.dtype(np.int8 if self._kv_quant else np.float32)
 
-    def step_fn(self, n_slots):
+    def _step_specs(self, n_slots):
         import jax
         L, H, Dh, _ = self._dims()
         S = self.max_seq_len
-        cache = jax.ShapeDtypeStruct((L, int(n_slots), S, H, Dh),
+        n = int(n_slots)
+        cache = jax.ShapeDtypeStruct((L, n, S, H, Dh),
                                      self._cache_np_dtype())
-        specs = (cache, cache,
-                 jax.ShapeDtypeStruct((int(n_slots),),
-                                      np.dtype(np.int32)),
-                 jax.ShapeDtypeStruct((int(n_slots),),
-                                      np.dtype(np.int32)),
-                 jax.ShapeDtypeStruct((int(n_slots),), np.dtype(bool)))
+        i32 = np.dtype(np.int32)
+        return (cache, cache, jax.ShapeDtypeStruct((n,), i32),
+                jax.ShapeDtypeStruct((n,), i32),
+                jax.ShapeDtypeStruct((n,), np.dtype(bool)))
+
+    def step_fn(self, n_slots):
         return self._resolve(("step", int(n_slots)), self._step_math,
-                             specs,
+                             self._step_specs(n_slots),
                              tp_math=self._tp_math(self._step_math))
+
+    def step_logits_fn(self, n_slots):
+        """The decode step with its logits left un-argmaxed (same math,
+        one more compile-cache fingerprint per n_slots) — what a
+        logit-level comparison against a reference reads
+        (`DecodeSession.decode_logits`)."""
+        return self._resolve(("step_logits", int(n_slots)),
+                             self._step_logits,
+                             self._step_specs(n_slots),
+                             tp_math=self._tp_math(self._step_logits))
 
     def verify_fn(self, n_slots, spec_k):
         """The speculative-verify executable for a (slot table,
@@ -1557,20 +1565,38 @@ class DecodeSession:
         np.int32 [n_slots] token vector (only entries of slots active
         at call time are meaningful).  Bumps each active slot's length
         and last token."""
+        toks = np.asarray(self._step(
+            self.predictor.step_fn(self.n_slots)))
+        self._advance(toks)
+        return toks
+
+    def decode_logits(self):
+        """`decode()` that also hands back the step's logits: returns
+        (tokens [n_slots] i32, logits [n_slots, vocab] f32).  A caller
+        comparing two predictors on one stream overwrites
+        `last_tokens` afterwards (teacher forcing), as the speculative
+        session does for its draft."""
+        logits = np.asarray(self._step(
+            self.predictor.step_logits_fn(self.n_slots)))
+        toks = logits.argmax(axis=-1).astype(np.int32)
+        self._advance(toks)
+        return toks, logits
+
+    def _step(self, fn):
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
-        fn = self.predictor.step_fn(self.n_slots)
-        new_tok, self._kc, self._vc = fn(
+        out, self._kc, self._vc = fn(
             self.predictor._state, self._kc, self._vc,
             self._put(self.lengths), self._put(self.last_tokens),
             self._put(self.active))
-        toks = np.asarray(new_tok)
+        return out
+
+    def _advance(self, toks):
         act = self.active
         self.lengths = self.lengths + act.astype(np.int32)
         self.last_tokens = np.where(act, toks, self.last_tokens).astype(
             np.int32)
         self.steps += 1
-        return toks
 
     def decode_fused(self, n_steps, budget=None, max_trips=None):
         """Up to `n_steps` decode steps in ONE dispatch (SERVING.md

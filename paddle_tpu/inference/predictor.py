@@ -794,13 +794,12 @@ class AotPredictor:
         from paddle_tpu.native import wire
         from paddle_tpu import compile_cache as cc
 
-        if cc.cache_enabled():
-            # the artifact IS a pre-serialized AOT cache; flipping the
-            # store on points jax's persistent XLA cache at it, so even
-            # the first .call per bucket skips the XLA compile on a
-            # warm boot (counted as artifact_loads, not hits — the
-            # hit/miss ratio stays about the fingerprint store)
-            cc.default_cache()
+        # the artifact IS a pre-serialized AOT cache; with jax's
+        # persistent cache placed, even the first .call per bucket can
+        # skip the XLA compile on a warm boot (counted as
+        # artifact_loads, not hits — the hit/miss ratio stays about
+        # the fingerprint store)
+        cc.ensure_jax_cache()
 
         with open(os.path.join(dirname, "aot_meta.bin"), "rb") as f:
             meta = wire.decode(f.read())

@@ -3,12 +3,14 @@
 The reference's native layer (recordio C++, LoDTensorBlockingQueue, tensor
 serde in save_op.cc) maps here: we dlopen libpaddle_tpu_native.so (built
 from native/ via make; pybind11 is not available in this image, so the ABI
-is a plain C API). If the library is missing we build it on first import;
-if no compiler is available, pure-Python fallbacks keep everything
-functional (slower).
+is a plain C API). The library is untracked (.gitignore): it is built on
+first import when it is missing OR older than any native/*.cc, so a
+checkout never runs a library its sources have moved past. If it cannot
+be built, pure-Python fallbacks keep everything functional (slower).
 """
 
 import ctypes
+import glob
 import os
 import subprocess
 import threading
@@ -35,11 +37,18 @@ def _try_build():
         return False
 
 
+def _stale():
+    if not os.path.exists(_LIB_PATH):
+        return True
+    built = os.path.getmtime(_LIB_PATH)
+    return any(os.path.getmtime(src) > built
+               for src in glob.glob(os.path.join(_NATIVE_DIR, "*.cc")))
+
+
 def _load():
     global lib
-    if not os.path.exists(_LIB_PATH):
-        if not _try_build():
-            return None
+    if _stale() and not _try_build():
+        return None
     try:
         l = ctypes.CDLL(_LIB_PATH)
     except OSError:

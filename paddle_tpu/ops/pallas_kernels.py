@@ -48,15 +48,6 @@ _TINY = 1e-20
 _MIN_LANES = attention_tuning.MIN_LANES
 
 
-def _compiler_params(**kw):
-    """jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5;
-    resolve whichever this install ships."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
-
-
 _DISPATCH = threading.local()
 
 
@@ -159,6 +150,7 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
     (_interpret_dispatch), like every kernel here always has."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     n_in = len(operands)
     n_out = len(out_shape) if isinstance(out_shape, (list, tuple)) else 1
@@ -196,7 +188,8 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
             kern, grid=grid, in_specs=list(in_specs),
             out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=list(scratch),
-            compiler_params=_compiler_params(dimension_semantics=sem),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=sem),
             interpret=interp,
         )(*ops)
 
@@ -609,7 +602,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         return decode_attention_reference(q, k_cache, v_cache, lengths,
                                           scale=scale,
                                           kv_scales=kv_scales)
-    lengths2d = jnp.asarray(lengths).astype(jnp.int32).reshape(N, 1)
+    lengths = jnp.asarray(lengths).astype(jnp.int32).reshape(N)
 
     def tile(ctx):
         q_ref, k_ref, v_ref, len_ref = ctx.ins[:4]
@@ -618,11 +611,14 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         kb = _stage_dequant(k_ref[0].transpose(1, 0, 2),
                             jnp.float32)           # [H, BKV, D]
         vb = _stage_dequant(v_ref[0].transpose(1, 0, 2), jnp.float32)
-        length = len_ref[0, 0]
+        length = len_ref[ctx.ids[0]]
         # elementwise-multiply + lane reduction instead of a matmul:
         # one query row per head makes this VPU work, and the step is
         # memory-bound on the K/V stream anyway (ROOFLINE.md)
-        s = jnp.sum(qb[:, None, :].astype(jnp.float32) * kb,
+        # (widen q before the [H, 1, D] broadcast: Mosaic has no
+        # layout for that reshape of a packed bf16 tile unless H fills
+        # its 16 sublanes)
+        s = jnp.sum(qb.astype(jnp.float32)[:, None, :] * kb,
                     axis=-1) * scale               # [H, BKV]
         if quant:
             # per-head K scale folds into the score scale, once per
@@ -643,12 +639,14 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
             o = o * ctx.ins[4][1]                  # per-head V scale
         o_ref[0] = o.astype(o_ref.dtype)
 
-    operands = [q, k_cache, v_cache, lengths2d]
+    operands = [q, k_cache, v_cache, lengths]
     in_specs = [
         pl.BlockSpec((1, H, D), lambda b, j: (b, 0, 0)),
         pl.BlockSpec((1, bkv, H, D), lambda b, j: (b, j, 0, 0)),
         pl.BlockSpec((1, bkv, H, D), lambda b, j: (b, j, 0, 0)),
-        pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+        # the whole [N] vector in scalar memory, indexed by slot: a
+        # (1, 1) VMEM block of it is below Mosaic's (8, 128) tile floor
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
     if quant:
         operands.append(jnp.asarray(kv_scales, jnp.float32).reshape(
@@ -1072,7 +1070,7 @@ def fused_bottleneck(x, w0, b0, w1, b1, w2, b2, ws=None, bs=None,
             out_specs=pl.BlockSpec((1, bh, Wo, C4),
                                    lambda b, i: (b, i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((N, Ho, Wo, C4), x.dtype),
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interp,
         )(*ops)

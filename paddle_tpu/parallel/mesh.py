@@ -17,7 +17,7 @@ import re
 
 import numpy as np
 
-__all__ = ["make_mesh", "data_parallel_mesh", "local_device_count", "get_shard_map",
+__all__ = ["make_mesh", "data_parallel_mesh", "local_device_count",
            "MeshGroup", "MeshMemberLost", "as_mesh_group",
            "set_member_poison", "check_member_poison",
            "tp_param_pspec", "tp_supported",
@@ -55,16 +55,7 @@ def local_device_count(use_cuda=True):
 
 
 def make_mesh(axis_sizes, devices=None):
-    """axis_sizes: dict axis-name -> size (row-major over the device list).
-
-    RNG caveat (jax 0.4.x, legacy threefry): jax.random bits CHANGE with
-    an array's sharding, so a seeded op (dropout) computes a different
-    mask on a mesh than replicated on one device. Harnesses that assert
-    replicated-vs-sharded trajectory PARITY must flip
-    ``jax_threefry_partitionable`` first (see __graft_entry__.py) — not
-    done here because the flag redefines every seeded stream
-    process-wide, and flipping it lazily at first-mesh-use makes RNG
-    order-dependent across a test session."""
+    """axis_sizes: dict axis-name -> size (row-major over the device list)."""
     import jax
     from jax.sharding import Mesh
     if devices is None:
@@ -86,27 +77,13 @@ def data_parallel_mesh(num_devices=None, use_cuda=True):
     return make_mesh({DATA_AXIS: num_devices}, devs[:num_devices])
 
 
-def get_shard_map():
-    """Version-compat accessor for jax's shard_map (moved out of
-    jax.experimental in jax 0.8)."""
-    try:
-        from jax import shard_map
-    except ImportError:       # older jax
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def shard_map_no_rep_check(fn, mesh, in_specs, out_specs):
     """shard_map with replication checking disabled — required for shard
     bodies that invoke Pallas kernels (jax has no replication rule for
-    pallas_call). The kwarg was renamed across jax versions."""
-    sm = get_shard_map()
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:          # jax >= 0.8
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+    pallas_call)."""
+    from jax import shard_map
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 # ---------------------------------------------------------------------------

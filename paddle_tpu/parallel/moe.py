@@ -92,9 +92,8 @@ def moe_ffn_sharded(x, gate_w, w_in, w_out, mesh, axis_name="expert",
     over the mesh — XLA lowers the two all_to_alls onto ICI."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from .mesh import get_shard_map
+    from jax import shard_map
 
-    shard_map = get_shard_map()
     fn = shard_map(
         lambda xs, gw, wi, wo: moe_ffn(xs, gw, wi, wo, axis_name,
                                        capacity_factor),

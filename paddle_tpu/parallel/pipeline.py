@@ -60,12 +60,8 @@ def pipeline_apply(stage_fn, stage_params, microbatches, axis_name):
     outs0 = jnp.zeros((M,) + x_shape, microbatches.dtype)
     # carries become device-varying after the first tick (ppermute/rank
     # branches); mark the initial values as varying so scan types match
-    if hasattr(jax.lax, "pcast"):          # jax >= 0.8 spelling
-        buf0 = jax.lax.pcast(buf0, axis_name, to="varying")
-        outs0 = jax.lax.pcast(outs0, axis_name, to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        buf0 = jax.lax.pvary(buf0, (axis_name,))
-        outs0 = jax.lax.pvary(outs0, (axis_name,))
+    buf0 = jax.lax.pcast(buf0, axis_name, to="varying")
+    outs0 = jax.lax.pcast(outs0, axis_name, to="varying")
     (_, outs), _ = jax.lax.scan(tick, (buf0, outs0), jnp.arange(ticks))
     # broadcast results from the last stage to every device so the caller
     # sees a replicated output (psum of the masked buffer = broadcast)
@@ -80,8 +76,7 @@ def pipeline_sharded(stage_fn, stacked_params, microbatches, mesh,
     microbatches [M, mb, ...] replicated. Returns [M, mb, ...]."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from .mesh import get_shard_map
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     param_spec = jax.tree_util.tree_map(
         lambda _: P(axis_name), stacked_params)

@@ -48,8 +48,7 @@ def sharded_lookup(table, ids, mesh, axis="model"):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from .mesh import get_shard_map
-    shard_map = get_shard_map()
+    from jax import shard_map
 
     n = mesh.shape[axis]
     V = table.shape[0]

@@ -505,11 +505,15 @@ class DynamicBatcher:
                     return None
                 self._cv.wait(0.1)
             head = self._pending.popleft()
+            # carrying from the moment the head leaves the queue: the
+            # coalescing wait below releases the lock, and a drain()
+            # that found nothing queued and nothing carried would let
+            # close() stop the router with this group in hand
+            self._carrying = True
             head.t_taken = time.monotonic()
             group = [head]
             if head.batch is None:
                 # no batch-major feed: nothing to coalesce on
-                self._carrying = True
                 return group
             total = head.batch
             window = time.monotonic() + self.deadline_s
@@ -534,7 +538,6 @@ class DynamicBatcher:
                 if remaining <= 0 or self._stopped or self._closing:
                     break
                 self._cv.wait(min(remaining, 0.05))
-            self._carrying = True
             return group
 
     def _assign(self, group):

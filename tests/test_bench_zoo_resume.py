@@ -4,8 +4,7 @@ The zoo sweep's tracked JSON holds hour-scale real-chip records; the
 resume/preserve/supersede logic guards them across filtered passes,
 mid-sweep aborts, and mixed feed-staging sweeps (reference discipline:
 benchmark/README.md published-numbers contract). These tests stub the
-per-config subprocess and the backend probe so the invariants run
-in-suite without a chip.
+per-config subprocess so the invariants run in-suite without a chip.
 """
 
 import json
@@ -20,8 +19,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(
 import bench_zoo
 
 
-def _run(monkeypatch, tmp_path, argv, backend="tpu", fail=()):
-    """Drive bench_zoo.main with stubbed probe + per-config runner."""
+def _run(monkeypatch, tmp_path, argv, fail=()):
+    """Drive bench_zoo.main with a stubbed per-config runner."""
     out = tmp_path / "zoo.json"
     ran = []
 
@@ -38,7 +37,6 @@ def _run(monkeypatch, tmp_path, argv, backend="tpu", fail=()):
             rec["staged_transfer"] = True
         return rec
 
-    monkeypatch.setattr(bench_zoo, "probe_backend", lambda **kw: backend)
     monkeypatch.setattr(bench_zoo, "run_config", fake_run_config)
     monkeypatch.setattr(sys, "argv",
                         ["bench_zoo.py", "--out", str(out)] + argv)
@@ -92,10 +90,10 @@ def test_staged_resume_remeasures_but_keeps_hostfeed_rows(
 def test_failed_rerun_supersedes_nothing(monkeypatch, tmp_path):
     data, _, _ = _run(monkeypatch, tmp_path, ["--only", "mnist_cnn"])
     # the re-measure fails: the completed row must survive next to the
-    # error row, and --require_tpu must exit nonzero
+    # error row, and the sweep must exit nonzero
     data, _, code = _run(monkeypatch, tmp_path,
                          ["--only", "mnist_cnn", "--resume",
-                          "--staged", "4", "--require_tpu"],
+                          "--staged", "4"],
                          fail={"mnist_cnn"})
     assert code == 5
     assert _rows(data) == [("mnist_cnn", 0, False),

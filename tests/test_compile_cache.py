@@ -278,6 +278,11 @@ def _run_child(store, md, out_npz, poison):
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("PADDLE_TPU_FLAGS_compile_cache_dir", None)
+    # jax's own cache is placed from outside, as an operator would: a
+    # directory of this test's, and no compile too small to keep — so
+    # the warm boot skips the XLA compile as well as the trace
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(store, "jax")
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     proc = subprocess.run(
         [sys.executable, "-c", _CHILD, store, md, out_npz,
          "poison" if poison else "no"],
@@ -537,3 +542,74 @@ def test_executor_compile_cache_skips_training_programs(store):
             assert float(l2) < float(l1)
     finally:
         fluid.set_flags({"executor_compile_cache": False})
+
+
+# ---------------------------------------------------------------------------
+# jax's own persistent cache is placed from outside the program
+# ---------------------------------------------------------------------------
+
+_BUILDERS = {
+    "compile_cache": lambda root: cc.CompileCache(root=root),
+    "executor": lambda root: fluid.Executor(fluid.CPUPlace()),
+}
+
+
+@pytest.fixture
+def jax_cache_config():
+    """Run with jax's cache directory unconfigured; restore it
+    afterwards."""
+    import jax
+    prev_dir = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", prev_dir)
+
+
+@pytest.mark.parametrize("builder", sorted(_BUILDERS))
+def test_jax_cache_dir_env_set_is_left_alone(store, jax_cache_config,
+                                             monkeypatch, builder):
+    """JAX_COMPILATION_CACHE_DIR set: jax keeps its cache there and no
+    code of ours touches jax's config — neither a CompileCache (the
+    predictors) nor an Executor (the trainers)."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    _BUILDERS[builder](store)
+    assert jax_cache_config.jax_compilation_cache_dir is None
+    assert cc.ensure_jax_cache() == "/some/dir"
+
+
+@pytest.mark.parametrize("builder", sorted(_BUILDERS))
+def test_jax_cache_dir_env_unset_is_fixed_checkout_path(
+        store, jax_cache_config, monkeypatch, builder):
+    """Unset: the one setter points jax at <checkout>/.cache/jax — a path
+    that never moves with FLAGS.compile_cache_dir (the `store` fixture
+    repoints the AOT store to a temp dir), a pid or the time."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    _BUILDERS[builder](store)
+    want = os.path.join(REPO, ".cache", "jax")
+    assert jax_cache_config.jax_compilation_cache_dir == want
+    assert cc.ensure_jax_cache() == want
+    assert cc.cache_root() == store != os.path.dirname(want)
+
+
+def test_default_store_root_is_inside_the_checkout():
+    old = fluid.get_flags(["compile_cache_dir"])
+    fluid.set_flags({"compile_cache_dir": ""})
+    try:
+        assert cc.cache_root() == os.path.join(REPO, ".cache",
+                                               "paddle_tpu")
+    finally:
+        fluid.set_flags(old)
+
+
+@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+def test_no_chip_means_no_result(script):
+    """Without an accelerator the chip check and the benchmark exit
+    non-zero and print neither an `"ok": true` line nor a metric — they
+    never fall back to the CPU."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, os.path.join(REPO, script)],
+                          capture_output=True, text=True, timeout=300,
+                          cwd=REPO, env=env)
+    assert proc.returncode not in (0, None), proc.stdout[-500:]
+    assert '"ok"' not in proc.stdout and '"metric"' not in proc.stdout
+    assert "tpu" in proc.stderr

@@ -193,6 +193,65 @@ class TestDecodeSession:
         assert reason2 == "eos"
         assert toks2[-1] == eos_tok and len(toks2) < 50
 
+    def test_decode_logits_is_decode_plus_its_logits(self, predictor):
+        """`decode_logits` advances exactly like `decode` and hands back
+        the logits its tokens are the argmax of; forcing `last_tokens`
+        afterwards replays another stream on this session (what
+        chip_smoke.py holds the Mosaic step to its reference with)."""
+        a, b = predictor.new_session(3), predictor.new_session(3)
+        for sess in (a, b):
+            sess.prefill(0, [5, 9, 3])
+            sess.prefill(2, [7])
+        stream = []
+        for _ in range(4):
+            want = a.decode()
+            got, logits = b.decode_logits()
+            assert logits.shape == (3, predictor.vocab_size)
+            assert logits.dtype == np.float32
+            assert np.array_equal(got[[0, 2]], want[[0, 2]])
+            assert np.array_equal(logits.argmax(-1)[[0, 2]],
+                                  want[[0, 2]])
+            stream.append(want.copy())
+        assert np.array_equal(a.lengths, b.lengths) and a.steps == b.steps
+        # teacher forcing: a third session fed session a's tokens walks
+        # the same logits whatever it would have sampled itself
+        c = predictor.new_session(3)
+        c.prefill(0, [5, 9, 3])
+        c.prefill(2, [7])
+        for want in stream:
+            got, _ = c.decode_logits()
+            assert np.array_equal(got[[0, 2]], want[[0, 2]])
+            c.last_tokens[[0, 2]] = want[[0, 2]]
+
+    def test_export_failure_raises_not_warns(self, artifact, tmp_path,
+                                             monkeypatch):
+        """A decode phase that cannot be exported is broken, not
+        uncacheable: no warn-and-compile-another-way path hides it."""
+        import warnings
+        from jax import export as jax_export
+
+        def boom(*a, **k):
+            raise RuntimeError("lowering refused")
+
+        monkeypatch.setattr(jax_export, "export", boom)
+        old = fluid.get_flags(["compile_cache_dir"])
+        fluid.set_flags({"compile_cache_dir": str(tmp_path / "empty")})
+        try:
+            pred = GenerativePredictor(artifact)
+            with warnings.catch_warnings():
+                warnings.simplefilter("error")
+                with pytest.raises(RuntimeError, match="lowering refused"):
+                    pred.step_fn(3)
+        finally:
+            fluid.set_flags(old)
+
+    def test_artifact_pins_prefill_buckets(self, tmp_path):
+        d = str(tmp_path / "lm")
+        build_tiny_decode_model(d, max_seq_len=64, prefill_buckets=(64, 16))
+        p = GenerativePredictor(d)
+        assert p.prefill_buckets() == (16, 64)
+        assert p.prompt_bucket(5) == 16 and p.prompt_bucket(17) == 64
+
 
 # ---------------------------------------------------------------------------
 # DecodeBatcher: continuous batching semantics (in-process)

@@ -2,6 +2,7 @@
 test_operator_desc.py, test_variable.py)."""
 
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid.framework import Program
@@ -85,3 +86,17 @@ def test_operator_accessors():
         assert op.type == "relu"
         assert op.input("X") == [x.name]
         assert op.output("Out") == [y.name]
+
+
+def test_place_out_of_range_raises_instead_of_wrapping():
+    """TPUPlace(3) on a one-device host used to wrap onto device 0 without
+    a word; a Place names a device or fails."""
+    import jax
+    from paddle_tpu.fluid import core
+    n = len(jax.devices())
+    assert core.TPUPlace(n - 1).jax_device() == jax.devices()[n - 1]
+    for bad in (n, -1):
+        with pytest.raises(ValueError, match="device"):
+            core.TPUPlace(bad).jax_device()
+    with pytest.raises(ValueError, match="cpu"):
+        core.CPUPlace(len(jax.devices("cpu"))).jax_device()

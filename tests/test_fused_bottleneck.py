@@ -141,7 +141,7 @@ def test_transpiler_fuses_nhwc_blocks(layout, fusion_enabled):
 
 def test_wide_bottleneck_declines_fusion():
     """Measured-geometry gate: the r05 chip sweep (tune_bottleneck
-    stages in BENCH_recovery_r05.json) showed the Pallas kernel LOSES
+    stages, ROOFLINE.md round 5) showed the Pallas kernel LOSES
     to XLA for wide bottlenecks (F=256/512), so the pass must fuse only
     blocks with F <= FLAGS.fuse_bottleneck_max_width and leave wide
     ones (numerically intact) to XLA."""

@@ -19,6 +19,25 @@ def test_native_lib_builds():
     assert native.available(), "libpaddle_tpu_native.so failed to build"
 
 
+def test_library_is_stale_when_missing_or_older_than_a_source(
+        tmp_path, monkeypatch):
+    """The .so is untracked: it is rebuilt when missing or when any
+    native/*.cc is newer, so a checkout never runs a stale library."""
+    import os
+    lib = tmp_path / "libpaddle_tpu_native.so"
+    src = tmp_path / "wire.cc"
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_LIB_PATH", str(lib))
+    src.write_text("// source")
+    assert native._stale()                      # missing
+    lib.write_bytes(b"elf")
+    os.utime(src, (1000, 1000))
+    os.utime(lib, (2000, 2000))
+    assert not native._stale()                  # newer than every source
+    os.utime(src, (3000, 3000))
+    assert native._stale()                      # a source moved past it
+
+
 def test_recordio_roundtrip(tmp_path):
     path = str(tmp_path / "data.rio")
     records = [b"hello", b"", b"x" * 10000, b"tail"]

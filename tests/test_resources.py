@@ -333,6 +333,28 @@ def test_device_peaks_table():
     assert rep.est_step_ms >= 0.0 and 0.0 <= rep.mfu_cap() <= 1.0
 
 
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e"])
+def test_device_peaks_v5e_is_the_published_row(kind):
+    peaks = device_peaks(_FakeDevice("tpu", kind))
+    assert (peaks["peak_flops"], peaks["hbm_bytes_per_s"],
+            peaks["hbm_bytes"]) == (197e12, 819e9, 16 << 30)
+
+
+def test_device_peaks_unknown_accelerator_raises():
+    """The cpu placeholder is for platform == 'cpu' only: an accelerator
+    kind the table does not hold is an error, never a made-up row."""
+    assert device_peaks(_FakeDevice("cpu", "cpu"))["hbm_bytes"] == 0
+    with pytest.raises(ValueError, match="TPU v9"):
+        device_peaks(_FakeDevice("tpu", "TPU v9"))
+    with pytest.raises(ValueError, match="gpu"):
+        device_peaks(_FakeDevice("gpu", "cpu-like name"))
+
+
 # ---------------------------------------------------------------------------
 # debugger cost columns (satellite)
 # ---------------------------------------------------------------------------

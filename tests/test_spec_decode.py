@@ -546,9 +546,9 @@ def test_spec_bench_smoke_subprocess():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_serving.py"),
-         "--decode", "--decode_mode", "cb", "--decode_slots", "2",
-         "--spec_k", "0,2", "--step_cost_ms", "20", "--qps", "20",
-         "--duration", "3"],
+         "--smoke", "--decode", "--decode_mode", "cb",
+         "--decode_slots", "2", "--spec_k", "0,2",
+         "--step_cost_ms", "20", "--qps", "20", "--duration", "3"],
         capture_output=True, text=True, timeout=600, cwd=REPO, env=env)
     assert proc.returncode == 0, proc.stderr[-2000:]
     recs = [json.loads(l) for l in proc.stdout.splitlines()

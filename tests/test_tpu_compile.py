@@ -1,0 +1,125 @@
+"""The main path's kernels, compiled by the TPU's own compiler without a chip.
+
+Interpret mode cannot see what Mosaic refuses: a block below the (8, 128)
+tile floor, a reshape of a packed tile with no layout, a VMEM overrun.  The
+TPU compiler is installed wherever jax[tpu] is and compiles for a chip that
+is DESCRIBED, not attached (on-chip-measurement guide §2.3), so every shape
+below is lowered with `interpret=False` and compiled for one v5e — about a
+second each, no chip time.  PR 21 added this file after `decode_attention`
+turned out never to have compiled for a TPU at any shape.
+
+A compile that passes is not a chip run: results and times come from
+`chip_smoke.py`.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from paddle_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """SingleDeviceSharding on the first device of a described v5e 2x2."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip("cannot describe a v5e topology: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to jax's persistent cache
+    but cannot be read back without the chip — the next run would warn and
+    compile again — so the cache is off around these tests."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def compile_for_chip(fn, one_chip, *specs):
+    args = [jax.ShapeDtypeStruct(shape, np.dtype(dt), sharding=one_chip)
+            for shape, dt in specs]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the executable"
+    return text
+
+
+# the serve phase of chip_smoke.py (FLAGS.serving_decode_slots slots of a
+# GPT-2-small cache) and a wider, longer table
+DECODE_GEOMETRIES = {"n8_s1024_h12_d64": (8, 1024, 12, 64),
+                     "n32_s2048_h16_d128": (32, 2048, 16, 128)}
+DECODE_DTYPES = {"fp32": ("float32", "float32"),
+                 "bf16": ("bfloat16", "bfloat16"),
+                 "int8kv": ("float32", "int8")}
+
+
+def decode_specs(N, S, H, D, q_dt, kv_dt, h_scales=None):
+    specs = [((N, H, D), q_dt), ((N, S, H, D), kv_dt), ((N, S, H, D), kv_dt),
+             ((N,), "int32")]
+    if kv_dt == "int8":
+        specs.append(((2, h_scales or H), "float32"))
+    return specs
+
+
+@pytest.mark.parametrize("dtypes", sorted(DECODE_DTYPES))
+@pytest.mark.parametrize("geometry", sorted(DECODE_GEOMETRIES))
+def test_decode_attention_compiles(one_chip, geometry, dtypes):
+    q_dt, kv_dt = DECODE_DTYPES[dtypes]
+
+    def fn(q, k, v, lengths, *scales):
+        return pk.decode_attention(q, k, v, lengths, interpret=False,
+                                   kv_scales=scales[0] if scales else None)
+
+    compile_for_chip(fn, one_chip, *decode_specs(
+        *DECODE_GEOMETRIES[geometry], q_dt, kv_dt))
+
+
+@pytest.mark.parametrize("kv_dt", ["float32", "int8"])
+def test_decode_attention_head_slice_compiles(one_chip, kv_dt):
+    """One member's head block of a 4-way tensor-parallel split of the
+    12-head table: Hl = 3, scales sliced from the full [2, 12] table."""
+    N, S, H, D, Hl = 8, 1024, 12, 64, 3
+
+    def fn(q, k, v, lengths, *scales):
+        return pk.decode_attention_head_slice(
+            q, k, v, lengths, head_offset=Hl, n_local_heads=Hl,
+            interpret=False, kv_scales=scales[0] if scales else None)
+
+    compile_for_chip(fn, one_chip, *decode_specs(
+        N, S, Hl, D, "float32", kv_dt, h_scales=H))
+
+
+@pytest.mark.parametrize("shape", [(2, 4096, 16, 128), (8, 512, 8, 64)],
+                         ids=["b2_s4096_h16_d128", "b8_s512_h8_d64"])
+def test_flash_attention_fwd_bwd_compiles(one_chip, shape):
+    def loss(q, k, v):
+        return jnp.sum(pk.flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32))
+
+    text = compile_for_chip(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                            *[(shape, "bfloat16")] * 3)
+    assert text.count("tpu_custom_call") >= 3     # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("m,k,n,dt", [(256, 2048, 8192, "bfloat16"),
+                                      (32, 2048, 2048, "float32")])
+def test_dequant_matmul_compiles(one_chip, m, k, n, dt):
+    compile_for_chip(
+        lambda x, w, s: pk.dequant_matmul(x, w, s, interpret=False),
+        one_chip, ((m, k), dt), ((k, n), "int8"), ((n,), "float32"))

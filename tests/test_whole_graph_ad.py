@@ -178,9 +178,7 @@ def test_remat_step_lowers_for_tpu_offchip(amp):
     """The BENCH_REMAT step must LOWER for TPU — checkable without a
     chip via cross-platform jax.export (the full ResNet-50 variant was
     validated the same way; this keeps a fast guard in the suite, in
-    BOTH precisions since bench runs bf16 AMP). The r03/r04 transport
-    wedges during the remat compile were load failures, not lowering
-    failures — this test pins that."""
+    BOTH precisions since bench runs bf16 AMP)."""
     fluid.set_amp(amp)
     try:
         main, startup, loss = _conv_model()

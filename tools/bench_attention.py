@@ -19,12 +19,11 @@ Prints one JSON line per measurement:
   {"metric": "attention_tune", "seq_len": S, "block_q": ..., ...}
   {"metric": "attention_tuned", "seq_len": S, "config": {...}}
 
-Runs as a best-effort EXTRA at the end of the tpu_watch sweep — after
-every primary stage has completed and been flushed, so a wedge here
-cannot cost recorded numbers. CPU smoke: --smoke runs tiny shapes in
-interpret mode (tiny tile candidates under --tune), so the full
-bench/tune/cache plumbing is exercised without a chip — the tier-1
-test in tests/test_flash_attention.py does exactly that.
+CPU smoke: --smoke runs tiny shapes in interpret mode (tiny tile
+candidates under --tune), so the full bench/tune/cache plumbing is
+exercised without a chip — the tier-1 test in
+tests/test_flash_attention.py does exactly that. Without --smoke the run
+needs the chip (exit 3 otherwise).
 """
 
 import argparse
@@ -160,7 +159,6 @@ def main():
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--causal", type=int, default=1)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--require_tpu", action="store_true")
     ap.add_argument("--tune", action="store_true",
                     help="sweep (block_q, block_kv) geometries per seq "
                          "len and persist the winners to the trace-time "
@@ -171,9 +169,8 @@ def main():
     args = ap.parse_args()
 
     from bench import init_backend
-    on_tpu, backend_label = init_backend(
-        smoke=args.smoke, require_tpu=args.require_tpu,
-        tool="bench_attention")
+    device = init_backend(smoke=args.smoke, tool="bench_attention")
+    on_tpu = device["platform"] == "tpu"
     _on_tpu[0] = on_tpu
     import jax
     import jax.numpy as jnp
@@ -195,8 +192,7 @@ def main():
     dtype = jnp.bfloat16 if on_tpu else jnp.float32
 
     def emit(rec):
-        if backend_label:
-            rec["backend"] = backend_label
+        rec.update(device)
         print(json.dumps(rec), flush=True)
 
     def make_fn(attn):

@@ -50,10 +50,12 @@ def run(layout, batch, amp=True, iters=20):
     final = float(np.asarray(fetches[0]))
     dt = time.perf_counter() - t0
     ips = batch * iters / dt
-    tflops = ips * 12.3e9 / 1e12
+    from paddle_tpu.analysis.resources import device_peaks
+    flops = ips * 12.3e9
+    peak = device_peaks(jax.devices()[0])["peak_flops"]
     print("layout=%s batch=%d amp=%s: %.1f img/s  %.1f TFLOP/s  %.1f%% MFU "
-          "(loss %.4f)" % (layout, batch, amp, ips, tflops,
-                           tflops / 197.0 * 100.0, final), flush=True)
+          "(loss %.4f)" % (layout, batch, amp, ips, flops / 1e12,
+                           flops / peak * 100.0, final), flush=True)
 
 
 if __name__ == "__main__":

@@ -2,7 +2,7 @@
 
 Pallas->Mosaic conversion and XLA lowering happen at jax.export time,
 so every zoo config's training step can be validated for the TPU
-platform from a CPU-only host — no transport window gets burned
+platform from a CPU-only host — no chip time gets burned
 discovering a lowering bug mid-sweep. Prints one JSON line per config:
 
   {"config": ..., "ok": true, "mlir_bytes": N}
@@ -188,9 +188,9 @@ def main():
     ap.add_argument("--only", default="",
                     help="comma-separated config-name substring filter")
     args = ap.parse_args()
-    # pin CPU BEFORE any backend query: on a transport-attached host the
-    # first jax op would otherwise initialize the TPU runtime this
-    # sweep exists to avoid touching (same guard as fluid_benchmark)
+    # pin CPU BEFORE any backend query: on a host with a chip the first
+    # jax op would otherwise take the TPU this sweep exists to avoid
+    # touching
     import jax
     jax.config.update("jax_platforms", "cpu")
     wanted = [w for w in args.only.split(",") if w]

@@ -11,7 +11,7 @@ below ln(1000) as the model memorizes the pool.
 Prints ONE JSON line {"metric": "convergence", "losses": [...], ...};
 the watcher archives it into the tracked recovery record.
 
-Usage: convergence_run.py [--steps 300] [--batch 256] [--require_tpu]
+Usage: convergence_run.py [--steps 300] [--batch 256] [--smoke]
 """
 
 import argparse
@@ -37,15 +37,13 @@ def main():
                     help="memorization-run lr: the flagship bench's 0.1 "
                          "is tuned for real-data epochs, not a "
                          "300-step random-label memorization probe")
-    ap.add_argument("--require_tpu", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CPU shapes, 20 steps (CI path check)")
     args = ap.parse_args()
 
     from bench import init_backend
-    on_tpu, backend_label = init_backend(
-        smoke=args.smoke, require_tpu=args.require_tpu,
-        tool="convergence_run")
+    device = init_backend(smoke=args.smoke, tool="convergence_run")
+    on_tpu = device["platform"] == "tpu"
     import jax
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import functionalizer
@@ -98,9 +96,8 @@ def main():
         "gate_passed": bool(last < np.log(1000.0) * 0.7) if on_tpu
         else None,
         "wall_sec": round(dt, 1),
+        **device,
     }
-    if not on_tpu:
-        rec["backend"] = backend_label
     print(json.dumps(rec))
 
 

@@ -20,7 +20,7 @@ def main(layout="NHWC", batch=256, remat=False):
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     from bench import init_backend
-    init_backend(require_tpu=True, tool="profile_step")
+    init_backend(tool="profile_step")
     import jax
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import functionalizer

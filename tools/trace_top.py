@@ -9,7 +9,7 @@ scatter for a request; prefetch_wait / dispatch / drain / ckpt for a
 train step).  `--trace_id` resolves ONE reply-visible id into its span
 tree; `--json` dumps raw.
 
-`--capture` is the tpu_watch "obs" stage: runs one traced serving run +
+`--capture` runs one traced serving run +
 one traced train step in-process under the jax profiler, exports the
 MERGED chrome trace (obs spans + device timeline,
 profiler.export_chrome_tracing) to `--out_dir`, and prints a one-line
@@ -122,7 +122,7 @@ def render_tree(spans):
 
 
 # ---------------------------------------------------------------------------
-# --capture: the tpu_watch "obs" stage
+# --capture
 # ---------------------------------------------------------------------------
 
 def capture(model_kind=None, out_dir=None, steps=3):
@@ -217,7 +217,7 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true")
     ap.add_argument("--capture", action="store_true",
                     help="traced serving run + train step; archive the "
-                         "merged chrome trace (tpu_watch obs stage)")
+                         "merged chrome trace")
     ap.add_argument("--model", default=None,
                     help="--capture model kind (default: resnet on "
                          "tpu, fc elsewhere)")

@@ -4,7 +4,7 @@ the real chip and report the fastest (plus the XLA-composition baseline).
 The kernel's one tiling knob is block_h (output rows per program); the
 best value depends on Mosaic's relayout costs for the stride-2
 reshape-decimation and on VMEM double-buffering, which can only be
-measured on silicon. Run when the transport is stable:
+measured on silicon:
 
     python tools/tune_bottleneck.py            # all ResNet-50 stages
     python tools/tune_bottleneck.py --stage 1  # one stage
@@ -43,16 +43,11 @@ def main():
     ap.add_argument("--batch", type=int, default=16)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--require_tpu", action="store_true",
-                    help="exit 3 instead of falling back to CPU — "
-                         "interpret-mode timings must never be mistaken "
-                         "for chip tuner results")
     args = ap.parse_args()
 
     from bench import init_backend
-    on_tpu, backend_label = init_backend(smoke=args.smoke,
-                                         require_tpu=args.require_tpu,
-                                         tool="tune_bottleneck")
+    device = init_backend(smoke=args.smoke, tool="tune_bottleneck")
+    on_tpu = device["platform"] == "tpu"
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas_kernels import (fused_bottleneck,
@@ -123,8 +118,7 @@ def main():
             print(json.dumps(rec))
         summary = {"stage": stage, "best": best[0],
                    "best_ms": round(best[1], 3)}
-        if backend_label:
-            summary["backend"] = backend_label
+        summary.update(device)
         print(json.dumps(summary))
 
 

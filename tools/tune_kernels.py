@@ -287,14 +287,11 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CPU-safe shapes, 2 iters — the tier-1 "
                          "path proving the sweep-record-resolve loop")
-    ap.add_argument("--require_tpu", action="store_true")
     args = ap.parse_args()
 
     from bench import init_backend
-    on_tpu, backend = init_backend(smoke=args.smoke,
-                                   require_tpu=args.require_tpu,
-                                   tool="tune_kernels")
-    _on_tpu[0] = on_tpu
+    device = init_backend(smoke=args.smoke, tool="tune_kernels")
+    _on_tpu[0] = device["platform"] == "tpu"
     if args.cache_dir:
         from paddle_tpu.flags import FLAGS
         FLAGS.compile_cache_dir = args.cache_dir
@@ -321,8 +318,8 @@ def main():
         tuned += tune_dequant(_parse_shapes(args.dequant_shapes, 3,
                                             "dequant_shapes"),
                               dtypes, args.iters)
-    emit({"metric": "tune_kernels_done", "backend": backend,
-          "families": families, "entries": len(tuned)})
+    emit({"metric": "tune_kernels_done", "families": families,
+          "entries": len(tuned), **device})
     return 0 if tuned else 2
 
 

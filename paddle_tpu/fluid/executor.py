@@ -15,6 +15,7 @@ updates are in-place at the XLA level.
 """
 
 import threading
+import time
 import warnings
 
 import numpy as np
@@ -23,6 +24,7 @@ from . import core
 from .framework import Program, Variable, default_main_program
 from . import functionalizer
 from .pipeline import FetchFuture
+from ..obs import tracing as obs_tracing
 
 __all__ = ["Executor", "Scope", "global_scope", "scope_guard", "as_numpy",
            "StepWatchdogTimeout", "FetchFuture"]
@@ -256,6 +258,66 @@ def prepare_feeds(program, feed, device_put=True):
     return feeds
 
 
+def _feed_bytes(feed, feeds):
+    """(h2d_bytes, cast_bytes) of one prepared feed, for the
+    `executor/feed` span: the bytes of the values that arrived as host
+    arrays (what the feed uploads; a jax.Array feed counts 0), and of
+    those the bytes that went through an `astype` on the host first."""
+    import jax
+    if isinstance(feed, (list, tuple)):     # per-device dicts, all host
+        feed = {k: None for d in feed for k in d}
+    h2d = cast = 0
+    for name, value in feed.items():
+        if isinstance(value, jax.Array):
+            continue
+        for key in (name, name + functionalizer.LOD_LEN_SUFFIX,
+                    name + functionalizer.LOD_SEG_SUFFIX):
+            if key in feeds:
+                h2d += int(feeds[key].nbytes)
+        src = getattr(value, "dtype", None)
+        if src is not None and name in feeds \
+                and np.dtype(src) != feeds[name].dtype:
+            cast += int(feeds[name].nbytes)
+    return h2d, cast
+
+
+def _stamp_dispatched(t_in, t_fed, t_called, feed, feeds, state_in, step,
+                      compiled):
+    """`executor/feed` and `executor/dispatch` of one call, landed as soon
+    as the jitted call has returned: the device is working then, so the
+    counting (the feed's bytes, a pass over the state for leaves still on
+    the host) costs the step nothing.  Returns the `step` its spans carry
+    (the enclosing train span's, else the executor's own)."""
+    step = obs_tracing.inherited("step", step)
+    h2d, cast = _feed_bytes(feed, feeds)
+    obs_tracing.stamp("executor/feed", t_in, t_fed, kind="train",
+                      parent="executor/run", step=step, h2d_bytes=h2d,
+                      cast_bytes=cast)
+    obs_tracing.stamp(
+        "executor/dispatch", t_fed, t_called, kind="train",
+        parent="executor/run", step=step, compiled=int(compiled),
+        state_host_bytes=sum(
+            int(v.nbytes) for v in state_in.values()
+            if isinstance(v, (np.ndarray, np.generic))))
+    return step
+
+
+def _stamp_returned(t_in, t_called, step, steps, path, out):
+    """`executor/fetch` and the `executor/run` root, from the same
+    contiguous stamps as `_stamp_dispatched` (entry, feed prepared, call
+    returned, now) so that the children tile the root exactly.  `out` is
+    what the call returns, or None for a dispatch whose fetch is left to a
+    FetchFuture (no `executor/fetch` then)."""
+    t_out = time.monotonic()
+    if out is not None:
+        obs_tracing.stamp(
+            "executor/fetch", t_called, t_out, kind="train",
+            parent="executor/run", step=step, d2h_bytes=sum(
+                int(v.nbytes) for v in out if isinstance(v, np.ndarray)))
+    obs_tracing.stamp("executor/run", t_in, t_out, kind="train", step=step,
+                      steps=steps, path=path)
+
+
 class Executor:
     """reference executor.py:256. `place` selects the jax backend; under jit
     there is no per-op placement, so CPUPlace/TPUPlace only choose where the
@@ -441,6 +503,9 @@ class Executor:
         """
         import jax
         import jax.numpy as jnp
+        traced = obs_tracing.enabled()
+        if traced:
+            t_in = time.monotonic()
         if program is None:
             program = default_main_program()
         if feed is None:
@@ -471,6 +536,8 @@ class Executor:
 
         fetch_names = tuple(_fetch_name(f) for f in fetch_list)
         feeds = self._prepare_feeds(program, feed)
+        if traced:
+            t_fed, n_built = time.monotonic(), len(self._cache)
         feed_key = tuple(sorted(feeds.keys()))
         lod_fetch = tuple(n + functionalizer.LOD_LEN_SUFFIX
                           for n in fetch_names)
@@ -502,12 +569,20 @@ class Executor:
         # only a successful dispatch advances the counter — a build or
         # compile failure must not skew the RNG step fold for later runs
         self._step_counters[id(program)] = step0 + steps
+        if traced:
+            t_called = time.monotonic()
+            step_attr = _stamp_dispatched(
+                t_in, t_fed, t_called, feed, feeds, state_in, step0,
+                len(self._cache) > n_built)
         if FLAGS.benchmark:
             jax.block_until_ready((fetches, new_state))
         for n, val in new_state.items():
             scope.set(n, val)
-        return self._post_fetches(fetch_names, lod_fetch, seg_fetch,
-                                  fetches, return_numpy)
+        out = self._post_fetches(fetch_names, lod_fetch, seg_fetch,
+                                 fetches, return_numpy)
+        if traced:
+            _stamp_returned(t_in, t_called, step_attr, steps, "jit", out)
+        return out
 
     def run(self, program=None, feed=None, fetch_list=None,
             feed_var_name="feed", fetch_var_name="fetch", scope=None,
@@ -523,6 +598,9 @@ class Executor:
         that are inherently synchronous (FLAGS.check_nan_inf, host-op
         programs, FLAGS.benchmark) still honor the contract by
         returning an already-resolved future."""
+        traced = obs_tracing.enabled()
+        if traced:
+            t_in = time.monotonic()
         if program is None:
             program = default_main_program()
         if feed is None:
@@ -535,6 +613,8 @@ class Executor:
         fetch_names = tuple(_fetch_name(f) for f in fetch_list)
 
         feeds = self._prepare_feeds(program, feed)
+        if traced:
+            t_fed, n_built = time.monotonic(), len(self._cache)
         feed_key = tuple(sorted(feeds.keys()))
 
         # for ragged fetches, also fetch the companion lengths (present in
@@ -582,6 +662,7 @@ class Executor:
             fetches, new_state = self._dispatch(
                 lambda: fn(state_in, feeds, np.uint32(step)),
                 FLAGS.step_watchdog_secs, "eager executor step")
+            path = "eager"
         elif has_host:
             # RPC / IO host ops do side effects, but the compute BETWEEN
             # them still runs from the XLA jit cache: the segmented runner
@@ -602,8 +683,10 @@ class Executor:
                 FLAGS.step_watchdog_secs, "segmented executor step")
             fetches = [env.get(n) for n in fetch_ext]
             new_state = {n: env[n] for n in persistables if n in env}
+            path = "segmented"
         else:
             fn = None
+            path = "aot"
             if FLAGS.executor_compile_cache:
                 # inference-side persistent compile cache (opt-in): a
                 # program whose fingerprint derives from its content
@@ -611,6 +694,7 @@ class Executor:
                 fn = self._get_aot_cached(program, feed_key, fetch_ext,
                                           persistables, state_in, feeds)
             if fn is None:
+                path = "jit"
                 fn = self._get_jitted(program, feed_key, fetch_ext,
                                       persistables)
             # in-flight mode: the dispatch is non-blocking by design and
@@ -620,6 +704,11 @@ class Executor:
             fetches, new_state = self._dispatch(
                 lambda: fn(state_in, feeds, np.uint32(step)),
                 wd, "jitted executor step")
+        if traced:
+            t_called = time.monotonic()
+            step_attr = _stamp_dispatched(
+                t_in, t_fed, t_called, feed, feeds, state_in, step,
+                len(self._cache) > n_built)
         if FLAGS.benchmark:
             # reference FLAGS_benchmark: force device sync per step so
             # wall-clock timing around run() is honest (scope.cc:25)
@@ -640,9 +729,14 @@ class Executor:
                 # hand back a resolved future so the caller's drain is
                 # a no-op rather than a second conversion site
                 fut.result()
-            return fut
-        return self._post_fetches(fetch_names, lod_fetch, seg_fetch,
-                                  fetches, return_numpy)
+            out = fut
+        else:
+            out = self._post_fetches(fetch_names, lod_fetch, seg_fetch,
+                                     fetches, return_numpy)
+        if traced:
+            _stamp_returned(t_in, t_called, step_attr, 1, path,
+                            None if as_future else out)
+        return out
 
     @staticmethod
     def _post_fetches(fetch_names, lod_fetch, seg_fetch, fetches,

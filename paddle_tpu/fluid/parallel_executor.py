@@ -19,15 +19,18 @@ Param broadcast at construction (BCastParamsToDevices, parallel_executor.cc
 """
 
 import os
+import time
 
 import numpy as np
 
 from . import core
-from .executor import global_scope, as_numpy, _fetch_name
+from .executor import (global_scope, as_numpy, _fetch_name,
+                       _stamp_dispatched, _stamp_returned)
 from .pipeline import FetchFuture
 from .framework import default_main_program
 from . import functionalizer
 from ..parallel.mesh import data_parallel_mesh, DATA_AXIS
+from ..obs import tracing as obs_tracing
 
 __all__ = ["ParallelExecutor", "ExecutionStrategy", "BuildStrategy"]
 
@@ -305,6 +308,9 @@ class ParallelExecutor:
         involvement between steps."""
         import jax
         import jax.numpy as jnp
+        traced = obs_tracing.enabled()
+        if traced:
+            t_in = time.monotonic()
         steps = int(steps)
         if steps < 1:
             raise ValueError("run_loop: steps must be >= 1")
@@ -331,6 +337,8 @@ class ParallelExecutor:
                 "as one device computation — use ParallelExecutor.run")
         fetch_names = tuple(_fetch_name(f) for f in fetch_list)
         feeds = self._prepare_feeds(feed)
+        if traced:
+            t_fed, n_built = time.monotonic(), len(self._cache)
         feed_key = tuple(sorted(feeds.keys()))
         persistables = tuple(
             functionalizer.persistable_names(self._main_program))
@@ -351,15 +359,21 @@ class ParallelExecutor:
                     if self._scope.get(n) is not None}
         fetches, new_state = fn(state_in, feeds,
                                 np.uint32(self._step), np.int32(steps))
+        if traced:
+            t_called = time.monotonic()
+            step_attr = _stamp_dispatched(
+                t_in, t_fed, t_called, feed or {}, feeds, state_in,
+                self._step, len(self._cache) > n_built)
         self._step += steps
         for n, val in new_state.items():
             self._scope.set(n, val)
-        if return_numpy:
-            # one batched device->host copy for the whole fetch list —
-            # a per-item np.asarray loop would serialize the transfers
-            import jax
-            return jax.device_get(list(fetches))
-        return list(fetches)
+        # one batched device->host copy for the whole fetch list —
+        # a per-item np.asarray loop would serialize the transfers
+        out = jax.device_get(list(fetches)) if return_numpy \
+            else list(fetches)
+        if traced:
+            _stamp_returned(t_in, t_called, step_attr, steps, "jit", out)
+        return out
 
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True,
             as_future=False):
@@ -374,6 +388,9 @@ class ParallelExecutor:
         the FetchFuture keeps the fetches as live (sharded) device
         arrays and the host sync is deferred to `.result()` — same
         in-flight contract as Executor.run (PIPELINE.md)."""
+        traced = obs_tracing.enabled()
+        if traced:
+            t_in = time.monotonic()
         fetch_names = tuple(_fetch_name(f) for f in fetch_list)
         from ..flags import FLAGS
         if FLAGS.verify_program:
@@ -383,6 +400,8 @@ class ParallelExecutor:
                 feeds=sorted(feed) if isinstance(feed, dict) else None,
                 fetches=fetch_names, what="parallel executor program")
         feeds = self._prepare_feeds(feed, feed_dict)
+        if traced:
+            t_fed, n_built = time.monotonic(), len(self._cache)
         feed_key = tuple(sorted(feeds.keys()))
 
         persistables = tuple(
@@ -391,15 +410,26 @@ class ParallelExecutor:
         state_in = {n: self._scope.get(n) for n in persistables
                     if self._scope.get(n) is not None}
         fetches, new_state = fn(state_in, feeds, np.uint32(self._step))
+        if traced:
+            t_called = time.monotonic()
+            step_attr = _stamp_dispatched(
+                t_in, t_fed, t_called,
+                feed if feed is not None else feed_dict or {}, feeds,
+                state_in, self._step, len(self._cache) > n_built)
         self._step += 1
         for n, val in new_state.items():
             self._scope.set(n, val)
         if as_future:
-            return FetchFuture(fetches, return_numpy=return_numpy,
-                               what="parallel executor step drain")
-        if return_numpy:
+            out = FetchFuture(fetches, return_numpy=return_numpy,
+                              what="parallel executor step drain")
+        elif return_numpy:
             # one batched device->host copy for the whole fetch list —
             # per-item np.asarray would serialize the gathers
             import jax
-            return jax.device_get(list(fetches))
-        return list(fetches)
+            out = jax.device_get(list(fetches))
+        else:
+            out = list(fetches)
+        if traced:
+            _stamp_returned(t_in, t_called, step_attr, 1, "jit",
+                            None if as_future else out)
+        return out

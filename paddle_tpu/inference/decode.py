@@ -101,6 +101,8 @@ import warnings
 
 import numpy as np
 
+from paddle_tpu.obs import tracing as obs_tracing
+
 __all__ = ["GenerativePredictor", "DecodeSession",
            "SpeculativeDecodeSession", "save_decode_model",
            "build_tiny_decode_model", "load_decode_predictor",
@@ -134,6 +136,18 @@ def _check_draft_poison():
     if _DRAFT_POISON["steps"] > after:
         raise RuntimeError("chaos: draft predictor poisoned "
                            "(set_draft_poison)")
+
+
+def _nbytes(leaves):
+    return sum(int(np.asarray(a).nbytes) for a in leaves)
+
+
+def _host_nbytes(leaves):
+    """Bytes of the leaves an executable call has to upload itself:
+    every one that is not a jax.Array yet."""
+    import jax
+    return sum(int(np.asarray(a).nbytes) for a in leaves
+               if not isinstance(a, jax.Array))
 
 
 def normalize_kv_dtype(value):
@@ -430,6 +444,7 @@ class GenerativePredictor:
         else:
             self._state = {n: np.asarray(v)
                            for n, v in self._state_host.items()}
+        self._state_host_nbytes = None
         self._fns = {}          # per-instance resolved callables
         self._lock = threading.Lock()
         # prompt lengths past every configured prefill bucket that have
@@ -556,6 +571,14 @@ class GenerativePredictor:
         """Static weight footprint (host-state nbytes sum)."""
         return sum(int(np.asarray(v).nbytes)
                    for v in self._state_host.values())
+
+    def state_host_bytes(self):
+        """Bytes of the weights that are NOT on a device (numpy leaves
+        of `_state`, the default placement): what every executable call
+        uploads again.  The state is static, so this is counted once."""
+        if self._state_host_nbytes is None:
+            self._state_host_nbytes = _host_nbytes(self._state.values())
+        return self._state_host_nbytes
 
     # -- model math -----------------------------------------------------
 
@@ -1530,6 +1553,29 @@ class DecodeSession:
             return _put_feed(arr, self.predictor.device)
         return arr
 
+    def _call(self, phase, fn, cache, small):
+        """`fn(state, *cache, *small)`, with its `decode/put` and
+        `decode/launch` spans when tracing is on: `_put` of the small
+        per-call arguments, then the executable call until it returns
+        (synchronous for whatever host argument it has to upload;
+        `h2d_bytes` counts those)."""
+        state = self.predictor._state
+        if not obs_tracing.enabled():
+            return fn(state, *cache, *[self._put(a) for a in small])
+        t0 = time.monotonic()
+        args = [self._put(a) for a in small]
+        t1 = time.monotonic()
+        out = fn(state, *cache, *args)
+        t2 = time.monotonic()
+        obs_tracing.stamp("decode/put", t0, t1, kind="serving",
+                          phase=phase,
+                          bytes=_nbytes(small) - _host_nbytes(args))
+        obs_tracing.stamp("decode/launch", t1, t2, kind="serving",
+                          phase=phase,
+                          h2d_bytes=self.predictor.state_host_bytes()
+                          + _host_nbytes(cache) + _host_nbytes(args))
+        return out
+
     def prefill(self, slot, tokens):
         """Run the prompt through the bucketed prefill, land its K/V in
         `slot`, and return the first generated token (greedy).  The
@@ -1547,14 +1593,14 @@ class DecodeSession:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = tokens
         fn = self.predictor.prefill_fn(bucket)
-        first, kc, vc = fn(self.predictor._state, self._put(padded),
-                           self._put(np.int32(n)))
+        first, kc, vc = self._call("prefill", fn, (),
+                                   (padded, np.int32(n)))
         # land the bucket-length K/V at the slot; positions past the
         # bucket are already zero (the slot was zeroed on free)
         at = (0, slot, 0, 0, 0)
         self._kc = jax.lax.dynamic_update_slice(self._kc, kc, at)
         self._vc = jax.lax.dynamic_update_slice(self._vc, vc, at)
-        tok = int(first)
+        tok = int(self._fetch("prefill", first)[0])
         self.lengths[slot] = n
         self.last_tokens[slot] = tok
         self.active[slot] = True
@@ -1565,7 +1611,7 @@ class DecodeSession:
         np.int32 [n_slots] token vector (only entries of slots active
         at call time are meaningful).  Bumps each active slot's length
         and last token."""
-        toks = np.asarray(self._step(
+        toks, = self._fetch("step", self._step(
             self.predictor.step_fn(self.n_slots)))
         self._advance(toks)
         return toks
@@ -1576,7 +1622,7 @@ class DecodeSession:
         comparing two predictors on one stream overwrites
         `last_tokens` afterwards (teacher forcing), as the speculative
         session does for its draft."""
-        logits = np.asarray(self._step(
+        logits, = self._fetch("step", self._step(
             self.predictor.step_logits_fn(self.n_slots)))
         toks = logits.argmax(axis=-1).astype(np.int32)
         self._advance(toks)
@@ -1585,11 +1631,23 @@ class DecodeSession:
     def _step(self, fn):
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
-        out, self._kc, self._vc = fn(
-            self.predictor._state, self._kc, self._vc,
-            self._put(self.lengths), self._put(self.last_tokens),
-            self._put(self.active))
+        out, self._kc, self._vc = self._call(
+            "step", fn, (self._kc, self._vc),
+            (self.lengths, self.last_tokens, self.active))
         return out
+
+    @staticmethod
+    def _fetch(phase, *outs):
+        """`np.asarray` of each result: the wait for the device and the
+        copy to the host, under one `decode/fetch` span."""
+        if not obs_tracing.enabled():
+            return [np.asarray(o) for o in outs]
+        t0 = time.monotonic()
+        got = [np.asarray(o) for o in outs]
+        obs_tracing.stamp("decode/fetch", t0, time.monotonic(),
+                          kind="serving", phase=phase,
+                          d2h_bytes=_nbytes(got))
+        return got
 
     def _advance(self, toks):
         act = self.active
@@ -1625,17 +1683,19 @@ class DecodeSession:
             b = np.clip(np.where(act, b, 0), 0, T).astype(np.int32)
         mt = T if max_trips is None else max(1, min(int(max_trips), T))
         fn = self.predictor.fused_step_fn(self.n_slots, T)
-        toks, counts, trips, self._kc, self._vc, lengths, last = fn(
-            self.predictor._state, self._kc, self._vc,
-            self._put(self.lengths), self._put(self.last_tokens),
-            self._put(act), self._put(b), self._put(np.int32(mt)))
+        toks, counts, trips, self._kc, self._vc, lengths, last = \
+            self._call("fused", fn, (self._kc, self._vc),
+                       (self.lengths, self.last_tokens, act, b,
+                        np.int32(mt)))
         # lengths/last_tokens come back from the device: pure integer
         # bookkeeping, so device round-trip is exact
-        self.lengths = np.asarray(lengths).astype(np.int32)
-        self.last_tokens = np.asarray(last).astype(np.int32)
+        lengths, last, trips, toks, counts = self._fetch(
+            "fused", lengths, last, trips, toks, counts)
+        self.lengths = lengths.astype(np.int32)
+        self.last_tokens = last.astype(np.int32)
         trips = int(trips)
         self.steps += trips
-        return np.asarray(toks), np.asarray(counts), trips
+        return toks, counts, trips
 
     def room(self, slot):
         """Generated tokens this slot can still hold (cache positions
@@ -1945,12 +2005,11 @@ class SpeculativeDecodeSession:
             for j in range(k):
                 chunk[:, j + 1] = drafts[j]
             fn = self.predictor.verify_fn(N, k)
-            g, m, ts._kc, ts._vc = fn(
-                self.predictor._state, ts._kc, ts._vc,
-                ts._put(ts.lengths), ts._put(chunk),
-                ts._put(active))
-            g = np.asarray(g)
-            m = np.where(active, np.asarray(m), 0).astype(np.int32)
+            g, m, ts._kc, ts._vc = ts._call(
+                "verify", fn, (ts._kc, ts._vc),
+                (ts.lengths, chunk, active))
+            g, m = ts._fetch("verify", g, m)
+            m = np.where(active, m, 0).astype(np.int32)
             counts = np.where(active, m + 1, 0).astype(np.int32)
             ts.lengths = (ts.lengths + counts).astype(np.int32)
             ts.last_tokens = np.where(

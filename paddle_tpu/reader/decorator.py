@@ -504,7 +504,7 @@ def prefetch_to_device(reader, depth=2, prepare=None, mesh=None):
             # queue per batch (0 when prefetch is hiding the host work
             # — the per-step breakdown's first column, PIPELINE.md /
             # OBSERVABILITY.md)
-            wait_t0 = _time.perf_counter()
+            wait_t0 = _time.monotonic()
             while True:
                 try:
                     item = q.get(timeout=1.0)
@@ -522,13 +522,10 @@ def prefetch_to_device(reader, depth=2, prepare=None, mesh=None):
                         "prefetch_to_device worker failed mid-stream: %s"
                         % item.exc_repr, cause_repr=item.exc_repr)
                 if _obs_tracing.enabled():
-                    wait_ms = (_time.perf_counter() - wait_t0) * 1e3
-                    _obs_tracing.add_span(_obs_tracing.Span(
-                        "train/prefetch_wait", kind="train",
-                        ts=_time.time() - wait_ms / 1e3,
-                        dur_ms=wait_ms))
+                    _obs_tracing.stamp("train/prefetch_wait", wait_t0,
+                                       _time.monotonic(), kind="train")
                 yield item
-                wait_t0 = _time.perf_counter()
+                wait_t0 = _time.monotonic()
         finally:
             stop.set()
             try:

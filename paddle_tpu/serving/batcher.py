@@ -616,23 +616,17 @@ class DynamicBatcher:
         """Land one request's stage span set in the tracing ring.  The
         stamps are contiguous monotonic instants, so the stages tile the
         root `serving/request` span exactly: a p99 outlier decomposes
-        into WHICH stage ate the time (OBSERVABILITY.md).  Wall-clock
-        `ts` per span is reconstructed from one time.time() anchor."""
-        wall_now = time.time()
+        into WHICH stage ate the time (OBSERVABILITY.md)."""
         model = self._model_name
         tid = r.trace_id
         t_taken = r.t_taken if r.t_taken is not None else t_start
         t_grouped = r.t_grouped if r.t_grouped is not None else t_start
 
-        def _mk(name, t0, t1, **attrs):
-            if t1 < t0:
-                t1 = t0
-            a = {"model": model} if model else {}
-            a.update(attrs)
-            obs_tracing.add_span(obs_tracing.Span(
-                name, kind="serving", trace_id=tid,
-                ts=wall_now - (now - t0), dur_ms=(t1 - t0) * 1e3,
-                attrs=a))
+        def _mk(name, t0, t1, parent="serving/request", **attrs):
+            if model:
+                attrs["model"] = model
+            obs_tracing.stamp(name, t0, t1, kind="serving", trace_id=tid,
+                              parent=parent, **attrs)
 
         _mk("serving/queue_wait", r.enqueued, t_taken)
         _mk("serving/coalesce", t_taken, t_grouped)
@@ -641,8 +635,9 @@ class DynamicBatcher:
         _mk("serving/compute", t_run, t_run_end, replica=lane.index,
             rows=total, batch_fill=n_live)
         _mk("serving/scatter", t_run_end, now)
-        _mk("serving/request", r.enqueued, now, replica=lane.index,
-            batch=r.batch or 0, batch_fill=n_live, priority=r.priority)
+        _mk("serving/request", r.enqueued, now, parent=None,
+            replica=lane.index, batch=r.batch or 0, batch_fill=n_live,
+            priority=r.priority)
 
     def _scatter(self, group, fetches, total, lane, t_start, t_run,
                  t_run_end):
@@ -1321,30 +1316,26 @@ class DecodeBatcher:
         queue_wait + prefill + decode tile serving/request exactly —
         the same tiling contract as the one-shot stage spans
         (OBSERVABILITY.md)."""
-        wall_now = time.time()
         model = self._model_name
         t_adm = req.t_admitted if req.t_admitted is not None \
             else req.enqueued
         t_first = req.t_first if req.t_first is not None else t_adm
 
-        def _mk(name, t0, t1, **attrs):
-            if t1 < t0:
-                t1 = t0
-            a = {"model": model} if model else {}
-            a.update(attrs)
-            obs_tracing.add_span(obs_tracing.Span(
-                name, kind="serving", trace_id=req.trace_id,
-                ts=wall_now - (now - t0), dur_ms=(t1 - t0) * 1e3,
-                attrs=a))
+        def _mk(name, t0, t1, parent="serving/request", **attrs):
+            if model:
+                attrs["model"] = model
+            obs_tracing.stamp(name, t0, t1, kind="serving",
+                              trace_id=req.trace_id, parent=parent,
+                              **attrs)
 
         _mk("serving/queue_wait", req.enqueued, t_adm)
         _mk("serving/prefill", t_adm, t_first, replica=lane.index,
             prompt=len(req.prompt))
         _mk("serving/decode", t_first, now, replica=lane.index,
             tokens=len(req.gen))
-        _mk("serving/request", req.enqueued, now, replica=lane.index,
-            prompt=len(req.prompt), tokens=len(req.gen),
-            priority=req.priority)
+        _mk("serving/request", req.enqueued, now, parent=None,
+            replica=lane.index, prompt=len(req.prompt),
+            tokens=len(req.gen), priority=req.priority)
 
     def _obs_info(self, req, lane, now):
         t_adm = req.t_admitted or now
@@ -1423,6 +1414,7 @@ class DecodeBatcher:
         try:
             with obs_tracing.trace("serving/prefill_compute",
                                    kind="serving", trace_id=req.trace_id,
+                                   parent="serving/lane_iter",
                                    model=self._model_name,
                                    replica=lane.index,
                                    prompt=len(req.prompt)):
@@ -1452,31 +1444,38 @@ class DecodeBatcher:
             req.buf = []
 
     def _emit_step_spans(self, lane, t0, t_draft_end, now, n_slots,
-                         accepted=None, tokens=None, trips=None):
+                         rnd, accepted=None, tokens=None, trips=None):
         """Per-round step spans: `serving/decode_step` always (now a
         per-DISPATCH span: `tokens` emitted and `trips` loop
         iterations ride as attrs, the tokens-per-dispatch axis of the
         fused-decode win); on a speculative round its `serving/draft`
         + `serving/verify` children are cut from the same contiguous
         monotonic stamps so they TILE the round exactly (draft end ==
-        verify start).  One time.time() anchor places them on the
-        wall-clock axis; every duration rides the monotonic stamps."""
-        wall_now = time.time()
+        verify start).  `rnd` is the lane's dispatch count, the `round`
+        the session's `decode/*` spans of this dispatch inherited."""
         attrs = {"model": self._model_name or "", "replica": lane.index,
-                 "slots": n_slots}
-
-        def _mk(name, a, b, **extra):
-            at = dict(attrs)
-            at.update(extra)
-            obs_tracing.add_span(obs_tracing.Span(
-                name, kind="serving", ts=wall_now - (now - a),
-                dur_ms=(max(b, a) - a) * 1e3, attrs=at))
-
+                 "slots": n_slots, "round": rnd}
         if t_draft_end is not None:
-            _mk("serving/draft", t0, t_draft_end,
-                spec_k=lane.session.spec_k)
-            _mk("serving/verify", t_draft_end, now, accepted=accepted)
-        _mk("serving/decode_step", t0, now, tokens=tokens, trips=trips)
+            obs_tracing.stamp("serving/draft", t0, t_draft_end,
+                              kind="serving", parent="serving/decode_step",
+                              spec_k=lane.session.spec_k, **attrs)
+            obs_tracing.stamp("serving/verify", t_draft_end, now,
+                              kind="serving", parent="serving/decode_step",
+                              accepted=accepted, **attrs)
+        obs_tracing.stamp("serving/decode_step", t0, now, kind="serving",
+                          parent="serving/lane_iter", tokens=tokens,
+                          trips=trips, **attrs)
+
+    def _emit_lane_iter(self, lane, t_iter, rnd, admits, emitted):
+        """`serving/lane_iter`: one iteration of the lane's loop that
+        prefilled or dispatched, from the admission take to the notify —
+        the parent of its `serving/prefill_compute`, `serving/decode_step`
+        and `serving/emit`; what it holds beyond them is the lane's own
+        host time."""
+        obs_tracing.stamp("serving/lane_iter", t_iter, time.monotonic(),
+                          kind="serving", model=self._model_name or "",
+                          replica=lane.index, admits=admits,
+                          emitted=emitted, round=rnd)
 
     def _note_degraded(self, lane):
         """First observation of a degraded spec session: latch the obs
@@ -1547,6 +1546,8 @@ class DecodeBatcher:
                 self._cv.wait(0.1)
             if self._stopped and not lane.assigned:
                 return False
+            traced = obs_tracing.enabled()
+            t_iter = time.monotonic() if traced else None
             admits = self._take_admits_locked(lane) \
                 if self._admissible(lane) else []
         # prefill OUTSIDE the lock: other lanes keep decoding
@@ -1565,6 +1566,9 @@ class DecodeBatcher:
                 raise
         if not lane.assigned:
             self._note_degraded(lane)
+            if traced and admits:
+                self._emit_lane_iter(lane, t_iter, lane.steps,
+                                     len(admits), 0)
             return True
         fuse = self.fuse_steps
         if fuse > 1:
@@ -1581,6 +1585,9 @@ class DecodeBatcher:
                         and nowb > req.deadline:
                     self._expire(lane, slot, req, nowb)
             if not lane.assigned:
+                if traced and admits:
+                    self._emit_lane_iter(lane, t_iter, lane.steps,
+                                         len(admits), 0)
                 return True
         n_act = len(lane.assigned)
         t0 = time.monotonic()
@@ -1596,38 +1603,43 @@ class DecodeBatcher:
         if host_delay:
             time.sleep(host_delay)
         trips = 1
-        if lane.spec:
-            toks2d, counts = sess.step(
-                step_delay=delay,
-                draft_delay=_draft_chaos_delay(),
-                fused=fuse > 1)
-            spec_round = sess.last_spec
-        elif fuse > 1:
-            # per-slot token budgets (max_new / cache-room
-            # headroom) + the deadline governor: the lane's EWMA
-            # step time clamps the trip count so a deadlined
-            # stream never overshoots by more than ~one dispatch
-            budget = np.zeros(self.n_slots, np.int32)
-            max_trips = fuse
-            for slot, req in lane.assigned.items():
-                budget[slot] = min(req.max_new - len(req.gen),
-                                   sess.room(slot), fuse)
-                if req.deadline is not None and lane.step_ewma:
-                    allow = int((req.deadline - t0)
-                                / lane.step_ewma)
-                    max_trips = min(max_trips, max(allow, 1))
-            toks2d, counts, trips = sess.decode_fused(
-                fuse, budget=budget, max_trips=max_trips)
-            spec_round = False
-            if delay:
-                # the device-cost stand-in scales with the trips
-                # that actually ran (in-graph early exit included)
-                time.sleep(delay * trips)
-        else:
-            if delay:
-                time.sleep(delay)
-            toks = sess.decode()
-            spec_round = False
+        rnd = lane.steps
+        # the dispatch is the region `serving/decode_step` covers (it is
+        # stamped below, once the round's tokens are counted): the
+        # session's `decode/*` spans find their parent and round here
+        with obs_tracing.under("serving/decode_step", round=rnd):
+            if lane.spec:
+                toks2d, counts = sess.step(
+                    step_delay=delay,
+                    draft_delay=_draft_chaos_delay(),
+                    fused=fuse > 1)
+                spec_round = sess.last_spec
+            elif fuse > 1:
+                # per-slot token budgets (max_new / cache-room
+                # headroom) + the deadline governor: the lane's EWMA
+                # step time clamps the trip count so a deadlined
+                # stream never overshoots by more than ~one dispatch
+                budget = np.zeros(self.n_slots, np.int32)
+                max_trips = fuse
+                for slot, req in lane.assigned.items():
+                    budget[slot] = min(req.max_new - len(req.gen),
+                                       sess.room(slot), fuse)
+                    if req.deadline is not None and lane.step_ewma:
+                        allow = int((req.deadline - t0)
+                                    / lane.step_ewma)
+                        max_trips = min(max_trips, max(allow, 1))
+                toks2d, counts, trips = sess.decode_fused(
+                    fuse, budget=budget, max_trips=max_trips)
+                spec_round = False
+                if delay:
+                    # the device-cost stand-in scales with the trips
+                    # that actually ran (in-graph early exit included)
+                    time.sleep(delay * trips)
+            else:
+                if delay:
+                    time.sleep(delay)
+                toks = sess.decode()
+                spec_round = False
         now = time.monotonic()
         lane.steps += 1
         lane.last_step_t = now
@@ -1682,11 +1694,15 @@ class DecodeBatcher:
             elif len(req.buf) >= req.chunk:
                 req.stream._put_tokens(req.buf)
                 req.buf = []
-        if obs_tracing.enabled():
+        if traced:
+            obs_tracing.stamp("serving/emit", now, time.monotonic(),
+                              kind="serving", parent="serving/lane_iter",
+                              replica=lane.index, round=rnd,
+                              tokens=emitted)
             self._emit_step_spans(
                 lane, t0,
                 sess.last_draft_end if spec_round else None, now,
-                n_act,
+                n_act, rnd,
                 accepted=(int(counts.sum()) - n_act)
                 if spec_round else None,
                 tokens=emitted, trips=trips)
@@ -1700,6 +1716,8 @@ class DecodeBatcher:
                 self.metrics.note_tokens(emitted)
         with self._cv:
             self._cv.notify_all()
+        if traced:
+            self._emit_lane_iter(lane, t_iter, rnd, len(admits), emitted)
         return True
 
     # ------------------------------------------------------------------

@@ -572,6 +572,298 @@ class TestTrainingSpans:
 # profiler merge
 # ---------------------------------------------------------------------------
 
+# ---------------------------------------------------------------------------
+# phase spans where the chip waits: Executor.run, DecodeSession, the lane
+# ---------------------------------------------------------------------------
+
+def _fc_regression():
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 5
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[4], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        pred = fluid.layers.fc(input=x, size=1)
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(
+            input=pred, label=y))
+        fluid.optimizer.SGD(learning_rate=0.01).minimize(loss)
+    return main, startup, loss
+
+
+def _run_steps(feeds):
+    """Loss of each step of a fresh fc regression over `feeds`."""
+    main, startup, loss = _fc_regression()
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        obs_tracing.clear()
+        return [exe.run(main, feed=f, fetch_list=[loss])[0] for f in feeds]
+
+
+def _tree(spans, root):
+    """{child name: span} of the children stamped with `root`'s t0..end."""
+    return {s["name"]: s for s in spans if s.get("parent") == root["name"]
+            and root["t0"] <= s["t0"] <= root["t0"] + root["dur_ms"] * 1e-3}
+
+
+class _NoClock(object):
+    """Stands in for the `time` module of an instrumented file: with
+    tracing off no instrumented call may read the clock."""
+
+    def __init__(self):
+        self.sleep = time.sleep
+
+    def monotonic(self):
+        raise AssertionError("time.monotonic() read with FLAGS.trace off")
+
+
+@pytest.fixture(scope="module")
+def tiny_decode_dir(tmp_path_factory):
+    from paddle_tpu.inference.decode import build_tiny_decode_model
+    d = str(tmp_path_factory.mktemp("obs_decode") / "lm")
+    build_tiny_decode_model(d)
+    return d
+
+
+class TestPhaseSpans:
+    def test_executor_run_children_tile_it_and_count_bytes(self):
+        import jax.numpy as jnp
+        rng = np.random.RandomState(0)
+        x = rng.randn(8, 4).astype(np.float32)
+        y = rng.randn(8, 1).astype(np.float32)
+        _run_steps([{"x": x, "y": y}, {"x": x, "y": y},
+                    {"x": jnp.asarray(x), "y": jnp.asarray(y)}])
+        spans = obs.recent_spans(kind="train")
+        roots = [s for s in spans if s["name"] == "executor/run"]
+        assert len(roots) == 3
+        assert [r["attrs"]["path"] for r in roots] == ["jit"] * 3
+        assert [r["attrs"]["steps"] for r in roots] == [1, 1, 1]
+        want_h2d = [x.nbytes + y.nbytes, x.nbytes + y.nbytes, 0]
+        for root, h2d, compiled in zip(roots, want_h2d, [1, 0, 0]):
+            kids = _tree(spans, root)
+            assert set(kids) == {"executor/feed", "executor/dispatch",
+                                 "executor/fetch"}
+            assert abs(sum(k["dur_ms"] for k in kids.values())
+                       - root["dur_ms"]) < 1.0
+            assert kids["executor/feed"]["t0"] == root["t0"]
+            assert (kids["executor/feed"]["t0"]
+                    <= kids["executor/dispatch"]["t0"]
+                    <= kids["executor/fetch"]["t0"])
+            assert kids["executor/feed"]["attrs"]["h2d_bytes"] == h2d
+            assert kids["executor/feed"]["attrs"]["cast_bytes"] == 0
+            disp = kids["executor/dispatch"]["attrs"]
+            assert disp["compiled"] == compiled
+            assert disp["state_host_bytes"] == 0
+            assert kids["executor/fetch"]["attrs"]["d2h_bytes"] == 4
+            assert {k["attrs"]["step"] for k in kids.values()} == \
+                {root["attrs"]["step"]}
+
+    def test_executor_feed_counts_host_casts(self):
+        x = np.zeros((8, 4), np.float64)
+        _run_steps([{"x": x, "y": np.zeros((8, 1), np.float32)}])
+        (feed,) = obs.recent_spans(name="executor/feed")
+        assert feed["attrs"]["h2d_bytes"] == 8 * 4 * 4 + 8 * 4
+        assert feed["attrs"]["cast_bytes"] == 8 * 4 * 4
+
+    def test_executor_spans_take_the_step_of_the_span_around_them(self):
+        main, startup, loss = _fc_regression()
+        exe = fluid.Executor(fluid.CPUPlace())
+        feed = {"x": np.zeros((2, 4), np.float32),
+                "y": np.zeros((2, 1), np.float32)}
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            with obs.trace("train/step", kind="train", step=41):
+                exe.run(main, feed=feed, fetch_list=[loss])
+        (root,) = obs.recent_spans(name="executor/run")[-1:]
+        assert root["parent"] == "train/step"
+        assert root["attrs"]["step"] == 41
+
+    def test_run_loop_emits_one_run_span_for_its_steps(self):
+        main, startup, loss = _fc_regression()
+        exe = fluid.Executor(fluid.CPUPlace())
+        feed = {"x": np.ones((2, 4), np.float32),
+                "y": np.ones((2, 1), np.float32)}
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            obs_tracing.clear()
+            exe.run_loop(main, feed=feed, fetch_list=[loss], steps=3)
+        (root,) = obs.recent_spans(name="executor/run")
+        assert root["attrs"]["steps"] == 3
+        assert set(_tree(obs.recent_spans(), root)) == {
+            "executor/feed", "executor/dispatch", "executor/fetch"}
+
+    @pytest.mark.parametrize("placed", [False, True],
+                             ids=["default_placement", "on_a_device"])
+    def test_decode_launch_counts_what_the_call_uploads(
+            self, tiny_decode_dir, placed):
+        import jax
+        from paddle_tpu.inference.decode import GenerativePredictor
+        pred = GenerativePredictor(
+            tiny_decode_dir, device=jax.devices()[0] if placed else None)
+        sess = pred.new_session(4)
+        sess.prefill(0, [3, 1, 4])
+        obs_tracing.clear()
+        sess.decode()
+        by = {s["name"]: s for s in obs.recent_spans(kind="serving")}
+        assert set(by) == {"decode/put", "decode/launch", "decode/fetch"}
+        assert {s["attrs"]["phase"] for s in by.values()} == {"step"}
+        small = 4 * 4 + 4 * 4 + 4        # lengths, last_tokens, active
+        put, launch = by["decode/put"]["attrs"], by["decode/launch"]["attrs"]
+        if placed:
+            assert (put["bytes"], launch["h2d_bytes"]) == (small, 0)
+        else:
+            assert put["bytes"] == 0
+            assert launch["h2d_bytes"] == pred.param_bytes() + small
+        assert by["decode/fetch"]["attrs"]["d2h_bytes"] == 4 * 4
+        assert (by["decode/put"]["t0"] <= by["decode/launch"]["t0"]
+                <= by["decode/fetch"]["t0"])
+
+    def test_every_span_of_a_decode_round_has_parent_and_round(
+            self, tiny_decode_dir):
+        from paddle_tpu.inference.decode import GenerativePredictor
+        from paddle_tpu.serving.batcher import DecodeBatcher
+        pred = GenerativePredictor(tiny_decode_dir)
+        b = DecodeBatcher(pred, n_slots=2)
+        try:
+            streams = [b.submit([5, 9, 3], max_new_tokens=6,
+                                trace_id="round-a"),
+                       b.submit([7, 2], max_new_tokens=4)]
+            for s in streams:
+                s.result(timeout=60)
+        finally:
+            b.close()
+        spans = obs.recent_spans(kind="serving")
+        iters = [s for s in spans if s["name"] == "serving/lane_iter"]
+        steps = [s for s in spans if s["name"] == "serving/decode_step"]
+        assert iters and len(steps) >= 5
+        assert sum(i["attrs"]["admits"] for i in iters) == 2
+        assert sum(i["attrs"]["emitted"] for i in iters) == \
+            sum(s["attrs"]["tokens"] for s in steps) == 6 + 4 - 2
+        by_round = {i["attrs"]["round"]: i for i in iters
+                    if i["attrs"]["emitted"] or not i["attrs"]["admits"]}
+        for step in steps:
+            rnd = step["attrs"]["round"]
+            it = by_round[rnd]
+            assert step["parent"] == "serving/lane_iter"
+            end = it["t0"] + it["dur_ms"] * 1e-3
+            mine = [s for s in spans
+                    if s.get("attrs", {}).get("round") == rnd
+                    and s["name"] != "serving/lane_iter"
+                    and it["t0"] <= s["t0"] <= end]
+            assert {s["name"] for s in mine} == {
+                "serving/decode_step", "serving/emit", "decode/put",
+                "decode/launch", "decode/fetch"}
+            for s in mine:
+                want = "serving/decode_step" \
+                    if s["name"].startswith("decode/") \
+                    else "serving/lane_iter"
+                assert s["parent"] == want, s
+                # t0 never runs backwards down the tree
+                assert it["t0"] <= s["t0"]
+                if s["name"].startswith("decode/"):
+                    assert step["t0"] <= s["t0"] <= \
+                        step["t0"] + step["dur_ms"] * 1e-3 + 1e-6
+        # a prefill's session spans hang under the request's trace id
+        pre = [s for s in spans if s.get("trace_id") == "round-a"
+               and s.get("attrs", {}).get("phase") == "prefill"]
+        assert {s["name"] for s in pre} == {"decode/put", "decode/launch",
+                                            "decode/fetch"}
+        assert {s["parent"] for s in pre} == {"serving/prefill_compute"}
+        (pc,) = [s for s in spans if s["name"] == "serving/prefill_compute"
+                 and s.get("trace_id") == "round-a"]
+        assert pc["parent"] == "serving/lane_iter"
+
+    def test_trace_off_emits_nothing_reads_no_clock_same_results(
+            self, tiny_decode_dir, monkeypatch):
+        from paddle_tpu.fluid import executor as executor_mod
+        from paddle_tpu.inference import decode as decode_mod
+        rng = np.random.RandomState(2)
+        feeds = [{"x": rng.randn(8, 4).astype(np.float32),
+                  "y": rng.randn(8, 1).astype(np.float32)}
+                 for _ in range(3)]
+
+        def decode_tokens():
+            pred = decode_mod.GenerativePredictor(tiny_decode_dir)
+            sess = pred.new_session(2)
+            out = [sess.prefill(0, [3, 1, 4, 1, 5])]
+            out += [int(sess.decode()[0]) for _ in range(4)]
+            out += [t.tolist() for t in sess.decode_fused(3)[:2]]
+            return out
+
+        on = (_run_steps(feeds), decode_tokens())
+        assert obs_tracing.stats()["spans_total"] > 0
+        set_flags({"trace": False})
+        obs_tracing.clear()
+        monkeypatch.setattr(executor_mod, "time", _NoClock())
+        monkeypatch.setattr(decode_mod, "time", _NoClock())
+        off = (_run_steps(feeds), decode_tokens())
+        assert obs_tracing.stats()["spans_total"] == 0
+        assert all(np.array_equal(a, b) for a, b in zip(on[0], off[0]))
+        assert on[1] == off[1]
+
+    def test_every_emitter_is_on_the_one_clock(self, tiny_decode_dir):
+        from paddle_tpu.inference.decode import GenerativePredictor
+        from paddle_tpu.serving.batcher import DecodeBatcher
+        before = time.monotonic()
+        with obs.trace("t"):
+            pass
+        _run_steps([{"x": np.zeros((2, 4), np.float32),
+                     "y": np.zeros((2, 1), np.float32)}])
+        with obs.trace("t"):
+            pass
+        b = DecodeBatcher(GenerativePredictor(tiny_decode_dir), n_slots=1)
+        try:
+            b.submit([5, 9], max_new_tokens=3).result(timeout=60)
+        finally:
+            b.close()
+        spans = obs.recent_spans()
+        names = {s["name"] for s in spans}
+        assert {"t", "executor/run", "executor/feed", "serving/request",
+                "serving/queue_wait", "serving/decode_step",
+                "serving/prefill_compute", "decode/launch"} <= names
+        offsets = [s["ts"] - s["t0"] for s in spans]
+        # `ts` is `t0` plus ONE offset (float rounding at epoch size only)
+        assert max(offsets) - min(offsets) < 1e-5
+        assert abs(offsets[0] - (time.time() - time.monotonic())) < 5.0
+        assert all(before <= s["t0"] <= time.monotonic() for s in spans)
+
+    def test_trace_top_train_shows_the_executor_phases(self):
+        main, startup, loss = _fc_regression()
+        exe = fluid.Executor(fluid.CPUPlace())
+        feed = {"x": np.zeros((2, 4), np.float32),
+                "y": np.zeros((2, 1), np.float32)}
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            obs_tracing.clear()
+            with obs.trace("train/step", kind="train", step=7):
+                exe.run(main, feed=feed, fetch_list=[loss])
+            exe.run(main, feed=feed, fetch_list=[loss])   # a bare loop
+        recs = {r["step"]: r for r in trace_top.group_steps(
+            obs.recent_spans(kind="train"))}
+        assert set(recs) == {7, 1}
+        for r in recs.values():
+            assert set(r["executor"]) == {"feed", "dispatch", "fetch"}
+            assert sum(r["executor"].values()) <= r["total_ms"] + 1e-3
+        assert set(recs[7]["stages"]) == {"step"}
+        assert recs[1]["stages"] == {} and recs[1]["total_ms"] > 0
+        table = trace_top.render_steps(list(recs.values()), 5)
+        assert "executor feed|dispatch|fetch" in table.splitlines()[0]
+        assert " | " in table.splitlines()[1]
+
+    def test_under_declares_a_parent_without_emitting(self):
+        with obs_tracing.under("outer", trace_id="tid", round=7):
+            with obs.trace("inner"):
+                t = time.monotonic()
+                obs_tracing.stamp("stamped", t, t + 0.001, kind="x")
+        spans = obs.recent_spans()
+        assert [s["name"] for s in spans] == ["stamped", "inner"]
+        assert spans[0]["parent"] == "inner"
+        assert spans[1]["parent"] == "outer"
+        for s in spans:
+            assert s["trace_id"] == "tid" and s["attrs"]["round"] == 7
+        with obs.trace("alone") as s:
+            assert s.parent is None
+
+
 class TestChromeMerge:
     def test_export_chrome_tracing_merges_obs_spans(self, tmp_path):
         import gzip

@@ -107,33 +107,14 @@ NOTIFY_MODULES = ("paddle_tpu/",)
 # ---------------------------------------------------------------------------
 
 SUPPRESSIONS = [
-    ("paddle_tpu/obs/tracing.py", "nonmonotonic-time", "Span.__init__",
-     "span `ts` is the wall-clock RECORD timestamp shown in trace "
-     "readouts; the duration math uses the monotonic t0/t1 pair"),
+    ("paddle_tpu/obs/tracing.py", "nonmonotonic-time", "<module>",
+     "the ONE wall-clock reading of the tracer: the process-wide "
+     "wall - monotonic offset every span's record `ts` is derived "
+     "from; starts and durations ride time.monotonic() alone, and no "
+     "emitter reads the wall clock"),
     ("paddle_tpu/obs/events.py", "nonmonotonic-time", "EventLog.emit",
      "event `ts` is the wall-clock record timestamp operators grep "
      "against log files; no duration is derived from it"),
-    ("paddle_tpu/reader/decorator.py", "nonmonotonic-time",
-     "prefetch_to_device.data_reader",
-     "prefetch_wait span anchor: wall `ts` for the record, the "
-     "duration comes from the monotonic perf_counter wait_ms"),
-    ("paddle_tpu/serving/batcher.py", "nonmonotonic-time",
-     "DynamicBatcher._emit_request_spans",
-     "one wall-clock anchor reconstructs span `ts` fields from the "
-     "request's contiguous MONOTONIC stage stamps (the stamps, not "
-     "the wall clock, carry the durations)"),
-    ("paddle_tpu/serving/batcher.py", "nonmonotonic-time",
-     "DecodeBatcher._emit_request_spans",
-     "same wall-anchor reconstruction as DynamicBatcher: durations "
-     "ride monotonic stamps, time.time() only places them on the "
-     "wall-clock axis"),
-    ("paddle_tpu/serving/batcher.py", "nonmonotonic-time",
-     "DecodeBatcher._emit_step_spans",
-     "decode_step/draft/verify span anchors: one time.time() reading "
-     "minus the monotonic elapsed places each span on the wall axis; "
-     "every dur_ms rides the contiguous monotonic round stamps (the "
-     "draft->verify boundary included), so the tiling contract never "
-     "touches the wall clock"),
     ("paddle_tpu/obs/slo.py", "nonmonotonic-time",
      "SLOMonitor._read_lane",
      "sample `ts` is the wall-clock RECORD stamp the timeline/bundle "

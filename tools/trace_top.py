@@ -6,8 +6,9 @@ running server, or in-process — groups serving spans by trace_id and
 training spans by step, and prints the slowest roots with their stage
 breakdown (queue_wait / coalesce / lane_wait / dispatch / compute /
 scatter for a request; prefetch_wait / dispatch / drain / ckpt for a
-train step).  `--trace_id` resolves ONE reply-visible id into its span
-tree; `--json` dumps raw.
+train step, with the executor's own feed / dispatch / fetch phases of
+that step beside them).  `--trace_id` resolves ONE reply-visible id into
+its span tree; `--json` dumps raw.
 
 `--capture` runs one traced serving run +
 one traced train step in-process under the jax profiler, exports the
@@ -37,6 +38,11 @@ SERVING_STAGES = ("serving/queue_wait", "serving/coalesce",
                   "serving/compute", "serving/scatter")
 TRAIN_SPANS = ("train/prefetch_wait", "train/dispatch", "train/step",
                "train/drain", "train/ckpt")
+# the phases of one Executor.run / ParallelExecutor.run (children of
+# `executor/run`; they carry the step of the train span around them, or
+# the executor's own count): host casts + the feed's upload, lookups + the
+# jitted call, the wait for the step + the fetches' copy to the host
+EXECUTOR_PHASES = ("executor/feed", "executor/dispatch", "executor/fetch")
 
 
 def group_requests(spans):
@@ -63,20 +69,38 @@ def group_requests(spans):
 
 def group_steps(spans):
     """Train spans -> one record per step id with the per-step
-    breakdown (prefetch_wait / dispatch / drain / ckpt ms).  Spans
-    without a step attr (e.g. prefetch_wait) aggregate into step=None
-    totals shown as the 'unattributed' row."""
+    breakdown (prefetch_wait / dispatch / drain / ckpt ms) and, under
+    `executor`, the feed / dispatch / fetch ms of the step's executor
+    calls.  The executor's phases lie INSIDE the train spans, so they do
+    not add to the total; a step run by a bare `Executor.run` loop (no
+    train span) totals its `executor/run`.  Spans without a step attr
+    (e.g. prefetch_wait) aggregate into step=None totals shown as the
+    'unattributed' row."""
     by_step = {}
     for s in spans:
-        if s.get("kind") != "train" or s["name"] not in TRAIN_SPANS:
+        name = s["name"]
+        if s.get("kind") != "train" or not (
+                name in TRAIN_SPANS or name in EXECUTOR_PHASES
+                or name == "executor/run"):
             continue
         step = (s.get("attrs") or {}).get("step")
         rec = by_step.setdefault(step, {"step": step, "total_ms": 0.0,
-                                        "stages": {}})
-        key = s["name"].split("/", 1)[1]
-        rec["stages"][key] = rec["stages"].get(key, 0.0) + s["dur_ms"]
-        rec["total_ms"] += s["dur_ms"]
+                                        "stages": {}, "executor": {},
+                                        "run_ms": 0.0})
+        key = name.split("/", 1)[1]
+        if name in TRAIN_SPANS:
+            rec["stages"][key] = rec["stages"].get(key, 0.0) + s["dur_ms"]
+            rec["total_ms"] += s["dur_ms"]
+        elif name in EXECUTOR_PHASES:
+            rec["executor"][key] = rec["executor"].get(key, 0.0) \
+                + s["dur_ms"]
+        else:
+            rec["run_ms"] += s["dur_ms"]
     out = list(by_step.values())
+    for rec in out:
+        if not rec["stages"]:
+            rec["total_ms"] = rec["run_ms"]
+        del rec["run_ms"]
     out.sort(key=lambda r: -r["total_ms"])
     return out
 
@@ -99,12 +123,20 @@ def render_requests(recs, limit):
 
 
 def render_steps(recs, limit):
-    lines = ["%-8s %9s  %s" % ("STEP", "TOTALms", "breakdown")]
+    lines = ["%-8s %9s  %-32s  %s" % ("STEP", "TOTALms",
+                                      "executor feed|dispatch|fetch",
+                                      "breakdown")]
     for r in recs[:limit]:
         stages = "  ".join("%s=%.1f" % (k, v)
                            for k, v in sorted(r["stages"].items()))
+        exe = r.get("executor") or {}
+        phases = " | ".join(
+            "%.1f" % exe[n.split("/", 1)[1]]
+            if n.split("/", 1)[1] in exe else "-"
+            for n in EXECUTOR_PHASES)
         step = "-" if r["step"] is None else r["step"]
-        lines.append("%-8s %9.2f  %s" % (step, r["total_ms"], stages))
+        lines.append("%-8s %9.2f  %-32s  %s"
+                     % (step, r["total_ms"], phases, stages))
     return "\n".join(lines)
 
 

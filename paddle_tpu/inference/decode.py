@@ -340,7 +340,9 @@ class GenerativePredictor:
     """A decode artifact opened for serving: weights + meta + the two
     compiled phases (per-bucket prefill, one fixed-shape decode step
     per slot-table size).  `device` pins state and compute to one
-    jax.Device — the serving registry's replica placement; `clone_to`
+    jax.Device — the serving registry's replica placement; the default
+    (`device=None`) keeps the weights RESIDENT on jax's default device
+    but uncommitted (placed once at open, pinned nowhere); `clone_to`
     shares the artifact read and the in-process export map so N
     same-device-kind replicas deserialize ONE executable each
     (COMPILE_CACHE.md).
@@ -431,19 +433,17 @@ class GenerativePredictor:
                         % (self._dims()[1], self._dims()[3],
                            self.vocab_size, group.mesh_size),
                         RuntimeWarning, stacklevel=2)
-        if device is not None:
-            if self._tp_size:
-                from paddle_tpu.inference.predictor import _put_state_tp
-                self._state = _put_state_tp(self._state_host, group)
-            else:
-                from paddle_tpu.inference.predictor import _put_state
-                # a MeshGroup placement shards every param at rest over
-                # the mesh (SERVING.md "Mesh replicas"); a plain device
-                # is the legacy single-chip pin
-                self._state = _put_state(self._state_host, device)
+        # the weights go to a device ONCE, here, under every placement
+        # (`_state_host` stays numpy for fingerprints, specs and clones)
+        if self._tp_size:
+            from paddle_tpu.inference.predictor import _put_state_tp
+            self._state = _put_state_tp(self._state_host, group)
         else:
-            self._state = {n: np.asarray(v)
-                           for n, v in self._state_host.items()}
+            from paddle_tpu.inference.predictor import _put_state
+            # MeshGroup: every param sharded at rest over the mesh
+            # (SERVING.md "Mesh replicas"); plain device: the single-chip
+            # pin; None: jax's default device, uncommitted
+            self._state = _put_state(self._state_host, device)
         self._state_host_nbytes = None
         self._fns = {}          # per-instance resolved callables
         self._lock = threading.Lock()
@@ -573,9 +573,12 @@ class GenerativePredictor:
                    for v in self._state_host.values())
 
     def state_host_bytes(self):
-        """Bytes of the weights that are NOT on a device (numpy leaves
-        of `_state`, the default placement): what every executable call
-        uploads again.  The state is static, so this is counted once."""
+        """Bytes of the weights that are NOT on a device (leaves of
+        `_state` that are no jax.Array): 0 under every placement, since
+        the weights are placed once when the artifact is opened.  It
+        feeds `h2d_bytes` of every `decode/launch` span, so a placement
+        that left host leaves behind would show there.  The state is
+        static, so this is counted once."""
         if self._state_host_nbytes is None:
             self._state_host_nbytes = _host_nbytes(self._state.values())
         return self._state_host_nbytes
@@ -1558,7 +1561,9 @@ class DecodeSession:
         `decode/launch` spans when tracing is on: `_put` of the small
         per-call arguments, then the executable call until it returns
         (synchronous for whatever host argument it has to upload;
-        `h2d_bytes` counts those)."""
+        `h2d_bytes` counts those: the weights and the cache are
+        device-resident under every placement, so under the default one
+        it is the small arguments `_put` leaves as numpy)."""
         state = self.predictor._state
         if not obs_tracing.enabled():
             return fn(state, *cache, *[self._put(a) for a in small])

@@ -130,9 +130,11 @@ def _mesh_of(device):
 
 
 def _put_state(state, device):
-    """Commit a param dict to its placement: plain device -> device_put;
-    mesh group -> every param SHARDED AT REST over the mesh
-    (`MeshGroup.param_sharding` — per-device resident bytes ~
+    """Place a param dict on its placement, once: plain device ->
+    device_put (committed); None -> jax's default device, UNCOMMITTED
+    (the "floating" default replica of `resolve_placement(1)`: resident,
+    but pinned nowhere); mesh group -> every param SHARDED AT REST over
+    the mesh (`MeshGroup.param_sharding` — per-device resident bytes ~
     1/mesh_size, the whole point of a mesh replica)."""
     import jax
     group = _mesh_of(device)

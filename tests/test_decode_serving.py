@@ -257,6 +257,84 @@ class TestDecodeSession:
 # DecodeBatcher: continuous batching semantics (in-process)
 # ---------------------------------------------------------------------------
 
+class TestDefaultPlacement:
+    """The default placement (`device=None`, what `load_model` gives
+    when nothing is said) keeps the weights on jax's default device,
+    placed once at open and pinned nowhere: a launch uploads its small
+    arguments, never the model."""
+
+    def test_load_model_places_weights_once_and_uncommitted(
+            self, artifact):
+        import jax
+        from paddle_tpu.obs import tracing as obs_tracing
+        from paddle_tpu.serving import ModelRegistry
+        n_slots = 2
+        was = obs_tracing.enabled()
+        obs_tracing.set_enabled(True)
+        reg = ModelRegistry()
+        try:
+            entry = reg.load_model("lm", artifact, decode_slots=n_slots)
+            pred = entry.predictor
+            assert entry.devices == [None] and pred.device is None
+            leaves = list(pred._state.values())
+            assert leaves and len(leaves) == len(pred._state_host)
+            assert all(isinstance(v, jax.Array) for v in leaves)
+            assert not any(v.committed for v in leaves)
+            assert pred.state_host_bytes() == 0
+            obs_tracing.clear()
+            out = reg.submit_stream("lm", [5, 9, 3],
+                                    max_new_tokens=5).result(timeout=60)
+            assert len(out[0]) >= 1
+            launches = obs_tracing.recent_spans(name="decode/launch")
+        finally:
+            reg.close_all()
+            obs_tracing.set_enabled(was)
+        bucket = pred.prompt_bucket(3)
+        small = {"prefill": 4 * bucket + 4,   # padded prompt, its length
+                 # lengths, last_tokens, active
+                 "step": 4 * n_slots + 4 * n_slots + n_slots}
+        by_phase = {}
+        for s in launches:
+            by_phase.setdefault(s["attrs"]["phase"], set()).add(
+                s["attrs"]["h2d_bytes"])
+        assert by_phase == {ph: {n} for ph, n in small.items()}
+
+    @pytest.mark.parametrize("kv", ["float32", "int8"])
+    @pytest.mark.parametrize(
+        "other", ["opened_on_a_device", "cloned_to_a_device",
+                  "cloned_back_to_default"])
+    def test_same_tokens_and_logits_as_a_pinned_replica(
+            self, artifact, other, kv):
+        """Where the weights live moves no bit: streams and step logits
+        of the default placement equal a pinned replica's and a
+        clone's, for both cache widths."""
+        import jax
+        dev = jax.devices()[0]
+        default = GenerativePredictor(artifact, kv_cache_dtype=kv)
+        if other == "opened_on_a_device":
+            peer = GenerativePredictor(artifact, device=dev,
+                                       kv_cache_dtype=kv)
+        elif other == "cloned_to_a_device":
+            peer = default.clone_to(dev)
+        else:
+            peer = default.clone_to(dev).clone_to(None)
+            assert not any(v.committed for v in peer._state.values())
+        assert peer.kv_cache_dtype == default.kv_cache_dtype == kv
+        assert default.state_host_bytes() == peer.state_host_bytes() == 0
+        a, b = default.new_session(3), peer.new_session(3)
+        for sess in (a, b):
+            sess.prefill(0, [5, 9, 3])
+            sess.prefill(2, [1, 2, 3, 4, 5, 6, 7])
+        for _ in range(6):
+            ta, la = a.decode_logits()
+            tb, lb = b.decode_logits()
+            assert np.array_equal(ta[[0, 2]], tb[[0, 2]])
+            assert np.array_equal(la[[0, 2]], lb[[0, 2]])
+        for prompt in ([5, 9, 3], [31, 30]):
+            assert greedy_decode(default, prompt, 12) == \
+                greedy_decode(peer, prompt, 12)
+
+
 class TestDecodeBatcher:
     def test_slot_recycling_more_requests_than_slots(self, predictor):
         metrics = ServingMetrics().model("lm")

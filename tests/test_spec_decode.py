@@ -201,6 +201,40 @@ class TestSpeculativeSession:
             "twin-draft accept under fusion must be exactly 1.0"
         assert sess.rounds > 0 and sess.plain_steps == 0
 
+    def test_fused_round_uploads_neither_model(self, artifact,
+                                               other_artifact):
+        """The fused call hands both predictors' `_state` straight to
+        the executable, outside `DecodeSession._call` and so under no
+        `decode/launch` span: under the default placement every leaf
+        it gets must already be a device array."""
+        import jax
+        from paddle_tpu.inference.decode import _host_nbytes
+        target = GenerativePredictor(artifact)
+        draft = GenerativePredictor(other_artifact)
+        assert target.device is None and draft.device is None
+        seen = []
+        real = target.fused_spec_fn
+
+        def spying(*a):
+            fn = real(*a)
+
+            def call(tstate, dstate, *rest):
+                seen.append((tstate, dstate))
+                return fn(tstate, dstate, *rest)
+            return call
+
+        target.fused_spec_fn = spying
+        sess = SpeculativeDecodeSession(target, draft, 2, spec_k=2)
+        sess.prefill(0, [11, 12, 13, 14])
+        sess.prefill(1, [2])
+        sess.step(fused=True)
+        assert sess.last_spec and len(seen) == 1
+        for state in seen[0]:
+            leaves = list(state.values())
+            assert leaves and _host_nbytes(leaves) == 0
+            assert all(isinstance(v, jax.Array) and not v.committed
+                       for v in leaves)
+
     def test_fused_round_mismatched_draft_rollback_bit_exact(
             self, artifact, other_artifact, predictor):
         """Fused rounds with a DISAGREEING draft: the in-graph rollback

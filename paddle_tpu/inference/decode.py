@@ -12,7 +12,15 @@ TOGETHER.  This module is the inference-side half of the answer
   — a directory holding a causal-transformer LM's weights plus a meta
   record (vocab, layers, heads, max_seq_len, eos id, prefill buckets)
   in the typed wire format, detected by `decode_meta.bin` the way the
-  AOT predictor is detected by `aot_meta.bin`;
+  AOT predictor is detected by `aot_meta.bin`.  The meta also DESCRIBES
+  the decoder block (`BLOCK_DEFAULTS`: LayerNorm | RMSNorm, learned |
+  rotary positions, qk-norm, ReLU MLP | dropless routed SwiGLU experts);
+  an artifact that names none of those keys is the GPT-2-shaped block.
+  ONE per-layer function (`GenerativePredictor._block`) serves prefill
+  and the plain decode step for every block; the speculative verify
+  step, the fused windows, sequence-parallel prefill and the TP lane
+  implement the default block only and raise for any other, naming the
+  meta key;
 * a **prefill / decode phase split** (`GenerativePredictor`): prefill
   runs the whole prompt through the causal forward once per padded
   *prompt bucket* (each bucket's executable rides the persistent
@@ -177,14 +185,104 @@ def _default_prefill_buckets(max_seq_len):
     return buckets
 
 
+# The decoder block an artifact's meta describes.  An artifact that names
+# none of these keys is the GPT-2-shaped block this module began with
+# (LayerNorm, learned absolute positions, MHA, ReLU MLP), so every
+# artifact written before the keys existed opens unchanged.
+BLOCK_DEFAULTS = (
+    ("norm", "layernorm"),        # | "rmsnorm" (gain only, no bias)
+    ("norm_eps", 1e-5),
+    ("position", "learned"),      # | "rope" (half-split rotary over the
+    ("rope_theta", 10000.0),      #   whole head, no position table)
+    ("qk_norm", False),           # RMSNorm of the whole q / k projection
+    ("ffn", "relu_mlp"),          # | "moe_swiglu" (dropless routed experts)
+    ("n_experts", 0),
+    ("experts_per_token", 0),
+    ("expert_width", 0),
+    ("norm_topk_prob", False),    # renormalise the kept router weights
+)
+_BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
+                  "position": ("learned", "rope"),
+                  "ffn": ("relu_mlp", "moe_swiglu")}
+
+
+def block_of(meta):
+    """The block description of a decode artifact's meta: every key of
+    BLOCK_DEFAULTS, defaulted where the meta is silent, typed and
+    checked.  A value this module has no math for is a typed error that
+    names the key."""
+    out = {}
+    for key, default in BLOCK_DEFAULTS:
+        v = type(default)(meta.get(key, default))
+        if key in _BLOCK_CHOICES and v not in _BLOCK_CHOICES[key]:
+            raise ValueError("decode meta %s=%r is not one of %s"
+                             % (key, v, "|".join(_BLOCK_CHOICES[key])))
+        out[key] = v
+    if out["ffn"] == "moe_swiglu" and not (
+            1 <= out["experts_per_token"] <= out["n_experts"]
+            and out["expert_width"] >= 1):
+        raise ValueError(
+            "decode meta ffn=moe_swiglu needs 1 <= experts_per_token (%d) "
+            "<= n_experts (%d) and expert_width (%d) >= 1"
+            % (out["experts_per_token"], out["n_experts"],
+               out["expert_width"]))
+    if out["position"] == "rope" and (
+            int(meta["d_model"]) // int(meta["n_heads"])) % 2:
+        raise ValueError("decode meta position=rope needs an even "
+                         "head size")
+    return out
+
+
+def decode_state_shapes(meta):
+    """{weight name: shape} of the state a decode artifact with this
+    meta holds — what `save_decode_model` checks a state against."""
+    blk = block_of(meta)
+    V, D, L, S = (int(meta[k]) for k in
+                  ("vocab_size", "d_model", "n_layers", "max_seq_len"))
+    norm_bias = blk["norm"] == "layernorm"
+    shapes = {"embed": (V, D), "lm_head": (D, V), "lnf_g": (D,)}
+    if norm_bias:
+        shapes["lnf_b"] = (D,)
+    if blk["position"] == "learned":
+        shapes["pos"] = (S, D)
+    for i in range(L):
+        p = "l%d_" % i
+        for n in ("wq", "wk", "wv", "wo"):
+            shapes[p + n] = (D, D)
+        for n in ("ln1", "ln2"):
+            shapes[p + n + "_g"] = (D,)
+            if norm_bias:
+                shapes[p + n + "_b"] = (D,)
+        if blk["qk_norm"]:
+            shapes[p + "qn_g"] = shapes[p + "kn_g"] = (D,)
+        if blk["ffn"] == "moe_swiglu":
+            E, F = blk["n_experts"], blk["expert_width"]
+            shapes[p + "router"] = (D, E)
+            shapes[p + "w_gate"] = shapes[p + "w_up"] = (E, D, F)
+            shapes[p + "w_down"] = (E, F, D)
+        else:
+            shapes[p + "w1"], shapes[p + "b1"] = (D, 4 * D), (4 * D,)
+            shapes[p + "w2"], shapes[p + "b2"] = (4 * D, D), (D,)
+    return shapes
+
+
 def save_decode_model(dirname, state, meta):
     """Write a decode artifact: `meta` (vocab_size, d_model, n_heads,
-    n_layers, max_seq_len, eos_id, dtype, prefill_buckets) +  `state`
-    (the weight dict) in the typed wire format — no pickle, same
-    discipline as save_aot."""
+    n_layers, max_seq_len, eos_id, dtype, prefill_buckets, and the
+    decoder block's keys — BLOCK_DEFAULTS; an artifact that names none
+    is the GPT-2-shaped block) + `state` (the weight dict) in the typed
+    wire format — no pickle, same discipline as save_aot.  A state that
+    lacks a weight the described block reads, or holds one of another
+    shape, is refused here and not at the first trace."""
     from paddle_tpu.native import wire
     os.makedirs(dirname, exist_ok=True)
     meta = dict(meta)
+    for name, shape in decode_state_shapes(meta).items():
+        if name not in state or tuple(np.shape(state[name])) != shape:
+            raise ValueError(
+                "decode state %r: the meta's block needs shape %s, the "
+                "state has %s" % (name, shape, np.shape(state[name])
+                                  if name in state else "no such weight"))
     meta.setdefault("arch", "causal_lm")
     meta.setdefault("version", 1)
     meta.setdefault("dtype", "float32")
@@ -206,16 +304,37 @@ def save_decode_model(dirname, state, meta):
 
 def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
                             n_heads=2, n_layers=2, max_seq_len=64,
-                            eos_id=0, seed=7, prefill_buckets=None):
+                            eos_id=0, seed=7, prefill_buckets=None,
+                            block=None):
     """Deterministic random-weight tiny causal LM — the CPU-smoke /
     test fixture (the decode analogue of bench_serving's `fc` model).
     Same seed -> bit-identical artifact.  `prefill_buckets` pins the
     artifact's prompt buckets (default: powers of two up to
-    max_seq_len) — every bucket is one warm-up compile."""
+    max_seq_len) — every bucket is one warm-up compile.  `block` names
+    the decoder block's meta keys (BLOCK_DEFAULTS; None: the GPT-2-shaped
+    one): its weights are drawn in name order, matrices normal(0,
+    1/sqrt(fan_in)), gains 1, biases 0."""
     if d_model % n_heads:
         raise ValueError("d_model %d not divisible by n_heads %d"
                          % (d_model, n_heads))
     rng = np.random.RandomState(seed)
+    if block:
+        meta = dict(block, vocab_size=int(vocab_size),
+                    d_model=int(d_model), n_heads=int(n_heads),
+                    n_layers=int(n_layers), max_seq_len=int(max_seq_len),
+                    eos_id=int(eos_id))
+        if prefill_buckets:
+            meta["prefill_buckets"] = sorted(int(b)
+                                             for b in prefill_buckets)
+        state = {}
+        for name, shape in sorted(decode_state_shapes(meta).items()):
+            if len(shape) == 1:
+                state[name] = (np.ones if name.endswith("_g")
+                               else np.zeros)(shape, np.float32)
+            else:
+                state[name] = (rng.randn(*shape) / np.sqrt(
+                    shape[-2])).astype(np.float32)
+        return save_decode_model(dirname, state, meta)
     scale = 1.0 / np.sqrt(d_model)
 
     def w(*shape):
@@ -248,12 +367,133 @@ def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
     return save_decode_model(dirname, state, meta)
 
 
-def _ln(x, g, b):
+def _ln(x, g, b, eps=1e-5):
     import jax.numpy as jnp
     x = x.astype(jnp.float32)
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mu), axis=-1, keepdims=True)
-    return (x - mu) / jnp.sqrt(var + 1e-5) * g + b
+    return (x - mu) / jnp.sqrt(var + eps) * g + b
+
+
+def _rms(x, g, eps):
+    import jax
+    import jax.numpy as jnp
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * g
+
+
+def _rope(x, positions, theta):
+    """Rotary position embedding over the whole head, half-split
+    convention: x [..., H, Dh] at `positions` [...] (one per leading
+    index) -> x * cos + concat(-x2, x1) * sin with the angles
+    position * theta^(-2i/Dh) repeated over both halves."""
+    import jax.numpy as jnp
+    half = x.shape[-1] // 2
+    inv = jnp.float32(theta) ** (
+        jnp.arange(half, dtype=jnp.float32) * (-2.0 / x.shape[-1]))
+    ang = jnp.asarray(positions).astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)              # [..., 1, half]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
+            live=None):
+    """Dropless, exact top-k routed SwiGLU experts: h [T, D], router
+    [D, E], w_gate / w_up [E, D, F], w_down [E, F, D] ->
+    (sum over each token's k experts of p_e * ((silu(h @ w_gate[e]) *
+    (h @ w_up[e])) @ w_down[e]) [T, D], facts [2] i32).  No capacity: every
+    (token, expert) pair is computed, whatever the routing.
+
+    ONE form for both regimes: the T * k pairs are sorted by expert and
+    the three expert matmuls run as grouped matmuls over the sorted rows
+    (`jax.lax.ragged_dot`; on the TPU a grouped-matmul kernel that visits
+    only the groups that hold rows).  A decode step (a few tokens, most
+    experts untouched) then streams the touched experts' weights and no
+    others; a prefill (every expert gets tens of rows) does k / E of the
+    dense formula's FLOPs.
+
+    The router (matmul, softmax, top-k) runs in fp32 at "highest"
+    precision: D x E is free, and a bf16-rounded router logit flips the
+    k-th against the (k+1)-th expert on a near-tie, which is a different
+    function and not a rounding.  `norm_topk_prob` renormalises the kept
+    weights to sum to 1; without it they are the softmax's own values.
+
+    facts = (experts that received a token, most tokens one expert
+    received), counted over the tokens `live` [T] marks (all if None):
+    a dead slot's or a pad position's row is computed but not counted."""
+    import jax
+    import jax.numpy as jnp
+    T, E, k = h.shape[0], router.shape[1], int(k)
+    with jax.named_scope("moe_ffn"):
+        with jax.named_scope("moe_router"):
+            p = jax.nn.softmax(jnp.dot(
+                h.astype(jnp.float32), router,
+                precision=jax.lax.Precision.HIGHEST), axis=-1)
+            w, idx = jax.lax.top_k(p, k)                        # [T, k]
+            if norm_topk_prob:
+                w = w / jnp.sum(w, axis=-1, keepdims=True)
+        flat = idx.reshape(T * k)
+        onehot = flat[:, None] == jnp.arange(E)[None]           # [T*k, E]
+        sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)        # [E]
+        counted = sizes if live is None else jnp.sum(
+            onehot & jnp.repeat(live, k)[:, None], axis=0,
+            dtype=jnp.int32)
+        facts = jnp.stack([jnp.sum(counted > 0, dtype=jnp.int32),
+                           jnp.max(counted)])
+        order = jnp.argsort(flat)           # stable: pairs by expert
+        rows = h[order // k]                                    # [T*k, D]
+        act = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes)) \
+            * jax.lax.ragged_dot(rows, w_up, sizes)
+        out = jax.lax.ragged_dot(act, w_down, sizes)            # [T*k, D]
+        # back to token order by a gather (the inverse permutation), then
+        # a fixed-order sum over each token's k experts
+        out = out[jnp.argsort(order)].reshape(T, k, -1)
+        return jnp.sum(out * w[:, :, None], axis=1), facts
+
+
+def _pack_routing(tokens, facts):
+    """A routed-expert phase's first result: its tokens, then each
+    layer's (experts touched, most tokens on one expert), as ONE int32
+    vector, so the routing facts ride the fetch that brings the tokens
+    (`DecodeSession._fetch` splits them off again)."""
+    import jax.numpy as jnp
+    return jnp.concatenate([tokens.reshape(-1).astype(jnp.int32),
+                            jnp.stack(facts).reshape(-1)])
+
+
+_SLOT_WRITERS = []
+
+
+def _slot_writers():
+    """(write_rows, zero_slot): the two eager writes of a slot table
+    [L, N, S, H, Dh], jitted with the table DONATED so that they land in
+    place.  `write_rows(table, rows [L, 1, B, H, Dh], slot)` puts `rows`
+    at `slot` from position 0 (a prefill's K or V); `zero_slot(table,
+    slot)` zeroes the slot's whole row (its release).  Undonated, each
+    was a copy of the whole table (1.2 GB at GPT-2 small with 32 slots:
+    ~3 ms of the device and a transient table in memory), twice for
+    every admission and every release, with the chip's memory nearly
+    full.  `slot` is traced: one executable per table and bucket."""
+    if not _SLOT_WRITERS:
+        import jax
+        import jax.numpy as jnp
+
+        def write_rows(table, rows, slot):
+            return jax.lax.dynamic_update_slice(table, rows,
+                                                (0, slot, 0, 0, 0))
+
+        def zero_slot(table, slot):
+            z = jnp.zeros((table.shape[0], 1) + table.shape[2:],
+                          table.dtype)
+            return jax.lax.dynamic_update_slice(table, z,
+                                                (0, slot, 0, 0, 0))
+
+        _SLOT_WRITERS.extend((jax.jit(write_rows, donate_argnums=0),
+                              jax.jit(zero_slot, donate_argnums=0)))
+    return _SLOT_WRITERS
 
 
 def _causal_attention(q, k, v, scale):
@@ -359,6 +599,7 @@ class GenerativePredictor:
         if _clone_of is not None:
             src = _clone_of
             self.meta = src.meta
+            self._block_meta = src._block_meta
             self._state_host = src._state_host
             self._shared_exports = src._shared_exports
             self._shared_lock = src._shared_lock
@@ -368,6 +609,8 @@ class GenerativePredictor:
         else:
             with open(os.path.join(dirname, DECODE_META), "rb") as f:
                 self.meta = wire.decode(f.read())
+            # the decoder block this artifact describes (BLOCK_DEFAULTS)
+            self._block_meta = block_of(self.meta)
             with open(os.path.join(dirname, _DECODE_STATE), "rb") as f:
                 raw_state = f.read()
             self._state_host = wire.decode(raw_state)
@@ -418,6 +661,10 @@ class GenerativePredictor:
         if group is not None:
             from paddle_tpu.flags import FLAGS
             if FLAGS.mesh_tp:
+                # no fall-back to the gather path for a block the TP
+                # grammar cannot split: it was asked for by name
+                self._require_default_block(
+                    "tensor-parallel compute (FLAGS.mesh_tp)")
                 _, H, _, D = self._dims()
                 if tp_supported(group.mesh_size, H, D,
                                 self.vocab_size, 4 * D):
@@ -473,6 +720,33 @@ class GenerativePredictor:
     @property
     def is_decode(self):
         return True
+
+    @property
+    def block(self):
+        """The decoder block's description (a copy): every key of
+        BLOCK_DEFAULTS, as the artifact's meta gives or defaults it."""
+        return dict(self._block_meta)
+
+    @property
+    def routed_layers(self):
+        """Layers with a routed-expert FFN: n_layers under
+        ffn=moe_swiglu, else 0.  The step and the prefill of such an
+        artifact return their routing facts behind their tokens."""
+        return (int(self.meta["n_layers"])
+                if self._block_meta["ffn"] == "moe_swiglu" else 0)
+
+    def _require_default_block(self, what):
+        """Raise for a phase that implements only the GPT-2-shaped
+        block, naming the first meta key that asks for another: prefill
+        and the plain decode step share `_block`; verify, the fused
+        windows, sequence-parallel prefill and the TP lane do not yet."""
+        for key, default in BLOCK_DEFAULTS:
+            if self._block_meta[key] != default:
+                raise NotImplementedError(
+                    "%s implements only the default decoder block, and "
+                    "this artifact's meta has %s=%r (prefill and the "
+                    "plain decode step run it)"
+                    % (what, key, self._block_meta[key]))
 
     @property
     def kv_cache_dtype(self):
@@ -674,37 +948,32 @@ class GenerativePredictor:
         if tp is not None and self._tp_seq_parallel(B):
             return self._prefill_core_seqpar(state, tokens, true_len,
                                              tp)
-        Hl = H if tp is None else H // tp.size
-        if tp is None:
-            x = state["embed"][tokens] + state["pos"][:B][None]
-        else:
-            x = tp.embed_lookup(state["embed"], tokens) \
-                + state["pos"][:B][None]
-        ks, vs = [], []
-        for i in range(L):
-            p = "l%d_" % i
-            h = _ln(x, state[p + "ln1_g"], state[p + "ln1_b"])
-            q = (h @ state[p + "wq"]).reshape(1, B, Hl, Dh)
-            k = (h @ state[p + "wk"]).reshape(1, B, Hl, Dh)
-            v = (h @ state[p + "wv"]).reshape(1, B, Hl, Dh)
-            att = _causal_attention(q, k, v, scale).reshape(
-                1, B, Hl * Dh)
-            wo_out = att @ state[p + "wo"]
-            x = x + (wo_out if tp is None else tp.psum(wo_out))
-            h2 = _ln(x, state[p + "ln2_g"], state[p + "ln2_b"])
-            mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
-                              0.0) @ state[p + "w2"]
-            x = x + (mlp if tp is None else tp.psum(mlp)) \
-                + state[p + "b2"]
+        blk = self._block_meta
+        x = state["embed"][tokens] if tp is None \
+            else tp.embed_lookup(state["embed"], tokens)
+        if blk["position"] == "learned":
+            x = x + state["pos"][:B][None]
+        positions = jnp.arange(B)[None]                     # [1, B]
+        ks, vs, facts = [], [], []
+
+        def attend(q, k, v):
             ks.append(k)
             vs.append(v)
-        logits = _ln(x, state["lnf_g"], state["lnf_b"]) @ state["lm_head"]
+            return _causal_attention(q, k, v, scale)
+
+        for i in range(L):
+            x, f = self._block(state, "l%d_" % i, x, positions, attend,
+                               positions[0] < true_len, tp=tp)
+            facts.append(f)
+        logits = self._norm(x, state, "lnf") @ state["lm_head"]
         if tp is not None:
             # vocab-sharded logits reassemble (exact data movement)
             # before the replicated argmax
             logits = tp.all_gather(logits, axis=2)
         first = jnp.argmax(logits[0, true_len - 1], axis=-1).astype(
             jnp.int32)
+        if self.routed_layers:
+            first = _pack_routing(first, facts)
         # zero the pad positions: the slot cache must hold exact zeros
         # past the live length (free() zeroes, writes are length-gated —
         # this keeps prefill on the same contract)
@@ -713,6 +982,60 @@ class GenerativePredictor:
         kc = jnp.where(live, jnp.stack(ks), 0.0)
         vc = jnp.where(live, jnp.stack(vs), 0.0)
         return first, kc, vc
+
+    def _norm(self, x, state, name):
+        """The block's norm over the last axis with the weights
+        `name`_g (and `name`_b under layernorm)."""
+        blk = self._block_meta
+        if blk["norm"] == "rmsnorm":
+            return _rms(x, state[name + "_g"], blk["norm_eps"])
+        return _ln(x, state[name + "_g"], state[name + "_b"],
+                   blk["norm_eps"])
+
+    def _block(self, state, p, x, positions, attend, live, tp=None):
+        """ONE decoder layer, as the artifact's meta describes it
+        (BLOCK_DEFAULTS), for prefill and for the decode step alike: x
+        [..., D] with one position per leading index, weights
+        `state[p + name]`.  `attend(q, k, v)` gets the layer's q/k/v
+        [..., Hl, Dh] (normed and rotated where the block says so — the
+        cache holds rotated K) and returns the attention output in q's
+        shape; what it does with k and v (collect them, write them to
+        the slot table) is the phase's.  `live` [tokens] marks the rows
+        a routed FFN counts.  Returns (x', routing facts [2] i32 or
+        None).  Under TP each column->row pair closes with one psum."""
+        import jax.numpy as jnp
+        blk = self._block_meta
+        _, H, Dh, D = self._dims()
+        Hl = H if tp is None else H // tp.size
+        lead = x.shape[:-1]
+        h = self._norm(x, state, p + "ln1")
+
+        def project(w, gain=None):
+            t = h @ state[p + w]
+            if gain and blk["qk_norm"]:
+                # over the whole projection, before the split into heads
+                t = _rms(t, state[p + gain], blk["norm_eps"])
+            return t.reshape(lead + (Hl, Dh))
+
+        q, k, v = project("wq", "qn_g"), project("wk", "kn_g"), project("wv")
+        if blk["position"] == "rope":
+            q = _rope(q, positions, blk["rope_theta"])
+            k = _rope(k, positions, blk["rope_theta"])
+        wo_out = attend(q, k, v).reshape(lead + (Hl * Dh,)) \
+            @ state[p + "wo"]
+        x = x + (wo_out if tp is None else tp.psum(wo_out))
+        h2 = self._norm(x, state, p + "ln2")
+        if blk["ffn"] == "moe_swiglu":
+            y, facts = moe_ffn(
+                h2.reshape(-1, D), state[p + "router"],
+                state[p + "w_gate"], state[p + "w_up"],
+                state[p + "w_down"], blk["experts_per_token"],
+                blk["norm_topk_prob"], live)
+            return x + y.reshape(x.shape), facts
+        mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
+                          0.0) @ state[p + "w2"]
+        return x + (mlp if tp is None else tp.psum(mlp)) \
+            + state[p + "b2"], None
 
     def _prefill_core_seqpar(self, state, tokens, true_len, tp):
         """SEQUENCE-parallel TP prefill (parallel/ulysses.py's scheme):
@@ -729,6 +1052,7 @@ class GenerativePredictor:
         import jax.numpy as jnp
         from paddle_tpu.parallel.ulysses import (heads_to_seq,
                                                  seq_to_heads)
+        self._require_default_block("sequence-parallel TP prefill")
         L, H, Dh, D = self._dims()
         B = tokens.shape[1]
         m = tp.size
@@ -781,19 +1105,34 @@ class GenerativePredictor:
 
     def _step_math(self, state, kc, vc, lengths, last_tokens, active,
                    tp=None):
-        """One greedy decode step: `_step_logits` + argmax ->
-        (new_tokens [N] i32, kc', vc')."""
+        """One greedy decode step: `_step_core` + argmax ->
+        (new_tokens [N] i32, kc', vc').  A routed-expert artifact's
+        first result carries the call's routing facts behind the N
+        tokens (`_pack_routing`)."""
         import jax.numpy as jnp
-        logits, kc, vc = self._step_logits(state, kc, vc, lengths,
-                                           last_tokens, active, tp=tp)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc
+        logits, kc, vc, facts = self._step_core(
+            state, kc, vc, lengths, last_tokens, active, tp=tp)
+        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if self.routed_layers:
+            toks = _pack_routing(toks, facts)
+        return toks, kc, vc
 
     def _step_logits(self, state, kc, vc, lengths, last_tokens, active,
                      tp=None):
+        """`_step_core` without the routing facts: (logits [N, vocab]
+        f32, kc', vc')."""
+        return self._step_core(state, kc, vc, lengths, last_tokens,
+                               active, tp=tp)[:3]
+
+    def _step_core(self, state, kc, vc, lengths, last_tokens, active,
+                   tp=None):
         """One fixed-shape decode step over the whole slot table.
         kc/vc [L, N, S, H, Dh] (fp32, or int8 under the quantized
         cache), lengths [N] i32 (live cached positions), last_tokens
-        [N] i32, active [N] bool -> (logits [N, vocab] f32, kc', vc').
+        [N] i32, active [N] bool -> (logits [N, vocab] f32, kc', vc',
+        per-layer routing facts).  Each layer is `_block` at position
+        `lengths` (a slot's own), its attention the write of the new row
+        and the decode kernel over the slot table.
         Cache writes are gated by `active`, so a freed (zeroed) slot
         stays zero and per-slot independence is exact.  Under int8,
         fresh K/V rows quantize in-graph before landing and the
@@ -811,58 +1150,49 @@ class GenerativePredictor:
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas_kernels import (
             decode_attention, decode_attention_head_slice)
-        L, H, Dh, D = self._dims()
+        L, H, Dh, _ = self._dims()
         N, S = kc.shape[1], kc.shape[2]
         quant = self._kv_quant
         scale = 1.0 / np.sqrt(Dh)
         Hl = H if tp is None else H // tp.size
-        if tp is None:
-            x = state["embed"][last_tokens] + state["pos"][lengths]
-        else:
-            x = tp.embed_lookup(state["embed"], last_tokens) \
-                + state["pos"][lengths]                         # [N, D]
+        x = state["embed"][last_tokens] if tp is None \
+            else tp.embed_lookup(state["embed"], last_tokens)   # [N, D]
+        if self._block_meta["position"] == "learned":
+            x = x + state["pos"][lengths]
         write = (jnp.arange(S)[None, :] == lengths[:, None]) \
             & active[:, None]                                   # [N, S]
         wmask = write[:, :, None, None]
-        kcs, vcs = [], []
+        kcs, vcs, facts = [], [], []
         for i in range(L):
-            p = "l%d_" % i
-            h = _ln(x, state[p + "ln1_g"], state[p + "ln1_b"])
-            q = (h @ state[p + "wq"]).reshape(N, Hl, Dh)
-            k_new = (h @ state[p + "wk"]).reshape(N, Hl, Dh)
-            v_new = (h @ state[p + "wv"]).reshape(N, Hl, Dh)
-            if quant:
-                sc_i = self._kv_scales[:, i] if tp is None \
-                    else tp.head_scales(self._kv_scales[:, i], Hl)
-                k_new = self._quantize_kv(
-                    k_new, sc_i[0]).astype(jnp.int8)
-                v_new = self._quantize_kv(
-                    v_new, sc_i[1]).astype(jnp.int8)
-            kci = jnp.where(wmask, k_new[:, None], kc[i])
-            vci = jnp.where(wmask, v_new[:, None], vc[i])
-            if tp is None:
-                att = decode_attention(q, kci, vci, lengths + 1,
-                                       scale=scale,
-                                       kv_scales=self._kv_scales[:, i]
-                                       if quant else None)
-            else:
-                att = decode_attention_head_slice(
+            def attend(q, k_new, v_new, i=i):
+                if quant:
+                    sc_i = self._kv_scales[:, i] if tp is None \
+                        else tp.head_scales(self._kv_scales[:, i], Hl)
+                    k_new = self._quantize_kv(
+                        k_new, sc_i[0]).astype(jnp.int8)
+                    v_new = self._quantize_kv(
+                        v_new, sc_i[1]).astype(jnp.int8)
+                kci = jnp.where(wmask, k_new[:, None], kc[i])
+                vci = jnp.where(wmask, v_new[:, None], vc[i])
+                kcs.append(kci)
+                vcs.append(vci)
+                if tp is None:
+                    return decode_attention(
+                        q, kci, vci, lengths + 1, scale=scale,
+                        kv_scales=self._kv_scales[:, i]
+                        if quant else None)
+                return decode_attention_head_slice(
                     q, kci, vci, lengths + 1, tp.index() * Hl, Hl,
                     scale=scale,
                     kv_scales=self._kv_scales[:, i] if quant else None)
-            wo_out = att.reshape(N, Hl * Dh) @ state[p + "wo"]
-            x = x + (wo_out if tp is None else tp.psum(wo_out))
-            h2 = _ln(x, state[p + "ln2_g"], state[p + "ln2_b"])
-            mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
-                              0.0) @ state[p + "w2"]
-            x = x + (mlp if tp is None else tp.psum(mlp)) \
-                + state[p + "b2"]
-            kcs.append(kci)
-            vcs.append(vci)
-        logits = _ln(x, state["lnf_g"], state["lnf_b"]) @ state["lm_head"]
+
+            x, f = self._block(state, "l%d_" % i, x, lengths, attend,
+                               active, tp=tp)
+            facts.append(f)
+        logits = self._norm(x, state, "lnf") @ state["lm_head"]
         if tp is not None:
             logits = tp.all_gather(logits, axis=1)
-        return logits, jnp.stack(kcs), jnp.stack(vcs)
+        return logits, jnp.stack(kcs), jnp.stack(vcs), facts
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
                      tp=None):
@@ -895,6 +1225,7 @@ class GenerativePredictor:
         import jax.numpy as jnp
         from paddle_tpu.ops.pallas_kernels import (
             decode_attention, decode_attention_head_slice)
+        self._require_default_block("the speculative verify step")
         L, H, Dh, D = self._dims()
         N, C = tokens.shape
         S = kc.shape[2]
@@ -1009,6 +1340,7 @@ class GenerativePredictor:
         block, `emitted[s]` of them valid per slot, in stream order."""
         import jax
         import jax.numpy as jnp
+        self._require_default_block("the fused multi-step decode window")
         n_steps = int(n_steps)
         eos = self.eos_id
 
@@ -1069,6 +1401,9 @@ class GenerativePredictor:
         fp32-only plain stream and twin-draft acceptance stays exactly
         1.0."""
         import jax.numpy as jnp
+        self._require_default_block("the fused speculative round")
+        draft._require_default_block("the fused speculative round's "
+                                     "draft")
         k = int(spec_k)
 
         def fused(state, dstate, t_kc, t_vc, t_len, t_last,
@@ -1147,6 +1482,10 @@ class GenerativePredictor:
             # int8 executables from ever colliding (COMPILE_CACHE.md);
             # rev bumps when the phase math itself changes shape
             "kv_dtype": self._kv_dtype,
+            # so do the block's keys (norm, position, qk-norm, FFN kind
+            # and routing): equal weight shapes, another function
+            "block": [[k, self._block_meta[k]]
+                      for k in sorted(self._block_meta)],
             "rev": 3,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
@@ -1511,24 +1850,44 @@ class DecodeSession:
         # the cache allocates at the predictor's kv_cache_dtype width:
         # int8 slot tables hold exact int8 zeros when free (QUANTIZE.md
         # "Quantized KV cache" — the zero-slot contract is dtype-blind)
-        z = jnp.zeros(shape, jnp.int8 if predictor._kv_quant
-                      else jnp.float32)
-        if predictor.device is not None:
-            from paddle_tpu.parallel.mesh import as_mesh_group
-            group = as_mesh_group(predictor.device)
-            if group is not None:
-                # the slot table shards AT REST across the mesh (heads
-                # axis first) — per-device resident KV ~ 1/mesh_size,
-                # which is what makes decode slots scale with mesh HBM
-                z = jax.device_put(z, group.kv_sharding(shape))
-            else:
-                z = jax.device_put(z, predictor.device)
-        self._kc = z
-        self._vc = z
+        # a slot's admission and release write its rows IN PLACE
+        # (`_slot_writers`: the table is donated) except on a mesh,
+        # where the table's sharding at rest is the eager write's to keep
+        self._inplace = True
+
+        def table():
+            z = jnp.zeros(shape, jnp.int8 if predictor._kv_quant
+                          else jnp.float32)
+            if predictor.device is not None:
+                from paddle_tpu.parallel.mesh import as_mesh_group
+                group = as_mesh_group(predictor.device)
+                if group is not None:
+                    # the slot table shards AT REST across the mesh
+                    # (heads axis first) — per-device resident KV ~
+                    # 1/mesh_size, which is what makes decode slots
+                    # scale with mesh HBM
+                    self._inplace = False
+                    return jax.device_put(z, group.kv_sharding(shape))
+                return jax.device_put(z, predictor.device)
+            return z
+
+        # two buffers: a donated K table must not take V's with it
+        self._kc = table()
+        self._vc = table()
+        # each slot's index as a device scalar: a write's `slot` is then
+        # no upload (a jitted call handed a numpy value waits for its
+        # copy to land: PERF.md, PR 24)
+        self._slot_ids = [
+            jnp.asarray(np.int32(i)) if predictor.device is None
+            else jax.device_put(np.int32(i), predictor.device)
+            for i in range(self.n_slots)] if self._inplace else None
         self.lengths = np.zeros(self.n_slots, np.int32)
         self.last_tokens = np.zeros(self.n_slots, np.int32)
         self.active = np.zeros(self.n_slots, bool)
         self.steps = 0
+        # a routed-expert artifact: the newest step's or prefill's
+        # per-layer (experts touched, most tokens on one expert) [L, 2]
+        self.last_routing = None
 
     # -- occupancy ------------------------------------------------------
 
@@ -1602,10 +1961,17 @@ class DecodeSession:
                                    (padded, np.int32(n)))
         # land the bucket-length K/V at the slot; positions past the
         # bucket are already zero (the slot was zeroed on free)
-        at = (0, slot, 0, 0, 0)
-        self._kc = jax.lax.dynamic_update_slice(self._kc, kc, at)
-        self._vc = jax.lax.dynamic_update_slice(self._vc, vc, at)
-        tok = int(self._fetch("prefill", first)[0])
+        if self._inplace:
+            write_rows = _slot_writers()[0]
+            at = self._slot_ids[slot]
+            self._kc = write_rows(self._kc, kc, at)
+            self._vc = write_rows(self._vc, vc, at)
+        else:
+            at = (0, slot, 0, 0, 0)
+            self._kc = jax.lax.dynamic_update_slice(self._kc, kc, at)
+            self._vc = jax.lax.dynamic_update_slice(self._vc, vc, at)
+        tok = int(self._fetch("prefill", first,
+                              routed=True)[0].reshape(-1)[0])
         self.lengths[slot] = n
         self.last_tokens[slot] = tok
         self.active[slot] = True
@@ -1617,7 +1983,7 @@ class DecodeSession:
         at call time are meaningful).  Bumps each active slot's length
         and last token."""
         toks, = self._fetch("step", self._step(
-            self.predictor.step_fn(self.n_slots)))
+            self.predictor.step_fn(self.n_slots)), routed=True)
         self._advance(toks)
         return toks
 
@@ -1641,17 +2007,31 @@ class DecodeSession:
             (self.lengths, self.last_tokens, self.active))
         return out
 
-    @staticmethod
-    def _fetch(phase, *outs):
+    def _fetch(self, phase, *outs, routed=False):
         """`np.asarray` of each result: the wait for the device and the
-        copy to the host, under one `decode/fetch` span."""
-        if not obs_tracing.enabled():
+        copy to the host, under one `decode/fetch` span.  `routed` marks
+        a step's or a prefill's result: for a routed-expert artifact the
+        call's routing facts sit behind its tokens in that one vector
+        (`_pack_routing`) and are split off here, kept as `last_routing`
+        and given to the span as `moe_experts_touched` (summed over the
+        layers) and `moe_tokens_per_expert_max`.  Any other artifact
+        takes the path it always took."""
+        n_routed = 2 * self.predictor.routed_layers if routed else 0
+        if not (n_routed or obs_tracing.enabled()):
             return [np.asarray(o) for o in outs]
         t0 = time.monotonic()
         got = [np.asarray(o) for o in outs]
-        obs_tracing.stamp("decode/fetch", t0, time.monotonic(),
-                          kind="serving", phase=phase,
-                          d2h_bytes=_nbytes(got))
+        d2h, attrs = _nbytes(got), {}
+        if n_routed:
+            facts = got[0][-n_routed:].reshape(-1, 2)
+            got[0] = got[0][:-n_routed]
+            self.last_routing = facts
+            attrs = {"moe_experts_touched": int(facts[:, 0].sum()),
+                     "moe_tokens_per_expert_max": int(facts[:, 1].max())}
+        if obs_tracing.enabled():
+            obs_tracing.stamp("decode/fetch", t0, time.monotonic(),
+                              kind="serving", phase=phase,
+                              d2h_bytes=d2h, **attrs)
         return got
 
     def _advance(self, toks):
@@ -1712,14 +2092,19 @@ class DecodeSession:
         reused — a later occupant starts from exact zeros, never from a
         previous request's keys (the no-leakage contract the chaos
         decode-disconnect scenario pins)."""
-        import jax.lax
-        import jax.numpy as jnp
-        L = self._kc.shape[0]
-        S, H, Dh = self._kc.shape[2], self._kc.shape[3], self._kc.shape[4]
-        z = self._put(jnp.zeros((L, 1, S, H, Dh), self._kc.dtype))
-        at = (0, int(slot), 0, 0, 0)
-        self._kc = jax.lax.dynamic_update_slice(self._kc, z, at)
-        self._vc = jax.lax.dynamic_update_slice(self._vc, z, at)
+        if self._inplace:
+            zero_slot = _slot_writers()[1]
+            self._kc = zero_slot(self._kc, self._slot_ids[slot])
+            self._vc = zero_slot(self._vc, self._slot_ids[slot])
+        else:
+            import jax.lax
+            import jax.numpy as jnp
+            L = self._kc.shape[0]
+            S, H, Dh = self._kc.shape[2:]
+            z = self._put(jnp.zeros((L, 1, S, H, Dh), self._kc.dtype))
+            at = (0, int(slot), 0, 0, 0)
+            self._kc = jax.lax.dynamic_update_slice(self._kc, z, at)
+            self._vc = jax.lax.dynamic_update_slice(self._vc, z, at)
         self.lengths[slot] = 0
         self.last_tokens[slot] = 0
         self.active[slot] = False
@@ -1812,6 +2197,9 @@ class SpeculativeDecodeSession:
                 "draft max_seq_len %d < target max_seq_len %d — the "
                 "draft cache cannot mirror the committed stream"
                 % (draft.max_seq_len, target.max_seq_len))
+        for pred, what in ((target, "speculative decoding"),
+                           (draft, "a speculative draft")):
+            pred._require_default_block(what)
         self.predictor = target
         self.draft_predictor = draft
         self.spec_k = int(spec_k)

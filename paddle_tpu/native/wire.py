@@ -130,7 +130,13 @@ def _encode_native(obj):
     n = lib.wirb_finish(h, ctypes.byref(out))
     if n < 0:
         raise MemoryError("wire encode failed")
-    buf = ctypes.string_at(out, n)
+    if n < (1 << 31):
+        buf = ctypes.string_at(out, n)
+    else:
+        # string_at's size is a C int, and a decode artifact's state
+        # passes 2 GiB (one layer of OLMoE-1B-7B is 1.7 GB)
+        buf = bytes((ctypes.c_uint8 * n).from_address(
+            ctypes.addressof(out.contents)))
     lib.wire_free(out)
     return buf
 

@@ -168,6 +168,34 @@ class TestDecodeSession:
             out.append(int(sess.decode()[0]))
         assert out == ref
 
+    def test_admission_and_release_write_the_table_in_place(self, predictor):
+        """prefill() and free() land their rows through `_slot_writers`
+        with the table donated: the table a write was given is consumed
+        (no second table in memory), K's donation leaves V alone (two
+        buffers from the start), only the written slot changes, and the
+        neighbour's stream is what it would be alone."""
+        sess = predictor.new_session(3)
+        assert sess._inplace and sess._kc is not sess._vc
+        sess.prefill(1, [7, 2, 9])
+        k_old, v_old = sess._kc, sess._vc
+        # a copy: a zero-copy view of the buffer would pin it, and a
+        # pinned buffer is copied and not donated
+        before = np.array(k_old, copy=True)
+        sess.prefill(0, [5, 9, 3])
+        assert k_old.is_deleted() and v_old.is_deleted(), \
+            "prefill copied the slot table instead of writing in place"
+        after = np.array(sess._kc, copy=True)
+        assert np.array_equal(after[:, 1:], before[:, 1:])
+        assert after[:, 0].any() and not sess.slot_is_zero(0)
+        k_old = sess._kc
+        sess.free(0)
+        assert k_old.is_deleted(), "free copied the slot table"
+        assert sess.slot_is_zero(0) and not sess.slot_is_zero(1)
+        assert np.array_equal(np.asarray(sess._kc)[:, 1:], before[:, 1:])
+        ref, _ = greedy_decode(predictor, [7, 2, 9], 4)
+        out = [int(sess.decode()[1]) for _ in range(3)]
+        assert out == ref[1:4]
+
     def test_prompt_bucket_and_oversize_rejection(self, predictor):
         assert predictor.prompt_bucket(3) == 8
         assert predictor.prompt_bucket(8) == 8

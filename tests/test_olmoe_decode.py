@@ -1,0 +1,366 @@
+"""A decode artifact whose meta describes another block than GPT-2's
+(RMSNorm, rotary positions, qk-norm, a dropless top-k routed-expert FFN:
+OLMoE's) through the serving path, against the plain reference
+`benchmark/reference/olmoe_1b_7b.py`, at a tiny size on the CPU.
+
+TOL_LOGITS: both sides compute in float32 here (the CPU backend does not
+round matmul operands to bf16), in another order of operations: sorted
+groups against all-experts-then-mask, a cache and a kernel against one
+causal pass.  Measured differences are a few 1e-6 on logits of std ~1; the
+bound is 1e-4.  Storage in bf16 (8 mantissa bits) of the weights or of the
+cache moves a logit by 1e-2, a hundred times the bound, and the two cases
+that round them must FAIL it.  The router runs at "highest" precision on
+both sides, so both keep the same experts but on an exact tie.
+"""
+
+import os
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import olmoe_1b_7b as reference  # noqa: E402
+from paddle_tpu.flags import set_flags  # noqa: E402
+from paddle_tpu.inference import decode as dec  # noqa: E402
+from paddle_tpu.inference.decode import (GenerativePredictor,  # noqa: E402
+                                         build_tiny_decode_model,
+                                         save_decode_model)
+from paddle_tpu.obs import tracing as obs_tracing  # noqa: E402
+from paddle_tpu.serving import (DecodeBatcher,  # noqa: E402
+                                InferenceServer, ServingClient)
+
+TOL_LOGITS = 1e-4
+OLMOE_BLOCK = {"norm": "rmsnorm", "norm_eps": 1e-5, "position": "rope",
+               "rope_theta": 10000.0, "qk_norm": True, "ffn": "moe_swiglu",
+               "n_experts": 8, "experts_per_token": 2, "expert_width": 32,
+               "norm_topk_prob": False}
+TINY = dict(vocab_size=97, d_model=64, n_heads=4, n_layers=2,
+            max_seq_len=64, eos_id=0, seed=11, prefill_buckets=[16, 32, 64])
+
+
+def _open(dirname):
+    pred = GenerativePredictor(dirname)
+    state = {n: jnp.asarray(v) for n, v in pred._state_host.items()}
+    return pred, state
+
+
+@pytest.fixture(scope="module")
+def artifact(tmp_path_factory):
+    d = str(tmp_path_factory.mktemp("olmoe") / "lm")
+    return build_tiny_decode_model(d, block=OLMOE_BLOCK, **TINY)
+
+
+@pytest.fixture(scope="module")
+def opened(artifact):
+    return _open(artifact)
+
+
+_REF = {}
+
+
+def _ref_logits(state, seq, meta):
+    """The reference's logits for `seq`, through ONE jitted program: the
+    sequence padded to max_seq_len (causal, so the pad changes nothing
+    before it)."""
+    fn = _REF.get("fn")
+    if fn is None:
+        model = {k: meta[k] for k in sorted(meta)}
+        fn = _REF["fn"] = jax.jit(
+            lambda st, t: reference.forward(st, t, model)[0])
+    tokens = np.zeros(TINY["max_seq_len"], np.int32)
+    tokens[:len(seq)] = seq
+    return np.asarray(fn(state, jnp.asarray(tokens)))[:len(seq)]
+
+
+def _prompts(lens, seed=3):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, TINY["vocab_size"], n, dtype=np.int32)
+            for n in lens]
+
+
+def _logit_diff(pred, ref_state, prompts, steps=6, spoil_cache=False):
+    """max |program - reference| over the logits of `steps` decode steps
+    after each prompt's prefill, the program teacher-forced on its own
+    tokens; plus the prefill tokens' worst gap below the reference top-1."""
+    sess = pred.new_session(len(prompts))
+    seqs = [list(p) + [sess.prefill(i, p)] for i, p in enumerate(prompts)]
+    if spoil_cache:
+        sess._kc = sess._kc.astype(jnp.bfloat16).astype(jnp.float32)
+        sess._vc = sess._vc.astype(jnp.bfloat16).astype(jnp.float32)
+    got = []
+    for _ in range(steps):
+        toks, logits = sess.decode_logits()
+        got.append(logits)
+        for i, s in enumerate(seqs):
+            s.append(int(toks[i]))
+    worst, gap = 0.0, 0.0
+    for i, s in enumerate(seqs):
+        want = _ref_logits(ref_state, s, pred.meta)
+        n = len(prompts[i])
+        gap = max(gap, float(want[n - 1].max() - want[n - 1, s[n]]))
+        for t in range(steps):
+            worst = max(worst, float(np.max(np.abs(got[t][i]
+                                                   - want[n + t]))))
+    return worst, gap
+
+
+# (a) prefill + decode through the cache against the full forward ---------
+
+def test_prefill_and_decode_match_the_reference_by_logits(opened):
+    pred, state = opened
+    prompts = _prompts([10, 27])                 # buckets 16 and 32
+    assert {pred.prompt_bucket(len(p)) for p in prompts} == {16, 32}
+    diff, gap = _logit_diff(pred, state, prompts)
+    assert diff <= TOL_LOGITS and gap <= 2 * TOL_LOGITS, (diff, gap)
+
+
+@pytest.mark.parametrize("what", ["weights", "cache"])
+def test_bf16_storage_fails_the_tolerance(opened, tmp_path, what):
+    pred, state = opened
+    if what == "weights":
+        rounded = {n: np.asarray(jnp.asarray(v).astype(jnp.bfloat16)
+                                 .astype(jnp.float32))
+                   for n, v in pred._state_host.items()}
+        pred = GenerativePredictor(save_decode_model(
+            str(tmp_path / "bf16"), rounded, pred.meta))
+    diff, _ = _logit_diff(pred, state, _prompts([10, 27]),
+                          spoil_cache=(what == "cache"))
+    assert diff > 10 * TOL_LOGITS, diff
+
+
+def test_int8_cache_runs_the_block_and_stays_near_fp32(artifact, opened):
+    _, state = opened
+    pred = GenerativePredictor(artifact, kv_cache_dtype="int8")
+    diff, _ = _logit_diff(pred, state, _prompts([10, 27]))
+    assert TOL_LOGITS < diff < 0.5, diff
+
+
+# (b) the batcher and the wire, streams joining and leaving ---------------
+
+def _held_to_reference_top1(state, meta, prompt, out):
+    seq = list(prompt) + list(out)
+    want = _ref_logits(state, seq, meta)
+    n = len(prompt)
+    return max(float(want[n - 1 + t].max() - want[n - 1 + t, tok])
+               for t, tok in enumerate(out))
+
+
+def test_batcher_streams_join_and_leave(opened):
+    pred, state = opened
+    b = DecodeBatcher(pred, n_slots=2)
+    prompts = _prompts([3, 12, 5, 20, 2, 9], seed=5)
+    budgets = [7, 3, 9, 2, 6, 5]
+    try:
+        streams = [b.submit([int(t) for t in p], max_new_tokens=m)
+                   for p, m in zip(prompts, budgets)]
+        outs = [s.result(timeout=120)[0].tolist() for s in streams]
+    finally:
+        b.close()
+    for p, m, out in zip(prompts, budgets, outs):
+        assert 1 <= len(out) <= m
+        assert _held_to_reference_top1(state, pred.meta, p, out) \
+            <= 2 * TOL_LOGITS
+    assert b.slot_occupancy() == (0, 2)
+
+
+def test_served_through_the_wire_with_the_default_placement(artifact,
+                                                            opened):
+    pred, state = opened
+    server = InferenceServer().start()
+    boot = ServingClient(server.endpoint)
+    prompts = _prompts([4, 18, 7], seed=9)
+    outs, errs = [None] * 3, []
+    try:
+        boot.load_model("olmoe", artifact, decode_slots=2)
+
+        def worker(i):
+            cli = ServingClient(server.endpoint)
+            try:
+                outs[i] = [t for c in cli.infer_stream(
+                    "olmoe", prompts[i], max_new_tokens=5 + i,
+                    deadline_ms=60000.0) for t in c]
+            except Exception as e:                       # noqa: BLE001
+                errs.append(e)
+            finally:
+                cli.close()
+
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not errs, errs
+        for p, out in zip(prompts, outs):
+            assert _held_to_reference_top1(state, pred.meta, p, out) \
+                <= 2 * TOL_LOGITS
+    finally:
+        boot.close()
+        server.shutdown(drain=True)
+
+
+# (c) the routed FFN alone against dense-and-mask --------------------------
+
+def _dense_and_mask(h, router, wg, wu, wd, k, norm):
+    with jax.default_matmul_precision("highest"):
+        p = jax.nn.softmax(h @ router, axis=-1)
+        _, idx = jax.lax.top_k(p, k)
+        w = p * jnp.sum(jax.nn.one_hot(idx, p.shape[1]), axis=1)
+        if norm:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        act = jax.nn.silu(jnp.einsum("td,edf->tef", h, wg)) \
+            * jnp.einsum("td,edf->tef", h, wu)
+        return jnp.einsum("tef,efd->td", act * w[:, :, None], wd), w
+
+
+@pytest.mark.parametrize("tokens,one_expert,norm", [
+    (3, False, False), (40, False, False), (40, True, False),
+    (5, False, True)])
+def test_routed_ffn_is_dropless_and_exact(tokens, one_expert, norm):
+    D, E, F, k = 32, 8, 16, 2
+    ks = jax.random.split(jax.random.PRNGKey(tokens), 5)
+    h = jax.random.normal(ks[0], (tokens, D))
+    router = jax.random.normal(ks[1], (D, E)) / np.sqrt(D)
+    wg, wu = (jax.random.normal(kk, (E, D, F)) / np.sqrt(D)
+              for kk in ks[2:4])
+    wd = jax.random.normal(ks[4], (E, F, D)) / np.sqrt(F)
+    if one_expert:
+        # every token's largest router logit is expert 5's: dropless means
+        # expert 5 takes all 40 rows and none is lost
+        h = jnp.abs(h)
+        router = router.at[:, 5].set(1.0)
+    got, facts = jax.jit(dec.moe_ffn, static_argnums=(5, 6))(
+        h, router, wg, wu, wd, k, norm)
+    want, w = _dense_and_mask(h, router, wg, wu, wd, k, norm)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=0)
+    per_expert = np.asarray(jnp.sum(w > 0, axis=0))
+    assert int(per_expert.sum()) == tokens * k          # none dropped
+    if one_expert:
+        assert per_expert[5] == tokens
+    assert facts.tolist() == [int((per_expert > 0).sum()),
+                              int(per_expert.max())]
+
+
+# (d) the routing counters against counts made by hand ---------------------
+
+def test_routing_counters_ride_the_fetch(opened):
+    pred, state = opened
+    L, k = TINY["n_layers"], OLMOE_BLOCK["experts_per_token"]
+    set_flags({"trace": True})
+    obs_tracing.clear()
+    try:
+        sess = pred.new_session(3)
+        prompt = _prompts([9])[0]
+        sess.prefill(1, prompt)
+        pre = sess.last_routing.copy()
+        sess.decode()
+        step = sess.last_routing.copy()
+        fetches = [s for s in obs_tracing.recent_spans()
+                   if s["name"] == "decode/fetch"]
+    finally:
+        set_flags({"trace": False})
+    # by hand: the reference's router over the prompt, layer by layer
+    x = reference.embed(state["embed"], jnp.asarray(prompt))
+    for i in range(L):
+        w = {n: state["l%d_%s" % (i, n)] for n in reference.LAYER_WEIGHTS}
+        with jax.default_matmul_precision("highest"):
+            h = reference._rms(reference.layer(x, dict(
+                w, w_down=jnp.zeros_like(w["w_down"])), pred.meta)[0],
+                w["ln2_g"], 1e-5)
+            _, idx = jax.lax.top_k(jax.nn.softmax(h @ w["router"]), k)
+        counts = np.bincount(np.asarray(idx).reshape(-1), minlength=8)
+        assert pre[i].tolist() == [int((counts > 0).sum()),
+                                   int(counts.max())]
+        x = reference.layer(x, w, pred.meta)[0]
+    # the step: ONE live slot of three, so k experts a layer, one token each
+    assert step.tolist() == [[k, 1]] * L
+    by_phase = {s["attrs"]["phase"]: s["attrs"] for s in fetches}
+    assert by_phase["prefill"]["moe_experts_touched"] == int(pre[:, 0].sum())
+    assert by_phase["prefill"]["moe_tokens_per_expert_max"] \
+        == int(pre[:, 1].max())
+    assert by_phase["step"]["moe_experts_touched"] == k * L
+    assert by_phase["step"]["moe_tokens_per_expert_max"] == 1
+    # tokens and facts came in one vector: 3 tokens + 2 per layer, int32
+    assert by_phase["step"]["d2h_bytes"] == 4 * (3 + 2 * L)
+
+
+def test_default_block_step_returns_what_it_did(tmp_path):
+    pred = GenerativePredictor(build_tiny_decode_model(
+        str(tmp_path / "gpt"), vocab_size=32, d_model=16, n_heads=2,
+        n_layers=2, max_seq_len=32))
+    set_flags({"trace": True})
+    obs_tracing.clear()
+    try:
+        sess = pred.new_session(2)
+        sess.prefill(0, [3, 4, 5])
+        assert sess.decode().shape == (2,)
+        attrs = [s["attrs"] for s in obs_tracing.recent_spans()
+                 if s["name"] == "decode/fetch"]
+    finally:
+        set_flags({"trace": False})
+    assert pred.routed_layers == 0 and sess.last_routing is None
+    assert all("moe_experts_touched" not in a for a in attrs)
+    assert [a["d2h_bytes"] for a in attrs] == [4, 8]
+
+
+# (e) a phase that does not implement the block says so, by key ------------
+
+@pytest.mark.parametrize("phase,call", [
+    ("verify", lambda p: p.verify_fn(2, 2)),
+    ("fused", lambda p: p.fused_step_fn(2, 4)),
+    ("spec", lambda p: dec.SpeculativeDecodeSession(p, p, 2, 2)),
+    ("seqpar", lambda p: p._prefill_core_seqpar(None, None, None, None)),
+])
+def test_untaught_phase_refuses_naming_the_key(opened, phase, call):
+    with pytest.raises(NotImplementedError, match=r"norm='rmsnorm'"):
+        call(opened[0])
+
+
+def test_tp_lane_refuses_instead_of_falling_back(artifact):
+    from paddle_tpu.parallel.mesh import MeshGroup
+    set_flags({"mesh_tp": True})
+    try:
+        with pytest.raises(NotImplementedError, match="mesh_tp.*norm="):
+            GenerativePredictor(artifact,
+                                device=MeshGroup(jax.devices()[:2]))
+    finally:
+        set_flags({"mesh_tp": False})
+
+
+def test_unknown_block_value_and_missing_weight_are_typed_errors(tmp_path):
+    with pytest.raises(ValueError, match="norm='batchnorm'"):
+        dec.block_of({"norm": "batchnorm"})
+    meta = dict(OLMOE_BLOCK, vocab_size=8, d_model=8, n_heads=2, n_layers=1,
+                max_seq_len=8, eos_id=0)
+    state = {n: np.zeros(s, np.float32)
+             for n, s in dec.decode_state_shapes(meta).items()}
+    del state["l0_router"]
+    with pytest.raises(ValueError, match="l0_router"):
+        save_decode_model(str(tmp_path / "x"), state, meta)
+
+
+# (f) the block is in the fingerprint --------------------------------------
+
+def test_block_keys_reach_the_fingerprint(tmp_path):
+    preds = []
+    for i, theta in enumerate((10000.0, 500000.0)):
+        preds.append(GenerativePredictor(build_tiny_decode_model(
+            str(tmp_path / str(i)), block=dict(OLMOE_BLOCK, rope_theta=theta),
+            **TINY)))
+    a, b = (p._fingerprint(("step", 2), p._step_specs(2)) for p in preds)
+    assert a["state"] == b["state"] and a["args"] == b["args"]
+    assert a["block"] != b["block"]
+    assert dict(a["block"])["rope_theta"] == 10000.0
+    # and on its own, with the model's hash held equal
+    b2 = dict(b, model=a["model"])
+    from paddle_tpu import compile_cache as cc
+    assert cc.fingerprint_key(a) != cc.fingerprint_key(b2)

@@ -63,7 +63,9 @@ def compile_for_chip(fn, one_chip, *specs):
 # the serve phase of chip_smoke.py (FLAGS.serving_decode_slots slots of a
 # GPT-2-small cache) and a wider, longer table
 DECODE_GEOMETRIES = {"n8_s1024_h12_d64": (8, 1024, 12, 64),
-                     "n32_s2048_h16_d128": (32, 2048, 16, 128)}
+                     "n32_s2048_h16_d128": (32, 2048, 16, 128),
+                     # the olmoe_1b_7b configuration's slot table
+                     "n8_s4096_h16_d128": (8, 4096, 16, 128)}
 DECODE_DTYPES = {"fp32": ("float32", "float32"),
                  "bf16": ("bfloat16", "bfloat16"),
                  "int8kv": ("float32", "int8")}
@@ -123,3 +125,29 @@ def test_dequant_matmul_compiles(one_chip, m, k, n, dt):
     compile_for_chip(
         lambda x, w, s: pk.dequant_matmul(x, w, s, interpret=False),
         one_chip, ((m, k), dt), ((k, n), "int8"), ((n,), "float32"))
+
+
+def test_routed_ffn_compiles_to_grouped_matmul_kernels(one_chip):
+    """`decode.moe_ffn` at OLMoE's published widths, a decode step's and a
+    prefill's token counts: the three expert matmuls are XLA's
+    grouped-matmul kernels (Mosaic custom calls named `ragged-dot-*`, which
+    visit only the groups that hold rows), not a dense all-experts
+    expansion, and the program holds no second copy of the 1.6 GB of expert
+    weights (a scan over experts had XLA hoist a bf16 copy of all of them
+    out of the loop)."""
+    from paddle_tpu.inference.decode import moe_ffn
+    D, E, F, K = 2048, 64, 1024, 8
+    for tokens in (8, 1024):
+        args = [jax.ShapeDtypeStruct(shape, np.float32, sharding=one_chip)
+                for shape in ((tokens, D), (D, E), (E, D, F), (E, D, F),
+                              (E, F, D))]
+        compiled = jax.jit(lambda h, r, g, u, d: moe_ffn(
+            h, r, g, u, d, K)).lower(*args).compile()
+        text = compiled.as_text()
+        grouped = [ln for ln in text.splitlines()
+                   if "tpu_custom_call" in ln
+                   and ln.strip().startswith("%ragged-dot-none")]
+        assert len(grouped) == 3, len(grouped)
+        flops = compiled.cost_analysis()["flops"]
+        assert flops < 1.2 * tokens * K * 3 * 2 * D * F + 1e9, flops
+        assert compiled.memory_analysis().temp_size_in_bytes < 4e8

@@ -322,7 +322,9 @@ def reference_attention():
     from paddle_tpu.ops import pallas_kernels as pk
 
     def ref(q, k_cache, v_cache, lengths, scale=None, block_kv=None,
-            interpret=None, kv_scales=None):
+            interpret=None, kv_scales=None, layer=None):
+        if layer is not None:       # the step hands it the stacked table
+            k_cache, v_cache = k_cache[layer], v_cache[layer]
         with jax.default_matmul_precision("highest"):
             return pk.decode_attention_reference(
                 q, k_cache, v_cache, lengths, scale=scale,
@@ -426,8 +428,13 @@ def serve_one(srv, artifact, kv, seed, devs):
     n_calls = require_mosaic(step.lower(*args).compile().as_text(),
                              pred.meta["n_layers"],
                              "the served %s step executable" % kv)
-    toks_dev = step(*args)[0]
+    # the step consumes the tables it is given (donated) and hands them
+    # back updated in place: take them back, as the session does
+    toks_dev, sess._kc, sess._vc = step(*args)
     require_on_chip(toks_dev, "decode step output")
+    require(args[1].is_deleted() and args[2].is_deleted(),
+            "the %s step did not consume the slot table it was given"
+            % kv)
     del sess, args
 
     # same prefix, kernel step vs reference-attention step
@@ -553,7 +560,11 @@ def phase_serve_olmoe(seed, devs):
         require(n_grouped == 3 * OLMOE_LAYERS,
                 "the served OLMoE step holds %d grouped-matmul kernels, "
                 "expected 3 a layer" % n_grouped)
-        require_on_chip(step(*args)[0], "decode step output")
+        toks_dev, sess._kc, sess._vc = step(*args)
+        require_on_chip(toks_dev, "decode step output")
+        require(args[1].is_deleted() and args[2].is_deleted(),
+                "the OLMoE step did not consume the slot table it was "
+                "given")
         del sess, args
 
         firsts, logits = teacher_forced_logits(pred, plist, served,

@@ -827,7 +827,7 @@ def _decode_report(path, meta, decode_slots, device, what,
     check statically reads ~0.25x KV bytes for an int8-cache load
     (int8 slots + the per-(layer,head) fp32 scale table)."""
     from ..flags import FLAGS
-    from ..inference.decode import normalize_kv_dtype
+    from ..inference.decode import normalize_kv_dtype, table_row
     n_slots = int(decode_slots or FLAGS.serving_decode_slots)
     L = int(meta["n_layers"])
     H = int(meta["n_heads"])
@@ -855,13 +855,15 @@ def _decode_report(path, meta, decode_slots, device, what,
             if os.path.exists(state_path) else 0
         rep.actual_param_bytes = rep.param_bytes
         n_params = rep.param_bytes // 4
-    # K and V, [L, n_slots, S, H, Dh] each at the cache dtype's width
-    # (4 B fp32, 1 B int8 + the fp32 scale table) — must match
-    # GenerativePredictor.kv_cache_bytes exactly (pinned by
-    # tests/test_resources.py)
+    # K and V, [L, n_slots, S, Hp, Dp] each at the cache dtype's width
+    # (4 B fp32, 1 B int8 + the fp32 scale table), a row (Hp, Dp) as
+    # the placement's table holds it (padded to the kernel's tile on one
+    # TPU device) — must match GenerativePredictor.kv_cache_bytes
+    # exactly (pinned by tests/test_resources.py)
     kv_elem = 1 if kv_dtype == "int8" else 4
     kv_scales = 2 * L * H * 4 if kv_dtype == "int8" else 0
-    rep.kv_cache_bytes = (2 * L * n_slots * S * H * dh * kv_elem
+    hp, dp = table_row(H, dh, device)
+    rep.kv_cache_bytes = (2 * L * n_slots * S * hp * dp * kv_elem
                           + kv_scales)
     # decode-step working set: one token's activations per slot
     rep.activation_peak_bytes = n_slots * D * 4 * (L + 2)

@@ -539,14 +539,16 @@ def _flight_changed(v):
 # the flags whose on_change hooks read them, so an env override firing
 # mid-import finds them registered.
 DEFINE_int(
-    "trace_buffer_events", 16384,
+    "trace_buffer_events", 65536,
     "Capacity of the obs span ring buffer (paddle_tpu/obs/tracing.py): "
     "completed spans land in a fixed-size ring; the oldest fall off "
     "silently under load (the drop count rides the metrics surface). "
     "Sized so the slowest recent requests/steps tools/trace_top.py "
     "prints are always resolvable, and so a 45 s benchmark window of a "
-    "decode lane (6 spans a round, 8 a request) fits whole; memory cost "
-    "is ~200 bytes/span.",
+    "decode lane (6 spans a round, 8 a request) fits whole with room to "
+    "spare: 19 300 spans at a 13 ms round (PR 27; the benchmark calls a "
+    "run with a dropped span not correct); memory cost is ~200 "
+    "bytes/span.",
     on_change=_trace_buffer_changed)
 DEFINE_float(
     "trace_slow_ms", 0.0,

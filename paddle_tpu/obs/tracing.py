@@ -55,7 +55,7 @@ __all__ = ["Span", "trace", "stamp", "under", "inherited", "new_trace_id",
            "spans_for_trace", "clear", "stats", "add_span", "chrome_events"]
 
 _lock = threading.Lock()           # guards reconfiguration only
-_ring = collections.deque(maxlen=16384)
+_ring = collections.deque(maxlen=65536)
 _enabled = True
 _spans_total = 0                   # lifetime appends (overflow = total - len)
 _rng = random.Random()

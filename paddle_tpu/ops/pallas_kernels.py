@@ -561,12 +561,22 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
 
 
 def decode_attention(q, k_cache, v_cache, lengths, scale=None,
-                     block_kv=None, interpret=None, kv_scales=None):
+                     block_kv=None, interpret=None, kv_scales=None,
+                     layer=None):
     """Slot-cache decode attention: q [N, H, D] (the one new token of
     each of N slots), k_cache/v_cache [N, S, H, D] (the slot table's
     cached keys/values, time-major; fp32 or int8), lengths [N] int32
     (live positions per slot — cached positions >= length are masked
     out) -> [N, H, D] in q's dtype.
+
+    With `layer` (a static int) k_cache/v_cache are the STACKED slot
+    table [L, N, S, H, D] and the kernel reaches that layer through its
+    BlockSpec index maps, (layer, b, j, 0, 0): no slice of the table is
+    materialised for the custom call, so a decode step that carries the
+    table and updates it in place keeps ONE buffer of it
+    (`inference/decode.py::_step_core`).  Body, block geometry and
+    arithmetic are those of the 4-D form, which stays for callers that
+    hold a single layer.
 
     With int8 caches, `kv_scales` [2, H] f32 (k-scales row 0, v-scales
     row 1 — the per-(layer,head) scales of the quantized slot table,
@@ -588,7 +598,13 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     from jax.experimental.pallas import tpu as pltpu
 
     N, H, D = q.shape
-    S = k_cache.shape[1]
+    stacked = layer is not None
+    if stacked != (k_cache.ndim == 5):
+        raise ValueError(
+            "decode_attention: a stacked table [L, N, S, H, D] goes with "
+            "a static `layer`, a single layer [N, S, H, D] without one "
+            "(got %d-D caches, layer=%r)" % (k_cache.ndim, layer))
+    S = k_cache.shape[-3]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
     kv_dtype = jnp.dtype(k_cache.dtype)
     quant = kv_dtype == jnp.dtype(jnp.int8)
@@ -599,10 +615,20 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     bkv = int(block_kv or attention_tuning.get_decode_config(
         S, D, kv_dtype.name) or 0)
     if not bkv or S % bkv:
+        if stacked:
+            k_cache, v_cache = k_cache[layer], v_cache[layer]
         return decode_attention_reference(q, k_cache, v_cache, lengths,
                                           scale=scale,
                                           kv_scales=kv_scales)
     lengths = jnp.asarray(lengths).astype(jnp.int32).reshape(N)
+    if stacked:
+        # the layer's axis is squeezed out of the block: the body sees
+        # the (1, bkv, H, D) tile it always saw
+        layer = int(layer)
+        kv_spec = pl.BlockSpec((None, 1, bkv, H, D),
+                               lambda b, j: (layer, b, j, 0, 0))
+    else:
+        kv_spec = pl.BlockSpec((1, bkv, H, D), lambda b, j: (b, j, 0, 0))
 
     def tile(ctx):
         q_ref, k_ref, v_ref, len_ref = ctx.ins[:4]
@@ -642,8 +668,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     operands = [q, k_cache, v_cache, lengths]
     in_specs = [
         pl.BlockSpec((1, H, D), lambda b, j: (b, 0, 0)),
-        pl.BlockSpec((1, bkv, H, D), lambda b, j: (b, j, 0, 0)),
-        pl.BlockSpec((1, bkv, H, D), lambda b, j: (b, j, 0, 0)),
+        kv_spec,
+        kv_spec,
         # the whole [N] vector in scalar memory, indexed by slot: a
         # (1, 1) VMEM block of it is below Mosaic's (8, 128) tile floor
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -670,11 +696,14 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
 
 def decode_attention_head_slice(q, k_cache, v_cache, lengths, head_offset,
                                 n_local_heads, scale=None, block_kv=None,
-                                interpret=None, kv_scales=None):
+                                interpret=None, kv_scales=None,
+                                layer=None):
     """Tensor-parallel entry (SERVING.md "Tensor-parallel compute"):
     decode attention over one member's RESIDENT head block of the slot
     table. q/k_cache/v_cache are already the LOCAL head shards
-    ([N, Hl, D] / [N, S, Hl, D], Hl = n_local_heads), but `kv_scales`
+    ([N, Hl, D] / [N, S, Hl, D], Hl = n_local_heads; or the stacked
+    local table [L, N, S, Hl, D] with a static `layer`, as in
+    `decode_attention`), but `kv_scales`
     arrives as the FULL per-layer table [2, H_total] (or [2, H_total,
     1]) — the scales are baked compile-time constants shared by every
     member, so each member dynamic-slices its own [2, Hl] window at
@@ -696,7 +725,7 @@ def decode_attention_head_slice(q, k_cache, v_cache, lengths, head_offset,
             full, jnp.asarray(head_offset, jnp.int32), Hl, axis=1)
     return decode_attention(q, k_cache, v_cache, lengths, scale=scale,
                             block_kv=block_kv, interpret=interpret,
-                            kv_scales=sc)
+                            kv_scales=sc, layer=layer)
 
 
 # ---------------------------------------------------------------------------

@@ -920,3 +920,416 @@ def test_chaos_decode_disconnect_fused_scenario():
     res = chaos.scenario_decode_disconnect_fused(verbose=False)
     assert res["freed_steps"] <= 3 * res["fuse_steps"]
     assert res["overshoot_ms"] is not None
+
+
+# ---------------------------------------------------------------------------
+# the step updates the slot table in place (PR 27): the table is carried
+# through the layers, its N new rows scattered, and every phase that takes
+# it consumes it
+# ---------------------------------------------------------------------------
+
+ROUTED_BLOCK = {"norm": "rmsnorm", "norm_eps": 1e-5, "position": "rope",
+                "rope_theta": 10000.0, "qk_norm": True, "ffn": "moe_swiglu",
+                "n_experts": 8, "experts_per_token": 2, "expert_width": 32,
+                "norm_topk_prob": False}
+INPLACE_CASES = [(blk, kv) for blk in ("default", "routed")
+                 for kv in ("float32", "int8")]
+
+
+@pytest.fixture(scope="module")
+def inplace_artifacts(tmp_path_factory):
+    root = tmp_path_factory.mktemp("inplace")
+    common = dict(vocab_size=48, d_model=32, n_heads=2, n_layers=2,
+                  max_seq_len=32, eos_id=0, seed=5,
+                  prefill_buckets=[8, 32])
+    return {"default": build_tiny_decode_model(str(root / "d"), **common),
+            "routed": build_tiny_decode_model(str(root / "r"),
+                                              block=ROUTED_BLOCK, **common)}
+
+
+@pytest.fixture(scope="module", params=INPLACE_CASES,
+                ids=["%s-%s" % c for c in INPLACE_CASES])
+def inplace_pred(request, inplace_artifacts):
+    blk, kv = request.param
+    return GenerativePredictor(inplace_artifacts[blk], kv_cache_dtype=kv)
+
+
+def _tables(sess):
+    # copies: a zero-copy view would pin the buffer, and a pinned buffer
+    # is copied instead of donated
+    return (np.array(sess._kc, copy=True), np.array(sess._vc, copy=True))
+
+
+def _where_stack_step(pred, state, kc, vc, lengths, last_tokens, active):
+    """The step as it was before PR 27, as an oracle: each layer selects
+    its new row into a copy of the layer (`jnp.where` over [N, S, H, Dh]),
+    the kernel reads that copy, and the layers are stacked back."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas_kernels import decode_attention
+    L, H, Dh, _ = pred._dims()
+    S = kc.shape[2]
+    x = state["embed"][last_tokens]
+    if pred.block["position"] == "learned":
+        x = x + state["pos"][lengths]
+    wmask = ((jnp.arange(S)[None, :] == lengths[:, None])
+             & active[:, None])[:, :, None, None]
+    kcs, vcs = [], []
+    for i in range(L):
+        def attend(q, k_new, v_new, i=i):
+            if pred._kv_quant:
+                sc = pred._kv_scales[:, i]
+                k_new = pred._quantize_kv(k_new, sc[0]).astype(jnp.int8)
+                v_new = pred._quantize_kv(v_new, sc[1]).astype(jnp.int8)
+            kcs.append(jnp.where(wmask, k_new[:, None], kc[i]))
+            vcs.append(jnp.where(wmask, v_new[:, None], vc[i]))
+            return decode_attention(
+                q, kcs[-1], vcs[-1], lengths + 1, scale=1.0 / np.sqrt(Dh),
+                kv_scales=pred._kv_scales[:, i] if pred._kv_quant
+                else None)
+        x, _ = pred._block(state, "l%d_" % i, x, lengths, attend, active)
+    logits = pred._norm(x, state, "lnf") @ state["lm_head"]
+    return logits, jnp.stack(kcs), jnp.stack(vcs)
+
+
+class TestStepInPlace:
+    def test_a_step_consumes_its_table_and_says_so(self, inplace_pred):
+        """The table a step is given is donated (no second table), and
+        `decode/launch` counts it: `donated_bytes` is both tables'."""
+        from paddle_tpu.obs import tracing as obs_tracing
+        sess = inplace_pred.new_session(4)
+        sess.prefill(1, [7, 2, 9])
+        k_old, v_old = sess._kc, sess._vc
+        was = obs_tracing.enabled()
+        obs_tracing.set_enabled(True)
+        try:
+            obs_tracing.clear()
+            sess.decode()
+            sess.decode_logits()
+            launches = [s for s in
+                        obs_tracing.recent_spans(name="decode/launch")
+                        if s["attrs"]["phase"] == "step"]
+        finally:
+            obs_tracing.set_enabled(was)
+        assert k_old.is_deleted() and v_old.is_deleted(), \
+            "the step copied the slot table instead of updating it"
+        assert sess._kc is not k_old and not sess._kc.is_deleted()
+        both = int(sess._kc.nbytes) + int(sess._vc.nbytes)
+        assert both == sess.cache_bytes() - (
+            int(np.asarray(inplace_pred._kv_scales).nbytes)
+            if inplace_pred._kv_quant else 0)
+        assert [s["attrs"]["donated_bytes"] for s in launches] \
+            == [both, both]
+
+    def test_only_the_new_row_of_each_active_slot_changes(self,
+                                                          inplace_pred):
+        """After a step the table differs from the one before in row
+        `lengths[n]` of each active slot and nowhere else: a slot never
+        admitted and a freed slot stay exactly zero, and a slot at
+        `lengths == S` (no room) is not touched at all."""
+        pred = inplace_pred
+        S = pred.max_seq_len
+        sess = pred.new_session(5)
+        sess.prefill(0, [3, 1, 4, 1, 5])
+        sess.prefill(1, list(range(1, S + 1)))     # fills its row: no room
+        sess.prefill(2, [9, 2])
+        sess.prefill(3, [6])
+        sess.free(3)                               # slot 4: never admitted
+        assert sess.room(1) == 0
+        for _ in range(3):
+            lengths = sess.lengths.copy()
+            k0, v0 = _tables(sess)
+            sess.decode()
+            k1, v1 = _tables(sess)
+            for a, b in ((k0, k1), (v0, v1)):
+                changed = np.argwhere((a != b).any(axis=(0, 3, 4)))
+                assert sorted(map(tuple, changed)) \
+                    == [(0, lengths[0]), (2, lengths[2])]
+                assert (a[:, 0, lengths[0]] == 0).all() \
+                    and b[:, 0, lengths[0]].any()
+            assert sess.slot_is_zero(3) and sess.slot_is_zero(4)
+        assert list(sess.lengths) == [8, S + 3, 5, 0, 0]
+
+    def test_tokens_logits_and_table_equal_the_where_stack_form(
+            self, inplace_pred):
+        """N steps through the session against the deleted
+        `where`/`stack` form written out above: the same logits to the
+        bit, the same tokens, the same table after every step."""
+        import jax
+        import jax.numpy as jnp
+        pred = inplace_pred
+        sess = pred.new_session(3)
+        sess.prefill(0, [5, 9, 3, 7])
+        sess.prefill(2, [11, 4])
+        state = {n: jnp.asarray(v) for n, v in pred._state_host.items()}
+        kc, vc = (jnp.asarray(t) for t in _tables(sess))
+        oracle = jax.jit(lambda *a: _where_stack_step(pred, *a))
+        for _ in range(5):
+            lengths, last = sess.lengths.copy(), sess.last_tokens.copy()
+            want, kc, vc = oracle(state, kc, vc, lengths, last,
+                                  sess.active.copy())
+            toks, logits = sess.decode_logits()
+            act = sess.active
+            assert np.array_equal(logits[act], np.asarray(want)[act])
+            assert np.array_equal(
+                toks[act], np.asarray(want).argmax(-1)[act])
+            k1, v1 = _tables(sess)
+            assert np.array_equal(k1, np.asarray(kc))
+            assert np.array_equal(v1, np.asarray(vc))
+
+    def test_a_call_that_fails_after_donation_kills_the_session(
+            self, inplace_pred):
+        """A phase call that raises AFTER consuming the table leaves a
+        session that says so on its next use, whatever the use; one that
+        raises before it donates leaves the session as it was."""
+        from paddle_tpu.inference.decode import DecodeSessionDead
+        sess = inplace_pred.new_session(2)
+        first = sess.prefill(0, [5, 9, 3])
+
+        def refuses(state, kc, vc, *small):
+            raise ValueError("bad argument")
+
+        def dies(state, kc, vc, *small):
+            kc.delete()
+            vc.delete()
+            raise RuntimeError("the device fell over")
+
+        with pytest.raises(ValueError):
+            sess._call("step", refuses, (sess._kc, sess._vc), ())
+        ref, _ = greedy_decode(inplace_pred, [5, 9, 3], 3)
+        assert [first, int(sess.decode()[0])] == ref[:2]
+        with pytest.raises(RuntimeError, match="fell over"):
+            sess._call("step", dies, (sess._kc, sess._vc), ())
+        for use in (sess.decode, sess.decode_logits,
+                    lambda: sess.decode_fused(2),
+                    lambda: sess.prefill(1, [4]), lambda: sess.free(0),
+                    lambda: sess.rollback(0, 1),
+                    lambda: sess.slot_is_zero(1)):
+            with pytest.raises(DecodeSessionDead,
+                               match="step call failed.*fell over"):
+                use()
+
+
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+def test_fused_window_is_the_sequential_steps_table_and_all(
+        inplace_artifacts, kv):
+    """The fused window carries the same table through its `while_loop`
+    and lands the same rows: tokens AND tables bit-identical to the
+    sequential steps, and the window consumes the table it was given."""
+    pred = GenerativePredictor(inplace_artifacts["default"],
+                               kv_cache_dtype=kv)
+    a, b = pred.new_session(3), pred.new_session(3)
+    for sess in (a, b):
+        sess.prefill(0, [5, 9, 3, 7])
+        sess.prefill(2, [11, 4])
+    k_old = a._kc
+    toks, counts, trips = a.decode_fused(6)
+    assert k_old.is_deleted() and trips == 6
+    seq = np.stack([b.decode() for _ in range(6)], axis=1)
+    for s in (0, 2):
+        assert list(toks[s, :counts[s]]) == list(seq[s, :counts[s]])
+    for x, y in zip(_tables(a), _tables(b)):
+        assert np.array_equal(x, y)
+    assert a.slot_is_zero(1) and list(a.lengths) == list(b.lengths)
+
+
+def test_a_failed_fused_speculative_round_kills_both_sessions(
+        inplace_artifacts):
+    from paddle_tpu.inference.decode import (DecodeSessionDead,
+                                             SpeculativeDecodeSession)
+    target = GenerativePredictor(inplace_artifacts["default"])
+    draft = GenerativePredictor(inplace_artifacts["default"],
+                                kv_cache_dtype="int8")
+    sp = SpeculativeDecodeSession(target, draft, 2, 2)
+    sp.prefill(0, [5, 9, 3])
+
+    def dies(state, dstate, t_kc, t_vc, t_len, t_last, d_kc, d_vc, *rest):
+        for t in (t_kc, t_vc, d_kc, d_vc):
+            t.delete()
+        raise RuntimeError("the device fell over")
+
+    target._fns[("fused_spec", 2, 3, draft._model_fp[:16],
+                 draft._kv_dtype)] = dies
+    with pytest.raises(RuntimeError, match="fell over"):
+        sp.step(fused=True)
+    for sess in (sp.session, sp.draft_session):
+        with pytest.raises(DecodeSessionDead, match="fused_spec"):
+            sess.decode()
+
+
+# -- the kernel over the stacked table, and what each placement donates -----
+
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_kernel_reads_a_layer_of_the_stacked_table(kv, layer):
+    """`decode_attention(..., layer=i)` over [L, N, S, H, D] is the 4-D call
+    on layer i to the bit (the block index map replaces the slice), for the
+    Pallas body and for the plain-XLA fallback alike; so is the head-sliced
+    entry; and a table without a layer (or a layer without a table) is
+    refused."""
+    from paddle_tpu.ops.pallas_kernels import (decode_attention,
+                                               decode_attention_head_slice)
+    rng = np.random.RandomState(11 + layer)
+    L, N, S, H, D = 3, 4, 32, 2, 8
+    q = rng.randn(N, H, D).astype(np.float32)
+    k = rng.randn(L, N, S, H, D)
+    v = rng.randn(L, N, S, H, D)
+    scales = None
+    if kv == "int8":
+        k, v = ((np.clip(t * 40, -127, 127)).astype(np.int8) for t in (k, v))
+        scales = (rng.rand(2, H).astype(np.float32) + 0.5) / 127
+    else:
+        k, v = k.astype(np.float32), v.astype(np.float32)
+    lengths = np.array([1, 9, 32, 0], np.int32)
+    for bkv in (8, 32, 5):            # 5 divides nothing: the fallback
+        want = np.asarray(decode_attention(
+            q, k[layer], v[layer], lengths, block_kv=bkv, kv_scales=scales))
+        got = np.asarray(decode_attention(
+            q, k, v, lengths, block_kv=bkv, kv_scales=scales, layer=layer))
+        assert np.array_equal(got, want)
+    want = np.asarray(decode_attention_head_slice(
+        q[:, 1:], k[layer][:, :, 1:], v[layer][:, :, 1:], lengths, 1, 1,
+        block_kv=8, kv_scales=scales))
+    got = np.asarray(decode_attention_head_slice(
+        q[:, 1:], k[:, :, :, 1:], v[:, :, :, 1:], lengths, 1, 1,
+        block_kv=8, kv_scales=scales, layer=layer))
+    assert np.array_equal(got, want)
+    with pytest.raises(ValueError, match="stacked table"):
+        decode_attention(q, k, v, lengths, kv_scales=scales)
+    with pytest.raises(ValueError, match="stacked table"):
+        decode_attention(q, k[0], v[0], lengths, kv_scales=scales, layer=0)
+
+
+@pytest.mark.parametrize("placement", ["pinned", "gather_mesh", "tp_mesh"])
+def test_what_each_placement_donates(inplace_artifacts, placement):
+    """One device (pinned here; the default one above): the step consumes
+    the table.  A tensor-parallel mesh: its tables stay head-sharded in
+    and out, consumed too.  A gather-mode mesh: `_mesh_wrap` gathers the
+    table and re-shards the result, the call is not donated and
+    `donated_bytes` says 0.  The streams are the same everywhere."""
+    import jax
+    from paddle_tpu.flags import get_flags, set_flags
+    from paddle_tpu.obs import tracing as obs_tracing
+    from paddle_tpu.parallel.mesh import MeshGroup
+    devs = jax.devices()
+    if len(devs) < 2:
+        pytest.skip("needs two devices")
+    saved = get_flags(["mesh_tp"])
+    set_flags({"mesh_tp": placement == "tp_mesh"})
+    was = obs_tracing.enabled()
+    obs_tracing.set_enabled(True)
+    try:
+        device = devs[1] if placement == "pinned" else MeshGroup(devs[:2])
+        pred = GenerativePredictor(inplace_artifacts["default"],
+                                   device=device)
+        assert pred.tp_active == (placement == "tp_mesh")
+        sess = pred.new_session(2)
+        first = sess.prefill(0, [5, 9, 3])
+        k_old = sess._kc
+        obs_tracing.clear()
+        toks = [first] + [int(sess.decode()[0]) for _ in range(3)]
+        donated = [s["attrs"]["donated_bytes"] for s in
+                   obs_tracing.recent_spans(name="decode/launch")
+                   if s["attrs"]["phase"] == "step"]
+    finally:
+        obs_tracing.set_enabled(was)
+        set_flags(saved)
+    both = int(sess._kc.nbytes) + int(sess._vc.nbytes)
+    if placement == "gather_mesh":
+        assert not k_old.is_deleted() and donated == [0, 0, 0]
+    else:
+        assert k_old.is_deleted() and donated == [both] * 3
+        assert sess._inplace == (placement == "pinned")
+    ref, _ = greedy_decode(
+        GenerativePredictor(inplace_artifacts["default"]), [5, 9, 3], 4)
+    assert toks == ref
+
+
+# -- rows padded to the kernel's tile (what one TPU device gets) ------------
+
+@pytest.fixture
+def padded_rows(monkeypatch):
+    """Make every predictor hold its table as ONE TPU device would: rows
+    (H, Dh) rounded up to the (8, 128) tile (`table_row`)."""
+    plain = GenerativePredictor.table_row
+
+    def padded(self):
+        H, Dh = plain(self)
+        return -(-H // 8) * 8, -(-Dh // 128) * 128
+    monkeypatch.setattr(GenerativePredictor, "table_row", padded)
+    return plain
+
+
+@pytest.mark.parametrize("blk,kv", INPLACE_CASES,
+                         ids=["%s-%s" % c for c in INPLACE_CASES])
+def test_padded_rows_serve_the_same_streams(inplace_artifacts, padded_rows,
+                                            blk, kv):
+    """A table of padded rows: the pad is exact zeros and stays so, the
+    tokens are those of the plain table and the logits agree to rounding
+    (a padded lane adds an exact zero to a sum whose order may differ), the
+    step still consumes its table, and the closed-form bytes are the
+    measured ones."""
+    pred = GenerativePredictor(inplace_artifacts[blk], kv_cache_dtype=kv)
+    H, Dh = padded_rows(pred)
+    assert pred.table_row() == (8, 128) and (H, Dh) == (2, 16)
+    sess = pred.new_session(3)
+    assert sess._kc.shape == (2, 3, 32, 8, 128)
+    assert sess.cache_bytes() == pred.kv_cache_bytes(3)
+    prompts = {0: [5, 9, 3, 7], 2: [11, 4]}
+    firsts = {s: sess.prefill(s, p) for s, p in prompts.items()}
+    got = []
+    for _ in range(5):
+        k_old = sess._kc
+        got.append(sess.decode_logits())
+        assert k_old.is_deleted()
+    k, v = _tables(sess)
+    for t in (k, v):
+        assert not t[..., H:, :].any() and not t[..., Dh:].any()
+        assert t[:, 0, :9, :H, :Dh].any() and not t[:, 1].any()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(GenerativePredictor, "table_row", padded_rows)
+        plain = GenerativePredictor(inplace_artifacts[blk],
+                                    kv_cache_dtype=kv)
+        ref = plain.new_session(3)
+        assert ref._kc.shape == (2, 3, 32, 2, 16)
+        assert firsts == {s: ref.prefill(s, p)
+                          for s, p in prompts.items()}
+        for toks, logits in got:
+            want_toks, want = ref.decode_logits()
+            act = ref.active
+            assert np.array_equal(toks[act], want_toks[act])
+            np.testing.assert_allclose(logits[act], want[act],
+                                       rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+def test_padded_rows_fused_window_and_speculative_round(
+        inplace_artifacts, padded_rows, kv):
+    """The phases that carry the table through more than one step, over
+    padded rows: the fused window equals the sequential steps (tables too),
+    and a speculative session (verify lands its chunk through its own
+    one-hot write, rollback zeroes a span) commits the plain stream with
+    every draft accepted."""
+    from paddle_tpu.inference.decode import SpeculativeDecodeSession
+    pred = GenerativePredictor(inplace_artifacts["default"],
+                               kv_cache_dtype=kv)
+    a, b = pred.new_session(2), pred.new_session(2)
+    for sess in (a, b):
+        sess.prefill(0, [5, 9, 3, 7])
+    toks, counts, _ = a.decode_fused(5)
+    seq = [int(b.decode()[0]) for _ in range(5)]
+    assert list(toks[0, :counts[0]]) == seq
+    for x, y in zip(_tables(a), _tables(b)):
+        assert np.array_equal(x, y)
+    a.rollback(0, 2, last_token=seq[2])
+    assert int(a.decode()[0]) == seq[3]
+    target = GenerativePredictor(inplace_artifacts["default"])
+    ref, _ = greedy_decode(target, [5, 9, 3, 7], 10)
+    for fused in (False, True):
+        sp = SpeculativeDecodeSession(target, pred, 2, 2)
+        out = [sp.prefill(0, [5, 9, 3, 7])]
+        while len(out) < 10:
+            g, n = sp.step(fused=fused)
+            out += [int(t) for t in g[0, :n[0]]]
+        assert out[:10] == ref and not sp.degraded
+        if kv == "float32":
+            assert sp.accepted == sp.proposed

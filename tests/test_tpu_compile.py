@@ -151,3 +151,120 @@ def test_routed_ffn_compiles_to_grouped_matmul_kernels(one_chip):
         flops = compiled.cost_analysis()["flops"]
         assert flops < 1.2 * tokens * K * 3 * 2 * D * F + 1e9, flops
         assert compiled.memory_analysis().temp_size_in_bytes < 4e8
+
+
+# ---------------------------------------------------------------------------
+# the decode step as a whole: the slot table is updated in place (PR 27).
+# Every argument is left in the device's OWN layout here, as at run time:
+# jax 0.9 loses a pinned output layout on a persistent-cache hit, so the
+# table must be row-major by the device's choice (`table_row`), not by a pin
+# ---------------------------------------------------------------------------
+
+STEP_MODELS = {
+    # benchmark/configs/gpt2_small.json at its 32 slots and full depth: the
+    # depth matters, XLA's rematerialisation pass misjudges the step only
+    # once the tables pass ~5.6 GB (`decode._TPU_PHASE_OPTIONS`)
+    "gpt2_small": (dict(vocab_size=50257, d_model=768, n_heads=12,
+                        n_layers=12, max_seq_len=1024, eos_id=0), 32),
+    # benchmark/configs/olmoe_1b_7b.json at its 8 slots, depth cut to 2
+    "olmoe_1b_7b": (dict(vocab_size=50304, d_model=2048, n_heads=16,
+                         n_layers=2, max_seq_len=4096, eos_id=0,
+                         norm="rmsnorm", norm_eps=1e-5, position="rope",
+                         rope_theta=10000.0, qk_norm=True,
+                         ffn="moe_swiglu", n_experts=64,
+                         experts_per_token=8, expert_width=1024), 8),
+}
+
+
+def described_predictor(meta, device, kv="float32"):
+    """A GenerativePredictor with no weights, placed on a DESCRIBED chip:
+    enough of it to trace a phase and build the lane's jitted call."""
+    from paddle_tpu.inference import decode as dec
+    pred = object.__new__(dec.GenerativePredictor)
+    pred.meta, pred._block_meta = meta, dec.block_of(meta)
+    pred._kv_dtype, pred._tp_size, pred._device = kv, 0, device
+    pred._kv_scales = None if kv == "float32" else np.full(
+        (2, meta["n_layers"], meta["n_heads"], 1), 0.01, np.float32)
+    on = jax.sharding.SingleDeviceSharding(device)
+    state = {n: jax.ShapeDtypeStruct(s, np.float32, sharding=on)
+             for n, s in dec.decode_state_shapes(meta).items()}
+    return pred, state
+
+
+def compile_phase(pred, state, math_fn, specs):
+    """The phase as `_resolve` builds it (`_phase_jit`: tables donated, the
+    TPU's options), compiled for the described chip with every argument
+    in the device's own layout."""
+    on = jax.sharding.SingleDeviceSharding(pred._device)
+    specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on)
+             for s in specs]
+    with pk.mosaic_lowering():
+        return pred._phase_jit(math_fn, specs).lower(
+            state, *specs).compile()
+
+
+def assert_table_updated_in_place(compiled, table_shape, n_kernels):
+    """What PERF.md (PR 27) predicts of the module: both tables aliased to
+    outputs, temporaries under a tenth of one table, and no select,
+    concatenate, copy, pad or transpose whose result is a whole table or a
+    whole layer."""
+    import re
+    L, N, S, H, D = table_shape
+    table = L * N * S * H * D * 4
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * table, \
+        (ma.alias_size_in_bytes, table)
+    assert ma.temp_size_in_bytes < table / 10, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= n_kernels
+    big = re.compile(r"\[(%d,)?%d,%d,%d,%d\]" % (L, N, S, H, D))
+    bad = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ([\w\-]+)\(", ln)
+        if m and big.search(m.group(2)) and m.group(3) in (
+                "select", "concatenate", "copy", "pad", "transpose"):
+            bad.append((m.group(1), m.group(2), m.group(3)))
+    assert not bad, bad[:6]
+    return text
+
+
+@pytest.mark.parametrize("model", sorted(STEP_MODELS))
+def test_decode_step_updates_the_table_in_place(one_chip, model):
+    meta, slots = STEP_MODELS[model]
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(meta, device)
+    compiled = compile_phase(pred, state, pred._step_math,
+                             pred._step_specs(slots))
+    # rows padded to the kernel's tile: (12, 64) -> (16, 128), (16, 128) as
+    # it is, so that row-major is the device's own layout for the table
+    assert pred.table_row() == (16, 128)
+    assert_table_updated_in_place(compiled, pred.table_shape(slots),
+                                  n_kernels=meta["n_layers"])
+
+
+def test_fused_window_updates_the_table_in_place(one_chip):
+    """The same of the fused window, whose `while` body carries the table:
+    no table- or layer-sized copy inside the loop either."""
+    meta, slots = STEP_MODELS["gpt2_small"]
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(meta, device)
+    i32 = np.dtype(np.int32)
+    specs = pred._step_specs(slots) + (
+        jax.ShapeDtypeStruct((slots,), i32), jax.ShapeDtypeStruct((), i32))
+    compiled = compile_phase(pred, state, pred._fused_step_math(8), specs)
+    text = assert_table_updated_in_place(compiled, pred.table_shape(slots),
+                                         n_kernels=meta["n_layers"])
+    assert " while(" in text
+
+
+def test_int8_table_step_compiles_in_place(one_chip):
+    meta, slots = STEP_MODELS["gpt2_small"]
+    meta = dict(meta, n_layers=2)
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(meta, device, kv="int8")
+    compiled = compile_phase(pred, state, pred._step_math,
+                             pred._step_specs(slots))
+    ma = compiled.memory_analysis()
+    table = int(np.prod(pred.table_shape(slots)))    # int8, rows (16, 128)
+    assert ma.alias_size_in_bytes >= 2 * table
+    assert ma.temp_size_in_bytes < table / 10

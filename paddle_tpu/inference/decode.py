@@ -16,11 +16,16 @@ TOGETHER.  This module is the inference-side half of the answer
   the decoder block (`BLOCK_DEFAULTS`: LayerNorm | RMSNorm, learned |
   rotary positions, qk-norm, ReLU MLP | dropless routed SwiGLU experts);
   an artifact that names none of those keys is the GPT-2-shaped block.
-  ONE per-layer function (`GenerativePredictor._block`) serves prefill
-  and the plain decode step for every block; the speculative verify
-  step, the fused windows, sequence-parallel prefill and the TP lane
-  implement the default block only and raise for any other, naming the
-  meta key;
+  A PHASE is: embed the tokens at their positions, run ONE per-layer
+  function (`GenerativePredictor._block`) once a layer with the phase's
+  own `attend(q, k, v)`, apply the head.  `_block` is the only spelling
+  of a decoder layer and every phase runs every block (prefill, the
+  step, the speculative verify, sequence-parallel prefill, the fused
+  windows); a phase owns only what `attend` does with K and V.  Rows
+  are written to a slot table by ONE scatter (`_land`) and cleared by
+  ONE scatter of zeros (`_clear_rows`).  Only the tensor-parallel
+  placement refuses a block other than the default, naming the meta
+  key: its grammar has no rule for sharding experts;
 * a **prefill / decode phase split** (`GenerativePredictor`): prefill
   runs the whole prompt through the causal forward once per padded
   *prompt bucket* (each bucket's executable rides the persistent
@@ -51,7 +56,7 @@ re-reads the whole cache.  `kv_cache_dtype="int8"` (a `load_model` /
 slots as int8 with per-(layer, head) symmetric fp32 scales calibrated
 once per artifact from a deterministic probe prefill: cache WRITES
 quantize in-graph (prefill, step, and verify all land
-`clip(round(x / scale))` rows), and the decode/verify kernels stream
+`clip(round(x / scale))` rows), and the decode kernel streams
 int8 tiles dequantized in-register (`ops/pallas_kernels.
 decode_attention` — float KV never materializes in HBM), cutting cache
 bytes 4x at equal slots.  The scales are baked constants of the traced
@@ -76,12 +81,12 @@ scores all k+1 positions in ONE fixed-shape batched verify step (its
 executable is one new compile-cache fingerprint per (n_slots, k)).
 The longest greedily-agreeing prefix commits to the target's KV slot
 cache; rejected suffixes roll the slot's length pointer back with the
-stale KV rows zeroed in-graph.  Greedy acceptance keeps the committed
+stale KV rows cleared in-graph.  Greedy acceptance keeps the committed
 stream BIT-IDENTICAL to the fp32-only plain-step stream: every emitted
-token is a target argmax, and the verify step attends through the SAME
-`decode_attention` kernel the plain step runs (each chunk position is
-a pseudo-slot with its own length mask), so verify logits round
-exactly like sequential step logits.  A draft failure mid-round
+token is a target argmax, and the verify step attends through the
+plain step's own `decode_attention` call (once per chunk position,
+under that position's length), so verify logits round
+like sequential step logits.  A draft failure mid-round
 degrades the session to target-only plain decode within that same
 step (`degraded`), never wedging or corrupting a stream.
 
@@ -523,6 +528,37 @@ def _pad_rows(x, row):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, h), (0, d)])
 
 
+def _land(table, layer, where, rows):
+    """`table` [L, N, S, Hp, Dp] with `rows` [N(, C), H, Dh] written at
+    (layer, *where), `where` = (slots, positions) broadcasting to the
+    rows' leading shape: THE write of a decode phase.  A row whose
+    position is S or more lands nowhere and is dropped (no row of
+    zeros, no rewrite of a neighbour), which is how a phase gates an
+    inactive or a full slot; the kept (slot, position) pairs are
+    distinct and in order.  On a donated table it is a write of the
+    rows in place."""
+    return table.at[(layer,) + where].set(
+        _pad_rows(rows, table.shape[3:]).astype(table.dtype), mode="drop",
+        indices_are_sorted=True, unique_indices=True)
+
+
+def _clear_rows(table, lo, hi, width):
+    """`table` with positions lo[n] <= s < hi[n] of every slot n zeroed in
+    all layers, `width` (static) bounding hi - lo: THE way rows leave a
+    slot table short of the slot's release.  A scatter of zeros through
+    `_land`'s gate: the positions outside a slot's range go past the end
+    and are dropped."""
+    import jax.numpy as jnp
+    if not width:
+        return table
+    j = jnp.arange(width)[None]
+    at = lo[:, None] + j
+    at = jnp.where(at < hi[:, None], at, table.shape[2] + j)
+    return table.at[:, jnp.arange(table.shape[1])[:, None], at].set(
+        jnp.zeros((), table.dtype), mode="drop",
+        indices_are_sorted=True, unique_indices=True)
+
+
 # What the TPU's compiler is told for a phase that carries a slot table.
 # Its rematerialisation pass counts every in-place update of the donated
 # table as a NEW table on top of the parameter (two tables of 3.2 GB at
@@ -540,18 +576,18 @@ _SLOT_WRITERS = []
 
 
 def _slot_writers():
-    """(write_rows, zero_slot, zero_rows): the three eager writes of a
+    """(write_rows, zero_slot, clear_rows): the three eager writes of a
     slot table [L, N, S, Hp, Dp], jitted with the table DONATED so that
     they land in place.  `write_rows(table, rows [L, 1, B, H, Dh], slot)`
     puts `rows`, padded to the table's row, at `slot` from position 0 (a
     prefill's K or V);
     `zero_slot(table, slot)` zeroes the slot's whole row (its release);
-    `zero_rows(table, slot, lo, hi)` zeroes its positions lo <= s < hi
-    (a rollback).  Undonated, each was a copy of the whole table (1.2 GB
-    at GPT-2 small with 32 slots: ~3 ms of the device and a transient
-    table in memory), twice for every admission and every release, with
-    the chip's memory nearly full.  `slot`, `lo`, `hi` are traced: one
-    executable per table and bucket."""
+    `clear_rows` is `_clear_rows` (a rollback), one executable per depth.
+    Undonated, each was a copy of the whole table (1.2 GB at GPT-2 small
+    with 32 slots: ~3 ms of the device and a transient table in memory),
+    twice for every admission and every release, with the chip's memory
+    nearly full.  `slot` is traced: one executable per table and
+    bucket."""
     if not _SLOT_WRITERS:
         import jax
         import jax.numpy as jnp
@@ -567,18 +603,10 @@ def _slot_writers():
             return jax.lax.dynamic_update_slice(table, z,
                                                 (0, slot, 0, 0, 0))
 
-        def zero_rows(table, slot, lo, hi):
-            L, _, S, H, Dh = table.shape
-            row = jax.lax.dynamic_slice(table, (0, slot, 0, 0, 0),
-                                        (L, 1, S, H, Dh))
-            pos = jnp.arange(S)[None, None, :, None, None]
-            row = jnp.where((pos >= lo) & (pos < hi),
-                            jnp.zeros((), table.dtype), row)
-            return jax.lax.dynamic_update_slice(table, row,
-                                                (0, slot, 0, 0, 0))
-
         _SLOT_WRITERS.extend(jax.jit(fn, donate_argnums=0)
-                             for fn in (write_rows, zero_slot, zero_rows))
+                             for fn in (write_rows, zero_slot))
+        _SLOT_WRITERS.append(jax.jit(_clear_rows, donate_argnums=0,
+                                     static_argnums=3))
     return _SLOT_WRITERS
 
 
@@ -611,6 +639,18 @@ def _causal_attention(q, k, v, scale):
     return o
 
 
+def _zero_pad_positions(ks, vs, true_len):
+    """A prefill's per-layer K and V [1, B, H, Dh], stacked [L, 1, B, H,
+    Dh] with the positions at or past `true_len` zeroed: the slot cache
+    must hold exact zeros past the live length (free() zeroes, writes are
+    length-gated — this keeps prefill on the same contract)."""
+    import jax.numpy as jnp
+    live = (jnp.arange(ks[0].shape[1])[None, :, None, None]
+            < true_len)[None]            # [1, 1, B, 1, 1]
+    return (jnp.where(live, jnp.stack(ks), 0.0),
+            jnp.where(live, jnp.stack(vs), 0.0))
+
+
 class _TPContext:
     """Trace-time handle threaded through the phase math when the
     program lowers TENSOR-PARALLEL over a mesh replica (`FLAGS.mesh_tp`,
@@ -618,16 +658,15 @@ class _TPContext:
     every weight/KV operand is this member's LOCAL shard; the context
     carries the axis grammar plus the handful of collectives the
     Megatron split needs — one psum per column->row pair, one logits
-    all_gather, the exact masked-gather+psum embedding lookup.
-    `tp=None` (the default everywhere) keeps each math fn's trace
-    byte-identical to the single-device program."""
+    all_gather, the exact masked-gather+psum embedding lookup.  Off a
+    mesh the math runs under `_OFF_MESH`, the context of ONE member,
+    so it is written once and never asks where it runs."""
 
     __slots__ = ("axis", "size")
 
-    def __init__(self, size, axis=None):
-        from paddle_tpu.parallel.mesh import MODEL_AXIS
+    def __init__(self, size, axis):
         self.size = int(size)
-        self.axis = axis or MODEL_AXIS
+        self.axis = axis
 
     def index(self):
         import jax
@@ -672,6 +711,29 @@ class _TPContext:
         ok = (local >= 0) & (local < vl)
         rows = embed_local[jnp.clip(local, 0, vl - 1)]
         return self.psum(jnp.where(ok[..., None], rows, 0.0))
+
+
+class _OneMember(_TPContext):
+    """The context of a program that is not partitioned: every weight
+    and table is whole, and each collective is the identity and traces
+    to nothing, so a phase's module is what its math alone would be."""
+
+    __slots__ = ()
+
+    def psum(self, x):
+        return x
+
+    def all_gather(self, x, axis):
+        return x
+
+    def head_scales(self, scales, n_local):
+        return scales
+
+    def embed_lookup(self, embed, ids):
+        return embed[ids]
+
+
+_OFF_MESH = _OneMember(1, None)
 
 
 class GenerativePredictor:
@@ -834,16 +896,17 @@ class GenerativePredictor:
                 if self._block_meta["ffn"] == "moe_swiglu" else 0)
 
     def _require_default_block(self, what):
-        """Raise for a phase that implements only the GPT-2-shaped
-        block, naming the first meta key that asks for another: prefill
-        and the plain decode step share `_block`; verify, the fused
-        windows, sequence-parallel prefill and the TP lane do not yet."""
+        """Raise for a placement that can hold only the GPT-2-shaped
+        block, naming the first meta key that asks for another.  Every
+        PHASE runs every block (`_block`); the TP lane is the one
+        caller, because its grammar (`parallel/mesh.py`) has no rule
+        for sharding qk-norm gains or experts."""
         for key, default in BLOCK_DEFAULTS:
             if self._block_meta[key] != default:
                 raise NotImplementedError(
                     "%s implements only the default decoder block, and "
-                    "this artifact's meta has %s=%r (prefill and the "
-                    "plain decode step run it)"
+                    "this artifact's meta has %s=%r (every other "
+                    "placement runs it)"
                     % (what, key, self._block_meta[key]))
 
     @property
@@ -981,10 +1044,10 @@ class GenerativePredictor:
     def _quantize_kv(x, scale):
         """Symmetric int8 quantization of fresh K/V rows against the
         calibrated per-head scale: clip(round(x / scale)) as EXACT
-        integer values in fp32 (the caller casts to int8, directly or
-        after the verify path's one-hot scatter — both land the same
-        byte, which is what keeps step and verify rows bit-identical
-        and spec-decode acceptance at 1.0 under the quantized cache)."""
+        integer values in fp32 (the caller casts to int8: `_write` for
+        the step and the verify alike, which is what keeps their rows
+        bit-identical and spec-decode acceptance at 1.0 under the
+        quantized cache)."""
         import jax.numpy as jnp
         return jnp.clip(jnp.round(x / scale), -127.0, 127.0)
 
@@ -1010,7 +1073,7 @@ class GenerativePredictor:
 
         return np.stack([sc(kc), sc(vc)])[..., None]
 
-    def _prefill_math(self, state, tokens, true_len, tp=None):
+    def _prefill_math(self, state, tokens, true_len, tp=_OFF_MESH):
         """The traced prefill phase: `_prefill_core` plus the int8
         cache-write quantization epilogue (zeros quantize to exact
         int8 zeros, so the zero-slot contract is dtype-blind).  Under
@@ -1022,47 +1085,59 @@ class GenerativePredictor:
                                            tp=tp)
         if not self._kv_quant:
             return first, kc, vc
-        sc = self._kv_scales                     # [2, L, H, 1] np
-        if tp is not None:
-            sc = tp.head_scales(sc, kc.shape[3])  # [2, L, Hl, 1]
+        sc = tp.head_scales(self._kv_scales, kc.shape[3])  # [2, L, Hl, 1]
         kq = self._quantize_kv(
             kc, sc[0][:, None, None]).astype(jnp.int8)
         vq = self._quantize_kv(
             vc, sc[1][:, None, None]).astype(jnp.int8)
         return first, kq, vq
 
-    def _tp_seq_parallel(self, bucket):
+    def _tp_seq_parallel(self, bucket, tp):
         """Does this prompt bucket prefill SEQUENCE-parallel under TP?
         Long prompts at a bucket the mesh divides shard the sequence
         axis (ulysses reshard into head-parallel attention, per-layer
         weight all_gathers amortized over the bucket — bit-exact);
         short ones run head/column-parallel like decode (top-1
         contract, no per-layer gathers)."""
-        m = self._tp_size
-        return bool(m and bucket % m == 0
+        return bool(tp.size > 1 and bucket % tp.size == 0
                     and bucket >= self._tp_prefill_seq)
 
-    def _prefill_core(self, state, tokens, true_len, tp=None):
+    def _embed(self, state, tokens, positions, tp):
+        """x [..., D] for `tokens` [...]: the embedding rows (the exact
+        vocab-sharded lookup under TP), plus the position table's rows
+        at `positions` where the block learns its positions (a rotary
+        block turns q and k in `_block` instead).  `positions` indexes
+        the table: an index array, or the slice a prefill's run of
+        consecutive positions is."""
+        x = tp.embed_lookup(state["embed"], tokens)
+        if self._block_meta["position"] == "learned":
+            x = x + state["pos"][positions]
+        return x
+
+    def _head(self, state, x, tp):
+        """logits [..., vocab] of x [..., D]: the final norm and the
+        `lm_head`; under TP the vocab-sharded logits reassemble (exact
+        data movement) before the replicated argmax."""
+        logits = self._norm(x, state, "lnf") @ state["lm_head"]
+        return tp.all_gather(logits, axis=logits.ndim - 1)
+
+    def _prefill_core(self, state, tokens, true_len, tp=_OFF_MESH):
         """tokens [1, B] int32, true_len scalar int32 -> (first_token
         [] int32, k/v [L, 1, B, H, Dh] fp32 with pad positions zeroed).
-        Under TP (`tp` set, inside shard_map) weights are local shards:
+        Under TP (inside shard_map) weights are local shards:
         the returned K/V carry this member's HEAD block [L, 1, B, H/m,
         Dh] (the cache's at-rest layout), attention is head-parallel
         (exact per head), and each column->row pair closes with one
         psum; long buckets divert to the bit-exact sequence-parallel
         body instead."""
         import jax.numpy as jnp
-        L, H, Dh, D = self._dims()
+        L, _, Dh, _ = self._dims()
         B = tokens.shape[1]
         scale = 1.0 / np.sqrt(Dh)
-        if tp is not None and self._tp_seq_parallel(B):
+        if self._tp_seq_parallel(B, tp):
             return self._prefill_core_seqpar(state, tokens, true_len,
                                              tp)
-        blk = self._block_meta
-        x = state["embed"][tokens] if tp is None \
-            else tp.embed_lookup(state["embed"], tokens)
-        if blk["position"] == "learned":
-            x = x + state["pos"][:B][None]
+        x = self._embed(state, tokens, slice(B), tp)
         positions = jnp.arange(B)[None]                     # [1, B]
         ks, vs, facts = [], [], []
 
@@ -1075,23 +1150,11 @@ class GenerativePredictor:
             x, f = self._block(state, "l%d_" % i, x, positions, attend,
                                positions[0] < true_len, tp=tp)
             facts.append(f)
-        logits = self._norm(x, state, "lnf") @ state["lm_head"]
-        if tp is not None:
-            # vocab-sharded logits reassemble (exact data movement)
-            # before the replicated argmax
-            logits = tp.all_gather(logits, axis=2)
-        first = jnp.argmax(logits[0, true_len - 1], axis=-1).astype(
-            jnp.int32)
+        first = jnp.argmax(self._head(state, x, tp)[0, true_len - 1],
+                           axis=-1).astype(jnp.int32)
         if self.routed_layers:
             first = _pack_routing(first, facts)
-        # zero the pad positions: the slot cache must hold exact zeros
-        # past the live length (free() zeroes, writes are length-gated —
-        # this keeps prefill on the same contract)
-        live = (jnp.arange(B)[None, :, None, None]
-                < true_len)[None]            # [1, 1, B, 1, 1]
-        kc = jnp.where(live, jnp.stack(ks), 0.0)
-        vc = jnp.where(live, jnp.stack(vs), 0.0)
-        return first, kc, vc
+        return (first,) + _zero_pad_positions(ks, vs, true_len)
 
     def _norm(self, x, state, name):
         """The block's norm over the last axis with the weights
@@ -1102,21 +1165,21 @@ class GenerativePredictor:
         return _ln(x, state[name + "_g"], state[name + "_b"],
                    blk["norm_eps"])
 
-    def _block(self, state, p, x, positions, attend, live, tp=None):
+    def _block(self, state, p, x, positions, attend, live, tp=_OFF_MESH):
         """ONE decoder layer, as the artifact's meta describes it
-        (BLOCK_DEFAULTS), for prefill and for the decode step alike: x
-        [..., D] with one position per leading index, weights
-        `state[p + name]`.  `attend(q, k, v)` gets the layer's q/k/v
-        [..., Hl, Dh] (normed and rotated where the block says so — the
-        cache holds rotated K) and returns the attention output in q's
-        shape; what it does with k and v (collect them, write them to
-        the slot table) is the phase's.  `live` [tokens] marks the rows
-        a routed FFN counts.  Returns (x', routing facts [2] i32 or
-        None).  Under TP each column->row pair closes with one psum."""
+        (BLOCK_DEFAULTS), for every phase: x [..., D] with one position
+        per leading index, weights `state[p + name]`.  `attend(q, k, v)`
+        gets the layer's q/k/v [..., Hl, Dh] (normed and rotated where
+        the block says so — the cache holds rotated K) and returns the
+        attention output in q's shape; what it does with k and v
+        (collect them, write them to the slot table) is the phase's.
+        `live` [tokens] marks the rows a routed FFN counts.  Returns
+        (x', routing facts [2] i32 or None).  Under TP each column->row
+        pair closes with one psum."""
         import jax.numpy as jnp
         blk = self._block_meta
         _, H, Dh, D = self._dims()
-        Hl = H if tp is None else H // tp.size
+        Hl = H // tp.size
         lead = x.shape[:-1]
         h = self._norm(x, state, p + "ln1")
 
@@ -1131,9 +1194,8 @@ class GenerativePredictor:
         if blk["position"] == "rope":
             q = _rope(q, positions, blk["rope_theta"])
             k = _rope(k, positions, blk["rope_theta"])
-        wo_out = attend(q, k, v).reshape(lead + (Hl * Dh,)) \
-            @ state[p + "wo"]
-        x = x + (wo_out if tp is None else tp.psum(wo_out))
+        x = x + tp.psum(attend(q, k, v).reshape(lead + (Hl * Dh,))
+                        @ state[p + "wo"])
         h2 = self._norm(x, state, p + "ln2")
         if blk["ffn"] == "moe_swiglu":
             y, facts = moe_ffn(
@@ -1144,87 +1206,105 @@ class GenerativePredictor:
             return x + y.reshape(x.shape), facts
         mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
                           0.0) @ state[p + "w2"]
-        return x + (mlp if tp is None else tp.psum(mlp)) \
-            + state[p + "b2"], None
+        return x + tp.psum(mlp) + state[p + "b2"], None
 
     def _prefill_core_seqpar(self, state, tokens, true_len, tp):
         """SEQUENCE-parallel TP prefill (parallel/ulysses.py's scheme):
         each member owns B/m prompt positions; per layer the sharded
         weights all_gather back whole (exact data movement, amortized
         over the long bucket — prefill is compute-bound, unlike
-        decode), attention rides the ulysses seq<->heads all_to_all
-        pair around the SAME `_causal_attention` oracle, and K/V
-        all_to_all into the head-sharded cache layout.  Every
-        position's math runs with FULL weights in the single-device
-        reduction order, so this path is BIT-EXACT vs the oracle — no
-        psum ever touches an activation."""
+        decode) and `_block` runs on them as off a mesh, its attention
+        the ulysses seq<->heads all_to_all pair around the SAME
+        `_causal_attention` oracle, whose head-sharded K/V are the
+        cache's at-rest layout.  Every position's math runs with FULL
+        weights in the single-device reduction order, so this path is
+        BIT-EXACT vs the oracle — no psum ever touches an activation."""
         import jax
         import jax.numpy as jnp
+        from paddle_tpu.parallel.mesh import tp_param_pspec
         from paddle_tpu.parallel.ulysses import (heads_to_seq,
                                                  seq_to_heads)
-        self._require_default_block("sequence-parallel TP prefill")
-        L, H, Dh, D = self._dims()
-        B = tokens.shape[1]
-        m = tp.size
-        Bl = B // m
+        L, _, Dh, _ = self._dims()
+        Bl = tokens.shape[1] // tp.size
         scale = 1.0 / np.sqrt(Dh)
-        idx = tp.index()
-        tok_l = jax.lax.dynamic_slice(tokens, (0, idx * Bl), (1, Bl))
-        pos_l = jax.lax.dynamic_slice(
-            state["pos"], (idx * Bl, jnp.int32(0)),
-            (Bl, state["pos"].shape[1]))
-        x = tp.embed_lookup(state["embed"], tok_l) + pos_l[None]
+        at = tp.index() * Bl
+        positions = (at + jnp.arange(Bl))[None]              # [1, Bl]
+        x = self._embed(
+            state, jax.lax.dynamic_slice(tokens, (0, at), (1, Bl)),
+            positions, tp)
         ks, vs = [], []
+
+        def whole(*prefixes):
+            out = {}
+            for n in state:
+                if n.startswith(prefixes):
+                    axes = [a for a, ax in enumerate(
+                        tp_param_pspec(n, state[n].shape)) if ax]
+                    out[n] = tp.all_gather(state[n], axis=axes[0]) \
+                        if axes else state[n]
+            return out
+
+        def attend(q, k, v):
+            # seq->heads: full sequence, resident head block (exact)
+            q, k, v = (seq_to_heads(t, tp.axis) for t in (q, k, v))
+            ks.append(k)
+            vs.append(v)
+            return heads_to_seq(_causal_attention(q, k, v, scale),
+                                tp.axis)
+
         for i in range(L):
             p = "l%d_" % i
-            wq = tp.all_gather(state[p + "wq"], axis=1)
-            wk = tp.all_gather(state[p + "wk"], axis=1)
-            wv = tp.all_gather(state[p + "wv"], axis=1)
-            wo = tp.all_gather(state[p + "wo"], axis=0)
-            w1 = tp.all_gather(state[p + "w1"], axis=1)
-            b1 = tp.all_gather(state[p + "b1"], axis=0)
-            w2 = tp.all_gather(state[p + "w2"], axis=0)
-            h = _ln(x, state[p + "ln1_g"], state[p + "ln1_b"])
-            q = (h @ wq).reshape(1, Bl, H, Dh)
-            k = (h @ wk).reshape(1, Bl, H, Dh)
-            v = (h @ wv).reshape(1, Bl, H, Dh)
-            # seq->heads: full sequence, resident head block (exact)
-            qh = seq_to_heads(q, tp.axis)        # [1, B, H/m, Dh]
-            kh = seq_to_heads(k, tp.axis)
-            vh = seq_to_heads(v, tp.axis)
-            atth = _causal_attention(qh, kh, vh, scale)
-            att = heads_to_seq(atth, tp.axis).reshape(1, Bl, D)
-            x = x + att @ wo
-            h2 = _ln(x, state[p + "ln2_g"], state[p + "ln2_b"])
-            x = x + jnp.maximum(h2 @ w1 + b1, 0.0) @ w2 \
-                + state[p + "b2"]
-            # the cache's at-rest layout IS the post-reshard one: full
-            # sequence, this member's heads
-            ks.append(kh)
-            vs.append(vh)
+            x, _ = self._block(whole(p), p, x, positions, attend,
+                               positions[0] < true_len)
         xg = tp.all_gather(x, axis=1)            # [1, B, D] whole
-        lm = tp.all_gather(state["lm_head"], axis=1)
-        logits = _ln(xg, state["lnf_g"], state["lnf_b"]) @ lm
-        first = jnp.argmax(logits[0, true_len - 1], axis=-1).astype(
-            jnp.int32)
-        live = (jnp.arange(B)[None, :, None, None]
-                < true_len)[None]            # [1, 1, B, 1, 1]
-        kc = jnp.where(live, jnp.stack(ks), 0.0)
-        vc = jnp.where(live, jnp.stack(vs), 0.0)
-        return first, kc, vc
+        logits = self._head(whole("lnf_", "lm_head"), xg, _OFF_MESH)
+        first = jnp.argmax(logits[0, true_len - 1],
+                           axis=-1).astype(jnp.int32)
+        return (first,) + _zero_pad_positions(ks, vs, true_len)
 
-    def _row_scales(self, i, row):
-        """Layer i's int8 dequant scales [2, Hp] for the kernel over a
-        table of rows `row`: the calibrated ones, 1 for padded heads
-        (their rows are zeros); None for a float cache."""
-        if not self._kv_quant:
-            return None
-        sc = np.asarray(self._kv_scales)[:, i, :, 0]
-        return np.pad(sc, ((0, 0), (0, row[0] - sc.shape[1])),
-                      constant_values=1.0)
+    def _write(self, kc, vc, i, where, k_new, v_new, tp):
+        """(kc', vc'): layer i's new rows `_land`ed at `where` in the
+        carried tables.  Under int8 they quantize in-graph first
+        (every phase through here, so a row is the same byte whichever
+        phase wrote it) and the attention dequantizes in-register —
+        float KV rows never reach the cache arrays."""
+        if self._kv_quant:
+            sc = tp.head_scales(self._kv_scales[:, i], k_new.shape[-2])
+            k_new = self._quantize_kv(k_new, sc[0])
+            v_new = self._quantize_kv(v_new, sc[1])
+        return _land(kc, i, where, k_new), _land(vc, i, where, v_new)
+
+    def _attend_table(self, q, kc, vc, lengths, ahead, i, tp):
+        """The decode kernel over layer i of the carried tables: q
+        [N, Hl, Dh], slot n under its first `lengths[n] + ahead`
+        positions -> [N, Hl, Dh].  The kernel reads the layer of the
+        stacked table through its block index maps
+        (`decode_attention(..., layer=i)`): no layer is sliced out."""
+        from paddle_tpu.ops.pallas_kernels import (
+            decode_attention, decode_attention_head_slice)
+        row = kc.shape[3:]
+        Hl, Dh = q.shape[1:]
+        scale = 1.0 / np.sqrt(Dh)
+        scales = self._kv_scales[:, i] if self._kv_quant else None
+        if tp.size > 1:
+            # a mesh keeps the plain row; each member slices its heads'
+            # scales out of the baked full table
+            return decode_attention_head_slice(
+                q, kc, vc, lengths + ahead, tp.index() * Hl, Hl,
+                scale=scale, kv_scales=scales, layer=i)
+        # the table's rows may be padded to the kernel's tile
+        # (`table_row`): q goes in padded alike (zero heads, zero lanes),
+        # a padded head's scale is 1 (its rows are zeros), and the pad
+        # comes off the result
+        if scales is not None:
+            scales = np.pad(np.asarray(scales)[..., 0],
+                            ((0, 0), (0, row[0] - Hl)), constant_values=1.0)
+        return decode_attention(
+            _pad_rows(q, row), kc, vc, lengths + ahead, scale=scale,
+            kv_scales=scales, layer=i)[:, :Hl, :Dh]
 
     def _step_math(self, state, kc, vc, lengths, last_tokens, active,
-                   tp=None):
+                   tp=_OFF_MESH):
         """One greedy decode step: `_step_core` + argmax ->
         (new_tokens [N] i32, kc', vc').  A routed-expert artifact's
         first result carries the call's routing facts behind the N
@@ -1238,234 +1318,136 @@ class GenerativePredictor:
         return toks, kc, vc
 
     def _step_logits(self, state, kc, vc, lengths, last_tokens, active,
-                     tp=None):
+                     tp=_OFF_MESH):
         """`_step_core` without the routing facts: (logits [N, vocab]
         f32, kc', vc')."""
         return self._step_core(state, kc, vc, lengths, last_tokens,
                                active, tp=tp)[:3]
 
+    def _step_tokens(self, state, kc, vc, lengths, last_tokens, active,
+                     tp):
+        """`_step_math` without the routing facts, for the fused
+        windows: a routed FFN runs, what it touched is dropped."""
+        import jax.numpy as jnp
+        logits, kc, vc = self._step_logits(
+            state, kc, vc, lengths, last_tokens, active, tp=tp)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc
+
     def _step_core(self, state, kc, vc, lengths, last_tokens, active,
-                   tp=None):
+                   tp=_OFF_MESH):
         """One fixed-shape decode step over the whole slot table.
-        kc/vc [L, N, S, H, Dh] (fp32, or int8 under the quantized
+        kc/vc [L, N, S, Hp, Dp] (fp32, or int8 under the quantized
         cache), lengths [N] i32 (live cached positions), last_tokens
         [N] i32, active [N] bool -> (logits [N, vocab] f32, kc', vc',
         per-layer routing facts).  Each layer is `_block` at position
         `lengths` (a slot's own), its attention the write of the new row
-        and the decode kernel over the slot table.  The table's rows are
-        [Hp, Dp] >= [H, Dh] (`table_row`: padded to the kernel's tile on
-        one TPU device); the table's shape is all this function knows
-        of that.
+        and the decode kernel over the slot table.
 
         The table is CARRIED through the layers and updated IN PLACE:
-        layer i scatters its N new rows [N, H, Dh] to (i, n,
-        lengths[n]) of the stacked table and the kernel reads layer i
-        of that same table through its block index maps
-        (`decode_attention(..., layer=i)`).  No layer is selected,
-        sliced out or stacked back, so with the table donated (every
-        phase that returns it donates it: `_phase_jit`) the step's
-        input and output are ONE buffer and what it writes is N rows a
-        layer.  Nobody else may hold the table: `DecodeSession` replaces
-        its `_kc`/`_vc` by each call's results.
+        layer i scatters its N new rows to (i, n, lengths[n]) of the
+        stacked table (`_land`) and the kernel reads layer i of that
+        same table (`_attend_table`).  No layer is selected, sliced out
+        or stacked back, so with the table donated (every phase that
+        returns it donates it: `_phase_jit`) the step's input and output
+        are ONE buffer and what it writes is N rows a layer.  Nobody
+        else may hold the table: `DecodeSession` replaces its
+        `_kc`/`_vc` by each call's results.
 
         Cache writes are gated by `active`: an inactive slot's row goes
-        to position S, out of range, and is DROPPED (no row of zeros,
-        no rewrite of a neighbour), as is the row of a slot already at
-        `lengths == S`; so a freed (zeroed) slot stays zero and per-slot
-        independence is exact.  Under int8, fresh K/V rows quantize
-        in-graph before landing and the attention dequantizes
-        in-register — float KV rows never reach the cache arrays.
+        to position S, out of range, and is DROPPED, as is the row of a
+        slot already at `lengths == S`; so a freed (zeroed) slot stays
+        zero and per-slot independence is exact.
 
-        Under TP (`tp` set, inside shard_map) kc/vc are this member's
-        resident HEAD shard and weights are local column/row shards:
-        attention runs the head-sliced decode kernel on the local
-        block (exact per head — heads are independent), each
-        column->row pair closes with ONE psum, and the vocab-sharded
-        logits all_gather before the argmax — params and KV never
-        materialize unsharded, per-step HBM traffic per member
+        Under TP (inside shard_map) kc/vc are this member's resident
+        HEAD shard and weights are local column/row shards — params and
+        KV never materialize unsharded, per-step HBM traffic per member
         ~1/mesh_size."""
         import jax.numpy as jnp
-        from paddle_tpu.ops.pallas_kernels import (
-            decode_attention, decode_attention_head_slice)
-        L, H, Dh, _ = self._dims()
-        N, S, row = kc.shape[1], kc.shape[2], kc.shape[3:]
-        quant = self._kv_quant
-        scale = 1.0 / np.sqrt(Dh)
-        Hl = H if tp is None else H // tp.size
-        x = state["embed"][last_tokens] if tp is None \
-            else tp.embed_lookup(state["embed"], last_tokens)   # [N, D]
-        if self._block_meta["position"] == "learned":
-            x = x + state["pos"][lengths]
-        slots = jnp.arange(N)
+        L = self._dims()[0]
+        N, S = kc.shape[1], kc.shape[2]
+        x = self._embed(state, last_tokens, lengths, tp)        # [N, D]
         # where a slot's new row lands; S (past the end) = nowhere
-        at = jnp.where(active, lengths, S).astype(jnp.int32)    # [N]
+        where = (jnp.arange(N),
+                 jnp.where(active, lengths, S).astype(jnp.int32))
         facts = []
-
-        def land(t, i, rows):
-            return t.at[i, slots, at].set(
-                _pad_rows(rows, row).astype(t.dtype), mode="drop",
-                indices_are_sorted=True, unique_indices=True)
-
         for i in range(L):
             def attend(q, k_new, v_new, i=i):
                 nonlocal kc, vc
-                if quant:
-                    sc_i = self._kv_scales[:, i] if tp is None \
-                        else tp.head_scales(self._kv_scales[:, i], Hl)
-                    k_new = self._quantize_kv(k_new, sc_i[0])
-                    v_new = self._quantize_kv(v_new, sc_i[1])
-                kc, vc = land(kc, i, k_new), land(vc, i, v_new)
-                if tp is None:
-                    # the table's rows may be padded to the kernel's
-                    # tile (`table_row`): q goes in padded alike (zero
-                    # heads, zero lanes) and the pad comes off the result
-                    return decode_attention(
-                        _pad_rows(q, row), kc, vc, lengths + 1,
-                        scale=scale, kv_scales=self._row_scales(i, row),
-                        layer=i)[:, :H, :Dh]
-                return decode_attention_head_slice(
-                    q, kc, vc, lengths + 1, tp.index() * Hl, Hl,
-                    scale=scale,
-                    kv_scales=self._kv_scales[:, i] if quant else None,
-                    layer=i)
+                kc, vc = self._write(kc, vc, i, where, k_new, v_new, tp)
+                return self._attend_table(q, kc, vc, lengths, 1, i, tp)
 
             x, f = self._block(state, "l%d_" % i, x, lengths, attend,
                                active, tp=tp)
             facts.append(f)
-        logits = self._norm(x, state, "lnf") @ state["lm_head"]
-        if tp is not None:
-            logits = tp.all_gather(logits, axis=1)
-        return logits, kc, vc, facts
+        return self._head(state, x, tp), kc, vc, facts
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
-                     tp=None):
+                     tp=_OFF_MESH):
         """One speculative VERIFY step over the whole slot table:
         tokens [N, C] = [pending last token, draft d1..dk] (C = k+1),
         -> (g [N, C] target greedy tokens per position, m [N] accepted
         draft counts 0..k, kc', vc').
 
-        Scores all C positions in one fixed-shape launch: the chunk's
-        Q/K/V come from ONE batched projection (weights stream once for
-        all C positions — the step-latency/bandwidth win), all C rows
-        land in the slot cache first (the step path's write-before-
-        attend order), and every chunk position then attends through
-        the SAME `decode_attention` kernel the plain decode step runs —
-        position j is a pseudo-slot over the same S-length cache axis
-        masked to length+j+1.  Same kernel, same axis geometry, same
-        masking semantics => verify logits round exactly like the
-        sequential plain-step logits, which is what makes greedy
-        acceptance bit-exact against the fp32-only stream.
+        The step's phase over C positions a slot: x is [N, C, D] at
+        positions `lengths + j`, so the chunk's projections and FFN run
+        ONCE (weights stream once for all C positions — the bandwidth
+        win); a layer's `attend` lands all C rows a slot in the carried
+        table with the step's write, an inactive slot's past the end,
+        and position j of every slot then attends through the step's
+        own kernel call under `lengths + j + 1` — C calls of the plain
+        step's shape, so verify logits round like C sequential steps',
+        which is what makes greedy acceptance exact against the
+        fp32-only stream.  A routed FFN runs; its routing facts are
+        dropped.
 
         Acceptance and rollback are in-graph: m = longest prefix with
-        d_i == g_{i-1}; rows past length+m (the rejected suffix) are
-        zeroed before the caches return, so stale draft K/V never
-        survives into the committed cache.
-
-        Under TP the same head-parallel discipline as `_step_math`
-        applies: local head shards through the head-sliced kernel, one
-        psum per pair, logits all_gather — the spec-decode round's
-        verify rides the partitioned program unchanged."""
+        d_i == g_{i-1}; the rows past length+m (the rejected suffix)
+        are cleared by one scatter of zeros over all layers before the
+        tables return, so stale draft K/V never survives into the
+        committed cache."""
         import jax.numpy as jnp
-        from paddle_tpu.ops.pallas_kernels import (
-            decode_attention, decode_attention_head_slice)
-        self._require_default_block("the speculative verify step")
-        L, H, Dh, D = self._dims()
+        L = self._dims()[0]
         N, C = tokens.shape
-        S, row = kc.shape[2], kc.shape[3:]
-        quant = self._kv_quant
-        scale = 1.0 / np.sqrt(Dh)
-        Hl = H if tp is None else H // tp.size
-        pos_idx = lengths[:, None] + jnp.arange(C)[None]        # [N, C]
-        if tp is None:
-            x = state["embed"][tokens] + state["pos"][pos_idx]  # [N,C,D]
-        else:
-            x = tp.embed_lookup(state["embed"], tokens) \
-                + state["pos"][pos_idx]
-        write = (jnp.arange(S)[None, None, :]
-                 == pos_idx[:, :, None]) & active[:, None, None]
-        written = jnp.any(write, axis=1)[:, :, None, None]      # [N,S,1,1]
-        qlens = (pos_idx + 1).reshape(N * C).astype(jnp.int32)
-        kcs, vcs = [], []
+        S = kc.shape[2]
+        ahead = jnp.arange(C)[None]
+        positions = lengths[:, None] + ahead                    # [N, C]
+        x = self._embed(state, tokens, positions, tp)           # [N,C,D]
+        where = (jnp.arange(N)[:, None],
+                 jnp.where(active[:, None], positions, S + ahead))
+        live = jnp.repeat(active, C)
         for i in range(L):
-            p = "l%d_" % i
-            h = _ln(x, state[p + "ln1_g"], state[p + "ln1_b"])
-            q = (h @ state[p + "wq"]).reshape(N, C, Hl, Dh)
-            k_new = (h @ state[p + "wk"]).reshape(N, C, Hl, Dh)
-            v_new = (h @ state[p + "wv"]).reshape(N, C, Hl, Dh)
-            if quant:
-                # quantize BEFORE the scatter: the one-hot contraction
-                # moves exact fp32 integer values, so the int8 cast
-                # lands the same byte a sequential step write would —
-                # verify rows == step rows bit-for-bit
-                sc_i = self._kv_scales[:, i] if tp is None \
-                    else tp.head_scales(self._kv_scales[:, i], Hl)
-                k_new = self._quantize_kv(k_new, sc_i[0])
-                v_new = self._quantize_kv(v_new, sc_i[1])
-            # land all C rows (positions are distinct, so the scatter
-            # contraction adds exact zeros around one exact value)
-            wf = write.astype(k_new.dtype)
-            ksc = jnp.einsum("ncs,nchd->nshd", wf, _pad_rows(k_new, row))
-            vsc = jnp.einsum("ncs,nchd->nshd", wf, _pad_rows(v_new, row))
-            if quant:
-                ksc = ksc.astype(jnp.int8)
-                vsc = vsc.astype(jnp.int8)
-            kci = jnp.where(written, ksc, kc[i])
-            vci = jnp.where(written, vsc, vc[i])
-            kx = jnp.broadcast_to(
-                kci[:, None], (N, C, S) + row).reshape((N * C, S) + row)
-            vx = jnp.broadcast_to(
-                vci[:, None], (N, C, S) + row).reshape((N * C, S) + row)
-            if tp is None:
-                att = decode_attention(
-                    _pad_rows(q.reshape(N * C, Hl, Dh), row), kx, vx,
-                    qlens, scale=scale,
-                    kv_scales=self._row_scales(i, row))[:, :H, :Dh]
-            else:
-                att = decode_attention_head_slice(
-                    q.reshape(N * C, Hl, Dh), kx, vx, qlens,
-                    tp.index() * Hl, Hl, scale=scale,
-                    kv_scales=self._kv_scales[:, i] if quant else None)
-            wo_out = att.reshape(N, C, Hl * Dh) @ state[p + "wo"]
-            x = x + (wo_out if tp is None else tp.psum(wo_out))
-            h2 = _ln(x, state[p + "ln2_g"], state[p + "ln2_b"])
-            mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
-                              0.0) @ state[p + "w2"]
-            x = x + (mlp if tp is None else tp.psum(mlp)) \
-                + state[p + "b2"]
-            kcs.append(kci)
-            vcs.append(vci)
-        logits = _ln(x, state["lnf_g"], state["lnf_b"]) @ state["lm_head"]
-        if tp is not None:
-            logits = tp.all_gather(logits, axis=2)
-        g = jnp.argmax(logits, axis=-1).astype(jnp.int32)       # [N, C]
+            def attend(q, k_new, v_new, i=i):
+                nonlocal kc, vc
+                kc, vc = self._write(kc, vc, i, where, k_new, v_new, tp)
+                return jnp.stack(
+                    [self._attend_table(q[:, j], kc, vc, lengths, j + 1,
+                                        i, tp) for j in range(C)], axis=1)
+
+            x, _ = self._block(state, "l%d_" % i, x, positions, attend,
+                               live, tp=tp)
+        g = jnp.argmax(self._head(state, x, tp),
+                       axis=-1).astype(jnp.int32)               # [N, C]
         match = (tokens[:, 1:] == g[:, :C - 1]).astype(jnp.int32)
         m = jnp.sum(jnp.cumprod(match, axis=1), axis=1).astype(jnp.int32)
-        # rejected suffix: the committed cache keeps rows for the
-        # pending token + the m accepted drafts (length + m + 1 rows
-        # total); everything this step wrote past that is zeroed
-        posS = jnp.arange(S)[None, :]
-        stale = (posS >= (lengths + m + 1)[:, None]) \
-            & (posS < (lengths + C)[:, None]) & active[:, None]
-        stale_m = stale[None, :, :, None, None]
-        kall = jnp.stack(kcs)
-        vall = jnp.stack(vcs)
-        # select, not multiply-by-mask: exact zeros either way for
-        # fp32, and int8 caches cannot ride a float multiply
-        zero = jnp.zeros((), kall.dtype)
-        return (g, m, jnp.where(stale_m, zero, kall),
-                jnp.where(stale_m, zero, vall))
+        # the committed cache keeps rows for the pending token + the m
+        # accepted drafts (length + m + 1 rows); the rest of the chunk
+        # goes
+        lo, hi = lengths + m + 1, jnp.where(active, lengths + C, 0)
+        return (g, m, _clear_rows(kc, lo, hi, C - 1),
+                _clear_rows(vc, lo, hi, C - 1))
 
-    def _fused_step_math(self, n_steps, tp=None):
+    def _fused_step_math(self, n_steps, tp=_OFF_MESH):
         """Build the FUSED multi-step decode phase (SERVING.md "Fused
         multi-step decode"): up to `n_steps` plain decode steps run as
         ONE compiled executable — a `lax.while_loop` carrying {KV
         cache, lengths, last_tokens, per-slot running masks} through
         step+argmax+KV-write per trip, with in-graph early exit the
         moment no slot is still running.  Per-trip the body is EXACTLY
-        `_step_math` (same kernel, same masking, same write order), so
-        a fused stream is bit-identical to `n_steps` sequential
-        `decode()` calls — the per-slot independence that makes batched
-        decode bit-exact makes fusion bit-exact too.
+        the plain step (`_step_tokens`: same kernel, same masking, same
+        write order; a routed block's facts are dropped), so a fused
+        stream is bit-identical to `n_steps` sequential `decode()`
+        calls — the per-slot independence that makes batched decode
+        bit-exact makes fusion bit-exact too.
 
         Runtime args (the executable stays one fingerprint per
         (n_slots, n_steps) geometry):
@@ -1481,7 +1463,6 @@ class GenerativePredictor:
         block, `emitted[s]` of them valid per slot, in stream order."""
         import jax
         import jax.numpy as jnp
-        self._require_default_block("the fused multi-step decode window")
         n_steps = int(n_steps)
         eos = self.eos_id
 
@@ -1501,8 +1482,8 @@ class GenerativePredictor:
 
             def body(carry):
                 i, kc, vc, lengths, last, emitted, toks, running = carry
-                tok, kc, vc = self._step_math(state, kc, vc, lengths,
-                                              last, running, tp=tp)
+                tok, kc, vc = self._step_tokens(state, kc, vc, lengths,
+                                                last, running, tp)
                 # land this trip's tokens at column i (one-hot select —
                 # stopped slots keep their block rows untouched)
                 col = (jnp.arange(n_steps)[None, :] == i) \
@@ -1525,7 +1506,7 @@ class GenerativePredictor:
 
         return fused
 
-    def _fused_spec_math(self, draft, spec_k, tp=None):
+    def _fused_spec_math(self, draft, spec_k, tp=_OFF_MESH):
         """Build the FUSED speculative round: k draft decode steps +
         the batched k+1-position verify + in-graph accept / draft-
         rollback / draft-catch-up bookkeeping, all ONE executable (one
@@ -1536,29 +1517,25 @@ class GenerativePredictor:
         on one executable.
 
         Every sub-phase is the same traced math the host-driven round
-        runs (`draft._step_math` per draft trip, `self._verify_math`
-        for scoring, the rollback zeroing mirrors `DecodeSession.
-        rollback`), so committed streams stay bit-identical to the
-        fp32-only plain stream and twin-draft acceptance stays exactly
-        1.0."""
+        runs (`draft._step_tokens` per draft trip, `self._verify_math`
+        for scoring, `_clear_rows` for the draft's rollback as in
+        `DecodeSession.rollback`), so committed streams stay
+        bit-identical to the fp32-only plain stream and twin-draft
+        acceptance stays exactly 1.0."""
         import jax.numpy as jnp
-        self._require_default_block("the fused speculative round")
-        draft._require_default_block("the fused speculative round's "
-                                     "draft")
         k = int(spec_k)
 
         def fused(state, dstate, t_kc, t_vc, t_len, t_last,
                   d_kc, d_vc, d_len, d_last, active):
             N = t_kc.shape[1]
-            Sd = d_kc.shape[2]
             adv = active.astype(jnp.int32)
             rows = jnp.arange(N)
             # 1. DRAFT: k steps on the draft table (unrolled — k is a
             # geometry constant of this executable)
             drafts = []
             for _ in range(k):
-                dtok, d_kc, d_vc = draft._step_math(
-                    dstate, d_kc, d_vc, d_len, d_last, active, tp=tp)
+                dtok, d_kc, d_vc = draft._step_tokens(
+                    dstate, d_kc, d_vc, d_len, d_last, active, tp)
                 d_len = d_len + adv
                 d_last = jnp.where(active, dtok, d_last)
                 drafts.append(dtok)
@@ -1573,25 +1550,20 @@ class GenerativePredictor:
             t_last = jnp.where(active, g[rows, jnp.minimum(m, k)],
                                t_last)
             # draft sync, in-graph: partially-accepted slots roll the
-            # rejected rows back (zeroed, length pointer retreats,
+            # rejected rows back (cleared, length pointer retreats,
             # pending token re-pins to the target's correction)...
             part = active & (m < k)
-            nback = jnp.where(part, k - 1 - m, 0)
-            newlen = d_len - nback
-            posS = jnp.arange(Sd)[None, :]
-            stale = (posS >= newlen[:, None]) & (posS < d_len[:, None])
-            stale_m = stale[None, :, :, None, None]
-            zero = jnp.zeros((), d_kc.dtype)
-            d_kc = jnp.where(stale_m, zero, d_kc)
-            d_vc = jnp.where(stale_m, zero, d_vc)
+            newlen = d_len - jnp.where(part, k - 1 - m, 0)
+            d_kc = _clear_rows(d_kc, newlen, d_len, k - 1)
+            d_vc = _clear_rows(d_vc, newlen, d_len, k - 1)
             d_len = newlen
             d_last = jnp.where(part, g[rows, jnp.minimum(m, k)], d_last)
             # ...and fully-accepted slots owe the draft one catch-up
             # step (it emitted d_k without ever consuming it), pending
             # token re-pinned to the target's bonus token
             full = active & (m == k)
-            _cu, d_kc, d_vc = draft._step_math(
-                dstate, d_kc, d_vc, d_len, d_last, full, tp=tp)
+            _cu, d_kc, d_vc = draft._step_tokens(
+                dstate, d_kc, d_vc, d_len, d_last, full, tp)
             d_len = d_len + full.astype(jnp.int32)
             d_last = jnp.where(full, g[:, k], d_last)
             return (g, m, t_kc, t_vc, t_len, t_last,
@@ -1621,15 +1593,16 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (4: the
-            # step scatters its rows into the carried table; a stored
-            # step of the `where`/`stack` math must miss)
+            # rev bumps when the phase math itself changes shape (5:
+            # verify and the fused rounds scatter their rows into the
+            # carried table as the step does since 4; a stored phase of
+            # the `where`/`stack` math must miss)
             "kv_dtype": self._kv_dtype,
             # so do the block's keys (norm, position, qk-norm, FFN kind
             # and routing): equal weight shapes, another function
             "block": [[k, self._block_meta[k]]
                       for k in sorted(self._block_meta)],
-            "rev": 4,
+            "rev": 5,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -1683,7 +1656,8 @@ class GenerativePredictor:
         return as_mesh_group(self._device)
 
     def _tp_ctx(self):
-        return _TPContext(self._tp_size)
+        from paddle_tpu.parallel.mesh import MODEL_AXIS
+        return _TPContext(self._tp_size, MODEL_AXIS)
 
     def _tp_math(self, math_fn):
         """The per-member tensor-parallel body for a phase math fn, or
@@ -1739,7 +1713,7 @@ class GenerativePredictor:
         tables head-sharded (axis 3 — `tp_supported` guarantees heads
         divide, so this coincides with the at-rest `kv_sharding`),
         scalars/token tables replicated.  Output specs come from
-        eval_shape of the plain (tp=None) math — the TP body returns
+        eval_shape of the plain (off-mesh) math — the TP body returns
         the same tree, with 5-D caches staying head-sharded and
         everything else fully reduced (psum/all_gather) hence
         replicated."""
@@ -1907,12 +1881,9 @@ class GenerativePredictor:
         other phase (COMPILE_CACHE.md)."""
         import jax
         n, C = int(n_slots), int(spec_k) + 1
-        cache = jax.ShapeDtypeStruct(self.table_shape(n),
-                                     self._cache_np_dtype())
-        specs = (cache, cache,
-                 jax.ShapeDtypeStruct((n,), np.dtype(np.int32)),
-                 jax.ShapeDtypeStruct((n, C), np.dtype(np.int32)),
-                 jax.ShapeDtypeStruct((n,), np.dtype(bool)))
+        cache, _, lengths, _, active = self._step_specs(n)
+        specs = (cache, cache, lengths,
+                 jax.ShapeDtypeStruct((n, C), np.dtype(np.int32)), active)
         return self._resolve(("verify", n, C), self._verify_math, specs,
                              tp_math=self._tp_math(self._verify_math))
 
@@ -1927,15 +1898,9 @@ class GenerativePredictor:
         n, T = int(n_slots), int(n_steps)
         if T < 1:
             raise ValueError("fuse window must be >= 1, got %d" % T)
-        cache = jax.ShapeDtypeStruct(self.table_shape(n),
-                                     self._cache_np_dtype())
         i32 = np.dtype(np.int32)
-        specs = (cache, cache,
-                 jax.ShapeDtypeStruct((n,), i32),
-                 jax.ShapeDtypeStruct((n,), i32),
-                 jax.ShapeDtypeStruct((n,), np.dtype(bool)),
-                 jax.ShapeDtypeStruct((n,), i32),
-                 jax.ShapeDtypeStruct((), i32))
+        specs = self._step_specs(n) + (jax.ShapeDtypeStruct((n,), i32),
+                                       jax.ShapeDtypeStruct((), i32))
         tp_math = (self._fused_step_math(T, tp=self._tp_ctx())
                    if self._tp_size else None)
         return self._resolve(("fused_step", n, T),
@@ -1951,21 +1916,13 @@ class GenerativePredictor:
         executable."""
         import jax
         n, C = int(n_slots), int(spec_k) + 1
-        i32 = np.dtype(np.int32)
-        cache = jax.ShapeDtypeStruct(self.table_shape(n),
-                                     self._cache_np_dtype())
-        dcache = jax.ShapeDtypeStruct(draft.table_shape(n),
-                                      draft._cache_np_dtype())
+        cache, _, i32n, _, active = self._step_specs(n)
+        dcache = draft._step_specs(n)[0]
         dstate = {name: jax.ShapeDtypeStruct(np.shape(v),
                                              np.asarray(v).dtype)
                   for name, v in draft._state_host.items()}
-        specs = (dstate, cache, cache,
-                 jax.ShapeDtypeStruct((n,), i32),
-                 jax.ShapeDtypeStruct((n,), i32),
-                 dcache, dcache,
-                 jax.ShapeDtypeStruct((n,), i32),
-                 jax.ShapeDtypeStruct((n,), i32),
-                 jax.ShapeDtypeStruct((n,), np.dtype(bool)))
+        specs = (dstate, cache, cache, i32n, i32n,
+                 dcache, dcache, i32n, i32n, active)
         key = ("fused_spec", n, C, draft._model_fp[:16],
                draft._kv_dtype)
         # the fused round partitions only when BOTH sides split under
@@ -2308,10 +2265,12 @@ class DecodeSession:
                 "cached" % (n, slot, length))
         self._alive()
         if n > 0 and self._inplace:
-            zero_rows = _slot_writers()[2]
-            at, lo, hi = self._slot_ids[slot], length - n, length
-            self._kc = zero_rows(self._kc, at, lo, hi)
-            self._vc = zero_rows(self._vc, at, lo, hi)
+            clear_rows = _slot_writers()[2]
+            mine = np.arange(self.n_slots) == slot
+            lo = np.where(mine, length - n, 0).astype(np.int32)
+            hi = np.where(mine, length, 0).astype(np.int32)
+            self._kc = clear_rows(self._kc, lo, hi, n)
+            self._vc = clear_rows(self._vc, lo, hi, n)
         elif n > 0:
             L = self._kc.shape[0]
             H, Dh = self._kc.shape[3], self._kc.shape[4]
@@ -2380,9 +2339,6 @@ class SpeculativeDecodeSession:
                 "draft max_seq_len %d < target max_seq_len %d — the "
                 "draft cache cannot mirror the committed stream"
                 % (draft.max_seq_len, target.max_seq_len))
-        for pred, what in ((target, "speculative decoding"),
-                           (draft, "a speculative draft")):
-            pred._require_default_block(what)
         self.predictor = target
         self.draft_predictor = draft
         self.spec_k = int(spec_k)

@@ -103,18 +103,16 @@ class TestHeadSliceParity:
         return q, k, v, lengths
 
     @staticmethod
-    def _pin(got, full, m):
+    def _pin(got, full):
         """Heads are independent, so the per-head math is identical —
         but XLA schedules the narrower [N, Hl, ...] contraction of a
-        1-head block differently, so bit-exactness holds only while
-        the compiled reduction shape is preserved (m <= 2 here).  At
-        m=4 pin the ULP-level bound instead."""
-        if m <= 2:
-            assert np.array_equal(got, full)
-        else:
-            np.testing.assert_allclose(got.astype(np.float64),
-                                       full.astype(np.float64),
-                                       rtol=1e-6, atol=1e-7)
+        head block differently (at m = 2 already: 0.23925762 against
+        0.23925759), so what is pinned is the ULP-level bound;
+        bit-exactness across compiled shapes is no contract (PERF.md,
+        PR 21)."""
+        np.testing.assert_allclose(got.astype(np.float64),
+                                   full.astype(np.float64),
+                                   rtol=1e-6, atol=1e-7)
 
     @pytest.mark.parametrize("m", [1, 2, 4])
     def test_matches_full_kernel(self, m):
@@ -127,7 +125,7 @@ class TestHeadSliceParity:
             parts.append(np.asarray(decode_attention_head_slice(
                 q[:, sl], k[:, :, sl], v[:, :, sl], lengths,
                 head_offset=i * hl, n_local_heads=hl)))
-        self._pin(np.concatenate(parts, axis=1), full, m)
+        self._pin(np.concatenate(parts, axis=1), full)
 
     @pytest.mark.parametrize("m", [2, 4])
     def test_int8_scale_window_per_member(self, m):
@@ -147,7 +145,7 @@ class TestHeadSliceParity:
                 q[:, sl], k[:, :, sl], v[:, :, sl], lengths,
                 head_offset=i * hl, n_local_heads=hl,
                 kv_scales=scales)))
-        self._pin(np.concatenate(parts, axis=1), full, m)
+        self._pin(np.concatenate(parts, axis=1), full)
 
 
 # ---------------------------------------------------------------------------

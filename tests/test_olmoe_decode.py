@@ -312,17 +312,71 @@ def test_default_block_step_returns_what_it_did(tmp_path):
     assert [a["d2h_bytes"] for a in attrs] == [4, 8]
 
 
-# (e) a phase that does not implement the block says so, by key ------------
+# (e) every phase runs the block: `_block` is the one decoder layer ---------
 
-@pytest.mark.parametrize("phase,call", [
-    ("verify", lambda p: p.verify_fn(2, 2)),
-    ("fused", lambda p: p.fused_step_fn(2, 4)),
-    ("spec", lambda p: dec.SpeculativeDecodeSession(p, p, 2, 2)),
-    ("seqpar", lambda p: p._prefill_core_seqpar(None, None, None, None)),
-])
-def test_untaught_phase_refuses_naming_the_key(opened, phase, call):
-    with pytest.raises(NotImplementedError, match=r"norm='rmsnorm'"):
-        call(opened[0])
+@pytest.fixture(scope="module")
+def other_draft(tmp_path_factory):
+    """A draft that disagrees with the target: a GPT-2-shaped block of
+    the same vocabulary, so one fused round traces both kinds of block."""
+    d = str(tmp_path_factory.mktemp("draft") / "lm")
+    return GenerativePredictor(build_tiny_decode_model(
+        d, **dict(TINY, n_layers=1, seed=5)))
+
+
+def _spec_streams(target, draft, prompts, n, fused):
+    """`n` tokens a prompt through speculative rounds of depth 3, the
+    session left open for the caller to look at its tables."""
+    sp = dec.SpeculativeDecodeSession(target, draft, len(prompts), 3)
+    streams = [[sp.prefill(i, p)] for i, p in enumerate(prompts)]
+    while min(len(s) for s in streams) < n:
+        g, counts = sp.step(fused=fused)
+        for i, s in enumerate(streams):
+            s.extend(int(t) for t in g[i, :counts[i]])
+    assert not sp.degraded, sp.degrade_error
+    return sp, [s[:n] for s in streams]
+
+
+@pytest.mark.parametrize("phase", ["fused_window", "spec", "spec_fused",
+                                   "other_draft", "other_draft_fused"])
+def test_every_phase_runs_the_block_and_keeps_the_plain_stream(
+        opened, other_draft, phase):
+    """Token for token against `decode()`: on the CPU a chunk of C
+    positions through the routed FFN (C x N rows sorted by expert) rounds
+    like C single steps, so no logit tolerance is needed here."""
+    pred = opened[0]
+    prompts, n = _prompts([9, 4, 12], seed=8), 12
+    plain = []
+    for p in prompts:
+        sess = pred.new_session(1)
+        plain.append([sess.prefill(0, p)]
+                     + [int(sess.decode()[0]) for _ in range(n - 1)])
+    if phase == "fused_window":
+        sess = pred.new_session(4)               # slot 3 stays free
+        first = [sess.prefill(i, p) for i, p in enumerate(prompts)]
+        toks, counts, trips = sess.decode_fused(n - 1)
+        assert trips == n - 1 and counts.tolist() == [n - 1] * 3 + [0]
+        assert [[f] + toks[i].tolist() for i, f in enumerate(first)] \
+            == plain
+        assert sess.slot_is_zero(3)
+        return
+    twin = not phase.startswith("other_draft")
+    sp, streams = _spec_streams(pred, pred if twin else other_draft,
+                                prompts, n, fused=phase.endswith("fused"))
+    assert streams == plain
+    if twin:
+        assert sp.accepted == sp.proposed > 0
+    else:
+        assert sp.accepted < sp.proposed
+    # a rejected suffix leaves nothing behind: zeros from the committed
+    # length on, in the target's tables and in the draft's
+    for s in (sp.session, sp.draft_session):
+        for table in (np.asarray(s._kc), np.asarray(s._vc)):
+            for i, length in enumerate(s.lengths):
+                assert table[:, i, :length].any()
+                assert not table[:, i, length:].any()
+    for i in range(len(prompts)):
+        sp.free(i)
+        assert sp.slot_is_zero(i) and sp.draft_session.slot_is_zero(i)
 
 
 def test_tp_lane_refuses_instead_of_falling_back(artifact):

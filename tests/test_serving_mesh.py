@@ -354,16 +354,18 @@ class TestMeshServing:
             prompt = [3, 5, 7]
             ref, _ = greedy_decode(pred, prompt, 10)
             set_member_poison("cpu:3")
-            # drive until the poisoned lane has eaten a stream: lane
-            # assignment is least-loaded, so a few streams cover both
+            # drive until each lane has eaten a stream: the lanes pull
+            # from one queue and whichever wakes first takes a request,
+            # so four streams all went to one lane in a run of ten
             outcomes = []
-            for _ in range(4):
+            kinds = []
+            while len(kinds) < 32 and not {"ok", "dead"} <= set(kinds):
                 s = reg.submit_stream("lm", prompt, max_new_tokens=10)
                 try:
                     outcomes.append(("ok", _flat(s.result(timeout=300))))
                 except MeshMemberLost as e:
                     outcomes.append(("dead", str(e)))
-            kinds = [k for k, _ in outcomes]
+                kinds = [k for k, _ in outcomes]
             assert "dead" in kinds, \
                 "poisoned lane never took a stream: %s" % (outcomes,)
             assert "ok" in kinds, \

@@ -203,18 +203,20 @@ def compile_phase(pred, state, math_fn, specs):
             state, *specs).compile()
 
 
-def assert_table_updated_in_place(compiled, table_shape, n_kernels):
+def assert_table_updated_in_place(compiled, table_shape, n_kernels,
+                                  temporaries=None):
     """What PERF.md (PR 27) predicts of the module: both tables aliased to
-    outputs, temporaries under a tenth of one table, and no select,
-    concatenate, copy, pad or transpose whose result is a whole table or a
-    whole layer."""
+    outputs, temporaries under a tenth of one table (or `temporaries`
+    bytes), and no select, concatenate, copy, pad or transpose whose result
+    is a whole table or a whole layer."""
     import re
     L, N, S, H, D = table_shape
     table = L * N * S * H * D * 4
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= 2 * table, \
         (ma.alias_size_in_bytes, table)
-    assert ma.temp_size_in_bytes < table / 10, ma.temp_size_in_bytes
+    assert ma.temp_size_in_bytes < (temporaries or table / 10), \
+        ma.temp_size_in_bytes
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= n_kernels
     big = re.compile(r"\[(%d,)?%d,%d,%d,%d\]" % (L, N, S, H, D))
@@ -255,6 +257,47 @@ def test_fused_window_updates_the_table_in_place(one_chip):
     text = assert_table_updated_in_place(compiled, pred.table_shape(slots),
                                          n_kernels=meta["n_layers"])
     assert " while(" in text
+
+
+def _verify_specs(pred, slots, k):
+    cache, _, lengths, _, active = pred._step_specs(slots)
+    return (cache, cache, lengths,
+            jax.ShapeDtypeStruct((slots, k + 1), np.dtype(np.int32)), active)
+
+
+def test_verify_updates_the_table_in_place(one_chip):
+    """`verify_fn(32, 4)`: five rows a slot and layer land in the carried
+    table by the step's scatter, five kernel calls a layer read it, the
+    rejected suffix leaves by a scatter of zeros.  Before PR 28 the phase
+    broadcast each layer to N x C pseudo-slots (1.34 GB a layer and
+    table here) beside two stacked tables and did not fit the chip."""
+    meta, slots = STEP_MODELS["gpt2_small"]
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(meta, device)
+    compiled = compile_phase(pred, state, pred._verify_math,
+                             _verify_specs(pred, slots, 4))
+    assert_table_updated_in_place(compiled, pred.table_shape(slots),
+                                  n_kernels=5 * meta["n_layers"])
+
+
+def test_fused_window_takes_the_routed_block(one_chip):
+    """The fused window at the OLMoE geometry (refused before PR 28): the
+    `while` body is the plain step with the routing facts dropped, the
+    table in place as there.  XLA hoists the bf16 rounding of the dense
+    matmuls' weights out of the loop (the head 0.21 GB, the projections
+    0.07 GB at this depth); the 3.2 GB of experts go to the grouped-matmul
+    kernels as they are and must NOT be copied."""
+    meta, slots = STEP_MODELS["olmoe_1b_7b"]
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(meta, device)
+    i32 = np.dtype(np.int32)
+    specs = pred._step_specs(slots) + (
+        jax.ShapeDtypeStruct((slots,), i32), jax.ShapeDtypeStruct((), i32))
+    compiled = compile_phase(pred, state, pred._fused_step_math(8), specs)
+    text = assert_table_updated_in_place(compiled, pred.table_shape(slots),
+                                         n_kernels=meta["n_layers"],
+                                         temporaries=0.35e9)
+    assert " while(" in text and "ragged-dot" in text
 
 
 def test_int8_table_step_compiles_in_place(one_chip):

@@ -400,6 +400,43 @@ def check_decode_kernel(pred, n_slots, seed):
     return err
 
 
+def check_window(pred, n_slots, prompt_list, what):
+    """A window of `decode.STEP_WINDOW` trips against as many one-trip
+    dispatches of the same step executable, from the same admissions: equal
+    tokens, and equal tables bit for bit, before the committed length and
+    past it.  Returns the trips the window ran (fewer than the window only
+    if a stream met EOS)."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.decode import STEP_WINDOW
+
+    def admitted():
+        sess = pred.new_session(n_slots)
+        for i, p in enumerate(prompt_list):
+            sess.prefill(i, p)
+        return sess
+    live = len(prompt_list)
+    win = admitted()
+    toks, counts, trips = win.decode_fused(STEP_WINDOW)
+    require(1 <= trips <= STEP_WINDOW
+            and counts.tolist() == [trips] * live + [0] * (n_slots - live),
+            "the %s window ran %d trips and emitted %s"
+            % (what, trips, counts.tolist()))
+    one = admitted()
+    steps = np.stack([one.decode() for _ in range(trips)], axis=1)
+    require(np.array_equal(toks[:live, :trips], steps[:live]),
+            "the %s window's tokens differ from %d one-trip dispatches: "
+            "%s against %s" % (what, trips, toks[:live, :trips].tolist(),
+                               steps[:live].tolist()))
+    require(np.array_equal(win.lengths, one.lengths)
+            and np.array_equal(win.last_tokens, one.last_tokens),
+            "the %s window left other lengths or last tokens" % what)
+    for name, a, b in (("K", win._kc, one._kc), ("V", win._vc, one._vc)):
+        require(bool(jnp.array_equal(a, b)),
+                "the %s window's %s table differs from the one-trip "
+                "dispatches'" % (what, name))
+    return trips
+
+
 def serve_one(srv, artifact, kv, seed, devs):
     """Serve the four prompts with KV-cache dtype `kv`, then hold the result
     to the reference-attention twin of the same model."""
@@ -422,8 +459,10 @@ def serve_one(srv, artifact, kv, seed, devs):
     # jitted callable, lowered and compiled for the same arguments: a
     # persistent-cache hit)
     sess = pred.new_session(n_slots)
+    # no slot live, budget 0, one trip: the dispatch's shape, not its work
     args = (pred._state, sess._kc, sess._vc, sess.lengths,
-            sess.last_tokens, sess.active)
+            sess.last_tokens, sess.active, np.zeros(n_slots, np.int32),
+            np.int32(1))
     step = pred.step_fn(n_slots)
     n_calls = require_mosaic(step.lower(*args).compile().as_text(),
                              pred.meta["n_layers"],
@@ -436,6 +475,7 @@ def serve_one(srv, artifact, kv, seed, devs):
             "the %s step did not consume the slot table it was given"
             % kv)
     del sess, args
+    window_trips = check_window(pred, n_slots, plist, kv)
 
     # same prefix, kernel step vs reference-attention step
     k_firsts, k_logits = teacher_forced_logits(pred, plist, served, n_slots)
@@ -474,6 +514,7 @@ def serve_one(srv, artifact, kv, seed, devs):
          load_and_warm_s=round(load_s, 2), compile_cache=entry.compile_cache,
          ms_per_token=round(wall * 1e3 / n_tok, 2),
          stream_wall_s=round(wall, 2), mosaic_calls_in_step=n_calls,
+         window_trips_equal_to_one_trip_dispatches=window_trips,
          decode_kernel_max_err=float("%.3g" % kernel_err),
          tol_decode_kernel=TOL_DECODE_KERNEL,
          max_logit_diff_vs_reference=round(max_diff, 5),
@@ -550,7 +591,8 @@ def phase_serve_olmoe(seed, devs):
 
         sess = pred.new_session(OLMOE_SLOTS)
         args = (pred._state, sess._kc, sess._vc, sess.lengths,
-                sess.last_tokens, sess.active)
+                sess.last_tokens, sess.active,
+                np.zeros(OLMOE_SLOTS, np.int32), np.int32(1))
         step = pred.step_fn(OLMOE_SLOTS)
         n_att, n_grouped = mosaic_calls_by_kind(
             step.lower(*args).compile().as_text())
@@ -566,6 +608,7 @@ def phase_serve_olmoe(seed, devs):
                 "the OLMoE step did not consume the slot table it was "
                 "given")
         del sess, args
+        window_trips = check_window(pred, OLMOE_SLOTS, plist, "OLMoE")
 
         firsts, logits = teacher_forced_logits(pred, plist, served,
                                                OLMOE_SLOTS)
@@ -602,6 +645,7 @@ def phase_serve_olmoe(seed, devs):
              ms_per_token=round(wall * 1e3 / n_tok, 2),
              mosaic_decode_attention_calls=n_att,
              grouped_matmul_kernels=n_grouped,
+             window_trips_equal_to_one_trip_dispatches=window_trips,
              max_logit_diff_vs_reference=round(kept, 5),
              tol_logits=TOL_LOGITS,
              router_near_ties="%d/%d" % (n_near, n_pos),

@@ -379,20 +379,6 @@ DEFINE_int(
     "frame to the client every this many generated tokens (and always "
     "at end of stream). 1 streams every token as it decodes; larger "
     "values trade time-to-token for fewer wire frames.")
-DEFINE_int(
-    "serving_decode_fuse_steps", 1,
-    "Fused multi-step decode window (SERVING.md \"Fused multi-step "
-    "decode\"): each decode lane dispatch compiles up to this many "
-    "decode steps as ONE device executable (a lax.while_loop with "
-    "in-graph early exit), so one host round-trip emits up to N "
-    "tokens per slot — the host-dispatch-amortization lever at real "
-    "silicon step costs. Slot joins/leaves/deadline evictions move "
-    "to window boundaries (a per-lane step-time EWMA clamps trips so "
-    "deadlines overshoot by at most ~one dispatch); streams stay "
-    "bit-identical to N=1 token-for-token. Spec lanes fuse the whole "
-    "draft+verify round into one dispatch instead. 1 (default) keeps "
-    "the classic one-dispatch-per-token loop. Per-load override: "
-    "load_model(fuse_steps=...).")
 DEFINE_string(
     "serving_kv_cache_dtype", "",
     "Default KV-cache numerics for decode artifacts that do not pin "

@@ -90,22 +90,25 @@ like sequential step logits.  A draft failure mid-round
 degrades the session to target-only plain decode within that same
 step (`degraded`), never wedging or corrupting a stream.
 
-**Fused multi-step decode** (SERVING.md "Fused multi-step decode"):
-every plain decode step is one host->device dispatch, so at real
-silicon step costs the HOST becomes the tokens/sec ceiling long
-before the HBM roofline does.  `fused_step_fn(n_slots, n_steps)`
-compiles up to N steps as ONE executable — a `lax.while_loop`
-carrying {cache, lengths, last_tokens, running masks} through
-step+argmax+KV-write per trip with in-graph early exit — and
-`DecodeSession.decode_fused` drives it, returning a [n_slots,
-n_steps] token block per dispatch.  The speculative path rides the
+**The step is a window** (SERVING.md "Fused multi-step decode"): every
+decode dispatch costs the host a launch, a fetch and one wake-up per
+stream, and on the chip that, not the device's work, bounds the token
+rate.  So the step executable, `step_fn(n_slots)`, IS up to
+`STEP_WINDOW` decode steps: a `lax.while_loop` carrying {cache, last
+tokens, the token block} through step + argmax + KV write per trip,
+with per-slot budgets and the trip count RUNTIME arguments (`budget`,
+`max_trips`), so one executable per slot count serves a one-trip round
+and a full window alike, and ending in-graph with the trip in which
+the first running slot stops (EOS, budget, cache room).
+`DecodeSession.decode()` is one trip of it, `decode_fused` a window;
+the serving lane picks the trips of each dispatch from its own slot
+table (`DecodeBatcher._lane_iter`).  The speculative path rides the
 same discipline: `fused_spec_fn` runs k draft steps + batched verify
 + in-graph accept/rollback/catch-up as one dispatch
 (`SpeculativeDecodeSession.step(fused=True)`).  Because the per-trip
-body IS the plain step math and per-slot math is independent, fused
-streams are bit-identical to N=1 streams token-for-token — the
-serving layer (FLAGS.serving_decode_fuse_steps) moves slot
-joins/leaves to window boundaries without moving a single token.
+body is one `_step_core` and per-slot math is independent, a window's
+streams are those of one-trip dispatches token for token: a window
+moves slot joins/leaves to its boundaries without moving a token.
 """
 
 import hashlib
@@ -123,10 +126,17 @@ __all__ = ["GenerativePredictor", "DecodeSession", "DecodeSessionDead",
            "SpeculativeDecodeSession", "save_decode_model",
            "build_tiny_decode_model", "load_decode_predictor",
            "greedy_decode", "set_draft_poison", "normalize_kv_dtype",
-           "table_row",
+           "table_row", "STEP_WINDOW",
            "DECODE_META"]
 
 DECODE_META = "decode_meta.bin"
+# the most decode steps ONE dispatch of the step executable runs
+# (`GenerativePredictor.step_fn`); how many a dispatch does run is a
+# runtime argument.  Chosen on the chip (PERF.md section 6, PR 30: over
+# 4, 8 gave +13% and +8% in the two saturated cells, 16 over 8 +4.7% and
+# +3.9%, under the 5% asked of it); a stream receives its tokens in
+# blocks of up to this many.
+STEP_WINDOW = 8
 _DECODE_STATE = "decode_state.bin"
 
 # chaos hook (tools/chaos.py spec-fallback scenario): once armed, the
@@ -1303,20 +1313,6 @@ class GenerativePredictor:
             _pad_rows(q, row), kc, vc, lengths + ahead, scale=scale,
             kv_scales=scales, layer=i)[:, :Hl, :Dh]
 
-    def _step_math(self, state, kc, vc, lengths, last_tokens, active,
-                   tp=_OFF_MESH):
-        """One greedy decode step: `_step_core` + argmax ->
-        (new_tokens [N] i32, kc', vc').  A routed-expert artifact's
-        first result carries the call's routing facts behind the N
-        tokens (`_pack_routing`)."""
-        import jax.numpy as jnp
-        logits, kc, vc, facts = self._step_core(
-            state, kc, vc, lengths, last_tokens, active, tp=tp)
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if self.routed_layers:
-            toks = _pack_routing(toks, facts)
-        return toks, kc, vc
-
     def _step_logits(self, state, kc, vc, lengths, last_tokens, active,
                      tp=_OFF_MESH):
         """`_step_core` without the routing facts: (logits [N, vocab]
@@ -1326,8 +1322,9 @@ class GenerativePredictor:
 
     def _step_tokens(self, state, kc, vc, lengths, last_tokens, active,
                      tp):
-        """`_step_math` without the routing facts, for the fused
-        windows: a routed FFN runs, what it touched is dropped."""
+        """One greedy step without the routing facts, for the fused
+        speculative round's draft: a routed FFN runs, what it touched is
+        dropped."""
         import jax.numpy as jnp
         logits, kc, vc = self._step_logits(
             state, kc, vc, lengths, last_tokens, active, tp=tp)
@@ -1436,75 +1433,89 @@ class GenerativePredictor:
         return (g, m, _clear_rows(kc, lo, hi, C - 1),
                 _clear_rows(vc, lo, hi, C - 1))
 
-    def _fused_step_math(self, n_steps, tp=_OFF_MESH):
-        """Build the FUSED multi-step decode phase (SERVING.md "Fused
-        multi-step decode"): up to `n_steps` plain decode steps run as
-        ONE compiled executable — a `lax.while_loop` carrying {KV
-        cache, lengths, last_tokens, per-slot running masks} through
-        step+argmax+KV-write per trip, with in-graph early exit the
-        moment no slot is still running.  Per-trip the body is EXACTLY
-        the plain step (`_step_tokens`: same kernel, same masking, same
-        write order; a routed block's facts are dropped), so a fused
-        stream is bit-identical to `n_steps` sequential `decode()`
-        calls — the per-slot independence that makes batched decode
-        bit-exact makes fusion bit-exact too.
+    def _step_math(self, tp=_OFF_MESH):
+        """Build the decode STEP phase (SERVING.md "Fused multi-step
+        decode"): up to `STEP_WINDOW` greedy decode steps as ONE
+        executable, a `lax.while_loop` carrying {KV tables, last
+        tokens, the token block} through `_step_core` + argmax per
+        trip.  Per-slot math is independent and every trip is the same
+        `_step_core`, so a window's stream is that of one-trip
+        dispatches token for token.
 
-        Runtime args (the executable stays one fingerprint per
-        (n_slots, n_steps) geometry):
-          * `budget` [N] i32 — tokens each slot may still emit (its
-            max_new / cache-room headroom); a slot stops running when
-            its budget is met, without stopping the others;
-          * `max_trips` [] i32 — dispatch-wide trip clamp (<= n_steps),
-            the serving deadline governor (a lane about to expire runs
-            a short window instead of recompiling a new geometry).
+        Runtime arguments beside the step's own (the executable stays
+        one fingerprint per slot count):
+          * `budget` [N] i32: tokens each slot may still emit (its
+            max_new / cache-room headroom);
+          * `max_trips` [] i32: the dispatch's trip count (at most the
+            window).  The serving lane sets it per dispatch: 1 while a
+            slot is free, the smallest live budget when none is, less
+            under the deadline governor.
 
-        A slot stops running after emitting EOS, exhausting its
-        budget, or filling its cache; tokens land in a [N, n_steps]
-        block, `emitted[s]` of them valid per slot, in stream order."""
+        The slots that RUN are those active with budget and cache room
+        left when the window begins, and the window ends in-graph with
+        the trip in which the FIRST of them stops, whatever stops it
+        (EOS, its budget met, its cache full): every trip of a window
+        advances every running slot, none sits out a dead trip, and a
+        lane with every slot assigned can say that nothing joins before
+        a slot ends.  Returns (out, kc', vc'); `out` is ONE int32
+        vector, so a dispatch costs one fetch
+        (`DecodeSession.decode_fused` splits it): the [N, STEP_WINDOW]
+        token block (`emitted[s]` of row s valid, in stream order), `emitted`
+        [N] (the trips run for a running slot, 0 for the others), the
+        trips run, and for a routed-expert artifact each layer's
+        (experts touched SUMMED over the trips, most tokens on one
+        expert, the LARGEST over the trips), which is what
+        `_pack_routing` carries for a prefill."""
         import jax
         import jax.numpy as jnp
-        n_steps = int(n_steps)
+        W = int(STEP_WINDOW)
         eos = self.eos_id
+        routed = self.routed_layers
 
-        def fused(state, kc, vc, lengths, last_tokens, active, budget,
-                  max_trips):
+        def step(state, kc, vc, lengths, last_tokens, active, budget,
+                 max_trips):
             S = kc.shape[2]
             N = kc.shape[1]
-            toks0 = jnp.zeros((N, n_steps), jnp.int32)
-            emitted0 = jnp.zeros((N,), jnp.int32)
-            running0 = active & (budget > 0) \
-                & (lengths < jnp.int32(S))
-            trips = jnp.minimum(max_trips, jnp.int32(n_steps))
+            running = active & (budget > 0) & (lengths < jnp.int32(S))
+            adv = running.astype(jnp.int32)
+            trips = jnp.minimum(max_trips, jnp.int32(W))
 
             def cond(carry):
-                i, _kc, _vc, _len, _last, _em, _tk, running = carry
-                return (i < trips) & jnp.any(running)
+                return (carry[0] < trips) & ~carry[-1]
 
             def body(carry):
-                i, kc, vc, lengths, last, emitted, toks, running = carry
-                tok, kc, vc = self._step_tokens(state, kc, vc, lengths,
-                                                last, running, tp)
-                # land this trip's tokens at column i (one-hot select —
-                # stopped slots keep their block rows untouched)
-                col = (jnp.arange(n_steps)[None, :] == i) \
-                    & running[:, None]
+                i, kc, vc, last, toks, facts, _ = carry
+                logits, kc, vc, f = self._step_core(
+                    state, kc, vc, lengths + adv * i, last, running,
+                    tp=tp)
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                # land this trip's tokens at column i (one-hot select:
+                # a slot that does not run keeps its row of zeros)
+                col = (jnp.arange(W)[None, :] == i) & running[:, None]
                 toks = jnp.where(col, tok[:, None], toks)
-                adv = running.astype(jnp.int32)
-                emitted = emitted + adv
-                lengths = lengths + adv
-                last = jnp.where(running, tok, last)
-                running = running & (tok != jnp.int32(eos)) \
-                    & (emitted < budget) & (lengths < jnp.int32(S))
-                return (i + 1, kc, vc, lengths, last, emitted, toks,
-                        running)
+                if routed:
+                    f = jnp.stack(f)                         # [L, 2]
+                    facts = jnp.stack(
+                        [facts[:, 0] + f[:, 0],
+                         jnp.maximum(facts[:, 1], f[:, 1])], axis=1)
+                i = i + 1
+                stopped = jnp.any(running & (
+                    (tok == jnp.int32(eos)) | (i >= budget)
+                    | (lengths + i >= jnp.int32(S))))
+                return (i, kc, vc, jnp.where(running, tok, last), toks,
+                        facts, stopped)
 
-            carry = (jnp.int32(0), kc, vc, lengths, last_tokens,
-                     emitted0, toks0, running0)
-            (i, kc, vc, lengths, last, emitted, toks,
-             _running) = jax.lax.while_loop(cond, body, carry)
-            return toks, emitted, i, kc, vc, lengths, last
+            carry = (jnp.int32(0), kc, vc, last_tokens,
+                     jnp.zeros((N, W), jnp.int32),
+                     jnp.zeros((routed, 2), jnp.int32),
+                     ~jnp.any(running))
+            i, kc, vc, _last, toks, facts, _ = jax.lax.while_loop(
+                cond, body, carry)
+            out = jnp.concatenate([toks.reshape(-1), adv * i, i[None],
+                                   facts.reshape(-1)])
+            return out, kc, vc
 
-        return fused
+        return step
 
     def _fused_spec_math(self, draft, spec_k, tp=_OFF_MESH):
         """Build the FUSED speculative round: k draft decode steps +
@@ -1593,16 +1604,17 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (5:
-            # verify and the fused rounds scatter their rows into the
-            # carried table as the step does since 4; a stored phase of
-            # the `where`/`stack` math must miss)
+            # rev bumps when the phase math itself changes shape (6:
+            # the step is a window of runtime trips; 5: verify and the
+            # fused rounds scatter their rows into the carried table as
+            # the step does since 4; a stored phase of older math must
+            # miss)
             "kv_dtype": self._kv_dtype,
             # so do the block's keys (norm, position, qk-norm, FFN kind
             # and routing): equal weight shapes, another function
             "block": [[k, self._block_meta[k]]
                       for k in sorted(self._block_meta)],
-            "rev": 5,
+            "rev": 6,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -1848,7 +1860,9 @@ class GenerativePredictor:
     def _cache_np_dtype(self):
         return np.dtype(np.int8 if self._kv_quant else np.float32)
 
-    def _step_specs(self, n_slots):
+    def _table_specs(self, n_slots):
+        """(kc, vc, lengths [N] i32, last tokens [N] i32, active [N]
+        bool): what every phase over the slot table takes."""
         import jax
         n = int(n_slots)
         cache = jax.ShapeDtypeStruct(self.table_shape(n),
@@ -1858,10 +1872,29 @@ class GenerativePredictor:
                 jax.ShapeDtypeStruct((n,), i32),
                 jax.ShapeDtypeStruct((n,), np.dtype(bool)))
 
+    def _step_specs(self, n_slots):
+        """The step executable's arguments: the table's, then `budget`
+        [N] i32 and `max_trips` [] i32 (`_step_math`)."""
+        import jax
+        i32 = np.dtype(np.int32)
+        return self._table_specs(n_slots) + (
+            jax.ShapeDtypeStruct((int(n_slots),), i32),
+            jax.ShapeDtypeStruct((), i32))
+
     def step_fn(self, n_slots):
-        return self._resolve(("step", int(n_slots)), self._step_math,
-                             self._step_specs(n_slots),
-                             tp_math=self._tp_math(self._step_math))
+        """The decode step executable of a slot table: up to
+        `STEP_WINDOW` steps a dispatch, how many being a runtime
+        argument (`_step_math`).  ONE executable per slot count serves
+        every round of a lane, a one-trip round and a full window
+        alike.  (The window is in the phase's key, so a process that
+        rebinds `STEP_WINDOW` for a sweep meets no stored phase of
+        another window.)"""
+        n = int(n_slots)
+        tp_math = (self._step_math(tp=self._tp_ctx())
+                   if self._tp_size else None)
+        return self._resolve(("step", n, int(STEP_WINDOW)),
+                             self._step_math(), self._step_specs(n),
+                             tp_math=tp_math)
 
     def step_logits_fn(self, n_slots):
         """The decode step with its logits left un-argmaxed (same math,
@@ -1870,7 +1903,7 @@ class GenerativePredictor:
         (`DecodeSession.decode_logits`)."""
         return self._resolve(("step_logits", int(n_slots)),
                              self._step_logits,
-                             self._step_specs(n_slots),
+                             self._table_specs(n_slots),
                              tp_math=self._tp_math(self._step_logits))
 
     def verify_fn(self, n_slots, spec_k):
@@ -1881,31 +1914,11 @@ class GenerativePredictor:
         other phase (COMPILE_CACHE.md)."""
         import jax
         n, C = int(n_slots), int(spec_k) + 1
-        cache, _, lengths, _, active = self._step_specs(n)
+        cache, _, lengths, _, active = self._table_specs(n)
         specs = (cache, cache, lengths,
                  jax.ShapeDtypeStruct((n, C), np.dtype(np.int32)), active)
         return self._resolve(("verify", n, C), self._verify_math, specs,
                              tp_math=self._tp_math(self._verify_math))
-
-    def fused_step_fn(self, n_slots, n_steps):
-        """The fused multi-step decode executable for a (slot table,
-        window) geometry: up to `n_steps` tokens per slot per dispatch
-        with in-graph early exit (`_fused_step_math`).  One new
-        compile-cache fingerprint per (n_slots, n_steps) — warm boots
-        of a fused-configured server deserialize it like every other
-        phase (COMPILE_CACHE.md)."""
-        import jax
-        n, T = int(n_slots), int(n_steps)
-        if T < 1:
-            raise ValueError("fuse window must be >= 1, got %d" % T)
-        i32 = np.dtype(np.int32)
-        specs = self._step_specs(n) + (jax.ShapeDtypeStruct((n,), i32),
-                                       jax.ShapeDtypeStruct((), i32))
-        tp_math = (self._fused_step_math(T, tp=self._tp_ctx())
-                   if self._tp_size else None)
-        return self._resolve(("fused_step", n, T),
-                             self._fused_step_math(T), specs,
-                             tp_math=tp_math)
 
     def fused_spec_fn(self, draft, n_slots, spec_k):
         """The fused speculative-round executable: k draft steps +
@@ -1916,8 +1929,8 @@ class GenerativePredictor:
         executable."""
         import jax
         n, C = int(n_slots), int(spec_k) + 1
-        cache, _, i32n, _, active = self._step_specs(n)
-        dcache = draft._step_specs(n)[0]
+        cache, _, i32n, _, active = self._table_specs(n)
+        dcache = draft._table_specs(n)[0]
         dstate = {name: jax.ShapeDtypeStruct(np.shape(v),
                                              np.asarray(v).dtype)
                   for name, v in draft._state_host.items()}
@@ -1972,9 +1985,16 @@ class DecodeSession:
             z = jnp.zeros(shape, dtype)
             if group is not None:
                 return jax.device_put(z, group.kv_sharding(shape))
-            if predictor.device is not None:
-                return jax.device_put(z, predictor.device)
-            return z
+            # COMMITTED to its device from the start, under the default
+            # placement too (to the device jax chose).  A phase's K/V
+            # come back committed, so a fresh table would be committed
+            # by its first admission, and a jitted write is lowered
+            # anew when a table's commitment differs from what it has
+            # seen: the lane's first prefill of every bucket but the one
+            # `ModelEntry.warm` happened to run first, and the second of
+            # that one, compiled under traffic
+            return jax.device_put(
+                z, predictor.device or next(iter(z.devices())))
 
         # two buffers: a donated K table must not take V's with it
         self._kc = table()
@@ -1996,6 +2016,9 @@ class DecodeSession:
         # a routed-expert artifact: the newest step's or prefill's
         # per-layer (experts touched, most tokens on one expert) [L, 2]
         self.last_routing = None
+        # the `decode/put` / `decode/launch` spans of a call whose
+        # results are not fetched yet (`_call`, `_fetch`)
+        self._launched = ()
 
     # -- occupancy ------------------------------------------------------
 
@@ -2033,7 +2056,8 @@ class DecodeSession:
 
     def _call(self, phase, fn, cache, small):
         """`fn(state, *cache, *small)`, with its `decode/put` and
-        `decode/launch` spans when tracing is on: `_put` of the small
+        `decode/launch` spans when tracing is on (stamped once the
+        call's results are fetched: `_fetch`): `_put` of the small
         per-call arguments, then the executable call until it returns
         (synchronous for whatever host argument it has to upload;
         `h2d_bytes` counts those: the weights and the cache are
@@ -2059,15 +2083,16 @@ class DecodeSession:
         except BaseException as e:
             _mark_dead(phase, e, self)
             raise
-        obs_tracing.stamp("decode/put", t0, t1, kind="serving",
-                          phase=phase,
-                          bytes=_nbytes(small) - _host_nbytes(args))
-        obs_tracing.stamp("decode/launch", t1, t2, kind="serving",
-                          phase=phase,
-                          h2d_bytes=self.predictor.state_host_bytes()
-                          + _host_nbytes(cache) + _host_nbytes(args),
-                          donated_bytes=sum(int(c.nbytes) for c in cache
-                                            if c.is_deleted()))
+        # stamped by the `_fetch` that ends this call, which knows how
+        # many trips a step's dispatch ran
+        self._launched = (
+            ("decode/put", t0, t1,
+             {"bytes": _nbytes(small) - _host_nbytes(args)}),
+            ("decode/launch", t1, t2,
+             {"h2d_bytes": self.predictor.state_host_bytes()
+              + _host_nbytes(cache) + _host_nbytes(args),
+              "donated_bytes": sum(int(c.nbytes) for c in cache
+                                   if c.is_deleted())}))
         return out
 
     def prefill(self, slot, tokens):
@@ -2108,44 +2133,48 @@ class DecodeSession:
         return tok
 
     def decode(self):
-        """ONE fixed-shape step over the whole slot table; returns the
-        np.int32 [n_slots] token vector (only entries of slots active
-        at call time are meaningful).  Bumps each active slot's length
-        and last token."""
-        toks, = self._fetch("step", self._step(
-            self.predictor.step_fn(self.n_slots)), routed=True)
-        self._advance(toks)
-        return toks
+        """ONE fixed-shape step over the whole slot table: a one-trip
+        dispatch of the step executable.  Returns the np.int32
+        [n_slots] token vector (only entries of slots active at call
+        time are meaningful).  Bumps each active slot's length and last
+        token."""
+        return self.decode_fused(1)[0][:, 0]
 
     def decode_logits(self):
-        """`decode()` that also hands back the step's logits: returns
-        (tokens [n_slots] i32, logits [n_slots, vocab] f32).  A caller
-        comparing two predictors on one stream overwrites
-        `last_tokens` afterwards (teacher forcing), as the speculative
-        session does for its draft."""
-        logits, = self._fetch("step", self._step(
-            self.predictor.step_logits_fn(self.n_slots)))
-        toks = logits.argmax(axis=-1).astype(np.int32)
-        self._advance(toks)
-        return toks, logits
-
-    def _step(self, fn):
+        """One step that hands back its logits: returns (tokens
+        [n_slots] i32, logits [n_slots, vocab] f32), through an
+        executable of its own (`step_logits_fn`: the step's `_step_core`,
+        no window around it).  A caller comparing two predictors on one
+        stream overwrites `last_tokens` afterwards (teacher forcing), as
+        the speculative session does for its draft."""
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
-        out, self._kc, self._vc = self._call(
-            "step", fn, (self._kc, self._vc),
+        logits, self._kc, self._vc = self._call(
+            "step", self.predictor.step_logits_fn(self.n_slots),
+            (self._kc, self._vc),
             (self.lengths, self.last_tokens, self.active))
-        return out
+        logits, = self._fetch("step", logits)
+        toks = logits.argmax(axis=-1).astype(np.int32)
+        act = self.active
+        self.lengths = self.lengths + act.astype(np.int32)
+        self.last_tokens = np.where(act, toks, self.last_tokens).astype(
+            np.int32)
+        self.steps += 1
+        return toks, logits
 
-    def _fetch(self, phase, *outs, routed=False):
+    def _fetch(self, phase, *outs, routed=False, trips_at=None):
         """`np.asarray` of each result: the wait for the device and the
-        copy to the host, under one `decode/fetch` span.  `routed` marks
-        a step's or a prefill's result: for a routed-expert artifact the
-        call's routing facts sit behind its tokens in that one vector
-        (`_pack_routing`) and are split off here, kept as `last_routing`
-        and given to the span as `moe_experts_touched` (summed over the
-        layers) and `moe_tokens_per_expert_max`.  Any other artifact
-        takes the path it always took."""
+        copy to the host, under one `decode/fetch` span; the call's
+        `decode/put` and `decode/launch` spans (`_call`) are stamped
+        here too.  `routed` marks a step's or a prefill's result: for a
+        routed-expert artifact the call's routing facts sit behind its
+        tokens in that one vector (`_pack_routing`, `_step_math`) and
+        are split off here, kept as `last_routing` and given to the
+        fetch span as `moe_experts_touched` (summed over the layers and
+        a step's trips) and `moe_tokens_per_expert_max`.  `trips_at`
+        is where a step's vector holds the trips it ran: all three
+        spans carry them as `trips`.  Any other artifact takes the
+        path it always took."""
         n_routed = 2 * self.predictor.routed_layers if routed else 0
         if not (n_routed or obs_tracing.enabled()):
             return [np.asarray(o) for o in outs]
@@ -2159,57 +2188,59 @@ class DecodeSession:
             attrs = {"moe_experts_touched": int(facts[:, 0].sum()),
                      "moe_tokens_per_expert_max": int(facts[:, 1].max())}
         if obs_tracing.enabled():
-            obs_tracing.stamp("decode/fetch", t0, time.monotonic(),
-                              kind="serving", phase=phase,
-                              d2h_bytes=d2h, **attrs)
+            t1 = time.monotonic()
+            trips = {} if trips_at is None \
+                else {"trips": int(got[0][trips_at])}
+            launched, self._launched = self._launched, ()
+            for name, a, b, more in launched:
+                obs_tracing.stamp(name, a, b, kind="serving", phase=phase,
+                                  **more, **trips)
+            obs_tracing.stamp("decode/fetch", t0, t1, kind="serving",
+                              phase=phase, d2h_bytes=d2h, **attrs, **trips)
         return got
 
-    def _advance(self, toks):
-        act = self.active
-        self.lengths = self.lengths + act.astype(np.int32)
-        self.last_tokens = np.where(act, toks, self.last_tokens).astype(
-            np.int32)
-        self.steps += 1
-
     def decode_fused(self, n_steps, budget=None, max_trips=None):
-        """Up to `n_steps` decode steps in ONE dispatch (SERVING.md
-        "Fused multi-step decode").  Returns (tokens [n_slots, n_steps]
-        int32, counts [n_slots] int32, trips int): slot s emitted
-        `counts[s]` tokens this dispatch, `tokens[s, :counts[s]]` in
-        stream order; `trips` is how many loop iterations actually ran
-        (in-graph early exit — all slots hitting EOS/budget ends the
-        window early).  `budget` [n_slots] caps each slot's emissions
-        (max_new / cache-room headroom; clipped to [0, n_steps], zero
-        for inactive slots); `max_trips` clamps the whole dispatch (the
-        serving deadline governor) without changing the compiled
-        geometry.  Bit-exact vs `n_steps` sequential `decode()` calls
-        — per-slot math is independent and the per-trip body IS the
-        plain step math."""
-        T = int(n_steps)
+        """Up to `n_steps` decode steps (at most `STEP_WINDOW`) in ONE
+        dispatch of the step executable (SERVING.md "Fused multi-step
+        decode").  Returns (tokens [n_slots, n_steps] int32, counts
+        [n_slots] int32, trips int): slot s emitted `counts[s]` tokens
+        this dispatch, `tokens[s, :counts[s]]` in stream order; `trips`
+        is how many loop iterations ran.  The window ends with the trip
+        in which the first running slot stops (EOS, its budget, its
+        cache full: `_step_math`), so `counts[s]` is `trips` for every
+        slot that ran and 0 for the others, and a caller that wants
+        more calls again.  `budget` [n_slots] caps each slot's
+        emissions (max_new / cache-room headroom; clipped to [0,
+        n_steps], zero for inactive slots); `max_trips` clamps the
+        whole dispatch.  Both are runtime arguments of the slot table's
+        one executable.  Token for token the stream of one-trip
+        dispatches: per-slot math is independent and every trip is the
+        same `_step_core`."""
+        N, W, act = self.n_slots, int(STEP_WINDOW), self.active
+        T = min(int(n_steps), W)
         if T < 1:
             raise ValueError("n_steps must be >= 1, got %d" % T)
         self._alive()
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
-        act = self.active
         if budget is None:
             b = np.where(act, T, 0).astype(np.int32)
         else:
-            b = np.asarray(budget, np.int32).reshape(self.n_slots)
+            b = np.asarray(budget, np.int32).reshape(N)
             b = np.clip(np.where(act, b, 0), 0, T).astype(np.int32)
         mt = T if max_trips is None else max(1, min(int(max_trips), T))
-        fn = self.predictor.fused_step_fn(self.n_slots, T)
-        toks, counts, trips, self._kc, self._vc, lengths, last = \
-            self._call("fused", fn, (self._kc, self._vc),
-                       (self.lengths, self.last_tokens, act, b,
-                        np.int32(mt)))
-        # lengths/last_tokens come back from the device: pure integer
-        # bookkeeping, so device round-trip is exact
-        lengths, last, trips, toks, counts = self._fetch(
-            "fused", lengths, last, trips, toks, counts)
-        self.lengths = lengths.astype(np.int32)
-        self.last_tokens = last.astype(np.int32)
-        trips = int(trips)
+        out, self._kc, self._vc = self._call(
+            "step", self.predictor.step_fn(N), (self._kc, self._vc),
+            (self.lengths, self.last_tokens, act, b, np.int32(mt)))
+        out, = self._fetch("step", out, routed=True, trips_at=N * W + N)
+        toks = out[:N * W].reshape(N, W)[:, :T]
+        counts, trips = out[N * W:N * W + N], int(out[N * W + N])
+        # what the loop carried, from what it returned: a slot advanced
+        # by the tokens it emitted and holds the last of them
+        self.lengths = (self.lengths + counts).astype(np.int32)
+        self.last_tokens = np.where(
+            counts > 0, toks[np.arange(N), np.maximum(counts - 1, 0)],
+            self.last_tokens).astype(np.int32)
         self.steps += trips
         return toks, counts, trips
 

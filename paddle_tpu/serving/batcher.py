@@ -1044,18 +1044,25 @@ class DecodeBatcher:
     failure degrades the lane to target-only decode within one round
     (`spec_degraded` event + counter), never wedging a stream.
 
-    ``fuse_steps`` > 1 (SERVING.md "Fused multi-step decode",
-    FLAGS.serving_decode_fuse_steps) runs each lane iteration as ONE
-    fused dispatch of up to N decode steps (`DecodeSession.
-    decode_fused`): slot joins/leaves/deadline evictions move to the
-    N-step window boundary, per-token EOS/max-new cuts still land in
-    stream order from the returned token block, and spec lanes fuse
-    the whole draft+verify round into one dispatch
-    (`SpeculativeDecodeSession.step(fused=True)`).  Streams stay
-    bit-identical to N=1 whatever joins or leaves; a per-lane EWMA of
-    step time clamps the trip count so no deadline overshoots by more
-    than one dispatch (the overshoot lands on the `deadline_expired`
-    event)."""
+    A dispatch is a WINDOW of decode steps (SERVING.md "Fused
+    multi-step decode"; `DecodeSession.decode_fused`), and the lane
+    picks its trips from its own slot table: while a slot is free a
+    newcomer could be admitted on the next round, so the dispatch is
+    one trip; with every slot assigned nothing can join before a slot
+    ends, so it runs min(cap, smallest remaining budget of the live
+    slots) trips and ends on the round in which the first slot must
+    end, in-graph no later than the trip in which the first slot does
+    end (an early EOS).  Joins, leaves, cancels and deadline evictions
+    happen at dispatch boundaries, per-token EOS/max-new cuts land in stream
+    order from the returned token block, and a per-lane EWMA of step
+    time clamps the trips so no deadline overshoots by more than one
+    dispatch (the overshoot lands on the `deadline_expired` event).
+    Streams are those of one-trip dispatches token for token whatever
+    joins or leaves.  ``fuse_steps`` pins the cap (tests; 1 = every
+    dispatch one trip); None is the built-in `decode.STEP_WINDOW`.  A
+    speculative lane runs its rounds host-driven unless ``fuse_steps``
+    > 1 is pinned, which fuses the whole draft+verify round into one
+    dispatch (`SpeculativeDecodeSession.step(fused=True)`)."""
 
     def __init__(self, predictor, replicas=None, n_slots=None,
                  max_queue=None, metrics=None, max_new_tokens=None,
@@ -1072,12 +1079,13 @@ class DecodeBatcher:
                                    else max_new_tokens), 1)
         self.continuous = bool(continuous)
         self.metrics = metrics
-        # fused multi-step decode window (1 = the classic one-dispatch-
-        # per-token loop; the default rides the flag so existing
-        # servers keep N=1 behavior bit-for-bit)
-        self.fuse_steps = max(int(FLAGS.serving_decode_fuse_steps
-                                  if fuse_steps is None
-                                  else fuse_steps), 1)
+        # the most trips one dispatch runs: the step executable's own
+        # window unless a caller pins fewer (tests); a speculative lane
+        # fuses its rounds only where a caller pinned a window
+        from ..inference.decode import STEP_WINDOW
+        self.fuse_steps = STEP_WINDOW if fuse_steps is None \
+            else min(max(int(fuse_steps), 1), STEP_WINDOW)
+        self.spec_fused = fuse_steps is not None and int(fuse_steps) > 1
         # speculative decoding (SERVING.md): one draft predictor per
         # replica lane (`draft_replicas`, or one shared `draft` for the
         # single-lane shape); spec_k is the draft depth per round
@@ -1570,25 +1578,21 @@ class DecodeBatcher:
                 self._emit_lane_iter(lane, t_iter, lane.steps,
                                      len(admits), 0)
             return True
-        fuse = self.fuse_steps
-        if fuse > 1:
-            # window-boundary housekeeping (SERVING.md "Fused
-            # multi-step decode"): drop cancelled/expired streams
-            # BEFORE burning an N-step window on them — joins and
-            # leaves happen only at dispatch boundaries
-            nowb = time.monotonic()
-            for slot, req in list(lane.assigned.items()):
-                if req.stream.cancelled():
-                    req.buf = []
-                    self._finish(lane, slot, req, "cancelled")
-                elif req.deadline is not None \
-                        and nowb > req.deadline:
-                    self._expire(lane, slot, req, nowb)
-            if not lane.assigned:
-                if traced and admits:
-                    self._emit_lane_iter(lane, t_iter, lane.steps,
-                                         len(admits), 0)
-                return True
+        # dispatch-boundary housekeeping (SERVING.md "Fused multi-step
+        # decode"): drop cancelled/expired streams BEFORE burning a
+        # window on them; joins and leaves happen only here
+        nowb = time.monotonic()
+        for slot, req in list(lane.assigned.items()):
+            if req.stream.cancelled():
+                req.buf = []
+                self._finish(lane, slot, req, "cancelled")
+            elif req.deadline is not None and nowb > req.deadline:
+                self._expire(lane, slot, req, nowb)
+        if not lane.assigned:
+            if traced and admits:
+                self._emit_lane_iter(lane, t_iter, lane.steps,
+                                     len(admits), 0)
+            return True
         n_act = len(lane.assigned)
         t0 = time.monotonic()
         # the same slow-worker chaos hook / deterministic per-step
@@ -1612,34 +1616,34 @@ class DecodeBatcher:
                 toks2d, counts = sess.step(
                     step_delay=delay,
                     draft_delay=_draft_chaos_delay(),
-                    fused=fuse > 1)
+                    fused=self.spec_fused)
                 spec_round = sess.last_spec
-            elif fuse > 1:
-                # per-slot token budgets (max_new / cache-room
-                # headroom) + the deadline governor: the lane's EWMA
-                # step time clamps the trip count so a deadlined
-                # stream never overshoots by more than ~one dispatch
+            else:
+                # the window, from what the lane can see.  A slot free:
+                # a newcomer could join on the next round, so one trip.
+                # Every slot assigned: nothing can join before a slot
+                # ends, so run to the round in which the first live
+                # slot must end (its max_new / cache-room budget), the
+                # cap at most.  The deadline governor: the lane's EWMA
+                # step time clamps the trips so a deadlined stream
+                # never overshoots by more than ~one dispatch
+                cap = self.fuse_steps
                 budget = np.zeros(self.n_slots, np.int32)
-                max_trips = fuse
+                max_trips = cap if n_act == self.n_slots else 1
                 for slot, req in lane.assigned.items():
                     budget[slot] = min(req.max_new - len(req.gen),
-                                       sess.room(slot), fuse)
+                                       sess.room(slot), cap)
+                    max_trips = min(max_trips, int(budget[slot]))
                     if req.deadline is not None and lane.step_ewma:
-                        allow = int((req.deadline - t0)
-                                    / lane.step_ewma)
-                        max_trips = min(max_trips, max(allow, 1))
+                        max_trips = min(max_trips, int(
+                            (req.deadline - t0) / lane.step_ewma))
                 toks2d, counts, trips = sess.decode_fused(
-                    fuse, budget=budget, max_trips=max_trips)
+                    cap, budget=budget, max_trips=max(max_trips, 1))
                 spec_round = False
                 if delay:
                     # the device-cost stand-in scales with the trips
                     # that actually ran (in-graph early exit included)
                     time.sleep(delay * trips)
-            else:
-                if delay:
-                    time.sleep(delay)
-                toks = sess.decode()
-                spec_round = False
         now = time.monotonic()
         lane.steps += 1
         lane.last_step_t = now
@@ -1657,19 +1661,14 @@ class DecodeBatcher:
                 accepted = int(counts.sum()) - n_act
                 self.metrics.note_spec(proposed, accepted)
         self._note_degraded(lane)
-        fused_plain = not lane.spec and fuse > 1
         emitted = 0
         for slot, req in list(lane.assigned.items()):
-            # a spec round commits 1..k+1 tokens per slot (a fused
-            # window up to fuse_steps); consume them in stream
-            # order with per-token EOS/max-new cuts so the emitted
-            # stream is bit-identical to the plain
-            # one-token-per-step path
-            slot_toks = [int(toks2d[slot, j])
-                         for j in range(int(counts[slot]))] \
-                if (lane.spec or fused_plain) else [int(toks[slot])]
+            # a spec round commits 1..k+1 tokens per slot, a window up
+            # to its trips; consume them in stream order with per-token
+            # EOS/max-new cuts so the emitted stream is that of
+            # one-token dispatches
             finished = None
-            for tok in slot_toks:
+            for tok in toks2d[slot, :int(counts[slot])].tolist():
                 req.gen.append(tok)
                 req.buf.append(tok)
                 emitted += 1

@@ -293,14 +293,15 @@ class ModelEntry:
         whole replica set BEFORE the `latest` flip.
 
         Decode models warm BOTH phases: every prompt-bucket prefill
-        plus the fixed-shape slot-table decode step, on a scratch
+        plus the fixed-shape slot-table decode step (one executable
+        for every window of trips the lane dispatches), on a scratch
         session per replica (the lane sessions share the resolved
         executables, so the first real stream pays no compile)."""
         if self.is_decode:
+            from ..inference.decode import STEP_WINDOW
             n_slots = self.batcher.n_slots
             spec_k = getattr(self.batcher, "spec_k", 0)
             drafts = getattr(self.batcher, "draft_replicas", None)
-            fuse = int(getattr(self.batcher, "fuse_steps", 1))
             for i, pred in enumerate(self.replicas):
                 sess = pred.new_session(n_slots)
                 for bucket in pred.prefill_buckets():
@@ -309,20 +310,21 @@ class ModelEntry:
                     # warmed with the longest SERVABLE prompt length
                     n = min(bucket, pred.max_seq_len - 1)
                     sess.prefill(0, [0] * n)
+                    # the lane's ONE step executable: how many trips a
+                    # dispatch runs is a runtime argument of it, so a
+                    # one-trip round resolves every window the lane
+                    # will run; a full window is run here all the same,
+                    # so that whatever a window touches was touched
+                    # before the flip
                     sess.decode()
+                    sess.decode_fused(STEP_WINDOW)
                     sess.free(0)
-                if fuse > 1 and not (drafts and spec_k):
-                    # fused lanes: force-resolve the (n_slots, N)
-                    # window executable so the first real dispatch
-                    # pays no compile (COMPILE_CACHE.md — the fused
-                    # fingerprint rides the warm-reload hits:N pin)
-                    pred.fused_step_fn(n_slots, fuse)
                 if drafts and spec_k:
                     # spec lanes: force-resolve the verify executable
                     # plus the draft's phases so the first real stream
                     # pays no compile on EITHER side of the flip
                     pred.verify_fn(n_slots, spec_k)
-                    if fuse > 1:
+                    if self.batcher.spec_fused:
                         pred.fused_spec_fn(drafts[i], n_slots, spec_k)
                     dsess = drafts[i].new_session(n_slots)
                     for bucket in drafts[i].prefill_buckets():
@@ -513,12 +515,13 @@ class ModelRegistry:
         never share an executable.
 
         `fuse_steps` (decode artifacts only, SERVING.md "Fused
-        multi-step decode"): each lane dispatch fuses up to this many
-        decode steps into ONE device executable (default
-        FLAGS.serving_decode_fuse_steps; 1 keeps the classic loop).
-        Streams stay bit-identical to N=1; warm() force-resolves the
-        fused-window executables so the flip pays no first-dispatch
-        compile."""
+        multi-step decode"): pins the most decode steps one lane
+        dispatch runs, at most the step executable's own window
+        (`decode.STEP_WINDOW`, the default); 1 makes every dispatch one
+        step.  How many a dispatch does run the lane decides from its
+        slot table.  Streams are the same tokens whatever the value.
+        With a draft, a value > 1 also fuses each speculative round
+        into one dispatch."""
         from .. import compile_cache
         spec = devices if devices is not None else (
             replicas if replicas is not None else self._replicas)
@@ -538,9 +541,8 @@ class ModelRegistry:
                 else (FLAGS.serving_spec_draft or None)
             if not draft_path or spec_depth < 1:
                 draft_path, spec_depth = None, 0
-            fuse_steps = max(int(FLAGS.serving_decode_fuse_steps
-                                 if fuse_steps is None
-                                 else fuse_steps), 1)
+            if fuse_steps is not None:
+                fuse_steps = max(int(fuse_steps), 1)
         else:
             kv_cache_dtype = None
             fuse_steps = None
@@ -598,8 +600,9 @@ class ModelRegistry:
             "kv_cache_dtype": (str(getattr(preds[0], "kv_cache_dtype",
                                            "float32"))
                                if entry.is_decode else None),
-            "fuse_steps": (batcher.fuse_steps
-                           if entry.is_decode else None),
+            # as given: None is the lane's own window, a value a pin
+            # (and, with a draft, fused speculative rounds)
+            "fuse_steps": fuse_steps,
         }
         if placement == [None]:
             entry.load_spec["replicas"] = 1

@@ -454,8 +454,9 @@ class InferenceServer:
                 # (QUANTIZE.md "Quantized KV cache")
                 reply["kv_cache_dtype"] = str(getattr(
                     entry.predictor, "kv_cache_dtype", "float32"))
-                # fused multi-step decode window this load dispatches
-                # (SERVING.md "Fused multi-step decode"; 1 = classic)
+                # the cap of the window this load's lanes dispatch
+                # (SERVING.md "Fused multi-step decode"; 1 = pinned to
+                # one step a dispatch)
                 reply["fuse_steps"] = int(getattr(
                     entry.batcher, "fuse_steps", 1))
                 if getattr(entry.batcher, "spec_k", 0):

@@ -319,8 +319,9 @@ class TestDefaultPlacement:
             obs_tracing.set_enabled(was)
         bucket = pred.prompt_bucket(3)
         small = {"prefill": 4 * bucket + 4,   # padded prompt, its length
-                 # lengths, last_tokens, active
-                 "step": 4 * n_slots + 4 * n_slots + n_slots}
+                 # lengths, last_tokens, active, budget, max_trips
+                 "step": 4 * n_slots + 4 * n_slots + n_slots
+                 + 4 * n_slots + 4}
         by_phase = {}
         for s in launches:
             by_phase.setdefault(s["attrs"]["phase"], set()).add(
@@ -732,9 +733,11 @@ class TestFusedDecode:
         assert metrics.tokens_per_dispatch.count == dispatches
 
     def test_fused_eos_early_exit_mid_window(self, predictor):
-        """A slot hitting EOS mid-window stops the while_loop early:
-        the dispatch returns fewer trips than the window, the EOS token
-        itself is emitted, and the stream equals the greedy oracle."""
+        """A slot hitting EOS mid-window ends the window in-graph with
+        that trip: the dispatch returns fewer trips than it was asked
+        for, the EOS token itself is emitted, no other slot sits
+        through a dead trip, and the stream equals the greedy oracle."""
+        from paddle_tpu.inference.decode import STEP_WINDOW
         # pick an eos id whose FIRST occurrence in the greedy stream is
         # mid-window (index >= 4) so the early exit is provoked for
         # real, not at the prefill token
@@ -750,16 +753,34 @@ class TestFusedDecode:
         p2 = GenerativePredictor(d)
         ref, reason = greedy_decode(p2, [5, 9, 3], 50)
         assert reason == "eos" and len(ref) == j + 1
+        # a lane in miniature: two streams, each cut and released at its
+        # own EOS; every dispatch asks for a full window
         sess = p2.new_session(2)
-        first = sess.prefill(0, [5, 9, 3])
-        n_window = j + 6   # EOS lands with trips to spare
-        toks, counts, trips = sess.decode_fused(n_window)
-        assert trips < n_window, \
-            "EOS mid-window did not early-exit the fused loop"
-        out = [first] + [int(toks[0, i]) for i in range(int(counts[0]))]
-        assert out == ref, "fused EOS stream diverged: %s vs %s" \
-            % (out, ref)
-        assert out[-1] == eos_tok
+        prompts = {0: [5, 9, 3], 1: [7, 2]}
+        refs = {i: greedy_decode(p2, p, 50)[0] for i, p in prompts.items()}
+        outs = {i: [sess.prefill(i, p)] for i, p in prompts.items()}
+        live = {i for i, o in outs.items() if o[-1] != eos_tok}
+        ran = []
+        while live:
+            # the window must end with the trip in which the first live
+            # stream meets its EOS
+            want = min([STEP_WINDOW] + [len(refs[i]) - len(outs[i])
+                                        for i in live])
+            toks, counts, trips = sess.decode_fused(STEP_WINDOW)
+            assert trips == want, (trips, want, ran)
+            # every trip of a window advances every running slot
+            assert counts.tolist() == [trips if i in live else 0
+                                       for i in (0, 1)]
+            for i in sorted(live):
+                outs[i] += toks[i, :trips].tolist()
+                if outs[i][-1] == eos_tok:
+                    sess.free(i)
+                    live.discard(i)
+            ran.append(trips)
+        assert outs == refs, "fused EOS streams diverged: %s vs %s" \
+            % (outs, refs)
+        assert outs[0][-1] == eos_tok and min(ran) < STEP_WINDOW, \
+            "EOS mid-window did not end the window with its trip"
 
     def test_fused_warm_reload_all_hits(self, artifact, tmp_path):
         """The fused executables ride the persistent compile cache
@@ -827,14 +848,201 @@ class TestFusedDecode:
             b.close()
 
 
+# ---------------------------------------------------------------------------
+# the lane picks each dispatch's window from its own slot table (PR 29)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def endless(tmp_path_factory):
+    """A tiny model that never emits EOS: a stream ends by length alone,
+    so the trips of every dispatch follow from the budgets."""
+    d = str(tmp_path_factory.mktemp("endless_model") / "lm")
+    build_tiny_decode_model(d, vocab_size=32, d_model=16, n_heads=2,
+                            n_layers=2, max_seq_len=256, eos_id=-1,
+                            seed=11)
+    return GenerativePredictor(d)
+
+
+def _dispatches(t0=None):
+    """The `serving/decode_step` spans since `t0`, in dispatch order."""
+    from paddle_tpu.obs import tracing as obs_tracing
+    steps = [s for s in obs_tracing.recent_spans(name="serving/decode_step")
+             if t0 is None or s["t0"] >= t0]
+    return sorted(steps, key=lambda s: s["attrs"]["round"])
+
+
+@pytest.fixture
+def traced():
+    from paddle_tpu.obs import tracing as obs_tracing
+    was = obs_tracing.enabled()
+    obs_tracing.set_enabled(True)
+    obs_tracing.clear()
+    yield obs_tracing
+    obs_tracing.set_enabled(was)
+
+
+def _rule(budgets, n_slots, cap):
+    """What the rule dispatches for streams admitted together with
+    `budgets` decode tokens left each: [trips] until all have ended."""
+    left, out = list(budgets), []
+    while any(left):
+        live = [b for b in left if b]
+        trips = min(cap, min(live)) if len(live) == n_slots else 1
+        out.append(trips)
+        left = [max(b - trips, 0) for b in left]
+    return out
+
+
+class TestWindowRule:
+    @pytest.mark.parametrize("cap,max_new", [
+        (None, (20, 13)), (None, (9, 9)), (4, (20, 13)), (None, (3, 30))])
+    def test_every_slot_assigned_runs_to_the_first_end(
+            self, endless, traced, cap, max_new):
+        """All slots assigned: each dispatch runs min(cap, smallest
+        remaining budget) trips, so a window ends on the round in which
+        the first slot must end; once a slot is free (nothing queued to
+        refill it) every dispatch is one trip."""
+        from paddle_tpu.inference.decode import STEP_WINDOW
+        b = DecodeBatcher(endless, n_slots=2, fuse_steps=cap)
+        assert b.fuse_steps == (cap or STEP_WINDOW)
+        try:
+            with b._cv:     # both admitted by ONE lane iteration
+                streams = [b.submit([5, 9, 3], max_new_tokens=m)
+                           for m in max_new]
+            outs = [s.result(timeout=60)[0].tolist() for s in streams]
+        finally:
+            b.close()
+        for m, out in zip(max_new, outs):
+            assert out == greedy_decode(endless, [5, 9, 3], m)[0]
+        steps = _dispatches()
+        # the prefill emitted each stream's first token
+        want = _rule([m - 1 for m in max_new], 2, b.fuse_steps)
+        assert [s["attrs"]["trips"] for s in steps] == want
+        assert max(want) > 1 and sum(s["attrs"]["tokens"] for s in steps) \
+            == sum(max_new) - 2
+
+    def test_a_free_slot_means_one_trip(self, endless, traced):
+        """A slot free: a newcomer could be admitted on the next round,
+        so no dispatch runs more than one trip and a late joiner waits
+        one round, not a window."""
+        b = DecodeBatcher(endless, n_slots=3)
+        try:
+            with b._cv:
+                first = [b.submit([5, 9, 3], max_new_tokens=12),
+                         b.submit([7, 2], max_new_tokens=10)]
+            # joins mid-flight into the free slot: the lane is then full
+            # and may run windows, which is the rule, not a leak
+            for s in first:
+                s.result(timeout=60)
+        finally:
+            b.close()
+        steps = _dispatches()
+        assert steps and {s["attrs"]["trips"] for s in steps} == {1}
+        assert {s["attrs"]["slots"] for s in steps} <= {1, 2}
+
+    def test_cancel_inside_a_window_is_honoured_at_its_boundary(
+            self, endless, traced):
+        """A cancel that lands while a window runs frees the slot at
+        that window's end: no dispatch BEGINS after the cancel but the
+        housekeeping one that finds it."""
+        set_dispatch_delay(0.02)        # a window of 8 lasts ~160 ms
+        b = DecodeBatcher(endless, n_slots=1)
+        try:
+            s = b.submit([5, 9, 3], max_new_tokens=200)
+            deadline = time.monotonic() + 30
+            while len(s.tokens) < 9 and time.monotonic() < deadline:
+                time.sleep(0.005)
+            t_cancel = time.monotonic()
+            s.cancel()
+            while b.slot_occupancy()[0] and time.monotonic() < deadline:
+                time.sleep(0.005)
+            assert b.slot_occupancy()[0] == 0
+            freed_after = time.monotonic() - t_cancel
+        finally:
+            set_dispatch_delay(0.0)
+            b.close()
+        from paddle_tpu.obs import tracing as obs_tracing
+        off = time.time() - time.monotonic()
+        began_after = [d for d in _dispatches()
+                       if d["ts"] - off > t_cancel]
+        assert len(began_after) <= 1, began_after
+        # within the window that was running (8 x 20 ms) plus host slack
+        assert freed_after < 8 * 0.02 + 0.5, freed_after
+
+    def test_deadline_inside_a_window_overshoots_under_one_dispatch(
+            self, endless, traced):
+        """The governor at the built-in cap: the lane's EWMA step time
+        clamps the trips of the dispatch that would cross the deadline,
+        so the eviction lands within about one dispatch of it."""
+        from paddle_tpu.obs import events as obs_events
+        b = DecodeBatcher(endless, n_slots=1)
+        try:
+            b.submit([4, 4], max_new_tokens=10).result(timeout=60)
+            set_dispatch_delay(0.03)
+            s = b.submit([5, 9, 3], max_new_tokens=200,
+                         deadline=time.monotonic() + 1.0,
+                         trace_id="window-deadline")
+            with pytest.raises(DeadlineExceeded):
+                s.result(timeout=30)
+        finally:
+            set_dispatch_delay(0.0)
+            b.close()
+        ev = [e for e in obs_events.recent_events(kind="deadline_expired")
+              if e.get("trace_id") == "window-deadline"]
+        assert ev and ev[-1]["overshoot_ms"] is not None
+        # one dispatch is at most 8 x 30 ms; the clamp keeps the last one
+        # shorter than that
+        assert ev[-1]["overshoot_ms"] <= 8 * 30.0 + 300.0, ev[-1]
+        # the warm-up stream ran [8, 1]; then full windows while the
+        # deadline is far, and a clamped one once the EWMA has seen the
+        # step's cost
+        trips = [d["attrs"]["trips"] for d in _dispatches()][2:]
+        assert trips[0] == 8 and trips[-1] < 8, trips
+
+    @pytest.mark.parametrize("n_slots", [2, 3])
+    def test_churn_streams_equal_a_lane_pinned_to_one_trip(
+            self, predictor, n_slots):
+        """Joins and leaves over more requests than slots (EOS cuts
+        among them): every stream of the lane that chooses its windows
+        equals the stream of a lane pinned to one trip."""
+        rng = np.random.RandomState(5)
+        reqs = [[int(x) for x in rng.randint(1, 32, size=n)]
+                for n in (2, 5, 3, 7, 1, 4, 6, 2, 3)]
+        budgets = [6, 3, 19, 2, 12, 7, 25, 1, 9]
+        outs, dispatches = {}, {}
+        for cap in (None, 1):
+            metrics = ServingMetrics().model("lm")
+            b = DecodeBatcher(predictor, n_slots=n_slots, metrics=metrics,
+                              fuse_steps=cap)
+            try:
+                streams = []
+                for i, (p, m) in enumerate(zip(reqs, budgets)):
+                    streams.append(b.submit(p, max_new_tokens=m))
+                    if i % 3 == 2:
+                        time.sleep(0.02)        # some join mid-flight
+                outs[cap] = [s.result(timeout=60)[0].tolist()
+                             for s in streams]
+            finally:
+                b.close()
+            dispatches[cap] = metrics.decode_dispatches.value
+            assert metrics.decode_tokens.value \
+                == sum(len(o) for o in outs[cap])
+        assert outs[None] == outs[1]
+        # (how many dispatches each lane took depends on when the joins
+        # land against its rounds; the rule's arithmetic is held by
+        # `test_every_slot_assigned_runs_to_the_first_end`)
+        assert min(dispatches.values()) >= 1
+
+
 def test_fused_gate_smoke(artifact, predictor):
     """The ci_checks.sh `fused_decode` gate body (exit 17): a served
-    fuse_steps=4 stream is BIT-EXACT vs the N=1 greedy oracle and the
-    dispatch count amortizes (~N tokens per dispatch)."""
+    stream of a lane whose window is pinned to 4 is BIT-EXACT vs the
+    one-step greedy oracle and, with every slot of the lane assigned,
+    the dispatch count amortizes (~N tokens per dispatch)."""
     server = InferenceServer().start()
     cli = ServingClient(server.endpoint)
     try:
-        loaded = cli.load_model("lm", artifact, decode_slots=2,
+        loaded = cli.load_model("lm", artifact, decode_slots=1,
                                 fuse_steps=4)
         assert loaded.get("fuse_steps") == 4
         for prompt, budget in [([5, 9, 3], 12), ([1, 2, 3, 4], 9)]:
@@ -1047,7 +1255,8 @@ class TestStepInPlace:
                 assert (a[:, 0, lengths[0]] == 0).all() \
                     and b[:, 0, lengths[0]].any()
             assert sess.slot_is_zero(3) and sess.slot_is_zero(4)
-        assert list(sess.lengths) == [8, S + 3, 5, 0, 0]
+        # the full slot ran no trip: its length stays at S
+        assert list(sess.lengths) == [8, S, 5, 0, 0]
 
     def test_tokens_logits_and_table_equal_the_where_stack_form(
             self, inplace_pred):

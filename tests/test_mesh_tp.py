@@ -51,8 +51,7 @@ import jax
 PROMPT = [3, 5, 7, 9, 11]
 BUDGET = 12
 
-_FLAGS = ["mesh_tp", "mesh_tp_prefill_seq", "serving_decode_fuse_steps",
-          "compile_cache_dir"]
+_FLAGS = ["mesh_tp", "mesh_tp_prefill_seq", "compile_cache_dir"]
 
 
 @pytest.fixture(autouse=True)
@@ -192,9 +191,16 @@ class TestTPDecodeParity:
         md = _lm(tmp_path)
         devs = jax.devices()
         ref, _ = _stream(md, devs[0])
-        set_flags({"serving_decode_fuse_steps": 4})
-        out, pm = _stream(md, MeshGroup(devs[:2]))
+        pm = GenerativePredictor(md, device=MeshGroup(devs[:2]))
         assert pm.tp_active
+        # the partitioned step executable, windows of 4 trips and a tail
+        sess = pm.new_session(4)
+        out = [sess.prefill(1, PROMPT)]
+        while len(out) < BUDGET:
+            toks, counts, trips = sess.decode_fused(
+                4, max_trips=min(4, BUDGET - len(out)))
+            assert trips == counts[1] >= 1
+            out.extend(int(t) for t in toks[1, :counts[1]])
         assert out == ref
 
     def test_spec_twin_accepts_everything_under_tp(self, tmp_path):

@@ -706,7 +706,10 @@ class TestPhaseSpans:
         by = {s["name"]: s for s in obs.recent_spans(kind="serving")}
         assert set(by) == {"decode/put", "decode/launch", "decode/fetch"}
         assert {s["attrs"]["phase"] for s in by.values()} == {"step"}
-        small = 4 * 4 + 4 * 4 + 4        # lengths, last_tokens, active
+        # lengths, last_tokens, active, budget, max_trips
+        small = 4 * 4 + 4 * 4 + 4 + 4 * 4 + 4
+        # all three carry the trips the dispatch ran
+        assert {s["attrs"]["trips"] for s in by.values()} == {1}
         put, launch = by["decode/put"]["attrs"], by["decode/launch"]["attrs"]
         # the weights are resident under either placement: the launch
         # uploads no more than the small arguments `_put` left on the host
@@ -715,7 +718,10 @@ class TestPhaseSpans:
             assert (put["bytes"], launch["h2d_bytes"]) == (small, 0)
         else:
             assert (put["bytes"], launch["h2d_bytes"]) == (0, small)
-        assert by["decode/fetch"]["attrs"]["d2h_bytes"] == 4 * 4
+        # ONE int32 vector: the window's token block, 4 counts, the trips
+        from paddle_tpu.inference.decode import STEP_WINDOW
+        assert by["decode/fetch"]["attrs"]["d2h_bytes"] \
+            == 4 * (4 * STEP_WINDOW + 4 + 1)
         assert (by["decode/put"]["t0"] <= by["decode/launch"]["t0"]
                 <= by["decode/fetch"]["t0"])
 
@@ -736,7 +742,10 @@ class TestPhaseSpans:
         spans = obs.recent_spans(kind="serving")
         iters = [s for s in spans if s["name"] == "serving/lane_iter"]
         steps = [s for s in spans if s["name"] == "serving/decode_step"]
-        assert iters and len(steps) >= 5
+        # both slots assigned: ONE window to the round in which the
+        # shorter stream must end (3 trips), then a slot is free and
+        # every dispatch is one trip
+        assert iters and [s["attrs"]["trips"] for s in steps] == [3, 1, 1]
         assert sum(i["attrs"]["admits"] for i in iters) == 2
         assert sum(i["attrs"]["emitted"] for i in iters) == \
             sum(s["attrs"]["tokens"] for s in steps) == 6 + 4 - 2

@@ -289,8 +289,12 @@ def test_routing_counters_ride_the_fetch(opened):
         == int(pre[:, 1].max())
     assert by_phase["step"]["moe_experts_touched"] == k * L
     assert by_phase["step"]["moe_tokens_per_expert_max"] == 1
-    # tokens and facts came in one vector: 3 tokens + 2 per layer, int32
-    assert by_phase["step"]["d2h_bytes"] == 4 * (3 + 2 * L)
+    # everything a dispatch returns came in ONE int32 vector: the step
+    # window's token block, 3 counts, the trips, 2 facts per layer
+    from paddle_tpu.inference.decode import STEP_WINDOW
+    assert by_phase["step"]["d2h_bytes"] \
+        == 4 * (3 * STEP_WINDOW + 3 + 1 + 2 * L)
+    assert by_phase["step"]["trips"] == 1
 
 
 def test_default_block_step_returns_what_it_did(tmp_path):
@@ -309,7 +313,10 @@ def test_default_block_step_returns_what_it_did(tmp_path):
         set_flags({"trace": False})
     assert pred.routed_layers == 0 and sess.last_routing is None
     assert all("moe_experts_touched" not in a for a in attrs)
-    assert [a["d2h_bytes"] for a in attrs] == [4, 8]
+    # a prefill's token; a step's window block, 2 counts and the trips
+    from paddle_tpu.inference.decode import STEP_WINDOW
+    assert [a["d2h_bytes"] for a in attrs] \
+        == [4, 4 * (2 * STEP_WINDOW + 2 + 1)]
 
 
 # (e) every phase runs the block: `_block` is the one decoder layer ---------
@@ -353,10 +360,14 @@ def test_every_phase_runs_the_block_and_keeps_the_plain_stream(
     if phase == "fused_window":
         sess = pred.new_session(4)               # slot 3 stays free
         first = [sess.prefill(i, p) for i, p in enumerate(prompts)]
-        toks, counts, trips = sess.decode_fused(n - 1)
-        assert trips == n - 1 and counts.tolist() == [n - 1] * 3 + [0]
-        assert [[f] + toks[i].tolist() for i, f in enumerate(first)] \
-            == plain
+        got = [[f] for f in first]
+        while len(got[0]) < n:                   # windows of 8 and of 3
+            toks, counts, trips = sess.decode_fused(n - len(got[0]))
+            assert trips == min(n - len(got[0]), dec.STEP_WINDOW) \
+                and counts.tolist() == [trips] * 3 + [0]
+            for i, g in enumerate(got):
+                g.extend(toks[i, :trips].tolist())
+        assert got == plain
         assert sess.slot_is_zero(3)
         return
     twin = not phase.startswith("other_draft")
@@ -418,3 +429,128 @@ def test_block_keys_reach_the_fingerprint(tmp_path):
     b2 = dict(b, model=a["model"])
     from paddle_tpu import compile_cache as cc
     assert cc.fingerprint_key(a) != cc.fingerprint_key(b2)
+
+
+# ---------------------------------------------------------------------------
+# a window's records (PR 29): what the step's dispatch stamps and returns
+# when it runs several trips, and what the benchmark's readers make of it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("trips", [1, 5])
+def test_a_window_reports_what_its_trips_touched(opened, trips):
+    """`trips` one-trip dispatches against ONE dispatch of `trips`: the
+    same tokens, `moe_experts_touched` the sum over the trips (and the
+    layers), `moe_tokens_per_expert_max` the largest; the window's three
+    `decode/*` spans are `phase="step"` and carry the trips it ran."""
+    pred = opened[0]
+    prompts = _prompts([9, 4, 12], seed=21)
+
+    def admitted():
+        sess = pred.new_session(4)               # slot 3 stays free
+        for i, p in enumerate(prompts):
+            sess.prefill(i, p)
+        return sess
+    one = admitted()
+    steps, facts = [], []
+    for _ in range(trips):
+        steps.append(one.decode()[:3].tolist())
+        facts.append(one.last_routing.copy())
+    set_flags({"trace": True})
+    obs_tracing.clear()
+    try:
+        win = admitted()
+        obs_tracing.clear()
+        toks, counts, ran = win.decode_fused(trips)
+        spans = {s["name"]: s["attrs"] for s in obs_tracing.recent_spans()
+                 if s["name"].startswith("decode/")}
+    finally:
+        set_flags({"trace": False})
+    assert ran == trips and counts.tolist() == [trips] * 3 + [0]
+    assert toks[:3].T.tolist() == steps
+    facts = np.stack(facts)                                 # [trips, L, 2]
+    assert win.last_routing.tolist() == np.stack(
+        [facts[:, :, 0].sum(axis=0), facts[:, :, 1].max(axis=0)],
+        axis=1).tolist()
+    assert set(spans) == {"decode/put", "decode/launch", "decode/fetch"}
+    assert {a["phase"] for a in spans.values()} == {"step"}
+    assert {a["trips"] for a in spans.values()} == {trips}
+    assert spans["decode/fetch"]["moe_experts_touched"] \
+        == int(facts[:, :, 0].sum())
+    assert spans["decode/fetch"]["moe_tokens_per_expert_max"] \
+        == int(facts[:, :, 1].max())
+
+
+def test_benchmark_readers_read_a_lane_of_windows(opened):
+    """The rehearsed tiny cell's spans (a closed loop over a full lane,
+    so the lane runs windows) through the benchmark's own readers:
+    `moe_ffn_roofline` finds its `phase="step"` fetches with the summed
+    routing facts and reads a share between 0 and 100, not `null`;
+    `decode_trips_per_dispatch` reads more than one trip a dispatch and
+    `decode_launch_ms_per_round` finds the dispatches' launches."""
+    import time
+    from benchmark import run as bench_run
+    from benchmark import xplane
+    pred = opened[0]
+    set_flags({"trace": True})
+    obs_tracing.clear()
+    b = DecodeBatcher(pred, n_slots=2)
+    t0 = time.monotonic()
+    try:
+        with b._cv:
+            streams = [b.submit(p, max_new_tokens=20)
+                       for p in _prompts([9, 4, 12, 7], seed=4)]
+        for s in streams:
+            s.result(timeout=120)
+    finally:
+        b.close()
+        set_flags({"trace": False})
+    t1 = time.monotonic()
+    off = time.time() - time.monotonic()
+    spans = [{"name": s["name"], "t0": s["ts"] - off,
+              "t1": s["ts"] - off + s["dur_ms"] * 1e-3,
+              "attrs": s.get("attrs", {})}
+             for s in obs_tracing.recent_spans()]
+    steps = [s for s in spans if s["name"] == "serving/decode_step"]
+    assert max(s["attrs"]["trips"] for s in steps) > 1
+    # a device that spent a third of every dispatch in the routed FFN
+    ops = [("%fusion.3 = f32[4,64] fusion(...)", s["t0"] - t0,
+            s["t0"] - t0 + (s["t1"] - s["t0"]) / 3.0) for s in steps]
+    trace = xplane.Trace({0: ops})
+    trace.anchor = (0.0, 0.0, t0)
+    run = {"window": (t0, t1), "trace_window_monotonic": (t0, t1),
+           "slots": 2, "scope_ops": {"moe_ffn": ["fusion.3"]},
+           "device_kind": "TPU v5 lite",
+           "meta": dict(pred.meta, **pred._block_meta)}
+    share = bench_run.load_reader("moe_ffn_roofline")(spans, trace, run)
+    assert share is not None and 0.0 < share < 100.0
+    # the cost is linear in tokens and in experts touched: the reader's
+    # numerator is what the same rounds cost dispatched one trip at a time
+    from benchmark import costs_moe, peaks
+    L = pred.meta["n_layers"]
+    fetch = {s["attrs"]["round"]: s["attrs"] for s in spans
+             if s["name"] == "decode/fetch"
+             and s["attrs"].get("phase") == "step"}
+    bytes_ = sum(L * costs_moe.moe_ffn_cost(
+        s["attrs"]["tokens"],
+        fetch[s["attrs"]["round"]]["moe_experts_touched"] / float(L),
+        pred.meta["d_model"], 32, 8, 2)[1] for s in steps)
+    busy = sum(e - a for _, a, e in ops)
+    assert share == pytest.approx(
+        100.0 * bytes_ / peaks.peaks_for("TPU v5 lite")["hbm_bytes_per_s"]
+        / busy, rel=1e-6)
+    trips = bench_run.load_reader("decode_trips_per_dispatch")(
+        spans, trace, run)
+    assert trips == pytest.approx(
+        sum(s["attrs"]["trips"] for s in steps) / float(len(steps)))
+    assert 1.0 < trips <= dec.STEP_WINDOW
+    assert bench_run.load_reader("decode_launch_ms_per_round")(
+        spans, trace, run) > 0.0
+    # a program whose step is one decode step stamps no trips on its
+    # fetches (the parent commit): each counts as one trip.  No step's
+    # fetch at all: no reading
+    bare = [dict(s, attrs={k: v for k, v in s["attrs"].items()
+                           if k != "trips"}) for s in spans]
+    assert bench_run.load_reader("decode_trips_per_dispatch")(
+        bare, trace, run) == 1.0
+    assert bench_run.load_reader("decode_trips_per_dispatch")(
+        [s for s in spans if s["name"] != "decode/fetch"], trace, run) is None

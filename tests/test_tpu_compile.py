@@ -14,6 +14,8 @@ A compile that passes is not a chip run: results and times come from
 
 import os
 
+import re
+
 import numpy as np
 import pytest
 
@@ -232,35 +234,69 @@ def assert_table_updated_in_place(compiled, table_shape, n_kernels,
 
 @pytest.mark.parametrize("model", sorted(STEP_MODELS))
 def test_decode_step_updates_the_table_in_place(one_chip, model):
+    """The lane's step executable, which is a WINDOW of up to
+    `decode.STEP_WINDOW` trips (PR 30), at the geometry of each decode
+    cell: `gpt2_small` at 32 slots and 12 layers, `olmoe_1b_7b` at 8 slots
+    and the cell's 4 layers."""
     meta, slots = STEP_MODELS[model]
+    routed = model == "olmoe_1b_7b"
+    if routed:
+        meta = dict(meta, n_layers=4)
     device = list(one_chip.device_set)[0]
     pred, state = described_predictor(meta, device)
-    compiled = compile_phase(pred, state, pred._step_math,
-                             pred._step_specs(slots))
+    specs = pred._step_specs(slots)
+    compiled = compile_phase(pred, state, pred._step_math(), specs)
     # rows padded to the kernel's tile: (12, 64) -> (16, 128), (16, 128) as
     # it is, so that row-major is the device's own layout for the table
     assert pred.table_row() == (16, 128)
-    assert_table_updated_in_place(compiled, pred.table_shape(slots),
-                                  n_kernels=meta["n_layers"])
+    # its `while` body carries the table, and no table- or layer-sized copy
+    # may sit inside the loop either.  XLA hoists the bf16 rounding of the
+    # dense matmuls' weights out of the loop: the head and, a layer, the
+    # four projections (and gpt2_small's MLP), two bytes a weight:
+    # gpt2_small 0.25 GB, under a tenth of its table; olmoe_1b_7b at the
+    # cell's depth 0.21 + 4 x 0.034 = 0.34 GB (the issue's 0.35 GB was
+    # PR 29's reading at 2 layers, 0.28, with room)
+    D, V, L = meta["d_model"], meta["vocab_size"], meta["n_layers"]
+    hoisted = 2 * (D * V + L * (4 * D * D + (0 if routed else 8 * D * D)))
+    text = assert_table_updated_in_place(
+        compiled, pred.table_shape(slots), n_kernels=L,
+        temporaries=hoisted + 0.02e9)
+    # ONE executable for every trip count: the trips of a dispatch are its
+    # last argument, a scalar the one `while` is bounded by at run time
+    assert [(s.shape, str(s.dtype)) for s in specs[-2:]] \
+        == [((slots,), "int32"), ((), "int32")]
+    assert text.count(" while(") == 1 and ("ragged-dot" in text) == routed
+    assert re.search(r"ENTRY [^\n]*\bmax_trips[\w.]*: s32\[\]", text), \
+        "max_trips is not a runtime parameter"
+    if routed:
+        # the 3.2 GB of experts go to the grouped-matmul kernels as they
+        # are: nothing whose result is a whole expert tensor but a
+        # parameter (or a view of one)
+        E, D, F = (meta[k] for k in ("n_experts", "d_model", "expert_width"))
+        whole = re.compile(r"\[%d,(%d,%d|%d,%d)\]" % (E, D, F, F, D))
+        made = [m.group(0) for m in re.finditer(
+            r"(%[\w.\-]+) = (\S+) ([\w\-]+)\(", text)
+            if whole.search(m.group(2)) and m.group(3) not in (
+                "parameter", "bitcast", "get-tuple-element")]
+        assert not made, made[:6]
 
 
-def test_fused_window_updates_the_table_in_place(one_chip):
-    """The same of the fused window, whose `while` body carries the table:
-    no table- or layer-sized copy inside the loop either."""
+def test_step_logits_updates_the_table_in_place(one_chip):
+    """The one-step phase with no window around it (`step_logits_fn`, what
+    a logit-level comparison against a reference reads): in place, with
+    nothing hoisted, so temporaries under a tenth of a table."""
     meta, slots = STEP_MODELS["gpt2_small"]
     device = list(one_chip.device_set)[0]
     pred, state = described_predictor(meta, device)
-    i32 = np.dtype(np.int32)
-    specs = pred._step_specs(slots) + (
-        jax.ShapeDtypeStruct((slots,), i32), jax.ShapeDtypeStruct((), i32))
-    compiled = compile_phase(pred, state, pred._fused_step_math(8), specs)
+    compiled = compile_phase(pred, state, pred._step_logits,
+                             pred._table_specs(slots))
     text = assert_table_updated_in_place(compiled, pred.table_shape(slots),
                                          n_kernels=meta["n_layers"])
-    assert " while(" in text
+    assert " while(" not in text
 
 
 def _verify_specs(pred, slots, k):
-    cache, _, lengths, _, active = pred._step_specs(slots)
+    cache, _, lengths, _, active = pred._table_specs(slots)
     return (cache, cache, lengths,
             jax.ShapeDtypeStruct((slots, k + 1), np.dtype(np.int32)), active)
 
@@ -280,32 +316,12 @@ def test_verify_updates_the_table_in_place(one_chip):
                                   n_kernels=5 * meta["n_layers"])
 
 
-def test_fused_window_takes_the_routed_block(one_chip):
-    """The fused window at the OLMoE geometry (refused before PR 28): the
-    `while` body is the plain step with the routing facts dropped, the
-    table in place as there.  XLA hoists the bf16 rounding of the dense
-    matmuls' weights out of the loop (the head 0.21 GB, the projections
-    0.07 GB at this depth); the 3.2 GB of experts go to the grouped-matmul
-    kernels as they are and must NOT be copied."""
-    meta, slots = STEP_MODELS["olmoe_1b_7b"]
-    device = list(one_chip.device_set)[0]
-    pred, state = described_predictor(meta, device)
-    i32 = np.dtype(np.int32)
-    specs = pred._step_specs(slots) + (
-        jax.ShapeDtypeStruct((slots,), i32), jax.ShapeDtypeStruct((), i32))
-    compiled = compile_phase(pred, state, pred._fused_step_math(8), specs)
-    text = assert_table_updated_in_place(compiled, pred.table_shape(slots),
-                                         n_kernels=meta["n_layers"],
-                                         temporaries=0.35e9)
-    assert " while(" in text and "ragged-dot" in text
-
-
 def test_int8_table_step_compiles_in_place(one_chip):
     meta, slots = STEP_MODELS["gpt2_small"]
     meta = dict(meta, n_layers=2)
     device = list(one_chip.device_set)[0]
     pred, state = described_predictor(meta, device, kv="int8")
-    compiled = compile_phase(pred, state, pred._step_math,
+    compiled = compile_phase(pred, state, pred._step_math(),
                              pred._step_specs(slots))
     ma = compiled.memory_analysis()
     table = int(np.prod(pred.table_shape(slots)))    # int8, rows (16, 128)

@@ -435,8 +435,10 @@ def run_decode_lane(args, device):
     `tokens_per_sec_per_slot` at equal step cost, spec_k=N vs 0.
 
     Fused-decode sweep (SERVING.md "Fused multi-step decode"):
-    `--fuse_steps 1,4,16` pins the batcher's per-dispatch window per
-    point; `--host_cost_ms` charges the per-DISPATCH host round-trip
+    `--fuse_steps 1,4,8` pins the cap of the lane's per-dispatch
+    window per point (1: every dispatch one step; past
+    `decode.STEP_WINDOW` it is clamped; without the option the lane
+    picks its windows under the built-in cap); `--host_cost_ms` charges the per-DISPATCH host round-trip
     the window amortizes (once per dispatch, however many trips run).
     Because the bit-exact replay goes through the loaded server, each
     fused point PROVES its stream equals the N=1 greedy oracle before
@@ -455,7 +457,7 @@ def run_decode_lane(args, device):
     # fused-decode sweep (SERVING.md "Fused multi-step decode"): one
     # fresh server per window so the amortization curve is honest
     fuse_points = [int(s) for s in args.fuse_steps.split(",")
-                   if s.strip() != ""] if args.fuse_steps else [1]
+                   if s.strip() != ""] if args.fuse_steps else [None]
     # KV-cache dtype A/B (QUANTIZE.md "Quantized KV cache"): one fresh
     # server per cache dtype, identical seeded workloads — the ratio
     # columns read the 4x cache-byte cut directly
@@ -492,7 +494,7 @@ def run_decode_lane(args, device):
                     decode_mode="static" if mode == "static" else None,
                     draft=draft_dir, spec_k=spec_k if draft_dir else 0,
                     kv_cache_dtype=kv_dtype,
-                    fuse_steps=fuse if fuse > 1 else None,
+                    fuse_steps=fuse,
                     replicas=args.replicas
                     if not args.replicas.isdigit()
                     or args.replicas != "1"
@@ -1367,7 +1369,7 @@ def main():
     ap.add_argument("--fuse_steps", default=None,
                     help="fused multi-step decode sweep (SERVING.md "
                          "\"Fused multi-step decode\"): comma list of "
-                         "per-dispatch windows ('1,4,16'); each point "
+                         "per-dispatch window caps ('1,4,8'); each point "
                          "gets a fresh server with the batcher's "
                          "fuse_steps pinned, a per-point bit-exact "
                          "replay vs the N=1 greedy stream, and "

@@ -201,7 +201,7 @@ def render(reply, health=None, fleet=None):
         # ACC% is the speculative-decoding lifetime draft accept rate
         # (absent without a draft — target-only lanes show "-").
         # TPD is lifetime tokens-per-dispatch — the fused-decode
-        # amortization ratio (≈ fuse_steps when windows run full)
+        # amortization ratio (≈ the window's cap when the lane is full)
         ttft = (m.get("ttft_ms") or {}).get("p95")
         tps = m.get("tokens_per_sec")
         dispatches = m.get("decode_dispatches")
@@ -264,7 +264,8 @@ def render(reply, health=None, fleet=None):
             if d.get("decode"):
                 extra = " decode_slots=%s max_seq_len=%s" % (
                     d.get("decode_slots"), d.get("max_seq_len"))
-                if d.get("fuse_steps") and int(d["fuse_steps"]) > 1:
+                if d.get("fuse_steps"):
+                    # the cap of the lane's per-dispatch window
                     extra += " fuse_steps=%s" % (d["fuse_steps"],)
                 if d.get("spec_k"):
                     extra += " spec_k=%s draft=%s" % (

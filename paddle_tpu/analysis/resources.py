@@ -826,14 +826,13 @@ def _decode_report(path, meta, decode_slots, device, what,
     resolution the GenerativePredictor makes, so the admission fit
     check statically reads ~0.25x KV bytes for an int8-cache load
     (int8 slots + the per-(layer,head) fp32 scale table)."""
+    import numpy as np
     from ..flags import FLAGS
-    from ..inference.decode import normalize_kv_dtype, table_row
+    from ..inference.decode import normalize_kv_dtype, slot_state_shapes
     n_slots = int(decode_slots or FLAGS.serving_decode_slots)
     L = int(meta["n_layers"])
     H = int(meta["n_heads"])
     D = int(meta["d_model"])
-    S = int(meta["max_seq_len"])
-    dh = D // H
     kv_dtype = normalize_kv_dtype(
         kv_cache_dtype if kv_cache_dtype is not None
         else (meta.get("kv_cache_dtype")
@@ -845,7 +844,6 @@ def _decode_report(path, meta, decode_slots, device, what,
         from ..native import wire
         with open(state_path, "rb") as f:
             state = wire.decode(f.read())
-        import numpy as np
         rep.param_bytes = sum(int(np.asarray(v).nbytes)
                               for v in state.values())
         rep.actual_param_bytes = rep.param_bytes
@@ -855,18 +853,20 @@ def _decode_report(path, meta, decode_slots, device, what,
             if os.path.exists(state_path) else 0
         rep.actual_param_bytes = rep.param_bytes
         n_params = rep.param_bytes // 4
-    # K and V, [L, n_slots, S, Hp, Dp] each at the cache dtype's width
-    # (4 B fp32, 1 B int8 + the fp32 scale table), a row (Hp, Dp) as
-    # the placement's table holds it (padded to the kernel's tile on one
-    # TPU device) — must match GenerativePredictor.kv_cache_bytes
-    # exactly (pinned by tests/test_resources.py)
+    # K and V, [attention layers, n_slots, S, Hp, Dp] each at the cache
+    # dtype's width (4 B fp32, 1 B int8 + the fp32 scale table), a row
+    # (Hp, Dp) of the K/V heads as the placement's table holds it (padded
+    # to the kernel's tile on one TPU device) — must match
+    # GenerativePredictor.kv_cache_bytes exactly (pinned by
+    # tests/test_resources.py)
     kv_elem = 1 if kv_dtype == "int8" else 4
     kv_scales = 2 * L * H * 4 if kv_dtype == "int8" else 0
-    hp, dp = table_row(H, dh, device)
-    rep.kv_cache_bytes = (2 * L * n_slots * S * hp * dp * kv_elem
-                          + kv_scales)
-    # decode-step working set: one token's activations per slot
-    rep.activation_peak_bytes = n_slots * D * 4 * (L + 2)
+    kv_shape, conv_shape = slot_state_shapes(meta, n_slots, device)
+    rep.kv_cache_bytes = 2 * int(np.prod(kv_shape)) * kv_elem + kv_scales
+    # decode-step working set: one token's activations per slot, and the
+    # conv layers' carried state (K-1 inputs a slot and layer, fp32)
+    rep.activation_peak_bytes = n_slots * D * 4 * (L + 2) + (
+        4 * int(np.prod(conv_shape)) if conv_shape else 0)
     # one decode step: every weight multiplies once per slot, and the
     # whole KV cache streams through the attention gather; a fused
     # dispatch is N such steps back-to-back at the same peak

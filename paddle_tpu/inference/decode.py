@@ -13,19 +13,27 @@ TOGETHER.  This module is the inference-side half of the answer
   record (vocab, layers, heads, max_seq_len, eos id, prefill buckets)
   in the typed wire format, detected by `decode_meta.bin` the way the
   AOT predictor is detected by `aot_meta.bin`.  The meta also DESCRIBES
-  the decoder block (`BLOCK_DEFAULTS`: LayerNorm | RMSNorm, learned |
-  rotary positions, qk-norm, ReLU MLP | dropless routed SwiGLU experts);
-  an artifact that names none of those keys is the GPT-2-shaped block.
+  the decoder stack (`BLOCK_DEFAULTS`: LayerNorm | RMSNorm, learned |
+  rotary positions, qk-norm over the projection or per head, multi-head
+  | grouped-query attention, a layer's operator attention | a gated
+  short convolution (`layer_types`), leading dense SwiGLU layers, ReLU
+  MLP | dropless routed SwiGLU experts under a softmax or a sigmoid,
+  bias-corrected router, a head of its own | tied); an artifact that
+  names none of those keys is the GPT-2-shaped block in every layer.
   A PHASE is: embed the tokens at their positions, run ONE per-layer
   function (`GenerativePredictor._block`) once a layer with the phase's
-  own `attend(q, k, v)`, apply the head.  `_block` is the only spelling
-  of a decoder layer and every phase runs every block (prefill, the
-  step, the speculative verify, sequence-parallel prefill, the fused
-  windows); a phase owns only what `attend` does with K and V.  Rows
-  are written to a slot table by ONE scatter (`_land`) and cleared by
-  ONE scatter of zeros (`_clear_rows`).  Only the tensor-parallel
-  placement refuses a block other than the default, naming the meta
-  key: its grammar has no rule for sharding experts;
+  own `attend(q, k, v)` and `convolve(z, taps)`, apply the head.
+  `_block` is the only spelling of a decoder layer and every phase
+  runs every stack it can (prefill, the step and its fused window for
+  all; the speculative verify and a rollback for stacks without a
+  recurrent layer); a phase owns only what `attend` does with K and V
+  and where `convolve` finds a position's earlier inputs.  Rows
+  are written to a K/V slot table by ONE scatter (`_land`) and cleared
+  by ONE scatter of zeros (`_clear_rows`).  What a placement or a phase
+  cannot hold is refused by a typed error that names the meta key (the
+  tensor-parallel lane any block but the default: its grammar has no
+  rule for sharding experts; a mesh, a rollback, the speculative
+  phases and an int8 cache a stack with conv layers);
 * a **prefill / decode phase split** (`GenerativePredictor`): prefill
   runs the whole prompt through the causal forward once per padded
   *prompt bucket* (each bucket's executable rides the persistent
@@ -35,9 +43,12 @@ TOGETHER.  This module is the inference-side half of the answer
   table — XLA compiles it exactly once per (n_slots) geometry, and
   every later step, whatever mix of requests occupies the slots, reuses
   that executable;
-* a **slot-indexed KV cache** (`DecodeSession`): [layers, n_slots,
-  max_seq_len, heads, head_dim] arrays resident on the session's
-  device, ONE buffer each for K and V that every write updates in
+* **slot-indexed state of two kinds** (`DecodeSession`,
+  `slot_state_shapes`): the KV cache, [attention layers, n_slots,
+  max_seq_len, K/V heads, head_dim] arrays, and for a stack with conv
+  layers their conv state, [conv layers, n_slots, taps - 1, d_model],
+  a fixed size a slot; resident on the session's
+  device, ONE buffer each (K, V, conv state) that every write updates in
   place (a step's rows, an admission, a release: each call is given
   the table donated and the session keeps the result; SERVING.md "The
   slot table is ONE buffer").  A request owns one slot from prefill to
@@ -111,6 +122,7 @@ streams are those of one-trip dispatches token for token: a window
 moves slot joins/leaves to its boundaries without moving a token.
 """
 
+import functools
 import hashlib
 import json
 import os
@@ -212,38 +224,65 @@ def _default_prefill_buckets(max_seq_len):
     return buckets
 
 
-# The decoder block an artifact's meta describes.  An artifact that names
+# The decoder stack an artifact's meta describes.  An artifact that names
 # none of these keys is the GPT-2-shaped block this module began with
-# (LayerNorm, learned absolute positions, MHA, ReLU MLP), so every
-# artifact written before the keys existed opens unchanged.
+# (LayerNorm, learned absolute positions, MHA, ReLU MLP, its own head) in
+# every layer, so every artifact written before the keys existed opens
+# unchanged.
 BLOCK_DEFAULTS = (
     ("norm", "layernorm"),        # | "rmsnorm" (gain only, no bias)
     ("norm_eps", 1e-5),
     ("position", "learned"),      # | "rope" (half-split rotary over the
     ("rope_theta", 10000.0),      #   whole head, no position table)
-    ("qk_norm", False),           # RMSNorm of the whole q / k projection
+    ("qk_norm", False),           # True: RMSNorm of the whole q / k
+                                  # projection | "head": of each head (a
+                                  # gain of the head size), before RoPE
     ("ffn", "relu_mlp"),          # | "moe_swiglu" (dropless routed experts)
     ("n_experts", 0),
     ("experts_per_token", 0),
     ("expert_width", 0),
     ("norm_topk_prob", False),    # renormalise the kept router weights
+    # a layer's OPERATOR, layer by layer: "attention" | "conv" (a gated
+    # short convolution: depthwise, causal, `conv_kernel` taps, whose slot
+    # state is its last conv_kernel - 1 inputs); () = attention everywhere
+    ("layer_types", ()),
+    ("conv_kernel", 0),
+    ("n_kv_heads", 0),            # K/V heads (grouped-query: query head a
+                                  # reads K/V head a // group); 0 = n_heads
+    ("n_dense_layers", 0),        # the first layers' FFN is ONE dense
+    ("dense_width", 0),           # SwiGLU of this width, the rest are `ffn`
+    ("router", "softmax"),        # | "sigmoid_bias": sigmoid scores, top-k
+                                  # of score + a per-expert bias, weights
+                                  # from the unbiased scores
+    ("head", "untied"),           # | "tied": logits = norm(x) @ embed.T
 )
 _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "position": ("learned", "rope"),
-                  "ffn": ("relu_mlp", "moe_swiglu")}
+                  "qk_norm": (False, True, "head"),
+                  "ffn": ("relu_mlp", "moe_swiglu"),
+                  "router": ("softmax", "sigmoid_bias"),
+                  "head": ("untied", "tied")}
+_LAYER_TYPES = ("attention", "conv")
 
 
 def block_of(meta):
-    """The block description of a decode artifact's meta: every key of
+    """The stack description of a decode artifact's meta: every key of
     BLOCK_DEFAULTS, defaulted where the meta is silent, typed and
     checked.  A value this module has no math for is a typed error that
     names the key."""
     out = {}
     for key, default in BLOCK_DEFAULTS:
-        v = type(default)(meta.get(key, default))
+        v = meta.get(key, default)
+        if key == "qk_norm":
+            v = bool(v) if isinstance(v, (bool, int)) else v
+        elif key == "layer_types":
+            v = tuple(str(t) for t in v)
+        else:
+            v = type(default)(v)
         if key in _BLOCK_CHOICES and v not in _BLOCK_CHOICES[key]:
             raise ValueError("decode meta %s=%r is not one of %s"
-                             % (key, v, "|".join(_BLOCK_CHOICES[key])))
+                             % (key, v, "|".join(
+                                 str(c) for c in _BLOCK_CHOICES[key])))
         out[key] = v
     if out["ffn"] == "moe_swiglu" and not (
             1 <= out["experts_per_token"] <= out["n_experts"]
@@ -253,38 +292,113 @@ def block_of(meta):
             "<= n_experts (%d) and expert_width (%d) >= 1"
             % (out["experts_per_token"], out["n_experts"],
                out["expert_width"]))
+    n_layers, n_heads = int(meta["n_layers"]), int(meta["n_heads"])
     if out["position"] == "rope" and (
-            int(meta["d_model"]) // int(meta["n_heads"])) % 2:
+            int(meta["d_model"]) // n_heads) % 2:
         raise ValueError("decode meta position=rope needs an even "
                          "head size")
+    kinds = out["layer_types"]
+    if kinds and (len(kinds) != n_layers
+                  or any(t not in _LAYER_TYPES for t in kinds)
+                  or "attention" not in kinds):
+        raise ValueError(
+            "decode meta layer_types=%r needs one of %s for each of the %d "
+            "layers, and an attention layer among them"
+            % (list(kinds), "|".join(_LAYER_TYPES), n_layers))
+    if "conv" in kinds and out["conv_kernel"] < 2:
+        raise ValueError("decode meta conv_kernel=%d: a conv layer needs "
+                         "at least 2 taps" % out["conv_kernel"])
+    if out["n_kv_heads"] and (out["n_kv_heads"] < 0
+                              or n_heads % out["n_kv_heads"]):
+        raise ValueError("decode meta n_kv_heads=%d does not divide "
+                         "n_heads %d" % (out["n_kv_heads"], n_heads))
+    if not 0 <= out["n_dense_layers"] <= n_layers or (
+            out["n_dense_layers"] and out["dense_width"] < 1):
+        raise ValueError(
+            "decode meta n_dense_layers=%d needs 0 <= it <= n_layers (%d) "
+            "and dense_width (%d) >= 1"
+            % (out["n_dense_layers"], n_layers, out["dense_width"]))
+    if out["router"] != "softmax" and out["ffn"] != "moe_swiglu":
+        raise ValueError("decode meta router=%r goes with ffn=moe_swiglu"
+                         % out["router"])
     return out
+
+
+def layer_kinds(meta, blk=None):
+    """(operator, FFN) of every layer of the stack `meta` describes
+    (`blk`: its `block_of`, where the caller has it): operator
+    "attention" | "conv", FFN "dense_swiglu" (the first
+    `n_dense_layers`) or the meta's `ffn`."""
+    blk = blk or block_of(meta)
+    n = int(meta["n_layers"])
+    ops = blk["layer_types"] or ("attention",) * n
+    return [(ops[i], "dense_swiglu" if i < blk["n_dense_layers"]
+             else blk["ffn"]) for i in range(n)]
+
+
+def slot_state_shapes(meta, n_slots, device):
+    """The two kinds of state a slot of an `n_slots` session on `device`
+    holds, as (K/V table shape, conv-state table shape or None):
+
+      * [attention layers, N, S, Hp, Dp]: a K (or V) row for every cached
+        position of every ATTENTION layer, addressed by the slot's length;
+        (Hp, Dp) is `table_row` of the K/V heads;
+      * [conv layers, N, conv_kernel - 1, D]: the last inputs of every
+        CONV layer's filter, a fixed size whatever the slot's length;
+        None for a stack with no conv layer."""
+    blk = block_of(meta)
+    ops = [op for op, _ in layer_kinds(meta, blk)]
+    H, D = int(meta["n_heads"]), int(meta["d_model"])
+    row = table_row(blk["n_kv_heads"] or H, D // H, device)
+    kv = (ops.count("attention"), int(n_slots),
+          int(meta["max_seq_len"])) + row
+    n_conv = ops.count("conv")
+    return kv, ((n_conv, int(n_slots), blk["conv_kernel"] - 1, D)
+                if n_conv else None)
 
 
 def decode_state_shapes(meta):
     """{weight name: shape} of the state a decode artifact with this
     meta holds — what `save_decode_model` checks a state against."""
     blk = block_of(meta)
-    V, D, L, S = (int(meta[k]) for k in
-                  ("vocab_size", "d_model", "n_layers", "max_seq_len"))
+    V, D, H, S = (int(meta[k]) for k in
+                  ("vocab_size", "d_model", "n_heads", "max_seq_len"))
+    Dh = D // H
+    kv_width = (blk["n_kv_heads"] or H) * Dh
     norm_bias = blk["norm"] == "layernorm"
-    shapes = {"embed": (V, D), "lm_head": (D, V), "lnf_g": (D,)}
+    shapes = {"embed": (V, D), "lnf_g": (D,)}
+    if blk["head"] == "untied":
+        shapes["lm_head"] = (D, V)
     if norm_bias:
         shapes["lnf_b"] = (D,)
     if blk["position"] == "learned":
         shapes["pos"] = (S, D)
-    for i in range(L):
+    for i, (op, ffn) in enumerate(layer_kinds(meta, blk)):
         p = "l%d_" % i
-        for n in ("wq", "wk", "wv", "wo"):
-            shapes[p + n] = (D, D)
         for n in ("ln1", "ln2"):
             shapes[p + n + "_g"] = (D,)
             if norm_bias:
                 shapes[p + n + "_b"] = (D,)
-        if blk["qk_norm"]:
-            shapes[p + "qn_g"] = shapes[p + "kn_g"] = (D,)
-        if blk["ffn"] == "moe_swiglu":
+        if op == "conv":
+            shapes[p + "conv_in"] = (D, 3 * D)
+            shapes[p + "conv_w"] = (D, blk["conv_kernel"])
+            shapes[p + "conv_out"] = (D, D)
+        else:
+            shapes[p + "wq"] = shapes[p + "wo"] = (D, D)
+            shapes[p + "wk"] = shapes[p + "wv"] = (D, kv_width)
+            if blk["qk_norm"] == "head":
+                shapes[p + "qn_g"] = shapes[p + "kn_g"] = (Dh,)
+            elif blk["qk_norm"]:
+                shapes[p + "qn_g"], shapes[p + "kn_g"] = (D,), (kv_width,)
+        if ffn == "dense_swiglu":
+            F = blk["dense_width"]
+            shapes[p + "ffn_gate"] = shapes[p + "ffn_up"] = (D, F)
+            shapes[p + "ffn_down"] = (F, D)
+        elif ffn == "moe_swiglu":
             E, F = blk["n_experts"], blk["expert_width"]
             shapes[p + "router"] = (D, E)
+            if blk["router"] == "sigmoid_bias":
+                shapes[p + "expert_bias"] = (E,)
             shapes[p + "w_gate"] = shapes[p + "w_up"] = (E, D, F)
             shapes[p + "w_down"] = (E, F, D)
         else:
@@ -340,7 +454,9 @@ def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
     max_seq_len) — every bucket is one warm-up compile.  `block` names
     the decoder block's meta keys (BLOCK_DEFAULTS; None: the GPT-2-shaped
     one): its weights are drawn in name order, matrices normal(0,
-    1/sqrt(fan_in)), gains 1, biases 0."""
+    1/sqrt(fan_in)) (a conv layer's taps fan in over the taps), gains 1,
+    biases 0, a router's expert bias normal(0, 0.05) (zero would make
+    selection by biased score the selection by score)."""
     if d_model % n_heads:
         raise ValueError("d_model %d not divisible by n_heads %d"
                          % (d_model, n_heads))
@@ -355,12 +471,15 @@ def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
                                              for b in prefill_buckets)
         state = {}
         for name, shape in sorted(decode_state_shapes(meta).items()):
-            if len(shape) == 1:
+            if name.endswith("_expert_bias"):
+                state[name] = (0.05 * rng.randn(*shape)).astype(np.float32)
+            elif len(shape) == 1:
                 state[name] = (np.ones if name.endswith("_g")
                                else np.zeros)(shape, np.float32)
             else:
+                fan_in = shape[-1 if name.endswith("_conv_w") else -2]
                 state[name] = (rng.randn(*shape) / np.sqrt(
-                    shape[-2])).astype(np.float32)
+                    fan_in)).astype(np.float32)
         return save_decode_model(dirname, state, meta)
     scale = 1.0 / np.sqrt(d_model)
 
@@ -427,7 +546,7 @@ def _rope(x, positions, theta):
 
 
 def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
-            live=None):
+            live=None, expert_bias=None, picks=None):
     """Dropless, exact top-k routed SwiGLU experts: h [T, D], router
     [D, E], w_gate / w_up [E, D, F], w_down [E, F, D] ->
     (sum over each token's k experts of p_e * ((silu(h @ w_gate[e]) *
@@ -447,21 +566,35 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     k-th against the (k+1)-th expert on a near-tie, which is a different
     function and not a rounding.  `norm_topk_prob` renormalises the kept
     weights to sum to 1; without it they are the softmax's own values.
+    With `expert_bias` [E] (meta router=sigmoid_bias) the scores are
+    sigmoids, the k experts are those of the largest score + bias, their
+    weights the UNBIASED scores, renormalised over (their sum + 1e-6).
 
     facts = (experts that received a token, most tokens one expert
     received), counted over the tokens `live` [T] marks (all if None):
-    a dead slot's or a pad position's row is computed but not counted."""
+    a dead slot's or a pad position's row is computed but not counted.
+    A list given as `picks` receives the chosen experts [T, k] i32 (at
+    trace time): what a comparison with a reference needs to tell the
+    router's near-ties from a fault (`_step_logits`)."""
     import jax
     import jax.numpy as jnp
     T, E, k = h.shape[0], router.shape[1], int(k)
     with jax.named_scope("moe_ffn"):
         with jax.named_scope("moe_router"):
-            p = jax.nn.softmax(jnp.dot(
-                h.astype(jnp.float32), router,
-                precision=jax.lax.Precision.HIGHEST), axis=-1)
-            w, idx = jax.lax.top_k(p, k)                        # [T, k]
-            if norm_topk_prob:
-                w = w / jnp.sum(w, axis=-1, keepdims=True)
+            logits = jnp.dot(h.astype(jnp.float32), router,
+                             precision=jax.lax.Precision.HIGHEST)
+            if expert_bias is None:
+                w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+                if norm_topk_prob:
+                    w = w / jnp.sum(w, axis=-1, keepdims=True)
+            else:
+                p = jax.nn.sigmoid(logits)
+                _, idx = jax.lax.top_k(p + expert_bias, k)      # [T, k]
+                w = jnp.take_along_axis(p, idx, axis=-1)
+                if norm_topk_prob:
+                    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+        if picks is not None:
+            picks.append(idx.astype(jnp.int32))
         flat = idx.reshape(T * k)
         onehot = flat[:, None] == jnp.arange(E)[None]           # [T*k, E]
         sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)        # [E]
@@ -483,26 +616,23 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
 
 def _pack_routing(tokens, facts):
     """A routed-expert phase's first result: its tokens, then each
-    layer's (experts touched, most tokens on one expert), as ONE int32
+    ROUTED layer's (experts touched, most tokens on one expert; a layer
+    with a dense FFN has `None` for its facts), as ONE int32
     vector, so the routing facts ride the fetch that brings the tokens
     (`DecodeSession._fetch` splits them off again)."""
     import jax.numpy as jnp
-    return jnp.concatenate([tokens.reshape(-1).astype(jnp.int32),
-                            jnp.stack(facts).reshape(-1)])
-
-
-def _is_table(spec):
-    """A slot table among a phase's arguments or results: the 5-D leaf
-    [L, N, S, H, Dh] (a dict is the draft's weights)."""
-    return not isinstance(spec, dict) and len(spec.shape) == 5
+    return jnp.concatenate([
+        tokens.reshape(-1).astype(jnp.int32),
+        jnp.stack([f for f in facts if f is not None]).reshape(-1)])
 
 
 def table_row(n_heads, head_dim, device):
-    """(Hp, Dp): one cached position's K (or V) row as a slot table on
-    `device` (a jax.Device, a MeshGroup, or None: jax's default device)
-    holds it.  On ONE TPU device that is (H, Dh) rounded up to the
-    (8, 128) tile of the decode kernel's operands, the pad exact zeros;
-    everywhere else (H, Dh) itself.
+    """(Hp, Dp): one cached position's K (or V) row, `n_heads` K/V heads
+    of `head_dim`, as a K/V slot table on `device` (a jax.Device, a
+    MeshGroup, or None: jax's default device) holds it.  On ONE TPU
+    device that is (H, Dh) rounded up to the (8, 128) tile of the decode
+    kernel's operands, the pad exact zeros; everywhere else (H, Dh)
+    itself.
 
     Why: a Mosaic operand is row-major with its last two axes in the
     tile, so the kernel streams GPT-2 small's (12, 64) rows as (16, 128)
@@ -587,12 +717,14 @@ _SLOT_WRITERS = []
 
 def _slot_writers():
     """(write_rows, zero_slot, clear_rows): the three eager writes of a
-    slot table [L, N, S, Hp, Dp], jitted with the table DONATED so that
-    they land in place.  `write_rows(table, rows [L, 1, B, H, Dh], slot)`
+    slot-state table (K/V [L, N, S, Hp, Dp] or conv state [L, N, K-1, D]),
+    jitted with the table DONATED so that they land in place.
+    `write_rows(table, rows [L, 1, B, H, Dh], slot)`
     puts `rows`, padded to the table's row, at `slot` from position 0 (a
-    prefill's K or V);
+    prefill's K or V, or its conv state [L, 1, K-1, D] whole);
     `zero_slot(table, slot)` zeroes the slot's whole row (its release);
-    `clear_rows` is `_clear_rows` (a rollback), one executable per depth.
+    `clear_rows` is `_clear_rows` (a rollback of a K/V table), one
+    executable per depth.
     Undonated, each was a copy of the whole table (1.2 GB at GPT-2 small
     with 32 slots: ~3 ms of the device and a transient table in memory),
     twice for every admission and every release, with the chip's memory
@@ -604,14 +736,14 @@ def _slot_writers():
 
         def write_rows(table, rows, slot):
             return jax.lax.dynamic_update_slice(
-                table, _pad_rows(rows, table.shape[3:]),
-                (0, slot, 0, 0, 0))
+                table, _pad_rows(rows, table.shape[-2:]),
+                (0, slot) + (0,) * (table.ndim - 2))
 
         def zero_slot(table, slot):
             z = jnp.zeros((table.shape[0], 1) + table.shape[2:],
                           table.dtype)
-            return jax.lax.dynamic_update_slice(table, z,
-                                                (0, slot, 0, 0, 0))
+            return jax.lax.dynamic_update_slice(
+                table, z, (0, slot) + (0,) * (table.ndim - 2))
 
         _SLOT_WRITERS.extend(jax.jit(fn, donate_argnums=0)
                              for fn in (write_rows, zero_slot))
@@ -628,7 +760,7 @@ def _mark_dead(phase, exc, *sessions):
     later round.  A call that raises before it donates (a poison check,
     a bad argument) leaves the session as it was."""
     for sess in sessions:
-        if sess._kc.is_deleted() or sess._vc.is_deleted():
+        if any(t.is_deleted() for t in sess._tables()):
             sess._dead = (phase, "%s: %s" % (type(exc).__name__, exc))
 
 
@@ -808,6 +940,10 @@ class GenerativePredictor:
                 from paddle_tpu.flags import FLAGS
                 self._kv_dtype = normalize_kv_dtype(
                     FLAGS.serving_kv_cache_dtype)
+            if self._kv_dtype == "int8":
+                # the scales are per (layer, head) of an all-attention
+                # multi-head table, calibrated through a prefill of it
+                self._require_plain_stack("an int8 KV cache")
             # per-(layer, head) symmetric fp32 scales [2, L, H, 1]
             # (K row 0, V row 1), a deterministic function of the
             # weights — baked into the traced phases as constants
@@ -830,6 +966,9 @@ class GenerativePredictor:
             group = as_mesh_group(device)
         if group is not None:
             from paddle_tpu.flags import FLAGS
+            # a mesh shards the K/V tables by heads at rest and has no
+            # rule for a conv state
+            self._require_attention_stack("a mesh placement")
             if FLAGS.mesh_tp:
                 # no fall-back to the gather path for a block the TP
                 # grammar cannot split: it was asked for by name
@@ -897,13 +1036,64 @@ class GenerativePredictor:
         BLOCK_DEFAULTS, as the artifact's meta gives or defaults it."""
         return dict(self._block_meta)
 
+    @functools.cached_property
+    def layer_kinds(self):
+        """[(operator, FFN)] of the stack, layer by layer (module-level
+        `layer_kinds`; worked out once: the lane asks on every
+        dispatch)."""
+        return layer_kinds(self.meta, self._block_meta)
+
+    def _table_layer(self, i):
+        """Where layer i's slot state lies in the table of its kind (the
+        K/V tables hold the attention layers only, the conv-state table
+        the conv layers): its rank among the layers of its operator."""
+        ops = [op for op, _ in self.layer_kinds]
+        return ops[:i].count(ops[i])
+
+    @functools.cached_property
+    def conv_layers(self):
+        """Layers whose operator is a gated short convolution: those
+        with a row in the conv-state table."""
+        return [op for op, _ in self.layer_kinds].count("conv")
+
     @property
     def routed_layers(self):
-        """Layers with a routed-expert FFN: n_layers under
-        ffn=moe_swiglu, else 0.  The step and the prefill of such an
-        artifact return their routing facts behind their tokens."""
-        return (int(self.meta["n_layers"])
-                if self._block_meta["ffn"] == "moe_swiglu" else 0)
+        """Layers with a routed-expert FFN (under ffn=moe_swiglu, those
+        past the leading dense ones).  The step and the prefill of such
+        an artifact return their routing facts behind their tokens."""
+        return [ffn for _, ffn in self.layer_kinds].count("moe_swiglu")
+
+    @property
+    def _step_picks(self):
+        """Whether `step_logits_fn` hands out the routed layers' picks:
+        a stack with conv layers and routed FFNs."""
+        return bool(self.conv_layers and self.routed_layers)
+
+    def _require_attention_stack(self, what):
+        """Raise for what has no rule for a RECURRENT layer's state: a
+        conv layer's state is a window of its last inputs, rolled on
+        every token, so it cannot be undone by moving a slot's length
+        back (a rollback, the speculative verify and its rejected
+        suffix), and neither the mesh grammar nor the int8 cache's
+        per-head scales know it."""
+        if self.conv_layers:
+            raise NotImplementedError(
+                "%s has no rule for a conv layer's slot state, and this "
+                "artifact's meta has layer_types=%r (that state is the "
+                "layer's last inputs, rolled by every token: moving a "
+                "slot's length back does not undo it, and it is neither "
+                "sharded by heads nor scaled a head)"
+                % (what, list(self._block_meta["layer_types"])))
+
+    def _require_plain_stack(self, what):
+        """Raise for what is written for all-attention multi-head
+        tables only, naming the meta key that asks for another."""
+        self._require_attention_stack(what)
+        if self._kv_heads() != self._dims()[1]:
+            raise NotImplementedError(
+                "%s is written for a multi-head table, and this "
+                "artifact's meta has n_kv_heads=%d under n_heads=%d"
+                % (what, self._kv_heads(), self._dims()[1]))
 
     def _require_default_block(self, what):
         """Raise for a placement that can hold only the GPT-2-shaped
@@ -999,30 +1189,52 @@ class GenerativePredictor:
     # -- static byte accounting (ANALYSIS.md resource analysis) ---------
 
     def table_row(self):
-        """(Hp, Dp): a cached position's row in this placement's slot
-        tables (module-level `table_row`)."""
-        _, H, Dh, _ = self._dims()
-        return table_row(H, Dh, self._device)
+        """(Hp, Dp): a cached position's row in this placement's K/V
+        slot tables (module-level `table_row` of the K/V heads)."""
+        return table_row(self._kv_heads(), self._dims()[2], self._device)
 
     def table_shape(self, n_slots):
-        """[L, n_slots, S, Hp, Dp]: the slot table (K or V) of an
-        `n_slots` session of this predictor."""
-        return (self._dims()[0], int(n_slots), self.max_seq_len) \
-            + self.table_row()
+        """[attention layers, n_slots, S, Hp, Dp]: the K (or V) slot
+        table of an `n_slots` session of this predictor
+        (`slot_state_shapes`)."""
+        return self._slot_state_shapes(n_slots)[0][:3] + self.table_row()
+
+    def conv_state_shape(self, n_slots):
+        """[conv layers, n_slots, conv_kernel - 1, D]: the conv-state
+        table of an `n_slots` session; None for a stack with no conv
+        layer."""
+        return self._slot_state_shapes(n_slots)[1]
+
+    def _slot_state_shapes(self, n_slots):
+        """`slot_state_shapes` of this predictor, kept a slot count."""
+        memo = self.__dict__.setdefault("_slot_shapes", {})
+        n = int(n_slots)
+        if n not in memo:
+            memo[n] = slot_state_shapes(self.meta, n, self._device)
+        return memo[n]
 
     def kv_cache_bytes(self, n_slots):
-        """Closed-form slot-table KV cache footprint for an `n_slots`
-        session: K and V, `table_shape(n_slots)` each at the CACHE
-        dtype's width (4 B fp32, 1 B int8 — plus the int8 cache's
-        per-(layer, head) fp32 scale table) — the HBM term that bounds
-        decode slots (FLAGS.serving_decode_slots) and the number the
-        admission fit check adds per replica.  Off a single TPU device
-        it matches analysis/resources.py's `_decode_report` pricing
-        exactly; there the rows are padded to the tile (`table_row`)."""
+        """Closed-form K/V slot-table footprint for an `n_slots`
+        session: K and V, `table_shape(n_slots)` each (the ATTENTION
+        layers' rows, as the table holds them: padded to the tile on one
+        TPU device, `table_row`) at the CACHE dtype's width (4 B fp32,
+        1 B int8 — plus the int8 cache's per-(layer, head) fp32 scale
+        table) — the HBM term that bounds decode slots
+        (FLAGS.serving_decode_slots) and the number the admission fit
+        check adds per replica; analysis/resources.py's `_decode_report`
+        prices the same shape.  The conv layers' state is
+        `conv_state_bytes`, apart."""
         L, H, _, _ = self._dims()
         elem = 1 if self._kv_quant else 4
         scales = 2 * L * H * 4 if self._kv_quant else 0
         return 2 * int(np.prod(self.table_shape(n_slots))) * elem + scales
+
+    def conv_state_bytes(self, n_slots):
+        """Closed-form footprint of the conv layers' slot state for an
+        `n_slots` session (fp32; 0 for a stack with no conv layer): a
+        fixed size a slot, whatever the slot's length."""
+        shape = self.conv_state_shape(n_slots)
+        return 4 * int(np.prod(shape)) if shape else 0
 
     def param_bytes(self):
         """Static weight footprint (host-state nbytes sum)."""
@@ -1047,6 +1259,9 @@ class GenerativePredictor:
         return (int(m["n_layers"]), int(m["n_heads"]),
                 int(m["d_model"]) // int(m["n_heads"]),
                 int(m["d_model"]))
+
+    def _kv_heads(self):
+        return self._block_meta["n_kv_heads"] or int(self.meta["n_heads"])
 
     # -- int8 KV cache: quantization epilogues --------------------------
 
@@ -1091,10 +1306,10 @@ class GenerativePredictor:
         slices to the resident head block — same per-head scale, same
         quantized byte as the single-device write."""
         import jax.numpy as jnp
-        first, kc, vc = self._prefill_core(state, tokens, true_len,
-                                           tp=tp)
+        out = self._prefill_core(state, tokens, true_len, tp=tp)
         if not self._kv_quant:
-            return first, kc, vc
+            return out
+        first, kc, vc = out
         sc = tp.head_scales(self._kv_scales, kc.shape[3])  # [2, L, Hl, 1]
         kq = self._quantize_kv(
             kc, sc[0][:, None, None]).astype(jnp.int8)
@@ -1126,20 +1341,29 @@ class GenerativePredictor:
 
     def _head(self, state, x, tp):
         """logits [..., vocab] of x [..., D]: the final norm and the
-        `lm_head`; under TP the vocab-sharded logits reassemble (exact
-        data movement) before the replicated argmax."""
-        logits = self._norm(x, state, "lnf") @ state["lm_head"]
+        `lm_head` (under head=tied the embedding table itself, read
+        transposed: one table in memory); under TP the vocab-sharded
+        logits reassemble (exact data movement) before the replicated
+        argmax."""
+        x = self._norm(x, state, "lnf")
+        if self._block_meta["head"] == "tied":
+            return x @ state["embed"].T
+        logits = x @ state["lm_head"]
         return tp.all_gather(logits, axis=logits.ndim - 1)
 
     def _prefill_core(self, state, tokens, true_len, tp=_OFF_MESH):
         """tokens [1, B] int32, true_len scalar int32 -> (first_token
-        [] int32, k/v [L, 1, B, H, Dh] fp32 with pad positions zeroed).
+        [] int32, k/v [attention layers, 1, B, Hkv, Dh] fp32 with pad
+        positions zeroed[, conv state [conv layers, 1, K-1, D]: each conv
+        layer's last K-1 inputs before the TRUE prompt end, not the
+        bucket's, zeros where the prompt is shorter]).
         Under TP (inside shard_map) weights are local shards:
         the returned K/V carry this member's HEAD block [L, 1, B, H/m,
         Dh] (the cache's at-rest layout), attention is head-parallel
         (exact per head), and each column->row pair closes with one
         psum; long buckets divert to the bit-exact sequence-parallel
         body instead."""
+        import jax
         import jax.numpy as jnp
         L, _, Dh, _ = self._dims()
         B = tokens.shape[1]
@@ -1149,22 +1373,36 @@ class GenerativePredictor:
                                              tp)
         x = self._embed(state, tokens, slice(B), tp)
         positions = jnp.arange(B)[None]                     # [1, B]
-        ks, vs, facts = [], [], []
+        ks, vs, facts, conv = [], [], [], []
+        group = self._dims()[1] // self._kv_heads()
 
         def attend(q, k, v):
             ks.append(k)
             vs.append(v)
+            if group > 1:
+                # query head a reads K/V head a // group
+                k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
             return _causal_attention(q, k, v, scale)
 
+        def convolve(z, taps):
+            # z [1, B, D], taps [D, K]: position t reads z[t - (K-1) .. t]
+            K = taps.shape[1]
+            zp = jnp.pad(z, ((0, 0), (K - 1, 0), (0, 0)))
+            conv.append(jax.lax.dynamic_slice_in_dim(zp, true_len, K - 1,
+                                                     axis=1))
+            return sum(taps[:, j] * zp[:, j:j + B] for j in range(K))
+
         for i in range(L):
-            x, f = self._block(state, "l%d_" % i, x, positions, attend,
-                               positions[0] < true_len, tp=tp)
+            x, f = self._block(state, i, x, positions, attend,
+                               positions[0] < true_len, tp=tp,
+                               convolve=convolve)
             facts.append(f)
         first = jnp.argmax(self._head(state, x, tp)[0, true_len - 1],
                            axis=-1).astype(jnp.int32)
         if self.routed_layers:
             first = _pack_routing(first, facts)
-        return (first,) + _zero_pad_positions(ks, vs, true_len)
+        out = (first,) + _zero_pad_positions(ks, vs, true_len)
+        return out + ((jnp.stack(conv),) if conv else ())
 
     def _norm(self, x, state, name):
         """The block's norm over the last axis with the weights
@@ -1175,44 +1413,78 @@ class GenerativePredictor:
         return _ln(x, state[name + "_g"], state[name + "_b"],
                    blk["norm_eps"])
 
-    def _block(self, state, p, x, positions, attend, live, tp=_OFF_MESH):
-        """ONE decoder layer, as the artifact's meta describes it
+    def _block(self, state, i, x, positions, attend, live, tp=_OFF_MESH,
+               convolve=None, picks=None):
+        """Layer i of the stack, as the artifact's meta describes it
         (BLOCK_DEFAULTS), for every phase: x [..., D] with one position
-        per leading index, weights `state[p + name]`.  `attend(q, k, v)`
-        gets the layer's q/k/v [..., Hl, Dh] (normed and rotated where
-        the block says so — the cache holds rotated K) and returns the
-        attention output in q's shape; what it does with k and v
-        (collect them, write them to the slot table) is the phase's.
-        `live` [tokens] marks the rows a routed FFN counts.  Returns
-        (x', routing facts [2] i32 or None).  Under TP each column->row
-        pair closes with one psum."""
+        per leading index, weights `state["l<i>_" + name]`: the layer's
+        operator on the normed x, then its FFN on the normed sum.
+
+        An ATTENTION layer: `attend(q, k, v)` gets q [..., Hl, Dh] and
+        k / v [..., K/V heads, Dh] (normed and rotated where the block
+        says so — the cache holds rotated K) and returns the attention
+        output in q's shape; what it does with k and v (collect them,
+        write them to the slot table) is the phase's.  A CONV layer (a
+        gated short convolution): B, C, u = split3(h @ conv_in); y = C *
+        `convolve(B * u, taps [D, K])` @ conv_out, where `convolve`
+        returns, at each position, the taps' sum over that position's
+        input and the K-1 before it; where those come from (the
+        sequence, the slot's conv state) and what is kept of them is the
+        phase's.  `live` [tokens] marks the rows a routed FFN counts,
+        and a list given as `picks` receives its chosen experts.
+        Returns (x', routing facts [2] i32 or None).  Under TP each
+        column->row pair closes with one psum."""
+        import contextlib
+        import jax
         import jax.numpy as jnp
         blk = self._block_meta
         _, H, Dh, D = self._dims()
+        op, ffn = self.layer_kinds[i]
+        p = "l%d_" % i
         Hl = H // tp.size
         lead = x.shape[:-1]
         h = self._norm(x, state, p + "ln1")
 
-        def project(w, gain=None):
+        def project(w, heads, gain=None):
             t = h @ state[p + w]
-            if gain and blk["qk_norm"]:
+            if gain and blk["qk_norm"] is True:
                 # over the whole projection, before the split into heads
                 t = _rms(t, state[p + gain], blk["norm_eps"])
-            return t.reshape(lead + (Hl, Dh))
+            t = t.reshape(lead + (heads, Dh))
+            if gain and blk["qk_norm"] == "head":
+                t = _rms(t, state[p + gain], blk["norm_eps"])
+            return t
 
-        q, k, v = project("wq", "qn_g"), project("wk", "kn_g"), project("wv")
-        if blk["position"] == "rope":
-            q = _rope(q, positions, blk["rope_theta"])
-            k = _rope(k, positions, blk["rope_theta"])
-        x = x + tp.psum(attend(q, k, v).reshape(lead + (Hl * Dh,))
-                        @ state[p + "wo"])
+        if op == "conv":
+            with jax.named_scope("short_conv"):
+                b, c, u = jnp.split(h @ state[p + "conv_in"], 3, axis=-1)
+                x = x + (c * convolve(b * u, state[p + "conv_w"])) \
+                    @ state[p + "conv_out"]
+        else:
+            Hkv = self._kv_heads() // tp.size
+            with (jax.named_scope("gqa_attention") if Hkv != Hl
+                  else contextlib.nullcontext()):
+                q, k, v = (project("wq", Hl, "qn_g"),
+                           project("wk", Hkv, "kn_g"), project("wv", Hkv))
+                if blk["position"] == "rope":
+                    q = _rope(q, positions, blk["rope_theta"])
+                    k = _rope(k, positions, blk["rope_theta"])
+                x = x + tp.psum(attend(q, k, v).reshape(lead + (Hl * Dh,))
+                                @ state[p + "wo"])
         h2 = self._norm(x, state, p + "ln2")
-        if blk["ffn"] == "moe_swiglu":
+        if ffn == "dense_swiglu":
+            with jax.named_scope("dense_ffn"):
+                return x + (jax.nn.silu(h2 @ state[p + "ffn_gate"])
+                            * (h2 @ state[p + "ffn_up"])) \
+                    @ state[p + "ffn_down"], None
+        if ffn == "moe_swiglu":
             y, facts = moe_ffn(
                 h2.reshape(-1, D), state[p + "router"],
                 state[p + "w_gate"], state[p + "w_up"],
                 state[p + "w_down"], blk["experts_per_token"],
-                blk["norm_topk_prob"], live)
+                blk["norm_topk_prob"], live,
+                expert_bias=state[p + "expert_bias"]
+                if blk["router"] == "sigmoid_bias" else None, picks=picks)
             return x + y.reshape(x.shape), facts
         mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
                           0.0) @ state[p + "w2"]
@@ -1263,8 +1535,7 @@ class GenerativePredictor:
                                 tp.axis)
 
         for i in range(L):
-            p = "l%d_" % i
-            x, _ = self._block(whole(p), p, x, positions, attend,
+            x, _ = self._block(whole("l%d_" % i), i, x, positions, attend,
                                positions[0] < true_len)
         xg = tp.all_gather(x, axis=1)            # [1, B, D] whole
         logits = self._head(whole("lnf_", "lm_head"), xg, _OFF_MESH)
@@ -1273,7 +1544,8 @@ class GenerativePredictor:
         return (first,) + _zero_pad_positions(ks, vs, true_len)
 
     def _write(self, kc, vc, i, where, k_new, v_new, tp):
-        """(kc', vc'): layer i's new rows `_land`ed at `where` in the
+        """(kc', vc'): the new rows of the K/V tables' layer i (an
+        attention layer's `_table_layer`) `_land`ed at `where` in the
         carried tables.  Under int8 they quantize in-graph first
         (every phase through here, so a row is the same byte whichever
         phase wrote it) and the attention dequantizes in-register —
@@ -1285,15 +1557,19 @@ class GenerativePredictor:
         return _land(kc, i, where, k_new), _land(vc, i, where, v_new)
 
     def _attend_table(self, q, kc, vc, lengths, ahead, i, tp):
-        """The decode kernel over layer i of the carried tables: q
+        """The decode kernel over layer i of the carried K/V tables: q
         [N, Hl, Dh], slot n under its first `lengths[n] + ahead`
         positions -> [N, Hl, Dh].  The kernel reads the layer of the
         stacked table through its block index maps
-        (`decode_attention(..., layer=i)`): no layer is sliced out."""
+        (`decode_attention(..., layer=i)`): no layer is sliced out.
+        Where the table holds fewer heads than q has (grouped-query),
+        the kernel streams each K/V row once for its group of query
+        heads."""
         from paddle_tpu.ops.pallas_kernels import (
             decode_attention, decode_attention_head_slice)
         row = kc.shape[3:]
         Hl, Dh = q.shape[1:]
+        group = self._dims()[1] // self._kv_heads()
         scale = 1.0 / np.sqrt(Dh)
         scales = self._kv_scales[:, i] if self._kv_quant else None
         if tp.size > 1:
@@ -1303,22 +1579,32 @@ class GenerativePredictor:
                 q, kc, vc, lengths + ahead, tp.index() * Hl, Hl,
                 scale=scale, kv_scales=scales, layer=i)
         # the table's rows may be padded to the kernel's tile
-        # (`table_row`): q goes in padded alike (zero heads, zero lanes),
-        # a padded head's scale is 1 (its rows are zeros), and the pad
-        # comes off the result
+        # (`table_row`): q goes in padded alike (zero heads, zero lanes;
+        # a padded K/V head's `group` query heads), a padded head's
+        # scale is 1 (its rows are zeros), and the pad comes off the
+        # result
         if scales is not None:
             scales = np.pad(np.asarray(scales)[..., 0],
                             ((0, 0), (0, row[0] - Hl)), constant_values=1.0)
         return decode_attention(
-            _pad_rows(q, row), kc, vc, lengths + ahead, scale=scale,
-            kv_scales=scales, layer=i)[:, :Hl, :Dh]
+            _pad_rows(q, (row[0] * group, row[1])), kc, vc,
+            lengths + ahead, scale=scale, kv_scales=scales,
+            layer=i)[:, :Hl, :Dh]
 
-    def _step_logits(self, state, kc, vc, lengths, last_tokens, active,
-                     tp=_OFF_MESH):
-        """`_step_core` without the routing facts: (logits [N, vocab]
-        f32, kc', vc')."""
-        return self._step_core(state, kc, vc, lengths, last_tokens,
-                               active, tp=tp)[:3]
+    def _step_logits(self, state, *args, tp=_OFF_MESH):
+        """`_step_core` without the routing facts, on the flat arguments
+        of `_table_specs` (the slot state's `_n_tables` leaves first):
+        (logits [N, vocab] f32, *slot state').  A stack in which a
+        recurrent layer follows a routed FFN (`_step_picks`) hands out
+        the routed layers' chosen experts [routed layers, N, k] i32
+        second: there a router's near-tie moves the positions AFTER it
+        too, and only the picks tell that from a fault."""
+        import jax.numpy as jnp
+        n = self._n_tables
+        picks = [] if self._step_picks else None
+        logits, tables, _ = self._step_core(state, args[:n], *args[n:],
+                                            tp=tp, picks=picks)
+        return (logits,) + ((jnp.stack(picks),) if picks else ()) + tables
 
     def _step_tokens(self, state, kc, vc, lengths, last_tokens, active,
                      tp):
@@ -1326,34 +1612,39 @@ class GenerativePredictor:
         speculative round's draft: a routed FFN runs, what it touched is
         dropped."""
         import jax.numpy as jnp
-        logits, kc, vc = self._step_logits(
-            state, kc, vc, lengths, last_tokens, active, tp=tp)
+        logits, (kc, vc), _ = self._step_core(
+            state, (kc, vc), lengths, last_tokens, active, tp=tp)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), kc, vc
 
-    def _step_core(self, state, kc, vc, lengths, last_tokens, active,
-                   tp=_OFF_MESH):
-        """One fixed-shape decode step over the whole slot table.
-        kc/vc [L, N, S, Hp, Dp] (fp32, or int8 under the quantized
-        cache), lengths [N] i32 (live cached positions), last_tokens
-        [N] i32, active [N] bool -> (logits [N, vocab] f32, kc', vc',
+    def _step_core(self, state, tables, lengths, last_tokens, active,
+                   tp=_OFF_MESH, picks=None):
+        """One fixed-shape decode step over the slots' whole state.
+        `tables` = (kc, vc[, cs]): the K/V tables [attention layers, N,
+        S, Hp, Dp] (fp32, or int8 under the quantized cache) and, for a
+        stack with conv layers, the conv-state table [conv layers, N,
+        K-1, D]; lengths [N] i32 (live cached positions), last_tokens
+        [N] i32, active [N] bool -> (logits [N, vocab] f32, tables',
         per-layer routing facts).  Each layer is `_block` at position
-        `lengths` (a slot's own), its attention the write of the new row
-        and the decode kernel over the slot table.
+        `lengths` (a slot's own): an attention layer's `attend` the
+        write of the new row and the decode kernel over the slot table,
+        a conv layer's `convolve` the taps over the slot's conv state
+        and the new input, which then roll into the state.
 
-        The table is CARRIED through the layers and updated IN PLACE:
-        layer i scatters its N new rows to (i, n, lengths[n]) of the
-        stacked table (`_land`) and the kernel reads layer i of that
-        same table (`_attend_table`).  No layer is selected, sliced out
-        or stacked back, so with the table donated (every phase that
-        returns it donates it: `_phase_jit`) the step's input and output
-        are ONE buffer and what it writes is N rows a layer.  Nobody
-        else may hold the table: `DecodeSession` replaces its
-        `_kc`/`_vc` by each call's results.
+        The tables are CARRIED through the layers and updated IN PLACE:
+        attention layer i scatters its N new rows to (i, n, lengths[n])
+        of the stacked table (`_land`) and the kernel reads layer i of
+        that same table (`_attend_table`).  No layer is selected, sliced
+        out or stacked back, so with the tables donated (every phase
+        that returns them donates them: `_phase_jit`) the step's input
+        and output are ONE buffer and what it writes is N rows a layer.
+        Nobody else may hold them: `DecodeSession` replaces its own by
+        each call's results.
 
-        Cache writes are gated by `active`: an inactive slot's row goes
-        to position S, out of range, and is DROPPED, as is the row of a
-        slot already at `lengths == S`; so a freed (zeroed) slot stays
-        zero and per-slot independence is exact.
+        Writes are gated by `active`: an inactive slot's K/V row goes to
+        position S, out of range, and is DROPPED, as is the row of a
+        slot already at `lengths == S`, and its conv state keeps what it
+        held; so a freed (zeroed) slot stays zero and per-slot
+        independence is exact.
 
         Under TP (inside shard_map) kc/vc are this member's resident
         HEAD shard and weights are local column/row shards — params and
@@ -1361,6 +1652,8 @@ class GenerativePredictor:
         ~1/mesh_size."""
         import jax.numpy as jnp
         L = self._dims()[0]
+        kc, vc = tables[:2]
+        cs = tables[2] if len(tables) > 2 else None
         N, S = kc.shape[1], kc.shape[2]
         x = self._embed(state, last_tokens, lengths, tp)        # [N, D]
         # where a slot's new row lands; S (past the end) = nowhere
@@ -1368,15 +1661,27 @@ class GenerativePredictor:
                  jnp.where(active, lengths, S).astype(jnp.int32))
         facts = []
         for i in range(L):
-            def attend(q, k_new, v_new, i=i):
-                nonlocal kc, vc
-                kc, vc = self._write(kc, vc, i, where, k_new, v_new, tp)
-                return self._attend_table(q, kc, vc, lengths, 1, i, tp)
+            at = self._table_layer(i)
 
-            x, f = self._block(state, "l%d_" % i, x, lengths, attend,
-                               active, tp=tp)
+            def attend(q, k_new, v_new, at=at):
+                nonlocal kc, vc
+                kc, vc = self._write(kc, vc, at, where, k_new, v_new, tp)
+                return self._attend_table(q, kc, vc, lengths, 1, at, tp)
+
+            def convolve(z, taps, at=at):
+                # z [N, D]: the slot's K-1 kept inputs, then this one
+                nonlocal cs
+                seen = jnp.concatenate([cs[at], z[:, None]], axis=1)
+                cs = cs.at[at].set(jnp.where(active[:, None, None],
+                                             seen[:, 1:], cs[at]))
+                return sum(taps[:, j] * seen[:, j]
+                           for j in range(taps.shape[1]))
+
+            x, f = self._block(state, i, x, lengths, attend, active, tp=tp,
+                               convolve=convolve, picks=picks)
             facts.append(f)
-        return self._head(state, x, tp), kc, vc, facts
+        return (self._head(state, x, tp),
+                (kc, vc) + (() if cs is None else (cs,)), facts)
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
                      tp=_OFF_MESH):
@@ -1420,8 +1725,8 @@ class GenerativePredictor:
                     [self._attend_table(q[:, j], kc, vc, lengths, j + 1,
                                         i, tp) for j in range(C)], axis=1)
 
-            x, _ = self._block(state, "l%d_" % i, x, positions, attend,
-                               live, tp=tp)
+            x, _ = self._block(state, i, x, positions, attend, live,
+                               tp=tp)
         g = jnp.argmax(self._head(state, x, tp),
                        axis=-1).astype(jnp.int32)               # [N, C]
         match = (tokens[:, 1:] == g[:, :C - 1]).astype(jnp.int32)
@@ -1436,7 +1741,8 @@ class GenerativePredictor:
     def _step_math(self, tp=_OFF_MESH):
         """Build the decode STEP phase (SERVING.md "Fused multi-step
         decode"): up to `STEP_WINDOW` greedy decode steps as ONE
-        executable, a `lax.while_loop` carrying {KV tables, last
+        executable, a `lax.while_loop` carrying {the slots' state (K/V
+        tables, and the conv-state table of a stack that has one), last
         tokens, the token block} through `_step_core` + argmax per
         trip.  Per-slot math is independent and every trip is the same
         `_step_core`, so a window's stream is that of one-trip
@@ -1457,7 +1763,7 @@ class GenerativePredictor:
         (EOS, its budget met, its cache full): every trip of a window
         advances every running slot, none sits out a dead trip, and a
         lane with every slot assigned can say that nothing joins before
-        a slot ends.  Returns (out, kc', vc'); `out` is ONE int32
+        a slot ends.  Returns (out, *slot state'); `out` is ONE int32
         vector, so a dispatch costs one fetch
         (`DecodeSession.decode_fused` splits it): the [N, STEP_WINDOW]
         token block (`emitted[s]` of row s valid, in stream order), `emitted`
@@ -1465,17 +1771,17 @@ class GenerativePredictor:
         trips run, and for a routed-expert artifact each layer's
         (experts touched SUMMED over the trips, most tokens on one
         expert, the LARGEST over the trips), which is what
-        `_pack_routing` carries for a prefill."""
+        `_pack_routing` carries for a prefill.  Its arguments are those
+        of `_step_specs`, flat."""
         import jax
         import jax.numpy as jnp
         W = int(STEP_WINDOW)
         eos = self.eos_id
         routed = self.routed_layers
 
-        def step(state, kc, vc, lengths, last_tokens, active, budget,
-                 max_trips):
-            S = kc.shape[2]
-            N = kc.shape[1]
+        def window(state, tables, lengths, last_tokens, active, budget,
+                   max_trips):
+            N, S = tables[0].shape[1:3]
             running = active & (budget > 0) & (lengths < jnp.int32(S))
             adv = running.astype(jnp.int32)
             trips = jnp.minimum(max_trips, jnp.int32(W))
@@ -1484,9 +1790,9 @@ class GenerativePredictor:
                 return (carry[0] < trips) & ~carry[-1]
 
             def body(carry):
-                i, kc, vc, last, toks, facts, _ = carry
-                logits, kc, vc, f = self._step_core(
-                    state, kc, vc, lengths + adv * i, last, running,
+                i, tables, last, toks, facts, _ = carry
+                logits, tables, f = self._step_core(
+                    state, tables, lengths + adv * i, last, running,
                     tp=tp)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 # land this trip's tokens at column i (one-hot select:
@@ -1494,7 +1800,7 @@ class GenerativePredictor:
                 col = (jnp.arange(W)[None, :] == i) & running[:, None]
                 toks = jnp.where(col, tok[:, None], toks)
                 if routed:
-                    f = jnp.stack(f)                         # [L, 2]
+                    f = jnp.stack([r for r in f if r is not None])
                     facts = jnp.stack(
                         [facts[:, 0] + f[:, 0],
                          jnp.maximum(facts[:, 1], f[:, 1])], axis=1)
@@ -1502,18 +1808,25 @@ class GenerativePredictor:
                 stopped = jnp.any(running & (
                     (tok == jnp.int32(eos)) | (i >= budget)
                     | (lengths + i >= jnp.int32(S))))
-                return (i, kc, vc, jnp.where(running, tok, last), toks,
+                return (i, tables, jnp.where(running, tok, last), toks,
                         facts, stopped)
 
-            carry = (jnp.int32(0), kc, vc, last_tokens,
+            carry = (jnp.int32(0), tables, last_tokens,
                      jnp.zeros((N, W), jnp.int32),
                      jnp.zeros((routed, 2), jnp.int32),
                      ~jnp.any(running))
-            i, kc, vc, _last, toks, facts, _ = jax.lax.while_loop(
+            i, tables, _last, toks, facts, _ = jax.lax.while_loop(
                 cond, body, carry)
             out = jnp.concatenate([toks.reshape(-1), adv * i, i[None],
                                    facts.reshape(-1)])
-            return out, kc, vc
+            return (out,) + tables
+
+        # the executable's arguments, flat (`_step_specs`): the slot
+        # state's leaves first
+        n_tables = self._n_tables
+
+        def step(state, *args):
+            return window(state, args[:n_tables], *args[n_tables:])
 
         return step
 
@@ -1637,7 +1950,7 @@ class GenerativePredictor:
                           getattr(d, "device_kind", ""))
 
     def _resolve(self, phase_key, math_fn, arg_specs, tp_math=None,
-                 draft=None):
+                 draft=None, tables=()):
         """Persistent-cache-first compile of one phase (same order as
         Predictor._get_aot_fn: in-process shared map -> store hit ->
         fresh export+commit; direct compilation only with the store
@@ -1647,7 +1960,8 @@ class GenerativePredictor:
         compiles as ONE shard_map'd partitioned program instead of the
         replicate-compute gather wrap.  `draft` (fused-spec only) tells
         the spec builder how the draft's dict-shaped state is actually
-        placed."""
+        placed.  `tables`: where the slot state sits among the
+        arguments (`_phase_jit` donates it)."""
         import time as _time
         import jax
         fn = self._fns.get(phase_key)
@@ -1659,7 +1973,7 @@ class GenerativePredictor:
                 return fn
             fn = self._resolve_locked(phase_key, math_fn, arg_specs,
                                       _time, jax, tp_math=tp_math,
-                                      draft=draft)
+                                      draft=draft, tables=tables)
             self._fns[phase_key] = fn
             return fn
 
@@ -1753,25 +2067,25 @@ class GenerativePredictor:
                                       in_specs=in_specs,
                                       out_specs=out_specs)
 
-    def _phase_jit(self, call, arg_specs):
-        """`jax.jit(call)` for a phase `call(state, *args)`, with every
-        slot table among `args` (`_is_table`) DONATED: a phase that
-        takes a table returns it, and the session replaces its own by
-        the result (`DecodeSession._call`), so nothing reads the table a
-        call was given and the call may update it in place.  It stays a
-        jitted callable: `fn.lower(state, *specs).compile()` is the
-        module the lane runs (the benchmark reads instruction names
+    def _phase_jit(self, call, tables):
+        """`jax.jit(call)` for a phase `call(state, *args)`, with the
+        slot state among `args` (`tables`: the positions in `args` of
+        the K/V tables and the conv-state table) DONATED: a phase that
+        takes a slot's state returns it, and the session replaces its
+        own by the result (`DecodeSession._call`), so nothing reads the
+        table a call was given and the call may update it in place.  It
+        stays a jitted callable: `fn.lower(state, *specs).compile()` is
+        the module the lane runs (the benchmark reads instruction names
         from it)."""
         import jax
-        donate = tuple(1 + j for j, a in enumerate(arg_specs)
-                       if _is_table(a))
+        donate = tuple(1 + j for j in tables)
         if donate and self._device_kind().startswith("tpu/"):
             return jax.jit(call, donate_argnums=donate,
                            compiler_options=_TPU_PHASE_OPTIONS)
         return jax.jit(call, donate_argnums=donate)
 
     def _resolve_locked(self, phase_key, math_fn, arg_specs, _time, jax,
-                        tp_math=None, draft=None):
+                        tp_math=None, draft=None, tables=()):
         from paddle_tpu import compile_cache as cc
         state_spec = {n: jax.ShapeDtypeStruct(np.shape(v),
                                               np.asarray(v).dtype)
@@ -1843,9 +2157,9 @@ class GenerativePredictor:
                         cache.put(fp, exp.serialize())
                 with self._shared_lock:
                     self._shared_exports[skey] = exp
-            return self._phase_jit(exp.call, arg_specs)
+            return self._phase_jit(exp.call, tables)
         # compile NOW (not on first call) so warm() covers the stall
-        return self._phase_jit(math_fn, arg_specs).lower(
+        return self._phase_jit(math_fn, tables).lower(
             state_spec, *arg_specs).compile()
 
     def prefill_fn(self, bucket):
@@ -1860,17 +2174,29 @@ class GenerativePredictor:
     def _cache_np_dtype(self):
         return np.dtype(np.int8 if self._kv_quant else np.float32)
 
+    @property
+    def _n_tables(self):
+        """Leaves of a session's slot state: the K and V tables, and the
+        conv-state table of a stack with conv layers.  They lead the
+        arguments of every phase over the slots (`_table_specs`)."""
+        return 3 if self.conv_layers else 2
+
     def _table_specs(self, n_slots):
-        """(kc, vc, lengths [N] i32, last tokens [N] i32, active [N]
-        bool): what every phase over the slot table takes."""
+        """(kc, vc[, conv state], lengths [N] i32, last tokens [N] i32,
+        active [N] bool): what every phase over the slots takes, their
+        state first (`_n_tables` leaves)."""
         import jax
         n = int(n_slots)
         cache = jax.ShapeDtypeStruct(self.table_shape(n),
                                      self._cache_np_dtype())
+        conv = self.conv_state_shape(n)
         i32 = np.dtype(np.int32)
-        return (cache, cache, jax.ShapeDtypeStruct((n,), i32),
-                jax.ShapeDtypeStruct((n,), i32),
-                jax.ShapeDtypeStruct((n,), np.dtype(bool)))
+        return (cache, cache) + (
+            (jax.ShapeDtypeStruct(conv, np.dtype(np.float32)),)
+            if conv else ()) + (
+            jax.ShapeDtypeStruct((n,), i32),
+            jax.ShapeDtypeStruct((n,), i32),
+            jax.ShapeDtypeStruct((n,), np.dtype(bool)))
 
     def _step_specs(self, n_slots):
         """The step executable's arguments: the table's, then `budget`
@@ -1894,17 +2220,22 @@ class GenerativePredictor:
                    if self._tp_size else None)
         return self._resolve(("step", n, int(STEP_WINDOW)),
                              self._step_math(), self._step_specs(n),
-                             tp_math=tp_math)
+                             tp_math=tp_math,
+                             tables=range(self._n_tables))
 
     def step_logits_fn(self, n_slots):
         """The decode step with its logits left un-argmaxed (same math,
         one more compile-cache fingerprint per n_slots) — what a
         logit-level comparison against a reference reads
         (`DecodeSession.decode_logits`)."""
-        return self._resolve(("step_logits", int(n_slots)),
+        # (the picks are in the phase's key: a stored executable of the
+        # same artifact without them has another signature)
+        return self._resolve(("step_logits", int(n_slots))
+                             + (("picks",) if self._step_picks else ()),
                              self._step_logits,
                              self._table_specs(n_slots),
-                             tp_math=self._tp_math(self._step_logits))
+                             tp_math=self._tp_math(self._step_logits),
+                             tables=range(self._n_tables))
 
     def verify_fn(self, n_slots, spec_k):
         """The speculative-verify executable for a (slot table,
@@ -1913,12 +2244,14 @@ class GenerativePredictor:
         boot of a spec-configured server deserializes it like every
         other phase (COMPILE_CACHE.md)."""
         import jax
+        self._require_attention_stack("the speculative verify")
         n, C = int(n_slots), int(spec_k) + 1
         cache, _, lengths, _, active = self._table_specs(n)
         specs = (cache, cache, lengths,
                  jax.ShapeDtypeStruct((n, C), np.dtype(np.int32)), active)
         return self._resolve(("verify", n, C), self._verify_math, specs,
-                             tp_math=self._tp_math(self._verify_math))
+                             tp_math=self._tp_math(self._verify_math),
+                             tables=(0, 1))
 
     def fused_spec_fn(self, draft, n_slots, spec_k):
         """The fused speculative-round executable: k draft steps +
@@ -1928,6 +2261,8 @@ class GenerativePredictor:
         the phase key, so swapping drafts can never resolve a stale
         executable."""
         import jax
+        for side in (self, draft):
+            side._require_attention_stack("the fused speculative round")
         n, C = int(n_slots), int(spec_k) + 1
         cache, _, i32n, _, active = self._table_specs(n)
         dcache = draft._table_specs(n)[0]
@@ -1948,14 +2283,21 @@ class GenerativePredictor:
                    else None)
         return self._resolve(key,
                              self._fused_spec_math(draft, int(spec_k)),
-                             specs, tp_math=tp_math, draft=draft)
+                             specs, tp_math=tp_math, draft=draft,
+                             tables=(1, 2, 5, 6))
 
     def new_session(self, n_slots):
         return DecodeSession(self, n_slots)
 
 
 class DecodeSession:
-    """One slot table: the per-lane KV cache + occupancy bookkeeping.
+    """One lane's slots: their state + occupancy bookkeeping.  A slot's
+    state is of two kinds (`slot_state_shapes`): its rows of the K/V
+    tables (`_kc`, `_vc`: the attention layers', addressed by the slot's
+    length) and, for a stack with conv layers, its row of the conv-state
+    table (`_cs`: a fixed size, rolled by every token; None otherwise).
+    Every phase that advances the slots is given all of it donated and
+    the session keeps the results (`_tables`, `_keep`).
     NOT thread-safe — a serving lane owns its session exclusively (the
     decode loop is single-threaded per replica by design: the step
     function is one executable over the whole table)."""
@@ -1999,6 +2341,18 @@ class DecodeSession:
         # two buffers: a donated K table must not take V's with it
         self._kc = table()
         self._vc = table()
+        # the conv layers' state (refused on a mesh: `_inplace`)
+        conv = predictor.conv_state_shape(self.n_slots)
+        self._cs = None
+        # what a hybrid stack's fetch spans say of it
+        self._stack_attrs = {}
+        if conv:
+            z = jnp.zeros(conv, jnp.float32)
+            self._cs = jax.device_put(
+                z, predictor.device or next(iter(z.devices())))
+            self._stack_attrs = {
+                "conv_layers": conv[0], "attn_layers": shape[0],
+                "conv_state_bytes": int(self._cs.nbytes)}
         # set when a call failed after its table was donated to it
         # (`_mark_dead`): (phase, error); every later use raises
         self._dead = None
@@ -2014,8 +2368,13 @@ class DecodeSession:
         self.active = np.zeros(self.n_slots, bool)
         self.steps = 0
         # a routed-expert artifact: the newest step's or prefill's
-        # per-layer (experts touched, most tokens on one expert) [L, 2]
+        # (experts touched, most tokens on one expert) of each routed
+        # layer [routed layers, 2]
         self.last_routing = None
+        # a hybrid routed stack's newest `decode_logits`: the experts
+        # each routed layer chose [routed layers, n_slots, k]
+        self.last_picks = None
+        self._n_routed = predictor.routed_layers
         # the `decode/put` / `decode/launch` spans of a call whose
         # results are not fetched yet (`_call`, `_fetch`)
         self._launched = ()
@@ -2029,14 +2388,32 @@ class DecodeSession:
         return int(self.active.sum())
 
     def cache_bytes(self):
-        """MEASURED slot-table footprint: the K + V device arrays'
-        nbytes plus the int8 cache's fp32 scale table — what
+        """MEASURED K/V slot-table footprint: the K + V device arrays'
+        nbytes (rows as the table holds them, their padding to the tile
+        included) plus the int8 cache's fp32 scale table — what
         bench_serving's --kv_dtype A/B reports against the closed-form
-        `GenerativePredictor.kv_cache_bytes`."""
+        `GenerativePredictor.kv_cache_bytes`.  The conv layers' state is
+        `conv_state_bytes`, apart."""
         n = int(self._kc.nbytes) + int(self._vc.nbytes)
         if self.predictor._kv_quant:
             n += int(np.asarray(self.predictor._kv_scales).nbytes)
         return n
+
+    def conv_state_bytes(self):
+        """MEASURED footprint of the conv layers' slot state (0 for a
+        stack with none)."""
+        return 0 if self._cs is None else int(self._cs.nbytes)
+
+    def _tables(self):
+        """The slots' state as the phases take it: (kc, vc[, cs])."""
+        return (self._kc, self._vc) + (
+            () if self._cs is None else (self._cs,))
+
+    def _keep(self, tables):
+        """Replace the slots' state by a phase's results."""
+        self._kc, self._vc = tables[:2]
+        if self._cs is not None:
+            self._cs, = tables[2:]
 
     # -- phases ---------------------------------------------------------
 
@@ -2096,9 +2473,10 @@ class DecodeSession:
         return out
 
     def prefill(self, slot, tokens):
-        """Run the prompt through the bucketed prefill, land its K/V in
-        `slot`, and return the first generated token (greedy).  The
-        slot must be free (and therefore zeroed)."""
+        """Run the prompt through the bucketed prefill, land its K/V
+        (and the conv layers' state at the prompt's end) in `slot`, and
+        return the first generated token (greedy).  The slot must be
+        free (and therefore zeroed)."""
         import jax.lax
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
@@ -2112,8 +2490,8 @@ class DecodeSession:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = tokens
         fn = self.predictor.prefill_fn(bucket)
-        first, kc, vc = self._call("prefill", fn, (),
-                                   (padded, np.int32(n)))
+        first, kc, vc, *conv = self._call("prefill", fn, (),
+                                          (padded, np.int32(n)))
         # land the bucket-length K/V at the slot; positions past the
         # bucket are already zero (the slot was zeroed on free)
         if self._inplace:
@@ -2121,6 +2499,8 @@ class DecodeSession:
             at = self._slot_ids[slot]
             self._kc = write_rows(self._kc, kc, at)
             self._vc = write_rows(self._vc, vc, at)
+            if conv:
+                self._cs = write_rows(self._cs, conv[0], at)
         else:
             at = (0, slot, 0, 0, 0)
             self._kc = jax.lax.dynamic_update_slice(self._kc, kc, at)
@@ -2144,15 +2524,20 @@ class DecodeSession:
         """One step that hands back its logits: returns (tokens
         [n_slots] i32, logits [n_slots, vocab] f32), through an
         executable of its own (`step_logits_fn`: the step's `_step_core`,
-        no window around it).  A caller comparing two predictors on one
+        no window around it).  For a stack with conv layers and routed
+        FFNs the step's chosen experts [routed layers, n_slots, k] are
+        kept as `last_picks`.  A caller comparing two predictors on one
         stream overwrites `last_tokens` afterwards (teacher forcing), as
         the speculative session does for its draft."""
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
-        logits, self._kc, self._vc = self._call(
+        logits, *tables = self._call(
             "step", self.predictor.step_logits_fn(self.n_slots),
-            (self._kc, self._vc),
-            (self.lengths, self.last_tokens, self.active))
+            self._tables(), (self.lengths, self.last_tokens, self.active))
+        if self.predictor._step_picks:
+            picks, *tables = tables
+            self.last_picks = np.asarray(picks)
+        self._keep(tables)
         logits, = self._fetch("step", logits)
         toks = logits.argmax(axis=-1).astype(np.int32)
         act = self.active
@@ -2173,9 +2558,11 @@ class DecodeSession:
         fetch span as `moe_experts_touched` (summed over the layers and
         a step's trips) and `moe_tokens_per_expert_max`.  `trips_at`
         is where a step's vector holds the trips it ran: all three
-        spans carry them as `trips`.  Any other artifact takes the
-        path it always took."""
-        n_routed = 2 * self.predictor.routed_layers if routed else 0
+        spans carry them as `trips`.  A stack with conv layers says so
+        on the fetch span of its steps and prefills: `conv_layers`,
+        `attn_layers`, `conv_state_bytes` (the session's conv-state
+        table).  Any other artifact takes the path it always took."""
+        n_routed = 2 * self._n_routed if routed else 0
         if not (n_routed or obs_tracing.enabled()):
             return [np.asarray(o) for o in outs]
         t0 = time.monotonic()
@@ -2187,6 +2574,8 @@ class DecodeSession:
             self.last_routing = facts
             attrs = {"moe_experts_touched": int(facts[:, 0].sum()),
                      "moe_tokens_per_expert_max": int(facts[:, 1].max())}
+        if routed:
+            attrs.update(self._stack_attrs)
         if obs_tracing.enabled():
             t1 = time.monotonic()
             trips = {} if trips_at is None \
@@ -2229,9 +2618,10 @@ class DecodeSession:
             b = np.asarray(budget, np.int32).reshape(N)
             b = np.clip(np.where(act, b, 0), 0, T).astype(np.int32)
         mt = T if max_trips is None else max(1, min(int(max_trips), T))
-        out, self._kc, self._vc = self._call(
-            "step", self.predictor.step_fn(N), (self._kc, self._vc),
+        out, *tables = self._call(
+            "step", self.predictor.step_fn(N), self._tables(),
             (self.lengths, self.last_tokens, act, b, np.int32(mt)))
+        self._keep(tables)
         out, = self._fetch("step", out, routed=True, trips_at=N * W + N)
         toks = out[:N * W].reshape(N, W)[:, :T]
         counts, trips = out[N * W:N * W + N], int(out[N * W + N])
@@ -2250,15 +2640,16 @@ class DecodeSession:
         return int(self.predictor.max_seq_len - self.lengths[slot])
 
     def free(self, slot):
-        """Release a slot: its KV lines are ZEROED before it can be
-        reused — a later occupant starts from exact zeros, never from a
-        previous request's keys (the no-leakage contract the chaos
-        decode-disconnect scenario pins)."""
+        """Release a slot: its state of BOTH kinds (K/V lines, conv
+        state) is ZEROED before it can be reused — a later occupant
+        starts from exact zeros, never from a previous request's keys or
+        inputs (the no-leakage contract the chaos decode-disconnect
+        scenario pins)."""
         self._alive()
         if self._inplace:
             zero_slot = _slot_writers()[1]
-            self._kc = zero_slot(self._kc, self._slot_ids[slot])
-            self._vc = zero_slot(self._vc, self._slot_ids[slot])
+            self._keep([zero_slot(t, self._slot_ids[slot])
+                        for t in self._tables()])
         else:
             import jax.lax
             import jax.numpy as jnp
@@ -2286,6 +2677,7 @@ class DecodeSession:
         and re-pins its pending token to the target's correction."""
         import jax.lax
         import jax.numpy as jnp
+        self.predictor._require_attention_stack("a rollback")
         slot, n = int(slot), int(n)
         if n < 0:
             raise ValueError("rollback of %d positions" % n)
@@ -2315,12 +2707,12 @@ class DecodeSession:
             self.last_tokens[slot] = np.int32(last_token)
 
     def slot_is_zero(self, slot):
-        """True when the slot's K and V cache lines are exact zeros —
-        the test hook for the zero-before-reuse contract."""
+        """True when the slot's state of both kinds (its K and V cache
+        lines, its conv state) is exact zeros — the test hook for the
+        zero-before-reuse contract."""
         self._alive()
-        k = np.asarray(self._kc[:, slot])
-        v = np.asarray(self._vc[:, slot])
-        return bool(not k.any() and not v.any())
+        return not any(np.asarray(t[:, slot]).any()
+                       for t in self._tables())
 
 
 class SpeculativeDecodeSession:
@@ -2357,6 +2749,8 @@ class SpeculativeDecodeSession:
     def __init__(self, target, draft, n_slots, spec_k):
         if int(spec_k) < 1:
             raise ValueError("spec_k must be >= 1, got %r" % (spec_k,))
+        for side in (target, draft):
+            side._require_attention_stack("speculative decoding")
         if draft.vocab_size != target.vocab_size:
             raise ValueError(
                 "draft vocab %d != target vocab %d — not a compatible "

@@ -537,11 +537,16 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
     lengths [N] live cached positions per slot -> [N, H, D].
     `kv_scales` [2, H] f32 (required iff the caches are int8) applies
     the same per-head dequant algebra as the kernel: K scale on the
-    scores, V scale after the normalizing divide."""
+    scores, V scale after the normalizing divide.  Caches of fewer heads
+    than q's are grouped-query: query head a reads K/V head a // (H /
+    cache heads), spelled here as a repeat of the K/V heads."""
     import jax.numpy as jnp
     N, S = k_cache.shape[0], k_cache.shape[1]
     H, D = q.shape[1], q.shape[-1]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
+    if k_cache.shape[2] != H:
+        k_cache, v_cache = (jnp.repeat(t, H // t.shape[2], axis=2)
+                            for t in (k_cache, v_cache))
     s = jnp.einsum("nhd,nshd->nhs", q.astype(jnp.float32),
                    k_cache.astype(jnp.float32)) * scale
     if kv_scales is not None:
@@ -568,6 +573,13 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     cached keys/values, time-major; fp32 or int8), lengths [N] int32
     (live positions per slot — cached positions >= length are masked
     out) -> [N, H, D] in q's dtype.
+
+    GROUPED-QUERY: caches of Hc < H heads (H = G * Hc), query head a
+    reading K/V head a // G.  The kernel streams each K/V tile ONCE for
+    its G query heads: q goes in group-major ([G, Hc] flattened, so that
+    group g is the contiguous rows g * Hc ..), the body scores and sums
+    each group against the one resident tile, and the result comes back
+    in head order.  Float caches only.
 
     With `layer` (a static int) k_cache/v_cache are the STACKED slot
     table [L, N, S, H, D] and the kernel reaches that layer through its
@@ -598,6 +610,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     from jax.experimental.pallas import tpu as pltpu
 
     N, H, D = q.shape
+    Hc = k_cache.shape[-2]
+    G = H // Hc
     stacked = layer is not None
     if stacked != (k_cache.ndim == 5):
         raise ValueError(
@@ -612,6 +626,10 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         raise ValueError(
             "decode_attention: int8 KV caches need kv_scales [2, H] "
             "(per-head fp32 dequant scales)")
+    if G * Hc != H or (quant and G > 1):
+        raise ValueError(
+            "decode_attention: %d query heads over %d %s K/V heads"
+            % (H, Hc, kv_dtype.name))
     bkv = int(block_kv or attention_tuning.get_decode_config(
         S, D, kv_dtype.name) or 0)
     if not bkv or S % bkv:
@@ -625,10 +643,20 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         # the layer's axis is squeezed out of the block: the body sees
         # the (1, bkv, H, D) tile it always saw
         layer = int(layer)
-        kv_spec = pl.BlockSpec((None, 1, bkv, H, D),
+        kv_spec = pl.BlockSpec((None, 1, bkv, Hc, D),
                                lambda b, j: (layer, b, j, 0, 0))
     else:
-        kv_spec = pl.BlockSpec((1, bkv, H, D), lambda b, j: (b, j, 0, 0))
+        kv_spec = pl.BlockSpec((1, bkv, Hc, D), lambda b, j: (b, j, 0, 0))
+    if G > 1:
+        q = q.reshape(N, Hc, G, D).transpose(0, 2, 1, 3).reshape(N, H, D)
+
+    def per_group(fn, rows):
+        """`fn` of each group's Hc rows of `rows` [G * Hc, ...] against
+        the resident K or V tile, stacked back group-major."""
+        if G == 1:
+            return fn(rows)
+        return jnp.concatenate(
+            [fn(rows[g * Hc:(g + 1) * Hc]) for g in range(G)], axis=0)
 
     def tile(ctx):
         q_ref, k_ref, v_ref, len_ref = ctx.ins[:4]
@@ -644,8 +672,9 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         # (widen q before the [H, 1, D] broadcast: Mosaic has no
         # layout for that reshape of a packed bf16 tile unless H fills
         # its 16 sublanes)
-        s = jnp.sum(qb.astype(jnp.float32)[:, None, :] * kb,
-                    axis=-1) * scale               # [H, BKV]
+        s = per_group(
+            lambda qg: jnp.sum(qg.astype(jnp.float32)[:, None, :] * kb,
+                               axis=-1), qb) * scale   # [H, BKV]
         if quant:
             # per-head K scale folds into the score scale, once per
             # score element — never per streamed cache element
@@ -654,7 +683,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
             jnp.int32, (H, bkv), 1)
         s = jnp.where(kpos >= length, _NEG_INF, s)
         _online_softmax_tile(
-            s, lambda p: jnp.sum(p[:, :, None] * vb, axis=1),
+            s, lambda p: per_group(
+                lambda pg: jnp.sum(pg[:, :, None] * vb, axis=1), p),
             acc_ref, m_ref, l_ref)
 
     def finalize(ctx):
@@ -679,7 +709,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
             2, H, 1))
         in_specs.append(pl.BlockSpec((2, H, 1),
                                      lambda b, j: (0, 0, 0)))
-    return tiled_contraction(
+    out = tiled_contraction(
         tuple(operands),
         grid=(N, S // bkv),
         reduce_axis=1,
@@ -692,6 +722,9 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         scratch_fill=(0.0, _NEG_INF, 0.0),
         tile=tile, finalize=finalize,
         interpret=interpret)
+    if G > 1:
+        out = out.reshape(N, G, Hc, D).transpose(0, 2, 1, 3).reshape(N, H, D)
+    return out
 
 
 def decode_attention_head_slice(q, k_cache, v_cache, lengths, head_offset,

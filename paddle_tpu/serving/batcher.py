@@ -1114,6 +1114,7 @@ class DecodeBatcher:
             metrics.replica_stats_fn = self.replica_stats
             metrics.slot_occupancy_fn = self.slot_occupancy
             metrics.kv_cache_fn = self.kv_cache_info
+            metrics.conv_state_fn = self.conv_state_bytes
         self._threads = [
             threading.Thread(
                 target=_guarded(self._lane_loop,
@@ -1190,6 +1191,14 @@ class DecodeBatcher:
             if cb is not None:
                 total += int(cb())
         return dtype, total
+
+    def conv_state_bytes(self):
+        """MEASURED bytes of the conv layers' slot state summed across
+        this batcher's lanes: the second kind of slot state of a stack
+        with conv layers (0 for any other), apart from the K/V bytes
+        `kv_cache_info` reports."""
+        return sum(int(getattr(lane.session, "conv_state_bytes",
+                               lambda: 0)()) for lane in self._lanes)
 
     def _slots_busy_total(self):
         return sum(len(l.assigned) for l in self._lanes)

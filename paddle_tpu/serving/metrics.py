@@ -178,6 +178,11 @@ class ModelMetrics:
         # cache bytes across lanes) — the quantized-KV-cache axis the
         # bench A/B and serving_top read (QUANTIZE.md)
         self.kv_cache_fn = None
+        # installed by the decode batcher: measured bytes of the conv
+        # layers' slot state across lanes (a hybrid stack's second kind
+        # of slot state; reported apart from the K/V bytes, and only
+        # where there is any)
+        self.conv_state_fn = None
         self._shed_by_priority = {}      # priority class -> shed count
         # static resource estimates (ANALYSIS.md): set once per load /
         # hot swap by the registry's note_resource — the placement-by-
@@ -418,6 +423,9 @@ class ModelMetrics:
                     snap["kv_cache_bytes"] = int(kv_bytes)
                 except Exception:
                     pass
+            conv_bytes = self.conv_state_fn() if self.conv_state_fn else 0
+            if conv_bytes:
+                snap["conv_state_bytes"] = int(conv_bytes)
         if self.spec_rounds.value or self.spec_degraded.value:
             # speculative decoding telemetry (serving_top's ACC%
             # column, Prometheus spec_* families)

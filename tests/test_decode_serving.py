@@ -1194,7 +1194,7 @@ def _where_stack_step(pred, state, kc, vc, lengths, last_tokens, active):
                 q, kcs[-1], vcs[-1], lengths + 1, scale=1.0 / np.sqrt(Dh),
                 kv_scales=pred._kv_scales[:, i] if pred._kv_quant
                 else None)
-        x, _ = pred._block(state, "l%d_" % i, x, lengths, attend, active)
+        x, _ = pred._block(state, i, x, lengths, attend, active)
     logits = pred._norm(x, state, "lnf") @ state["lm_head"]
     return logits, jnp.stack(kcs), jnp.stack(vcs)
 

@@ -193,15 +193,15 @@ def described_predictor(meta, device, kv="float32"):
     return pred, state
 
 
-def compile_phase(pred, state, math_fn, specs):
-    """The phase as `_resolve` builds it (`_phase_jit`: tables donated, the
-    TPU's options), compiled for the described chip with every argument
-    in the device's own layout."""
+def compile_phase(pred, state, math_fn, specs, tables=(0, 1)):
+    """The phase as `_resolve` builds it (`_phase_jit`: the slot state at
+    `tables` among the arguments donated, the TPU's options), compiled for
+    the described chip with every argument in the device's own layout."""
     on = jax.sharding.SingleDeviceSharding(pred._device)
     specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on)
              for s in specs]
     with pk.mosaic_lowering():
-        return pred._phase_jit(math_fn, specs).lower(
+        return pred._phase_jit(math_fn, tables).lower(
             state, *specs).compile()
 
 
@@ -266,8 +266,8 @@ def test_decode_step_updates_the_table_in_place(one_chip, model):
     assert [(s.shape, str(s.dtype)) for s in specs[-2:]] \
         == [((slots,), "int32"), ((), "int32")]
     assert text.count(" while(") == 1 and ("ragged-dot" in text) == routed
-    assert re.search(r"ENTRY [^\n]*\bmax_trips[\w.]*: s32\[\]", text), \
-        "max_trips is not a runtime parameter"
+    assert re.search(r"ENTRY [^\n]*: s32\[\]\) -> ", text), \
+        "max_trips (the last argument) is not a runtime parameter"
     if routed:
         # the 3.2 GB of experts go to the grouped-matmul kernels as they
         # are: nothing whose result is a whole expert tensor but a
@@ -327,3 +327,57 @@ def test_int8_table_step_compiles_in_place(one_chip):
     table = int(np.prod(pred.table_shape(slots)))    # int8, rows (16, 128)
     assert ma.alias_size_in_bytes >= 2 * table
     assert ma.temp_size_in_bytes < table / 10
+
+
+# benchmark/configs/lfm2_24b_a2b.json at its 32 slots: the leading dense conv
+# layer, the attention layer and one routed conv layer of its five, at the
+# published widths but 8 of the 64 experts (a compile's seconds, not a
+# kernel's shape)
+LFM2 = dict(vocab_size=65536, d_model=2048, n_heads=32, n_kv_heads=8,
+            n_layers=3, max_seq_len=4096, eos_id=0, norm="rmsnorm",
+            norm_eps=1e-5, position="rope", rope_theta=1e6, qk_norm="head",
+            layer_types=["conv", "attention", "conv"], conv_kernel=3,
+            n_dense_layers=1, dense_width=11776, ffn="moe_swiglu",
+            n_experts=8, experts_per_token=4, expert_width=1536,
+            norm_topk_prob=True, router="sigmoid_bias", head="tied")
+
+
+def test_grouped_query_decode_attention_compiles(one_chip):
+    """32 query heads over a table of 8 K/V heads padded to (8, 128) rows,
+    stacked, as `_attend_table` calls it for LFM2: Mosaic takes the
+    group-major body (interpret mode takes anything)."""
+    N, S, Hq, Hkv, D = 32, 4096, 32, 8, 128
+    with pk.mosaic_lowering():
+        compile_for_chip(
+            lambda q, k, v, n: pk.decode_attention(q, k, v, n, scale=0.125,
+                                                   layer=0),
+            one_chip, ((N, Hq, D), "float32"),
+            ((1, N, S, Hkv, D), "float32"), ((1, N, S, Hkv, D), "float32"),
+            ((N,), "int32"))
+
+
+def test_hybrid_step_updates_both_kinds_of_slot_state_in_place(one_chip):
+    """The step window of a stack with conv layers beside a grouped-query
+    attention layer: the K/V tables (the ATTENTION layer's only) and the
+    conv-state table are all three donated and aliased to outputs, one
+    Mosaic call an attention layer, and the `while` carries all three."""
+    slots = 32
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(LFM2, device)
+    assert pred.table_shape(slots) == (1, slots, 4096, 8, 128)
+    assert pred.conv_state_shape(slots) == (2, slots, 2, 2048)
+    specs = pred._step_specs(slots)
+    compiled = compile_phase(pred, state, pred._step_math(), specs,
+                             tables=range(3))
+    D, V = LFM2["d_model"], LFM2["vocab_size"]
+    hoisted = 2 * (D * V + 2 * 4 * D * D + 3 * D * LFM2["dense_width"])
+    text = assert_table_updated_in_place(
+        compiled, pred.table_shape(slots), n_kernels=1,
+        temporaries=hoisted + 0.02e9)
+    ma = compiled.memory_analysis()
+    conv = 4 * int(np.prod(pred.conv_state_shape(slots)))
+    table = 4 * int(np.prod(pred.table_shape(slots)))
+    assert ma.alias_size_in_bytes >= 2 * table + conv
+    assert text.count(" while(") == 1 and "ragged-dot" in text
+    assert re.search(r"ENTRY [^\n]*: f32\[2,32,2,2048\]", text), \
+        "the conv state is not an argument of its own"

@@ -1,0 +1,148 @@
+"""What a PR that must not move the decode cells holds itself to: the texts of
+the decode phases of ONE tree, to be compared with another tree's by `cmp`.
+
+    python tools/decode_hlo_dump.py <tree> <out dir>      # once a tree
+    diff -rq <out parent> <out change>
+
+For tiny GPT-2-shaped and OLMoE-shaped artifacts (fp32 and int8 caches, plain
+rows and rows padded to the kernel's tile) it writes the jaxpr, the StableHLO
+and the CPU's optimized HLO of `step` (the window), `step_logits` and
+`prefill`; and, compiled for a DESCRIBED v5e with the Mosaic kernels forced
+(no chip: the `on-chip-measurement` guide, section 2), the TPU's optimized
+HLO of `step` and `prefill` at the decode cells' widths and slot counts, two
+layers deep - the Mosaic kernel's payload is in that text.  Source locations
+are dropped (they name the tree), those inside a Mosaic payload too: the
+payload is written as its MLIR text.
+"""
+import base64
+import os
+import re
+import sys
+import tempfile
+
+root, out = os.path.abspath(sys.argv[1]), sys.argv[2]
+sys.path.insert(0, root)
+os.makedirs(out, exist_ok=True)
+
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+
+from paddle_tpu.inference import decode as dec  # noqa: E402
+from paddle_tpu.ops import pallas_kernels as pk  # noqa: E402
+
+assert dec.__file__.startswith(root), dec.__file__
+jax.config.update("jax_enable_compilation_cache", False)
+
+OLMOE = dict(norm="rmsnorm", norm_eps=1e-5, position="rope",
+             rope_theta=10000.0, qk_norm=True, ffn="moe_swiglu")
+CELLS = {"gpt2_small": (dict(vocab_size=50257, d_model=768, n_heads=12,
+                             n_layers=2, max_seq_len=1024, eos_id=0), 32),
+         "olmoe_1b_7b": (dict(vocab_size=50304, d_model=2048, n_heads=16,
+                              n_layers=2, max_seq_len=4096, eos_id=0,
+                              n_experts=64, experts_per_token=8,
+                              expert_width=1024, **OLMOE), 8)}
+
+
+def mosaic_text(match):
+    """A Mosaic kernel's payload (MLIR bytecode, base64) as its text
+    without source locations."""
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    raw = base64.b64decode(match.group(1))
+    ctx = ir.Context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    try:
+        with ctx:
+            text = ir.Module.parse(raw).operation.get_asm(
+                enable_debug_info=False)
+    except Exception:       # already text (an optimized module's copy)
+        text = re.sub(r"(?m)^#loc.*\n| ?loc\((?:[^()]|\([^()]*\))*\)", "",
+                      raw.decode("utf-8", "replace"))
+    return "MOSAIC<<%s>>" % text
+
+
+def write(tag, text):
+    """`text` without what names the tree: the instructions' metadata, the
+    tables of file and function names and the stack frames at the end of an
+    optimized module, the locations inside a Mosaic payload."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames"
+                  r"|\d+ [\"{].*)\n", "", text)
+    text = re.sub(r'\\?["2]{1,2}body\\?["2]{1,2}: ?\\?["2]{1,2}'
+                  r'([A-Za-z0-9+/=]+)\\?["2]{1,2}', mosaic_text, text)
+    with open(os.path.join(out, tag), "w") as f:
+        f.write(text)
+
+
+def phases(pred, n_slots, bucket):
+    """{phase: (math over (state, *args), the arguments' specs)}; every
+    phase behind ONE signature, so that a module's parameters are named
+    alike whatever a tree calls them."""
+    def flat(fn):
+        return lambda state, *args: fn(state, *args)
+    return {ph: (flat(fn), specs) for ph, (fn, specs) in _phases(
+        pred, n_slots, bucket).items()}
+
+
+def _phases(pred, n_slots, bucket):
+    return {"step": (pred._step_math(), pred._step_specs(n_slots)),
+            "step_logits": (pred._step_logits, pred._table_specs(n_slots)),
+            "prefill": (pred._prefill_math,
+                        (jax.ShapeDtypeStruct((1, bucket), np.int32),
+                         jax.ShapeDtypeStruct((), np.int32)))}
+
+
+plain_row = dec.table_row
+for padded in (False, True):
+    if padded:
+        dec.table_row = lambda h, d, dev: (-(-int(h) // 8) * 8,
+                                           -(-int(d) // 128) * 128)
+    for name, block in (("gpt2", None),
+                        ("olmoe", dict(OLMOE, n_experts=8,
+                                       experts_per_token=2,
+                                       expert_width=32))):
+        d = tempfile.mkdtemp()
+        dec.build_tiny_decode_model(
+            d, vocab_size=97, d_model=64, n_heads=4, n_layers=2,
+            max_seq_len=64, prefill_buckets=[16, 32], block=block)
+        for kv in ("float32", "int8"):
+            pred = dec.load_decode_predictor(d, kv_cache_dtype=kv)
+            state = {n: jax.ShapeDtypeStruct(np.shape(v),
+                                             np.asarray(v).dtype)
+                     for n, v in pred._state_host.items()}
+            for ph, (fn, specs) in phases(pred, 4, 16).items():
+                tag = "%s_%s_%s_%s" % (name, kv,
+                                       "pad" if padded else "plain", ph)
+                low = jax.jit(fn).lower(state, *specs)
+                write(tag + ".stablehlo", low.as_text())
+                write(tag + ".hlo", low.compile().as_text())
+                write(tag + ".jaxpr", str(jax.make_jaxpr(fn)(state, *specs)))
+dec.table_row = plain_row
+
+# the cells' widths on a described v5e, Mosaic kernels in the text
+from jax.experimental import topologies  # noqa: E402
+
+device = topologies.get_topology_desc(platform="tpu",
+                                      topology_name="v5e:2x2").devices[0]
+on = jax.sharding.SingleDeviceSharding(device)
+for name, (meta, slots) in CELLS.items():
+    pred = object.__new__(dec.GenerativePredictor)
+    pred.meta, pred._block_meta = meta, dec.block_of(meta)
+    pred._kv_dtype, pred._tp_size, pred._device = "float32", 0, device
+    pred._kv_scales = None
+    state = {n: jax.ShapeDtypeStruct(s, np.float32, sharding=on)
+             for n, s in dec.decode_state_shapes(meta).items()}
+    for ph, (fn, specs) in phases(pred, slots, 128).items():
+        if ph == "step_logits":
+            continue
+        specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on)
+                 for s in specs]
+        donate = (1, 2) if ph == "step" else ()
+        with pk.mosaic_lowering():
+            low = jax.jit(fn, donate_argnums=donate,
+                          compiler_options=dec._TPU_PHASE_OPTIONS).lower(
+                              state, *specs)
+            write("v5e_%s_%s.stablehlo" % (name, ph), low.as_text())
+            write("v5e_%s_%s.hlo" % (name, ph), low.compile().as_text())
+print("dumped", len(os.listdir(out)))

@@ -11,15 +11,18 @@ published width of one model each, on ONE TPU chip in ONE process:
            decode artifact (seeded weights) to four concurrent
            `infer_stream` clients, fp32 and int8 KV cache, each checked
            against the same model with the Mosaic decode kernel swapped for
-           its plain-XLA reference.
+           its plain-XLA reference; the kernel alone against its reference
+           and, bit for bit, against a whole-row stream, at short, mixed
+           and full lengths; a window of trips against one-trip dispatches.
   olmoe    the same server serving ONE layer of OLMoE-1B-7B at its
            published widths (RMSNorm, RoPE, qk-norm, 64 routed experts of
            1024, top-8: benchmark/configs/olmoe_1b_7b.json) to four
            concurrent `infer_stream` clients: one Mosaic `decode_attention`
            call and three grouped-matmul kernels a layer in the step, logits
            against benchmark/reference/olmoe_1b_7b.py.
-  kernels  flash attention fwd+bwd and dequant_matmul, compiled
-           (`interpret=False`) and compared with their references.
+  kernels  flash attention fwd+bwd, decode attention at a grouped-query
+           table and dequant_matmul, compiled (`interpret=False`) and
+           compared with their references.
 
 `--chips 4` runs instead — and only — what exists across chips:
 ParallelExecutor against the single-device Executor, and four one-chip serving
@@ -64,6 +67,9 @@ OLMOE_SLOTS = 4
 OLMOE_PROMPT_LENS = (9, 300, 512, 700)     # both buckets, a bucket's edge
 FLASH = dict(B=2, S=4096, H=16, D=128)
 DEQUANT = dict(M=256, K=2048, N=8192)
+# a grouped-query slot table: LFM2's rows (32 query heads over 8 K/V heads of
+# 64, padded to (8, 128)) and cache length, 8 slots of the cell's 32
+DECODE_GQA = (8, 4096, 32, 8, 128)
 
 # Stated tolerances.
 # serve, the kernel alone: decode_attention (fp32 VPU math, online softmax
@@ -360,44 +366,81 @@ def teacher_forced_logits(pred, prompt_list, served, n_slots):
     return firsts, out
 
 
-def check_decode_kernel(pred, n_slots, seed):
-    """decode_attention compiled for the chip vs its reference, alone, at the
-    served slot-table geometry and cache dtype, lengths on and around the
-    kernel's block edges.  Returns the max abs error."""
+def check_decode_kernel(geometry, kv_dtype, seed):
+    """decode_attention compiled for the chip, alone, over a slot table of
+    `geometry` = (slots, S, query heads, K/V heads, head size) and cache
+    dtype `kv_dtype`, at SHORT lengths (all inside a slot's first block),
+    MIXED ones (on and around the kernel's block edges, 1 and S among them)
+    and FULL rows: against its reference (the max abs error is returned) and,
+    bit for bit, against the same kernel made to stream whole rows, which is
+    what it did before its stream stopped at a slot's length
+    (`pk.kv_last_block` answering "the last block" for every length; the
+    mask follows the true lengths).  Also returns the microseconds a call
+    of each, by length profile."""
     import jax
     import jax.numpy as jnp
+    from paddle_tpu.ops import attention_tuning
     from paddle_tpu.ops import pallas_kernels as pk
-    _, H, Dh, _ = pred._dims()
-    S = pred.max_seq_len
+    n_slots, S, H, Hc, Dh = geometry
+    bkv = attention_tuning.get_decode_config(S, Dh, kv_dtype)
     kq, kk, kv_, ks = jax.random.split(jax.random.PRNGKey(seed + 2), 4)
     q = jax.random.normal(kq, (n_slots, H, Dh), jnp.float32)
-    lengths = jnp.asarray(
-        (sorted({1, 2, S // 8 - 1, S // 8, S // 8 + 1, S // 2, S - 1, S})
-         * n_slots)[:n_slots], jnp.int32)
-    if pred.kv_cache_dtype == "int8":
-        k = jax.random.randint(kk, (n_slots, S, H, Dh), -127, 128, jnp.int8)
-        v = jax.random.randint(kv_, (n_slots, S, H, Dh), -127, 128, jnp.int8)
+    edges = sorted({1, 2, bkv - 1, bkv, bkv + 1, S // 2, S - 1, S})
+    profiles = {"short": [1 + (7 * i) % bkv for i in range(n_slots)],
+                "mixed": (edges * n_slots)[:n_slots],
+                "full": [S] * n_slots}
+    if kv_dtype == "int8":
+        k = jax.random.randint(kk, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
+        v = jax.random.randint(kv_, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
         scales = jax.random.uniform(ks, (2, H), jnp.float32, 0.5, 1.5) / 127
     else:
-        k = jax.random.normal(kk, (n_slots, S, H, Dh), jnp.float32)
-        v = jax.random.normal(kv_, (n_slots, S, H, Dh), jnp.float32)
+        k = jax.random.normal(kk, (n_slots, S, Hc, Dh), jnp.float32)
+        v = jax.random.normal(kv_, (n_slots, S, Hc, Dh), jnp.float32)
         scales = None
-    kernel = jax.jit(lambda q, k, v, n: pk.decode_attention(
-        q, k, v, n, kv_scales=scales,
-        interpret=REQUIRED_PLATFORM != "tpu")).lower(
-            q, k, v, lengths).compile()
-    require_mosaic(kernel.as_text(), 1, "decode_attention")
+    lengths = jnp.asarray(profiles["mixed"], jnp.int32)
+
+    def compiled():
+        kernel = jax.jit(lambda q, k, v, n: pk.decode_attention(
+            q, k, v, n, kv_scales=scales,
+            interpret=REQUIRED_PLATFORM != "tpu")).lower(
+                q, k, v, lengths).compile()
+        require_mosaic(kernel.as_text(), 1, "decode_attention")
+        return kernel
+
+    bounded = compiled()
+    rule = pk.kv_last_block
+    pk.kv_last_block = lambda n, bkv, n_blocks, xp=np: n_blocks - 1 + 0 * n
+    try:
+        whole = compiled()
+    finally:
+        pk.kv_last_block = rule
 
     def ref(q, k, v, n):
         with jax.default_matmul_precision("highest"):
             return pk.decode_attention_reference(q, k, v, n,
                                                  kv_scales=scales)
 
-    err = float(jnp.max(jnp.abs(kernel(q, k, v, lengths)
-                                - jax.jit(ref)(q, k, v, lengths))))
+    def timed(kernel, n):
+        jax.block_until_ready(kernel(q, k, v, n))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            out = kernel(q, k, v, n)
+        jax.block_until_ready(out)
+        return out, round((time.perf_counter() - t0) / 20 * 1e6, 1)
+
+    ref, err, us = jax.jit(ref), 0.0, {}
+    for name, profile in sorted(profiles.items()):
+        n = jnp.asarray(profile, jnp.int32)
+        got, us[name] = timed(bounded, n)
+        want, us[name + "_whole_rows"] = timed(whole, n)
+        require(bool(jnp.array_equal(got, want)),
+                "decode_attention (%s cache, %s lengths) differs from the "
+                "whole-row stream by %.3g" % (
+                    kv_dtype, name, float(jnp.max(jnp.abs(got - want)))))
+        err = max(err, float(jnp.max(jnp.abs(got - ref(q, k, v, n)))))
     require(err <= TOL_DECODE_KERNEL, "decode_attention (%s cache) is %.3g "
-            "from its reference" % (pred.kv_cache_dtype, err))
-    return err
+            "from its reference" % (kv_dtype, err))
+    return err, us
 
 
 def check_window(pred, n_slots, prompt_list, what):
@@ -453,7 +496,10 @@ def serve_one(srv, artifact, kv, seed, devs):
     served = [toks for toks, _ in results]
     n_tok = sum(len(t) for t in served)
 
-    kernel_err = check_decode_kernel(pred, n_slots, seed)
+    _, heads, head_size, _ = pred._dims()
+    kernel_err, kernel_us = check_decode_kernel(
+        (n_slots, pred.max_seq_len, heads, heads, head_size),
+        pred.kv_cache_dtype, seed)
 
     # the step executable the lane ran holds the Mosaic kernel (the lane's
     # jitted callable, lowered and compiled for the same arguments: a
@@ -517,6 +563,8 @@ def serve_one(srv, artifact, kv, seed, devs):
          window_trips_equal_to_one_trip_dispatches=window_trips,
          decode_kernel_max_err=float("%.3g" % kernel_err),
          tol_decode_kernel=TOL_DECODE_KERNEL,
+         decode_kernel_equals_whole_row_stream=True,
+         decode_kernel_us=kernel_us,
          max_logit_diff_vs_reference=round(max_diff, 5),
          tol_logits=TOL_LOGITS,
          max_served_gap_below_reference_top1=round(max_gap, 5),
@@ -719,6 +767,14 @@ def phase_kernels(seed, devs):
          max_abs_err_fwd=round(err_fwd, 5), tol_fwd=TOL_FLASH_FWD,
          max_rel_err_bwd=round(err_bwd, 5), tol_bwd=TOL_FLASH_BWD_REL,
          peak_bytes_in_use=peak_bytes(devs[0]), device=where(got[0]))
+
+    err, us = check_decode_kernel(DECODE_GQA, "float32", seed)
+    emit("kernels", kernel="decode_attention_grouped_query",
+         shape=dict(zip(("slots", "S", "heads", "kv_heads", "D"),
+                        DECODE_GQA)), dtype="float32",
+         max_abs_err=float("%.3g" % err), tol=TOL_DECODE_KERNEL,
+         equals_whole_row_stream=True, call_us=us,
+         peak_bytes_in_use=peak_bytes(devs[0]))
 
     M, K, N = (DEQUANT[k] for k in "MKN")
     kx, kw, ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)

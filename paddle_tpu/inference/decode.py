@@ -1917,8 +1917,10 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (6:
-            # the step is a window of runtime trips; 5: verify and the
+            # rev bumps when the phase math itself changes shape (7:
+            # the decode kernel's stream stops at a slot's length, the
+            # same results from a fraction of the bytes; 6: the step is
+            # a window of runtime trips; 5: verify and the
             # fused rounds scatter their rows into the carried table as
             # the step does since 4; a stored phase of older math must
             # miss)
@@ -1927,7 +1929,7 @@ class GenerativePredictor:
             # and routing): equal weight shapes, another function
             "block": [[k, self._block_meta[k]]
                       for k in sorted(self._block_meta)],
-            "rev": 6,
+            "rev": 7,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -2353,6 +2355,12 @@ class DecodeSession:
             self._stack_attrs = {
                 "conv_layers": conv[0], "attn_layers": shape[0],
                 "conv_state_bytes": int(self._cs.nbytes)}
+        # the decode kernel's block edge over this table, as the step's
+        # trace resolves it (None: no edge divides S, the step attends
+        # through the plain-XLA reference, which reads whole rows)
+        from paddle_tpu.ops import attention_tuning
+        self._kv_block = attention_tuning.get_decode_config(
+            shape[2], shape[4], jnp.dtype(dtype).name)
         # set when a call failed after its table was donated to it
         # (`_mark_dead`): (phase, error); every later use raises
         self._dead = None
@@ -2547,6 +2555,24 @@ class DecodeSession:
         self.steps += 1
         return toks, logits
 
+    def _kv_stream(self, ran, trips):
+        """What the decode kernel staged in a step dispatch of `trips`
+        trips in which the slots `ran` [N] bool advanced, by the
+        kernel's own rule (`pallas_kernels.kv_last_block`): trip t
+        attends slot n under `lengths[n] + t + 1` positions if it ran,
+        `lengths[n] + 1` if not, in every attention layer.
+        `kv_blocks_live` are the K/V blocks staged, `kv_blocks_total`
+        what whole rows would be (trips x slots x layers x S / block)."""
+        if not self._kv_block:
+            return {}
+        from paddle_tpu.ops.pallas_kernels import kv_last_block
+        layers, n_slots, S = self._kc.shape[:3]
+        n_blocks = S // self._kv_block
+        seen = self.lengths[None] + ran[None] * np.arange(trips)[:, None] + 1
+        live = kv_last_block(seen, self._kv_block, n_blocks) + 1
+        return {"kv_blocks_live": int(live.sum()) * layers,
+                "kv_blocks_total": trips * n_slots * layers * n_blocks}
+
     def _fetch(self, phase, *outs, routed=False, trips_at=None):
         """`np.asarray` of each result: the wait for the device and the
         copy to the host, under one `decode/fetch` span; the call's
@@ -2557,8 +2583,11 @@ class DecodeSession:
         are split off here, kept as `last_routing` and given to the
         fetch span as `moe_experts_touched` (summed over the layers and
         a step's trips) and `moe_tokens_per_expert_max`.  `trips_at`
-        is where a step's vector holds the trips it ran: all three
-        spans carry them as `trips`.  A stack with conv layers says so
+        is where a step's vector holds the trips it ran, behind each
+        slot's emitted count: all three spans carry them as `trips`,
+        and the fetch span what the decode kernel streamed in them
+        (`_kv_stream`: `kv_blocks_live`, `kv_blocks_total`).  A stack
+        with conv layers says so
         on the fetch span of its steps and prefills: `conv_layers`,
         `attn_layers`, `conv_state_bytes` (the session's conv-state
         table).  Any other artifact takes the path it always took."""
@@ -2578,8 +2607,12 @@ class DecodeSession:
             attrs.update(self._stack_attrs)
         if obs_tracing.enabled():
             t1 = time.monotonic()
-            trips = {} if trips_at is None \
-                else {"trips": int(got[0][trips_at])}
+            trips = {}
+            if trips_at is not None:
+                trips = {"trips": int(got[0][trips_at])}
+                attrs.update(self._kv_stream(
+                    got[0][trips_at - self.n_slots:trips_at] > 0,
+                    trips["trips"]))
             launched, self._launched = self._launched, ()
             for name, a, b, more in launched:
                 obs_tracing.stamp(name, a, b, kind="serving", phase=phase,

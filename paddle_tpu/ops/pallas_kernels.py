@@ -35,6 +35,7 @@ import numpy as np
 from . import attention_tuning
 
 __all__ = ["tiled_contraction", "flash_attention", "decode_attention",
+           "kv_last_block",
            "decode_attention_reference", "decode_attention_head_slice",
            "fused_bottleneck",
            "bottleneck_reference", "dequant_matmul",
@@ -113,17 +114,20 @@ def _causal_tile_mask(s, iq, ik, block_q, block_kv):
 
 class _TileCtx(object):
     """What one grid step of a tiled contraction sees: the staged
-    operand refs, the output refs, the accumulator scratch refs, and
-    the grid coordinates (`ids`; `reduce_id`/`n_reduce` index the
-    streamed reduction axis)."""
+    operand refs, the output refs, the accumulator scratch refs, the
+    scalar-prefetch refs (`scalars`, whole in SMEM) and the grid
+    coordinates (`ids`; `reduce_id`/`n_reduce` index the streamed
+    reduction axis)."""
 
-    __slots__ = ("ins", "outs", "scratch", "ids", "reduce_id",
+    __slots__ = ("ins", "outs", "scratch", "scalars", "ids", "reduce_id",
                  "n_reduce")
 
-    def __init__(self, ins, outs, scratch, ids, reduce_id, n_reduce):
+    def __init__(self, ins, outs, scratch, scalars, ids, reduce_id,
+                 n_reduce):
         self.ins = ins
         self.outs = outs
         self.scratch = scratch
+        self.scalars = scalars
         self.ids = ids
         self.reduce_id = reduce_id
         self.n_reduce = n_reduce
@@ -132,7 +136,7 @@ class _TileCtx(object):
 def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
                       out_specs, out_shape, scratch=(), scratch_fill=(),
                       tile=None, finalize=None, tile_live=None,
-                      interpret=None):
+                      scalar_prefetch=(), interpret=None):
     """THE tiled-contraction core every kernel family instantiates.
 
     `grid` runs with "parallel" semantics on every axis except
@@ -143,9 +147,21 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
     reduction tile and `finalize(ctx)` writes the outputs from the
     accumulators on the last (normalization, per-channel dequant
     scales, and dtype casts live there).  `tile(ctx)` folds one
-    reduction tile into the accumulators; `tile_live(ids)` optionally
-    gates dead tiles (the causal upper triangle) out of the MXU work —
-    the tile's DMA is already in flight, the compute is what matters.
+    reduction tile into the accumulators; `tile_live(ids, *scalars)`
+    optionally gates dead tiles out of the compute.  Under a plain
+    index map (flash attention's causal upper triangle) a dead tile's
+    DMA is already in flight and the compute is what is saved.
+
+    `scalar_prefetch` operands (small int32 arrays) are in SMEM before
+    the grid starts: every index map takes their refs after the grid
+    coordinates, `tile_live` after `ids`, and the body finds them as
+    `ctx.scalars`.  That is what lets a family bound its STREAM by a
+    runtime value: an index map that repeats the block it staged last
+    makes the pipeline issue no copy (it re-stages an operand only when
+    its block index changes), so a tile that is dead by `tile_live` AND
+    repeats its predecessor's index costs the grid step's overhead and
+    nothing else (decode attention: a slot's K/V blocks past its
+    length).  With none given the call is the plain grid it always was.
     `interpret=None` resolves interpret-vs-Mosaic at trace time
     (_interpret_dispatch), like every kernel here always has."""
     import jax.numpy as jnp
@@ -154,14 +170,16 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
 
     n_in = len(operands)
     n_out = len(out_shape) if isinstance(out_shape, (list, tuple)) else 1
+    n_pre = len(scalar_prefetch)
     fills = tuple(scratch_fill) + (0.0,) * (len(scratch)
                                             - len(scratch_fill))
 
     def kern(*refs):
+        scalars, refs = refs[:n_pre], refs[n_pre:]
         ids = tuple(pl.program_id(i) for i in range(len(grid)))
         ctx = _TileCtx(refs[:n_in], refs[n_in:n_in + n_out],
-                       refs[n_in + n_out:], ids, ids[reduce_axis],
-                       pl.num_programs(reduce_axis))
+                       refs[n_in + n_out:], scalars, ids,
+                       ids[reduce_axis], pl.num_programs(reduce_axis))
 
         if ctx.scratch:
             @pl.when(ctx.reduce_id == 0)
@@ -170,7 +188,7 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
                     ref[...] = jnp.full_like(ref, fill)
 
         if tile_live is not None:
-            @pl.when(tile_live(ids))
+            @pl.when(tile_live(ids, *scalars))
             def _tile():
                 tile(ctx)
         else:
@@ -183,17 +201,22 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
     sem = tuple("arbitrary" if i == reduce_axis else "parallel"
                 for i in range(len(grid)))
 
+    plumbing = dict(grid=grid, in_specs=list(in_specs),
+                    out_specs=out_specs, scratch_shapes=list(scratch))
+    if n_pre:
+        plumbing = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_pre, **plumbing))
+
     def call(interp, *ops):
         return pl.pallas_call(
-            kern, grid=grid, in_specs=list(in_specs),
-            out_specs=out_specs, out_shape=out_shape,
-            scratch_shapes=list(scratch),
+            kern, out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=sem),
-            interpret=interp,
+            interpret=interp, **plumbing,
         )(*ops)
 
-    return _interpret_dispatch(call, interpret, *operands)
+    return _interpret_dispatch(call, interpret, *scalar_prefetch,
+                               *operands)
 
 
 def _online_softmax_tile(s, pv_of, acc_ref, m_ref, l_ref):
@@ -530,6 +553,21 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 # ---------------------------------------------------------------------------
 
 
+def kv_last_block(lengths, block_kv, n_blocks, xp=np):
+    """THE rule that bounds `decode_attention`'s K/V stream: the index
+    of the last block of `block_kv` positions that holds a live position
+    of a slot of `lengths` positions, max(ceil(lengths / block_kv), 1) - 1
+    and at most `n_blocks - 1`.  Grid step j of the slot stages block
+    min(j, last) and computes iff j <= last, so the slot's stream is
+    last + 1 blocks: one for a slot of length 0 (its first, all masked),
+    all `n_blocks` for a slot at (or, as an idle slot under `lengths + 1`
+    can be, past) the table's end.  Elementwise; `xp` is numpy for the
+    host's count of what a dispatch streamed (`DecodeSession`'s
+    `kv_blocks_live`), jax.numpy where `decode_attention` works it out
+    for its index maps and tile gate: one function for both."""
+    return xp.clip((lengths + (block_kv - 1)) // block_kv, 1, n_blocks) - 1
+
+
 def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
                                kv_scales=None):
     """Plain-XLA oracle/fallback with identical masking semantics:
@@ -583,8 +621,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
 
     With `layer` (a static int) k_cache/v_cache are the STACKED slot
     table [L, N, S, H, D] and the kernel reaches that layer through its
-    BlockSpec index maps, (layer, b, j, 0, 0): no slice of the table is
-    materialised for the custom call, so a decode step that carries the
+    BlockSpec index maps, (layer, b, block, 0, 0): no slice of the table
+    is materialised for the custom call, so a decode step that carries the
     table and updates it in place keeps ONE buffer of it
     (`inference/decode.py::_step_core`).  Body, block geometry and
     arithmetic are those of the 4-D form, which stays for callers that
@@ -601,9 +639,26 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     attention_tuning.get_decode_config keyed by the CACHE dtype
     (FLAGS.flash_block_kv override > kernel-tuning registry >
     heuristic). Falls back to the plain-XLA composition when no block
-    edge divides the cache length. A slot with length 0 produces
-    well-defined garbage (every position masked) — the decode step
-    gates dead slots out downstream."""
+    edge divides the cache length.
+
+    THE STREAM IS BOUNDED BY `lengths`: the vector and each slot's last
+    live block, last(b) = `kv_last_block(lengths[b], block_kv, S /
+    block_kv)` (worked out once a call, outside the grid), are
+    scalar-prefetch operands; slot b's K and V index maps stage block
+    min(j, last(b)) and the body runs under j <= last(b).  A block past
+    a slot's length
+    is neither copied from HBM (the pipeline copies only when
+    the block index changes) nor computed: a slot costs ceil(length /
+    block_kv) blocks and the loop overhead of the rest of its grid row,
+    and a slot at full length what it always cost.  Such a
+    block added exp(_NEG_INF - m) = 0 to the sums when it was streamed,
+    so for every slot of length >= 1 the result is that of a whole-row
+    stream; what the table holds past a slot's last live block is never
+    read.  A slot with length 0 has no live position: its first block
+    is staged, every position of it masked, and the result is
+    well-defined garbage (finite: the mean of that block's V rows) that
+    disturbs no other slot — the decode step gates dead slots out
+    downstream."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -639,14 +694,23 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
                                           scale=scale,
                                           kv_scales=kv_scales)
     lengths = jnp.asarray(lengths).astype(jnp.int32).reshape(N)
+    n_blocks = S // bkv
+    # slot b's stream stops at its last live block: past it the index
+    # map repeats that block, which the pipeline holds already and does
+    # not copy again, and `tile_live` keeps the body off it.  The rule
+    # runs once a call, outside the grid: a grid step only compares.
+    last = kv_last_block(lengths, bkv, n_blocks, xp=jnp)
     if stacked:
         # the layer's axis is squeezed out of the block: the body sees
         # the (1, bkv, H, D) tile it always saw
         layer = int(layer)
-        kv_spec = pl.BlockSpec((None, 1, bkv, Hc, D),
-                               lambda b, j: (layer, b, j, 0, 0))
+        kv_spec = pl.BlockSpec(
+            (None, 1, bkv, Hc, D), lambda b, j, len_ref, last_ref: (
+                layer, b, jnp.minimum(j, last_ref[b]), 0, 0))
     else:
-        kv_spec = pl.BlockSpec((1, bkv, Hc, D), lambda b, j: (b, j, 0, 0))
+        kv_spec = pl.BlockSpec(
+            (1, bkv, Hc, D), lambda b, j, len_ref, last_ref: (
+                b, jnp.minimum(j, last_ref[b]), 0, 0))
     if G > 1:
         q = q.reshape(N, Hc, G, D).transpose(0, 2, 1, 3).reshape(N, H, D)
 
@@ -659,7 +723,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
             [fn(rows[g * Hc:(g + 1) * Hc]) for g in range(G)], axis=0)
 
     def tile(ctx):
-        q_ref, k_ref, v_ref, len_ref = ctx.ins[:4]
+        q_ref, k_ref, v_ref = ctx.ins[:3]
+        len_ref = ctx.scalars[0]
         acc_ref, m_ref, l_ref = ctx.scratch
         qb = q_ref[0]                              # [H, D]
         kb = _stage_dequant(k_ref[0].transpose(1, 0, 2),
@@ -678,7 +743,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         if quant:
             # per-head K scale folds into the score scale, once per
             # score element — never per streamed cache element
-            s = s * ctx.ins[4][0]                  # [H, 1] broadcast
+            s = s * ctx.ins[3][0]                  # [H, 1] broadcast
         kpos = ctx.reduce_id * bkv + jax.lax.broadcasted_iota(
             jnp.int32, (H, bkv), 1)
         s = jnp.where(kpos >= length, _NEG_INF, s)
@@ -692,35 +757,34 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         acc_ref, m_ref, l_ref = ctx.scratch
         o, _ = _softmax_finalize(acc_ref, m_ref, l_ref)
         if quant:
-            o = o * ctx.ins[4][1]                  # per-head V scale
+            o = o * ctx.ins[3][1]                  # per-head V scale
         o_ref[0] = o.astype(o_ref.dtype)
 
-    operands = [q, k_cache, v_cache, lengths]
-    in_specs = [
-        pl.BlockSpec((1, H, D), lambda b, j: (b, 0, 0)),
-        kv_spec,
-        kv_spec,
-        # the whole [N] vector in scalar memory, indexed by slot: a
-        # (1, 1) VMEM block of it is below Mosaic's (8, 128) tile floor
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-    ]
+    operands = [q, k_cache, v_cache]
+    q_spec = pl.BlockSpec((1, H, D), lambda b, j, *_: (b, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     if quant:
         operands.append(jnp.asarray(kv_scales, jnp.float32).reshape(
             2, H, 1))
         in_specs.append(pl.BlockSpec((2, H, 1),
-                                     lambda b, j: (0, 0, 0)))
+                                     lambda b, j, *_: (0, 0, 0)))
     out = tiled_contraction(
         tuple(operands),
-        grid=(N, S // bkv),
+        grid=(N, n_blocks),
         reduce_axis=1,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, D), lambda b, j: (b, 0, 0)),
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((N, H, D), q.dtype),
         scratch=[pltpu.VMEM((H, D), jnp.float32),
                  pltpu.VMEM((H, _MIN_LANES), jnp.float32),
                  pltpu.VMEM((H, _MIN_LANES), jnp.float32)],
         scratch_fill=(0.0, _NEG_INF, 0.0),
         tile=tile, finalize=finalize,
+        tile_live=lambda ids, len_ref, last_ref:
+            ids[1] <= last_ref[ids[0]],
+        # two [N] vectors in scalar memory before the grid starts, where
+        # the index maps can read them: the mask's and the stream's
+        scalar_prefetch=(lengths, last),
         interpret=interpret)
     if G > 1:
         out = out.reshape(N, G, Hc, D).transpose(0, 2, 1, 3).reshape(N, H, D)

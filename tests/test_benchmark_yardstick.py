@@ -23,3 +23,61 @@ from benchmark.tests.test_idle_readers import *     # noqa: E402,F401,F403
 
 del test_resnet_reference_matches_the_program_loss_and_update  # noqa: F821
 del test_cell_rehearsal, test_deep_cell_rehearsal              # noqa: F821
+
+
+# ---------------------------------------------------------------------------
+# benchmark/layers/decode_kv_stream_share.py on a recorded span list (here,
+# not in benchmark/tests/: PR 32 may add one file under benchmark/, the reader)
+# ---------------------------------------------------------------------------
+
+import pytest                                        # noqa: E402
+
+
+def _fetch(t0, phase="step", **attrs):
+    return {"name": "decode/fetch", "t0": t0, "t1": t0 + 0.01,
+            "attrs": dict(attrs, phase=phase)}
+
+
+# three dispatches of a lane of 32 slots, 12 layers, 8 blocks a row: a
+# window of 8 trips, one of 7, and an admission round's single trip
+_STREAMED = [_fetch(1.0, trips=8, kv_blocks_live=4300, kv_blocks_total=24576),
+             _fetch(2.0, trips=7, kv_blocks_live=4100, kv_blocks_total=21504),
+             _fetch(3.0, trips=1, kv_blocks_live=3072, kv_blocks_total=3072)]
+_OTHERS = [_fetch(1.5, phase="prefill", d2h_bytes=4),
+           _fetch(9.0, trips=8, kv_blocks_live=1, kv_blocks_total=24576),
+           {"name": "decode/launch", "t0": 1.0, "t1": 1.1,
+            "attrs": {"phase": "step", "trips": 8}}]
+
+
+@pytest.mark.parametrize("case,spans,want", [
+    # blocks over blocks, whatever the dispatches' trips
+    ("streamed", _STREAMED,
+     100.0 * (4300 + 4100 + 3072) / (24576 + 21504 + 3072)),
+    # a program that stamps neither attribute streams whole rows: 100,
+    # with `trips` (PR 30's program) and without (a step a dispatch)
+    ("whole_rows", [_fetch(1.0, trips=8), _fetch(2.0, trips=7)], 100.0),
+    ("no_trips", [_fetch(1.0), _fetch(2.0)], 100.0),
+    # a fetch without them weighs its trips: 8 trips at 25%, 8 at 100%
+    ("mixed", [_fetch(1.0, trips=8, kv_blocks_live=25, kv_blocks_total=100),
+               _fetch(2.0, trips=8)], 62.5),
+    # other phases, other spans and fetches outside the window do not count
+    ("only_step_fetches_of_the_window", _STREAMED + _OTHERS,
+     100.0 * (4300 + 4100 + 3072) / (24576 + 21504 + 3072)),
+    ("no_step_fetch", _OTHERS, None)])
+def test_decode_kv_stream_share_reader(case, spans, want):
+    read = bench_run.load_reader("decode_kv_stream_share")
+    got = read(spans, None, {"window": (0.5, 5.0)})
+    assert got == (want if want is None else pytest.approx(want, rel=1e-12))
+
+
+def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
+    manifest = bench_run.load_json(bench_run.MANIFEST)
+    entry, = [m for m in manifest["per_layer"]
+              if m["name"] == "decode_kv_stream_share"]
+    assert entry == {
+        "name": "decode_kv_stream_share", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "tokens_per_s",
+        "workloads": ["gpt2s_decode_saturated", "gpt2s_decode_deep",
+                      "olmoe_decode_saturated", "lfm2_decode_saturated"]}
+    assert manifest["per_layer"][-1] is entry       # appended, not inserted

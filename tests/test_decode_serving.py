@@ -893,6 +893,31 @@ def _rule(budgets, n_slots, cap):
     return out
 
 
+@pytest.mark.parametrize("n_steps,live,total", [
+    # 2 layers x 2 blocks of 128 over S = 256.  Three trips: slot 0
+    # attends under 127, 128, 129 positions (1, 1, 2 blocks), slot 1
+    # under 6, 7, 8 (1, 1, 1), the idle slot 2 under 0 + 1 (1, 1, 1)
+    (3, 2 * (4 + 3 + 3), 3 * 3 * 2 * 2),
+    (1, 2 * (1 + 1 + 1), 1 * 3 * 2 * 2)])
+def test_step_fetch_span_counts_the_kv_blocks_streamed(
+        endless, traced, n_steps, live, total):
+    """`kv_blocks_live` / `kv_blocks_total` of a step's `decode/fetch`
+    span against a count by hand (the kernel's rule, `kv_last_block`:
+    ceil((lengths + t + 1) / block) blocks a slot, a trip and a layer)."""
+    sess = endless.new_session(3)
+    assert sess._kv_block == 128 and sess._kc.shape[:3] == (2, 3, 256)
+    sess.prefill(0, [1 + i % 30 for i in range(126)])
+    sess.prefill(1, [5, 9, 3, 7, 2])
+    traced.clear()
+    _, counts, trips = sess.decode_fused(n_steps)
+    assert trips == n_steps and counts.tolist() == [trips, trips, 0]
+    fetch, = [s for s in traced.recent_spans(name="decode/fetch")
+              if s["attrs"]["phase"] == "step"]
+    assert fetch["attrs"]["trips"] == trips
+    assert fetch["attrs"]["kv_blocks_live"] == live
+    assert fetch["attrs"]["kv_blocks_total"] == total
+
+
 class TestWindowRule:
     @pytest.mark.parametrize("cap,max_new", [
         (None, (20, 13)), (None, (9, 9)), (4, (20, 13)), (None, (3, 30))])

@@ -136,6 +136,156 @@ def test_decode_family_parity(kv_dtype, N, S, bkv):
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
+# ---------------------------------------------------------------------------
+# the bounded K/V stream: slot b's blocks stop at kv_last_block(lengths[b])
+# ---------------------------------------------------------------------------
+
+_BKV, _S = 8, 32
+# 1, bkv - 1, bkv, bkv + 1, S - 1, S, mixed over the slots
+_EDGE_LENGTHS = np.array([1, _BKV - 1, _BKV, _BKV + 1, _S - 1, _S], np.int32)
+
+
+def _bounded_case(form, kv_dtype, seed=5):
+    """(call(lengths, k, v) -> out [N, H, D], reference(lengths, k, v), k, v)
+    of one entry of the decode family: `form` in plain | stacked | gqa |
+    gqa_stacked | head_slice | head_slice_stacked."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    N = len(_EDGE_LENGTHS)
+    H, Hc = (32, 8) if form.startswith("gqa") else (4, 4)
+    q, k, v, _, scales = _decode_operands(N, _S, Hc, 8, kv_dtype, seed=seed)
+    if H != Hc:
+        q = jnp.asarray(np.random.RandomState(seed).randn(N, H, 8)
+                        .astype(np.float32))
+    stacked = form.endswith("stacked")
+    layer = 1 if stacked else None
+
+    def table(t):
+        # the layer of a stacked table that is NOT attended holds NaN
+        # (float) / 127 (int8): a kernel that strayed there would show
+        if not stacked:
+            return t
+        other = jnp.full_like(t, 127 if kv_dtype == "int8" else np.nan)
+        return jnp.stack([other, t])
+
+    if form.startswith("head_slice"):
+        # a member's 2 heads of 4, its scales sliced out of the full table
+        def call(lengths, k, v):
+            return pk.decode_attention_head_slice(
+                q[:, 2:], table(k[:, :, 2:]), table(v[:, :, 2:]), lengths,
+                head_offset=2, n_local_heads=2, block_kv=_BKV,
+                kv_scales=scales, layer=layer)
+
+        def ref(lengths, k, v):
+            return pk.decode_attention_reference(
+                q[:, 2:], k[:, :, 2:], v[:, :, 2:], lengths,
+                kv_scales=None if scales is None else scales[:, 2:])
+    else:
+        def call(lengths, k, v):
+            return pk.decode_attention(q, table(k), table(v), lengths,
+                                       block_kv=_BKV, kv_scales=scales,
+                                       layer=layer)
+
+        def ref(lengths, k, v):
+            return pk.decode_attention_reference(q, k, v, lengths,
+                                                 kv_scales=scales)
+    return call, ref, k, v
+
+
+_BOUNDED = [(form, dt) for form in ("plain", "stacked", "head_slice",
+                                    "head_slice_stacked")
+            for dt in ("float32", "int8")] \
+    + [("gqa", "float32"), ("gqa_stacked", "float32")]
+
+
+@pytest.fixture
+def whole_rows(monkeypatch):
+    """Enter to make `decode_attention` stream every block of every slot,
+    as it did before its stream was bounded: the rule says "the last
+    block" for every length, the mask still follows the true lengths."""
+    import contextlib
+    from paddle_tpu.ops import pallas_kernels as pk
+
+    @contextlib.contextmanager
+    def enter():
+        with monkeypatch.context() as m:
+            m.setattr(pk, "kv_last_block",
+                      lambda lengths, bkv, n_blocks, xp=np:
+                      n_blocks - 1 + 0 * lengths)
+            yield
+    return enter
+
+
+@pytest.mark.parametrize("form,kv_dtype", _BOUNDED)
+def test_decode_bounded_stream_is_the_whole_row_stream(form, kv_dtype,
+                                                       whole_rows):
+    """Lengths on and around every block edge: within rounding of the
+    reference, and BIT-EQUAL to the same kernel made to stream whole
+    rows (a wholly dead block added exp(_NEG_INF - m) = 0)."""
+    call, ref, k, v = _bounded_case(form, kv_dtype)
+    for shift in range(len(_EDGE_LENGTHS)):
+        lengths = np.roll(_EDGE_LENGTHS, shift)
+        got = np.asarray(call(lengths, k, v))
+        np.testing.assert_allclose(got, np.asarray(ref(lengths, k, v)),
+                                   rtol=2e-5, atol=2e-5)
+        with whole_rows():
+            whole = np.asarray(call(lengths, k, v))
+        assert np.array_equal(got, whole), (form, kv_dtype, shift)
+
+
+@pytest.mark.parametrize("form", ["plain", "stacked", "gqa", "gqa_stacked",
+                                  "head_slice"])
+def test_decode_never_reads_past_a_slots_last_live_block(form, whole_rows):
+    """Every position past a slot's last live block poisoned with NaN:
+    the result is finite and equal to the clean table's.  A kernel that
+    streams whole rows multiplies the poison by its zero weight and
+    returns NaN (the second half: the poison is real)."""
+    import jax.numpy as jnp
+    call, _, k, v = _bounded_case(form, "float32")
+    lengths = _EDGE_LENGTHS
+    dead_from = -(-lengths // _BKV) * _BKV                     # [N]
+    dead = (np.arange(_S)[None] >= dead_from[:, None])[:, :, None, None]
+    assert dead.any()
+    clean = np.asarray(call(lengths, k, v))
+    kp, vp = (jnp.where(dead, np.nan, t) for t in (k, v))
+    got = np.asarray(call(lengths, kp, vp))
+    assert np.all(np.isfinite(got))
+    assert np.array_equal(got, clean)
+    with whole_rows():
+        assert not np.all(np.isfinite(np.asarray(call(lengths, kp, vp))))
+
+
+@pytest.mark.parametrize("bkv,n_blocks", [(8, 4), (128, 8), (128, 32),
+                                          (1, 1), (16, 1)])
+def test_kv_last_block_rule(bkv, n_blocks):
+    """last = max(ceil(len / bkv), 1) - 1, never past the table; grid
+    step j stages block min(j, last) and computes iff j <= last.  The
+    host's numpy and the kernel's jax.numpy spell it alike."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas_kernels import kv_last_block
+    S = bkv * n_blocks
+    lengths = np.arange(0, S + 3, dtype=np.int32)   # past S: lengths + 1
+    want = np.array([min(max(-(-int(n) // bkv), 1), n_blocks) - 1
+                     for n in lengths])
+    got = kv_last_block(lengths, bkv, n_blocks)
+    assert isinstance(got, np.ndarray) and np.array_equal(got, want)
+    assert np.array_equal(
+        np.asarray(kv_last_block(jnp.asarray(lengths), bkv, n_blocks,
+                                 xp=jnp)), want)
+    assert got[0] == 0 and got[1] == 0                # length 0: block 0
+    assert got[S] == n_blocks - 1 == got[S + 2]       # full, and past it
+    if bkv > 1:
+        assert got[bkv] == 0 and got[bkv + 1] == min(1, n_blocks - 1)
+    j = np.arange(n_blocks)[:, None]
+    staged = np.minimum(j, got[None])                 # [n_blocks, lengths]
+    live = j <= got[None]
+    # a slot's stream is last + 1 distinct blocks, 0 .. last, in order;
+    # every dead step repeats the last live block
+    assert np.array_equal(live.sum(0), got + 1)
+    assert np.array_equal(staged.max(0), got)
+    assert np.all(staged[~live] == np.broadcast_to(got, staged.shape)[~live])
+
+
 def test_decode_int8_requires_scales():
     from paddle_tpu.ops.pallas_kernels import decode_attention
     q, kc, vc, lengths, _ = _decode_operands(2, 32, 2, 8, "int8")

@@ -94,6 +94,41 @@ def test_decode_attention_compiles(one_chip, geometry, dtypes):
         *DECODE_GEOMETRIES[geometry], q_dt, kv_dt))
 
 
+# the decode cells' slot tables as the step holds them (rows padded to the
+# kernel's tile, `decode.table_row`): (N, S, query heads, K/V heads, D)
+CELL_TABLES = {"gpt2_small": (32, 1024, 16, 16, 128),
+               "olmoe_1b_7b": (8, 4096, 16, 16, 128),
+               "lfm2_24b_a2b": (32, 4096, 32, 8, 128)}
+
+
+@pytest.mark.parametrize("cell,kv_dt", [
+    (c, dt) for c in sorted(CELL_TABLES) for dt in ("float32", "int8")
+    if not (c == "lfm2_24b_a2b" and dt == "int8")])   # no int8 GQA cache
+def test_bounded_decode_attention_compiles_at_the_cells_tables(
+        one_chip, cell, kv_dt):
+    """The kernel whose K/V stream stops at a slot's length (`lengths`
+    and each slot's last live block are scalar-prefetch operands, the
+    second read by the index maps) over a layer of
+    each cell's STACKED table, as `_attend_table` calls it: Mosaic takes
+    it, and it is ONE custom call a layer (the benchmark's attention
+    readers count every Mosaic call of the step as attention)."""
+    N, S, H, Hc, D = CELL_TABLES[cell]
+
+    def fn(q, k, v, lengths, *scales):
+        return pk.decode_attention(q, k, v, lengths, interpret=False,
+                                   kv_scales=scales[0] if scales else None,
+                                   layer=1)
+
+    specs = [((N, H, D), "float32"), ((2, N, S, Hc, D), kv_dt),
+             ((2, N, S, Hc, D), kv_dt), ((N,), "int32")]
+    if kv_dt == "int8":
+        specs.append(((2, H), "float32"))
+    text = compile_for_chip(fn, one_chip, *specs)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # no copy of a table's layer is made for the call
+    assert "[%d,%d,%d,%d]" % (N, S, Hc, D) not in text
+
+
 @pytest.mark.parametrize("kv_dt", ["float32", "int8"])
 def test_decode_attention_head_slice_compiles(one_chip, kv_dt):
     """One member's head block of a 4-way tensor-parallel split of the

@@ -7,6 +7,11 @@ under a serving traffic mix, through the path a deployment uses:
 with the default placement and no flag set; what a deployment sets
 (`decode_slots`) comes from the configuration file.
 
+The callers live in a process of their own (`loadgen.Generator`, started
+first so that its imports cost no set-up time): no thread of the process
+that holds the chip calls `infer_stream`, and the window's line says how
+late the generator ran and what CPU time it took.
+
 Traffic `loop` kinds (benchmark/loadgen.py):
   open    arrivals due at fixed times from the seed at `rate_per_s`, each
           followed to its end (drain limit `drain_s`); the tails are the
@@ -27,7 +32,7 @@ import time
 
 import numpy as np
 
-from benchmark import loadgen, stats, tracewin
+from benchmark import idle, loadgen, stats, tracewin
 
 # --- tolerances of the comparison with the plain reference -----------------
 # TOL_LOGITS: max |logit_program - logit_reference| over the compared
@@ -195,101 +200,65 @@ def program_spans(t0, t1):
     return out
 
 
-def run(ctx):
-    from paddle_tpu.inference.decode import save_decode_model
-    from paddle_tpu.obs import tracing
-    from paddle_tpu.serving.server import InferenceServer, ServingClient
+# --- what `serve_decode_arch.run` shares with `run` below: the traffic, its
+# warm-up, the window and the reduction, all through the generator's process
 
-    cfg, mix = ctx.config, ctx.traffic
-    meta = dict(cfg["model"])
-    n_slots = int(cfg["deployment"]["decode_slots"])
-    art = os.path.join(ctx.cache_dir, "artifacts", cfg["name"])
+def plan_traffic(ctx, n_slots, vocab_size):
+    """(requests, the loop's arguments after them) of the cell's mix."""
+    mix = ctx.traffic
+    if mix["loop"] == "open":
+        dues = loadgen.due_times(mix, ctx.seed, ctx.seconds)
+        n_req, args = len(dues), (dues, float(mix["drain_s"]))
+    elif mix["loop"] == "closed":
+        n_req = int(mix["requests"])
+        args = (int(mix["clients_per_slot"]) * n_slots, ctx.seconds)
+    else:
+        raise ValueError("%s: unknown loop %r" % (ctx.config["driver"],
+                                                  mix["loop"]))
+    return loadgen.make_requests(mix, ctx.seed, n_req, vocab_size), args
 
+
+def warm_up(ctx, gen, name, requests, pred):
+    """Two short streams a prompt bucket of the mix, through the wire and
+    the generator's process."""
     t_phase = time.time()
-    state_dev = make_state_on_device(meta, ctx.seed)
-    shutil.rmtree(art, ignore_errors=True)
-    save_decode_model(art, {n: np.asarray(v) for n, v in state_dev.items()},
-                      meta)
-    ctx.log(phase="artifact", seconds=time.time() - t_phase, path=art)
+    by_bucket = {}
+    for r in requests:
+        by_bucket.setdefault(pred.prompt_bucket(len(r["prompt"])), r)
+    warm = [dict(r, max_new=4) for r in by_bucket.values()] * 2
+    _, wrecs = loadgen.run_open_loop(gen, name, warm, [0.0] * len(warm),
+                                     120.0)
+    bad_warm = [r.error or r.info for r in wrecs
+                if not (r.info and r.info.get("done"))]
+    if bad_warm:
+        raise RuntimeError("warm-up stream failed: %r" % bad_warm[:2])
+    ctx.log(phase="warmed", seconds=time.time() - t_phase,
+            buckets=sorted(by_bucket), generator=gen.stats)
 
-    srv = InferenceServer("127.0.0.1:0").start()
-    try:
-        t_phase = time.time()
-        name = cfg["name"]
-        entry = srv.registry.load_model(name, art, decode_slots=n_slots)
-        pred = entry.predictor
-        if entry.batcher.n_slots != n_slots:
-            raise RuntimeError("the lane has %d slots, the configuration "
-                               "says %d" % (entry.batcher.n_slots, n_slots))
-        ctx.log(phase="loaded", seconds=time.time() - t_phase,
-                compile_cache=entry.compile_cache, slots=n_slots,
-                kv_cache_bytes=pred.kv_cache_bytes(n_slots),
-                param_bytes=pred.param_bytes(),
-                devices=entry.device_labels())
 
-        t_phase = time.time()
-        ok_ref = check_against_reference(ctx, pred, state_dev, meta)
-        ctx.log(phase="checked", seconds=time.time() - t_phase)
+def measure(ctx, gen, name, requests, args):
+    """The measured window.  Returns a dict: `t0`, `t1` (monotonic; t0 is
+    the generator's), `t0_wall`, `recs`, `spans`, `ring`, `win`."""
+    from paddle_tpu.obs import tracing
+    tracing.clear()
+    win = tracewin.Window(ctx)
+    ctx.memory.start()
+    t0_wall = time.time()
+    t0, recs = gen.run(ctx.traffic["loop"], name, requests, *args)
+    t1 = time.monotonic()
+    ctx.memory.stop()
+    win.close()
+    return {"t0": t0, "t1": t1, "t0_wall": t0_wall, "recs": recs,
+            "spans": program_spans(t0, t1 + 1.0), "ring": tracing.stats(),
+            "win": win, "generator": gen.stats}
 
-        # ---- the traffic, and a warm-up of its buckets through the wire ---
-        if mix["loop"] == "open":
-            dues = loadgen.due_times(mix, ctx.seed, ctx.seconds)
-            n_req = len(dues)
-        elif mix["loop"] == "closed":
-            clients = int(mix["clients_per_slot"]) * n_slots
-            n_req = int(mix["requests"])
-        else:
-            raise ValueError("serve_decode: unknown loop %r" % mix["loop"])
-        requests = loadgen.make_requests(mix, ctx.seed, n_req,
-                                         meta["vocab_size"])
-        factory = lambda: ServingClient(srv.endpoint)       # noqa: E731
-        t_phase = time.time()
-        by_bucket = {}
-        for r in requests:
-            by_bucket.setdefault(pred.prompt_bucket(len(r["prompt"])), r)
-        warm = [dict(r, max_new=4) for r in by_bucket.values()] * 2
-        _, wrecs = loadgen.run_open_loop(factory, name, warm,
-                                         [0.0] * len(warm), 120.0)
-        bad_warm = [r.error or r.info for r in wrecs
-                    if not (r.info and r.info.get("done"))]
-        if bad_warm:
-            raise RuntimeError("warm-up stream failed: %r" % bad_warm[:2])
-        ctx.log(phase="warmed", seconds=time.time() - t_phase,
-                buckets=sorted(by_bucket))
 
-        # ---- the measured window ------------------------------------------
-        # the reference's copy of the weights (0.65 GB at GPT-2 small) is
-        # not the deployment's: dropped for the window, redrawn after it
-        del state_dev
-        tracing.clear()
-        win = tracewin.Window(ctx)
-        ctx.memory.start()
-        t0_wall = time.time()
-        if mix["loop"] == "open":
-            t0, recs = loadgen.run_open_loop(factory, name, requests, dues,
-                                             float(mix["drain_s"]))
-            t1 = time.monotonic()
-        else:
-            t0, recs = loadgen.run_closed_loop(factory, name, requests,
-                                               clients, ctx.seconds)
-            t1 = time.monotonic()
-        ctx.memory.stop()
-        win.close()
-        spans = program_spans(t0, t1 + 1.0)
-        ring = tracing.stats()
-
-        t_phase = time.time()
-        state_dev = make_state_on_device(meta, ctx.seed)
-        ok_served = check_served(ctx, recs, requests, pred, state_dev, meta)
-        ctx.log(phase="after_window", window_to_here_s=time.time() - t0_wall
-                - ctx.seconds, served_check_s=time.time() - t_phase)
-    finally:
-        t_phase = time.time()
-        srv.shutdown(drain=False, timeout=10.0)
-        ctx.log(phase="shutdown", seconds=time.time() - t_phase)
-        shutil.rmtree(art, ignore_errors=True)
-
-    # ---- the generator's reduction ----------------------------------------
+def reduce_window(ctx, w, ok, pred, n_slots, meta, **run_facts):
+    """The generator's reduction of a measured window `w` (`measure`) to
+    the driver's result; `ok`: what the comparisons with the reference
+    said.  `run_facts` go to the per-layer readers beside the rest."""
+    t0, t1, recs, spans, ring = (w[k] for k in ("t0", "t1", "recs", "spans",
+                                                "ring"))
     eos, S = pred.eos_id, pred.max_seq_len
     judged = [r for r in recs if not r.cancelled]
     failed = [r for r in judged if not r.ok(eos, S)]
@@ -320,7 +289,11 @@ def run(ctx):
             k = int((sp_["t0"] - t0) / ctx.seconds * 3)
             if 0 <= k < 3:
                 thirds[k].append((sp_["t1"] - sp_["t0"]) * 1e3)
-    ctx.log(phase="window", loop=mix["loop"], requests=len(recs),
+    child = w["generator"]
+    # which of the program's phases grew in a low run: an untraced run
+    # keeps nothing else that says (PERF.md section 7)
+    table = idle.span_table(spans, (t0, t1))
+    ctx.log(phase="window", loop=ctx.traffic["loop"], requests=len(recs),
             queue_wait_p50_ms_by_third=[stats.median(t) if t else None
                                         for t in thirds],
             judged=len(judged), failed=len(failed),
@@ -337,27 +310,102 @@ def run(ctx):
             itl_p50_ms=stats.median(itl) if itl else None,
             itl_p95_ms=e2e.get("itl_p95_ms"),
             gen_late_p95_ms=stats.percentile(late, 95) if late else None,
+            gen_cpu_s=child["cpu_user_s"] + child["cpu_sys_s"],
+            generator=child,
             spans=len(spans), spans_dropped=ring["dropped"],
+            span_median_ms={k: float("%.4g" % v[0]) for k, v in table.items()},
+            span_mean_ms={k: float("%.4g" % v[1]) for k, v in table.items()},
+            span_count={k: v[2] for k, v in table.items()},
             first_failures=[r.error or r.info for r in failed[:3]],
             threads_left=threading.active_count())
-    result = {"correct": bool(ok_ref and ok_served and not failed
-                              and ring["dropped"] == 0),
+    result = {"correct": bool(ok and not failed and ring["dropped"] == 0),
               "attempted": len(recs), "failed": len(failed),
-              "end_to_end": e2e, "window_start_wall": t0_wall,
+              "end_to_end": e2e, "window_start_wall": w["t0_wall"],
               "window_monotonic": (t0, t1)}
     if ctx.trace:
+        win = w["win"]
         trace, w0, w1 = win.read()
         result.update(
             trace=trace, trace_window=(w0, w1), spans=spans,
-            run={"chips": ctx.chips, "slots": n_slots, "window": (t0, t1),
-                 "seconds": ctx.seconds, "records": recs, "meta": meta,
-                 "trace_window": (w0, w1),
-                 "trace_window_monotonic": (win.t_start, win.t_stop),
-                 "device_kind": ctx.devices[0].device_kind,
-                 "kernel_match": cfg.get("kernel_trace_match", {}),
-                 "host_spans": [(s["name"], trace.from_monotonic(s["t0"]),
-                                 trace.from_monotonic(s["t1"]))
-                                for s in spans
-                                if s["name"] in ("serving/decode_step",
-                                                 "serving/prefill_compute")]})
+            run=dict(
+                run_facts, chips=ctx.chips, slots=n_slots, window=(t0, t1),
+                seconds=ctx.seconds, records=recs, meta=meta,
+                trace_window=(w0, w1),
+                trace_window_monotonic=(win.t_start, win.t_stop),
+                device_kind=ctx.devices[0].device_kind,
+                kernel_match=ctx.config.get("kernel_trace_match", {}),
+                host_spans=[(s["name"], trace.from_monotonic(s["t0"]),
+                             trace.from_monotonic(s["t1"]))
+                            for s in spans
+                            if s["name"] in ("serving/decode_step",
+                                             "serving/prefill_compute")]))
     return result
+
+
+def run(ctx):
+    # the generator's process first: its imports run beside everything
+    # up to the warm-up
+    gen = loadgen.Generator()
+    try:
+        return _run(ctx, gen)
+    finally:
+        gen.close()
+
+
+def _run(ctx, gen):
+    from paddle_tpu.inference.decode import save_decode_model
+    from paddle_tpu.serving.server import InferenceServer
+
+    cfg = ctx.config
+    meta = dict(cfg["model"])
+    n_slots = int(cfg["deployment"]["decode_slots"])
+    art = os.path.join(ctx.cache_dir, "artifacts", cfg["name"])
+
+    t_phase = time.time()
+    state_dev = make_state_on_device(meta, ctx.seed)
+    shutil.rmtree(art, ignore_errors=True)
+    save_decode_model(art, {n: np.asarray(v) for n, v in state_dev.items()},
+                      meta)
+    ctx.log(phase="artifact", seconds=time.time() - t_phase, path=art)
+
+    srv = InferenceServer("127.0.0.1:0").start()
+    try:
+        t_phase = time.time()
+        name = cfg["name"]
+        entry = srv.registry.load_model(name, art, decode_slots=n_slots)
+        pred = entry.predictor
+        if entry.batcher.n_slots != n_slots:
+            raise RuntimeError("the lane has %d slots, the configuration "
+                               "says %d" % (entry.batcher.n_slots, n_slots))
+        ctx.log(phase="loaded", seconds=time.time() - t_phase,
+                compile_cache=entry.compile_cache, slots=n_slots,
+                kv_cache_bytes=pred.kv_cache_bytes(n_slots),
+                param_bytes=pred.param_bytes(),
+                devices=entry.device_labels())
+
+        t_phase = time.time()
+        ok_ref = check_against_reference(ctx, pred, state_dev, meta)
+        ctx.log(phase="checked", seconds=time.time() - t_phase)
+
+        requests, args = plan_traffic(ctx, n_slots, meta["vocab_size"])
+        gen.serve(srv.endpoint)
+        warm_up(ctx, gen, name, requests, pred)
+
+        # the reference's copy of the weights (0.65 GB at GPT-2 small) is
+        # not the deployment's: dropped for the window, redrawn after it
+        del state_dev
+        w = measure(ctx, gen, name, requests, args)
+
+        t_phase = time.time()
+        state_dev = make_state_on_device(meta, ctx.seed)
+        ok_served = check_served(ctx, w["recs"], requests, pred, state_dev,
+                                 meta)
+        ctx.log(phase="after_window", window_to_here_s=time.time()
+                - w["t0_wall"] - ctx.seconds,
+                served_check_s=time.time() - t_phase)
+    finally:
+        t_phase = time.time()
+        srv.shutdown(drain=False, timeout=10.0)
+        ctx.log(phase="shutdown", seconds=time.time() - t_phase)
+        shutil.rmtree(art, ignore_errors=True)
+    return reduce_window(ctx, w, ok_ref and ok_served, pred, n_slots, meta)

@@ -35,26 +35,27 @@ the whole state on the device.  Here
     position by position (`_precision`).  No fixed logit bound separates the
     two at this depth; the same run's bfloat16 reading does.
 
-Shared with `serve_decode.py` unchanged (imported): `program_spans`,
-`_kv_live_bytes`, the tolerances' defaults.  Its `check_served` does not
-fit (whole state on the device, `forward(state, tokens, L, H)`), so the
-replay after the window is written here on `reference_rows`.  The traffic
-`loop` kinds, the warm-up, the window and the reduction to the result are
-`serve_decode.run`'s, line for line where they can be (PERF.md section 7:
-let `serve_decode.run` take the state maker and the reference runner from
-the reference module, and the two drivers become one).
+Shared with `serve_decode.py` (imported): the tolerances' defaults and,
+since PR 34, everything from the traffic on: `plan_traffic`, `warm_up`
+through the generator's process, the window (`measure`) and the reduction
+to the result (`reduce_window`).  Its `check_served` does not fit (whole
+state on the device, `forward(state, tokens, L, H)`), so the replay after
+the window is written here on `reference_rows`.  What is left apart is the
+set-up up to the checks (PERF.md section 7: let `serve_decode.run` take the
+state maker and the reference runner from the reference module, and the two
+drivers become one).
 """
 
 import os
 import shutil
-import threading
 import time
 
 import numpy as np
 
-from benchmark import loadgen, stats, tracewin
+from benchmark import loadgen, stats
 from benchmark.drivers.serve_decode import (TOL_LOGITS, TOL_TOP1_GAP,
-                                            _kv_live_bytes, program_spans)
+                                            measure, plan_traffic,
+                                            reduce_window, warm_up)
 
 # What the configuration's `tolerances` may override; reasons beside the
 # numbers there.  With router_gap 0 no position is a near-tie and none is
@@ -306,26 +307,21 @@ def step_scope_ops(pred, n_slots, cfg):
         fn.as_text(), "moe_ffn", match))}
 
 
-def _span_medians(spans):
-    """{span name, with its phase where it has one: median ms} of the
-    window, on the line of every run: the cell runs at two levels 1.3%
-    apart on the chip machine (PERF.md section 7), and an untraced run
-    otherwise keeps nothing that says which of the host's phases grew."""
-    by = {}
-    for s in spans:
-        phase = s["attrs"].get("phase")
-        by.setdefault(s["name"] + ("[%s]" % phase if phase else ""),
-                      []).append((s["t1"] - s["t0"]) * 1e3)
-    return {k: float("%.4g" % stats.median(v)) for k, v in sorted(by.items())}
-
-
 def run(ctx):
+    # the generator's process first (serve_decode.run says why)
+    gen = loadgen.Generator()
+    try:
+        return _run(ctx, gen)
+    finally:
+        gen.close()
+
+
+def _run(ctx, gen):
     # a program that cannot describe a block fails here, at once
     from paddle_tpu.inference.decode import block_of, save_decode_model
-    from paddle_tpu.obs import tracing
-    from paddle_tpu.serving.server import InferenceServer, ServingClient
+    from paddle_tpu.serving.server import InferenceServer
 
-    cfg, mix = ctx.config, ctx.traffic
+    cfg = ctx.config
     meta = dict(cfg["model"])
     block_of(meta)
     n_slots = int(cfg["deployment"]["decode_slots"])
@@ -359,121 +355,21 @@ def run(ctx):
         ok_ref = check_against_reference(ctx, pred, meta)
         ctx.log(phase="checked", seconds=time.time() - t_phase)
 
-        # ---- the traffic, and a warm-up of its buckets through the wire ---
-        if mix["loop"] == "open":
-            dues = loadgen.due_times(mix, ctx.seed, ctx.seconds)
-            n_req = len(dues)
-        elif mix["loop"] == "closed":
-            clients = int(mix["clients_per_slot"]) * n_slots
-            n_req = int(mix["requests"])
-        else:
-            raise ValueError("serve_decode_arch: unknown loop %r"
-                             % mix["loop"])
-        requests = loadgen.make_requests(mix, ctx.seed, n_req,
-                                         meta["vocab_size"])
-        factory = lambda: ServingClient(srv.endpoint)       # noqa: E731
-        t_phase = time.time()
-        by_bucket = {}
-        for r in requests:
-            by_bucket.setdefault(pred.prompt_bucket(len(r["prompt"])), r)
-        warm = [dict(r, max_new=4) for r in by_bucket.values()] * 2
-        _, wrecs = loadgen.run_open_loop(factory, name, warm,
-                                         [0.0] * len(warm), 120.0)
-        bad_warm = [r.error or r.info for r in wrecs
-                    if not (r.info and r.info.get("done"))]
-        if bad_warm:
-            raise RuntimeError("warm-up stream failed: %r" % bad_warm[:2])
-        ctx.log(phase="warmed", seconds=time.time() - t_phase,
-                buckets=sorted(by_bucket))
-
-        # ---- the measured window ------------------------------------------
-        tracing.clear()
-        win = tracewin.Window(ctx)
-        ctx.memory.start()
-        t0_wall = time.time()
-        if mix["loop"] == "open":
-            t0, recs = loadgen.run_open_loop(factory, name, requests, dues,
-                                             float(mix["drain_s"]))
-            t1 = time.monotonic()
-        else:
-            t0, recs = loadgen.run_closed_loop(factory, name, requests,
-                                               clients, ctx.seconds)
-            t1 = time.monotonic()
-        ctx.memory.stop()
-        win.close()
-        spans = program_spans(t0, t1 + 1.0)
-        ring = tracing.stats()
+        requests, args = plan_traffic(ctx, n_slots, meta["vocab_size"])
+        gen.serve(srv.endpoint)
+        warm_up(ctx, gen, name, requests, pred)
+        w = measure(ctx, gen, name, requests, args)
 
         t_phase = time.time()
-        ok_served = check_served(ctx, recs, requests, pred, meta)
+        ok_served = check_served(ctx, w["recs"], requests, pred, meta)
         scope_ops = step_scope_ops(pred, n_slots, cfg) if ctx.trace else {}
-        ctx.log(phase="after_window", window_to_here_s=time.time() - t0_wall
-                - ctx.seconds, served_check_s=time.time() - t_phase)
+        ctx.log(phase="after_window", window_to_here_s=time.time()
+                - w["t0_wall"] - ctx.seconds,
+                served_check_s=time.time() - t_phase)
     finally:
         t_phase = time.time()
         srv.shutdown(drain=False, timeout=10.0)
         ctx.log(phase="shutdown", seconds=time.time() - t_phase)
         shutil.rmtree(art, ignore_errors=True)
-
-    # ---- the generator's reduction (serve_decode.run's) -------------------
-    eos, S = pred.eos_id, pred.max_seq_len
-    judged = [r for r in recs if not r.cancelled]
-    failed = [r for r in judged if not r.ok(eos, S)]
-    ttft = [(r.token_times[0] - r.due) * 1e3 for r in judged
-            if r.token_times]
-    itl = [(b - a) * 1e3 for r in judged
-           for a, b in zip(r.token_times, r.token_times[1:])]
-    late = [(r.sent - r.due) * 1e3 for r in recs if r.sent is not None]
-    # tokens_per_s: the tokens that reached the clients from the window's
-    # start to the LAST arrival inside it, over that time (serve_decode.py
-    # says why)
-    arrivals = sorted(t for r in recs for t in r.token_times
-                      if t0 <= t <= t0 + ctx.seconds)
-    in_window = len(arrivals)
-    span_s = (arrivals[-1] - t0) if arrivals else ctx.seconds
-    e2e = {"tokens_per_s": in_window / span_s}
-    if ttft:
-        e2e["ttft_p95_ms"] = stats.percentile(ttft, 95)
-    if itl:
-        e2e["itl_p95_ms"] = stats.percentile(itl, 95)
-    ctx.log(phase="window", loop=mix["loop"], requests=len(recs),
-            judged=len(judged), failed=len(failed),
-            cancelled_at_window_end=len(recs) - len(judged),
-            tokens_in_window=in_window, last_arrival_s=span_s,
-            tokens_per_s=e2e["tokens_per_s"],
-            tokens_per_nominal_window_s=in_window / ctx.seconds,
-            kv_reserved_bytes=pred.kv_cache_bytes(n_slots),
-            kv_live_bytes_mid_window=_kv_live_bytes(
-                recs, t0 + ctx.seconds / 2.0, meta),
-            ttft_samples=len(ttft), itl_samples=len(itl),
-            ttft_p50_ms=stats.median(ttft) if ttft else None,
-            ttft_p95_ms=e2e.get("ttft_p95_ms"),
-            itl_p50_ms=stats.median(itl) if itl else None,
-            itl_p95_ms=e2e.get("itl_p95_ms"),
-            gen_late_p95_ms=stats.percentile(late, 95) if late else None,
-            spans=len(spans), spans_dropped=ring["dropped"],
-            span_median_ms=_span_medians(spans),
-            first_failures=[r.error or r.info for r in failed[:3]],
-            threads_left=threading.active_count())
-    result = {"correct": bool(ok_ref and ok_served and not failed
-                              and ring["dropped"] == 0),
-              "attempted": len(recs), "failed": len(failed),
-              "end_to_end": e2e, "window_start_wall": t0_wall,
-              "window_monotonic": (t0, t1)}
-    if ctx.trace:
-        trace, w0, w1 = win.read()
-        result.update(
-            trace=trace, trace_window=(w0, w1), spans=spans,
-            run={"chips": ctx.chips, "slots": n_slots, "window": (t0, t1),
-                 "seconds": ctx.seconds, "records": recs, "meta": meta,
-                 "trace_window": (w0, w1),
-                 "trace_window_monotonic": (win.t_start, win.t_stop),
-                 "device_kind": ctx.devices[0].device_kind,
-                 "kernel_match": cfg.get("kernel_trace_match", {}),
-                 "scope_ops": scope_ops,
-                 "host_spans": [(s["name"], trace.from_monotonic(s["t0"]),
-                                 trace.from_monotonic(s["t1"]))
-                                for s in spans
-                                if s["name"] in ("serving/decode_step",
-                                                 "serving/prefill_compute")]})
-    return result
+    return reduce_window(ctx, w, ok_ref and ok_served, pred, n_slots, meta,
+                         scope_ops=scope_ops)

@@ -138,10 +138,11 @@ def step_phase_ms(spans, window, names):
     return list(by_round.values())
 
 
-def span_medians(spans, window, prefixes=("decode/", "serving/")):
-    """{name, or name@phase: [median ms, count]} of the program's spans of
-    those prefixes that began inside `window` (monotonic): the table an
-    earlier output line carries beside the metrics."""
+def span_table(spans, window, prefixes=("decode/", "serving/")):
+    """{name, or name@phase: (median ms, mean ms, count)} of the program's
+    spans of those prefixes that began inside `window` (monotonic).  The
+    median moves if every span of a kind grew, the mean alone if a few
+    grew much."""
     ms = {}
     for s in spans:
         if s["name"].startswith(prefixes) \
@@ -149,7 +150,15 @@ def span_medians(spans, window, prefixes=("decode/", "serving/")):
             phase = s["attrs"].get("phase")
             key = s["name"] + ("@" + str(phase) if phase else "")
             ms.setdefault(key, []).append((s["t1"] - s["t0"]) * 1e3)
-    return {k: [stats.median(v), len(v)] for k, v in sorted(ms.items())}
+    return {k: (stats.median(v), sum(v) / len(v), len(v))
+            for k, v in sorted(ms.items())}
+
+
+def span_medians(spans, window, prefixes=("decode/", "serving/")):
+    """{name, or name@phase: [median ms, count]}: the table an earlier
+    output line carries beside the metrics."""
+    return {k: [v[0], v[2]]
+            for k, v in span_table(spans, window, prefixes).items()}
 
 
 def lane_sums(spans, window):

@@ -4,9 +4,13 @@ kernel's share of its roofline over the profiled sub-window, in percent:
     least seconds the chip could take for the calls made  /  device seconds
     of the kernel's events in the trace
 
-The calls: one per layer per decode round inside the sub-window; each
-round's per-slot live lengths are rebuilt from the generator's records (a
-stream's length at a round = its prompt + the tokens it had received).
+The calls: one per layer per decode TRIP of every dispatch inside the
+sub-window - a dispatch's `trips` ride its `serving/decode_step` span (a
+span without them is one step), and every live stream is a token longer at
+each trip until its budget ends.  A stream's length at a dispatch is rebuilt
+from the generator's records (its prompt + the tokens it had received).
+Counted once a span, as before PR 34, the share read about 1/`trips` of the
+kernel's.
 Operations and bytes per call: benchmark/costs.py; peaks: benchmark/peaks.py.
 The binding bound is memory (attention over a cache at batch 1 per slot
 reads 2 x 4 bytes per 4 FLOPs), logged beside the value."""
@@ -33,17 +37,19 @@ def read(spans, trace, run):
     for step in sp.named(spans, "serving/decode_step", (m0, m1)):
         if step["t1"] > m1:
             continue
-        lengths = []
+        live = []
         for r in run["records"]:
             tt = r.token_times
             if tt and tt[0] <= step["t0"] and (r.done is None
                                                or r.done >= step["t1"]):
                 have = bisect.bisect_right(tt, step["t0"])
                 if have < r.max_new:
-                    lengths.append(r.prompt_len + have)
-        f, b = costs.decode_attention_cost(lengths, heads, dh)
-        flops += f * int(meta["n_layers"])
-        bytes_ += b * int(meta["n_layers"])
+                    live.append((r.prompt_len + have, r.max_new - have))
+        for trip in range(int(step["attrs"].get("trips") or 1)):
+            f, b = costs.decode_attention_cost(
+                [n + trip for n, left in live if trip < left], heads, dh)
+            flops += f * int(meta["n_layers"])
+            bytes_ += b * int(meta["n_layers"])
     if bytes_ <= 0.0:
         return None
     pk = peaks.peaks_for(run["device_kind"])

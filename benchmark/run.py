@@ -138,6 +138,8 @@ class MemoryWatch(object):
     def __init__(self, devices):
         self.devices = list(devices)
         self.peak, self.samples = 0, 0
+        self.first = self.last = None       # time.monotonic() of readings
+        self.longest_gap_s, self.longest_gap_end = 0.0, None
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._loop, daemon=True)
 
@@ -146,7 +148,30 @@ class MemoryWatch(object):
             st = d.memory_stats() or {}
             self.peak = max(self.peak, int(st.get("bytes_in_use", 0))
                             + int(st.get("bytes_reserved", 0)))
+        now = time.monotonic()
+        if self.last is None:
+            self.first = now
+        elif now - self.last > self.longest_gap_s:
+            self.longest_gap_s, self.longest_gap_end = now - self.last, now
+        self.last = now
         self.samples += 1
+
+    def stall(self, t0):
+        """What says whether the process PAUSED inside the window: the
+        readings taken, those a window of that length takes when nothing
+        holds this thread up (one an interval, and the two at its ends),
+        the longest time between two readings and when it ended, in
+        seconds from the window's start `t0` (time.monotonic()): the
+        generator's process keeps the same of its own
+        (`loadgen.Ticker`).  A low run has so far been a pause of the
+        whole process (122 and 171 readings where a clean 45 s window
+        takes 178-183, PERF.md section 7)."""
+        length = (self.last - self.first) if self.samples > 1 else 0.0
+        return {"readings": self.samples,
+                "readings_if_clean": int(length / self.INTERVAL_S) + 2,
+                "longest_gap_s": self.longest_gap_s,
+                "longest_gap_at_s": (self.longest_gap_end - t0
+                                     if self.longest_gap_end else None)}
 
     def _loop(self):
         while not self._stop.wait(self.INTERVAL_S):
@@ -221,7 +246,8 @@ def main(argv=None):
             first=in_window[:5])
     device = device_record(devices, ctx.memory)
     log(phase="memory", window_peak_bytes=ctx.memory.peak,
-        readings=ctx.memory.samples, lifetime=devices[0].memory_stats())
+        lifetime=devices[0].memory_stats(),
+        **ctx.memory.stall(result["window_monotonic"][0]))
 
     if not args.trace:
         values = dict(result["end_to_end"], setup_s=setup_s)

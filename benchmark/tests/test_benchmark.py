@@ -11,6 +11,8 @@ import shutil
 import statistics
 import subprocess
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -495,6 +497,190 @@ def test_resnet_reference_matches_the_program_loss_and_update():
         # the state was put back every time: the same step gives the same
         # loss again
         assert stepper.step(sample) == pytest.approx(loss_first, abs=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# a run names its own stall
+# ---------------------------------------------------------------------------
+
+class _Chip(object):
+    """A device whose `memory_stats()` takes `held` seconds at its
+    `at`-th reading: what a pause of the process looks like to the
+    memory thread."""
+
+    def __init__(self, at=None, held=0.0):
+        self.calls, self.at, self.held = 0, at, held
+
+    def memory_stats(self):
+        self.calls += 1
+        if self.calls == self.at:
+            time.sleep(self.held)
+        return {"bytes_in_use": 100 + self.calls, "bytes_reserved": 7}
+
+
+@pytest.mark.parametrize("case,chip,paused", [
+    ("clean", _Chip(), False), ("held_for_a_second", _Chip(3, 1.0), True)])
+def test_the_memory_thread_says_whether_the_process_paused(case, chip,
+                                                           paused):
+    watch = bench_run.MemoryWatch([chip])
+    watch.start()
+    time.sleep(2.1)
+    watch.stop()
+    t0 = watch.first
+    said = watch.stall(t0)
+    assert said["readings"] == watch.samples == chip.calls
+    assert watch.peak == 100 + chip.calls + 7
+    if paused:
+        # the third reading began 0.5 s in and was held for a second
+        assert said["longest_gap_s"] >= 1.0
+        assert 1.4 <= said["longest_gap_at_s"] <= 2.0
+        assert said["readings"] <= said["readings_if_clean"] - 3
+    else:
+        assert 0.25 <= said["longest_gap_s"] < 0.5
+        assert abs(said["readings"] - said["readings_if_clean"]) <= 1
+
+
+# ---------------------------------------------------------------------------
+# the generator's process against a tiny served model
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """(generator, server, model name): a two-slot lane of a tiny decode
+    model behind the wire, and ONE generator's process for the module."""
+    from paddle_tpu.inference.decode import build_tiny_decode_model
+    from paddle_tpu.serving.server import InferenceServer
+    art = str(tmp_path_factory.mktemp("loadgen_model") / "lm")
+    build_tiny_decode_model(art, vocab_size=32, d_model=16, n_heads=2,
+                            n_layers=2, max_seq_len=64, eos_id=0, seed=7)
+    gen = loadgen.Generator()
+    srv = InferenceServer("127.0.0.1:0").start()
+    try:
+        srv.registry.load_model("lm", art, decode_slots=2)
+        yield gen.serve(srv.endpoint), srv, "lm"
+    finally:
+        rc = gen.close()
+        srv.shutdown(drain=False, timeout=10.0)
+    assert rc == 0
+
+
+def tiny_requests(n, max_new=6):
+    mix = {"prompt_tokens": {"kind": "uniform", "min": 3, "max": 12},
+           "output_tokens": {"kind": "fixed", "value": max_new}}
+    return loadgen.make_requests(mix, 2 ** 31 + 9, n, 32)
+
+
+def whole(rec, requests):
+    """A record came back as the child wrote it: stamps in order on this
+    process's clock, one stamp a token, the request's own sizes."""
+    req = requests[rec.index % len(requests)]
+    assert rec.prompt_len == len(req["prompt"])
+    assert rec.max_new == req["max_new"]
+    assert len(rec.tokens) == len(rec.token_times)
+    stamps = [rec.sent] + rec.token_times + [rec.done]
+    assert stamps == sorted(stamps)
+    return True
+
+
+def test_closed_loop_runs_in_the_child_and_marks_what_it_cancelled(served):
+    gen, srv, name = served
+    requests = tiny_requests(8)
+    before = time.monotonic()
+    t0, recs = loadgen.run_closed_loop(gen, name, requests, 4, 3.0)
+    after = time.monotonic()
+    # one clock: the child's stamps lie inside this process's bracket
+    assert before <= t0 <= after
+    assert all(before <= r.sent and r.done <= after for r in recs)
+    assert gen.stats["clock_round_trip_ms"] < 1e3
+    assert [r.index for r in recs] == list(range(len(recs)))
+    assert all(whole(r, requests) for r in recs)
+    judged = [r for r in recs if not r.cancelled]
+    assert len(judged) >= 4 and all(r.ok(0, 64) for r in judged)
+    # every stream that was in flight at the window's end is marked, and
+    # nothing else
+    assert all(r.cancelled == (r.done > t0 + 3.0 or len(r.tokens) < 6
+                               and r.tokens[-1:] != [0]) for r in recs)
+    assert any(r.cancelled for r in recs)
+    # the child: another process, pinned to the CPU, no TPU backend made,
+    # its CPU time reported
+    assert gen.stats["pid"] != os.getpid()
+    assert gen.stats["jax_platforms"] == "cpu"
+    assert "tpu" not in gen.stats["jax_backends"]
+    assert gen.stats["cpu_user_s"] + gen.stats["cpu_sys_s"] > 0.0
+    assert gen.stats["threads_left"] == 1
+    # its ticker woke about four times a second, and says when it did not
+    assert 0.25 <= gen.stats["longest_gap_s"] < 1.0
+    assert 0.0 < gen.stats["longest_gap_at_s"] <= after - t0
+
+
+def test_open_loop_runs_in_the_child_on_the_due_times(served):
+    gen, srv, name = served
+    requests = tiny_requests(6)
+    dues = [0.0, 0.1, 0.2, 0.5, 0.6, 0.9]
+    t0, recs = loadgen.run_open_loop(gen, name, requests, dues, 30.0)
+    assert len(recs) == 6 and all(whole(r, requests) for r in recs)
+    assert [round(r.due - t0, 6) for r in recs] == dues
+    assert all(r.sent >= r.due for r in recs)
+    assert all(r.ok(0, 64) and not r.cancelled for r in recs)
+
+
+def test_a_failing_stream_counts_as_failed_not_as_missing(served):
+    gen, srv, name = served
+    requests = tiny_requests(3)
+    _, recs = loadgen.run_open_loop(gen, "no_such_model", requests,
+                                    [0.0] * 3, 30.0)
+    assert len(recs) == 3
+    assert all(r.error and r.done is not None and not r.ok(0, 64)
+               for r in recs)
+    # and the child lives on: the next job is served
+    _, recs = loadgen.run_open_loop(gen, name, requests, [0.0] * 3, 30.0)
+    assert all(r.ok(0, 64) for r in recs)
+
+
+def test_the_generator_is_pinned_to_the_cpu_whatever_the_parent_says(
+        served, monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    gen = loadgen.Generator()
+    try:
+        assert gen.hello()["jax_platforms"] == "cpu"
+        assert gen.hello()["hello"] == gen._proc.pid != os.getpid()
+        with pytest.raises(RuntimeError, match="serve"):
+            gen.run("open", "lm", [], [], 1.0)
+        # the child's death inside a window is an error, never an empty
+        # window: killed one second into a closed loop
+        _, srv, name = served
+        gen.serve(srv.endpoint)
+        threading.Timer(1.0, gen._proc.kill).start()
+        with pytest.raises(loadgen.GeneratorDied, match="exit code -9"):
+            loadgen.run_closed_loop(gen, name, tiny_requests(8), 2, 30.0)
+    finally:
+        gen.close()
+    # a child that fails a job says why, and the parent raises it
+    gen2, _, _ = served
+    with pytest.raises(loadgen.GeneratorDied, match="KeyError"):
+        gen2.run("no_such_loop", "lm", [])
+
+
+def test_a_killed_run_leaves_no_generator_behind():
+    """The driver ends a run at its time limit with SIGKILL; the child has
+    to go with it, whatever loop it is in."""
+    code = ("import sys, time; sys.path.insert(0, %r); "
+            "from benchmark import loadgen; g = loadgen.Generator(); "
+            "print(g.hello()['hello'], flush=True); time.sleep(120)" % ROOT)
+    run = subprocess.Popen([sys.executable, "-c", code],
+                           stdout=subprocess.PIPE, text=True)
+    try:
+        child = int(run.stdout.readline())
+        assert os.path.exists("/proc/%d" % child)
+        run.kill()
+        run.wait(timeout=30)
+        limit = time.monotonic() + 10.0
+        while os.path.exists("/proc/%d" % child) and time.monotonic() < limit:
+            time.sleep(0.1)
+        assert not os.path.exists("/proc/%d" % child)
+    finally:
+        run.kill()
+        run.stdout.close()
 
 
 # ---------------------------------------------------------------------------

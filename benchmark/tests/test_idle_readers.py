@@ -278,6 +278,88 @@ def test_rounds_that_dispatch_twice_are_summed_by_round():
 
 
 # ---------------------------------------------------------------------------
+# the two SHARES that count a dispatch's trips (a span is `trips` decode
+# steps since PR 30; read a span a step, the one passed 100% sevenfold and
+# the other read a seventh)
+# ---------------------------------------------------------------------------
+
+def dispatch(at, **attrs):
+    return span("serving/decode_step", at, at + 0.5, **attrs)
+
+
+@pytest.mark.parametrize("case,steps,want", [
+    ("a_step_a_span", [dispatch(1.0, tokens=4), dispatch(2.0, tokens=3)],
+     100.0 * 7 / (2 * 4)),
+    # a full window of 8 trips, then one whose streams ended a trip early
+    ("dispatches_of_trips", [dispatch(1.0, tokens=32, trips=8),
+                             dispatch(2.0, tokens=26, trips=7)],
+     100.0 * 58 / (15 * 4)),
+    ("with_and_without_the_attribute", [dispatch(1.0, tokens=32, trips=8),
+                                        dispatch(2.0, tokens=4)], 100.0),
+    ("spans_outside_the_window_do_not_count",
+     [dispatch(1.0, tokens=16, trips=8), dispatch(30.0, tokens=32, trips=8)],
+     50.0),
+    ("no_step", [span("serving/lane_iter", 1.0, 2.0)], None)])
+def test_slots_busy_share_counts_a_dispatchs_trips(case, steps, want):
+    got = reader("slots_busy_share")(
+        steps, None, {"window": (MONO, MONO + 20.0), "slots": 4})
+    assert got == (want if want is None else pytest.approx(want, rel=1e-12))
+    assert got is None or got <= 100.0
+
+
+@pytest.mark.parametrize("trips,calls", [
+    # a span without the attribute is one step over the lengths at its start
+    (None, [[13, 33]]),
+    # four trips: every stream a token longer at each, the one with two
+    # tokens of budget left gone after the second
+    (4, [[13, 33], [14, 34], [15], [16]])])
+def test_decode_attention_roofline_counts_every_trip_of_a_dispatch(trips,
+                                                                   calls):
+    from benchmark import costs, peaks
+    heads, dh, layers = 4, 8, 3
+    # the kernel: 2 s of device time inside the profiled 0..20
+    trace = trace_of([("decode_attention_kernel.1", 3.0, 4.0),
+                      ("fusion.2", 4.0, 5.0),
+                      ("decode_attention_kernel.1", 13.0, 14.0)])
+
+    def stream(prompt, max_new, got):
+        return types.SimpleNamespace(
+            prompt_len=prompt, max_new=max_new, done=None,
+            token_times=[MONO + 0.5 + 0.1 * k for k in range(got)])
+    records = [stream(10, 20, 3), stream(30, 5, 3),
+               # ended before the dispatch; not begun at it
+               types.SimpleNamespace(prompt_len=9, max_new=2, done=MONO + 1.0,
+                                     token_times=[MONO + 0.6, MONO + 0.9]),
+               types.SimpleNamespace(prompt_len=9, max_new=2, done=None,
+                                     token_times=[MONO + 9.0])]
+    attrs = {} if trips is None else {"trips": trips}
+    spans = [span("serving/decode_step", 2.0, 8.0, tokens=8, **attrs),
+             span("serving/decode_step", 19.0, 21.0, tokens=8, trips=8)]
+    run = {"kernel_match": {"decode_attention": "decode_attention_kernel"},
+           "trace_window": (0.0, 20.0),
+           "trace_window_monotonic": (MONO, MONO + 20.0),
+           "meta": {"n_heads": heads, "d_model": heads * dh,
+                    "n_layers": layers},
+           "records": records, "device_kind": "TPU v5 lite"}
+    flops = bytes_ = 0.0
+    for lengths in calls:
+        f, b = costs.decode_attention_cost(lengths, heads, dh)
+        flops, bytes_ = flops + f * layers, bytes_ + b * layers
+    pk = peaks.peaks_for("TPU v5 lite")
+    least, bound = costs.roofline_seconds(
+        flops, bytes_, pk["flops_per_s"]["float32_default_precision"],
+        pk["hbm_bytes_per_s"])
+    assert bound == "memory"
+    got = reader("decode_attention_roofline")(spans, trace, run)
+    assert got == pytest.approx(100.0 * least / 2.0, rel=1e-12)
+    # and nothing to read is nothing, never 0
+    assert reader("decode_attention_roofline")(
+        spans, trace_of([("fusion.2", 4.0, 5.0)]), run) is None
+    assert reader("decode_attention_roofline")(
+        spans, trace, dict(run, kernel_match={})) is None
+
+
+# ---------------------------------------------------------------------------
 # the new cell end to end at tiny size, off the chip
 # ---------------------------------------------------------------------------
 

@@ -71,12 +71,24 @@ DEQUANT = dict(M=256, K=2048, N=8192)
 # 64, padded to (8, 128)) and cache length, 8 slots of the cell's 32
 DECODE_GQA = (8, 4096, 32, 8, 128)
 
+# a latent slot table at openPangu-Ultra-MoE's published row: 128 absorbed
+# query heads over ONE row a position of 512 + 64 = 576 values, held as 640
+# lanes (decode.latent_row), the first 512 of them the values; 8 slots of the
+# cell's 64
+DECODE_LATENT = (8, 4096, 128, 576, 512)
+
 # Stated tolerances.
 # serve, the kernel alone: decode_attention (fp32 VPU math, online softmax
 # over blocks of 128) vs its reference at full fp32 matmul precision on
 # random O(1) inputs at the served geometry — same math, another summation
 # order over <= 1024 positions.
 TOL_DECODE_KERNEL = 5e-5
+# latent_decode_attention (both contractions on the MXU, operands rounded to
+# bfloat16 as the default precision rounds every matmul's, fp32 accumulation
+# and softmax) vs its reference at full fp32 precision ON THE SAME
+# BF16-ROUNDED q and rows: what is left is the rounding of the probabilities
+# (2^-9 relative) and the summation order, on outputs of O(1)
+TOL_LATENT_KERNEL = 1e-2
 # serve, the whole model: fp32 logits (std ~1) of the Mosaic-kernel step vs
 # the reference-attention step on the same prefix.  The attention outputs
 # agree to TOL_DECODE_KERNEL, but every other matmul of BOTH programs runs at
@@ -366,45 +378,29 @@ def teacher_forced_logits(pred, prompt_list, served, n_slots):
     return firsts, out
 
 
-def check_decode_kernel(geometry, kv_dtype, seed):
-    """decode_attention compiled for the chip, alone, over a slot table of
-    `geometry` = (slots, S, query heads, K/V heads, head size) and cache
-    dtype `kv_dtype`, at SHORT lengths (all inside a slot's first block),
-    MIXED ones (on and around the kernel's block edges, 1 and S among them)
-    and FULL rows: against its reference (the max abs error is returned) and,
-    bit for bit, against the same kernel made to stream whole rows, which is
-    what it did before its stream stopped at a slot's length
-    (`pk.kv_last_block` answering "the last block" for every length; the
-    mask follows the true lengths).  Also returns the microseconds a call
-    of each, by length profile."""
+def check_bounded_stream(call, ref, operands, n_slots, S, bkv, what, tol):
+    """A decode kernel whose stream stops at a slot's length, compiled for
+    the chip, alone: `call(*operands, lengths)` at SHORT lengths (all inside
+    a slot's first block), MIXED ones (on and around the kernel's block
+    edges, 1 and S among them) and FULL rows: against `ref` (the max abs
+    error is returned, held under `tol`) and, bit for bit, against the same
+    kernel made to stream whole rows, which is what `decode_attention` did
+    before its stream stopped at a slot's length (`pk.kv_last_block`
+    answering "the last block" for every length; the mask follows the true
+    lengths).  Also returns the microseconds a call of each, by length
+    profile."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops import attention_tuning
     from paddle_tpu.ops import pallas_kernels as pk
-    n_slots, S, H, Hc, Dh = geometry
-    bkv = attention_tuning.get_decode_config(S, Dh, kv_dtype)
-    kq, kk, kv_, ks = jax.random.split(jax.random.PRNGKey(seed + 2), 4)
-    q = jax.random.normal(kq, (n_slots, H, Dh), jnp.float32)
     edges = sorted({1, 2, bkv - 1, bkv, bkv + 1, S // 2, S - 1, S})
     profiles = {"short": [1 + (7 * i) % bkv for i in range(n_slots)],
                 "mixed": (edges * n_slots)[:n_slots],
                 "full": [S] * n_slots}
-    if kv_dtype == "int8":
-        k = jax.random.randint(kk, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
-        v = jax.random.randint(kv_, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
-        scales = jax.random.uniform(ks, (2, H), jnp.float32, 0.5, 1.5) / 127
-    else:
-        k = jax.random.normal(kk, (n_slots, S, Hc, Dh), jnp.float32)
-        v = jax.random.normal(kv_, (n_slots, S, Hc, Dh), jnp.float32)
-        scales = None
     lengths = jnp.asarray(profiles["mixed"], jnp.int32)
 
     def compiled():
-        kernel = jax.jit(lambda q, k, v, n: pk.decode_attention(
-            q, k, v, n, kv_scales=scales,
-            interpret=REQUIRED_PLATFORM != "tpu")).lower(
-                q, k, v, lengths).compile()
-        require_mosaic(kernel.as_text(), 1, "decode_attention")
+        kernel = jax.jit(call).lower(*operands, lengths).compile()
+        require_mosaic(kernel.as_text(), 1, what)
         return kernel
 
     bounded = compiled()
@@ -415,16 +411,11 @@ def check_decode_kernel(geometry, kv_dtype, seed):
     finally:
         pk.kv_last_block = rule
 
-    def ref(q, k, v, n):
-        with jax.default_matmul_precision("highest"):
-            return pk.decode_attention_reference(q, k, v, n,
-                                                 kv_scales=scales)
-
     def timed(kernel, n):
-        jax.block_until_ready(kernel(q, k, v, n))
+        jax.block_until_ready(kernel(*operands, n))
         t0 = time.perf_counter()
         for _ in range(20):
-            out = kernel(q, k, v, n)
+            out = kernel(*operands, n)
         jax.block_until_ready(out)
         return out, round((time.perf_counter() - t0) / 20 * 1e6, 1)
 
@@ -434,13 +425,76 @@ def check_decode_kernel(geometry, kv_dtype, seed):
         got, us[name] = timed(bounded, n)
         want, us[name + "_whole_rows"] = timed(whole, n)
         require(bool(jnp.array_equal(got, want)),
-                "decode_attention (%s cache, %s lengths) differs from the "
-                "whole-row stream by %.3g" % (
-                    kv_dtype, name, float(jnp.max(jnp.abs(got - want)))))
-        err = max(err, float(jnp.max(jnp.abs(got - ref(q, k, v, n)))))
-    require(err <= TOL_DECODE_KERNEL, "decode_attention (%s cache) is %.3g "
-            "from its reference" % (kv_dtype, err))
+                "%s (%s lengths) differs from the whole-row stream by %.3g"
+                % (what, name, float(jnp.max(jnp.abs(got - want)))))
+        err = max(err, float(jnp.max(jnp.abs(got - ref(*operands, n)))))
+    require(err <= tol, "%s is %.3g from its reference" % (what, err))
     return err, us
+
+
+def check_decode_kernel(geometry, kv_dtype, seed):
+    """decode_attention over a slot table of `geometry` = (slots, S, query
+    heads, K/V heads, head size) and cache dtype `kv_dtype`
+    (`check_bounded_stream`)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_tuning
+    from paddle_tpu.ops import pallas_kernels as pk
+    n_slots, S, H, Hc, Dh = geometry
+    bkv = attention_tuning.get_decode_config(S, Dh, kv_dtype)
+    kq, kk, kv_, ks = jax.random.split(jax.random.PRNGKey(seed + 2), 4)
+    q = jax.random.normal(kq, (n_slots, H, Dh), jnp.float32)
+    if kv_dtype == "int8":
+        k = jax.random.randint(kk, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
+        v = jax.random.randint(kv_, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
+        scales = jax.random.uniform(ks, (2, H), jnp.float32, 0.5, 1.5) / 127
+    else:
+        k = jax.random.normal(kk, (n_slots, S, Hc, Dh), jnp.float32)
+        v = jax.random.normal(kv_, (n_slots, S, Hc, Dh), jnp.float32)
+        scales = None
+
+    def ref(q, k, v, n):
+        with jax.default_matmul_precision("highest"):
+            return pk.decode_attention_reference(q, k, v, n,
+                                                 kv_scales=scales)
+
+    return check_bounded_stream(
+        lambda q, k, v, n: pk.decode_attention(
+            q, k, v, n, kv_scales=scales,
+            interpret=REQUIRED_PLATFORM != "tpu"),
+        ref, (q, k, v), n_slots, S, bkv,
+        "decode_attention (%s cache)" % kv_dtype, TOL_DECODE_KERNEL)
+
+
+def check_latent_kernel(geometry, seed):
+    """latent_decode_attention over a latent slot table of `geometry` =
+    (slots, S, heads, row values, value lanes), its rows padded to the
+    tile's lanes as one TPU device holds them (`check_bounded_stream`)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_tuning
+    from paddle_tpu.ops import pallas_kernels as pk
+    n_slots, S, H, R, V = geometry
+    Rp = -(-R // 128) * 128
+    scale = 1.0 / np.sqrt(192.0)
+    bkv = attention_tuning.get_decode_config(S, Rp, "float32")
+    kq, kt = jax.random.split(jax.random.PRNGKey(seed + 3))
+    lanes = jnp.arange(Rp) < R
+
+    def drawn(key, shape):      # bf16 numbers; the pad lanes exact zeros
+        x = jax.random.normal(key, shape + (Rp,), jnp.float32)
+        return jnp.where(lanes, x, 0.0).astype(jnp.bfloat16).astype(
+            jnp.float32)
+
+    def ref(q, t, n):
+        with jax.default_matmul_precision("highest"):
+            return pk.latent_decode_attention_reference(q, t, n, V, scale)
+
+    return check_bounded_stream(
+        lambda q, t, n: pk.latent_decode_attention(
+            q, t, n, V, scale, interpret=REQUIRED_PLATFORM != "tpu"),
+        ref, (drawn(kq, (n_slots, H)), drawn(kt, (n_slots, S))), n_slots, S,
+        bkv, "latent_decode_attention", TOL_LATENT_KERNEL)
 
 
 def check_window(pred, n_slots, prompt_list, what):
@@ -773,6 +827,14 @@ def phase_kernels(seed, devs):
          shape=dict(zip(("slots", "S", "heads", "kv_heads", "D"),
                         DECODE_GQA)), dtype="float32",
          max_abs_err=float("%.3g" % err), tol=TOL_DECODE_KERNEL,
+         equals_whole_row_stream=True, call_us=us,
+         peak_bytes_in_use=peak_bytes(devs[0]))
+
+    err, us = check_latent_kernel(DECODE_LATENT, seed)
+    emit("kernels", kernel="latent_decode_attention",
+         shape=dict(zip(("slots", "S", "heads", "row", "values"),
+                        DECODE_LATENT)), dtype="float32",
+         max_abs_err=float("%.3g" % err), tol=TOL_LATENT_KERNEL,
          equals_whole_row_stream=True, call_us=us,
          peak_bytes_in_use=peak_bytes(devs[0]))
 

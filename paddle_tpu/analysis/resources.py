@@ -828,7 +828,8 @@ def _decode_report(path, meta, decode_slots, device, what,
     (int8 slots + the per-(layer,head) fp32 scale table)."""
     import numpy as np
     from ..flags import FLAGS
-    from ..inference.decode import normalize_kv_dtype, slot_state_shapes
+    from ..inference.decode import (layer_kinds, normalize_kv_dtype,
+                                    slot_state_shapes)
     n_slots = int(decode_slots or FLAGS.serving_decode_slots)
     L = int(meta["n_layers"])
     H = int(meta["n_heads"])
@@ -862,7 +863,10 @@ def _decode_report(path, meta, decode_slots, device, what,
     kv_elem = 1 if kv_dtype == "int8" else 4
     kv_scales = 2 * L * H * 4 if kv_dtype == "int8" else 0
     kv_shape, conv_shape = slot_state_shapes(meta, n_slots, device)
-    rep.kv_cache_bytes = 2 * int(np.prod(kv_shape)) * kv_elem + kv_scales
+    # (an MLA stack: ONE latent table [layers, n_slots, S, Rp], no V)
+    n_tables = 1 if layer_kinds(meta)[0][0] == "mla" else 2
+    rep.kv_cache_bytes = (n_tables * int(np.prod(kv_shape)) * kv_elem
+                          + kv_scales)
     # decode-step working set: one token's activations per slot, and the
     # conv layers' carried state (K-1 inputs a slot and layer, fp32)
     rep.activation_peak_bytes = n_slots * D * 4 * (L + 2) + (

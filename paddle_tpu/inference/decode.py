@@ -15,11 +15,14 @@ TOGETHER.  This module is the inference-side half of the answer
   AOT predictor is detected by `aot_meta.bin`.  The meta also DESCRIBES
   the decoder stack (`BLOCK_DEFAULTS`: LayerNorm | RMSNorm, learned |
   rotary positions, qk-norm over the projection or per head, multi-head
-  | grouped-query attention, a layer's operator attention | a gated
-  short convolution (`layer_types`), leading dense SwiGLU layers, ReLU
-  MLP | dropless routed SwiGLU experts under a softmax or a sigmoid,
-  bias-corrected router, a head of its own | tied); an artifact that
-  names none of those keys is the GPT-2-shaped block in every layer.
+  | grouped-query | latent attention, a layer's operator attention | a
+  gated short convolution (`layer_types`), leading dense SwiGLU layers,
+  ReLU MLP | dropless routed SwiGLU experts under a softmax or a sigmoid
+  router, all of them or the run of them a member of an expert-parallel
+  deployment holds, a shared expert beside them, a norm after each
+  sublayer too, a head of its own | tied, matmul weights float32 |
+  bfloat16 at rest); an artifact that names none of those keys is the
+  GPT-2-shaped block in every layer.
   A PHASE is: embed the tokens at their positions, run ONE per-layer
   function (`GenerativePredictor._block`) once a layer with the phase's
   own `attend(q, k, v)` and `convolve(z, taps)`, apply the head.
@@ -33,7 +36,8 @@ TOGETHER.  This module is the inference-side half of the answer
   cannot hold is refused by a typed error that names the meta key (the
   tensor-parallel lane any block but the default: its grammar has no
   rule for sharding experts; a mesh, a rollback, the speculative
-  phases and an int8 cache a stack with conv layers);
+  phases and an int8 cache a stack with conv layers; a mesh, the
+  speculative phases and an int8 cache a stack of latent attention);
 * a **prefill / decode phase split** (`GenerativePredictor`): prefill
   runs the whole prompt through the causal forward once per padded
   *prompt bucket* (each bucket's executable rides the persistent
@@ -47,7 +51,12 @@ TOGETHER.  This module is the inference-side half of the answer
   `slot_state_shapes`): the KV cache, [attention layers, n_slots,
   max_seq_len, K/V heads, head_dim] arrays, and for a stack with conv
   layers their conv state, [conv layers, n_slots, taps - 1, d_model],
-  a fixed size a slot; resident on the session's
+  a fixed size a slot; a stack of latent attention holds, in the K/V
+  tables' place and ONCE, [mla layers, n_slots, max_seq_len, row]: one
+  row of kv_lora_rank + qk_rope_head_dim values a position, which its
+  prefill expands to per-head keys and values and its decode step
+  attends over as it is (`_mla_expanded`, `_mla_absorbed`); resident on
+  the session's
   device, ONE buffer each (K, V, conv state) that every write updates in
   place (a step's rows, an admission, a release: each call is given
   the table donated and the session keeps the result; SERVING.md "The
@@ -253,16 +262,48 @@ BLOCK_DEFAULTS = (
     ("dense_width", 0),           # SwiGLU of this width, the rest are `ffn`
     ("router", "softmax"),        # | "sigmoid_bias": sigmoid scores, top-k
                                   # of score + a per-expert bias, weights
-                                  # from the unbiased scores
+                                  # from the unbiased scores | "sigmoid":
+                                  # sigmoid scores, top-k of the scores
     ("head", "untied"),           # | "tied": logits = norm(x) @ embed.T
+    # layer_types "mla": latent attention.  The slot state is ONE row a
+    # position, [kv_lora_rank | qk_rope_head_dim] (the normed latent and
+    # the one rotated key every head shares); query heads are
+    # qk_nope_head_dim + qk_rope_head_dim wide (rotary over the rope lanes
+    # alone), value heads v_head_dim, the query goes through a latent of
+    # q_lora_rank.  A prefill EXPANDS the rows to per-head K and V, a
+    # decode step ABSORBS the up-projection into the query and the output
+    # and attends over the rows themselves (`_mla_expanded`, `_mla_absorbed`)
+    ("q_lora_rank", 0),
+    ("kv_lora_rank", 0),
+    ("qk_nope_head_dim", 0),
+    ("qk_rope_head_dim", 0),
+    ("v_head_dim", 0),
+    ("sandwich_norm", False),     # a norm AFTER each sublayer too, before
+                                  # its result joins the residual stream
+    ("routed_scaling", 1.0),      # the kept router weights times this
+    ("n_shared_experts", 0),      # SwiGLU experts every token takes, beside
+                                  # the routed ones (one matrix set of
+                                  # n_shared_experts * expert_width)
+    # the chip's share of the experts (expert parallelism, one member of
+    # it): () = all n_experts live here; (first, count) = the contiguous
+    # run first .. first + count - 1 does, w_gate / w_up / w_down are
+    # [count, ..], the router keeps its n_experts outputs and its top-k,
+    # and a pair routed to an expert held elsewhere adds nothing here
+    ("experts_held", ()),
+    ("weight_dtype", "float32"),  # | "bfloat16": the matmul weights at rest
+                                  # (`_bf16_at_rest`, `_contract`); gains,
+                                  # the router and every activation fp32
 )
 _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "position": ("learned", "rope"),
                   "qk_norm": (False, True, "head"),
                   "ffn": ("relu_mlp", "moe_swiglu"),
-                  "router": ("softmax", "sigmoid_bias"),
-                  "head": ("untied", "tied")}
-_LAYER_TYPES = ("attention", "conv")
+                  "router": ("softmax", "sigmoid_bias", "sigmoid"),
+                  "head": ("untied", "tied"),
+                  "weight_dtype": ("float32", "bfloat16")}
+_LAYER_TYPES = ("attention", "conv", "mla")
+_MLA_DIMS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+             "qk_rope_head_dim", "v_head_dim")
 
 
 def block_of(meta):
@@ -277,6 +318,8 @@ def block_of(meta):
             v = bool(v) if isinstance(v, (bool, int)) else v
         elif key == "layer_types":
             v = tuple(str(t) for t in v)
+        elif key == "experts_held":
+            v = tuple(int(t) for t in v)
         else:
             v = type(default)(v)
         if key in _BLOCK_CHOICES and v not in _BLOCK_CHOICES[key]:
@@ -293,18 +336,39 @@ def block_of(meta):
             % (out["experts_per_token"], out["n_experts"],
                out["expert_width"]))
     n_layers, n_heads = int(meta["n_layers"]), int(meta["n_heads"])
-    if out["position"] == "rope" and (
+    kinds = out["layer_types"]
+    if out["position"] == "rope" and "mla" not in kinds and (
             int(meta["d_model"]) // n_heads) % 2:
         raise ValueError("decode meta position=rope needs an even "
                          "head size")
-    kinds = out["layer_types"]
     if kinds and (len(kinds) != n_layers
                   or any(t not in _LAYER_TYPES for t in kinds)
-                  or "attention" not in kinds):
+                  or not {"attention", "mla"} & set(kinds)):
         raise ValueError(
             "decode meta layer_types=%r needs one of %s for each of the %d "
             "layers, and an attention layer among them"
             % (list(kinds), "|".join(_LAYER_TYPES), n_layers))
+    if "mla" in kinds:
+        if set(kinds) != {"mla"}:
+            raise ValueError(
+                "decode meta layer_types=%r mixes mla with other layers: a "
+                "session holds a latent table or K/V tables, not both"
+                % (list(kinds),))
+        for key in _MLA_DIMS:
+            if out[key] < 1:
+                raise ValueError("decode meta %s=%d: an mla layer needs it "
+                                 ">= 1" % (key, out[key]))
+        if out["qk_rope_head_dim"] % 2 or out["position"] != "rope":
+            raise ValueError(
+                "decode meta qk_rope_head_dim=%d, position=%r: an mla "
+                "layer turns an even number of rope lanes (position=rope)"
+                % (out["qk_rope_head_dim"], out["position"]))
+        for key in ("qk_norm", "n_kv_heads"):
+            if out[key]:
+                raise ValueError(
+                    "decode meta %s=%r does not go with layer_types mla "
+                    "(its one shared row has no heads to norm or group)"
+                    % (key, out[key]))
     if "conv" in kinds and out["conv_kernel"] < 2:
         raise ValueError("decode meta conv_kernel=%d: a conv layer needs "
                          "at least 2 taps" % out["conv_kernel"])
@@ -321,13 +385,29 @@ def block_of(meta):
     if out["router"] != "softmax" and out["ffn"] != "moe_swiglu":
         raise ValueError("decode meta router=%r goes with ffn=moe_swiglu"
                          % out["router"])
+    for key in ("n_shared_experts", "experts_held", "sandwich_norm"):
+        if out[key] and out["ffn"] != "moe_swiglu":
+            raise ValueError("decode meta %s=%r goes with ffn=moe_swiglu"
+                             % (key, out[key]))
+    if out["routed_scaling"] != 1.0 and out["router"] != "sigmoid":
+        raise ValueError("decode meta routed_scaling=%r goes with "
+                         "router=sigmoid" % out["routed_scaling"])
+    held = out["experts_held"]
+    if held and (len(held) != 2 or held[0] < 0 or held[1] < 1
+                 or held[0] + held[1] > out["n_experts"]):
+        raise ValueError(
+            "decode meta experts_held=%r is not (first, count) of a run "
+            "inside the %d experts" % (list(held), out["n_experts"]))
+    if out["n_shared_experts"] < 0:
+        raise ValueError("decode meta n_shared_experts=%d"
+                         % out["n_shared_experts"])
     return out
 
 
 def layer_kinds(meta, blk=None):
     """(operator, FFN) of every layer of the stack `meta` describes
     (`blk`: its `block_of`, where the caller has it): operator
-    "attention" | "conv", FFN "dense_swiglu" (the first
+    "attention" | "conv" | "mla", FFN "dense_swiglu" (the first
     `n_dense_layers`) or the meta's `ffn`."""
     blk = blk or block_of(meta)
     n = int(meta["n_layers"])
@@ -337,7 +417,7 @@ def layer_kinds(meta, blk=None):
 
 
 def slot_state_shapes(meta, n_slots, device):
-    """The two kinds of state a slot of an `n_slots` session on `device`
+    """The kinds of state a slot of an `n_slots` session on `device`
     holds, as (K/V table shape, conv-state table shape or None):
 
       * [attention layers, N, S, Hp, Dp]: a K (or V) row for every cached
@@ -345,9 +425,15 @@ def slot_state_shapes(meta, n_slots, device):
         (Hp, Dp) is `table_row` of the K/V heads;
       * [conv layers, N, conv_kernel - 1, D]: the last inputs of every
         CONV layer's filter, a fixed size whatever the slot's length;
-        None for a stack with no conv layer."""
+        None for a stack with no conv layer;
+      * for a stack of MLA layers, in the first place and held ONCE (no V
+        table): [mla layers, N, S, Rp], the latent row of every cached
+        position, `latent_row` lanes wide."""
     blk = block_of(meta)
     ops = [op for op, _ in layer_kinds(meta, blk)]
+    if "mla" in ops:
+        return (len(ops), int(n_slots), int(meta["max_seq_len"]),
+                latent_row(blk, device)), None
     H, D = int(meta["n_heads"]), int(meta["d_model"])
     row = table_row(blk["n_kv_heads"] or H, D // H, device)
     kv = (ops.count("attention"), int(n_slots),
@@ -366,6 +452,8 @@ def decode_state_shapes(meta):
     Dh = D // H
     kv_width = (blk["n_kv_heads"] or H) * Dh
     norm_bias = blk["norm"] == "layernorm"
+    norms = ("ln1", "ln2") + (("ln1p", "ln2p") if blk["sandwich_norm"]
+                              else ())
     shapes = {"embed": (V, D), "lnf_g": (D,)}
     if blk["head"] == "untied":
         shapes["lm_head"] = (D, V)
@@ -375,11 +463,18 @@ def decode_state_shapes(meta):
         shapes["pos"] = (S, D)
     for i, (op, ffn) in enumerate(layer_kinds(meta, blk)):
         p = "l%d_" % i
-        for n in ("ln1", "ln2"):
+        for n in norms:
             shapes[p + n + "_g"] = (D,)
             if norm_bias:
                 shapes[p + n + "_b"] = (D,)
-        if op == "conv":
+        if op == "mla":
+            rq, rkv, dn, dr, dv = (blk[k] for k in _MLA_DIMS)
+            shapes[p + "wq_a"], shapes[p + "q_a_g"] = (D, rq), (rq,)
+            shapes[p + "wq_b"] = (rq, H * (dn + dr))
+            shapes[p + "wkv_a"], shapes[p + "kv_a_g"] = (D, rkv + dr), (rkv,)
+            shapes[p + "wkv_b"] = (rkv, H * (dn + dv))
+            shapes[p + "wo"] = (H * dv, D)
+        elif op == "conv":
             shapes[p + "conv_in"] = (D, 3 * D)
             shapes[p + "conv_w"] = (D, blk["conv_kernel"])
             shapes[p + "conv_out"] = (D, D)
@@ -399,12 +494,26 @@ def decode_state_shapes(meta):
             shapes[p + "router"] = (D, E)
             if blk["router"] == "sigmoid_bias":
                 shapes[p + "expert_bias"] = (E,)
+            if blk["experts_held"]:
+                E = blk["experts_held"][1]
             shapes[p + "w_gate"] = shapes[p + "w_up"] = (E, D, F)
             shapes[p + "w_down"] = (E, F, D)
+            if blk["n_shared_experts"]:
+                Fs = blk["n_shared_experts"] * F
+                shapes[p + "shared_gate"] = shapes[p + "shared_up"] = (D, Fs)
+                shapes[p + "shared_down"] = (Fs, D)
         else:
             shapes[p + "w1"], shapes[p + "b1"] = (D, 4 * D), (4 * D,)
             shapes[p + "w2"], shapes[p + "b2"] = (4 * D, D), (D,)
     return shapes
+
+
+def _bf16_at_rest(name, value):
+    """Under meta weight_dtype=bfloat16: whether the weight `name` is kept
+    in bfloat16 at rest.  The matmul weights are (attention, dense, shared
+    and routed FFN, embedding, head): every matrix but a router's, which
+    is read at "highest" precision (`moe_ffn`); gains and biases are not."""
+    return np.ndim(value) >= 2 and not name.endswith("_router")
 
 
 def save_decode_model(dirname, state, meta):
@@ -414,7 +523,10 @@ def save_decode_model(dirname, state, meta):
     is the GPT-2-shaped block) + `state` (the weight dict) in the typed
     wire format — no pickle, same discipline as save_aot.  A state that
     lacks a weight the described block reads, or holds one of another
-    shape, is refused here and not at the first trace."""
+    shape, is refused here and not at the first trace.  Under meta
+    weight_dtype=bfloat16 the matmul weights are written, and held by the
+    predictor that opens them, in bfloat16 (`_bf16_at_rest`; a state that
+    brings them in bfloat16 is written as it is)."""
     from paddle_tpu.native import wire
     os.makedirs(dirname, exist_ok=True)
     meta = dict(meta)
@@ -436,8 +548,14 @@ def save_decode_model(dirname, state, meta):
             meta["kv_cache_dtype"])
     meta.setdefault("prefill_buckets",
                     _default_prefill_buckets(meta["max_seq_len"]))
+    state = {n: np.asarray(v) for n, v in state.items()}
+    if block_of(meta)["weight_dtype"] == "bfloat16":
+        import ml_dtypes
+        state = {n: v.astype(ml_dtypes.bfloat16, copy=False)
+                 if _bf16_at_rest(n, v)
+                 else v for n, v in state.items()}
     with open(os.path.join(dirname, _DECODE_STATE), "wb") as f:
-        f.write(wire.encode({n: np.asarray(v) for n, v in state.items()}))
+        f.write(wire.encode(state))
     with open(os.path.join(dirname, DECODE_META), "wb") as f:
         f.write(wire.encode(meta))
     return dirname
@@ -545,8 +663,43 @@ def _rope(x, positions, theta):
                            axis=-1)
 
 
+def _contract(x, w, contract):
+    """`contract(x, w, **how)` for a weight `w` at rest in float32 or in
+    bfloat16 (meta weight_dtype), result float32.  A float32 weight is
+    contracted as it always was (`how` empty).  A bfloat16 weight on the
+    TPU takes the activation rounded to bfloat16 and accumulates in
+    float32: the very numbers the default precision gives a float32 copy
+    of that weight (it rounds both operands to bf16), from half the bytes
+    and with no float32 copy of the weight anywhere.  Off the TPU, where a
+    float32 contraction rounds nothing, the weight is widened instead, so
+    that there too bf16 storage computes what fp32 storage of the same
+    values computes, bit for bit."""
+    import jax.numpy as jnp
+    if w.dtype != jnp.bfloat16:
+        return contract(x, w)
+    from paddle_tpu.ops.pallas_kernels import lowering_for_tpu
+    if lowering_for_tpu():
+        return contract(x.astype(jnp.bfloat16), w,
+                        preferred_element_type=jnp.float32)
+    return contract(x, w.astype(jnp.float32))
+
+
+def _mm(x, w):
+    """x @ w, float32, for a weight at rest in either dtype
+    (`_contract`)."""
+    import jax.numpy as jnp
+    return _contract(x, w, jnp.matmul)
+
+
+def _swiglu(h, gate, up, down):
+    """(silu(h gate) * (h up)) down: a dense SwiGLU FFN."""
+    import jax
+    return _mm(jax.nn.silu(_mm(h, gate)) * _mm(h, up), down)
+
+
 def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
-            live=None, expert_bias=None, picks=None):
+            live=None, expert_bias=None, picks=None, sigmoid=False,
+            scaling=1.0, held=None):
     """Dropless, exact top-k routed SwiGLU experts: h [T, D], router
     [D, E], w_gate / w_up [E, D, F], w_down [E, F, D] ->
     (sum over each token's k experts of p_e * ((silu(h @ w_gate[e]) *
@@ -569,9 +722,20 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     With `expert_bias` [E] (meta router=sigmoid_bias) the scores are
     sigmoids, the k experts are those of the largest score + bias, their
     weights the UNBIASED scores, renormalised over (their sum + 1e-6).
+    With `sigmoid` (meta router=sigmoid) the scores are sigmoids, the k
+    experts those of the largest scores, renormalised over (their sum +
+    1e-20) and multiplied by `scaling`.
 
-    facts = (experts that received a token, most tokens one expert
-    received), counted over the tokens `live` [T] marks (all if None):
+    `held` = (first, count) (meta experts_held): w_gate / w_up / w_down
+    hold the experts first .. first + count - 1 of the router's E and no
+    others.  The router and its top-k are those of all E; a pair routed to
+    an expert held elsewhere leaves BEFORE the sort (it joins no group of
+    the grouped matmuls, which run over the head of the sorted pairs,
+    where those that stay lie) and adds nothing to its token's sum: the
+    result is this member's PART of the layer's.
+
+    facts = (experts HELD HERE that received a token, most tokens one of
+    them received), counted over the tokens `live` [T] marks (all if None):
     a dead slot's or a pad position's row is computed but not counted.
     A list given as `picks` receives the chosen experts [T, k] i32 (at
     trace time): what a comparison with a reference needs to tell the
@@ -583,7 +747,12 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
         with jax.named_scope("moe_router"):
             logits = jnp.dot(h.astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
-            if expert_bias is None:
+            if sigmoid:
+                w, idx = jax.lax.top_k(jax.nn.sigmoid(logits), k)
+                if norm_topk_prob:
+                    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+                w = w * scaling
+            elif expert_bias is None:
                 w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
                 if norm_topk_prob:
                     w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -596,6 +765,14 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
         if picks is not None:
             picks.append(idx.astype(jnp.int32))
         flat = idx.reshape(T * k)
+        if held is not None:
+            # experts by their place HERE; a pair whose expert lives
+            # elsewhere gets the place past the last: sorted behind every
+            # group, a member of none, and weighted 0
+            first, E = held
+            here = (idx >= first) & (idx < first + E)
+            flat = jnp.where(here, idx - first, E).reshape(T * k)
+            w = jnp.where(here, w, 0.0)
         onehot = flat[:, None] == jnp.arange(E)[None]           # [T*k, E]
         sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)        # [E]
         counted = sizes if live is None else jnp.sum(
@@ -604,10 +781,37 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
         facts = jnp.stack([jnp.sum(counted > 0, dtype=jnp.int32),
                            jnp.max(counted)])
         order = jnp.argsort(flat)           # stable: pairs by expert
-        rows = h[order // k]                                    # [T*k, D]
-        act = jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes)) \
-            * jax.lax.ragged_dot(rows, w_up, sizes)
-        out = jax.lax.ragged_dot(act, w_down, sizes)            # [T*k, D]
+
+        def grouped(x, w):
+            return _contract(x, w, functools.partial(
+                jax.lax.ragged_dot, group_sizes=sizes))
+
+        def experts(m=None):
+            # the sorted pairs' rows (the first m of them; all if None)
+            # through their experts
+            at = order // k
+            rows = h[at if m is None else at[:m]]               # [m, D]
+            act = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+            return grouped(act, w_down)                         # [m, D]
+        if held is None:
+            out = experts()                                     # [T*k, D]
+        else:
+            # the pairs that stay sort FIRST, and they are about count / E
+            # of the T * k: the grouped matmuls run over the first `cap`
+            # sorted rows, four times that share (a grouped-matmul kernel
+            # computes whole row tiles, so a dead row behind the groups is
+            # not free), and over all T * k only in a dispatch whose
+            # routing crowds more than `cap` pairs onto this member:
+            # dropless and exact either way
+            stay, pairs = jnp.sum(sizes), T * k
+            cap = min(pairs, max(64, 4 * -(-pairs * E // router.shape[1])))
+            out = experts() if cap == pairs else jax.lax.cond(
+                stay <= cap,
+                lambda: jnp.pad(experts(cap), ((0, pairs - cap), (0, 0))),
+                experts)
+            # a row behind the last group is no expert's: whatever the
+            # grouped matmul left there, it is nothing
+            out = jnp.where((jnp.arange(pairs) < stay)[:, None], out, 0.0)
         # back to token order by a gather (the inverse permutation), then
         # a fixed-order sum over each token's k experts
         out = out[jnp.argsort(order)].reshape(T, k, -1)
@@ -647,29 +851,48 @@ def table_row(n_heads, head_dim, device):
     persistent cache: PERF.md, PR 27).  The price is the tile's padding
     at rest: 2.67x at (12, 64), nothing at (16, 128).  A mesh's table
     shards by heads and keeps the plain row."""
-    from paddle_tpu.parallel.mesh import as_mesh_group
-    if device is None:
-        import jax
-        device = jax.devices()[0]
-    if as_mesh_group(device) is not None \
-            or getattr(device, "platform", "cpu") != "tpu":
+    if not _rows_are_tiles(device):
         return int(n_heads), int(head_dim)
     return -(-int(n_heads) // 8) * 8, -(-int(head_dim) // 128) * 128
 
 
+def _rows_are_tiles(device):
+    """Whether a slot table on `device` (a jax.Device, a MeshGroup, or
+    None: jax's default device) pads its rows to the decode kernels'
+    tile: on ONE TPU device (`table_row` says why)."""
+    from paddle_tpu.parallel.mesh import as_mesh_group
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    return as_mesh_group(device) is None \
+        and getattr(device, "platform", "cpu") == "tpu"
+
+
+def latent_row(blk, device):
+    """Lanes of one cached position's row in an MLA stack's latent table:
+    kv_lora_rank + qk_rope_head_dim values (the normed latent, then the
+    rotated key all heads share), on one TPU device rounded up to the 128
+    lanes of the kernel's tile with exact zeros (`table_row` says why): the
+    published 512 + 64 = 576 are held as 640, +11%."""
+    n = blk["kv_lora_rank"] + blk["qk_rope_head_dim"]
+    return -(-n // 128) * 128 if _rows_are_tiles(device) else n
+
+
 def _pad_rows(x, row):
-    """`x` [..., H, Dh] zero-padded on its last two axes to the table's
-    row `row` = (Hp, Dp) (`table_row`); `x` itself where the row is not
+    """`x` [..., *r] zero-padded on its last axes to the table's row
+    `row`: (Hp, Dp) of a K/V table (`table_row`), (Rp,) of a latent table
+    (`latent_row`), (D,) of a conv state; `x` itself where the row is not
     padded."""
     import jax.numpy as jnp
-    h, d = row[0] - x.shape[-2], row[1] - x.shape[-1]
-    if not (h or d):
+    pad = [(0, r - n) for r, n in zip(row, x.shape[-len(row):])]
+    if not any(p for _, p in pad):
         return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, h), (0, d)])
+    return jnp.pad(x, [(0, 0)] * (x.ndim - len(row)) + pad)
 
 
 def _land(table, layer, where, rows):
-    """`table` [L, N, S, Hp, Dp] with `rows` [N(, C), H, Dh] written at
+    """`table` [L, N, S, Hp, Dp] with `rows` [N(, C), H, Dh] (or a latent
+    table [L, N, S, Rp] with `rows` [N, R]) written at
     (layer, *where), `where` = (slots, positions) broadcasting to the
     rows' leading shape: THE write of a decode phase.  A row whose
     position is S or more lands nowhere and is dropped (no row of
@@ -717,8 +940,9 @@ _SLOT_WRITERS = []
 
 def _slot_writers():
     """(write_rows, zero_slot, clear_rows): the three eager writes of a
-    slot-state table (K/V [L, N, S, Hp, Dp] or conv state [L, N, K-1, D]),
-    jitted with the table DONATED so that they land in place.
+    slot-state table (K/V [L, N, S, Hp, Dp], latent rows [L, N, S, Rp] or
+    conv state [L, N, K-1, D]), jitted with the table DONATED so that they
+    land in place.
     `write_rows(table, rows [L, 1, B, H, Dh], slot)`
     puts `rows`, padded to the table's row, at `slot` from position 0 (a
     prefill's K or V, or its conv state [L, 1, K-1, D] whole);
@@ -736,7 +960,7 @@ def _slot_writers():
 
         def write_rows(table, rows, slot):
             return jax.lax.dynamic_update_slice(
-                table, _pad_rows(rows, table.shape[-2:]),
+                table, _pad_rows(rows, table.shape[3:]),
                 (0, slot) + (0,) * (table.ndim - 2))
 
         def zero_slot(table, slot):
@@ -968,7 +1192,7 @@ class GenerativePredictor:
             from paddle_tpu.flags import FLAGS
             # a mesh shards the K/V tables by heads at rest and has no
             # rule for a conv state
-            self._require_attention_stack("a mesh placement")
+            self._require_kv_stack("a mesh placement")
             if FLAGS.mesh_tp:
                 # no fall-back to the gather path for a block the TP
                 # grammar cannot split: it was asked for by name
@@ -1051,6 +1275,19 @@ class GenerativePredictor:
         return ops[:i].count(ops[i])
 
     @functools.cached_property
+    def latent(self):
+        """Whether the stack's layers are MLA: its slot state is ONE
+        latent table (`slot_state_shapes`) where another stack holds a K
+        and a V table."""
+        return self.layer_kinds[0][0] == "mla"
+
+    @property
+    def _kv_tables(self):
+        """Tables of cached rows a session holds: K and V, or an MLA
+        stack's one latent table."""
+        return 1 if self.latent else 2
+
+    @functools.cached_property
     def conv_layers(self):
         """Layers whose operator is a gated short convolution: those
         with a row in the conv-state table."""
@@ -1085,10 +1322,23 @@ class GenerativePredictor:
                 "sharded by heads nor scaled a head)"
                 % (what, list(self._block_meta["layer_types"])))
 
+    def _require_kv_stack(self, what):
+        """Raise for what is written for K and V tables of per-head rows
+        (a mesh's sharding by heads, the int8 cache's per-head scales, the
+        speculative phases' [N, C] calls of `decode_attention`): an MLA
+        stack keeps one latent row a position, no head's."""
+        self._require_attention_stack(what)
+        if self.latent:
+            raise NotImplementedError(
+                "%s is written for K and V tables of per-head rows, and "
+                "this artifact's meta has layer_types=%r (one latent row "
+                "a position, shared by all heads)"
+                % (what, list(self._block_meta["layer_types"])))
+
     def _require_plain_stack(self, what):
         """Raise for what is written for all-attention multi-head
         tables only, naming the meta key that asks for another."""
-        self._require_attention_stack(what)
+        self._require_kv_stack(what)
         if self._kv_heads() != self._dims()[1]:
             raise NotImplementedError(
                 "%s is written for a multi-head table, and this "
@@ -1195,9 +1445,11 @@ class GenerativePredictor:
 
     def table_shape(self, n_slots):
         """[attention layers, n_slots, S, Hp, Dp]: the K (or V) slot
-        table of an `n_slots` session of this predictor
+        table of an `n_slots` session of this predictor; for an MLA
+        stack [mla layers, n_slots, S, Rp], its one latent table
         (`slot_state_shapes`)."""
-        return self._slot_state_shapes(n_slots)[0][:3] + self.table_row()
+        shape = self._slot_state_shapes(n_slots)[0]
+        return shape if self.latent else shape[:3] + self.table_row()
 
     def conv_state_shape(self, n_slots):
         """[conv layers, n_slots, conv_kernel - 1, D]: the conv-state
@@ -1219,7 +1471,8 @@ class GenerativePredictor:
         layers' rows, as the table holds them: padded to the tile on one
         TPU device, `table_row`) at the CACHE dtype's width (4 B fp32,
         1 B int8 — plus the int8 cache's per-(layer, head) fp32 scale
-        table) — the HBM term that bounds decode slots
+        table), or the ONE latent table of an MLA stack — the HBM term
+        that bounds decode slots
         (FLAGS.serving_decode_slots) and the number the admission fit
         check adds per replica; analysis/resources.py's `_decode_report`
         prices the same shape.  The conv layers' state is
@@ -1227,7 +1480,9 @@ class GenerativePredictor:
         L, H, _, _ = self._dims()
         elem = 1 if self._kv_quant else 4
         scales = 2 * L * H * 4 if self._kv_quant else 0
-        return 2 * int(np.prod(self.table_shape(n_slots))) * elem + scales
+        # an MLA stack's latent table is held once: it has no V
+        return (self._kv_tables
+                * int(np.prod(self.table_shape(n_slots))) * elem + scales)
 
     def conv_state_bytes(self, n_slots):
         """Closed-form footprint of the conv layers' slot state for an
@@ -1335,6 +1590,8 @@ class GenerativePredictor:
         the table: an index array, or the slice a prefill's run of
         consecutive positions is."""
         x = tp.embed_lookup(state["embed"], tokens)
+        if x.dtype != np.float32:       # a table bfloat16 at rest
+            x = x.astype(np.float32)
         if self._block_meta["position"] == "learned":
             x = x + state["pos"][positions]
         return x
@@ -1347,14 +1604,16 @@ class GenerativePredictor:
         argmax."""
         x = self._norm(x, state, "lnf")
         if self._block_meta["head"] == "tied":
-            return x @ state["embed"].T
-        logits = x @ state["lm_head"]
+            return _mm(x, state["embed"].T)
+        logits = _mm(x, state["lm_head"])
         return tp.all_gather(logits, axis=logits.ndim - 1)
 
     def _prefill_core(self, state, tokens, true_len, tp=_OFF_MESH):
         """tokens [1, B] int32, true_len scalar int32 -> (first_token
         [] int32, k/v [attention layers, 1, B, Hkv, Dh] fp32 with pad
-        positions zeroed[, conv state [conv layers, 1, K-1, D]: each conv
+        positions zeroed (an MLA stack: ONE array in their place, the
+        latent rows [mla layers, 1, B, R])[, conv state [conv layers, 1,
+        K-1, D]: each conv
         layer's last K-1 inputs before the TRUE prompt end, not the
         bucket's, zeros where the prompt is shorter]).
         Under TP (inside shard_map) weights are local shards:
@@ -1373,8 +1632,12 @@ class GenerativePredictor:
                                              tp)
         x = self._embed(state, tokens, slice(B), tp)
         positions = jnp.arange(B)[None]                     # [1, B]
-        ks, vs, facts, conv = [], [], [], []
+        ks, vs, facts, conv, rows = [], [], [], [], []
         group = self._dims()[1] // self._kv_heads()
+
+        def latent(q_nope, q_rope, row, wkv_b):
+            rows.append(row)
+            return self._mla_expanded(q_nope, q_rope, row, wkv_b)
 
         def attend(q, k, v):
             ks.append(k)
@@ -1395,12 +1658,17 @@ class GenerativePredictor:
         for i in range(L):
             x, f = self._block(state, i, x, positions, attend,
                                positions[0] < true_len, tp=tp,
-                               convolve=convolve)
+                               convolve=convolve, latent=latent)
             facts.append(f)
         first = jnp.argmax(self._head(state, x, tp)[0, true_len - 1],
                            axis=-1).astype(jnp.int32)
         if self.routed_layers:
             first = _pack_routing(first, facts)
+        if rows:
+            # the latent table's [mla layers, 1, B, R], pads zeroed
+            return first, jnp.where(
+                (positions[0] < true_len)[None, None, :, None],
+                jnp.stack(rows), 0.0)
         out = (first,) + _zero_pad_positions(ks, vs, true_len)
         return out + ((jnp.stack(conv),) if conv else ())
 
@@ -1414,11 +1682,20 @@ class GenerativePredictor:
                    blk["norm_eps"])
 
     def _block(self, state, i, x, positions, attend, live, tp=_OFF_MESH,
-               convolve=None, picks=None):
+               convolve=None, picks=None, latent=None):
         """Layer i of the stack, as the artifact's meta describes it
         (BLOCK_DEFAULTS), for every phase: x [..., D] with one position
         per leading index, weights `state["l<i>_" + name]`: the layer's
         operator on the normed x, then its FFN on the normed sum.
+
+        With meta sandwich_norm a sublayer's result is normed once more
+        (`ln1p`, `ln2p`) before it joins the residual stream.
+
+        An MLA layer (`_mla`): `latent(q_nope, q_rope, row, wkv_b)` gets
+        the heads' queries, the position's latent row and the
+        up-projection, and returns the heads' attention output [..., H,
+        v_head_dim]; whether it expands the rows or absorbs the
+        up-projection, and where it keeps the row, is the phase's.
 
         An ATTENTION layer: `attend(q, k, v)` gets q [..., Hl, Dh] and
         k / v [..., K/V heads, Dh] (normed and rotated where the block
@@ -1445,8 +1722,13 @@ class GenerativePredictor:
         lead = x.shape[:-1]
         h = self._norm(x, state, p + "ln1")
 
+        def joins(y, name):
+            # a sublayer's result on its way into the residual stream
+            return self._norm(y, state, p + name) \
+                if blk["sandwich_norm"] else y
+
         def project(w, heads, gain=None):
-            t = h @ state[p + w]
+            t = _mm(h, state[p + w])
             if gain and blk["qk_norm"] is True:
                 # over the whole projection, before the split into heads
                 t = _rms(t, state[p + gain], blk["norm_eps"])
@@ -1455,11 +1737,14 @@ class GenerativePredictor:
                 t = _rms(t, state[p + gain], blk["norm_eps"])
             return t
 
-        if op == "conv":
+        if op == "mla":
+            x = x + joins(self._mla(state, p, h, positions, latent), "ln1p")
+        elif op == "conv":
             with jax.named_scope("short_conv"):
-                b, c, u = jnp.split(h @ state[p + "conv_in"], 3, axis=-1)
-                x = x + (c * convolve(b * u, state[p + "conv_w"])) \
-                    @ state[p + "conv_out"]
+                b, c, u = jnp.split(_mm(h, state[p + "conv_in"]), 3,
+                                    axis=-1)
+                x = x + joins(_mm(c * convolve(b * u, state[p + "conv_w"]),
+                                  state[p + "conv_out"]), "ln1p")
         else:
             Hkv = self._kv_heads() // tp.size
             with (jax.named_scope("gqa_attention") if Hkv != Hl
@@ -1469,14 +1754,15 @@ class GenerativePredictor:
                 if blk["position"] == "rope":
                     q = _rope(q, positions, blk["rope_theta"])
                     k = _rope(k, positions, blk["rope_theta"])
-                x = x + tp.psum(attend(q, k, v).reshape(lead + (Hl * Dh,))
-                                @ state[p + "wo"])
+                x = x + joins(tp.psum(_mm(
+                    attend(q, k, v).reshape(lead + (Hl * Dh,)),
+                    state[p + "wo"])), "ln1p")
         h2 = self._norm(x, state, p + "ln2")
         if ffn == "dense_swiglu":
             with jax.named_scope("dense_ffn"):
-                return x + (jax.nn.silu(h2 @ state[p + "ffn_gate"])
-                            * (h2 @ state[p + "ffn_up"])) \
-                    @ state[p + "ffn_down"], None
+                return x + joins(_swiglu(
+                    h2, state[p + "ffn_gate"], state[p + "ffn_up"],
+                    state[p + "ffn_down"]), "ln2p"), None
         if ffn == "moe_swiglu":
             y, facts = moe_ffn(
                 h2.reshape(-1, D), state[p + "router"],
@@ -1484,11 +1770,113 @@ class GenerativePredictor:
                 state[p + "w_down"], blk["experts_per_token"],
                 blk["norm_topk_prob"], live,
                 expert_bias=state[p + "expert_bias"]
-                if blk["router"] == "sigmoid_bias" else None, picks=picks)
-            return x + y.reshape(x.shape), facts
+                if blk["router"] == "sigmoid_bias" else None, picks=picks,
+                sigmoid=blk["router"] == "sigmoid",
+                scaling=blk["routed_scaling"],
+                held=blk["experts_held"] or None)
+            y = y.reshape(x.shape)
+            if blk["n_shared_experts"]:
+                # beside `moe_ffn`'s scope, whose readers count the
+                # routed experts' work
+                with jax.named_scope("shared_expert"):
+                    y = y + _swiglu(h2, state[p + "shared_gate"],
+                                    state[p + "shared_up"],
+                                    state[p + "shared_down"])
+            return x + joins(y, "ln2p"), facts
         mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
                           0.0) @ state[p + "w2"]
         return x + tp.psum(mlp) + state[p + "b2"], None
+
+    def _mla(self, state, p, h, positions, latent):
+        """An MLA layer's operator on the normed input h [..., D] (weights
+        `state[p + name]`) -> [..., D], before the residual sum:
+
+            c_q = rms(h wq_a; q_a_g);  q = c_q wq_b -> [H, nope | rope]
+            [c_kv | k_rope] = h wkv_a;  c_kv = rms(c_kv; kv_a_g)
+            q_rope, k_rope turned by the position (half-split, over the
+            rope lanes alone; ONE k_rope a position, shared by the heads)
+            row = [c_kv | k_rope]: all the slot table keeps of a position
+            a = latent(q_nope, q_rope, row, wkv_b [rank, H, nope | v])
+            result = concat_h(a) wo
+
+        `latent` is the phase's (`_block`): `_mla_expanded` over a
+        prompt's own rows, `_mla_absorbed` over the slot table.  Scopes:
+        `mla_proj` here (and the up-projection or its absorption there),
+        `mla_prefill` / `mla_attention` around the attention itself."""
+        import jax
+        import jax.numpy as jnp
+        blk = self._block_meta
+        H = self._dims()[1]
+        _, rkv, dn, dr, dv = (blk[k] for k in _MLA_DIMS)
+        eps, theta = blk["norm_eps"], blk["rope_theta"]
+        lead = h.shape[:-1]
+        with jax.named_scope("mla_proj"):
+            c_q = _rms(_mm(h, state[p + "wq_a"]), state[p + "q_a_g"], eps)
+            q = _mm(c_q, state[p + "wq_b"]).reshape(lead + (H, dn + dr))
+            kv = _mm(h, state[p + "wkv_a"])
+            row = jnp.concatenate([
+                _rms(kv[..., :rkv], state[p + "kv_a_g"], eps),
+                _rope(kv[..., None, rkv:], positions, theta)[..., 0, :]],
+                axis=-1)
+            q_rope = _rope(q[..., dn:], positions, theta)
+        a = latent(q[..., :dn], q_rope, row,
+                   state[p + "wkv_b"].reshape(rkv, H, dn + dv))
+        with jax.named_scope("mla_proj"):
+            return _mm(a.reshape(lead + (H * dv,)), state[p + "wo"])
+
+    def _mla_scale(self):
+        blk = self._block_meta
+        return 1.0 / np.sqrt(blk["qk_nope_head_dim"]
+                             + blk["qk_rope_head_dim"])
+
+    def _mla_expanded(self, q_nope, q_rope, rows, wkv_b):
+        """The EXPANDED path, a prefill's: every row [1, B, R] of the
+        prompt goes up to its heads' keys and values, [k_nope_h | v_h] =
+        c_kv wkv_b, a head's key is [k_nope_h | k_rope], and the attention
+        is the causal oracle over them -> [1, B, H, v].  Compute-bound and
+        in the prompt's own arrays: nothing of it is kept but the rows.
+        The scores are whole, [H, B, B] fp32 (0.54 GB at 128 heads and a
+        bucket of 1024), so an MLA artifact's prefill buckets stop where
+        that fits."""
+        import jax
+        import jax.numpy as jnp
+        dn, rkv = q_nope.shape[-1], wkv_b.shape[0]
+        with jax.named_scope("mla_proj"):
+            kv = _contract(rows[..., :rkv], wkv_b, functools.partial(
+                jnp.einsum, "...r,rhd->...hd"))
+        with jax.named_scope("mla_prefill"):
+            k_rope = jnp.broadcast_to(
+                rows[..., None, rkv:],
+                kv.shape[:-1] + (rows.shape[-1] - rkv,))
+            return _causal_attention(
+                jnp.concatenate([q_nope, q_rope], axis=-1),
+                jnp.concatenate([kv[..., :dn], k_rope], axis=-1),
+                kv[..., dn:], self._mla_scale())
+
+    def _mla_absorbed(self, q_nope, q_rope, table, seen, at, wkv_b):
+        """The ABSORBED path, a decode step's: q_nope . k_nope_h(s) =
+        (q_nope wkv_b[k part, h]^T) . c_kv(s), so each head's query goes
+        DOWN to the rows' space once, [N, H, rank | rope], the kernel
+        attends over layer `at` of the latent table itself under `seen`
+        [N] positions (128 heads on one row, read once), and the weighted
+        latents go up to values afterwards: u_h wkv_b[v part, h] -> [N, H,
+        v].  No per-head key or value of a cached position is ever
+        formed."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas_kernels import latent_decode_attention
+        dn, rkv = q_nope.shape[-1], wkv_b.shape[0]
+        with jax.named_scope("mla_proj"):
+            q = jnp.concatenate([
+                _contract(q_nope, wkv_b[..., :dn], functools.partial(
+                    jnp.einsum, "nhd,rhd->nhr")), q_rope], axis=-1)
+            q = _pad_rows(q, table.shape[3:])
+        with jax.named_scope("mla_attention"):
+            u = latent_decode_attention(q, table, seen, rkv,
+                                        self._mla_scale(), layer=at)
+        with jax.named_scope("mla_proj"):
+            return _contract(u, wkv_b[..., dn:], functools.partial(
+                jnp.einsum, "nhr,rhd->nhd"))
 
     def _prefill_core_seqpar(self, state, tokens, true_len, tp):
         """SEQUENCE-parallel TP prefill (parallel/ulysses.py's scheme):
@@ -1622,7 +2010,9 @@ class GenerativePredictor:
         `tables` = (kc, vc[, cs]): the K/V tables [attention layers, N,
         S, Hp, Dp] (fp32, or int8 under the quantized cache) and, for a
         stack with conv layers, the conv-state table [conv layers, N,
-        K-1, D]; lengths [N] i32 (live cached positions), last_tokens
+        K-1, D]; or, for an MLA stack, (rows,): the latent table [mla
+        layers, N, S, Rp] alone; lengths [N] i32 (live cached positions),
+        last_tokens
         [N] i32, active [N] bool -> (logits [N, vocab] f32, tables',
         per-layer routing facts).  Each layer is `_block` at position
         `lengths` (a slot's own): an attention layer's `attend` the
@@ -1652,7 +2042,8 @@ class GenerativePredictor:
         ~1/mesh_size."""
         import jax.numpy as jnp
         L = self._dims()[0]
-        kc, vc = tables[:2]
+        # an MLA stack's one latent table stands where K stands
+        kc, vc = tables[:2] if not self.latent else (tables[0], None)
         cs = tables[2] if len(tables) > 2 else None
         N, S = kc.shape[1], kc.shape[2]
         x = self._embed(state, last_tokens, lengths, tp)        # [N, D]
@@ -1677,11 +2068,17 @@ class GenerativePredictor:
                 return sum(taps[:, j] * seen[:, j]
                            for j in range(taps.shape[1]))
 
+            def latent(q_nope, q_rope, row, wkv_b, at=at):
+                nonlocal kc
+                kc = _land(kc, at, where, row)
+                return self._mla_absorbed(q_nope, q_rope, kc, lengths + 1,
+                                          at, wkv_b)
+
             x, f = self._block(state, i, x, lengths, attend, active, tp=tp,
-                               convolve=convolve, picks=picks)
+                               convolve=convolve, picks=picks, latent=latent)
             facts.append(f)
         return (self._head(state, x, tp),
-                (kc, vc) + (() if cs is None else (cs,)), facts)
+                tuple(t for t in (kc, vc, cs) if t is not None), facts)
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
                      tp=_OFF_MESH):
@@ -2179,21 +2576,23 @@ class GenerativePredictor:
     @property
     def _n_tables(self):
         """Leaves of a session's slot state: the K and V tables, and the
-        conv-state table of a stack with conv layers.  They lead the
+        conv-state table of a stack with conv layers; the one latent table
+        of an MLA stack.  They lead the
         arguments of every phase over the slots (`_table_specs`)."""
-        return 3 if self.conv_layers else 2
+        return self._kv_tables + bool(self.conv_layers)
 
     def _table_specs(self, n_slots):
         """(kc, vc[, conv state], lengths [N] i32, last tokens [N] i32,
-        active [N] bool): what every phase over the slots takes, their
-        state first (`_n_tables` leaves)."""
+        active [N] bool) (an MLA stack: its latent table where kc, vc
+        stand): what every phase over the slots takes, their state first
+        (`_n_tables` leaves)."""
         import jax
         n = int(n_slots)
         cache = jax.ShapeDtypeStruct(self.table_shape(n),
                                      self._cache_np_dtype())
         conv = self.conv_state_shape(n)
         i32 = np.dtype(np.int32)
-        return (cache, cache) + (
+        return (cache,) * self._kv_tables + (
             (jax.ShapeDtypeStruct(conv, np.dtype(np.float32)),)
             if conv else ()) + (
             jax.ShapeDtypeStruct((n,), i32),
@@ -2246,7 +2645,7 @@ class GenerativePredictor:
         boot of a spec-configured server deserializes it like every
         other phase (COMPILE_CACHE.md)."""
         import jax
-        self._require_attention_stack("the speculative verify")
+        self._require_kv_stack("the speculative verify")
         n, C = int(n_slots), int(spec_k) + 1
         cache, _, lengths, _, active = self._table_specs(n)
         specs = (cache, cache, lengths,
@@ -2264,7 +2663,7 @@ class GenerativePredictor:
         executable."""
         import jax
         for side in (self, draft):
-            side._require_attention_stack("the fused speculative round")
+            side._require_kv_stack("the fused speculative round")
         n, C = int(n_slots), int(spec_k) + 1
         cache, _, i32n, _, active = self._table_specs(n)
         dcache = draft._table_specs(n)[0]
@@ -2296,8 +2695,10 @@ class DecodeSession:
     """One lane's slots: their state + occupancy bookkeeping.  A slot's
     state is of two kinds (`slot_state_shapes`): its rows of the K/V
     tables (`_kc`, `_vc`: the attention layers', addressed by the slot's
-    length) and, for a stack with conv layers, its row of the conv-state
-    table (`_cs`: a fixed size, rolled by every token; None otherwise).
+    length; a stack of latent attention holds ONE table of latent rows,
+    `_kc`, and `_vc` is None) and, for a stack with conv layers, its row
+    of the conv-state table (`_cs`: a fixed size, rolled by every token;
+    None otherwise).
     Every phase that advances the slots is given all of it donated and
     the session keeps the results (`_tables`, `_keep`).
     NOT thread-safe — a serving lane owns its session exclusively (the
@@ -2340,9 +2741,10 @@ class DecodeSession:
             return jax.device_put(
                 z, predictor.device or next(iter(z.devices())))
 
-        # two buffers: a donated K table must not take V's with it
+        # two buffers: a donated K table must not take V's with it (an
+        # MLA stack's latent table is `_kc`, and there is no V)
         self._kc = table()
-        self._vc = table()
+        self._vc = None if predictor.latent else table()
         # the conv layers' state (refused on a mesh: `_inplace`)
         conv = predictor.conv_state_shape(self.n_slots)
         self._cs = None
@@ -2355,12 +2757,18 @@ class DecodeSession:
             self._stack_attrs = {
                 "conv_layers": conv[0], "attn_layers": shape[0],
                 "conv_state_bytes": int(self._cs.nbytes)}
+        if predictor.latent:
+            self._stack_attrs = {"mla_layers": shape[0],
+                                 "latent_cache_bytes": int(self._kc.nbytes)}
+        if predictor._block_meta["experts_held"]:
+            self._stack_attrs["moe_experts_held"] = \
+                predictor._block_meta["experts_held"][1]
         # the decode kernel's block edge over this table, as the step's
         # trace resolves it (None: no edge divides S, the step attends
         # through the plain-XLA reference, which reads whole rows)
         from paddle_tpu.ops import attention_tuning
         self._kv_block = attention_tuning.get_decode_config(
-            shape[2], shape[4], jnp.dtype(dtype).name)
+            shape[2], shape[-1], jnp.dtype(dtype).name)
         # set when a call failed after its table was donated to it
         # (`_mark_dead`): (phase, error); every later use raises
         self._dead = None
@@ -2402,7 +2810,8 @@ class DecodeSession:
         bench_serving's --kv_dtype A/B reports against the closed-form
         `GenerativePredictor.kv_cache_bytes`.  The conv layers' state is
         `conv_state_bytes`, apart."""
-        n = int(self._kc.nbytes) + int(self._vc.nbytes)
+        n = sum(int(t.nbytes) for t in (self._kc, self._vc)
+                if t is not None)
         if self.predictor._kv_quant:
             n += int(np.asarray(self.predictor._kv_scales).nbytes)
         return n
@@ -2413,15 +2822,19 @@ class DecodeSession:
         return 0 if self._cs is None else int(self._cs.nbytes)
 
     def _tables(self):
-        """The slots' state as the phases take it: (kc, vc[, cs])."""
-        return (self._kc, self._vc) + (
-            () if self._cs is None else (self._cs,))
+        """The slots' state as the phases take it: (kc, vc[, cs]); an
+        MLA stack's (latent rows,)."""
+        return tuple(t for t in (self._kc, self._vc, self._cs)
+                     if t is not None)
 
     def _keep(self, tables):
         """Replace the slots' state by a phase's results."""
-        self._kc, self._vc = tables[:2]
+        tables = iter(tables)
+        self._kc = next(tables)
+        if self._vc is not None:
+            self._vc = next(tables)
         if self._cs is not None:
-            self._cs, = tables[2:]
+            self._cs = next(tables)
 
     # -- phases ---------------------------------------------------------
 
@@ -2498,18 +2911,17 @@ class DecodeSession:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = tokens
         fn = self.predictor.prefill_fn(bucket)
-        first, kc, vc, *conv = self._call("prefill", fn, (),
-                                          (padded, np.int32(n)))
-        # land the bucket-length K/V at the slot; positions past the
-        # bucket are already zero (the slot was zeroed on free)
+        first, *new = self._call("prefill", fn, (), (padded, np.int32(n)))
+        # land the bucket-length K/V (latent rows, conv state) at the
+        # slot; positions past the bucket are already zero (the slot was
+        # zeroed on free)
         if self._inplace:
             write_rows = _slot_writers()[0]
             at = self._slot_ids[slot]
-            self._kc = write_rows(self._kc, kc, at)
-            self._vc = write_rows(self._vc, vc, at)
-            if conv:
-                self._cs = write_rows(self._cs, conv[0], at)
+            self._keep([write_rows(t, rows, at)
+                        for t, rows in zip(self._tables(), new)])
         else:
+            kc, vc = new
             at = (0, slot, 0, 0, 0)
             self._kc = jax.lax.dynamic_update_slice(self._kc, kc, at)
             self._vc = jax.lax.dynamic_update_slice(self._vc, vc, at)
@@ -2725,8 +3137,8 @@ class DecodeSession:
             mine = np.arange(self.n_slots) == slot
             lo = np.where(mine, length - n, 0).astype(np.int32)
             hi = np.where(mine, length, 0).astype(np.int32)
-            self._kc = clear_rows(self._kc, lo, hi, n)
-            self._vc = clear_rows(self._vc, lo, hi, n)
+            # (no conv state among them: refused above)
+            self._keep([clear_rows(t, lo, hi, n) for t in self._tables()])
         elif n > 0:
             L = self._kc.shape[0]
             H, Dh = self._kc.shape[3], self._kc.shape[4]
@@ -2783,7 +3195,7 @@ class SpeculativeDecodeSession:
         if int(spec_k) < 1:
             raise ValueError("spec_k must be >= 1, got %r" % (spec_k,))
         for side in (target, draft):
-            side._require_attention_stack("speculative decoding")
+            side._require_kv_stack("speculative decoding")
         if draft.vocab_size != target.vocab_size:
             raise ValueError(
                 "draft vocab %d != target vocab %d — not a compatible "

@@ -36,7 +36,9 @@ from . import attention_tuning
 
 __all__ = ["tiled_contraction", "flash_attention", "decode_attention",
            "kv_last_block",
-           "decode_attention_reference", "decode_attention_head_slice",
+           "decode_attention_reference",
+           "latent_decode_attention", "latent_decode_attention_reference",
+           "decode_attention_head_slice", "lowering_for_tpu",
            "fused_bottleneck",
            "bottleneck_reference", "dequant_matmul",
            "dequant_matmul_reference", "mosaic_lowering"]
@@ -66,6 +68,16 @@ def mosaic_lowering(enable=True):
         _DISPATCH.force_kernel = prev
 
 
+def lowering_for_tpu():
+    """Whether the trace in hand lowers for a TPU: this thread's
+    `mosaic_lowering` choice where one is made, the default backend
+    otherwise.  What `interpret=None` resolves by, and what code with a
+    TPU form of its own asks (`inference/decode.py::_contract`)."""
+    import jax
+    force = getattr(_DISPATCH, "force_kernel", None)
+    return (jax.default_backend() == "tpu") if force is None else force
+
+
 def _interpret_dispatch(call, interpret, *ops):
     """Kernel-vs-interpret dispatch shared by every Pallas entry point:
     an explicit `interpret` wins; None resolves at TRACE time — the real
@@ -75,11 +87,8 @@ def _interpret_dispatch(call, interpret, *ops):
     This jax's lax.platform_dependent cannot serve here: it stages the
     dead Mosaic branch into single-platform CPU jits, whose pallas
     lowering rejects interpret=False outright."""
-    import jax
     if interpret is None:
-        force = getattr(_DISPATCH, "force_kernel", None)
-        interpret = (jax.default_backend() != "tpu") if force is None \
-            else not force
+        interpret = not lowering_for_tpu()
     return call(interpret, *ops)
 
 
@@ -789,6 +798,130 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     if G > 1:
         out = out.reshape(N, G, Hc, D).transpose(0, 2, 1, 3).reshape(N, H, D)
     return out
+
+
+def latent_decode_attention_reference(q, table, lengths, value_lanes,
+                                      scale):
+    """Plain-XLA oracle/fallback of `latent_decode_attention`: q [N, H, R]
+    (each head's absorbed query), table [N, S, R] (ONE latent row a cached
+    position, shared by all H heads; its first `value_lanes` lanes are the
+    values too), lengths [N] -> [N, H, value_lanes]: softmax over the live
+    positions of q . row * scale, then the weighted sum of the rows' value
+    lanes.  Masking as `decode_attention_reference`."""
+    import jax.numpy as jnp
+    S = table.shape[1]
+    rows = table.astype(jnp.float32)
+    s = jnp.einsum("nhr,nsr->nhs", q.astype(jnp.float32), rows) * scale
+    mask = jnp.arange(S)[None, None, :] >= \
+        jnp.asarray(lengths).astype(jnp.int32)[:, None, None]
+    s = jnp.where(mask, _NEG_INF, s)
+    p = jnp.exp(s - jnp.max(s, axis=-1)[..., None])
+    l = jnp.maximum(jnp.sum(p, axis=-1), _TINY)
+    o = jnp.einsum("nhs,nsv->nhv", p, rows[..., :value_lanes])
+    return (o / l[..., None]).astype(q.dtype)
+
+
+def latent_decode_attention(q, table, lengths, value_lanes, scale,
+                            block_kv=None, interpret=None, layer=None):
+    """Decode attention over LATENT rows (multi-head latent attention with
+    the up-projections absorbed into the query and the output): q [N, H,
+    R], one new token a slot, every head's query already in the rows' own
+    space; table [N, S, R] float32, ONE row a cached position which all H
+    heads read (a group of H on one K/V "head" whose V is the first
+    `value_lanes` lanes of its K); lengths [N] i32 -> [N, H, value_lanes].
+    With a static `layer`, table is the stacked slot table [L, N, S, R]
+    and the kernel reaches the layer through its index map
+    (`decode_attention` says why).
+
+    `decode_attention`'s stream: grid (slot, block of `block_kv`
+    positions), a slot's blocks past its length neither copied nor
+    computed (`kv_last_block`, the same scalar-prefetch pair), online
+    softmax over the blocks.  What differs is the body.  There a head
+    meets its own row and the products are formed on the VPU; here H
+    queries meet ONE row, 2 * (R + value_lanes) * H FLOP a position (278
+    kFLOP at 128 heads of 576 lanes over 512 values), so a block is
+    staged once and both contractions run on the MXU: scores [H, block] =
+    q rows^T, values [H, value_lanes] += p rows[:, :value_lanes].  On the
+    TPU their operands are rounded to bfloat16 with float32 accumulation,
+    which is what the default precision does to every other matmul of a
+    decode phase (a float32 Mosaic matmul is several passes, and would
+    make this kernel compute-bound at 128 heads); in interpret mode they
+    stay float32, as a float32 matmul off the TPU does.  Falls back to the
+    reference when no block edge divides S.  A slot of length 0: as in
+    `decode_attention`."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, H, R = q.shape
+    V = int(value_lanes)
+    stacked = layer is not None
+    if stacked != (table.ndim == 4) or table.shape[-1] != R:
+        raise ValueError(
+            "latent_decode_attention: a stacked table [L, N, S, R] goes "
+            "with a static `layer`, a single layer [N, S, R] without one, "
+            "R the queries' %d lanes (got a table %s, layer=%r)"
+            % (R, tuple(table.shape), layer))
+    S = table.shape[-2]
+    scale = float(scale)
+    bkv = int(block_kv or attention_tuning.get_decode_config(
+        S, R, jnp.dtype(table.dtype).name) or 0)
+    if not bkv or S % bkv:
+        return latent_decode_attention_reference(
+            q, table[layer] if stacked else table, lengths, V, scale)
+    if interpret is None:
+        interpret = not lowering_for_tpu()
+    operand = jnp.float32 if interpret else jnp.bfloat16
+    lengths = jnp.asarray(lengths).astype(jnp.int32).reshape(N)
+    n_blocks = S // bkv
+    last = kv_last_block(lengths, bkv, n_blocks, xp=jnp)
+    if stacked:
+        layer = int(layer)
+        row_spec = pl.BlockSpec(
+            (None, 1, bkv, R), lambda b, j, len_ref, last_ref: (
+                layer, b, jnp.minimum(j, last_ref[b]), 0))
+    else:
+        row_spec = pl.BlockSpec(
+            (1, bkv, R), lambda b, j, len_ref, last_ref: (
+                b, jnp.minimum(j, last_ref[b]), 0))
+
+    def tile(ctx):
+        q_ref, row_ref = ctx.ins
+        acc_ref, m_ref, l_ref = ctx.scratch
+        rows = row_ref[0].astype(operand)                   # [BKV, R]
+        s = jax.lax.dot_general(
+            q_ref[0].astype(operand), rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # [H, BKV]
+        kpos = ctx.reduce_id * bkv + jax.lax.broadcasted_iota(
+            jnp.int32, (H, bkv), 1)
+        s = jnp.where(kpos >= ctx.scalars[0][ctx.ids[0]], _NEG_INF, s)
+        _online_softmax_tile(
+            s, lambda p: jnp.dot(p.astype(operand), rows[:, :V],
+                                 preferred_element_type=jnp.float32),
+            acc_ref, m_ref, l_ref)
+
+    def finalize(ctx):
+        o, _ = _softmax_finalize(*ctx.scratch)
+        ctx.outs[0][0] = o.astype(ctx.outs[0].dtype)
+
+    return tiled_contraction(
+        (q, table),
+        grid=(N, n_blocks),
+        reduce_axis=1,
+        in_specs=[pl.BlockSpec((1, H, R), lambda b, j, *_: (b, 0, 0)),
+                  row_spec],
+        out_specs=pl.BlockSpec((1, H, V), lambda b, j, *_: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, H, V), q.dtype),
+        scratch=[pltpu.VMEM((H, V), jnp.float32),
+                 pltpu.VMEM((H, _MIN_LANES), jnp.float32),
+                 pltpu.VMEM((H, _MIN_LANES), jnp.float32)],
+        scratch_fill=(0.0, _NEG_INF, 0.0),
+        tile=tile, finalize=finalize,
+        tile_live=lambda ids, len_ref, last_ref:
+            ids[1] <= last_ref[ids[0]],
+        scalar_prefetch=(lengths, last),
+        interpret=interpret)
 
 
 def decode_attention_head_slice(q, k_cache, v_cache, lengths, head_offset,

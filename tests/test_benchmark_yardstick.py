@@ -79,5 +79,10 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
         "source": "program_counter", "layer": "kernels",
         "moves": "tokens_per_s",
         "workloads": ["gpt2s_decode_saturated", "gpt2s_decode_deep",
-                      "olmoe_decode_saturated", "lfm2_decode_saturated"]}
-    assert manifest["per_layer"][-1] is entry       # appended, not inserted
+                      "olmoe_decode_saturated", "lfm2_decode_saturated",
+                      # PR 35's cell, whose latent kernel shares the stream's
+                      # rule and counter (`kv_last_block`, `_kv_stream`)
+                      "pangu_decode_saturated"]}
+    # appended, not inserted: only PR 35's five readers stand behind it
+    assert manifest["per_layer"].index(entry) == len(
+        manifest["per_layer"]) - 6

@@ -416,3 +416,71 @@ def test_hybrid_step_updates_both_kinds_of_slot_state_in_place(one_chip):
     assert text.count(" while(") == 1 and "ragged-dot" in text
     assert re.search(r"ENTRY [^\n]*: f32\[2,32,2,2048\]", text), \
         "the conv state is not an argument of its own"
+
+
+# benchmark/configs/openpangu_ultra_moe_718b.json at its 64 slots: the
+# leading dense layer and one routed layer of its five, at the published
+# widths (a compile's seconds, not a kernel's shape), weights as the
+# artifact keeps them
+PANGU = dict(vocab_size=19200, d_model=7680, n_heads=128, n_layers=2,
+             max_seq_len=4096, eos_id=0, norm="rmsnorm", norm_eps=1e-5,
+             position="rope", rope_theta=25.6e6, layer_types=["mla", "mla"],
+             q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+             qk_rope_head_dim=64, v_head_dim=128, sandwich_norm=True,
+             n_dense_layers=1, dense_width=18432, ffn="moe_swiglu",
+             n_experts=256, experts_per_token=8, expert_width=2048,
+             norm_topk_prob=True, router="sigmoid", routed_scaling=2.5,
+             n_shared_experts=1, experts_held=[96, 8],
+             weight_dtype="bfloat16")
+
+
+def test_latent_decode_attention_compiles(one_chip):
+    """128 absorbed query heads over ONE 640-lane row a position of a
+    stacked latent table, as `_mla_absorbed` calls it at the published row:
+    Mosaic takes both MXU contractions, bf16 operands from a float32 table,
+    and the value lanes as a slice of the staged rows."""
+    N, S, H, R, V = 64, 4096, 128, 640, 512
+    with pk.mosaic_lowering():
+        compile_for_chip(
+            lambda q, t, n: pk.latent_decode_attention(
+                q, t, n, V, 192 ** -0.5, layer=1),
+            one_chip, ((N, H, R), "float32"), ((2, N, S, R), "float32"),
+            ((N,), "int32"))
+
+
+def test_latent_step_updates_its_one_table_in_place(one_chip):
+    """The step window of an MLA stack with bfloat16 weights at rest: ONE
+    latent table, 640-lane rows, donated and aliased to its output, one
+    Mosaic call a layer inside the one `while`; the bf16 weights go to
+    their matmuls as they are (no float32 copy of the held experts, nor of
+    any other weight, is made: the temporaries are under a tenth of the
+    table)."""
+    from paddle_tpu.inference import decode as dec
+    slots = 64
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(PANGU, device)
+    state = {n: jax.ShapeDtypeStruct(
+        s.shape, jnp.bfloat16 if dec._bf16_at_rest(n, s) else np.float32,
+        sharding=s.sharding) for n, s in state.items()}
+    assert pred.table_shape(slots) == (2, slots, 4096, 640)
+    assert pred._n_tables == 1 and len(pred._step_specs(slots)) == 6
+    compiled = compile_phase(pred, state, pred._step_math(),
+                             pred._step_specs(slots), tables=range(1))
+    ma = compiled.memory_analysis()
+    table = 4 * int(np.prod(pred.table_shape(slots)))
+    assert ma.alias_size_in_bytes >= table
+    assert ma.temp_size_in_bytes < table / 10, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count(" while(") == 1 and "ragged-dot" in text
+    assert text.count("tpu_custom_call") >= 2
+    # nothing whose result is a float32 copy of the held experts' stacks
+    whole = re.compile(r"f32\[8,(7680,2048|2048,7680)\]")
+    made = [m.group(0) for m in re.finditer(
+        r"(%[\w.\-]+) = (\S+) ([\w\-]+)\(", text) if whole.search(m.group(2))]
+    assert not made, made[:6]
+    big = re.compile(r"\[2,64,4096,640\]")
+    bad = [m.group(0) for m in re.finditer(
+        r"(%[\w.\-]+) = (\S+) ([\w\-]+)\(", text)
+        if big.search(m.group(2)) and m.group(3) in (
+            "select", "concatenate", "copy", "pad", "transpose")]
+    assert not bad, bad[:6]

@@ -2150,9 +2150,8 @@ class GenerativePredictor:
           * `budget` [N] i32: tokens each slot may still emit (its
             max_new / cache-room headroom);
           * `max_trips` [] i32: the dispatch's trip count (at most the
-            window).  The serving lane sets it per dispatch: 1 while a
-            slot is free, the smallest live budget when none is, less
-            under the deadline governor.
+            window).  The serving lane sets it per dispatch: the
+            smallest live budget, less under the deadline governor.
 
         The slots that RUN are those active with budget and cache room
         left when the window begins, and the window ends in-graph with
@@ -2794,6 +2793,9 @@ class DecodeSession:
         # the `decode/put` / `decode/launch` spans of a call whose
         # results are not fetched yet (`_call`, `_fetch`)
         self._launched = ()
+        # the step dispatch `launch_fused` left for `fetch_fused`: (its
+        # result vector, still on the device; the steps it was asked)
+        self._in_flight = None
 
     # -- occupancy ------------------------------------------------------
 
@@ -3049,11 +3051,25 @@ class DecodeSession:
         whole dispatch.  Both are runtime arguments of the slot table's
         one executable.  Token for token the stream of one-trip
         dispatches: per-slot math is independent and every trip is the
-        same `_step_core`."""
+        same `_step_core`.  It is `launch_fused` followed at once by
+        `fetch_fused`: a caller with host work to do while the device
+        runs the window calls the two apart."""
+        self.launch_fused(n_steps, budget=budget, max_trips=max_trips)
+        return self.fetch_fused()
+
+    def launch_fused(self, n_steps, budget=None, max_trips=None):
+        """The first half of `decode_fused`: the executable call, which
+        returns once the dispatch is in the device's queue (`_call`).
+        The slot tables are the call's results from here on; `lengths`
+        and `last_tokens` stay as they were until `fetch_fused`, which
+        must come before any other use of the session."""
         N, W, act = self.n_slots, int(STEP_WINDOW), self.active
         T = min(int(n_steps), W)
         if T < 1:
             raise ValueError("n_steps must be >= 1, got %d" % T)
+        if self._in_flight is not None:
+            raise RuntimeError("a step dispatch is in flight: fetch_fused "
+                               "comes before the next launch_fused")
         self._alive()
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
@@ -3067,6 +3083,18 @@ class DecodeSession:
             "step", self.predictor.step_fn(N), self._tables(),
             (self.lengths, self.last_tokens, act, b, np.int32(mt)))
         self._keep(tables)
+        self._in_flight = (out, T)
+
+    def fetch_fused(self):
+        """The second half of `decode_fused`: the wait for the dispatch
+        `launch_fused` left in flight and the copy of its one small
+        vector (`_fetch`), then the slots' lengths and last tokens from
+        what it returned.  Returns what `decode_fused` returns."""
+        if self._in_flight is None:
+            raise RuntimeError("no step dispatch in flight: launch_fused "
+                               "comes first")
+        (out, T), self._in_flight = self._in_flight, None
+        N, W = self.n_slots, int(STEP_WINDOW)
         out, = self._fetch("step", out, routed=True, trips_at=N * W + N)
         toks = out[:N * W].reshape(N, W)[:, :T]
         counts, trips = out[N * W:N * W + N], int(out[N * W + N])

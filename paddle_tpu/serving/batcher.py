@@ -911,7 +911,7 @@ class DecodeStream:
 
     def cancel(self):
         """Ask the owning lane to evict this request; the slot is freed
-        (and zeroed) within one decode step.  The server's stream
+        (and zeroed) at the next dispatch boundary.  The server's stream
         handler calls this when the client connection dies mid-reply."""
         self._cancel.set()
 
@@ -990,7 +990,7 @@ class _DecodeLane:
 
     __slots__ = ("index", "predictor", "session", "assigned", "steps",
                  "tokens", "spec", "degraded_noted", "last_step_t",
-                 "step_ewma", "dead", "tp")
+                 "step_ewma", "dead", "tp", "held", "early")
 
     def __init__(self, index, predictor, n_slots, draft=None, spec_k=0):
         # error string once a mesh member died under this lane
@@ -1018,6 +1018,11 @@ class _DecodeLane:
         self.steps = 0
         self.tokens = 0
         self.degraded_noted = False
+        # the newest dispatch's delivery, held back until the next
+        # dispatch is launched (`DecodeBatcher._lane_iter`), and how
+        # many dispatches were launched ahead of one
+        self.held = None
+        self.early = 0
 
 
 class DecodeBatcher:
@@ -1046,13 +1051,14 @@ class DecodeBatcher:
 
     A dispatch is a WINDOW of decode steps (SERVING.md "Fused
     multi-step decode"; `DecodeSession.decode_fused`), and the lane
-    picks its trips from its own slot table: while a slot is free a
-    newcomer could be admitted on the next round, so the dispatch is
-    one trip; with every slot assigned nothing can join before a slot
-    ends, so it runs min(cap, smallest remaining budget of the live
-    slots) trips and ends on the round in which the first slot must
-    end, in-graph no later than the trip in which the first slot does
-    end (an early EOS).  Joins, leaves, cancels and deadline evictions
+    picks its trips from its own slot table: min(cap, smallest
+    remaining budget of the live slots), so a dispatch ends on the
+    round in which the first slot must end, in-graph no later than the
+    trip in which the first slot does end (an early EOS).  With every
+    slot assigned nothing can join before that; with a slot free a
+    newcomer waits for the window's end.  A full lane whose last
+    dispatch ended nobody launches the next one before it hands the
+    last one's tokens to the streams (`_lane_iter`).  Joins, leaves, cancels and deadline evictions
     happen at dispatch boundaries, per-token EOS/max-new cuts land in stream
     order from the returned token block, and a per-lane EWMA of step
     time clamps the trips so no deadline overshoots by more than one
@@ -1221,6 +1227,7 @@ class DecodeBatcher:
                             "inflight": len(l.assigned),
                             "queue": 0,
                             "batches": l.steps,
+                            "early_launches": l.early,
                             "rows": l.tokens})
             return out
 
@@ -1461,7 +1468,8 @@ class DecodeBatcher:
             req.buf = []
 
     def _emit_step_spans(self, lane, t0, t_draft_end, now, n_slots,
-                         rnd, accepted=None, tokens=None, trips=None):
+                         rnd, accepted=None, tokens=None, trips=None,
+                         early=False):
         """Per-round step spans: `serving/decode_step` always (now a
         per-DISPATCH span: `tokens` emitted and `trips` loop
         iterations ride as attrs, the tokens-per-dispatch axis of the
@@ -1469,7 +1477,9 @@ class DecodeBatcher:
         + `serving/verify` children are cut from the same contiguous
         monotonic stamps so they TILE the round exactly (draft end ==
         verify start).  `rnd` is the lane's dispatch count, the `round`
-        the session's `decode/*` spans of this dispatch inherited."""
+        the session's `decode/*` spans of this dispatch inherited;
+        `early` says the dispatch was launched ahead of the previous
+        one's delivery (`_lane_iter`)."""
         attrs = {"model": self._model_name or "", "replica": lane.index,
                  "slots": n_slots, "round": rnd}
         if t_draft_end is not None:
@@ -1481,13 +1491,14 @@ class DecodeBatcher:
                               accepted=accepted, **attrs)
         obs_tracing.stamp("serving/decode_step", t0, now, kind="serving",
                           parent="serving/lane_iter", tokens=tokens,
-                          trips=trips, **attrs)
+                          trips=trips, early=early, **attrs)
 
     def _emit_lane_iter(self, lane, t_iter, rnd, admits, emitted):
-        """`serving/lane_iter`: one iteration of the lane's loop that
-        prefilled or dispatched, from the admission take to the notify —
-        the parent of its `serving/prefill_compute`, `serving/decode_step`
-        and `serving/emit`; what it holds beyond them is the lane's own
+        """`serving/lane_iter`: one pass of the lane's loop that
+        prefilled or dispatched, from the admission take to the notify
+        (to the decision, where the delivery is held) — the parent of
+        its `serving/prefill_compute`, `serving/decode_step` and
+        `serving/emit`; what it holds beyond them is the lane's own
         host time."""
         obs_tracing.stamp("serving/lane_iter", t_iter, time.monotonic(),
                           kind="serving", model=self._model_name or "",
@@ -1543,7 +1554,12 @@ class DecodeBatcher:
             try:
                 if not self._lane_iter(lane):
                     return
-            except MeshMemberLost as e:
+            except Exception as e:
+                # what a pass held back behind the launch that failed
+                # reaches its streams before the failure does
+                self._deliver(lane)
+                if not isinstance(e, MeshMemberLost):
+                    raise
                 # one member of this lane's mesh is gone: the lane
                 # dies WHOLE — typed failures, never a wedge — and
                 # exits cleanly (no server_thread_death); the chaos
@@ -1552,21 +1568,45 @@ class DecodeBatcher:
                 return
 
     def _lane_iter(self, lane):
-        """One iteration of the continuous loop: admit + prefill, one
-        decode dispatch, stream bookkeeping.  Returns False to stop."""
+        """One pass of the continuous loop: admit + prefill, one decode
+        dispatch, then DECIDE what each slot got and whether it ends
+        (list work: no queue, no lock, no device call) and DELIVER that
+        to the streams (`_deliver`).  Returns False to stop.
+
+        The order of delivery and the next launch follows from what the
+        pass can see in its own slot table.  Where the decision ends
+        nobody, every slot is assigned and the lane is not speculative,
+        nothing can join or leave before the next dispatch, so its
+        arguments are known.  The delivery is then HELD (`lane.held`)
+        and the next pass launches first, delivers while the device
+        runs, then fetches (SERVING.md "Fused multi-step decode").  The
+        streams' handler threads, which a delivery wakes, then run
+        beside the device and not in front of the launch.  In every
+        other case (a finisher, a cancellation, an expiry, a free slot,
+        a speculative lane, the lane's first dispatch) the pass
+        delivers, finishes, and the next one admits, prefills and
+        launches, as ever."""
         sess = lane.session
         eos = self.predictor.eos_id
-        with self._cv:
-            while not lane.assigned and not self._admissible(lane):
-                if self._stopped:
-                    return False
-                self._cv.wait(0.1)
-            if self._stopped and not lane.assigned:
-                return False
+        early = lane.held is not None
+        if early:
+            # every slot assigned and nobody ended a moment ago: nothing
+            # to wait for, to admit or to drop at this boundary
             traced = obs_tracing.enabled()
             t_iter = time.monotonic() if traced else None
-            admits = self._take_admits_locked(lane) \
-                if self._admissible(lane) else []
+            admits = []
+        else:
+            with self._cv:
+                while not lane.assigned and not self._admissible(lane):
+                    if self._stopped:
+                        return False
+                    self._cv.wait(0.1)
+                if self._stopped and not lane.assigned:
+                    return False
+                traced = obs_tracing.enabled()
+                t_iter = time.monotonic() if traced else None
+                admits = self._take_admits_locked(lane) \
+                    if self._admissible(lane) else []
         # prefill OUTSIDE the lock: other lanes keep decoding
         for i, req in enumerate(admits):
             try:
@@ -1587,21 +1627,24 @@ class DecodeBatcher:
                 self._emit_lane_iter(lane, t_iter, lane.steps,
                                      len(admits), 0)
             return True
-        # dispatch-boundary housekeeping (SERVING.md "Fused multi-step
-        # decode"): drop cancelled/expired streams BEFORE burning a
-        # window on them; joins and leaves happen only here
-        nowb = time.monotonic()
-        for slot, req in list(lane.assigned.items()):
-            if req.stream.cancelled():
-                req.buf = []
-                self._finish(lane, slot, req, "cancelled")
-            elif req.deadline is not None and nowb > req.deadline:
-                self._expire(lane, slot, req, nowb)
-        if not lane.assigned:
-            if traced and admits:
-                self._emit_lane_iter(lane, t_iter, lane.steps,
-                                     len(admits), 0)
-            return True
+        if not early:
+            # dispatch-boundary housekeeping (SERVING.md "Fused
+            # multi-step decode"): drop cancelled/expired streams BEFORE
+            # burning a window on them; joins and leaves happen only
+            # here (a pass that launches early looked a moment ago, when
+            # it decided the last dispatch)
+            nowb = time.monotonic()
+            for slot, req in list(lane.assigned.items()):
+                if req.stream.cancelled():
+                    req.buf = []
+                    self._finish(lane, slot, req, "cancelled")
+                elif req.deadline is not None and nowb > req.deadline:
+                    self._expire(lane, slot, req, nowb)
+            if not lane.assigned:
+                if traced and admits:
+                    self._emit_lane_iter(lane, t_iter, lane.steps,
+                                         len(admits), 0)
+                return True
         n_act = len(lane.assigned)
         t0 = time.monotonic()
         # the same slow-worker chaos hook / deterministic per-step
@@ -1617,6 +1660,7 @@ class DecodeBatcher:
             time.sleep(host_delay)
         trips = 1
         rnd = lane.steps
+        accept = None
         # the dispatch is the region `serving/decode_step` covers (it is
         # stamped below, once the round's tokens are counted): the
         # session's `decode/*` spans find their parent and round here
@@ -1626,19 +1670,25 @@ class DecodeBatcher:
                     step_delay=delay,
                     draft_delay=_draft_chaos_delay(),
                     fused=self.spec_fused)
-                spec_round = sess.last_spec
+                if sess.last_spec:
+                    # per-round accept telemetry: k proposals per
+                    # occupied slot, counts[s]-1 of them accepted
+                    accept = (sess.spec_k * n_act,
+                              int(counts.sum()) - n_act)
             else:
-                # the window, from what the lane can see.  A slot free:
-                # a newcomer could join on the next round, so one trip.
-                # Every slot assigned: nothing can join before a slot
-                # ends, so run to the round in which the first live
-                # slot must end (its max_new / cache-room budget), the
-                # cap at most.  The deadline governor: the lane's EWMA
-                # step time clamps the trips so a deadlined stream
+                # the window, from what the lane can see: run to the
+                # round in which the first live slot must end (its
+                # max_new / cache-room budget), the cap at most.  With
+                # every slot assigned nothing can join before that; with
+                # a slot free a newcomer waits for the window's end, one
+                # window at most, and the streams that are live do not
+                # pay a dispatch of host work for every token (PERF.md
+                # section 6, PR 38).  The deadline governor: the lane's
+                # EWMA step time clamps the trips so a deadlined stream
                 # never overshoots by more than ~one dispatch
                 cap = self.fuse_steps
                 budget = np.zeros(self.n_slots, np.int32)
-                max_trips = cap if n_act == self.n_slots else 1
+                max_trips = cap
                 for slot, req in lane.assigned.items():
                     budget[slot] = min(req.max_new - len(req.gen),
                                        sess.room(slot), cap)
@@ -1646,32 +1696,30 @@ class DecodeBatcher:
                     if req.deadline is not None and lane.step_ewma:
                         max_trips = min(max_trips, int(
                             (req.deadline - t0) / lane.step_ewma))
-                toks2d, counts, trips = sess.decode_fused(
-                    cap, budget=budget, max_trips=max(max_trips, 1))
-                spec_round = False
+                sess.launch_fused(cap, budget=budget,
+                                  max_trips=max(max_trips, 1))
+                # the device runs this dispatch: the streams get the
+                # last one's tokens now, if they were held
+                self._deliver(lane)
+                toks2d, counts, trips = sess.fetch_fused()
                 if delay:
                     # the device-cost stand-in scales with the trips
                     # that actually ran (in-graph early exit included)
                     time.sleep(delay * trips)
         now = time.monotonic()
         lane.steps += 1
+        lane.early += early
         lane.last_step_t = now
         # EWMA seconds per logical step (per trip): the fused
         # deadline governor's clamp input
         per_step = (now - t0) / max(trips, 1)
         lane.step_ewma = per_step if lane.step_ewma is None \
             else 0.5 * lane.step_ewma + 0.5 * per_step
-        if self.metrics is not None:
-            self.metrics.decode_steps.add(trips)
-            if spec_round:
-                # per-round accept telemetry: k proposals per
-                # occupied slot, counts[s]-1 of them accepted
-                proposed = sess.spec_k * n_act
-                accepted = int(counts.sum()) - n_act
-                self.metrics.note_spec(proposed, accepted)
         self._note_degraded(lane)
+        # decide: what each slot got, who ends and why
         emitted = 0
-        for slot, req in list(lane.assigned.items()):
+        puts, ended = [], []
+        for slot, req in lane.assigned.items():
             # a spec round commits 1..k+1 tokens per slot, a window up
             # to its trips; consume them in stream order with per-token
             # EOS/max-new cuts so the emitted stream is that of
@@ -1690,32 +1738,58 @@ class DecodeBatcher:
             if req.stream.cancelled():
                 # client gone: nobody reads the flush — just free
                 req.buf = []
-                self._finish(lane, slot, req, "cancelled")
-                continue
-            if req.deadline is not None and now > req.deadline:
-                self._expire(lane, slot, req, now)
-                continue
-            if finished is None and sess.room(slot) <= 0:
-                finished = "length"
-            if finished is not None:
-                self._finish(lane, slot, req, finished)
+                ended.append((slot, req, "cancelled"))
+            elif req.deadline is not None and now > req.deadline:
+                ended.append((slot, req, "deadline"))
+            elif finished is not None or sess.room(slot) <= 0:
+                ended.append((slot, req, finished or "length"))
             elif len(req.buf) >= req.chunk:
-                req.stream._put_tokens(req.buf)
-                req.buf = []
+                puts.append(req)
+        lane.tokens += emitted
+        lane.held = (rnd, now, trips, emitted, puts, ended, accept)
+        # deliver now, unless the next dispatch's arguments are settled
+        if lane.spec or ended or len(lane.assigned) < self.n_slots:
+            self._deliver(lane, since=now)
         if traced:
-            obs_tracing.stamp("serving/emit", now, time.monotonic(),
+            self._emit_step_spans(
+                lane, t0, sess.last_draft_end if accept else None, now,
+                n_act, rnd, accepted=accept[1] if accept else None,
+                tokens=emitted, trips=trips, early=early)
+            self._emit_lane_iter(lane, t_iter, rnd, len(admits), emitted)
+        return True
+
+    def _deliver(self, lane, since=None):
+        """Hand the lane's newest decided dispatch (`lane.held`, if
+        any) to its streams: the chunks due, then the terminal
+        transitions (`_finish`'s flush comes after what was due before
+        it, so a stream's frames stay in order), the dispatch's metrics
+        and its `serving/emit` span, which starts at `since` (the
+        dispatch's end, for a delivery made at once) or now (one that
+        was held: it then lies inside the NEXT `serving/decode_step`)."""
+        if lane.held is None:
+            return
+        (rnd, now, trips, emitted, puts, ended, accept), lane.held = \
+            lane.held, None
+        traced = obs_tracing.enabled()
+        if since is None and traced:
+            since = time.monotonic()
+        for req in puts:
+            req.stream._put_tokens(req.buf)
+            req.buf = []
+        for slot, req, reason in ended:
+            if reason == "deadline":
+                self._expire(lane, slot, req, now)
+            else:
+                self._finish(lane, slot, req, reason)
+        if traced:
+            obs_tracing.stamp("serving/emit", since, time.monotonic(),
                               kind="serving", parent="serving/lane_iter",
                               replica=lane.index, round=rnd,
                               tokens=emitted)
-            self._emit_step_spans(
-                lane, t0,
-                sess.last_draft_end if spec_round else None, now,
-                n_act, rnd,
-                accepted=(int(counts.sum()) - n_act)
-                if spec_round else None,
-                tokens=emitted, trips=trips)
-        lane.tokens += emitted
         if self.metrics is not None:
+            self.metrics.decode_steps.add(trips)
+            if accept:
+                self.metrics.note_spec(*accept)
             # per-dispatch accounting: the tokens-per-dispatch
             # histogram is the direct readout of the fused-decode
             # amortization (TPD ~1 at N=1, ~N when fused)
@@ -1724,9 +1798,6 @@ class DecodeBatcher:
                 self.metrics.note_tokens(emitted)
         with self._cv:
             self._cv.notify_all()
-        if traced:
-            self._emit_lane_iter(lane, t_iter, rnd, len(admits), emitted)
-        return True
 
     # ------------------------------------------------------------------
     # lifecycle

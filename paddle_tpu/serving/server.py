@@ -193,7 +193,7 @@ class InferenceServer:
                             # chunked reply: the stream handler owns the
                             # socket until its final frame (or the
                             # connection dies — which cancels the
-                            # stream so its slot frees within one step)
+                            # stream so its slot frees within one dispatch)
                             outer._handle_infer_stream(msg, self.request)
                             continue
                         try:
@@ -571,7 +571,7 @@ class InferenceServer:
         "finish_reason", "new_tokens", ...} or {"error", "code",
         "done": True}).  Every frame carries the trace_id.  A dead
         client connection (send failure) CANCELS the stream, so its
-        decode slot frees — and zeroes — within one step."""
+        decode slot frees — and zeroes — within one dispatch."""
         trace_id = str(msg.get("trace_id") or obs_tracing.new_trace_id())
         stream = None
         try:
@@ -625,7 +625,7 @@ class InferenceServer:
         except (ConnectionError, EOFError, OSError, WireError):
             # client went away mid-stream: evict the request so its
             # slot is reclaimed for waiting traffic (chaos scenario
-            # decode-disconnect pins the one-step bound)
+            # decode-disconnect pins the bound: two dispatches)
             stream.cancel()
             raise
 

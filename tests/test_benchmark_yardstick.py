@@ -83,6 +83,60 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       # PR 35's cell, whose latent kernel shares the stream's
                       # rule and counter (`kv_last_block`, `_kv_stream`)
                       "pangu_decode_saturated"]}
-    # appended, not inserted: only PR 35's five readers stand behind it
+    # appended, not inserted: only PR 35's five readers and PR 38's one
+    # stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 6
+        manifest["per_layer"]) - 7
+
+
+# ---------------------------------------------------------------------------
+# benchmark/layers/decode_early_launch_share.py on a recorded span list
+# (PR 38: the one file it adds under benchmark/ is the reader)
+# ---------------------------------------------------------------------------
+
+def _step(t0, **attrs):
+    return {"name": "serving/decode_step", "t0": t0, "t1": t0 + 0.02,
+            "attrs": dict(attrs, trips=7, tokens=224)}
+
+
+# a wave of a full lane: the dispatch after the admissions, three launched
+# ahead of the delivery before them, and the one-trip dispatch of a lane
+# with a slot free
+_WAVE = [_step(1.0, early=False), _step(1.1, early=True),
+         _step(1.2, early=True), _step(1.3, early=True),
+         _step(1.4, early=False)]
+
+
+@pytest.mark.parametrize("case,spans,want", [
+    ("a_wave", _WAVE, 60.0),
+    ("never", [_step(1.0, early=False), _step(2.0, early=False)], 0.0),
+    ("always", [_step(1.0, early=True)], 100.0),
+    # dispatches outside the window and other spans do not count
+    ("only_the_windows_dispatches",
+     _WAVE + [_step(9.0, early=True), _fetch(1.0, trips=7, early=True),
+              {"name": "serving/emit", "t0": 1.1, "t1": 1.11,
+               "attrs": {"early": True}}], 60.0),
+    # the parent's program stamps no such attribute: no reading, and a span
+    # without it beside spans with it is left out
+    ("the_parents_spans", [_step(1.0), _step(2.0)], None),
+    ("mixed", [_step(1.0), _step(2.0, early=True),
+               _step(3.0, early=False)], 50.0),
+    ("no_dispatch", _OTHERS, None)])
+def test_decode_early_launch_share_reader(case, spans, want):
+    read = bench_run.load_reader("decode_early_launch_share")
+    got = read(spans, None, {"window": (0.5, 5.0)})
+    assert got == (want if want is None else pytest.approx(want, rel=1e-12))
+
+
+def test_decode_early_launch_share_is_declared_for_the_decode_cells():
+    manifest = bench_run.load_json(bench_run.MANIFEST)
+    assert manifest["per_layer"][-1] == {
+        "name": "decode_early_launch_share", "unit": "%",
+        "better": "higher", "source": "program_span", "layer": "scheduler",
+        "moves": "tokens_per_s",
+        "workloads": ["gpt2s_decode_saturated", "gpt2s_decode_deep",
+                      "olmoe_decode_saturated", "lfm2_decode_saturated",
+                      "pangu_decode_saturated"]}
+    # the cells that report it are those that report what it moves
+    e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
+    assert manifest["per_layer"][-1]["workloads"] == e2e["workloads"]

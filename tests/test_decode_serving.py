@@ -532,7 +532,9 @@ class TestServerStream:
         server = InferenceServer().start()
         cli = ServingClient(server.endpoint)
         try:
-            cli.load_model("lm", artifact, decode_slots=2)
+            # one trip a dispatch: a window's tokens go out as one frame
+            # however small the chunk asked for
+            cli.load_model("lm", artifact, decode_slots=2, fuse_steps=1)
             ref, reason = greedy_decode(predictor, [5, 9, 3], 9)
             # grouped flush: every chunk <= 4 tokens, nothing lost
             chunks = list(cli.infer_stream("lm", [5, 9, 3],
@@ -881,13 +883,13 @@ def traced():
     obs_tracing.set_enabled(was)
 
 
-def _rule(budgets, n_slots, cap):
+def _rule(budgets, cap):
     """What the rule dispatches for streams admitted together with
     `budgets` decode tokens left each: [trips] until all have ended."""
     left, out = list(budgets), []
     while any(left):
         live = [b for b in left if b]
-        trips = min(cap, min(live)) if len(live) == n_slots else 1
+        trips = min(cap, min(live))
         out.append(trips)
         left = [max(b - trips, 0) for b in left]
     return out
@@ -923,10 +925,11 @@ class TestWindowRule:
         (None, (20, 13)), (None, (9, 9)), (4, (20, 13)), (None, (3, 30))])
     def test_every_slot_assigned_runs_to_the_first_end(
             self, endless, traced, cap, max_new):
-        """All slots assigned: each dispatch runs min(cap, smallest
-        remaining budget) trips, so a window ends on the round in which
-        the first slot must end; once a slot is free (nothing queued to
-        refill it) every dispatch is one trip."""
+        """Each dispatch runs min(cap, smallest remaining budget of the
+        live slots) trips, so a window ends on the round in which the
+        first slot must end; a slot that is free afterwards (nothing
+        queued to refill it) changes nothing for the stream that is
+        left."""
         from paddle_tpu.inference.decode import STEP_WINDOW
         b = DecodeBatcher(endless, n_slots=2, fuse_steps=cap)
         assert b.fuse_steps == (cap or STEP_WINDOW)
@@ -941,29 +944,28 @@ class TestWindowRule:
             assert out == greedy_decode(endless, [5, 9, 3], m)[0]
         steps = _dispatches()
         # the prefill emitted each stream's first token
-        want = _rule([m - 1 for m in max_new], 2, b.fuse_steps)
+        want = _rule([m - 1 for m in max_new], b.fuse_steps)
         assert [s["attrs"]["trips"] for s in steps] == want
         assert max(want) > 1 and sum(s["attrs"]["tokens"] for s in steps) \
             == sum(max_new) - 2
 
-    def test_a_free_slot_means_one_trip(self, endless, traced):
-        """A slot free: a newcomer could be admitted on the next round,
-        so no dispatch runs more than one trip and a late joiner waits
-        one round, not a window."""
+    def test_a_free_slot_runs_windows_too(self, endless, traced):
+        """A slot free: the live streams still get windows (a dispatch
+        of host work for every token is what they would pay otherwise),
+        and a late joiner waits for the end of one window, no longer."""
         b = DecodeBatcher(endless, n_slots=3)
         try:
             with b._cv:
                 first = [b.submit([5, 9, 3], max_new_tokens=12),
                          b.submit([7, 2], max_new_tokens=10)]
-            # joins mid-flight into the free slot: the lane is then full
-            # and may run windows, which is the rule, not a leak
             for s in first:
                 s.result(timeout=60)
         finally:
             b.close()
         steps = _dispatches()
-        assert steps and {s["attrs"]["trips"] for s in steps} == {1}
-        assert {s["attrs"]["slots"] for s in steps} <= {1, 2}
+        assert [s["attrs"]["trips"] for s in steps] == \
+            _rule([11, 9], b.fuse_steps) == [8, 1, 2]
+        assert [s["attrs"]["slots"] for s in steps] == [2, 2, 1]
 
     def test_cancel_inside_a_window_is_honoured_at_its_boundary(
             self, endless, traced):

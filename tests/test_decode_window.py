@@ -124,11 +124,16 @@ class _Served(object):
         return out
 
 
-def _trips():
-    """The trips of the lane's dispatches, in dispatch order."""
+def _step_attr(name):
+    """Attribute `name` of the lane's dispatches (`serving/decode_step`),
+    in dispatch order."""
     steps = sorted(obs_tracing.recent_spans(name="serving/decode_step"),
                    key=lambda s: s["attrs"]["round"])
-    return [s["attrs"]["trips"] for s in steps]
+    return [s["attrs"][name] for s in steps]
+
+
+def _trips():
+    return _step_attr("trips")
 
 
 def _traced():
@@ -294,3 +299,374 @@ def test_a_warm_lane_compiles_nothing_whatever_the_window(models):
                 assert events == []
     finally:
         on[0] = False
+
+
+# ---------------------------------------------------------------------------
+# The order of a dispatch's delivery and the next launch (PR 38): a full lane
+# whose last dispatch ended nobody launches the next one FIRST, whatever
+# waits behind it, and hands the tokens to the streams
+# while the device runs; every other pass delivers, finishes, admits, prefills
+# and launches, as ever.
+# ---------------------------------------------------------------------------
+
+from paddle_tpu.serving import batcher as batcher_mod            # noqa: E402
+from paddle_tpu.serving.batcher import DecodeBatcher             # noqa: E402
+
+
+class _Recorded(object):
+    """A `DecodeBatcher` whose lane's session and whose streams write what
+    they are asked into one list, in the order asked: ("launch", k) and
+    ("fetch", k) of the k-th step dispatch, ("put", stream, n tokens),
+    ("finish", stream, reason), ("fail", stream, error type).
+    `at_fetch[k]()` runs inside the k-th fetch, before the lane sees its
+    result."""
+
+    def __init__(self, monkeypatch, artifact, slots, **kw):
+        self.log = log = []
+        self.pred = GenerativePredictor(artifact)
+        self.batcher = DecodeBatcher(self.pred, n_slots=slots, **kw)
+        self.lane = self.batcher._lanes[0]
+        self.at_fetch = at_fetch = {}
+        sess, n = self.lane.session, [0, 0]
+        launch = getattr(sess, "launch_fused", None)
+        fetch = getattr(sess, "fetch_fused", None)
+
+        def launch_fused(*a, **k):
+            log.append(("launch", n[0]))
+            n[0] += 1
+            return launch(*a, **k)
+
+        def fetch_fused():
+            at_fetch.get(n[1], lambda: None)()
+            out = fetch()
+            log.append(("fetch", n[1]))
+            n[1] += 1
+            return out
+        if not self.lane.spec:
+            sess.launch_fused, sess.fetch_fused = launch_fused, fetch_fused
+        stream = batcher_mod.DecodeStream
+        put, finish, fail = stream._put_tokens, stream._finish, stream._fail
+
+        def _put_tokens(self_, toks):
+            log.append(("put", self_, len(toks)))
+            return put(self_, toks)
+
+        def _finish(self_, reason, **k):
+            log.append(("finish", self_, reason))
+            return finish(self_, reason, **k)
+
+        def _fail(self_, exc):
+            log.append(("fail", self_, type(exc).__name__))
+            return fail(self_, exc)
+        monkeypatch.setattr(stream, "_put_tokens", _put_tokens)
+        monkeypatch.setattr(stream, "_finish", _finish)
+        monkeypatch.setattr(stream, "_fail", _fail)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self.batcher.close(drain=False, timeout=10.0)
+
+    def submit_together(self, requests, then=None, waiting=0, **kw):
+        """Every request queued before the lane looks: it admits as many
+        as it has slots in ONE pass.  `then(streams)` runs before it
+        looks, too.  Behind them wait `waiting` requests of ONE token
+        each: they end in their prefill and never ride a dispatch."""
+        with self.batcher._cv:
+            streams = [self.batcher.submit(p, max_new_tokens=m, **kw)
+                       for p, m in requests]
+            for _ in range(waiting):
+                self.batcher.submit(PROMPTS[3], max_new_tokens=1)
+            if then is not None:
+                then(streams)
+        return streams
+
+    def between(self, k):
+        """(what the streams were handed between fetch k-1 and launch k,
+        between launch k and fetch k), first elements dropped: the kinds
+        and streams."""
+        at = {e: i for i, e in enumerate(self.log)
+              if e[0] in ("launch", "fetch")}
+        lo = at[("fetch", k - 1)] if k else -1
+        mid, hi = at[("launch", k)], at[("fetch", k)]
+        mine = [(i, e) for i, e in enumerate(self.log)
+                if e[0] in ("put", "finish", "fail")]
+        return ([e for i, e in mine if lo < i < mid],
+                [e for i, e in mine if mid < i < hi])
+
+
+def _early():
+    return _step_attr("early")
+
+
+def _wait_all(streams, timeout=120):
+    for s in streams:
+        s._done.wait(timeout)
+        assert s.done()
+
+
+@pytest.mark.parametrize("waiting", [0, 1, 2])
+def test_a_full_lane_launches_before_it_delivers(models, monkeypatch,
+                                                 waiting):
+    """Two slots, two streams of three windows, nobody ends before the
+    last, with nobody, one request or a lane's worth waiting behind them:
+    dispatch 1 and 2 are launched BEFORE any token of the dispatch before
+    them reaches a stream, and those tokens arrive before the fetch; the
+    counter and the span attribute count the same dispatches."""
+    endless = models[0]
+    _traced()
+    with _Recorded(monkeypatch, endless, 2) as r:
+        a, b = r.submit_together([(PROMPTS[0], 1 + 3 * W),
+                                  (PROMPTS[1], 1 + 3 * W)], waiting=waiting)
+        _wait_all([a, b])
+        assert _trips() == [W, W, W]
+        assert _early() == [False, True, True]
+        stats, = r.batcher.replica_stats()
+        assert stats["early_launches"] == 2 and stats["batches"] == 3
+        # the first dispatch follows the admissions: their prefills' first
+        # tokens, then the launch
+        before, during = r.between(0)
+        assert [e[0] for e in before] == ["put", "put"] and not during
+        for k in (1, 2):
+            before, during = r.between(k)
+            assert before == []
+            assert sorted(e[:1] + e[2:] for e in during) == \
+                [("put", W), ("put", W)]
+            assert {e[1] for e in during} == {a, b}
+        # the last dispatch ended both: delivered at once
+        tail = [e for e in r.log[r.log.index(("fetch", 2)) + 1:]
+                if e[1] in (a, b)]
+        assert sorted(e[0] for e in tail) == ["finish", "finish",
+                                              "put", "put"]
+        assert r.lane.held is None
+    for s, p in ((a, PROMPTS[0]), (b, PROMPTS[1])):
+        assert s.tokens == greedy_decode(r.pred, p, 1 + 3 * W)[0]
+
+
+def _order_finisher(r):
+    """A ends with dispatch 0 and C takes its slot: dispatch 1 runs a full
+    lane, yet it follows a finisher, an admission and a prefill."""
+    a, b, c = r.submit_together([(PROMPTS[0], 1 + W), (PROMPTS[1], 1 + 4 * W),
+                                 (PROMPTS[2], 1 + 2 * W)])
+    _wait_all([a, b, c])
+    assert _early() == [False, False, True, False]
+    assert _trips() == [W] * 4 and _step_attr("slots") == [2, 2, 2, 1]
+    before, during = r.between(1)
+    assert not during and ("finish", a, "length") in before
+    assert r.between(2)[0] == []
+    return 1
+
+
+def _order_cancelled(r):
+    a, b = r.submit_together(
+        [(PROMPTS[0], 1 + 4 * W), (PROMPTS[1], 1 + 2 * W + 2)],
+        then=lambda streams: r.at_fetch.update({1: streams[0].cancel}))
+    _wait_all([a, b])
+    assert a.finish_reason == "cancelled"
+    assert ("finish", a, "cancelled") in r.between(2)[0]
+    return 1
+
+
+def _order_expired(r):
+    def expire():
+        req, = [q for q in r.lane.assigned.values() if q.stream is a]
+        req.deadline = time.monotonic() - 1e-3
+    r.at_fetch[1] = expire
+    a, b = r.submit_together([(PROMPTS[0], 1 + 4 * W),
+                              (PROMPTS[1], 1 + 2 * W + 2)])
+    _wait_all([a, b])
+    # what the stream had been given, then the typed failure
+    mine = [e for e in r.between(2)[0] if e[1] is a]
+    assert [e[0] for e in mine] == ["put", "fail"]
+    assert mine[-1][2] == "DeadlineExceeded"
+    return 1
+
+
+def _order_free_slot(r):
+    """One stream on two slots: windows (a newcomer would wait for one to
+    end), each delivered before the next is launched."""
+    a, = r.submit_together([(PROMPTS[0], 1 + 2 * W + 2)])
+    _wait_all([a])
+    assert _trips() == [W, W, 2] and _step_attr("slots") == [1, 1, 1]
+    return 0
+
+
+@pytest.mark.parametrize("case", ["finisher", "cancelled", "expired",
+                                  "free_slot"])
+def test_any_other_pass_delivers_before_it_launches(models, monkeypatch,
+                                                    case):
+    """A dispatch that follows a finisher, a cancellation or an expiry, or
+    runs with a slot free, is launched AFTER the delivery of the one before
+    it, and a
+    cancelled or expired stream leaves at the first dispatch boundary after
+    the event (the next dispatch runs without it)."""
+    endless = models[0]
+    _traced()
+    with _Recorded(monkeypatch, endless, 2) as r:
+        want = globals()["_order_" + case](r)
+        early = _early()
+        stats, = r.batcher.replica_stats()
+        assert stats["early_launches"] == sum(early) == want
+        for k, flag in enumerate(early):
+            before, during = r.between(k)
+            assert not (during if not flag else before), (k, flag)
+        if case in ("cancelled", "expired"):
+            # dispatch 1 was launched early, saw the event and delivered at
+            # once; dispatch 2 runs the one stream that is left
+            assert early[:3] == [False, True, False]
+            assert _step_attr("slots")[:3] == [2, 2, 1]
+
+
+def test_a_speculative_lane_keeps_the_old_order(models, monkeypatch):
+    endless = models[0]
+    if "olmoe" in endless:
+        pytest.skip("a routed stack has no speculative session")
+    _traced()
+    with _Recorded(monkeypatch, endless, 1,
+                   draft=GenerativePredictor(endless), spec_k=2) as r:
+        assert r.lane.spec
+        a, = r.submit_together([(PROMPTS[0], 1 + 2 * W)])
+        _wait_all([a])
+        early = _early()
+        assert early and not any(early)
+        assert r.batcher.replica_stats()[0]["early_launches"] == 0
+        assert r.lane.held is None
+    assert a.tokens == greedy_decode(r.pred, PROMPTS[0], 1 + 2 * W)[0]
+
+
+def _stack_artifact(name, root):
+    if name in BLOCKS:
+        return build_tiny_decode_model(str(root / name), eos_id=-1,
+                                       **BLOCKS[name])
+    if name == "lfm2":
+        from tests.test_decode_hybrid import LFM2_BLOCK, TINY
+        return build_tiny_decode_model(
+            str(root / name), block=LFM2_BLOCK, **dict(TINY, eos_id=-1))
+    from paddle_tpu.inference.decode import save_decode_model
+    from tests.test_mla_decode import META, _drawn
+    meta = dict(META, eos_id=-1)
+    return save_decode_model(str(root / name), _drawn(meta), meta)
+
+
+@pytest.mark.parametrize("stack", ["gpt2", "olmoe", "lfm2", "latent"])
+def test_streams_of_the_new_order_equal_the_plain_stream(stack, tmp_path):
+    """Token for token: a full lane under windows (launch, deliver, fetch),
+    the same lane pinned to one trip a dispatch, and `greedy_decode`, for
+    each kind of stack the suites build."""
+    artifact = _stack_artifact(stack, tmp_path)
+    pred = GenerativePredictor(artifact)
+    requests = [(PROMPTS[0], 1 + 2 * W + 3), (PROMPTS[1], 1 + 3 * W)]
+    want = [greedy_decode(pred, p, m)[0] for p, m in requests]
+    for cap in (None, 1):
+        b = DecodeBatcher(GenerativePredictor(artifact), n_slots=2,
+                          fuse_steps=cap)
+        try:
+            with b._cv:
+                streams = [b.submit(p, max_new_tokens=m)
+                           for p, m in requests]
+            got = [s.result(timeout=120)[0].tolist() for s in streams]
+            assert b.replica_stats()[0]["early_launches"] >= 2
+        finally:
+            b.close(drain=False, timeout=10.0)
+        assert got == want, (stack, cap)
+
+
+def test_a_disconnect_frees_its_slot_at_a_dispatch_boundary(models):
+    """Through the server: two clients on a full lane that launches ahead
+    of its deliveries (two more wait behind them), one goes away
+    mid-stream.  Its slot goes to a waiting request long before the other
+    stream ends, that stream is untouched, and the waiting requests are
+    served from clean rows."""
+    endless = models[0]
+    set_dispatch_delay(0.005)
+    _traced()
+    done_at = {}
+
+    def client(s, key, prompt, n):
+        done_at[key] = (s.stream(prompt, n), time.monotonic())
+    with _Served(endless, 2, None) as s:
+        whole = s.stream(PROMPTS[1], 5 * W)[0]
+        threads = [threading.Thread(target=client,
+                                    args=(s, "stays", PROMPTS[0], 12 * W))]
+        threads[0].start()
+        cli = ServingClient(s.server.endpoint)
+        it = cli.infer_stream("lm", PROMPTS[1], max_new_tokens=12 * W)
+        seen = list(next(it))                   # admitted: the lane is full
+        for key, prompt in (("w1", PROMPTS[2]), ("w2", PROMPTS[3])):
+            threads.append(threading.Thread(target=client,
+                                            args=(s, key, prompt, 5)))
+            threads[-1].start()
+        deadline = time.monotonic() + 30
+        while len(s.batcher._pending) < 2 and time.monotonic() < deadline:
+            time.sleep(0.002)
+        mark = len(seen)
+        for chunk in it:
+            seen += chunk
+            if len(seen) > mark + 2 * W:
+                break
+        it.close()
+        cli.close()
+        for t in threads:
+            t.join(timeout=120)
+        assert any(_early())
+        pred = GenerativePredictor(endless)
+    assert seen == whole[:len(seen)] or seen[:5 * W] == whole
+    assert min(done_at["w1"][1], done_at["w2"][1]) < done_at["stays"][1]
+    for key, prompt, n in (("stays", PROMPTS[0], 12 * W),
+                           ("w1", PROMPTS[2], 5), ("w2", PROMPTS[3], 5)):
+        assert done_at[key][0][0] == greedy_decode(pred, prompt, n)[0]
+
+
+def test_a_held_delivery_and_a_finish_keep_a_streams_frames_in_order(
+        models, monkeypatch):
+    """One slot, chunks of more tokens than a window: the second dispatch
+    fills a chunk, which is held back behind the third's launch, and the
+    third's `_finish` flushes what is left: the chunk, the rest, then
+    `done`, the plain stream when joined."""
+    endless = models[0]
+    n = 1 + 2 * W + 3
+    with _Recorded(monkeypatch, endless, 1) as r:
+        a, = r.submit_together([(PROMPTS[0], n)], chunk_tokens=W + 3)
+        events = list(a.events(timeout=120))
+        assert r.batcher.replica_stats()[0]["early_launches"] == 2
+    assert [k for k, _ in events[:-1]] == ["tokens"] * (len(events) - 1)
+    assert events[-1] == ("done", "length")
+    assert [len(c) for _, c in events[:-1]] == [1 + 2 * W, 3]
+    assert [t for _, c in events[:-1] for t in c] == \
+        greedy_decode(r.pred, PROMPTS[0], n)[0]
+
+
+@pytest.mark.filterwarnings(
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+@pytest.mark.parametrize("lost", [True, False])
+def test_a_failed_launch_delivers_what_was_held_before_it_fails(
+        models, monkeypatch, lost):
+    """The launch of dispatch 2 fails while dispatch 1's tokens are held.
+    The lane's mesh died: the streams get those tokens, then the typed
+    failure, in that order.  Any other exception kills the lane's thread,
+    as it always did, and the tokens decided before it still reach their
+    stream."""
+    from paddle_tpu.parallel.mesh import MeshMemberLost
+    endless = models[0]
+    with _Recorded(monkeypatch, endless, 1) as r:
+        sess, launch = r.lane.session, r.lane.session.launch_fused
+
+        def failing(*a, **k):
+            if ("fetch", 1) in r.log:
+                raise MeshMemberLost("member gone") if lost \
+                    else RuntimeError("a chaos hook")
+            return launch(*a, **k)
+        sess.launch_fused = failing
+        a, = r.submit_together([(PROMPTS[0], 1 + 4 * W)])
+        r.batcher._threads[0].join(timeout=120)
+        assert not r.batcher._threads[0].is_alive()
+        assert r.lane.held is None and bool(r.lane.dead) == lost
+        events = []
+        while not a._q.empty():
+            events.append(a._q.get())
+    assert [k for k, _ in events] == ["tokens"] * 3 + ["error"] * lost
+    if lost:
+        assert isinstance(events[-1][1], MeshMemberLost)
+    assert [t for k, c in events if k == "tokens" for t in c] == \
+        greedy_decode(r.pred, PROMPTS[0], 1 + 2 * W)[0]

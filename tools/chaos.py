@@ -951,7 +951,10 @@ def scenario_decode_disconnect(verbose=True, kv_dtype=None):
             "decode_steps", 0)
 
     try:
-        boot.load_model("lm", md, decode_slots=2, kv_cache_dtype=kv_dtype)
+        # the classic loop, one step a dispatch: windows have their own
+        # scenario (decode-disconnect-fused)
+        boot.load_model("lm", md, decode_slots=2, kv_cache_dtype=kv_dtype,
+                        fuse_steps=1)
         # slow, deterministic steps so "mid-stream" is unambiguous
         set_dispatch_delay(step_ms / 1000.0)
 

@@ -45,7 +45,8 @@ _MAX_FRAME = int(os.environ.get("PADDLE_TPU_MAX_RPC_FRAME", 1 << 28))
 def _send_msg(sock, obj):
     """Typed native wire frame (native/wire.cc) with a u64 length prefix —
     no pickle anywhere on the socket path (the reference's typed
-    VariableMessage serde, grpc_serde.cc, not arbitrary object streams)."""
+    VariableMessage serde, grpc_serde.cc, not arbitrary object streams).
+    Returns the bytes it put on the socket."""
     payload = _wire_encode(obj)
     if len(payload) > _MAX_FRAME:
         # the peer's receive loop enforces the same cap; failing here
@@ -55,6 +56,7 @@ def _send_msg(sock, obj):
             "PADDLE_TPU_MAX_RPC_FRAME on both ends to raise it"
             % (len(payload), _MAX_FRAME))
     sock.sendall(_HDR.pack(len(payload)) + payload)
+    return _HDR.size + len(payload)
 
 
 def _recv_exact(sock, n):

@@ -2864,11 +2864,9 @@ class DecodeSession:
         device-resident under every placement, so under the default one
         it is the small arguments `_put` leaves as numpy).  `cache` is
         DONATED to the call wherever the placement allows
-        (`_phase_jit`): the caller replaces `_kc`/`_vc` by the results,
-        and `donated_bytes` on the launch span counts the tables the
-        call consumed (0 where it could not donate: a gather-mode
-        mesh).  A call that raises after consuming them leaves this
-        session dead (`_mark_dead`)."""
+        (`_phase_jit`; not on a gather-mode mesh): the caller replaces
+        `_kc`/`_vc` by the results.  A call that raises after consuming
+        them leaves this session dead (`_mark_dead`)."""
         self._alive()
         state = self.predictor._state
         try:
@@ -2886,13 +2884,10 @@ class DecodeSession:
         # stamped by the `_fetch` that ends this call, which knows how
         # many trips a step's dispatch ran
         self._launched = (
-            ("decode/put", t0, t1,
-             {"bytes": _nbytes(small) - _host_nbytes(args)}),
+            ("decode/put", t0, t1, {}),
             ("decode/launch", t1, t2,
              {"h2d_bytes": self.predictor.state_host_bytes()
-              + _host_nbytes(cache) + _host_nbytes(args),
-              "donated_bytes": sum(int(c.nbytes) for c in cache
-                                   if c.is_deleted())}))
+              + _host_nbytes(cache) + _host_nbytes(args)}))
         return out
 
     def prefill(self, slot, tokens):

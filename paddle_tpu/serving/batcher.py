@@ -884,6 +884,9 @@ class DecodeStream:
         self.obs_info = None     # stage timing attribution, at finish
         self.finish_reason = None
         self._q = queue_mod.Queue()
+        # one entry a "tokens" item of `_q`, in its order: the lane's
+        # (t_made, t_put) stamps of that chunk, None with tracing off
+        self._stamps = collections.deque()
         self._tokens = []
         self._done = threading.Event()
         self._cancel = threading.Event()
@@ -891,8 +894,12 @@ class DecodeStream:
 
     # -- lane side ------------------------------------------------------
 
-    def _put_tokens(self, toks):
+    def _put_tokens(self, toks, stamps=None):
+        """`stamps`, with tracing on: (end of the dispatch that made the
+        chunk, now), on time.monotonic(); the stream's handler takes
+        them beside the chunk (`take_stamps`)."""
         self._tokens.extend(int(t) for t in toks)
+        self._stamps.append(stamps)
         self._q.put(("tokens", [int(t) for t in toks]))
 
     def _finish(self, reason, obs_info=None):
@@ -935,6 +942,13 @@ class DecodeStream:
             yield ev
             if ev[0] != "tokens":
                 return
+
+    def take_stamps(self):
+        """The lane's stamps of the "tokens" event `events()` handed out
+        last, for the one consumer that asks after EVERY such event (the
+        server's stream handler): (t_made, t_put), or None where the
+        lane took none."""
+        return self._stamps.popleft() if self._stamps else None
 
     def __iter__(self):
         """Token-chunk iterator; raises the stream's typed error at the
@@ -1375,17 +1389,34 @@ class DecodeBatcher:
             "replica": lane.index,
         }
 
-    def _finish(self, lane, slot, req, reason, exc=None):
+    def _finish(self, lane, slot, req, reason, exc=None, made=None,
+                at=None):
         """Terminal transition: flush, emit spans/metrics, free (and
-        therefore ZERO) the slot so the next admit starts clean."""
+        therefore ZERO) the slot so the next admit starts clean.  With
+        tracing on it is one `serving/finish` span, the slot's release
+        a `serving/slot_free` inside it.  `made` is the end of the
+        dispatch (or prefill) whose tokens the flush carries; `at` =
+        (round, order, enders) places a finish among those of one
+        delivery (`_deliver`: its span then hangs under
+        `serving/emit`), None anywhere else."""
         now = time.monotonic()
+        traced = obs_tracing.enabled()
+        rnd, order, enders = at or (lane.steps, 0, 1)
         if req.buf:
-            req.stream._put_tokens(req.buf)
+            req.stream._put_tokens(
+                req.buf,
+                (now if made is None else made, now) if traced else None)
             req.buf = []
         if slot is not None:
+            t_free = time.monotonic() if traced else None
             lane.session.free(slot)
             lane.assigned.pop(slot, None)
-        if obs_tracing.enabled():
+            if traced:
+                obs_tracing.stamp(
+                    "serving/slot_free", t_free, time.monotonic(),
+                    kind="serving", trace_id=req.trace_id,
+                    parent="serving/finish", slot=slot, round=rnd)
+        if traced:
             self._emit_request_spans(req, lane, now)
         info = self._obs_info(req, lane, now)
         info["finish_reason"] = reason
@@ -1402,8 +1433,17 @@ class DecodeBatcher:
                     latency_ms=info["server_ms"],
                     queue_wait_ms=info["queue_wait_ms"])
             req.stream._finish(reason, obs_info=info)
+        if traced:
+            obs_tracing.stamp(
+                "serving/finish", now, time.monotonic(), kind="serving",
+                trace_id=req.trace_id,
+                parent="serving/lane_iter" if at is None
+                else "serving/emit",
+                replica=lane.index, round=rnd,
+                slot=-1 if slot is None else slot, reason=reason,
+                order=order, enders=enders)
 
-    def _expire(self, lane, slot, req, now):
+    def _expire(self, lane, slot, req, now, **place):
         """Deadline eviction — in queue, at prefill, or MID-DECODE: the
         deadline covers in-decode time (the PR 8 admission-control
         fix), so a streaming request past it frees its slot within one
@@ -1420,7 +1460,7 @@ class DecodeBatcher:
                         if req.deadline is not None else None)
         self._finish(lane, slot, req, "deadline", exc=DeadlineExceeded(
             "deadline passed after %.1f ms (%d tokens generated)"
-            % ((now - req.enqueued) * 1e3, len(req.gen))))
+            % ((now - req.enqueued) * 1e3, len(req.gen))), **place)
 
     def _prefill(self, lane, req):
         """Admit one request into a free slot: prefill the prompt,
@@ -1460,11 +1500,13 @@ class DecodeBatcher:
         req.buf.append(first)
         lane.assigned[slot] = req
         if first == self.predictor.eos_id:
-            self._finish(lane, slot, req, "eos")
+            self._finish(lane, slot, req, "eos", made=req.t_first)
         elif req.max_new <= 1 or sess.room(slot) <= 0:
-            self._finish(lane, slot, req, "length")
+            self._finish(lane, slot, req, "length", made=req.t_first)
         elif len(req.buf) >= req.chunk:
-            req.stream._put_tokens(req.buf)
+            req.stream._put_tokens(
+                req.buf, (req.t_first, time.monotonic())
+                if obs_tracing.enabled() else None)
             req.buf = []
 
     def _emit_step_spans(self, lane, t0, t_draft_end, now, n_slots,
@@ -1765,7 +1807,10 @@ class DecodeBatcher:
         it, so a stream's frames stay in order), the dispatch's metrics
         and its `serving/emit` span, which starts at `since` (the
         dispatch's end, for a delivery made at once) or now (one that
-        was held: it then lies inside the NEXT `serving/decode_step`)."""
+        was held: it then lies inside the NEXT `serving/decode_step`).
+        With tracing on every chunk carries the dispatch's end and the
+        moment of its put to its stream's handler (`_put_tokens`), and
+        every ender's `serving/finish` says its place among them."""
         if lane.held is None:
             return
         (rnd, now, trips, emitted, puts, ended, accept), lane.held = \
@@ -1774,18 +1819,21 @@ class DecodeBatcher:
         if since is None and traced:
             since = time.monotonic()
         for req in puts:
-            req.stream._put_tokens(req.buf)
+            req.stream._put_tokens(
+                req.buf, (now, time.monotonic()) if traced else None)
             req.buf = []
-        for slot, req, reason in ended:
+        for order, (slot, req, reason) in enumerate(ended):
+            at = (rnd, order, len(ended))
             if reason == "deadline":
-                self._expire(lane, slot, req, now)
+                self._expire(lane, slot, req, now, made=now, at=at)
             else:
-                self._finish(lane, slot, req, reason)
+                self._finish(lane, slot, req, reason, made=now, at=at)
         if traced:
             obs_tracing.stamp("serving/emit", since, time.monotonic(),
                               kind="serving", parent="serving/lane_iter",
                               replica=lane.index, round=rnd,
-                              tokens=emitted)
+                              tokens=emitted, puts=len(puts),
+                              enders=len(ended))
         if self.metrics is not None:
             self.metrics.decode_steps.add(trips)
             if accept:

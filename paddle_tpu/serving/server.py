@@ -600,13 +600,23 @@ class InferenceServer:
             _send_msg(sock, reply)
             return
         seq = 0
+        # with tracing on, what each frame waited for is folded into one
+        # `serving/stream_out` span a request (OBSERVABILITY.md); off,
+        # the loop reads no clock
+        out = _StreamOut() if obs_tracing.enabled() else None
         try:
             for kind, payload in stream.events():
+                if out is not None:
+                    out.woke(time.monotonic())
                 if kind == "tokens":
-                    _send_msg(sock, {"chunk": True, "seq": seq,
-                                     "tokens": [int(t) for t in payload],
-                                     "trace_id": trace_id})
+                    sent = _send_msg(sock, {
+                        "chunk": True, "seq": seq,
+                        "tokens": [int(t) for t in payload],
+                        "trace_id": trace_id})
                     seq += 1
+                    if out is not None:
+                        out.frame(stream.take_stamps(), time.monotonic(),
+                                  len(payload), sent)
                 elif kind == "error":
                     reply = _error_reply(payload)
                     reply["done"] = True
@@ -622,12 +632,86 @@ class InferenceServer:
                         final["debug"] = dict(stream.obs_info
                                               or {"trace_id": trace_id})
                     _send_msg(sock, final)
+            if out is not None:
+                # the terminal frame is out
+                out.t_sent = time.monotonic()
         except (ConnectionError, EOFError, OSError, WireError):
             # client went away mid-stream: evict the request so its
             # slot is reclaimed for waiting traffic (chaos scenario
             # decode-disconnect pins the bound: two dispatches)
             stream.cancel()
             raise
+        finally:
+            if out is not None:
+                out.land(trace_id, stream)
+
+
+class _StreamOut:
+    """What one request's frames waited for on their way out, folded by
+    the handler thread that sends them (one writer, no lock) and landed
+    as ONE `serving/stream_out` span at the request's end: a span a
+    frame would overflow the ring.  A frame's way has three parts, on
+    time.monotonic(): the dispatch's end to the lane's put (`lane_ms`),
+    the put to this thread's having the chunk (`wake_ms`: the queue and
+    the wait for the interpreter), and the encode and `sendall`
+    (`send_ms`)."""
+
+    __slots__ = ("t_first", "t_woke", "t_sent", "frames", "tokens",
+                 "bytes", "lane_ms", "wake_ms", "wake_max", "send_ms",
+                 "send_max")
+
+    def __init__(self):
+        self.t_first = self.t_woke = self.t_sent = None
+        self.frames = self.tokens = self.bytes = 0
+        self.lane_ms = self.wake_ms = self.wake_max = 0.0
+        self.send_ms = self.send_max = 0.0
+
+    def woke(self, now):
+        self.t_woke = now
+        if self.t_first is None:
+            self.t_first = now
+
+    def frame(self, stamps, t_sent, tokens, sent):
+        """One chunk frame is out: `stamps` the lane's (t_made, t_put)
+        of the chunk (None where it took none: the frame then counts
+        with no lane or wake time), `sent` its bytes on the socket."""
+        t_woke = self.t_woke
+        if stamps is not None:
+            t_made, t_put = stamps
+            if not self.frames:
+                self.t_first = t_put
+            wake = (t_woke - t_put) * 1e3
+            self.lane_ms += (t_put - t_made) * 1e3
+            self.wake_ms += wake
+            self.wake_max = max(self.wake_max, wake)
+        send = (t_sent - t_woke) * 1e3
+        self.send_ms += send
+        self.send_max = max(self.send_max, send)
+        self.t_sent = t_sent
+        self.frames += 1
+        self.tokens += tokens
+        self.bytes += sent
+
+    def land(self, trace_id, stream):
+        """The span: from the request's first put (the handler's first
+        wake-up, where it sent no chunk) to its last send's return; a
+        send that failed ends it where it failed."""
+        if self.t_first is None:
+            return
+        end = self.t_sent
+        if end is None or end < self.t_woke:
+            end = time.monotonic()
+        attrs = {}
+        replica = (stream.obs_info or {}).get("replica")
+        if replica is not None:
+            attrs["replica"] = replica
+        obs_tracing.stamp(
+            "serving/stream_out", self.t_first, end, kind="serving",
+            trace_id=trace_id, parent="serving/request",
+            frames=self.frames, tokens=self.tokens, bytes=self.bytes,
+            lane_ms_sum=self.lane_ms, wake_ms_sum=self.wake_ms,
+            wake_ms_max=self.wake_max, send_ms_sum=self.send_ms,
+            send_ms_max=self.send_max, **attrs)
 
 
 class _FederationLink:

@@ -83,10 +83,10 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       # PR 35's cell, whose latent kernel shares the stream's
                       # rule and counter (`kv_last_block`, `_kv_stream`)
                       "pangu_decode_saturated"]}
-    # appended, not inserted: only PR 35's five readers and PR 38's one
-    # stand behind it
+    # appended, not inserted: only PR 35's five readers, PR 38's one and
+    # PR 39's nine stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 7
+        manifest["per_layer"]) - 16
 
 
 # ---------------------------------------------------------------------------
@@ -130,7 +130,8 @@ def test_decode_early_launch_share_reader(case, spans, want):
 
 def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    assert manifest["per_layer"][-1] == {
+    # PR 39's nine readers stand behind it
+    assert manifest["per_layer"][-10] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -139,4 +140,4 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       "pangu_decode_saturated"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-1]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-10]["workloads"] == e2e["workloads"]

@@ -1227,33 +1227,22 @@ def _where_stack_step(pred, state, kc, vc, lengths, last_tokens, active):
 
 
 class TestStepInPlace:
-    def test_a_step_consumes_its_table_and_says_so(self, inplace_pred):
-        """The table a step is given is donated (no second table), and
-        `decode/launch` counts it: `donated_bytes` is both tables'."""
-        from paddle_tpu.obs import tracing as obs_tracing
+    def test_a_step_consumes_its_table(self, inplace_pred):
+        """The table a step is given is donated (no second table): both
+        tables are deleted after the call, and the session holds the
+        results."""
         sess = inplace_pred.new_session(4)
         sess.prefill(1, [7, 2, 9])
-        k_old, v_old = sess._kc, sess._vc
-        was = obs_tracing.enabled()
-        obs_tracing.set_enabled(True)
-        try:
-            obs_tracing.clear()
-            sess.decode()
-            sess.decode_logits()
-            launches = [s for s in
-                        obs_tracing.recent_spans(name="decode/launch")
-                        if s["attrs"]["phase"] == "step"]
-        finally:
-            obs_tracing.set_enabled(was)
-        assert k_old.is_deleted() and v_old.is_deleted(), \
-            "the step copied the slot table instead of updating it"
-        assert sess._kc is not k_old and not sess._kc.is_deleted()
+        for step in (sess.decode, sess.decode_logits):
+            k_old, v_old = sess._kc, sess._vc
+            step()
+            assert k_old.is_deleted() and v_old.is_deleted(), \
+                "the step copied the slot table instead of updating it"
+            assert sess._kc is not k_old and not sess._kc.is_deleted()
         both = int(sess._kc.nbytes) + int(sess._vc.nbytes)
         assert both == sess.cache_bytes() - (
             int(np.asarray(inplace_pred._kv_scales).nbytes)
             if inplace_pred._kv_quant else 0)
-        assert [s["attrs"]["donated_bytes"] for s in launches] \
-            == [both, both]
 
     def test_only_the_new_row_of_each_active_slot_changes(self,
                                                           inplace_pred):
@@ -1440,40 +1429,33 @@ def test_what_each_placement_donates(inplace_artifacts, placement):
     """One device (pinned here; the default one above): the step consumes
     the table.  A tensor-parallel mesh: its tables stay head-sharded in
     and out, consumed too.  A gather-mode mesh: `_mesh_wrap` gathers the
-    table and re-shards the result, the call is not donated and
-    `donated_bytes` says 0.  The streams are the same everywhere."""
+    table and re-shards the result, and the call is not donated.  The
+    streams are the same everywhere."""
     import jax
     from paddle_tpu.flags import get_flags, set_flags
-    from paddle_tpu.obs import tracing as obs_tracing
     from paddle_tpu.parallel.mesh import MeshGroup
     devs = jax.devices()
     if len(devs) < 2:
         pytest.skip("needs two devices")
     saved = get_flags(["mesh_tp"])
     set_flags({"mesh_tp": placement == "tp_mesh"})
-    was = obs_tracing.enabled()
-    obs_tracing.set_enabled(True)
     try:
         device = devs[1] if placement == "pinned" else MeshGroup(devs[:2])
         pred = GenerativePredictor(inplace_artifacts["default"],
                                    device=device)
         assert pred.tp_active == (placement == "tp_mesh")
         sess = pred.new_session(2)
-        first = sess.prefill(0, [5, 9, 3])
-        k_old = sess._kc
-        obs_tracing.clear()
-        toks = [first] + [int(sess.decode()[0]) for _ in range(3)]
-        donated = [s["attrs"]["donated_bytes"] for s in
-                   obs_tracing.recent_spans(name="decode/launch")
-                   if s["attrs"]["phase"] == "step"]
+        toks, donated = [sess.prefill(0, [5, 9, 3])], []
+        for _ in range(3):
+            k_old, v_old = sess._kc, sess._vc
+            toks.append(int(sess.decode()[0]))
+            donated.append((k_old.is_deleted(), v_old.is_deleted()))
     finally:
-        obs_tracing.set_enabled(was)
         set_flags(saved)
-    both = int(sess._kc.nbytes) + int(sess._vc.nbytes)
     if placement == "gather_mesh":
-        assert not k_old.is_deleted() and donated == [0, 0, 0]
+        assert donated == [(False, False)] * 3
     else:
-        assert k_old.is_deleted() and donated == [both] * 3
+        assert donated == [(True, True)] * 3
         assert sess._inplace == (placement == "pinned")
     ref, _ = greedy_decode(
         GenerativePredictor(inplace_artifacts["default"]), [5, 9, 3], 4)

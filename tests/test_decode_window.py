@@ -347,9 +347,9 @@ class _Recorded(object):
         stream = batcher_mod.DecodeStream
         put, finish, fail = stream._put_tokens, stream._finish, stream._fail
 
-        def _put_tokens(self_, toks):
+        def _put_tokens(self_, toks, *stamps):
             log.append(("put", self_, len(toks)))
-            return put(self_, toks)
+            return put(self_, toks, *stamps)
 
         def _finish(self_, reason, **k):
             log.append(("finish", self_, reason))

@@ -710,14 +710,12 @@ class TestPhaseSpans:
         small = 4 * 4 + 4 * 4 + 4 + 4 * 4 + 4
         # all three carry the trips the dispatch ran
         assert {s["attrs"]["trips"] for s in by.values()} == {1}
-        put, launch = by["decode/put"]["attrs"], by["decode/launch"]["attrs"]
         # the weights are resident under either placement: the launch
         # uploads no more than the small arguments `_put` left on the host
+        # (none where it placed them on the device)
         assert pred.state_host_bytes() == 0
-        if placed:
-            assert (put["bytes"], launch["h2d_bytes"]) == (small, 0)
-        else:
-            assert (put["bytes"], launch["h2d_bytes"]) == (0, small)
+        assert by["decode/launch"]["attrs"]["h2d_bytes"] == \
+            (0 if placed else small)
         # ONE int32 vector: the window's token block, 4 counts, the trips
         from paddle_tpu.inference.decode import STEP_WINDOW
         assert by["decode/fetch"]["attrs"]["d2h_bytes"] \
@@ -759,13 +757,18 @@ class TestPhaseSpans:
                     if s.get("attrs", {}).get("round") == rnd
                     and s["name"] != "serving/lane_iter"
                     and it["t0"] <= s["t0"] <= end]
+            # each of the two dispatches ends a stream: its delivery holds
+            # one finish, and that the slot's release
             assert {s["name"] for s in mine} == {
-                "serving/decode_step", "serving/emit", "decode/put",
-                "decode/launch", "decode/fetch"}
+                "serving/decode_step", "serving/emit", "serving/finish",
+                "serving/slot_free", "decode/put", "decode/launch",
+                "decode/fetch"}
             for s in mine:
                 want = "serving/decode_step" \
                     if s["name"].startswith("decode/") \
-                    else "serving/lane_iter"
+                    else {"serving/finish": "serving/emit",
+                          "serving/slot_free": "serving/finish"}.get(
+                              s["name"], "serving/lane_iter")
                 assert s["parent"] == want, s
                 # t0 never runs backwards down the tree
                 assert it["t0"] <= s["t0"]

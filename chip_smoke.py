@@ -21,8 +21,9 @@ published width of one model each, on ONE TPU chip in ONE process:
            call and three grouped-matmul kernels a layer in the step, logits
            against benchmark/reference/olmoe_1b_7b.py.
   kernels  flash attention fwd+bwd, decode attention at a grouped-query
-           table and dequant_matmul, compiled (`interpret=False`) and
-           compared with their references.
+           table and at OLMoE's wide rows, latent decode attention and
+           dequant_matmul, compiled (`interpret=False`) and compared with
+           their references.
 
 `--chips 4` runs instead — and only — what exists across chips:
 ParallelExecutor against the single-device Executor, and four one-chip serving
@@ -68,8 +69,12 @@ OLMOE_PROMPT_LENS = (9, 300, 512, 700)     # both buckets, a bucket's edge
 FLASH = dict(B=2, S=4096, H=16, D=128)
 DEQUANT = dict(M=256, K=2048, N=8192)
 # a grouped-query slot table: LFM2's rows (32 query heads over 8 K/V heads of
-# 64, padded to (8, 128)) and cache length, 8 slots of the cell's 32
-DECODE_GQA = (8, 4096, 32, 8, 128)
+# 64: flat rows of 512 lanes) and cache length, 8 slots of the cell's 32
+DECODE_GQA = (8, 4096, 32, 8, 64)
+# OLMoE's slot table (16 heads of 128: flat rows of 2048 lanes) at the cell's
+# 8 slots and cache length; GPT-2 small's (12 heads of 64: 768 lanes) is the
+# serve phase's own
+DECODE_WIDE = (8, 4096, 16, 16, 128)
 
 # a latent slot table at openPangu-Ultra-MoE's published row: 128 absorbed
 # query heads over ONE row a position of 512 + 64 = 576 values, held as 640
@@ -78,10 +83,11 @@ DECODE_GQA = (8, 4096, 32, 8, 128)
 DECODE_LATENT = (8, 4096, 128, 576, 512)
 
 # Stated tolerances.
-# serve, the kernel alone: decode_attention (fp32 VPU math, online softmax
-# over blocks of 128) vs its reference at full fp32 matmul precision on
-# random O(1) inputs at the served geometry — same math, another summation
-# order over <= 1024 positions.
+# serve, the kernel alone: decode_attention (block-diagonal queries against
+# flat K/V rows, both contractions on the MXU at fp32 precision, online
+# softmax over blocks of 128) vs its reference at full fp32 matmul precision
+# on random O(1) inputs at the served geometry — same math, another
+# summation order over <= 1024 positions.
 TOL_DECODE_KERNEL = 5e-5
 # latent_decode_attention (both contractions on the MXU, operands rounded to
 # bfloat16 as the default precision rounds every matmul's, fp32 accumulation
@@ -434,8 +440,9 @@ def check_bounded_stream(call, ref, operands, n_slots, S, bkv, what, tol):
 
 def check_decode_kernel(geometry, kv_dtype, seed):
     """decode_attention over a slot table of `geometry` = (slots, S, query
-    heads, K/V heads, head size) and cache dtype `kv_dtype`
-    (`check_bounded_stream`)."""
+    heads, K/V heads, head size), a position one flat row of K/V heads x
+    head size values as a session's table holds it, and cache dtype
+    `kv_dtype` (`check_bounded_stream`)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import attention_tuning
@@ -445,12 +452,13 @@ def check_decode_kernel(geometry, kv_dtype, seed):
     kq, kk, kv_, ks = jax.random.split(jax.random.PRNGKey(seed + 2), 4)
     q = jax.random.normal(kq, (n_slots, H, Dh), jnp.float32)
     if kv_dtype == "int8":
-        k = jax.random.randint(kk, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
-        v = jax.random.randint(kv_, (n_slots, S, Hc, Dh), -127, 128, jnp.int8)
+        k = jax.random.randint(kk, (n_slots, S, Hc * Dh), -127, 128, jnp.int8)
+        v = jax.random.randint(kv_, (n_slots, S, Hc * Dh), -127, 128,
+                               jnp.int8)
         scales = jax.random.uniform(ks, (2, H), jnp.float32, 0.5, 1.5) / 127
     else:
-        k = jax.random.normal(kk, (n_slots, S, Hc, Dh), jnp.float32)
-        v = jax.random.normal(kv_, (n_slots, S, Hc, Dh), jnp.float32)
+        k = jax.random.normal(kk, (n_slots, S, Hc * Dh), jnp.float32)
+        v = jax.random.normal(kv_, (n_slots, S, Hc * Dh), jnp.float32)
         scales = None
 
     def ref(q, k, v, n):
@@ -822,13 +830,15 @@ def phase_kernels(seed, devs):
          max_rel_err_bwd=round(err_bwd, 5), tol_bwd=TOL_FLASH_BWD_REL,
          peak_bytes_in_use=peak_bytes(devs[0]), device=where(got[0]))
 
-    err, us = check_decode_kernel(DECODE_GQA, "float32", seed)
-    emit("kernels", kernel="decode_attention_grouped_query",
-         shape=dict(zip(("slots", "S", "heads", "kv_heads", "D"),
-                        DECODE_GQA)), dtype="float32",
-         max_abs_err=float("%.3g" % err), tol=TOL_DECODE_KERNEL,
-         equals_whole_row_stream=True, call_us=us,
-         peak_bytes_in_use=peak_bytes(devs[0]))
+    for kernel, geometry in (("decode_attention_grouped_query", DECODE_GQA),
+                             ("decode_attention_wide_rows", DECODE_WIDE)):
+        err, us = check_decode_kernel(geometry, "float32", seed)
+        emit("kernels", kernel=kernel,
+             shape=dict(zip(("slots", "S", "heads", "kv_heads", "D"),
+                            geometry)), dtype="float32",
+             max_abs_err=float("%.3g" % err), tol=TOL_DECODE_KERNEL,
+             equals_whole_row_stream=True, call_us=us,
+             peak_bytes_in_use=peak_bytes(devs[0]))
 
     err, us = check_latent_kernel(DECODE_LATENT, seed)
     emit("kernels", kernel="latent_decode_attention",

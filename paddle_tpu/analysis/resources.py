@@ -854,11 +854,10 @@ def _decode_report(path, meta, decode_slots, device, what,
             if os.path.exists(state_path) else 0
         rep.actual_param_bytes = rep.param_bytes
         n_params = rep.param_bytes // 4
-    # K and V, [attention layers, n_slots, S, Hp, Dp] each at the cache
-    # dtype's width (4 B fp32, 1 B int8 + the fp32 scale table), a row
-    # (Hp, Dp) of the K/V heads as the placement's table holds it (padded
-    # to the kernel's tile on one TPU device) — must match
-    # GenerativePredictor.kv_cache_bytes exactly (pinned by
+    # K and V, [attention layers, n_slots, S, Hc * Dh] each at the cache
+    # dtype's width (4 B fp32, 1 B int8 + the fp32 scale table), a row the
+    # K/V heads' values and nothing else (`decode.slot_state_shapes`) — must
+    # match GenerativePredictor.kv_cache_bytes exactly (pinned by
     # tests/test_resources.py)
     kv_elem = 1 if kv_dtype == "int8" else 4
     kv_scales = 2 * L * H * 4 if kv_dtype == "int8" else 0

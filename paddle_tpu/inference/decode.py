@@ -147,7 +147,7 @@ __all__ = ["GenerativePredictor", "DecodeSession", "DecodeSessionDead",
            "SpeculativeDecodeSession", "save_decode_model",
            "build_tiny_decode_model", "load_decode_predictor",
            "greedy_decode", "set_draft_poison", "normalize_kv_dtype",
-           "table_row", "STEP_WINDOW",
+           "STEP_WINDOW",
            "DECODE_META"]
 
 DECODE_META = "decode_meta.bin"
@@ -420,24 +420,40 @@ def slot_state_shapes(meta, n_slots, device):
     """The kinds of state a slot of an `n_slots` session on `device`
     holds, as (K/V table shape, conv-state table shape or None):
 
-      * [attention layers, N, S, Hp, Dp]: a K (or V) row for every cached
-        position of every ATTENTION layer, addressed by the slot's length;
-        (Hp, Dp) is `table_row` of the K/V heads;
+      * [attention layers, N, S, Hc * Dh]: a K (or V) row for every cached
+        position of every ATTENTION layer, addressed by the slot's length:
+        ONE FLAT ROW a position, its Hc K/V heads' Dh features side by
+        side, on every placement (below);
       * [conv layers, N, conv_kernel - 1, D]: the last inputs of every
         CONV layer's filter, a fixed size whatever the slot's length;
         None for a stack with no conv layer;
       * for a stack of MLA layers, in the first place and held ONCE (no V
         table): [mla layers, N, S, Rp], the latent row of every cached
-        position, `latent_row` lanes wide."""
+        position, `latent_row` lanes wide.
+
+    Why a K/V row is flat: the decode kernel's operand is row-major with
+    its last two axes in (8, 128) tiles.  With (Hc, Dh) last, GPT-2
+    small's (12, 64) was held and streamed as (16, 128), 2.67x its bytes,
+    most of them exact zeros.  With (S, Hc * Dh) last the positions lie on
+    the sublanes and the row's 768 lanes (2048 at OLMoE, 512 at LFM2's 8
+    K/V heads of 64) are whole tiles: nothing is padded at rest or in the
+    stream, for any head size.  Such a table is row-major by the device's
+    own choice, so the table at rest IS the kernel's operand, a donated
+    call updates it in place, and no layout has to be pinned anywhere
+    (jax 0.9 loses a pinned output layout when it loads the executable
+    from its persistent cache: PERF.md, PR 27); a step's write of a
+    position stays one contiguous row a slot a layer.  `decode_attention`
+    contracts the flat tile per head.  A mesh shards the row's axis
+    (`MeshGroup.kv_sharding`): a member's Hc / m heads are its contiguous
+    (Hc / m) * Dh lanes, a flat table of its own."""
     blk = block_of(meta)
     ops = [op for op, _ in layer_kinds(meta, blk)]
     if "mla" in ops:
         return (len(ops), int(n_slots), int(meta["max_seq_len"]),
                 latent_row(blk, device)), None
     H, D = int(meta["n_heads"]), int(meta["d_model"])
-    row = table_row(blk["n_kv_heads"] or H, D // H, device)
-    kv = (ops.count("attention"), int(n_slots),
-          int(meta["max_seq_len"])) + row
+    kv = (ops.count("attention"), int(n_slots), int(meta["max_seq_len"]),
+          (blk["n_kv_heads"] or H) * (D // H))
     n_conv = ops.count("conv")
     return kv, ((n_conv, int(n_slots), blk["conv_kernel"] - 1, D)
                 if n_conv else None)
@@ -830,36 +846,10 @@ def _pack_routing(tokens, facts):
         jnp.stack([f for f in facts if f is not None]).reshape(-1)])
 
 
-def table_row(n_heads, head_dim, device):
-    """(Hp, Dp): one cached position's K (or V) row, `n_heads` K/V heads
-    of `head_dim`, as a K/V slot table on `device` (a jax.Device, a
-    MeshGroup, or None: jax's default device) holds it.  On ONE TPU
-    device that is (H, Dh) rounded up to the (8, 128) tile of the decode
-    kernel's operands, the pad exact zeros; everywhere else (H, Dh)
-    itself.
-
-    Why: a Mosaic operand is row-major with its last two axes in the
-    tile, so the kernel streams GPT-2 small's (12, 64) rows as (16, 128)
-    whatever the table looks like at rest; and a TPU left to itself lays
-    a table whose rows do not fill the tile out with S innermost, which
-    makes every step copy the table into the kernel's layout and back
-    (that copy, not the attention, was most of a round before PR 27).  A
-    table whose rows ARE tiles is row-major by the device's own choice,
-    so the table at rest is the kernel's operand, a donated call updates
-    it in place, and no layout has to be pinned anywhere (jax 0.9 loses a
-    pinned output layout when it loads the executable from its
-    persistent cache: PERF.md, PR 27).  The price is the tile's padding
-    at rest: 2.67x at (12, 64), nothing at (16, 128).  A mesh's table
-    shards by heads and keeps the plain row."""
-    if not _rows_are_tiles(device):
-        return int(n_heads), int(head_dim)
-    return -(-int(n_heads) // 8) * 8, -(-int(head_dim) // 128) * 128
-
-
 def _rows_are_tiles(device):
-    """Whether a slot table on `device` (a jax.Device, a MeshGroup, or
-    None: jax's default device) pads its rows to the decode kernels'
-    tile: on ONE TPU device (`table_row` says why)."""
+    """Whether a latent slot table on `device` (a jax.Device, a MeshGroup,
+    or None: jax's default device) pads its rows to the decode kernel's
+    lanes: on ONE TPU device (`latent_row`)."""
     from paddle_tpu.parallel.mesh import as_mesh_group
     if device is None:
         import jax
@@ -872,17 +862,18 @@ def latent_row(blk, device):
     """Lanes of one cached position's row in an MLA stack's latent table:
     kv_lora_rank + qk_rope_head_dim values (the normed latent, then the
     rotated key all heads share), on one TPU device rounded up to the 128
-    lanes of the kernel's tile with exact zeros (`table_row` says why): the
-    published 512 + 64 = 576 are held as 640, +11%."""
+    lanes of the kernel's tile with exact zeros (a table whose rows are
+    whole tiles is row-major by the device's own choice, so the table at
+    rest is the kernel's operand: `slot_state_shapes`): the published
+    512 + 64 = 576 are held as 640, +11%."""
     n = blk["kv_lora_rank"] + blk["qk_rope_head_dim"]
     return -(-n // 128) * 128 if _rows_are_tiles(device) else n
 
 
 def _pad_rows(x, row):
     """`x` [..., *r] zero-padded on its last axes to the table's row
-    `row`: (Hp, Dp) of a K/V table (`table_row`), (Rp,) of a latent table
-    (`latent_row`), (D,) of a conv state; `x` itself where the row is not
-    padded."""
+    `row`: (Rp,) of a latent table (`latent_row`); `x` itself where the
+    row is not padded (a K/V row, a conv state's (D,))."""
     import jax.numpy as jnp
     pad = [(0, r - n) for r, n in zip(row, x.shape[-len(row):])]
     if not any(p for _, p in pad):
@@ -891,7 +882,7 @@ def _pad_rows(x, row):
 
 
 def _land(table, layer, where, rows):
-    """`table` [L, N, S, Hp, Dp] with `rows` [N(, C), H, Dh] (or a latent
+    """`table` [L, N, S, H * Dh] with `rows` [N(, C), H * Dh] (or a latent
     table [L, N, S, Rp] with `rows` [N, R]) written at
     (layer, *where), `where` = (slots, positions) broadcasting to the
     rows' leading shape: THE write of a decode phase.  A row whose
@@ -917,7 +908,12 @@ def _clear_rows(table, lo, hi, width):
     j = jnp.arange(width)[None]
     at = lo[:, None] + j
     at = jnp.where(at < hi[:, None], at, table.shape[2] + j)
-    return table.at[:, jnp.arange(table.shape[1])[:, None], at].set(
+    # (layer, slot, position) -> a row, as `_land` addresses one: with the
+    # layers as the update's window the TPU's compiler moves a table of
+    # flat rows into a layout with the layers inside, and back
+    L, N = table.shape[:2]
+    return table.at[jnp.arange(L)[:, None, None],
+                    jnp.arange(N)[None, :, None], at[None]].set(
         jnp.zeros((), table.dtype), mode="drop",
         indices_are_sorted=True, unique_indices=True)
 
@@ -940,12 +936,13 @@ _SLOT_WRITERS = []
 
 def _slot_writers():
     """(write_rows, zero_slot, clear_rows): the three eager writes of a
-    slot-state table (K/V [L, N, S, Hp, Dp], latent rows [L, N, S, Rp] or
+    slot-state table (K/V [L, N, S, H * Dh], latent rows [L, N, S, Rp] or
     conv state [L, N, K-1, D]), jitted with the table DONATED so that they
     land in place.
-    `write_rows(table, rows [L, 1, B, H, Dh], slot)`
-    puts `rows`, padded to the table's row, at `slot` from position 0 (a
-    prefill's K or V, or its conv state [L, 1, K-1, D] whole);
+    `write_rows(table, rows [L, 1, B, H * Dh], slot)`
+    puts `rows`, padded to the table's row where that is (a latent
+    table's), at `slot` from position 0 (a prefill's K or V, or its conv
+    state [L, 1, K-1, D] whole);
     `zero_slot(table, slot)` zeroes the slot's whole row (its release);
     `clear_rows` is `_clear_rows` (a rollback of a K/V table), one
     executable per depth.
@@ -1438,18 +1435,12 @@ class GenerativePredictor:
 
     # -- static byte accounting (ANALYSIS.md resource analysis) ---------
 
-    def table_row(self):
-        """(Hp, Dp): a cached position's row in this placement's K/V
-        slot tables (module-level `table_row` of the K/V heads)."""
-        return table_row(self._kv_heads(), self._dims()[2], self._device)
-
     def table_shape(self, n_slots):
-        """[attention layers, n_slots, S, Hp, Dp]: the K (or V) slot
+        """[attention layers, n_slots, S, Hc * Dh]: the K (or V) slot
         table of an `n_slots` session of this predictor; for an MLA
         stack [mla layers, n_slots, S, Rp], its one latent table
         (`slot_state_shapes`)."""
-        shape = self._slot_state_shapes(n_slots)[0]
-        return shape if self.latent else shape[:3] + self.table_row()
+        return self._slot_state_shapes(n_slots)[0]
 
     def conv_state_shape(self, n_slots):
         """[conv layers, n_slots, conv_kernel - 1, D]: the conv-state
@@ -1468,8 +1459,8 @@ class GenerativePredictor:
     def kv_cache_bytes(self, n_slots):
         """Closed-form K/V slot-table footprint for an `n_slots`
         session: K and V, `table_shape(n_slots)` each (the ATTENTION
-        layers' rows, as the table holds them: padded to the tile on one
-        TPU device, `table_row`) at the CACHE dtype's width (4 B fp32,
+        layers' rows, which hold the K/V heads' values and nothing else:
+        `slot_state_shapes`) at the CACHE dtype's width (4 B fp32,
         1 B int8 — plus the int8 cache's per-(layer, head) fp32 scale
         table), or the ONE latent table of an MLA stack — the HBM term
         that bounds decode slots
@@ -1554,7 +1545,9 @@ class GenerativePredictor:
         return np.stack([sc(kc), sc(vc)])[..., None]
 
     def _prefill_math(self, state, tokens, true_len, tp=_OFF_MESH):
-        """The traced prefill phase: `_prefill_core` plus the int8
+        """The traced prefill phase: `_prefill_core` with its K and V as
+        a slot table holds a position, [attention layers, 1, B, Hkv * Dh]
+        (one flat row: `slot_state_shapes`), after the int8
         cache-write quantization epilogue (zeros quantize to exact
         int8 zeros, so the zero-slot contract is dtype-blind).  Under
         TP the K/V are this member's head shard, so the scale constant
@@ -1562,15 +1555,18 @@ class GenerativePredictor:
         quantized byte as the single-device write."""
         import jax.numpy as jnp
         out = self._prefill_core(state, tokens, true_len, tp=tp)
-        if not self._kv_quant:
+        if self.latent:
             return out
-        first, kc, vc = out
-        sc = tp.head_scales(self._kv_scales, kc.shape[3])  # [2, L, Hl, 1]
-        kq = self._quantize_kv(
-            kc, sc[0][:, None, None]).astype(jnp.int8)
-        vq = self._quantize_kv(
-            vc, sc[1][:, None, None]).astype(jnp.int8)
-        return first, kq, vq
+        first, kc, vc, *conv = out
+        if self._kv_quant:
+            # [2, L, Hl, 1]
+            sc = tp.head_scales(self._kv_scales, kc.shape[3])
+            kc = self._quantize_kv(
+                kc, sc[0][:, None, None]).astype(jnp.int8)
+            vc = self._quantize_kv(
+                vc, sc[1][:, None, None]).astype(jnp.int8)
+        kc, vc = (t.reshape(t.shape[:3] + (-1,)) for t in (kc, vc))
+        return (first, kc, vc, *conv)
 
     def _tp_seq_parallel(self, bucket, tp):
         """Does this prompt bucket prefill SEQUENCE-parallel under TP?
@@ -1942,7 +1938,9 @@ class GenerativePredictor:
             sc = tp.head_scales(self._kv_scales[:, i], k_new.shape[-2])
             k_new = self._quantize_kv(k_new, sc[0])
             v_new = self._quantize_kv(v_new, sc[1])
-        return _land(kc, i, where, k_new), _land(vc, i, where, v_new)
+        # [.., Hkv, Dh] -> the table's flat row
+        return tuple(_land(t, i, where, r.reshape(r.shape[:-2] + (-1,)))
+                     for t, r in ((kc, k_new), (vc, v_new)))
 
     def _attend_table(self, q, kc, vc, lengths, ahead, i, tp):
         """The decode kernel over layer i of the carried K/V tables: q
@@ -1955,29 +1953,17 @@ class GenerativePredictor:
         heads."""
         from paddle_tpu.ops.pallas_kernels import (
             decode_attention, decode_attention_head_slice)
-        row = kc.shape[3:]
         Hl, Dh = q.shape[1:]
-        group = self._dims()[1] // self._kv_heads()
         scale = 1.0 / np.sqrt(Dh)
         scales = self._kv_scales[:, i] if self._kv_quant else None
         if tp.size > 1:
-            # a mesh keeps the plain row; each member slices its heads'
-            # scales out of the baked full table
+            # each member slices its heads' scales out of the baked full
+            # table
             return decode_attention_head_slice(
                 q, kc, vc, lengths + ahead, tp.index() * Hl, Hl,
                 scale=scale, kv_scales=scales, layer=i)
-        # the table's rows may be padded to the kernel's tile
-        # (`table_row`): q goes in padded alike (zero heads, zero lanes;
-        # a padded K/V head's `group` query heads), a padded head's
-        # scale is 1 (its rows are zeros), and the pad comes off the
-        # result
-        if scales is not None:
-            scales = np.pad(np.asarray(scales)[..., 0],
-                            ((0, 0), (0, row[0] - Hl)), constant_values=1.0)
-        return decode_attention(
-            _pad_rows(q, (row[0] * group, row[1])), kc, vc,
-            lengths + ahead, scale=scale, kv_scales=scales,
-            layer=i)[:, :Hl, :Dh]
+        return decode_attention(q, kc, vc, lengths + ahead, scale=scale,
+                                kv_scales=scales, layer=i)
 
     def _step_logits(self, state, *args, tp=_OFF_MESH):
         """`_step_core` without the routing facts, on the flat arguments
@@ -2008,7 +1994,7 @@ class GenerativePredictor:
                    tp=_OFF_MESH, picks=None):
         """One fixed-shape decode step over the slots' whole state.
         `tables` = (kc, vc[, cs]): the K/V tables [attention layers, N,
-        S, Hp, Dp] (fp32, or int8 under the quantized cache) and, for a
+        S, Hc * Dh] (fp32, or int8 under the quantized cache) and, for a
         stack with conv layers, the conv-state table [conv layers, N,
         K-1, D]; or, for an MLA stack, (rows,): the latent table [mla
         layers, N, S, Rp] alone; lengths [N] i32 (live cached positions),
@@ -2313,7 +2299,9 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (7:
+            # rev bumps when the phase math itself changes shape (8: a
+            # prefill returns its K and V as the tables hold a position,
+            # one flat row; 7:
             # the decode kernel's stream stops at a slot's length, the
             # same results from a fraction of the bytes; 6: the step is
             # a window of runtime trips; 5: verify and the
@@ -2325,7 +2313,7 @@ class GenerativePredictor:
             # and routing): equal weight shapes, another function
             "block": [[k, self._block_meta[k]]
                       for k in sorted(self._block_meta)],
-            "rev": 7,
+            "rev": 8,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -2401,7 +2389,8 @@ class GenerativePredictor:
         compiled executable matches what the session actually passes:
         params sharded per `param_sharding` (or `tp_param_sharding`
         when this predictor runs tensor-parallel — AOT executables are
-        strict about input placement), 5-D KV slot tables per
+        strict about input placement), the K/V slot tables (a mesh's
+        only 4-D arguments: it holds K/V stacks alone) per
         `kv_sharding`, everything else replicated.  Dict-shaped args
         (the fused-speculative phase's DRAFT state) shard per the
         DRAFT's own placement — it rides the same mesh group as its
@@ -2422,7 +2411,7 @@ class GenerativePredictor:
                 return params(spec,
                               draft is not None
                               and getattr(draft, "_tp_size", 0))
-            if len(spec.shape) == 5:
+            if len(spec.shape) == 4:
                 return attach(spec, group.kv_sharding(spec.shape))
             return attach(spec, group.replicated())
 
@@ -2433,25 +2422,27 @@ class GenerativePredictor:
                       group, jax):
         """Build the partitioned program: ONE shard_map over the
         group's 1-D "model" axis running the per-member body.  Params
-        enter under the TP grammar (`tp_param_pspec`), 5-D KV slot
-        tables head-sharded (axis 3 — `tp_supported` guarantees heads
-        divide, so this coincides with the at-rest `kv_sharding`),
-        scalars/token tables replicated.  Output specs come from
-        eval_shape of the plain (off-mesh) math — the TP body returns
-        the same tree, with 5-D caches staying head-sharded and
+        enter under the TP grammar (`tp_param_pspec`), the K/V slot
+        tables [L, N, S, H * Dh] (a mesh's only 4-D arguments) sharded
+        by heads: the row's axis, a member's H / m heads its contiguous
+        lanes (`tp_supported` guarantees heads divide, so this
+        coincides with the at-rest `kv_sharding`), scalars/token tables
+        replicated.  Output specs come from eval_shape of the plain
+        (off-mesh) math — the TP body returns the same tree, with the
+        tables and a prefill's K/V rows staying head-sharded and
         everything else fully reduced (psum/all_gather) hence
         replicated."""
         from jax.sharding import PartitionSpec as P
         from paddle_tpu.parallel.mesh import (
             MODEL_AXIS, shard_map_no_rep_check, tp_param_pspec)
 
-        kv_spec = P(None, None, None, MODEL_AXIS, None)
+        kv_spec = P(None, None, None, MODEL_AXIS)
 
         def pspec_of(spec):
             if isinstance(spec, dict):
                 return {k: tp_param_pspec(k, v.shape)
                         for k, v in spec.items()}
-            if len(spec.shape) == 5:
+            if len(spec.shape) == 4:
                 return kv_spec
             return P()
 
@@ -2460,7 +2451,7 @@ class GenerativePredictor:
         in_specs += tuple(pspec_of(s) for s in arg_specs)
         out_shape = jax.eval_shape(plain_math, state_spec, *arg_specs)
         out_specs = jax.tree_util.tree_map(
-            lambda s: kv_spec if len(s.shape) == 5 else P(), out_shape)
+            lambda s: kv_spec if len(s.shape) == 4 else P(), out_shape)
         return shard_map_no_rep_check(tp_math, group.mesh(),
                                       in_specs=in_specs,
                                       out_specs=out_specs)
@@ -2717,9 +2708,10 @@ class DecodeSession:
         # on one device every write to the table lands IN PLACE, the
         # table donated: a step's rows (`_phase_jit`), a slot's
         # admission, release and rollback (`_slot_writers`).  On a mesh
-        # the table shards AT REST (heads axis first: per-device resident
-        # KV ~ 1/mesh_size, which is what makes decode slots scale with
-        # mesh HBM) and its sharding is the eager write's to keep.
+        # the table shards AT REST (the row's axis, by heads, first:
+        # per-device resident KV ~ 1/mesh_size, which is what makes decode
+        # slots scale with mesh HBM) and its sharding is the eager
+        # write's to keep.
         from paddle_tpu.parallel.mesh import as_mesh_group
         group = as_mesh_group(predictor.device) \
             if predictor.device is not None else None
@@ -2767,7 +2759,8 @@ class DecodeSession:
         # through the plain-XLA reference, which reads whole rows)
         from paddle_tpu.ops import attention_tuning
         self._kv_block = attention_tuning.get_decode_config(
-            shape[2], shape[-1], jnp.dtype(dtype).name)
+            shape[2], shape[-1] if predictor.latent
+            else predictor._dims()[2], jnp.dtype(dtype).name)
         # set when a call failed after its table was donated to it
         # (`_mark_dead`): (phase, error); every later use raises
         self._dead = None
@@ -2919,7 +2912,7 @@ class DecodeSession:
                         for t, rows in zip(self._tables(), new)])
         else:
             kc, vc = new
-            at = (0, slot, 0, 0, 0)
+            at = (0, slot, 0, 0)
             self._kc = jax.lax.dynamic_update_slice(self._kc, kc, at)
             self._vc = jax.lax.dynamic_update_slice(self._vc, vc, at)
         tok = int(self._fetch("prefill", first,
@@ -3121,10 +3114,9 @@ class DecodeSession:
         else:
             import jax.lax
             import jax.numpy as jnp
-            L = self._kc.shape[0]
-            S, H, Dh = self._kc.shape[2:]
-            z = self._put(jnp.zeros((L, 1, S, H, Dh), self._kc.dtype))
-            at = (0, int(slot), 0, 0, 0)
+            L, _, S, W = self._kc.shape
+            z = self._put(jnp.zeros((L, 1, S, W), self._kc.dtype))
+            at = (0, int(slot), 0, 0)
             self._kc = jax.lax.dynamic_update_slice(self._kc, z, at)
             self._vc = jax.lax.dynamic_update_slice(self._vc, z, at)
         self.lengths[slot] = 0
@@ -3163,10 +3155,9 @@ class DecodeSession:
             # (no conv state among them: refused above)
             self._keep([clear_rows(t, lo, hi, n) for t in self._tables()])
         elif n > 0:
-            L = self._kc.shape[0]
-            H, Dh = self._kc.shape[3], self._kc.shape[4]
-            z = self._put(jnp.zeros((L, 1, n, H, Dh), self._kc.dtype))
-            at = (0, slot, length - n, 0, 0)
+            L, W = self._kc.shape[0], self._kc.shape[3]
+            z = self._put(jnp.zeros((L, 1, n, W), self._kc.dtype))
+            at = (0, slot, length - n, 0)
             self._kc = jax.lax.dynamic_update_slice(self._kc, z, at)
             self._vc = jax.lax.dynamic_update_slice(self._vc, z, at)
         if n > 0:

@@ -180,8 +180,9 @@ def _mesh_wrap(math_fn, group, kv_outputs=False):
     bit-exact vs a single-device replica by construction (the
     weight-update-sharding blueprint: HBM shards, math does not).
 
-    `kv_outputs=True` re-shards 5-D outputs (the decode KV slot tables)
-    back to their at-rest `kv_sharding` before returning, so the
+    `kv_outputs=True` re-shards 4-D outputs (the decode KV slot tables
+    [L, N, S, H * Dh], and a prefill's K/V rows) back to their at-rest
+    `kv_sharding` before returning, so the
     session-resident cache stays ~1/mesh_size per device between
     dispatches; everything else returns replicated."""
     import jax
@@ -190,7 +191,7 @@ def _mesh_wrap(math_fn, group, kv_outputs=False):
         return jax.lax.with_sharding_constraint(x, group.replicated())
 
     def _out(x):
-        if kv_outputs and getattr(x, "ndim", 0) == 5:
+        if kv_outputs and getattr(x, "ndim", 0) == 4:
             return jax.lax.with_sharding_constraint(
                 x, group.kv_sharding(x.shape))
         return _rep(x)

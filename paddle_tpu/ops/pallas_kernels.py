@@ -233,8 +233,8 @@ def _online_softmax_tile(s, pv_of, acc_ref, m_ref, l_ref):
     attention: fold one masked f32 score tile `s` [R, BKV] into the
     running row max / normalizer / accumulator, rescaling prior
     contributions by alpha.  `pv_of(p)` contracts the tile
-    probabilities against the resident value tile — an MXU matmul for
-    flash, a VPU lane reduction for decode."""
+    probabilities against the resident value tile — an MXU matmul in
+    every family."""
     import jax.numpy as jnp
     m_prev = m_ref[...]                            # [R, LANES]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
@@ -580,8 +580,10 @@ def kv_last_block(lengths, block_kv, n_blocks, xp=np):
 def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
                                kv_scales=None):
     """Plain-XLA oracle/fallback with identical masking semantics:
-    q [N, H, D] one new token per slot, k/v caches [N, S, H, D],
-    lengths [N] live cached positions per slot -> [N, H, D].
+    q [N, H, D] one new token per slot, k/v caches [N, S, Hc * D] as a
+    slot table holds them (a position one flat row, its Hc heads' D
+    features side by side), lengths [N] live cached positions per slot
+    -> [N, H, D].
     `kv_scales` [2, H] f32 (required iff the caches are int8) applies
     the same per-head dequant algebra as the kernel: K scale on the
     scores, V scale after the normalizing divide.  Caches of fewer heads
@@ -591,6 +593,7 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
     N, S = k_cache.shape[0], k_cache.shape[1]
     H, D = q.shape[1], q.shape[-1]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
+    k_cache, v_cache = (t.reshape(N, S, -1, D) for t in (k_cache, v_cache))
     if k_cache.shape[2] != H:
         k_cache, v_cache = (jnp.repeat(t, H // t.shape[2], axis=2)
                             for t in (k_cache, v_cache))
@@ -616,26 +619,47 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
                      block_kv=None, interpret=None, kv_scales=None,
                      layer=None):
     """Slot-cache decode attention: q [N, H, D] (the one new token of
-    each of N slots), k_cache/v_cache [N, S, H, D] (the slot table's
-    cached keys/values, time-major; fp32 or int8), lengths [N] int32
-    (live positions per slot — cached positions >= length are masked
-    out) -> [N, H, D] in q's dtype.
+    each of N slots), k_cache/v_cache [N, S, Hc * D] (the slot table's
+    cached keys/values, time-major, a position ONE FLAT ROW: its Hc K/V
+    heads' D features side by side; fp32, bf16 or int8), lengths [N]
+    int32 (live positions per slot — cached positions >= length are
+    masked out) -> [N, H, D] in q's dtype.
+
+    WHY FLAT ROWS.  A Mosaic operand is row-major with its last two axes
+    in (8, 128) tiles.  With (Hc, D) last, GPT-2 small's (12, 64) is
+    held and streamed as (16, 128), 2.67x its bytes; with (S, Hc * D)
+    last, positions lie on the sublanes and 768 lanes are six full
+    tiles: nothing is padded, for any head size whose Hc * D is a
+    multiple of 128 (every stack served), and the table at rest is the
+    kernel's operand (`inference/decode.py::slot_state_shapes`).
+
+    THE BODY contracts a [block_kv, Hc * D] tile per head without taking
+    the heads apart: the slot's queries go in BLOCK-DIAGONAL, [H, Hc * D]
+    with head a's D values on the lanes of its K/V head and zeros
+    elsewhere, so scores [H, block_kv] = q_bd tile^T and values [H, Hc *
+    D] += p tile are two MXU contractions (as `latent_decode_attention`
+    contracts its flat row), and head a's result is the lanes of its K/V
+    head in row a, folded out after the call.  Both run at
+    Precision.HIGHEST: fp32 products and fp32 sums, as the configuration
+    states of its cache and as the VPU computed them when each head had
+    a tile of its own (on the chip the two bodies differ from an fp32
+    reference alike, by under 1e-6: PERF.md, PR 41); a zero lane adds an
+    exact zero.  The MXU does H times the multiplications that count and
+    is still far from its peak.
 
     GROUPED-QUERY: caches of Hc < H heads (H = G * Hc), query head a
-    reading K/V head a // G.  The kernel streams each K/V tile ONCE for
-    its G query heads: q goes in group-major ([G, Hc] flattened, so that
-    group g is the contiguous rows g * Hc ..), the body scores and sums
-    each group against the one resident tile, and the result comes back
-    in head order.  Float caches only.
+    reading K/V head a // G: its row of the block diagonal lies on that
+    head's lanes, so the one resident tile serves all G query heads of a
+    K/V head in the same two contractions.  Float caches only.
 
     With `layer` (a static int) k_cache/v_cache are the STACKED slot
-    table [L, N, S, H, D] and the kernel reaches that layer through its
-    BlockSpec index maps, (layer, b, block, 0, 0): no slice of the table
+    table [L, N, S, Hc * D] and the kernel reaches that layer through its
+    BlockSpec index maps, (layer, b, block, 0): no slice of the table
     is materialised for the custom call, so a decode step that carries the
     table and updates it in place keeps ONE buffer of it
     (`inference/decode.py::_step_core`).  Body, block geometry and
-    arithmetic are those of the 4-D form, which stays for callers that
-    hold a single layer.
+    arithmetic are those of the single-layer form, which stays for
+    callers that hold one layer.
 
     With int8 caches, `kv_scales` [2, H] f32 (k-scales row 0, v-scales
     row 1 — the per-(layer,head) scales of the quantized slot table,
@@ -674,15 +698,17 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     from jax.experimental.pallas import tpu as pltpu
 
     N, H, D = q.shape
-    Hc = k_cache.shape[-2]
-    G = H // Hc
     stacked = layer is not None
-    if stacked != (k_cache.ndim == 5):
+    if k_cache.ndim != 3 + stacked or k_cache.shape[-3] != N \
+            or k_cache.shape[-1] % D:
         raise ValueError(
-            "decode_attention: a stacked table [L, N, S, H, D] goes with "
-            "a static `layer`, a single layer [N, S, H, D] without one "
-            "(got %d-D caches, layer=%r)" % (k_cache.ndim, layer))
-    S = k_cache.shape[-3]
+            "decode_attention: a stacked table [L, N, S, Hc * D] goes "
+            "with a static `layer`, a single layer [N, S, Hc * D] "
+            "without one, N and D the queries' %d and %d (got caches %s, "
+            "layer=%r)" % (N, D, tuple(k_cache.shape), layer))
+    S, W = k_cache.shape[-2:]
+    Hc = W // D
+    G = H // Hc
     scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
     kv_dtype = jnp.dtype(k_cache.dtype)
     quant = kv_dtype == jnp.dtype(jnp.int8)
@@ -711,54 +737,39 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     last = kv_last_block(lengths, bkv, n_blocks, xp=jnp)
     if stacked:
         # the layer's axis is squeezed out of the block: the body sees
-        # the (1, bkv, H, D) tile it always saw
+        # the (1, bkv, Hc * D) tile of the single-layer form
         layer = int(layer)
         kv_spec = pl.BlockSpec(
-            (None, 1, bkv, Hc, D), lambda b, j, len_ref, last_ref: (
-                layer, b, jnp.minimum(j, last_ref[b]), 0, 0))
+            (None, 1, bkv, W), lambda b, j, len_ref, last_ref: (
+                layer, b, jnp.minimum(j, last_ref[b]), 0))
     else:
         kv_spec = pl.BlockSpec(
-            (1, bkv, Hc, D), lambda b, j, len_ref, last_ref: (
-                b, jnp.minimum(j, last_ref[b]), 0, 0))
-    if G > 1:
-        q = q.reshape(N, Hc, G, D).transpose(0, 2, 1, 3).reshape(N, H, D)
-
-    def per_group(fn, rows):
-        """`fn` of each group's Hc rows of `rows` [G * Hc, ...] against
-        the resident K or V tile, stacked back group-major."""
-        if G == 1:
-            return fn(rows)
-        return jnp.concatenate(
-            [fn(rows[g * Hc:(g + 1) * Hc]) for g in range(G)], axis=0)
+            (1, bkv, W), lambda b, j, len_ref, last_ref: (
+                b, jnp.minimum(j, last_ref[b]), 0))
+    # own[a, c]: lane c of a row belongs to the K/V head query head a reads
+    own = (jnp.arange(W)[None, :] // D) == (jnp.arange(H)[:, None] // G)
+    q_bd = jnp.where(own, jnp.tile(q, (1, 1, Hc)), 0)     # [N, H, W]
+    contract = functools.partial(
+        jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
     def tile(ctx):
         q_ref, k_ref, v_ref = ctx.ins[:3]
         len_ref = ctx.scalars[0]
         acc_ref, m_ref, l_ref = ctx.scratch
-        qb = q_ref[0]                              # [H, D]
-        kb = _stage_dequant(k_ref[0].transpose(1, 0, 2),
-                            jnp.float32)           # [H, BKV, D]
-        vb = _stage_dequant(v_ref[0].transpose(1, 0, 2), jnp.float32)
-        length = len_ref[ctx.ids[0]]
-        # elementwise-multiply + lane reduction instead of a matmul:
-        # one query row per head makes this VPU work, and the step is
-        # memory-bound on the K/V stream anyway (ROOFLINE.md)
-        # (widen q before the [H, 1, D] broadcast: Mosaic has no
-        # layout for that reshape of a packed bf16 tile unless H fills
-        # its 16 sublanes)
-        s = per_group(
-            lambda qg: jnp.sum(qg.astype(jnp.float32)[:, None, :] * kb,
-                               axis=-1), qb) * scale   # [H, BKV]
+        kb = _stage_dequant(k_ref[0], jnp.float32)     # [BKV, W]
+        vb = _stage_dequant(v_ref[0], jnp.float32)
+        s = contract(q_ref[0].astype(jnp.float32), kb,
+                     (((1,), (1,)), ((), ()))) * scale  # [H, BKV]
         if quant:
             # per-head K scale folds into the score scale, once per
             # score element — never per streamed cache element
             s = s * ctx.ins[3][0]                  # [H, 1] broadcast
         kpos = ctx.reduce_id * bkv + jax.lax.broadcasted_iota(
             jnp.int32, (H, bkv), 1)
-        s = jnp.where(kpos >= length, _NEG_INF, s)
+        s = jnp.where(kpos >= len_ref[ctx.ids[0]], _NEG_INF, s)
         _online_softmax_tile(
-            s, lambda p: per_group(
-                lambda pg: jnp.sum(pg[:, :, None] * vb, axis=1), p),
+            s, lambda p: contract(p, vb, (((1,), (0,)), ((), ()))),
             acc_ref, m_ref, l_ref)
 
     def finalize(ctx):
@@ -769,8 +780,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
             o = o * ctx.ins[3][1]                  # per-head V scale
         o_ref[0] = o.astype(o_ref.dtype)
 
-    operands = [q, k_cache, v_cache]
-    q_spec = pl.BlockSpec((1, H, D), lambda b, j, *_: (b, 0, 0))
+    operands = [q_bd, k_cache, v_cache]
+    q_spec = pl.BlockSpec((1, H, W), lambda b, j, *_: (b, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     if quant:
         operands.append(jnp.asarray(kv_scales, jnp.float32).reshape(
@@ -783,8 +794,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         reduce_axis=1,
         in_specs=in_specs,
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((N, H, D), q.dtype),
-        scratch=[pltpu.VMEM((H, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((N, H, W), q.dtype),
+        scratch=[pltpu.VMEM((H, W), jnp.float32),
                  pltpu.VMEM((H, _MIN_LANES), jnp.float32),
                  pltpu.VMEM((H, _MIN_LANES), jnp.float32)],
         scratch_fill=(0.0, _NEG_INF, 0.0),
@@ -795,9 +806,10 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         # the index maps can read them: the mask's and the stream's
         scalar_prefetch=(lengths, last),
         interpret=interpret)
-    if G > 1:
-        out = out.reshape(N, G, Hc, D).transpose(0, 2, 1, 3).reshape(N, H, D)
-    return out
+    # row a holds head a's result on the lanes of its K/V head, and on
+    # the others what it would be against their values: take its own
+    return jnp.sum(jnp.where(own.reshape(H, Hc, D),
+                             out.reshape(N, H, Hc, D), 0), axis=2)
 
 
 def latent_decode_attention_reference(q, table, lengths, value_lanes,
@@ -931,9 +943,10 @@ def decode_attention_head_slice(q, k_cache, v_cache, lengths, head_offset,
     """Tensor-parallel entry (SERVING.md "Tensor-parallel compute"):
     decode attention over one member's RESIDENT head block of the slot
     table. q/k_cache/v_cache are already the LOCAL head shards
-    ([N, Hl, D] / [N, S, Hl, D], Hl = n_local_heads; or the stacked
-    local table [L, N, S, Hl, D] with a static `layer`, as in
-    `decode_attention`), but `kv_scales`
+    ([N, Hl, D] / [N, S, Hl * D], Hl = n_local_heads: the member's
+    contiguous lanes of the flat row; or the stacked local table
+    [L, N, S, Hl * D] with a static `layer`, as in `decode_attention`),
+    but `kv_scales`
     arrives as the FULL per-layer table [2, H_total] (or [2, H_total,
     1]) — the scales are baked compile-time constants shared by every
     member, so each member dynamic-slices its own [2, Hl] window at

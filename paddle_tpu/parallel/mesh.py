@@ -263,17 +263,19 @@ class MeshGroup:
         return self.replicated()
 
     def kv_sharding(self, shape):
-        """At-rest sharding for a [L, n_slots, S, H, Dh] KV slot table:
-        heads first (the per-head independence axis the decode kernel
-        already respects), then slots, then layers; replicate only when
-        nothing divides."""
+        """At-rest sharding for a [L, n_slots, S, H * Dh] KV slot table
+        (a position one flat row, its heads' features side by side): the
+        row's axis first (where the mesh divides the heads a member holds
+        whole heads, its contiguous H / m * Dh lanes: the per-head
+        independence axis the decode kernel already respects), then
+        slots, then layers; replicate only when nothing divides."""
         n = self.mesh_size
         shape = tuple(int(s) for s in shape)
-        if len(shape) != 5:
+        if len(shape) != 4:
             return self.param_sharding(shape)
         for ax in (3, 1, 0):
             if shape[ax] >= n and shape[ax] % n == 0:
-                return self._axis_sharding(5, ax)
+                return self._axis_sharding(4, ax)
         return self.replicated()
 
 

@@ -97,7 +97,7 @@ def test_the_meta_describes_the_stack(opened):
                                                                       3)
     # the K/V tables hold the ATTENTION layer only, by its K/V heads; the
     # conv state is K-1 inputs a conv layer
-    assert pred.table_shape(3) == (1, 3, 64, 2, 8)
+    assert pred.table_shape(3) == (1, 3, 64, 2 * 8)
     assert pred.conv_state_shape(3) == (4, 3, K - 1, 64)
     assert pred.kv_cache_bytes(3) == 2 * 3 * 64 * 2 * 8 * 4
     assert pred.conv_state_bytes(3) == 4 * 3 * 2 * 64 * 4
@@ -289,15 +289,17 @@ def test_grouped_query_kernel_matches_the_reference(lengths):
     rng = np.random.default_rng(5)
     N, S, H, Hkv, D = 4, 64, 32, 8, 64
     q = jnp.asarray(rng.standard_normal((N, H, D)), jnp.float32)
-    kc = jnp.asarray(rng.standard_normal((2, N, S, Hkv, D)), jnp.float32)
-    vc = jnp.asarray(rng.standard_normal((2, N, S, Hkv, D)), jnp.float32)
+    apart = [jnp.asarray(rng.standard_normal((2, N, S, Hkv, D)), jnp.float32)
+             for _ in "kv"]
+    # as a slot table holds them: K/V head c is lanes c * D .. of the row
+    kc, vc = (t.reshape(2, N, S, Hkv * D) for t in apart)
     lens = jnp.asarray(lengths, jnp.int32)
     want = pk.decode_attention_reference(q, kc[1], vc[1], lens, scale=0.125)
     # the oracle's own grouping, spelled out for two heads
     for a in (5, 30):
         one = pk.decode_attention_reference(
-            q[:, a:a + 1], kc[1][:, :, a // 4:a // 4 + 1],
-            vc[1][:, :, a // 4:a // 4 + 1], lens, scale=0.125)
+            q[:, a:a + 1], *(t[1][:, :, a // 4] for t in apart), lens,
+            scale=0.125)
         np.testing.assert_allclose(np.asarray(want[:, a]),
                                    np.asarray(one[:, 0]), atol=1e-6)
     live = np.asarray(lengths) > 0          # length 0: well-defined garbage

@@ -49,8 +49,10 @@ def _qkv(B, S, H, D, dtype, seed=0):
 
 
 def _decode_operands(N, S, H, D, kv_dtype, seed=1):
-    """(q, k_cache, v_cache, lengths, kv_scales) for one decode shape;
-    int8 caches come with matching per-head scales."""
+    """(q, k_cache, v_cache, lengths, kv_scales) for one decode shape,
+    the caches as a slot table holds them, [N, S, H * D] (a position one
+    flat row, the heads side by side); int8 caches come with matching
+    per-head scales."""
     import jax.numpy as jnp
     rng = np.random.RandomState(seed)
     q = jnp.asarray(rng.randn(N, H, D).astype(np.float32))
@@ -58,13 +60,16 @@ def _decode_operands(N, S, H, D, kv_dtype, seed=1):
     vf = rng.randn(N, S, H, D).astype(np.float32)
     lengths = np.concatenate([[S], rng.randint(1, S + 1, size=N - 1)]) \
         .astype(np.int32) if N > 1 else np.array([S], np.int32)
+    def flat(t):
+        return jnp.asarray(t.reshape(N, S, H * D))
+
     if kv_dtype != "int8":
-        return q, jnp.asarray(kf), jnp.asarray(vf), lengths, None
+        return q, flat(kf), flat(vf), lengths, None
     ks = np.abs(kf).max(axis=(0, 1, 3)) * 1.25 / 127.0
     vs = np.abs(vf).max(axis=(0, 1, 3)) * 1.25 / 127.0
-    k8 = jnp.asarray(np.clip(np.round(
+    k8 = flat(np.clip(np.round(
         kf / ks[None, None, :, None]), -127, 127).astype(np.int8))
-    v8 = jnp.asarray(np.clip(np.round(
+    v8 = flat(np.clip(np.round(
         vf / vs[None, None, :, None]), -127, 127).astype(np.int8))
     return q, k8, v8, lengths, np.stack([ks, vs]).astype(np.float32)
 
@@ -136,6 +141,60 @@ def test_decode_family_parity(kv_dtype, N, S, bkv):
     assert float(jnp.abs(out - ref).max()) < 2e-5
 
 
+# GPT-2 small's heads, OLMoE's, LFM2's grouped-query table: (H, Hc, Dh)
+_FLAT_GEOMETRIES = [(12, 12, 64), (16, 16, 128), (32, 8, 64)]
+
+
+@pytest.mark.parametrize("form", ["single", "stacked"])
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("H,Hc,D", _FLAT_GEOMETRIES,
+                         ids=["h%d_kv%d_d%d" % g for g in _FLAT_GEOMETRIES])
+def test_flat_row_body_against_the_reference(H, Hc, D, kv_dtype, form):
+    """The body over FLAT rows [.., S, Hc * D] (block-diagonal queries,
+    both contractions on the MXU at fp32) against the plain-XLA reference,
+    whole and one head at a time over that head's lanes of the row (K/V
+    head c is lanes c * D .. (c + 1) * D), at the served stacks' head
+    geometries: lengths 0, 1, a block's edge and its neighbours, S; fp32 and
+    int8 caches; a single layer and a layer of the stacked table.  No
+    farther from the reference than the VPU body it replaced was on these
+    very cases (the parent's 4.8e-7 to 1.13e-6 beside this body's 5.1e-7
+    to 1.13e-6, values up to 3.5: PR 41's readings), and a slot of length
+    0 is finite."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    N, S, bkv = 6, 64, 16
+    q, k, v, _, scales = _decode_operands(N, S, Hc, D, kv_dtype, seed=H)
+    if H != Hc:
+        q = jnp.asarray(np.random.RandomState(H).randn(N, H, D)
+                        .astype(np.float32))
+    lengths = np.array([0, 1, bkv - 1, bkv, bkv + 1, S], np.int32)
+    flat = [k, v]
+    layer = None
+    if form == "stacked":
+        layer = 1
+        flat = [jnp.stack([jnp.zeros_like(t), t]) for t in flat]
+    if kv_dtype == "int8" and H != Hc:
+        with pytest.raises(ValueError, match="query heads over"):
+            pk.decode_attention(q, *flat, lengths, block_kv=bkv,
+                                kv_scales=np.ones((2, H), np.float32),
+                                layer=layer)
+        return
+    got = np.asarray(pk.decode_attention(q, *flat, lengths, block_kv=bkv,
+                                         kv_scales=scales, layer=layer))
+    want = np.asarray(pk.decode_attention_reference(q, k, v, lengths,
+                                                    kv_scales=scales))
+    assert got.shape == (N, H, D) and np.isfinite(got).all()
+    assert np.abs(got - want)[1:].max() <= 1.2e-6
+    G = H // Hc
+    for a in range(0, H, 5):
+        lanes = slice(a // G * D, (a // G + 1) * D)
+        alone = np.asarray(pk.decode_attention_reference(
+            q[:, a:a + 1], k[..., lanes], v[..., lanes], lengths,
+            kv_scales=None if scales is None else scales[:, a:a + 1]))
+        np.testing.assert_allclose(alone[:, 0], want[:, a], rtol=2e-6,
+                                   atol=2e-6)
+
+
 # ---------------------------------------------------------------------------
 # the bounded K/V stream: slot b's blocks stop at kv_last_block(lengths[b])
 # ---------------------------------------------------------------------------
@@ -169,16 +228,17 @@ def _bounded_case(form, kv_dtype, seed=5):
         return jnp.stack([other, t])
 
     if form.startswith("head_slice"):
-        # a member's 2 heads of 4, its scales sliced out of the full table
+        # a member's 2 heads of 4 (of 8 lanes each), its scales sliced out
+        # of the full table
         def call(lengths, k, v):
             return pk.decode_attention_head_slice(
-                q[:, 2:], table(k[:, :, 2:]), table(v[:, :, 2:]), lengths,
+                q[:, 2:], table(k[..., 16:]), table(v[..., 16:]), lengths,
                 head_offset=2, n_local_heads=2, block_kv=_BKV,
                 kv_scales=scales, layer=layer)
 
         def ref(lengths, k, v):
             return pk.decode_attention_reference(
-                q[:, 2:], k[:, :, 2:], v[:, :, 2:], lengths,
+                q[:, 2:], k[..., 16:], v[..., 16:], lengths,
                 kv_scales=None if scales is None else scales[:, 2:])
     else:
         def call(lengths, k, v):
@@ -244,7 +304,7 @@ def test_decode_never_reads_past_a_slots_last_live_block(form, whole_rows):
     call, _, k, v = _bounded_case(form, "float32")
     lengths = _EDGE_LENGTHS
     dead_from = -(-lengths // _BKV) * _BKV                     # [N]
-    dead = (np.arange(_S)[None] >= dead_from[:, None])[:, :, None, None]
+    dead = (np.arange(_S)[None] >= dead_from[:, None])[:, :, None]
     assert dead.any()
     clean = np.asarray(call(lengths, k, v))
     kp, vp = (jnp.where(dead, np.nan, t) for t in (k, v))

@@ -89,10 +89,12 @@ class TestHeadSliceParity:
     N, S, H, D = 3, 16, 4, 8
 
     def _case(self, rng, dtype=np.float32):
+        """q [N, H, D] and caches as a slot table holds them, [N, S,
+        H * D]: a member's heads are contiguous lanes of the row."""
         q = rng.standard_normal((self.N, self.H, self.D)).astype(
             np.float32)
-        k = rng.standard_normal((self.N, self.S, self.H, self.D))
-        v = rng.standard_normal((self.N, self.S, self.H, self.D))
+        k = rng.standard_normal((self.N, self.S, self.H * self.D))
+        v = rng.standard_normal((self.N, self.S, self.H * self.D))
         if dtype == np.int8:
             k = np.clip(k * 40, -127, 127).astype(np.int8)
             v = np.clip(v * 40, -127, 127).astype(np.int8)
@@ -121,8 +123,9 @@ class TestHeadSliceParity:
         parts = []
         for i in range(m):
             sl = slice(i * hl, (i + 1) * hl)
+            lanes = slice(i * hl * self.D, (i + 1) * hl * self.D)
             parts.append(np.asarray(decode_attention_head_slice(
-                q[:, sl], k[:, :, sl], v[:, :, sl], lengths,
+                q[:, sl], k[..., lanes], v[..., lanes], lengths,
                 head_offset=i * hl, n_local_heads=hl)))
         self._pin(np.concatenate(parts, axis=1), full)
 
@@ -140,8 +143,9 @@ class TestHeadSliceParity:
             sl = slice(i * hl, (i + 1) * hl)
             # each member receives the FULL [2, H] table and slices
             # its own window at the traced head offset
+            lanes = slice(i * hl * self.D, (i + 1) * hl * self.D)
             parts.append(np.asarray(decode_attention_head_slice(
-                q[:, sl], k[:, :, sl], v[:, :, sl], lengths,
+                q[:, sl], k[..., lanes], v[..., lanes], lengths,
                 head_offset=i * hl, n_local_heads=hl,
                 kv_scales=scales)))
         self._pin(np.concatenate(parts, axis=1), full)

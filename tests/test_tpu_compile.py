@@ -74,8 +74,9 @@ DECODE_DTYPES = {"fp32": ("float32", "float32"),
 
 
 def decode_specs(N, S, H, D, q_dt, kv_dt, h_scales=None):
-    specs = [((N, H, D), q_dt), ((N, S, H, D), kv_dt), ((N, S, H, D), kv_dt),
-             ((N,), "int32")]
+    # the caches as a slot table holds them: a position one flat row
+    specs = [((N, H, D), q_dt), ((N, S, H * D), kv_dt),
+             ((N, S, H * D), kv_dt), ((N,), "int32")]
     if kv_dt == "int8":
         specs.append(((2, h_scales or H), "float32"))
     return specs
@@ -94,11 +95,12 @@ def test_decode_attention_compiles(one_chip, geometry, dtypes):
         *DECODE_GEOMETRIES[geometry], q_dt, kv_dt))
 
 
-# the decode cells' slot tables as the step holds them (rows padded to the
-# kernel's tile, `decode.table_row`): (N, S, query heads, K/V heads, D)
-CELL_TABLES = {"gpt2_small": (32, 1024, 16, 16, 128),
+# the decode cells' slot tables as the step holds them (a position one flat
+# row of its K/V heads' values, `decode.slot_state_shapes`): (N, S, query
+# heads, K/V heads, D), the table's last axis K/V heads x D
+CELL_TABLES = {"gpt2_small": (32, 1024, 12, 12, 64),
                "olmoe_1b_7b": (8, 4096, 16, 16, 128),
-               "lfm2_24b_a2b": (32, 4096, 32, 8, 128)}
+               "lfm2_24b_a2b": (32, 4096, 32, 8, 64)}
 
 
 @pytest.mark.parametrize("cell,kv_dt", [
@@ -119,20 +121,24 @@ def test_bounded_decode_attention_compiles_at_the_cells_tables(
                                    kv_scales=scales[0] if scales else None,
                                    layer=1)
 
-    specs = [((N, H, D), "float32"), ((2, N, S, Hc, D), kv_dt),
-             ((2, N, S, Hc, D), kv_dt), ((N,), "int32")]
+    specs = [((N, H, D), "float32"), ((2, N, S, Hc * D), kv_dt),
+             ((2, N, S, Hc * D), kv_dt), ((N,), "int32")]
     if kv_dt == "int8":
         specs.append(((2, H), "float32"))
     text = compile_for_chip(fn, one_chip, *specs)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
-    # no copy of a table's layer is made for the call
-    assert "[%d,%d,%d,%d]" % (N, S, Hc, D) not in text
+    # no copy of a table's layer is made for the call, and the table goes
+    # to it in the layout the device gives it (row-major: nothing pinned,
+    # nothing padded)
+    assert "[%d,%d,%d]" % (N, S, Hc * D) not in text
+    assert re.search(r"\[2,%d,%d,%d\]\{3,2,1,0" % (N, S, Hc * D), text)
 
 
 @pytest.mark.parametrize("kv_dt", ["float32", "int8"])
 def test_decode_attention_head_slice_compiles(one_chip, kv_dt):
     """One member's head block of a 4-way tensor-parallel split of the
-    12-head table: Hl = 3, scales sliced from the full [2, 12] table."""
+    12-head table: Hl = 3 (its 192 lanes of the 768-lane row), scales
+    sliced from the full [2, 12] table."""
     N, S, H, D, Hl = 8, 1024, 12, 64, 3
 
     def fn(q, k, v, lengths, *scales):
@@ -194,7 +200,8 @@ def test_routed_ffn_compiles_to_grouped_matmul_kernels(one_chip):
 # the decode step as a whole: the slot table is updated in place (PR 27).
 # Every argument is left in the device's OWN layout here, as at run time:
 # jax 0.9 loses a pinned output layout on a persistent-cache hit, so the
-# table must be row-major by the device's choice (`table_row`), not by a pin
+# table must be row-major by the device's choice (`slot_state_shapes`), not
+# by a pin
 # ---------------------------------------------------------------------------
 
 STEP_MODELS = {
@@ -247,8 +254,8 @@ def assert_table_updated_in_place(compiled, table_shape, n_kernels,
     bytes), and no select, concatenate, copy, pad or transpose whose result
     is a whole table or a whole layer."""
     import re
-    L, N, S, H, D = table_shape
-    table = L * N * S * H * D * 4
+    L, N, S, W = table_shape
+    table = L * N * S * W * 4
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= 2 * table, \
         (ma.alias_size_in_bytes, table)
@@ -256,7 +263,7 @@ def assert_table_updated_in_place(compiled, table_shape, n_kernels,
         ma.temp_size_in_bytes
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= n_kernels
-    big = re.compile(r"\[(%d,)?%d,%d,%d,%d\]" % (L, N, S, H, D))
+    big = re.compile(r"\[(%d,)?%d,%d,%d\]" % (L, N, S, W))
     bad = []
     for ln in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ([\w\-]+)\(", ln)
@@ -281,9 +288,18 @@ def test_decode_step_updates_the_table_in_place(one_chip, model):
     pred, state = described_predictor(meta, device)
     specs = pred._step_specs(slots)
     compiled = compile_phase(pred, state, pred._step_math(), specs)
-    # rows padded to the kernel's tile: (12, 64) -> (16, 128), (16, 128) as
-    # it is, so that row-major is the device's own layout for the table
-    assert pred.table_row() == (16, 128)
+    # a position is one flat row of its heads' values, 768 lanes (2048 at
+    # OLMoE): whole tiles, so row-major is the device's own layout for the
+    # table and the table holds the data's bytes and no other
+    assert pred.table_shape(slots) == (
+        meta["n_layers"], slots, meta["max_seq_len"], meta["d_model"])
+    ma = compiled.memory_analysis()
+    if not routed:
+        # K and V of 32 slots are 2 x 1.21 GB where padded rows held 2 x
+        # 3.22: the step's arguments (tables and 0.65 GB of weights) were
+        # 7.09 GB
+        assert pred.kv_cache_bytes(slots) == 2 * 12 * 32 * 1024 * 768 * 4
+        assert ma.argument_size_in_bytes <= 3.3e9, ma.argument_size_in_bytes
     # its `while` body carries the table, and no table- or layer-sized copy
     # may sit inside the loop either.  XLA hoists the bf16 rounding of the
     # dense matmuls' weights out of the loop: the head and, a layer, the
@@ -296,6 +312,12 @@ def test_decode_step_updates_the_table_in_place(one_chip, model):
     text = assert_table_updated_in_place(
         compiled, pred.table_shape(slots), n_kernels=L,
         temporaries=hoisted + 0.02e9)
+    # one Mosaic call a layer and no other (`gpt2_small.json` takes every
+    # custom call of the step for the kernel)
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len([ln for ln in calls if "kernel_metadata={}" in ln]) == L
+    assert routed or len(calls) == L
     # ONE executable for every trip count: the trips of a dispatch are its
     # last argument, a scalar the one `while` is bounded by at run time
     assert [(s.shape, str(s.dtype)) for s in specs[-2:]] \
@@ -330,6 +352,55 @@ def test_step_logits_updates_the_table_in_place(one_chip):
     assert " while(" not in text
 
 
+@pytest.mark.parametrize("members", [2, 4])
+def test_tensor_parallel_step_holds_a_flat_shard_a_member(members):
+    """The step of a tensor-parallel mesh (`_tp_shard_map`) for `members`
+    described v5e chips, GPT-2 small's heads at a vocabulary the mesh
+    divides, two layers: a member's shard of the flat table is a flat table
+    of its own, [L, N, S, H / m * Dh], which its one Mosaic call a layer
+    takes as it is.  Two members hold 384 lanes, whole tiles: row-major by
+    the device's choice, both tables aliased, NO temporaries (a table that
+    kept the heads apart, [.., 6, 64], cost 0.54 GB of copies here; 0.27
+    at four members: PR 41's readings of the parent).  Four members hold 3
+    heads = 192 lanes, which the device lays out with S innermost: one
+    copy of a shard at 256 lanes remains (0.07 GB), a quarter of the
+    parent's."""
+    from jax.experimental import topologies
+    from paddle_tpu.inference import decode as dec
+    from paddle_tpu.parallel.mesh import MeshGroup
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip("cannot describe a v5e topology: %s" % e)
+    group = MeshGroup(topo.devices[:members])
+    meta = dict(STEP_MODELS["gpt2_small"][0], vocab_size=50304, n_layers=2)
+    pred = object.__new__(dec.GenerativePredictor)
+    pred.meta, pred._block_meta = meta, dec.block_of(meta)
+    pred._kv_dtype, pred._kv_scales = "float32", None
+    pred._tp_size, pred._tp_prefill_seq, pred._device = members, 128, group
+    slots = 32
+    assert pred.table_shape(slots) == (2, slots, 1024, 768)
+    state, specs = pred._mesh_specs(
+        group, {n: jax.ShapeDtypeStruct(shape, np.float32) for n, shape
+                in dec.decode_state_shapes(meta).items()},
+        pred._step_specs(slots), jax)
+    assert specs[0].sharding.shard_shape(specs[0].shape) \
+        == (2, slots, 1024, 768 // members)
+    fn = pred._tp_shard_map(pred._step_math(tp=pred._tp_ctx()),
+                            pred._step_math(), state, specs, group, jax)
+    with pk.mosaic_lowering():
+        compiled = pred._phase_jit(fn, range(2)).lower(
+            state, *specs).compile()
+    shard = 2 * slots * 1024 * (768 // members) * 4
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * shard
+    assert ma.temp_size_in_bytes < (shard / 10 if members == 2
+                                    else 1.5 * shard), ma.temp_size_in_bytes
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+
+
 def _verify_specs(pred, slots, k):
     cache, _, lengths, _, active = pred._table_specs(slots)
     return (cache, cache, lengths,
@@ -359,7 +430,7 @@ def test_int8_table_step_compiles_in_place(one_chip):
     compiled = compile_phase(pred, state, pred._step_math(),
                              pred._step_specs(slots))
     ma = compiled.memory_analysis()
-    table = int(np.prod(pred.table_shape(slots)))    # int8, rows (16, 128)
+    table = int(np.prod(pred.table_shape(slots)))    # int8, rows of 768
     assert ma.alias_size_in_bytes >= 2 * table
     assert ma.temp_size_in_bytes < table / 10
 
@@ -378,16 +449,17 @@ LFM2 = dict(vocab_size=65536, d_model=2048, n_heads=32, n_kv_heads=8,
 
 
 def test_grouped_query_decode_attention_compiles(one_chip):
-    """32 query heads over a table of 8 K/V heads padded to (8, 128) rows,
-    stacked, as `_attend_table` calls it for LFM2: Mosaic takes the
-    group-major body (interpret mode takes anything)."""
-    N, S, Hq, Hkv, D = 32, 4096, 32, 8, 128
+    """32 query heads over a table of 8 K/V heads of 64, flat rows of 512
+    lanes, stacked, as `_attend_table` calls it for LFM2: Mosaic takes the
+    block-diagonal queries of four heads a K/V head (interpret mode takes
+    anything)."""
+    N, S, Hq, Hkv, D = 32, 4096, 32, 8, 64
     with pk.mosaic_lowering():
         compile_for_chip(
             lambda q, k, v, n: pk.decode_attention(q, k, v, n, scale=0.125,
                                                    layer=0),
             one_chip, ((N, Hq, D), "float32"),
-            ((1, N, S, Hkv, D), "float32"), ((1, N, S, Hkv, D), "float32"),
+            ((1, N, S, Hkv * D), "float32"), ((1, N, S, Hkv * D), "float32"),
             ((N,), "int32"))
 
 
@@ -399,7 +471,7 @@ def test_hybrid_step_updates_both_kinds_of_slot_state_in_place(one_chip):
     slots = 32
     device = list(one_chip.device_set)[0]
     pred, state = described_predictor(LFM2, device)
-    assert pred.table_shape(slots) == (1, slots, 4096, 8, 128)
+    assert pred.table_shape(slots) == (1, slots, 4096, 8 * 64)
     assert pred.conv_state_shape(slots) == (2, slots, 2, 2048)
     specs = pred._step_specs(slots)
     compiled = compile_phase(pred, state, pred._step_math(), specs,

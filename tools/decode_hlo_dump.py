@@ -4,8 +4,9 @@ the decode phases of ONE tree, to be compared with another tree's by `cmp`.
     python tools/decode_hlo_dump.py <tree> <out dir>      # once a tree
     diff -rq <out parent> <out change>
 
-For tiny GPT-2-shaped and OLMoE-shaped artifacts (fp32 and int8 caches, plain
-rows and rows padded to the kernel's tile) it writes the jaxpr, the StableHLO
+For tiny GPT-2-shaped and OLMoE-shaped artifacts (fp32 and int8 caches; the
+slot tables hold flat rows, `decode.slot_state_shapes`) it writes the jaxpr,
+the StableHLO
 and the CPU's optimized HLO of `step` (the window), `step_logits` and
 `prefill`; and, compiled for a DESCRIBED v5e with the Mosaic kernels forced
 (no chip: the `on-chip-measurement` guide, section 2), the TPU's optimized
@@ -93,32 +94,23 @@ def _phases(pred, n_slots, bucket):
                          jax.ShapeDtypeStruct((), np.int32)))}
 
 
-plain_row = dec.table_row
-for padded in (False, True):
-    if padded:
-        dec.table_row = lambda h, d, dev: (-(-int(h) // 8) * 8,
-                                           -(-int(d) // 128) * 128)
-    for name, block in (("gpt2", None),
-                        ("olmoe", dict(OLMOE, n_experts=8,
-                                       experts_per_token=2,
-                                       expert_width=32))):
-        d = tempfile.mkdtemp()
-        dec.build_tiny_decode_model(
-            d, vocab_size=97, d_model=64, n_heads=4, n_layers=2,
-            max_seq_len=64, prefill_buckets=[16, 32], block=block)
-        for kv in ("float32", "int8"):
-            pred = dec.load_decode_predictor(d, kv_cache_dtype=kv)
-            state = {n: jax.ShapeDtypeStruct(np.shape(v),
-                                             np.asarray(v).dtype)
-                     for n, v in pred._state_host.items()}
-            for ph, (fn, specs) in phases(pred, 4, 16).items():
-                tag = "%s_%s_%s_%s" % (name, kv,
-                                       "pad" if padded else "plain", ph)
-                low = jax.jit(fn).lower(state, *specs)
-                write(tag + ".stablehlo", low.as_text())
-                write(tag + ".hlo", low.compile().as_text())
-                write(tag + ".jaxpr", str(jax.make_jaxpr(fn)(state, *specs)))
-dec.table_row = plain_row
+for name, block in (("gpt2", None),
+                    ("olmoe", dict(OLMOE, n_experts=8, experts_per_token=2,
+                                   expert_width=32))):
+    d = tempfile.mkdtemp()
+    dec.build_tiny_decode_model(
+        d, vocab_size=97, d_model=64, n_heads=4, n_layers=2,
+        max_seq_len=64, prefill_buckets=[16, 32], block=block)
+    for kv in ("float32", "int8"):
+        pred = dec.load_decode_predictor(d, kv_cache_dtype=kv)
+        state = {n: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype)
+                 for n, v in pred._state_host.items()}
+        for ph, (fn, specs) in phases(pred, 4, 16).items():
+            tag = "%s_%s_%s" % (name, kv, ph)
+            low = jax.jit(fn).lower(state, *specs)
+            write(tag + ".stablehlo", low.as_text())
+            write(tag + ".hlo", low.compile().as_text())
+            write(tag + ".jaxpr", str(jax.make_jaxpr(fn)(state, *specs)))
 
 # the cells' widths on a described v5e, Mosaic kernels in the text
 from jax.experimental import topologies  # noqa: E402

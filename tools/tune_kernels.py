@@ -157,6 +157,8 @@ def tune_decode(shapes, kv_dtypes, iters):
                 scales = np.stack([ks, vs]).astype(np.float32)
             else:
                 kc, vc, scales = jnp.asarray(kf), jnp.asarray(vf), None
+            # as a slot table holds a position: one flat row of H * D
+            kc, vc = (t.reshape(N, S, H * D) for t in (kc, vc))
             best, best_ms = None, None
             for bkv in _edges(S, 512 if _on_tpu[0] else 64):
                 fn = jax.jit(

@@ -861,11 +861,14 @@ def _decode_report(path, meta, decode_slots, device, what,
     # tests/test_resources.py)
     kv_elem = 1 if kv_dtype == "int8" else 4
     kv_scales = 2 * L * H * 4 if kv_dtype == "int8" else 0
-    kv_shape, conv_shape = slot_state_shapes(meta, n_slots, device)
-    # (an MLA stack: ONE latent table [layers, n_slots, S, Rp], no V)
+    kv_shape, conv_shape, ssm_shape = slot_state_shapes(meta, n_slots,
+                                                        device)
+    # (an MLA stack: ONE latent table [layers, n_slots, S, Rp], no V; a
+    # stack with attention+ssm layers: its fp32 scanned-state table too)
     n_tables = 1 if layer_kinds(meta)[0][0] == "mla" else 2
     rep.kv_cache_bytes = (n_tables * int(np.prod(kv_shape)) * kv_elem
-                          + kv_scales)
+                          + kv_scales
+                          + (4 * int(np.prod(ssm_shape)) if ssm_shape else 0))
     # decode-step working set: one token's activations per slot, and the
     # conv layers' carried state (K-1 inputs a slot and layer, fp32)
     rep.activation_peak_bytes = n_slots * D * 4 * (L + 2) + (

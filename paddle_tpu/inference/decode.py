@@ -15,9 +15,12 @@ TOGETHER.  This module is the inference-side half of the answer
   AOT predictor is detected by `aot_meta.bin`.  The meta also DESCRIBES
   the decoder stack (`BLOCK_DEFAULTS`: LayerNorm | RMSNorm, learned |
   rotary positions, qk-norm over the projection or per head, multi-head
-  | grouped-query | latent attention, a layer's operator attention | a
-  gated short convolution (`layer_types`), leading dense SwiGLU layers,
-  ReLU MLP | dropless routed SwiGLU experts under a softmax or a sigmoid
+  | grouped-query | latent attention, a head size of its own
+  (`head_dim`), a layer's operator attention | a gated short convolution
+  | attention IN PARALLEL with a Mamba-2 state-space mixer
+  (`layer_types`), fixed muP multipliers, leading dense SwiGLU layers,
+  ReLU MLP | a dense SwiGLU in every layer | dropless routed SwiGLU
+  experts under a softmax or a sigmoid
   router, all of them or the run of them a member of an expert-parallel
   deployment holds, a shared expert beside them, a norm after each
   sublayer too, a head of its own | tied, matmul weights float32 |
@@ -25,19 +28,24 @@ TOGETHER.  This module is the inference-side half of the answer
   GPT-2-shaped block in every layer.
   A PHASE is: embed the tokens at their positions, run ONE per-layer
   function (`GenerativePredictor._block`) once a layer with the phase's
-  own `attend(q, k, v)` and `convolve(z, taps)`, apply the head.
+  own `attend(q, k, v)`, `convolve(z, taps)` and `scan(xs, B, C, dt, A)`,
+  apply the head.
   `_block` is the only spelling of a decoder layer and every phase
   runs every stack it can (prefill, the step and its fused window for
   all; the speculative verify and a rollback for stacks without a
-  recurrent layer); a phase owns only what `attend` does with K and V
-  and where `convolve` finds a position's earlier inputs.  Rows
+  recurrent layer); a phase owns only what `attend` does with K and V,
+  where `convolve` finds a position's earlier inputs and where `scan`
+  keeps a state-space mixer's state (a prefill scans the prompt in
+  chunks, `ssd_chunked_scan`; a step advances the slots' state by one
+  position).  Rows
   are written to a K/V slot table by ONE scatter (`_land`) and cleared
   by ONE scatter of zeros (`_clear_rows`).  What a placement or a phase
   cannot hold is refused by a typed error that names the meta key (the
   tensor-parallel lane any block but the default: its grammar has no
   rule for sharding experts; a mesh, a rollback, the speculative
-  phases and an int8 cache a stack with conv layers; a mesh, the
-  speculative phases and an int8 cache a stack of latent attention);
+  phases and an int8 cache a stack with conv layers or with a scanned
+  state; a mesh, the speculative phases and an int8 cache a stack of
+  latent attention);
 * a **prefill / decode phase split** (`GenerativePredictor`): prefill
   runs the whole prompt through the causal forward once per padded
   *prompt bucket* (each bucket's executable rides the persistent
@@ -47,17 +55,22 @@ TOGETHER.  This module is the inference-side half of the answer
   table — XLA compiles it exactly once per (n_slots) geometry, and
   every later step, whatever mix of requests occupies the slots, reuses
   that executable;
-* **slot-indexed state of two kinds** (`DecodeSession`,
+* **slot-indexed state of three kinds** (`DecodeSession`,
   `slot_state_shapes`): the KV cache, [attention layers, n_slots,
-  max_seq_len, K/V heads, head_dim] arrays, and for a stack with conv
-  layers their conv state, [conv layers, n_slots, taps - 1, d_model],
-  a fixed size a slot; a stack of latent attention holds, in the K/V
+  max_seq_len, K/V heads * head_dim] arrays; for a stack with layers
+  that convolve their conv state, [conv layers, n_slots, taps - 1,
+  channels], a fixed size a slot; and for a stack with attention+ssm
+  layers their SCANNED state, [ssm layers, n_slots, ssm_heads,
+  ssm_head_dim, ssm_state] fp32: a decayed running sum over all a
+  slot's positions, a fixed size, read and rewritten whole by every
+  token; a stack of latent attention holds, in the K/V
   tables' place and ONCE, [mla layers, n_slots, max_seq_len, row]: one
   row of kv_lora_rank + qk_rope_head_dim values a position, which its
   prefill expands to per-head keys and values and its decode step
   attends over as it is (`_mla_expanded`, `_mla_absorbed`); resident on
   the session's
-  device, ONE buffer each (K, V, conv state) that every write updates in
+  device, ONE buffer each (K, V, conv state, scanned state) that
+  every write updates in
   place (a step's rows, an admission, a release: each call is given
   the table donated and the session keeps the result; SERVING.md "The
   slot table is ONE buffer").  A request owns one slot from prefill to
@@ -293,15 +306,50 @@ BLOCK_DEFAULTS = (
     ("weight_dtype", "float32"),  # | "bfloat16": the matmul weights at rest
                                   # (`_bf16_at_rest`, `_contract`); gains,
                                   # the router and every activation fp32
+    ("head_dim", 0),              # a head's size; 0 = d_model // n_heads
+    # layer_types "attention+ssm": TWO mixers on the same normed input,
+    # summed into one residual: grouped-query attention and a Mamba-2
+    # (SSD) state-space mixer of `ssm_heads` heads of `ssm_head_dim`, a
+    # state of `ssm_state` values a head feature, B and C shared by
+    # `ssm_groups` groups of heads, a causal depthwise conv of
+    # `ssm_conv_kernel` taps (with a bias) in front.  Its slot state is of
+    # three kinds: K/V rows, the conv's last inputs, and the SCANNED state
+    # [ssm_heads, ssm_head_dim, ssm_state] fp32, which every token decays
+    # and adds to (`GenerativePredictor._ssm`).  A prefill scans a prompt
+    # in chunks of `ssm_chunk` positions
+    ("ssm_heads", 0),
+    ("ssm_head_dim", 0),
+    ("ssm_state", 0),
+    ("ssm_groups", 1),
+    ("ssm_conv_kernel", 0),
+    ("ssm_chunk", 128),
+    # fixed multipliers (muP), plain numbers; 1 = none
+    ("embedding_multiplier", 1.0),    # the embedding's rows
+    ("lm_head_multiplier", 1.0),      # the logits
+    ("attention_in_multiplier", 1.0),     # the input of wq / wk / wv
+    ("key_multiplier", 1.0),              # k, before it is rotated
+    ("attention_out_multiplier", 1.0),    # wo's result
+    ("ssm_in_multiplier", 1.0),           # the input of ssm_in
+    ("ssm_out_multiplier", 1.0),          # ssm_out's result
+    # ssm_in's result by segment, [z | x | B | C | dt]; () = none
+    ("ssm_multipliers", ()),
+    # a dense gated FFN's (gate pre-activation, down's result); () = none
+    ("mlp_multipliers", ()),
 )
 _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "position": ("learned", "rope"),
                   "qk_norm": (False, True, "head"),
-                  "ffn": ("relu_mlp", "moe_swiglu"),
+                  # "swiglu": ONE dense gated FFN of `dense_width` in every
+                  # layer
+                  "ffn": ("relu_mlp", "moe_swiglu", "swiglu"),
                   "router": ("softmax", "sigmoid_bias", "sigmoid"),
                   "head": ("untied", "tied"),
                   "weight_dtype": ("float32", "bfloat16")}
-_LAYER_TYPES = ("attention", "conv", "mla")
+_LAYER_TYPES = ("attention", "conv", "mla", "attention+ssm")
+# the kinds of slot state a layer's operator keeps (`slot_state_shapes`)
+_HOLDS = {"attention": ("kv",), "conv": ("conv",), "mla": ("kv",),
+          "attention+ssm": ("kv", "conv", "ssm")}
+_SSM_DIMS = ("ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups")
 _MLA_DIMS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
              "qk_rope_head_dim", "v_head_dim")
 
@@ -320,6 +368,8 @@ def block_of(meta):
             v = tuple(str(t) for t in v)
         elif key == "experts_held":
             v = tuple(int(t) for t in v)
+        elif key in ("ssm_multipliers", "mlp_multipliers"):
+            v = tuple(float(t) for t in v)
         else:
             v = type(default)(v)
         if key in _BLOCK_CHOICES and v not in _BLOCK_CHOICES[key]:
@@ -337,13 +387,18 @@ def block_of(meta):
                out["expert_width"]))
     n_layers, n_heads = int(meta["n_layers"]), int(meta["n_heads"])
     kinds = out["layer_types"]
+    if out["head_dim"] < 0 or (out["head_dim"] and "mla" in kinds):
+        raise ValueError(
+            "decode meta head_dim=%d: a head's size is >= 1 (0 = d_model // "
+            "n_heads), and an mla layer's heads are sized by its own keys"
+            % out["head_dim"])
     if out["position"] == "rope" and "mla" not in kinds and (
-            int(meta["d_model"]) // n_heads) % 2:
+            out["head_dim"] or int(meta["d_model"]) // n_heads) % 2:
         raise ValueError("decode meta position=rope needs an even "
                          "head size")
     if kinds and (len(kinds) != n_layers
                   or any(t not in _LAYER_TYPES for t in kinds)
-                  or not {"attention", "mla"} & set(kinds)):
+                  or not {"attention", "mla", "attention+ssm"} & set(kinds)):
         raise ValueError(
             "decode meta layer_types=%r needs one of %s for each of the %d "
             "layers, and an attention layer among them"
@@ -369,6 +424,51 @@ def block_of(meta):
                     "decode meta %s=%r does not go with layer_types mla "
                     "(its one shared row has no heads to norm or group)"
                     % (key, out[key]))
+    if "attention+ssm" in kinds:
+        if "conv" in kinds:
+            raise ValueError(
+                "decode meta layer_types=%r mixes conv with attention+ssm "
+                "layers: a session's conv-state table has one width"
+                % (list(kinds),))
+        for key in _SSM_DIMS + ("ssm_chunk",):
+            if out[key] < 1:
+                raise ValueError("decode meta %s=%d: an attention+ssm layer "
+                                 "needs it >= 1" % (key, out[key]))
+        if out["ssm_conv_kernel"] < 2:
+            raise ValueError("decode meta ssm_conv_kernel=%d: an "
+                             "attention+ssm layer's conv needs at least 2 "
+                             "taps" % out["ssm_conv_kernel"])
+        if out["ssm_heads"] % out["ssm_groups"]:
+            raise ValueError("decode meta ssm_groups=%d does not divide "
+                             "ssm_heads %d" % (out["ssm_groups"],
+                                               out["ssm_heads"]))
+        if out["ssm_multipliers"] and len(out["ssm_multipliers"]) != 5:
+            raise ValueError(
+                "decode meta ssm_multipliers=%r is not one number for each "
+                "of the segments z, x, B, C, dt"
+                % (list(out["ssm_multipliers"]),))
+    elif out["ssm_multipliers"] or any(
+            out[k] != 1.0 for k in ("ssm_in_multiplier",
+                                    "ssm_out_multiplier")):
+        raise ValueError("decode meta ssm_*multiplier* goes with "
+                         "layer_types attention+ssm")
+    if out["ffn"] == "swiglu" and (out["dense_width"] < 1
+                                   or out["n_dense_layers"]):
+        raise ValueError(
+            "decode meta ffn=swiglu needs dense_width (%d) >= 1 and no "
+            "n_dense_layers (%d): every layer's FFN is that one"
+            % (out["dense_width"], out["n_dense_layers"]))
+    if out["mlp_multipliers"] and (len(out["mlp_multipliers"]) != 2
+                                   or out["ffn"] != "swiglu"):
+        raise ValueError(
+            "decode meta mlp_multipliers=%r is (gate, down) of ffn=swiglu"
+            % (list(out["mlp_multipliers"]),))
+    if "mla" in kinds and any(
+            out[k] != 1.0 for k in ("attention_in_multiplier",
+                                    "key_multiplier",
+                                    "attention_out_multiplier")):
+        raise ValueError("decode meta attention/key multipliers do not go "
+                         "with layer_types mla")
     if "conv" in kinds and out["conv_kernel"] < 2:
         raise ValueError("decode meta conv_kernel=%d: a conv layer needs "
                          "at least 2 taps" % out["conv_kernel"])
@@ -407,26 +507,50 @@ def block_of(meta):
 def layer_kinds(meta, blk=None):
     """(operator, FFN) of every layer of the stack `meta` describes
     (`blk`: its `block_of`, where the caller has it): operator
-    "attention" | "conv" | "mla", FFN "dense_swiglu" (the first
-    `n_dense_layers`) or the meta's `ffn`."""
+    "attention" | "conv" | "mla" | "attention+ssm", FFN "dense_swiglu"
+    (the first `n_dense_layers`; every layer under ffn=swiglu) or the
+    meta's `ffn`."""
     blk = blk or block_of(meta)
     n = int(meta["n_layers"])
     ops = blk["layer_types"] or ("attention",) * n
-    return [(ops[i], "dense_swiglu" if i < blk["n_dense_layers"]
-             else blk["ffn"]) for i in range(n)]
+    dense = n if blk["ffn"] == "swiglu" else blk["n_dense_layers"]
+    return [(ops[i], "dense_swiglu" if i < dense else blk["ffn"])
+            for i in range(n)]
+
+
+def _head_dim(meta, blk):
+    """A head's size: the meta's `head_dim`, or d_model // n_heads."""
+    return blk["head_dim"] or int(meta["d_model"]) // int(meta["n_heads"])
+
+
+def _ssm_widths(blk):
+    """(d_ssm, conv channels, ssm_in's outputs) of an attention+ssm
+    layer: the heads' features; those and the groups' B and C, which the
+    conv runs over; and z, the conv's channels and a dt a head."""
+    d_ssm = blk["ssm_heads"] * blk["ssm_head_dim"]
+    conv = d_ssm + 2 * blk["ssm_groups"] * blk["ssm_state"]
+    return d_ssm, conv, d_ssm + conv + blk["ssm_heads"]
 
 
 def slot_state_shapes(meta, n_slots, device):
     """The kinds of state a slot of an `n_slots` session on `device`
-    holds, as (K/V table shape, conv-state table shape or None):
+    holds, as (K/V table shape, conv-state table shape or None,
+    scanned-state table shape or None):
 
       * [attention layers, N, S, Hc * Dh]: a K (or V) row for every cached
-        position of every ATTENTION layer, addressed by the slot's length:
+        position of every layer that ATTENDS (an attention layer, an
+        attention+ssm layer), addressed by the slot's length:
         ONE FLAT ROW a position, its Hc K/V heads' Dh features side by
         side, on every placement (below);
-      * [conv layers, N, conv_kernel - 1, D]: the last inputs of every
-        CONV layer's filter, a fixed size whatever the slot's length;
-        None for a stack with no conv layer;
+      * [conv layers, N, K - 1, C]: the last inputs of the filter of every
+        layer that CONVOLVES, a fixed size whatever the slot's length: a
+        conv layer's (K = conv_kernel, C = D) or an attention+ssm layer's
+        (K = ssm_conv_kernel, C = the heads' features and the groups' B
+        and C); None for a stack with neither;
+      * [ssm layers, N, ssm_heads, ssm_head_dim, ssm_state] fp32: the
+        SCANNED state of every attention+ssm layer, a decayed running sum
+        over all the slot's positions, a fixed size, read and rewritten
+        whole by every token; None for a stack with no such layer;
       * for a stack of MLA layers, in the first place and held ONCE (no V
         table): [mla layers, N, S, Rp], the latent row of every cached
         position, `latent_row` lanes wide.
@@ -448,15 +572,25 @@ def slot_state_shapes(meta, n_slots, device):
     (Hc / m) * Dh lanes, a flat table of its own."""
     blk = block_of(meta)
     ops = [op for op, _ in layer_kinds(meta, blk)]
+    N = int(n_slots)
     if "mla" in ops:
-        return (len(ops), int(n_slots), int(meta["max_seq_len"]),
-                latent_row(blk, device)), None
-    H, D = int(meta["n_heads"]), int(meta["d_model"])
-    kv = (ops.count("attention"), int(n_slots), int(meta["max_seq_len"]),
-          (blk["n_kv_heads"] or H) * (D // H))
-    n_conv = ops.count("conv")
-    return kv, ((n_conv, int(n_slots), blk["conv_kernel"] - 1, D)
-                if n_conv else None)
+        return (len(ops), N, int(meta["max_seq_len"]),
+                latent_row(blk, device)), None, None
+
+    def holders(kind):
+        return sum(kind in _HOLDS[op] for op in ops)
+    kv = (holders("kv"), N, int(meta["max_seq_len"]),
+          (blk["n_kv_heads"] or int(meta["n_heads"])) * _head_dim(meta, blk))
+    conv = ssm = None
+    if holders("ssm"):
+        conv = (holders("conv"), N, blk["ssm_conv_kernel"] - 1,
+                _ssm_widths(blk)[1])
+        ssm = (holders("ssm"), N) + tuple(
+            blk[k] for k in ("ssm_heads", "ssm_head_dim", "ssm_state"))
+    elif holders("conv"):
+        conv = (holders("conv"), N, blk["conv_kernel"] - 1,
+                int(meta["d_model"]))
+    return kv, conv, ssm
 
 
 def decode_state_shapes(meta):
@@ -465,7 +599,7 @@ def decode_state_shapes(meta):
     blk = block_of(meta)
     V, D, H, S = (int(meta[k]) for k in
                   ("vocab_size", "d_model", "n_heads", "max_seq_len"))
-    Dh = D // H
+    Dh = _head_dim(meta, blk)
     kv_width = (blk["n_kv_heads"] or H) * Dh
     norm_bias = blk["norm"] == "layernorm"
     norms = ("ln1", "ln2") + (("ln1p", "ln2p") if blk["sandwich_norm"]
@@ -495,12 +629,23 @@ def decode_state_shapes(meta):
             shapes[p + "conv_w"] = (D, blk["conv_kernel"])
             shapes[p + "conv_out"] = (D, D)
         else:
-            shapes[p + "wq"] = shapes[p + "wo"] = (D, D)
+            shapes[p + "wq"], shapes[p + "wo"] = (D, H * Dh), (H * Dh, D)
             shapes[p + "wk"] = shapes[p + "wv"] = (D, kv_width)
             if blk["qk_norm"] == "head":
                 shapes[p + "qn_g"] = shapes[p + "kn_g"] = (Dh,)
             elif blk["qk_norm"]:
-                shapes[p + "qn_g"], shapes[p + "kn_g"] = (D,), (kv_width,)
+                shapes[p + "qn_g"], shapes[p + "kn_g"] = (H * Dh,), (
+                    kv_width,)
+        if op == "attention+ssm":
+            d_ssm, conv, wide = _ssm_widths(blk)
+            Hs = blk["ssm_heads"]
+            shapes[p + "ssm_in"], shapes[p + "ssm_out"] = (D, wide), (d_ssm,
+                                                                      D)
+            shapes[p + "ssm_conv_w"] = (conv, blk["ssm_conv_kernel"])
+            shapes[p + "ssm_conv_b"] = (conv,)
+            shapes[p + "ssm_dt_bias"] = shapes[p + "ssm_A_log"] = \
+                shapes[p + "ssm_D"] = (Hs,)
+            shapes[p + "ssm_norm_g"] = (d_ssm,)
         if ffn == "dense_swiglu":
             F = blk["dense_width"]
             shapes[p + "ffn_gate"] = shapes[p + "ffn_up"] = (D, F)
@@ -527,9 +672,14 @@ def decode_state_shapes(meta):
 def _bf16_at_rest(name, value):
     """Under meta weight_dtype=bfloat16: whether the weight `name` is kept
     in bfloat16 at rest.  The matmul weights are (attention, dense, shared
-    and routed FFN, embedding, head): every matrix but a router's, which
-    is read at "highest" precision (`moe_ffn`); gains and biases are not."""
-    return np.ndim(value) >= 2 and not name.endswith("_router")
+    and routed FFN, the SSM's in and out projections, embedding, head):
+    every matrix but a router's, which is read at "highest" precision
+    (`moe_ffn`), and an attention+ssm layer's depthwise taps (`ssm_conv_w`
+    [channels, taps]: two axes but no matmul's operand, 82 KB a layer,
+    multiplied into a float32 window element by element); gains, biases
+    and the SSM's vectors (`ssm_dt_bias`, `ssm_A_log`, `ssm_D`) are not."""
+    return np.ndim(value) >= 2 and not name.endswith(("_router",
+                                                      "_ssm_conv_w"))
 
 
 def save_decode_model(dirname, state, meta):
@@ -590,8 +740,10 @@ def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
     one): its weights are drawn in name order, matrices normal(0,
     1/sqrt(fan_in)) (a conv layer's taps fan in over the taps), gains 1,
     biases 0, a router's expert bias normal(0, 0.05) (zero would make
-    selection by biased score the selection by score)."""
-    if d_model % n_heads:
+    selection by biased score the selection by score), an attention+ssm
+    layer's `ssm_dt_bias`, `ssm_A_log`, `ssm_D` and `ssm_conv_b`
+    normal(0, 0.5)."""
+    if d_model % n_heads and not (block or {}).get("head_dim"):
         raise ValueError("d_model %d not divisible by n_heads %d"
                          % (d_model, n_heads))
     rng = np.random.RandomState(seed)
@@ -607,6 +759,11 @@ def build_tiny_decode_model(dirname, vocab_size=32, d_model=16,
         for name, shape in sorted(decode_state_shapes(meta).items()):
             if name.endswith("_expert_bias"):
                 state[name] = (0.05 * rng.randn(*shape)).astype(np.float32)
+            elif name.endswith(("_ssm_dt_bias", "_ssm_A_log", "_ssm_D",
+                                "_ssm_conv_b")):
+                # an SSM's vectors: at zero the decay, the skip and the
+                # conv's bias would go untested
+                state[name] = (0.5 * rng.randn(*shape)).astype(np.float32)
             elif len(shape) == 1:
                 state[name] = (np.ones if name.endswith("_g")
                                else np.zeros)(shape, np.float32)
@@ -707,10 +864,27 @@ def _mm(x, w):
     return _contract(x, w, jnp.matmul)
 
 
-def _swiglu(h, gate, up, down):
-    """(silu(h gate) * (h up)) down: a dense SwiGLU FFN."""
+def _gated_group_norm(y, z, g, groups, eps):
+    """rms(y * silu(z)) over each of `groups` equal groups of the last
+    axis, times the gain g: a state-space mixer's gated norm, the gate
+    BEFORE the norm."""
     import jax
-    return _mm(jax.nn.silu(_mm(h, gate)) * _mm(h, up), down)
+    import jax.numpy as jnp
+    y = (y * jax.nn.silu(z)).reshape(y.shape[:-1] + (groups, -1))
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + eps)
+    return y.reshape(z.shape) * g
+
+
+def _swiglu(h, gate, up, down, gate_by=1.0, down_by=1.0):
+    """(silu(h gate) * (h up)) down: a dense SwiGLU FFN; the gate's
+    pre-activation times `gate_by` and the result times `down_by` (meta
+    mlp_multipliers) where they are not 1."""
+    import jax
+    g = _mm(h, gate)
+    y = _mm(jax.nn.silu(g if gate_by == 1.0 else g * gate_by) * _mm(h, up),
+            down)
+    return y if down_by == 1.0 else y * down_by
 
 
 def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
@@ -936,35 +1110,41 @@ _SLOT_WRITERS = []
 
 def _slot_writers():
     """(write_rows, zero_slot, clear_rows): the three eager writes of a
-    slot-state table (K/V [L, N, S, H * Dh], latent rows [L, N, S, Rp] or
-    conv state [L, N, K-1, D]), jitted with the table DONATED so that they
-    land in place.
-    `write_rows(table, rows [L, 1, B, H * Dh], slot)`
-    puts `rows`, padded to the table's row where that is (a latent
-    table's), at `slot` from position 0 (a prefill's K or V, or its conv
-    state [L, 1, K-1, D] whole);
-    `zero_slot(table, slot)` zeroes the slot's whole row (its release);
+    session's slot-state tables (K/V [L, N, S, H * Dh], latent rows [L, N,
+    S, Rp], conv state [L, N, K-1, C], scanned state [L, N, Hs, P, Ns]),
+    jitted with the tables DONATED so that they land in place.
+    `write_rows(tables, rows, slot)` puts, table by table, `rows` ([L, 1,
+    B, H * Dh]; padded to the table's row where that is, a latent
+    table's) at `slot` from position 0 (a prefill's K and V, its conv
+    state [L, 1, K-1, C] and its scanned state whole);
+    `zero_slot(tables, slot)` zeroes the slot's whole row of every table
+    (its release).  Both take ALL of a session's tables in ONE call: a
+    jitted call costs the lane's thread 1.75 ms with the streams' handlers
+    awake (PERF.md, PR 39), 5.5 ms with 192 of them (PR 42: a release of
+    four tables in four calls read 22 ms an ender), whatever it writes.
     `clear_rows` is `_clear_rows` (a rollback of a K/V table), one
     executable per depth.
     Undonated, each was a copy of the whole table (1.2 GB at GPT-2 small
     with 32 slots: ~3 ms of the device and a transient table in memory),
     twice for every admission and every release, with the chip's memory
-    nearly full.  `slot` is traced: one executable per table and
+    nearly full.  `slot` is traced: one executable per stack and
     bucket."""
     if not _SLOT_WRITERS:
         import jax
         import jax.numpy as jnp
 
-        def write_rows(table, rows, slot):
+        def at_slot(table, rows, slot):
             return jax.lax.dynamic_update_slice(
-                table, _pad_rows(rows, table.shape[3:]),
-                (0, slot) + (0,) * (table.ndim - 2))
+                table, rows, (0, slot) + (0,) * (table.ndim - 2))
 
-        def zero_slot(table, slot):
-            z = jnp.zeros((table.shape[0], 1) + table.shape[2:],
-                          table.dtype)
-            return jax.lax.dynamic_update_slice(
-                table, z, (0, slot) + (0,) * (table.ndim - 2))
+        def write_rows(tables, rows, slot):
+            return tuple(at_slot(t, _pad_rows(r, t.shape[3:]), slot)
+                         for t, r in zip(tables, rows))
+
+        def zero_slot(tables, slot):
+            return tuple(at_slot(t, jnp.zeros((t.shape[0], 1) + t.shape[2:],
+                                              t.dtype), slot)
+                         for t in tables)
 
         _SLOT_WRITERS.extend(jax.jit(fn, donate_argnums=0)
                              for fn in (write_rows, zero_slot))
@@ -1012,6 +1192,66 @@ def _zero_pad_positions(ks, vs, true_len):
             < true_len)[None]            # [1, 1, B, 1, 1]
     return (jnp.where(live, jnp.stack(ks), 0.0),
             jnp.where(live, jnp.stack(vs), 0.0))
+
+
+def ssd_chunked_scan(xs, Bm, Cm, dt, A, chunk, state=None):
+    """The state-space recurrence of a Mamba-2 (SSD) mixer over a run of
+    positions, in CHUNKS: xs [T, Hs, P] inputs, Bm / Cm [T, G, N] (head h
+    reads group h // (Hs // G)), dt [T, Hs] >= 0, A [Hs] < 0, from the
+    state `state` [Hs, P, N] (zeros if None) ->
+    (y [T, Hs, P], the state after position T - 1), where
+
+        S_t = exp(dt_t A) S_{t-1} + dt_t xs_t (outer) B_t
+        y_t = S_t . C_t
+
+    Within a chunk of `chunk` positions the quadratic form (position t
+    reads s <= t through C_t . B_s decayed by exp(sum_{s<r<=t} dt_r A)),
+    across chunks the carried state: one [Hs, P, N] state lives at a time
+    and no [T, Hs, P, N] intermediate exists.  A position with dt = 0
+    neither decays nor adds to the state: how a prefill keeps the pad
+    positions of its bucket out of it.  T is padded up to whole chunks
+    with such positions.  The contractions run at "highest" precision:
+    they are a hundredth of a prefill's FLOPs, and the state they build is
+    what every later token of the stream reads."""
+    import jax
+    import jax.numpy as jnp
+    T, Hs, P = xs.shape
+    G, N = Bm.shape[1:]
+    Q, k = min(int(chunk), T), Hs // G
+    pad = -T % Q
+    if pad:
+        xs, Bm, Cm, dt = (jnp.pad(t, ((0, pad),) + ((0, 0),) * (t.ndim - 1))
+                          for t in (xs, Bm, Cm, dt))
+    n = (T + pad) // Q
+    xs, Bm, Cm, dt = (t.reshape((n, Q) + t.shape[1:])
+                      for t in (xs, Bm, Cm, dt))
+    causal = jnp.tril(jnp.ones((Q, Q), bool))[:, :, None]
+    hi = jax.lax.Precision.HIGHEST
+
+    def one(S, c):
+        x, b, cc, d = c            # [Q, Hs, P], [Q, G, N] x 2, [Q, Hs]
+        cum = jnp.cumsum(d * A, axis=0)                     # [Q, Hs], <= 0
+        # position t from position s <= t of this chunk
+        cb = jnp.einsum("tgn,sgn->tsg", cc, b, precision=hi)
+        reach = jnp.exp(jnp.where(causal, cum[:, None] - cum[None],
+                                  -jnp.inf)) * d[None]      # [t, s, Hs]
+        y = jnp.einsum("tsh,shp->thp",
+                       jnp.repeat(cb, k, axis=2) * reach, x, precision=hi)
+        # ... and from the state the chunk began with
+        y = y + jnp.einsum(
+            "tgn,gkpn->tgkp", cc, S.reshape(G, k, P, N),
+            precision=hi).reshape(Q, Hs, P) * jnp.exp(cum)[:, :, None]
+        # the state the chunk ends with
+        w = jnp.exp(cum[-1][None] - cum) * d                # [Q, Hs]
+        S = jnp.exp(cum[-1])[:, None, None] * S + jnp.einsum(
+            "sgkp,sgn->gkpn", (x * w[:, :, None]).reshape(Q, G, k, P), b,
+            precision=hi).reshape(Hs, P, N)
+        return S, y
+
+    if state is None:
+        state = jnp.zeros((Hs, P, N), jnp.float32)
+    state, y = jax.lax.scan(one, state, (xs, Bm, Cm, dt))
+    return y.reshape((n * Q, Hs, P))[:T], state
 
 
 class _TPContext:
@@ -1264,12 +1504,13 @@ class GenerativePredictor:
         dispatch)."""
         return layer_kinds(self.meta, self._block_meta)
 
-    def _table_layer(self, i):
-        """Where layer i's slot state lies in the table of its kind (the
-        K/V tables hold the attention layers only, the conv-state table
-        the conv layers): its rank among the layers of its operator."""
-        ops = [op for op, _ in self.layer_kinds]
-        return ops[:i].count(ops[i])
+    def _table_layer(self, i, kind="kv"):
+        """Where layer i's slot state of `kind` ("kv" | "conv" | "ssm")
+        lies in the table of that kind (the K/V tables hold the layers
+        that attend, the conv-state table those that convolve, the
+        scanned-state table the attention+ssm layers): its rank among the
+        layers that keep such state."""
+        return sum(kind in _HOLDS[op] for op, _ in self.layer_kinds[:i])
 
     @functools.cached_property
     def latent(self):
@@ -1286,9 +1527,16 @@ class GenerativePredictor:
 
     @functools.cached_property
     def conv_layers(self):
-        """Layers whose operator is a gated short convolution: those
-        with a row in the conv-state table."""
-        return [op for op, _ in self.layer_kinds].count("conv")
+        """Layers with a row in the conv-state table: those whose
+        operator is a gated short convolution, or holds a state-space
+        mixer (whose conv runs in front of its scan)."""
+        return sum("conv" in _HOLDS[op] for op, _ in self.layer_kinds)
+
+    @functools.cached_property
+    def ssm_layers(self):
+        """Layers with a row in the scanned-state table: those whose
+        operator holds a state-space mixer (attention+ssm)."""
+        return sum("ssm" in _HOLDS[op] for op, _ in self.layer_kinds)
 
     @property
     def routed_layers(self):
@@ -1305,18 +1553,20 @@ class GenerativePredictor:
 
     def _require_attention_stack(self, what):
         """Raise for what has no rule for a RECURRENT layer's state: a
-        conv layer's state is a window of its last inputs, rolled on
-        every token, so it cannot be undone by moving a slot's length
-        back (a rollback, the speculative verify and its rejected
-        suffix), and neither the mesh grammar nor the int8 cache's
-        per-head scales know it."""
+        conv window is a layer's last inputs, rolled on every token, and
+        a scanned state a decayed sum over ALL a slot's positions,
+        rewritten whole by every token, so neither can be undone by
+        moving a slot's length back (a rollback, the speculative verify
+        and its rejected suffix: that takes a snapshot), and neither the
+        mesh grammar nor the int8 cache's per-head scales know them."""
         if self.conv_layers:
             raise NotImplementedError(
-                "%s has no rule for a conv layer's slot state, and this "
-                "artifact's meta has layer_types=%r (that state is the "
-                "layer's last inputs, rolled by every token: moving a "
-                "slot's length back does not undo it, and it is neither "
-                "sharded by heads nor scaled a head)"
+                "%s has no rule for a recurrent layer's slot state, and "
+                "this artifact's meta has layer_types=%r (a conv window is "
+                "the layer's last inputs, rolled by every token, a scanned "
+                "state a decayed sum over all of a slot's positions: "
+                "moving a slot's length back undoes neither, and they are "
+                "neither sharded by heads nor scaled a head)"
                 % (what, list(self._block_meta["layer_types"])))
 
     def _require_kv_stack(self, what):
@@ -1443,10 +1693,16 @@ class GenerativePredictor:
         return self._slot_state_shapes(n_slots)[0]
 
     def conv_state_shape(self, n_slots):
-        """[conv layers, n_slots, conv_kernel - 1, D]: the conv-state
-        table of an `n_slots` session; None for a stack with no conv
-        layer."""
+        """[conv layers, n_slots, taps - 1, channels]: the conv-state
+        table of an `n_slots` session; None for a stack with no layer
+        that convolves."""
         return self._slot_state_shapes(n_slots)[1]
+
+    def ssm_state_shape(self, n_slots):
+        """[ssm layers, n_slots, ssm_heads, ssm_head_dim, ssm_state]: the
+        scanned-state table of an `n_slots` session; None for a stack
+        with no attention+ssm layer."""
+        return self._slot_state_shapes(n_slots)[2]
 
     def _slot_state_shapes(self, n_slots):
         """`slot_state_shapes` of this predictor, kept a slot count."""
@@ -1466,14 +1722,24 @@ class GenerativePredictor:
         that bounds decode slots
         (FLAGS.serving_decode_slots) and the number the admission fit
         check adds per replica; analysis/resources.py's `_decode_report`
-        prices the same shape.  The conv layers' state is
-        `conv_state_bytes`, apart."""
+        prices the same shape.  A stack with attention+ssm layers adds
+        its scanned-state table (`ssm_state_bytes`): it bounds the slots
+        as the rows do.  The conv layers' state is `conv_state_bytes`,
+        apart."""
         L, H, _, _ = self._dims()
         elem = 1 if self._kv_quant else 4
         scales = 2 * L * H * 4 if self._kv_quant else 0
         # an MLA stack's latent table is held once: it has no V
         return (self._kv_tables
-                * int(np.prod(self.table_shape(n_slots))) * elem + scales)
+                * int(np.prod(self.table_shape(n_slots))) * elem + scales
+                + self.ssm_state_bytes(n_slots))
+
+    def ssm_state_bytes(self, n_slots):
+        """Closed-form footprint of the scanned state for an `n_slots`
+        session (fp32; 0 for a stack with no attention+ssm layer): a
+        fixed size a slot, whatever the slot's length."""
+        shape = self.ssm_state_shape(n_slots)
+        return 4 * int(np.prod(shape)) if shape else 0
 
     def conv_state_bytes(self, n_slots):
         """Closed-form footprint of the conv layers' slot state for an
@@ -1501,10 +1767,10 @@ class GenerativePredictor:
     # -- model math -----------------------------------------------------
 
     def _dims(self):
+        """(layers, query heads, a head's size, d_model)."""
         m = self.meta
         return (int(m["n_layers"]), int(m["n_heads"]),
-                int(m["d_model"]) // int(m["n_heads"]),
-                int(m["d_model"]))
+                _head_dim(m, self._block_meta), int(m["d_model"]))
 
     def _kv_heads(self):
         return self._block_meta["n_kv_heads"] or int(self.meta["n_heads"])
@@ -1588,6 +1854,8 @@ class GenerativePredictor:
         x = tp.embed_lookup(state["embed"], tokens)
         if x.dtype != np.float32:       # a table bfloat16 at rest
             x = x.astype(np.float32)
+        if self._block_meta["embedding_multiplier"] != 1.0:
+            x = x * self._block_meta["embedding_multiplier"]
         if self._block_meta["position"] == "learned":
             x = x + state["pos"][positions]
         return x
@@ -1600,35 +1868,64 @@ class GenerativePredictor:
         argmax."""
         x = self._norm(x, state, "lnf")
         if self._block_meta["head"] == "tied":
-            return _mm(x, state["embed"].T)
-        logits = _mm(x, state["lm_head"])
-        return tp.all_gather(logits, axis=logits.ndim - 1)
+            logits = _mm(x, state["embed"].T)
+        else:
+            logits = _mm(x, state["lm_head"])
+            logits = tp.all_gather(logits, axis=logits.ndim - 1)
+        if self._block_meta["lm_head_multiplier"] != 1.0:
+            logits = logits * self._block_meta["lm_head_multiplier"]
+        return logits
+
+    def _first_token(self, state, x, true_len, tp):
+        """A prefill's greedy token from its last layer's x [1, B, D]: the
+        head over the ONE position `true_len - 1` (the row is taken before
+        the head: at a 261,120-row vocabulary the head over a bucket of
+        512 was 0.53 GB of logits and 1.4 TFLOP, all but one row of them
+        dropped)."""
+        import jax
+        import jax.numpy as jnp
+        row = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=1)
+        return jnp.argmax(self._head(state, row, tp)[0, 0],
+                          axis=-1).astype(jnp.int32)
 
     def _prefill_core(self, state, tokens, true_len, tp=_OFF_MESH):
         """tokens [1, B] int32, true_len scalar int32 -> (first_token
         [] int32, k/v [attention layers, 1, B, Hkv, Dh] fp32 with pad
         positions zeroed (an MLA stack: ONE array in their place, the
         latent rows [mla layers, 1, B, R])[, conv state [conv layers, 1,
-        K-1, D]: each conv
-        layer's last K-1 inputs before the TRUE prompt end, not the
-        bucket's, zeros where the prompt is shorter]).
+        K-1, C]: each convolving layer's last K-1 inputs before the TRUE
+        prompt end, not the bucket's, zeros where the prompt is shorter]
+        [, scanned state [ssm layers, 1, Hs, P, N]: each attention+ssm
+        layer's state after position true_len - 1]).
         Under TP (inside shard_map) weights are local shards:
         the returned K/V carry this member's HEAD block [L, 1, B, H/m,
         Dh] (the cache's at-rest layout), attention is head-parallel
         (exact per head), and each column->row pair closes with one
         psum; long buckets divert to the bit-exact sequence-parallel
         body instead."""
+        if self._tp_seq_parallel(tokens.shape[1], tp):
+            return self._prefill_core_seqpar(state, tokens, true_len,
+                                             tp)
+        x, facts, tables = self._prefill_layers(state, tokens, true_len,
+                                                tp)
+        first = self._first_token(state, x, true_len, tp)
+        if self.routed_layers:
+            first = _pack_routing(first, facts)
+        return (first,) + tables
+
+    def _prefill_layers(self, state, tokens, true_len, tp=_OFF_MESH):
+        """`_prefill_core` up to the head: (the last layer's x [1, B, D],
+        the layers' routing facts, the slot state the prompt leaves, as
+        `_prefill_core` returns it)."""
         import jax
         import jax.numpy as jnp
         L, _, Dh, _ = self._dims()
         B = tokens.shape[1]
         scale = 1.0 / np.sqrt(Dh)
-        if self._tp_seq_parallel(B, tp):
-            return self._prefill_core_seqpar(state, tokens, true_len,
-                                             tp)
         x = self._embed(state, tokens, slice(B), tp)
         positions = jnp.arange(B)[None]                     # [1, B]
-        ks, vs, facts, conv, rows = [], [], [], [], []
+        live = positions[0] < true_len
+        ks, vs, facts, conv, rows, scanned = [], [], [], [], [], []
         group = self._dims()[1] // self._kv_heads()
 
         def latent(q_nope, q_rope, row, wkv_b):
@@ -1644,29 +1941,34 @@ class GenerativePredictor:
             return _causal_attention(q, k, v, scale)
 
         def convolve(z, taps):
-            # z [1, B, D], taps [D, K]: position t reads z[t - (K-1) .. t]
+            # z [1, B, C], taps [C, K]: position t reads z[t - (K-1) .. t]
             K = taps.shape[1]
             zp = jnp.pad(z, ((0, 0), (K - 1, 0), (0, 0)))
             conv.append(jax.lax.dynamic_slice_in_dim(zp, true_len, K - 1,
                                                      axis=1))
             return sum(taps[:, j] * zp[:, j:j + B] for j in range(K))
 
+        def scan(xs, Bm, Cm, dt, A):
+            # [1, B, ..]: a pad position's dt is 0, so the state after the
+            # bucket is the state after position true_len - 1
+            y, after = ssd_chunked_scan(
+                xs[0], Bm[0], Cm[0], jnp.where(live[:, None], dt[0], 0.0),
+                A, self._block_meta["ssm_chunk"])
+            scanned.append(after[None])
+            return y[None]
+
         for i in range(L):
-            x, f = self._block(state, i, x, positions, attend,
-                               positions[0] < true_len, tp=tp,
-                               convolve=convolve, latent=latent)
+            x, f = self._block(state, i, x, positions, attend, live, tp=tp,
+                               convolve=convolve, latent=latent,
+                               ssm=("ssm_scan", scan))
             facts.append(f)
-        first = jnp.argmax(self._head(state, x, tp)[0, true_len - 1],
-                           axis=-1).astype(jnp.int32)
-        if self.routed_layers:
-            first = _pack_routing(first, facts)
         if rows:
             # the latent table's [mla layers, 1, B, R], pads zeroed
-            return first, jnp.where(
-                (positions[0] < true_len)[None, None, :, None],
-                jnp.stack(rows), 0.0)
-        out = (first,) + _zero_pad_positions(ks, vs, true_len)
-        return out + ((jnp.stack(conv),) if conv else ())
+            return x, facts, (jnp.where(live[None, None, :, None],
+                                        jnp.stack(rows), 0.0),)
+        tables = _zero_pad_positions(ks, vs, true_len)
+        return x, facts, tables + tuple(
+            jnp.stack(t) for t in (conv, scanned) if t)
 
     def _norm(self, x, state, name):
         """The block's norm over the last axis with the weights
@@ -1678,7 +1980,7 @@ class GenerativePredictor:
                    blk["norm_eps"])
 
     def _block(self, state, i, x, positions, attend, live, tp=_OFF_MESH,
-               convolve=None, picks=None, latent=None):
+               convolve=None, picks=None, latent=None, ssm=None):
         """Layer i of the stack, as the artifact's meta describes it
         (BLOCK_DEFAULTS), for every phase: x [..., D] with one position
         per leading index, weights `state["l<i>_" + name]`: the layer's
@@ -1703,7 +2005,13 @@ class GenerativePredictor:
         returns, at each position, the taps' sum over that position's
         input and the K-1 before it; where those come from (the
         sequence, the slot's conv state) and what is kept of them is the
-        phase's.  `live` [tokens] marks the rows a routed FFN counts,
+        phase's.  An ATTENTION+SSM layer: the attention above and a
+        state-space mixer (`_ssm`) on the SAME normed input, summed into
+        the residual stream together; `ssm` = (the phase's scope, its
+        `scan(xs, Bm, Cm, dt, A)`: the recurrence's outputs at the
+        positions, from wherever the phase keeps the scanned state), and
+        `convolve` finds the mixer's conv its earlier inputs.  `live`
+        [tokens] marks the rows a routed FFN counts,
         and a list given as `picks` receives its chosen experts.
         Returns (x', routing facts [2] i32 or None).  Under TP each
         column->row pair closes with one psum."""
@@ -1724,7 +2032,8 @@ class GenerativePredictor:
                 if blk["sandwich_norm"] else y
 
         def project(w, heads, gain=None):
-            t = _mm(h, state[p + w])
+            t = _mm(h if blk["attention_in_multiplier"] == 1.0
+                    else h * blk["attention_in_multiplier"], state[p + w])
             if gain and blk["qk_norm"] is True:
                 # over the whole projection, before the split into heads
                 t = _rms(t, state[p + gain], blk["norm_eps"])
@@ -1747,18 +2056,26 @@ class GenerativePredictor:
                   else contextlib.nullcontext()):
                 q, k, v = (project("wq", Hl, "qn_g"),
                            project("wk", Hkv, "kn_g"), project("wv", Hkv))
+                if blk["key_multiplier"] != 1.0:
+                    k = k * blk["key_multiplier"]
                 if blk["position"] == "rope":
                     q = _rope(q, positions, blk["rope_theta"])
                     k = _rope(k, positions, blk["rope_theta"])
-                x = x + joins(tp.psum(_mm(
+                att = tp.psum(_mm(
                     attend(q, k, v).reshape(lead + (Hl * Dh,)),
-                    state[p + "wo"])), "ln1p")
+                    state[p + "wo"]))
+                if blk["attention_out_multiplier"] != 1.0:
+                    att = att * blk["attention_out_multiplier"]
+                x = x + joins(att, "ln1p")
+            if op == "attention+ssm":
+                x = x + self._ssm(state, p, h, convolve, *ssm)
         h2 = self._norm(x, state, p + "ln2")
         if ffn == "dense_swiglu":
             with jax.named_scope("dense_ffn"):
                 return x + joins(_swiglu(
                     h2, state[p + "ffn_gate"], state[p + "ffn_up"],
-                    state[p + "ffn_down"]), "ln2p"), None
+                    state[p + "ffn_down"], *blk["mlp_multipliers"]),
+                    "ln2p"), None
         if ffn == "moe_swiglu":
             y, facts = moe_ffn(
                 h2.reshape(-1, D), state[p + "router"],
@@ -1782,6 +2099,63 @@ class GenerativePredictor:
         mlp = jnp.maximum(h2 @ state[p + "w1"] + state[p + "b1"],
                           0.0) @ state[p + "w2"]
         return x + tp.psum(mlp) + state[p + "b2"], None
+
+    def _ssm(self, state, p, h, convolve, scope, scan):
+        """An attention+ssm layer's state-space mixer (Mamba-2 / SSD) on
+        the normed input h [..., D] (weights `state[p + "ssm_" + name]`)
+        -> [..., D], before the residual sum:
+
+            [z | xBC | dt] = ((h * ssm_in_multiplier) ssm_in) * the
+                segments' ssm_multipliers            (z, x, B, C, dt)
+            xBC = silu(conv(xBC; conv_w [C, K]) + conv_b)   causal,
+                depthwise: `convolve`, the phase's, finds the K - 1
+                earlier inputs and keeps the last ones (PRE-activation)
+            xs, B, C = split(xBC) -> [Hs, P], [G, N], [G, N]
+            dt = softplus(dt + dt_bias) [Hs];  A = -exp(A_log) [Hs]
+            y = scan(xs, B, C, dt, A) + D xs:  S_t = exp(dt_t A) S_{t-1}
+                + dt_t xs_t (outer) B_t,  y_t = S_t . C_t   (head h reads
+                group h // (Hs / G))
+            y = rms(y * silu(z)) by group of d_ssm / G, times norm_g
+                (the gate BEFORE the norm)
+            result = (y ssm_out) * ssm_out_multiplier
+
+        `scan` is the phase's: a chunked scan over a prompt
+        (`ssd_chunked_scan`), one step of the recurrence on the slots'
+        scanned state.  Scopes: `ssm_proj` around the two projections,
+        the gate and the norm; `scope` (`ssm_scan` | `ssm_update`) around
+        the conv and the recurrence."""
+        import jax
+        import jax.numpy as jnp
+        blk = self._block_meta
+        Hs, P, N, G = (blk[k] for k in _SSM_DIMS)
+        d_ssm, conv, _ = _ssm_widths(blk)
+        lead = h.shape[:-1]
+        w = {n: state[p + "ssm_" + n] for n in (
+            "in", "out", "conv_w", "conv_b", "dt_bias", "A_log", "D",
+            "norm_g")}
+        with jax.named_scope("ssm_proj"):
+            proj = _mm(h if blk["ssm_in_multiplier"] == 1.0
+                       else h * blk["ssm_in_multiplier"], w["in"])
+            if blk["ssm_multipliers"]:
+                mz, mx, mb, mc, mdt = blk["ssm_multipliers"]
+                proj = proj * np.repeat(
+                    np.float32([mz, mx, mb, mc, mdt]),
+                    [d_ssm, d_ssm, G * N, G * N, Hs])
+            z, xBC, dt = jnp.split(proj, [d_ssm, d_ssm + conv], axis=-1)
+        with jax.named_scope(scope):
+            dt = jax.nn.softplus(dt + w["dt_bias"])
+            xBC = jax.nn.silu(convolve(xBC, w["conv_w"]) + w["conv_b"])
+            xs, Bm, Cm = jnp.split(xBC, [d_ssm, d_ssm + G * N], axis=-1)
+            xs = xs.reshape(lead + (Hs, P))
+            y = scan(xs, Bm.reshape(lead + (G, N)),
+                     Cm.reshape(lead + (G, N)), dt, -jnp.exp(w["A_log"]))
+            y = y + w["D"][:, None] * xs
+        with jax.named_scope("ssm_proj"):
+            y = _gated_group_norm(y.reshape(lead + (d_ssm,)), z,
+                                  w["norm_g"], G, blk["norm_eps"])
+            out = _mm(y, w["out"])
+            return out if blk["ssm_out_multiplier"] == 1.0 \
+                else out * blk["ssm_out_multiplier"]
 
     def _mla(self, state, p, h, positions, latent):
         """An MLA layer's operator on the normed input h [..., D] (weights
@@ -1922,9 +2296,8 @@ class GenerativePredictor:
             x, _ = self._block(whole("l%d_" % i), i, x, positions, attend,
                                positions[0] < true_len)
         xg = tp.all_gather(x, axis=1)            # [1, B, D] whole
-        logits = self._head(whole("lnf_", "lm_head"), xg, _OFF_MESH)
-        first = jnp.argmax(logits[0, true_len - 1],
-                           axis=-1).astype(jnp.int32)
+        first = self._first_token(whole("lnf_", "lm_head"), xg, true_len,
+                                  _OFF_MESH)
         return (first,) + _zero_pad_positions(ks, vs, true_len)
 
     def _write(self, kc, vc, i, where, k_new, v_new, tp):
@@ -1993,10 +2366,13 @@ class GenerativePredictor:
     def _step_core(self, state, tables, lengths, last_tokens, active,
                    tp=_OFF_MESH, picks=None):
         """One fixed-shape decode step over the slots' whole state.
-        `tables` = (kc, vc[, cs]): the K/V tables [attention layers, N,
-        S, Hc * Dh] (fp32, or int8 under the quantized cache) and, for a
-        stack with conv layers, the conv-state table [conv layers, N,
-        K-1, D]; or, for an MLA stack, (rows,): the latent table [mla
+        `tables` = (kc, vc[, cs[, ss]]): the K/V tables [attention layers,
+        N, S, Hc * Dh] (fp32, or int8 under the quantized cache), for a
+        stack with layers that convolve the conv-state table [conv
+        layers, N, K-1, C] and, for one with attention+ssm layers, the
+        scanned-state table [ssm layers, N, Hs, P, Ns], of which every
+        step reads and rewrites every live slot's whole state
+        (`scan` below); or, for an MLA stack, (rows,): the latent table [mla
         layers, N, S, Rp] alone; lengths [N] i32 (live cached positions),
         last_tokens
         [N] i32, active [N] bool -> (logits [N, vocab] f32, tables',
@@ -2018,9 +2394,9 @@ class GenerativePredictor:
 
         Writes are gated by `active`: an inactive slot's K/V row goes to
         position S, out of range, and is DROPPED, as is the row of a
-        slot already at `lengths == S`, and its conv state keeps what it
-        held; so a freed (zeroed) slot stays zero and per-slot
-        independence is exact.
+        slot already at `lengths == S`, and its conv state and its
+        scanned state keep what they held; so a freed (zeroed) slot stays
+        zero and per-slot independence is exact.
 
         Under TP (inside shard_map) kc/vc are this member's resident
         HEAD shard and weights are local column/row shards — params and
@@ -2031,6 +2407,7 @@ class GenerativePredictor:
         # an MLA stack's one latent table stands where K stands
         kc, vc = tables[:2] if not self.latent else (tables[0], None)
         cs = tables[2] if len(tables) > 2 else None
+        ss = tables[3] if len(tables) > 3 else None
         N, S = kc.shape[1], kc.shape[2]
         x = self._embed(state, last_tokens, lengths, tp)        # [N, D]
         # where a slot's new row lands; S (past the end) = nowhere
@@ -2045,14 +2422,28 @@ class GenerativePredictor:
                 kc, vc = self._write(kc, vc, at, where, k_new, v_new, tp)
                 return self._attend_table(q, kc, vc, lengths, 1, at, tp)
 
-            def convolve(z, taps, at=at):
-                # z [N, D]: the slot's K-1 kept inputs, then this one
+            def convolve(z, taps, at=self._table_layer(i, "conv")):
+                # z [N, C]: the slot's K-1 kept inputs, then this one
                 nonlocal cs
                 seen = jnp.concatenate([cs[at], z[:, None]], axis=1)
                 cs = cs.at[at].set(jnp.where(active[:, None, None],
                                              seen[:, 1:], cs[at]))
                 return sum(taps[:, j] * seen[:, j]
                            for j in range(taps.shape[1]))
+
+            def scan(xs, Bm, Cm, dt, A, at=self._table_layer(i, "ssm")):
+                # one step of the recurrence on every slot's state [N, Hs,
+                # P, Ns], read and rewritten whole; an inactive slot's
+                # keeps what it held
+                nonlocal ss
+                k = xs.shape[1] // Bm.shape[1]
+                Bh, Ch = (jnp.repeat(t, k, axis=1)[:, :, None]
+                          for t in (Bm, Cm))                # [N, Hs, 1, Ns]
+                new = (jnp.exp(dt * A)[:, :, None, None] * ss[at]
+                       + (dt[:, :, None] * xs)[..., None] * Bh)
+                ss = ss.at[at].set(jnp.where(
+                    active[:, None, None, None], new, ss[at]))
+                return jnp.sum(new * Ch, axis=-1)
 
             def latent(q_nope, q_rope, row, wkv_b, at=at):
                 nonlocal kc
@@ -2061,10 +2452,11 @@ class GenerativePredictor:
                                           at, wkv_b)
 
             x, f = self._block(state, i, x, lengths, attend, active, tp=tp,
-                               convolve=convolve, picks=picks, latent=latent)
+                               convolve=convolve, picks=picks, latent=latent,
+                               ssm=("ssm_update", scan))
             facts.append(f)
         return (self._head(state, x, tp),
-                tuple(t for t in (kc, vc, cs) if t is not None), facts)
+                tuple(t for t in (kc, vc, cs, ss) if t is not None), facts)
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
                      tp=_OFF_MESH):
@@ -2569,22 +2961,23 @@ class GenerativePredictor:
         conv-state table of a stack with conv layers; the one latent table
         of an MLA stack.  They lead the
         arguments of every phase over the slots (`_table_specs`)."""
-        return self._kv_tables + bool(self.conv_layers)
+        return self._kv_tables + bool(self.conv_layers) \
+            + bool(self.ssm_layers)
 
     def _table_specs(self, n_slots):
-        """(kc, vc[, conv state], lengths [N] i32, last tokens [N] i32,
-        active [N] bool) (an MLA stack: its latent table where kc, vc
+        """(kc, vc[, conv state[, scanned state]], lengths [N] i32, last
+        tokens [N] i32, active [N] bool) (an MLA stack: its latent table where kc, vc
         stand): what every phase over the slots takes, their state first
         (`_n_tables` leaves)."""
         import jax
         n = int(n_slots)
         cache = jax.ShapeDtypeStruct(self.table_shape(n),
                                      self._cache_np_dtype())
-        conv = self.conv_state_shape(n)
         i32 = np.dtype(np.int32)
-        return (cache,) * self._kv_tables + (
-            (jax.ShapeDtypeStruct(conv, np.dtype(np.float32)),)
-            if conv else ()) + (
+        return (cache,) * self._kv_tables + tuple(
+            jax.ShapeDtypeStruct(shape, np.dtype(np.float32))
+            for shape in (self.conv_state_shape(n), self.ssm_state_shape(n))
+            if shape) + (
             jax.ShapeDtypeStruct((n,), i32),
             jax.ShapeDtypeStruct((n,), i32),
             jax.ShapeDtypeStruct((n,), np.dtype(bool)))
@@ -2683,12 +3076,15 @@ class GenerativePredictor:
 
 class DecodeSession:
     """One lane's slots: their state + occupancy bookkeeping.  A slot's
-    state is of two kinds (`slot_state_shapes`): its rows of the K/V
+    state is of three kinds (`slot_state_shapes`): its rows of the K/V
     tables (`_kc`, `_vc`: the attention layers', addressed by the slot's
     length; a stack of latent attention holds ONE table of latent rows,
-    `_kc`, and `_vc` is None) and, for a stack with conv layers, its row
-    of the conv-state table (`_cs`: a fixed size, rolled by every token;
-    None otherwise).
+    `_kc`, and `_vc` is None); for a stack with layers that convolve, its
+    row of the conv-state table (`_cs`: a fixed size, rolled by every
+    token; None otherwise); and, for a stack with attention+ssm layers,
+    its row of the scanned-state table (`_ss`: a fixed size, a decayed sum
+    over all its positions, rewritten whole by every token; None
+    otherwise).
     Every phase that advances the slots is given all of it donated and
     the session keeps the results (`_tables`, `_keep`).
     NOT thread-safe — a serving lane owns its session exclusively (the
@@ -2717,7 +3113,7 @@ class DecodeSession:
             if predictor.device is not None else None
         self._inplace = group is None
 
-        def table():
+        def table(shape=shape, dtype=dtype):
             z = jnp.zeros(shape, dtype)
             if group is not None:
                 return jax.device_put(z, group.kv_sharding(shape))
@@ -2736,18 +3132,24 @@ class DecodeSession:
         # MLA stack's latent table is `_kc`, and there is no V)
         self._kc = table()
         self._vc = None if predictor.latent else table()
-        # the conv layers' state (refused on a mesh: `_inplace`)
+        # the conv layers' state, fp32 (refused on a mesh:
+        # `_require_kv_stack`)
         conv = predictor.conv_state_shape(self.n_slots)
         self._cs = None
         # what a hybrid stack's fetch spans say of it
         self._stack_attrs = {}
         if conv:
-            z = jnp.zeros(conv, jnp.float32)
-            self._cs = jax.device_put(
-                z, predictor.device or next(iter(z.devices())))
+            self._cs = table(conv, jnp.float32)
             self._stack_attrs = {
                 "conv_layers": conv[0], "attn_layers": shape[0],
                 "conv_state_bytes": int(self._cs.nbytes)}
+        # the attention+ssm layers' scanned state (refused on a mesh)
+        scanned = predictor.ssm_state_shape(self.n_slots)
+        self._ss = None
+        if scanned:
+            self._ss = table(scanned, jnp.float32)
+            self._stack_attrs.update(
+                ssm_layers=scanned[0], ssm_state_bytes=int(self._ss.nbytes))
         if predictor.latent:
             self._stack_attrs = {"mla_layers": shape[0],
                                  "latent_cache_bytes": int(self._kc.nbytes)}
@@ -2803,9 +3205,10 @@ class DecodeSession:
         nbytes (rows as the table holds them, their padding to the tile
         included) plus the int8 cache's fp32 scale table — what
         bench_serving's --kv_dtype A/B reports against the closed-form
-        `GenerativePredictor.kv_cache_bytes`.  The conv layers' state is
-        `conv_state_bytes`, apart."""
-        n = sum(int(t.nbytes) for t in (self._kc, self._vc)
+        `GenerativePredictor.kv_cache_bytes`; with it the scanned-state
+        table of a stack with attention+ssm layers (`ssm_state_bytes`).
+        The conv layers' state is `conv_state_bytes`, apart."""
+        n = sum(int(t.nbytes) for t in (self._kc, self._vc, self._ss)
                 if t is not None)
         if self.predictor._kv_quant:
             n += int(np.asarray(self.predictor._kv_scales).nbytes)
@@ -2816,10 +3219,15 @@ class DecodeSession:
         stack with none)."""
         return 0 if self._cs is None else int(self._cs.nbytes)
 
+    def ssm_state_bytes(self):
+        """MEASURED footprint of the scanned state (0 for a stack with no
+        attention+ssm layer)."""
+        return 0 if self._ss is None else int(self._ss.nbytes)
+
     def _tables(self):
-        """The slots' state as the phases take it: (kc, vc[, cs]); an
-        MLA stack's (latent rows,)."""
-        return tuple(t for t in (self._kc, self._vc, self._cs)
+        """The slots' state as the phases take it: (kc, vc[, cs[, ss]]);
+        an MLA stack's (latent rows,)."""
+        return tuple(t for t in (self._kc, self._vc, self._cs, self._ss)
                      if t is not None)
 
     def _keep(self, tables):
@@ -2830,6 +3238,8 @@ class DecodeSession:
             self._vc = next(tables)
         if self._cs is not None:
             self._cs = next(tables)
+        if self._ss is not None:
+            self._ss = next(tables)
 
     # -- phases ---------------------------------------------------------
 
@@ -2908,15 +3318,20 @@ class DecodeSession:
         if self._inplace:
             write_rows = _slot_writers()[0]
             at = self._slot_ids[slot]
-            self._keep([write_rows(t, rows, at)
-                        for t, rows in zip(self._tables(), new)])
+            self._keep(write_rows(self._tables(), tuple(new), at))
         else:
             kc, vc = new
             at = (0, slot, 0, 0)
             self._kc = jax.lax.dynamic_update_slice(self._kc, kc, at)
             self._vc = jax.lax.dynamic_update_slice(self._vc, vc, at)
-        tok = int(self._fetch("prefill", first,
-                              routed=True)[0].reshape(-1)[0])
+        # a scanning stack's prefill spans say what was scanned
+        scanned = {}
+        if self._ss is not None:
+            chunk = min(self.predictor._block_meta["ssm_chunk"], int(bucket))
+            scanned = {"bucket": int(bucket),
+                       "ssm_chunks": -(-int(bucket) // chunk)}
+        tok = int(self._fetch("prefill", first, routed=True,
+                              more=scanned)[0].reshape(-1)[0])
         self.lengths[slot] = n
         self.last_tokens[slot] = tok
         self.active[slot] = True
@@ -2975,7 +3390,7 @@ class DecodeSession:
         return {"kv_blocks_live": int(live.sum()) * layers,
                 "kv_blocks_total": trips * n_slots * layers * n_blocks}
 
-    def _fetch(self, phase, *outs, routed=False, trips_at=None):
+    def _fetch(self, phase, *outs, routed=False, trips_at=None, more=None):
         """`np.asarray` of each result: the wait for the device and the
         copy to the host, under one `decode/fetch` span; the call's
         `decode/put` and `decode/launch` spans (`_call`) are stamped
@@ -2992,7 +3407,11 @@ class DecodeSession:
         with conv layers says so
         on the fetch span of its steps and prefills: `conv_layers`,
         `attn_layers`, `conv_state_bytes` (the session's conv-state
-        table).  Any other artifact takes the path it always took."""
+        table); one with attention+ssm layers `ssm_layers` and
+        `ssm_state_bytes` (its scanned-state table) too, and `more` (its
+        prefill's `bucket` and `ssm_chunks`, the chunks scanned) goes on
+        the call's launch and fetch spans.  Any other artifact takes the
+        path it always took."""
         n_routed = 2 * self._n_routed if routed else 0
         if not (n_routed or obs_tracing.enabled()):
             return [np.asarray(o) for o in outs]
@@ -3016,11 +3435,14 @@ class DecodeSession:
                     got[0][trips_at - self.n_slots:trips_at] > 0,
                     trips["trips"]))
             launched, self._launched = self._launched, ()
-            for name, a, b, more in launched:
+            more = more or {}
+            for name, a, b, own in launched:
                 obs_tracing.stamp(name, a, b, kind="serving", phase=phase,
-                                  **more, **trips)
+                                  **own, **trips,
+                                  **(more if name == "decode/launch" else {}))
             obs_tracing.stamp("decode/fetch", t0, t1, kind="serving",
-                              phase=phase, d2h_bytes=d2h, **attrs, **trips)
+                              phase=phase, d2h_bytes=d2h, **attrs, **trips,
+                              **more)
         return got
 
     def decode_fused(self, n_steps, budget=None, max_trips=None):
@@ -3101,16 +3523,15 @@ class DecodeSession:
         return int(self.predictor.max_seq_len - self.lengths[slot])
 
     def free(self, slot):
-        """Release a slot: its state of BOTH kinds (K/V lines, conv
-        state) is ZEROED before it can be reused — a later occupant
+        """Release a slot: its state of EVERY kind (K/V lines, conv
+        state, scanned state) is ZEROED before it can be reused — a later occupant
         starts from exact zeros, never from a previous request's keys or
         inputs (the no-leakage contract the chaos decode-disconnect
         scenario pins)."""
         self._alive()
         if self._inplace:
             zero_slot = _slot_writers()[1]
-            self._keep([zero_slot(t, self._slot_ids[slot])
-                        for t in self._tables()])
+            self._keep(zero_slot(self._tables(), self._slot_ids[slot]))
         else:
             import jax.lax
             import jax.numpy as jnp
@@ -3166,8 +3587,8 @@ class DecodeSession:
             self.last_tokens[slot] = np.int32(last_token)
 
     def slot_is_zero(self, slot):
-        """True when the slot's state of both kinds (its K and V cache
-        lines, its conv state) is exact zeros — the test hook for the
+        """True when the slot's state of every kind (its K and V cache
+        lines, its conv state, its scanned state) is exact zeros — the test hook for the
         zero-before-reuse contract."""
         self._alive()
         return not any(np.asarray(t[:, slot]).any()

@@ -82,11 +82,14 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       "olmoe_decode_saturated", "lfm2_decode_saturated",
                       # PR 35's cell, whose latent kernel shares the stream's
                       # rule and counter (`kv_last_block`, `_kv_stream`)
-                      "pangu_decode_saturated"]}
-    # appended, not inserted: only PR 35's five readers, PR 38's one and
-    # PR 39's nine stand behind it
+                      "pangu_decode_saturated",
+                      # PR 42's cell: its K/V rows alone are counted, its
+                      # scanned state is no stream of rows
+                      "falconh1_decode_saturated"]}
+    # appended, not inserted: only PR 35's five readers, PR 38's one,
+    # PR 39's nine and PR 42's six stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 16
+        manifest["per_layer"]) - 22
 
 
 # ---------------------------------------------------------------------------
@@ -130,14 +133,15 @@ def test_decode_early_launch_share_reader(case, spans, want):
 
 def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # PR 39's nine readers stand behind it
-    assert manifest["per_layer"][-10] == {
+    # PR 39's nine readers and PR 42's six stand behind it
+    assert manifest["per_layer"][-16] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
         "workloads": ["gpt2s_decode_saturated", "gpt2s_decode_deep",
                       "olmoe_decode_saturated", "lfm2_decode_saturated",
-                      "pangu_decode_saturated"]}
+                      "pangu_decode_saturated",
+                      "falconh1_decode_saturated"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-10]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-16]["workloads"] == e2e["workloads"]

@@ -509,8 +509,10 @@ def check_window(pred, n_slots, prompt_list, what):
     """A window of `decode.STEP_WINDOW` trips against as many one-trip
     dispatches of the same step executable, from the same admissions: equal
     tokens, and equal tables bit for bit, before the committed length and
-    past it.  Returns the trips the window ran (fewer than the window only
-    if a stream met EOS)."""
+    past it.  A stream that meets EOS stops at its own trip and sits the
+    rest of the window out (it is held, not run, in the one-trip session
+    from that trip on).  Returns the trips the window ran (fewer than the
+    window only if every stream met EOS)."""
     import jax.numpy as jnp
     from paddle_tpu.inference.decode import STEP_WINDOW
 
@@ -522,13 +524,22 @@ def check_window(pred, n_slots, prompt_list, what):
     live = len(prompt_list)
     win = admitted()
     toks, counts, trips = win.decode_fused(STEP_WINDOW)
-    require(1 <= trips <= STEP_WINDOW
-            and counts.tolist() == [trips] * live + [0] * (n_slots - live),
+    ended = [i for i in range(live) if counts[i] < STEP_WINDOW]
+    require(1 <= trips == counts.max() and counts[:live].min() >= 1
+            and not counts[live:].any()
+            and all(toks[i, counts[i] - 1] == pred.eos_id for i in ended),
             "the %s window ran %d trips and emitted %s"
             % (what, trips, counts.tolist()))
     one = admitted()
-    steps = np.stack([one.decode() for _ in range(trips)], axis=1)
-    require(np.array_equal(toks[:live, :trips], steps[:live]),
+    steps = []
+    for t in range(trips):
+        one.active[:live] = counts[:live] > t
+        steps.append(one.decode())
+    one.active[:live] = True
+    steps = np.stack(steps, axis=1)
+    ran = np.arange(trips)[None] < counts[:live, None]
+    require(np.array_equal(toks[:live, :trips][ran], steps[:live][ran])
+            and not toks[:live, :trips][~ran].any(),
             "the %s window's tokens differ from %d one-trip dispatches: "
             "%s against %s" % (what, trips, toks[:live, :trips].tolist(),
                                steps[:live].tolist()))

@@ -131,12 +131,17 @@ rate.  So the step executable, `step_fn(n_slots)`, IS up to
 tokens, the token block} through step + argmax + KV write per trip,
 with per-slot budgets and the trip count RUNTIME arguments (`budget`,
 `max_trips`), so one executable per slot count serves a one-trip round
-and a full window alike, and ending in-graph with the trip in which
-the first running slot stops (EOS, budget, cache room).
+and a full window alike.  A slot STOPS in-graph with the trip in which
+it emits EOS, meets its budget or fills its cache, and sits out the
+rest of the window as a slot that does not run (nothing of its state is
+written again); the window ends at `max_trips` or with the trip in
+which its LAST running slot stops.  A slot that stops at trip j of W
+idles W - j slot-trips, which the benchmark's `slots_busy_share` reads.
 `DecodeSession.decode()` is one trip of it, `decode_fused` a window;
 the serving lane picks the trips of each dispatch from its own slot
-table (`DecodeBatcher._lane_iter`).  The speculative path rides the
-same discipline: `fused_spec_fn` runs k draft steps + batched verify
+table (`DecodeBatcher._lane_iter`: the largest live budget).  The
+speculative path rides the same discipline: `fused_spec_fn` runs k
+draft steps + batched verify
 + in-graph accept/rollback/catch-up as one dispatch
 (`SpeculativeDecodeSession.step(fused=True)`).  Because the per-trip
 body is one `_step_core` and per-slot math is independent, a window's
@@ -2529,20 +2534,28 @@ class GenerativePredictor:
             max_new / cache-room headroom);
           * `max_trips` [] i32: the dispatch's trip count (at most the
             window).  The serving lane sets it per dispatch: the
-            smallest live budget, less under the deadline governor.
+            largest live budget, less under the deadline governor.
 
         The slots that RUN are those active with budget and cache room
-        left when the window begins, and the window ends in-graph with
-        the trip in which the FIRST of them stops, whatever stops it
-        (EOS, its budget met, its cache full): every trip of a window
-        advances every running slot, none sits out a dead trip, and a
-        lane with every slot assigned can say that nothing joins before
-        a slot ends.  Returns (out, *slot state'); `out` is ONE int32
+        left when the window begins.  The carry holds who is still
+        `alive` and what each slot has `emitted`: a slot leaves `alive`
+        with the trip in which it stops, whatever stops it (EOS, its
+        budget met, its cache full), and from then on it is to
+        `_step_core` a slot that does not run, so it writes no K/V or
+        latent row, rolls no conv window and updates no scanned state:
+        its state after the window is its state at its own stop.  (It
+        also attends at length 0, so the decode kernel stages one block
+        for it a trip and not its rows.)  The window ends at `max_trips`
+        or with the trip in which the LAST running slot stops.  What a
+        window that runs past its first ender costs is dead slot-trips,
+        W - j for a slot that stops at trip j of W (`slots_busy_share`
+        in the benchmark); what it saves is a dispatch of host work for
+        every ender.  Returns (out, *slot state'); `out` is ONE int32
         vector, so a dispatch costs one fetch
         (`DecodeSession.decode_fused` splits it): the [N, STEP_WINDOW]
         token block (`emitted[s]` of row s valid, in stream order), `emitted`
-        [N] (the trips run for a running slot, 0 for the others), the
-        trips run, and for a routed-expert artifact each layer's
+        [N] (a slot's OWN count: the trips it ran, at most the window's),
+        the trips run, and for a routed-expert artifact each layer's
         (experts touched SUMMED over the trips, most tokens on one
         expert, the LARGEST over the trips), which is what
         `_pack_routing` carries for a prefill.  Its arguments are those
@@ -2556,42 +2569,44 @@ class GenerativePredictor:
         def window(state, tables, lengths, last_tokens, active, budget,
                    max_trips):
             N, S = tables[0].shape[1:3]
-            running = active & (budget > 0) & (lengths < jnp.int32(S))
-            adv = running.astype(jnp.int32)
+            alive = active & (budget > 0) & (lengths < jnp.int32(S))
             trips = jnp.minimum(max_trips, jnp.int32(W))
 
             def cond(carry):
-                return (carry[0] < trips) & ~carry[-1]
+                return (carry[0] < trips) & jnp.any(carry[-2])
 
             def body(carry):
-                i, tables, last, toks, facts, _ = carry
+                i, tables, last, toks, facts, alive, emitted = carry
+                # a slot that has stopped is one that does not run: it
+                # writes nothing, and attends over no row of its own
                 logits, tables, f = self._step_core(
-                    state, tables, lengths + adv * i, last, running,
-                    tp=tp)
+                    state, tables, jnp.where(alive, lengths + emitted, 0),
+                    last, alive, tp=tp)
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                # land this trip's tokens at column i (one-hot select:
-                # a slot that does not run keeps its row of zeros)
-                col = (jnp.arange(W)[None, :] == i) & running[:, None]
+                # land this trip's tokens at column i, which for a slot
+                # still alive is its own count (one-hot select: a slot
+                # that does not run keeps its zeros)
+                col = (jnp.arange(W)[None, :] == i) & alive[:, None]
                 toks = jnp.where(col, tok[:, None], toks)
                 if routed:
                     f = jnp.stack([r for r in f if r is not None])
                     facts = jnp.stack(
                         [facts[:, 0] + f[:, 0],
                          jnp.maximum(facts[:, 1], f[:, 1])], axis=1)
-                i = i + 1
-                stopped = jnp.any(running & (
-                    (tok == jnp.int32(eos)) | (i >= budget)
-                    | (lengths + i >= jnp.int32(S))))
-                return (i, tables, jnp.where(running, tok, last), toks,
-                        facts, stopped)
+                last = jnp.where(alive, tok, last)
+                emitted = emitted + alive.astype(jnp.int32)
+                alive = (alive & (tok != jnp.int32(eos))
+                         & (emitted < budget)
+                         & (lengths + emitted < jnp.int32(S)))
+                return (i + 1, tables, last, toks, facts, alive, emitted)
 
             carry = (jnp.int32(0), tables, last_tokens,
                      jnp.zeros((N, W), jnp.int32),
                      jnp.zeros((routed, 2), jnp.int32),
-                     ~jnp.any(running))
-            i, tables, _last, toks, facts, _ = jax.lax.while_loop(
+                     alive, jnp.zeros((N,), jnp.int32))
+            i, tables, _last, toks, facts, _, emitted = jax.lax.while_loop(
                 cond, body, carry)
-            out = jnp.concatenate([toks.reshape(-1), adv * i, i[None],
+            out = jnp.concatenate([toks.reshape(-1), emitted, i[None],
                                    facts.reshape(-1)])
             return (out,) + tables
 
@@ -2691,7 +2706,9 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (8: a
+            # rev bumps when the phase math itself changes shape (9: a
+            # window runs past its first ender, a slot that stops sits
+            # the rest out; 8: a
             # prefill returns its K and V as the tables hold a position,
             # one flat row; 7:
             # the decode kernel's stream stops at a slot's length, the
@@ -2705,7 +2722,7 @@ class GenerativePredictor:
             # and routing): equal weight shapes, another function
             "block": [[k, self._block_meta[k]]
                       for k in sorted(self._block_meta)],
-            "rev": 8,
+            "rev": 9,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -3372,20 +3389,23 @@ class DecodeSession:
         self.steps += 1
         return toks, logits
 
-    def _kv_stream(self, ran, trips):
+    def _kv_stream(self, counts, trips):
         """What the decode kernel staged in a step dispatch of `trips`
-        trips in which the slots `ran` [N] bool advanced, by the
+        trips of which slot n ran its own first `counts[n]`, by the
         kernel's own rule (`pallas_kernels.kv_last_block`): trip t
-        attends slot n under `lengths[n] + t + 1` positions if it ran,
-        `lengths[n] + 1` if not, in every attention layer.
-        `kv_blocks_live` are the K/V blocks staged, `kv_blocks_total`
-        what whole rows would be (trips x slots x layers x S / block)."""
+        attends slot n under `lengths[n] + t + 1` positions while it
+        runs and under one (a block, all masked) once it has stopped,
+        as a slot that never ran does (`_step_math`), in every attention
+        layer.  `kv_blocks_live` are the K/V blocks staged,
+        `kv_blocks_total` what whole rows would be (trips x slots x
+        layers x S / block)."""
         if not self._kv_block:
             return {}
         from paddle_tpu.ops.pallas_kernels import kv_last_block
         layers, n_slots, S = self._kc.shape[:3]
         n_blocks = S // self._kv_block
-        seen = self.lengths[None] + ran[None] * np.arange(trips)[:, None] + 1
+        trip = np.arange(trips)[:, None]
+        seen = np.where(trip < counts[None], self.lengths[None] + trip, 0) + 1
         live = kv_last_block(seen, self._kv_block, n_blocks) + 1
         return {"kv_blocks_live": int(live.sum()) * layers,
                 "kv_blocks_total": trips * n_slots * layers * n_blocks}
@@ -3432,7 +3452,7 @@ class DecodeSession:
             if trips_at is not None:
                 trips = {"trips": int(got[0][trips_at])}
                 attrs.update(self._kv_stream(
-                    got[0][trips_at - self.n_slots:trips_at] > 0,
+                    got[0][trips_at - self.n_slots:trips_at],
                     trips["trips"]))
             launched, self._launched = self._launched, ()
             more = more or {}
@@ -3451,10 +3471,11 @@ class DecodeSession:
         decode").  Returns (tokens [n_slots, n_steps] int32, counts
         [n_slots] int32, trips int): slot s emitted `counts[s]` tokens
         this dispatch, `tokens[s, :counts[s]]` in stream order; `trips`
-        is how many loop iterations ran.  The window ends with the trip
-        in which the first running slot stops (EOS, its budget, its
-        cache full: `_step_math`), so `counts[s]` is `trips` for every
-        slot that ran and 0 for the others, and a caller that wants
+        is how many loop iterations ran.  A slot stops with the trip in
+        which it emits EOS, meets its budget or fills its cache and
+        sits out the trips left; the window ends with the trip in which
+        the last running slot stops (`_step_math`), so `counts[s]` is
+        the slot's own count, at most `trips`, and a caller that wants
         more calls again.  `budget` [n_slots] caps each slot's
         emissions (max_new / cache-room headroom; clipped to [0,
         n_steps], zero for inactive slots); `max_trips` clamps the

@@ -1065,14 +1065,18 @@ class DecodeBatcher:
 
     A dispatch is a WINDOW of decode steps (SERVING.md "Fused
     multi-step decode"; `DecodeSession.decode_fused`), and the lane
-    picks its trips from its own slot table: min(cap, smallest
-    remaining budget of the live slots), so a dispatch ends on the
-    round in which the first slot must end, in-graph no later than the
-    trip in which the first slot does end (an early EOS).  With every
-    slot assigned nothing can join before that; with a slot free a
-    newcomer waits for the window's end.  A full lane whose last
-    dispatch ended nobody launches the next one before it hands the
-    last one's tokens to the streams (`_lane_iter`).  Joins, leaves, cancels and deadline evictions
+    picks its trips from its own slot table: min(cap, LARGEST
+    remaining budget of the live slots), each slot's own budget riding
+    beside it.  A slot that ends inside the window (its budget, its
+    cache room, an EOS) stops in-graph and sits out the trips left; the
+    dispatch ends when its last slot has stopped, so streams that end
+    one by one do not each cost a dispatch of host work.  What that
+    costs is the ender's dead slot-trips (the benchmark's
+    `slots_busy_share`) and up to a window's wait for its terminal
+    frame and for the newcomer that takes its slot.  A full lane whose
+    last dispatch ended nobody launches the next one before it hands
+    the last one's tokens to the streams (`_lane_iter`).  Joins, leaves,
+    cancels and deadline evictions
     happen at dispatch boundaries, per-token EOS/max-new cuts land in stream
     order from the returned token block, and a per-lane EWMA of step
     time clamps the trips so no deadline overshoots by more than one
@@ -1719,13 +1723,14 @@ class DecodeBatcher:
                               int(counts.sum()) - n_act)
             else:
                 # the window, from what the lane can see: run to the
-                # round in which the first live slot must end (its
-                # max_new / cache-room budget), the cap at most.  With
-                # every slot assigned nothing can join before that; with
-                # a slot free a newcomer waits for the window's end, one
-                # window at most, and the streams that are live do not
-                # pay a dispatch of host work for every token (PERF.md
-                # section 6, PR 38).  The deadline governor: the lane's
+                # round in which the LAST live slot must end (its
+                # max_new / cache-room budget), the cap at most.  A slot
+                # whose budget ends sooner stops in-graph at its own
+                # trip and sits the rest out (`_step_math`): streams
+                # that end one by one then cost dead slot-trips, not a
+                # dispatch of host work each (PERF.md section 6, PR 43).
+                # A newcomer waits for the window's end, one window at
+                # most (PR 38).  The deadline governor: the lane's
                 # EWMA step time clamps the trips so a deadlined stream
                 # never overshoots by more than ~one dispatch
                 cap = self.fuse_steps
@@ -1734,10 +1739,10 @@ class DecodeBatcher:
                 for slot, req in lane.assigned.items():
                     budget[slot] = min(req.max_new - len(req.gen),
                                        sess.room(slot), cap)
-                    max_trips = min(max_trips, int(budget[slot]))
                     if req.deadline is not None and lane.step_ewma:
                         max_trips = min(max_trips, int(
                             (req.deadline - t0) / lane.step_ewma))
+                max_trips = min(max_trips, int(budget.max()))
                 sess.launch_fused(cap, budget=budget,
                                   max_trips=max(max_trips, 1))
                 # the device runs this dispatch: the streams get the

@@ -182,6 +182,17 @@ def test_prefill_writes_the_conv_state_at_the_true_prompt_end(opened):
     assert not cs[:, 0, 0].any() and cs[:, 0, 1].any()
 
 
+def test_a_slot_that_stops_mid_window_keeps_both_kinds_of_state(opened):
+    """Its K/V rows and its conv state after the window are those of its
+    own stop, bit for bit (no row written, no window rolled in the trips it
+    sat out); the neighbour's stream, the routing facts and the K/V blocks
+    counted are those of the trips each slot ran
+    (`tests/test_decode_window.py`)."""
+    from tests.test_decode_window import a_slot_that_stops_sits_out_the_window
+    a_slot_that_stops_sits_out_the_window(
+        opened[0], [_prompt(9), _prompt(13, seed=5)], 3)
+
+
 def test_a_freed_slot_is_zero_in_both_kinds_of_state(opened):
     pred, _ = opened
     sess = pred.new_session(3)

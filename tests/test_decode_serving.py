@@ -735,10 +735,11 @@ class TestFusedDecode:
         assert metrics.tokens_per_dispatch.count == dispatches
 
     def test_fused_eos_early_exit_mid_window(self, predictor):
-        """A slot hitting EOS mid-window ends the window in-graph with
-        that trip: the dispatch returns fewer trips than it was asked
-        for, the EOS token itself is emitted, no other slot sits
-        through a dead trip, and the stream equals the greedy oracle."""
+        """An EOS landing mid-window stops ITS slot in-graph: the
+        dispatch returns the slot's own count (the EOS token itself its
+        last), the other slot runs the window out, and the window ends
+        once no slot is left alive; each stream equals the greedy
+        oracle."""
         from paddle_tpu.inference.decode import STEP_WINDOW
         # pick an eos id whose FIRST occurrence in the greedy stream is
         # mid-window (index >= 4) so the early exit is provoked for
@@ -764,25 +765,23 @@ class TestFusedDecode:
         live = {i for i, o in outs.items() if o[-1] != eos_tok}
         ran = []
         while live:
-            # the window must end with the trip in which the first live
-            # stream meets its EOS
-            want = min([STEP_WINDOW] + [len(refs[i]) - len(outs[i])
-                                        for i in live])
+            # each live slot runs to its own EOS, the window's end at
+            # most; the window runs while any of them is alive
+            own = [min(STEP_WINDOW, len(refs[i]) - len(outs[i]))
+                   if i in live else 0 for i in (0, 1)]
             toks, counts, trips = sess.decode_fused(STEP_WINDOW)
-            assert trips == want, (trips, want, ran)
-            # every trip of a window advances every running slot
-            assert counts.tolist() == [trips if i in live else 0
-                                       for i in (0, 1)]
+            assert (trips, counts.tolist()) == (max(own), own), ran
             for i in sorted(live):
-                outs[i] += toks[i, :trips].tolist()
+                outs[i] += toks[i, :counts[i]].tolist()
                 if outs[i][-1] == eos_tok:
                     sess.free(i)
                     live.discard(i)
-            ran.append(trips)
+            ran.append((trips, own))
         assert outs == refs, "fused EOS streams diverged: %s vs %s" \
             % (outs, refs)
-        assert outs[0][-1] == eos_tok and min(ran) < STEP_WINDOW, \
-            "EOS mid-window did not end the window with its trip"
+        assert outs[0][-1] == eos_tok \
+            and any(0 < min(own) < trips for trips, own in ran), \
+            "no slot met its EOS while its neighbour ran on: %r" % ran
 
     def test_fused_warm_reload_all_hits(self, artifact, tmp_path):
         """The fused executables ride the persistent compile cache
@@ -883,36 +882,35 @@ def traced():
     obs_tracing.set_enabled(was)
 
 
-def _rule(budgets, cap):
-    """What the rule dispatches for streams admitted together with
-    `budgets` decode tokens left each: [trips] until all have ended."""
-    left, out = list(budgets), []
-    while any(left):
-        live = [b for b in left if b]
-        trips = min(cap, min(live))
-        out.append(trips)
-        left = [max(b - trips, 0) for b in left]
-    return out
+# what the rule dispatches for streams admitted together: [(trips, [tokens
+# a slot])] until all have ended
+from tests.test_decode_window import _windows as _rule  # noqa: E402
 
 
-@pytest.mark.parametrize("n_steps,live,total", [
+@pytest.mark.parametrize("n_steps,budget,live,total", [
     # 2 layers x 2 blocks of 128 over S = 256.  Three trips: slot 0
     # attends under 127, 128, 129 positions (1, 1, 2 blocks), slot 1
     # under 6, 7, 8 (1, 1, 1), the idle slot 2 under 0 + 1 (1, 1, 1)
-    (3, 2 * (4 + 3 + 3), 3 * 3 * 2 * 2),
-    (1, 2 * (1 + 1 + 1), 1 * 3 * 2 * 2)])
+    (3, None, 2 * (4 + 3 + 3), 3 * 3 * 2 * 2),
+    (1, None, 2 * (1 + 1 + 1), 1 * 3 * 2 * 2),
+    # slot 0 stops with its second trip and sits the third out: under
+    # 127 and 128 positions, then as an idle slot does (1, 1, 1), and
+    # not under 129 (2 blocks), which it would have needed had it run
+    (3, [2, 3, 0], 2 * (3 + 3 + 3), 3 * 3 * 2 * 2)])
 def test_step_fetch_span_counts_the_kv_blocks_streamed(
-        endless, traced, n_steps, live, total):
+        endless, traced, n_steps, budget, live, total):
     """`kv_blocks_live` / `kv_blocks_total` of a step's `decode/fetch`
     span against a count by hand (the kernel's rule, `kv_last_block`:
-    ceil((lengths + t + 1) / block) blocks a slot, a trip and a layer)."""
+    ceil((lengths + t + 1) / block) blocks a slot, a trip and a layer,
+    over the slot's OWN trips; one block a trip once it has stopped)."""
     sess = endless.new_session(3)
     assert sess._kv_block == 128 and sess._kc.shape[:3] == (2, 3, 256)
     sess.prefill(0, [1 + i % 30 for i in range(126)])
     sess.prefill(1, [5, 9, 3, 7, 2])
     traced.clear()
-    _, counts, trips = sess.decode_fused(n_steps)
-    assert trips == n_steps and counts.tolist() == [trips, trips, 0]
+    _, counts, trips = sess.decode_fused(n_steps, budget=budget)
+    assert trips == n_steps \
+        and counts.tolist() == (budget or [trips, trips, 0])
     fetch, = [s for s in traced.recent_spans(name="decode/fetch")
               if s["attrs"]["phase"] == "step"]
     assert fetch["attrs"]["trips"] == trips
@@ -923,13 +921,14 @@ def test_step_fetch_span_counts_the_kv_blocks_streamed(
 class TestWindowRule:
     @pytest.mark.parametrize("cap,max_new", [
         (None, (20, 13)), (None, (9, 9)), (4, (20, 13)), (None, (3, 30))])
-    def test_every_slot_assigned_runs_to_the_first_end(
+    def test_every_slot_assigned_runs_to_the_last_end(
             self, endless, traced, cap, max_new):
-        """Each dispatch runs min(cap, smallest remaining budget of the
-        live slots) trips, so a window ends on the round in which the
-        first slot must end; a slot that is free afterwards (nothing
-        queued to refill it) changes nothing for the stream that is
-        left."""
+        """Each dispatch runs min(cap, LARGEST remaining budget of the
+        live slots) trips: a slot whose budget ends inside the window
+        stops there and sits the rest out, so its last tokens come with
+        the window's and the dispatch counts its own tokens only; a slot
+        that is free afterwards (nothing queued to refill it) changes
+        nothing for the stream that is left."""
         from paddle_tpu.inference.decode import STEP_WINDOW
         b = DecodeBatcher(endless, n_slots=2, fuse_steps=cap)
         assert b.fuse_steps == (cap or STEP_WINDOW)
@@ -945,8 +944,9 @@ class TestWindowRule:
         steps = _dispatches()
         # the prefill emitted each stream's first token
         want = _rule([m - 1 for m in max_new], b.fuse_steps)
-        assert [s["attrs"]["trips"] for s in steps] == want
-        assert max(want) > 1 and sum(s["attrs"]["tokens"] for s in steps) \
+        assert [(s["attrs"]["trips"], s["attrs"]["tokens"])
+                for s in steps] == [(t, sum(c)) for t, c in want]
+        assert want[0][0] > 1 and sum(s["attrs"]["tokens"] for s in steps) \
             == sum(max_new) - 2
 
     def test_a_free_slot_runs_windows_too(self, endless, traced):
@@ -963,9 +963,11 @@ class TestWindowRule:
         finally:
             b.close()
         steps = _dispatches()
-        assert [s["attrs"]["trips"] for s in steps] == \
-            _rule([11, 9], b.fuse_steps) == [8, 1, 2]
-        assert [s["attrs"]["slots"] for s in steps] == [2, 2, 1]
+        assert [(s["attrs"]["trips"], s["attrs"]["tokens"])
+                for s in steps] == [(t, sum(c)) for t, c in
+                                    _rule([11, 9], b.fuse_steps)] \
+            == [(8, 16), (3, 4)]
+        assert [s["attrs"]["slots"] for s in steps] == [2, 2]
 
     def test_cancel_inside_a_window_is_honoured_at_its_boundary(
             self, endless, traced):
@@ -1057,7 +1059,7 @@ class TestWindowRule:
         assert outs[None] == outs[1]
         # (how many dispatches each lane took depends on when the joins
         # land against its rounds; the rule's arithmetic is held by
-        # `test_every_slot_assigned_runs_to_the_first_end`)
+        # `test_every_slot_assigned_runs_to_the_last_end`)
         assert min(dispatches.values()) >= 1
 
 

@@ -277,6 +277,16 @@ def test_a_window_is_its_one_trip_dispatches(opened):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+def test_a_slot_that_stops_mid_window_keeps_all_three_kinds_of_state(opened):
+    """Its K/V rows, conv window and scanned state after the window are
+    those of its own stop, bit for bit; the neighbour's stream and the
+    K/V blocks counted are those of the trips each slot ran
+    (`tests/test_decode_window.py`)."""
+    from tests.test_decode_window import a_slot_that_stops_sits_out_the_window
+    a_slot_that_stops_sits_out_the_window(
+        opened[0], [_prompt(6, 8), _prompt(10, 9)], 3)
+
+
 REFUSALS = {
     "rollback": lambda pred, art: pred.new_session(2).rollback(0, 0),
     "verify_fn": lambda pred, art: pred.verify_fn(2, 2),

@@ -8,7 +8,9 @@ block.
   and frame fact for frame fact (`finish_reason`, `new_tokens`): a stream
   that hits EOS mid-window, one whose budget ends on a window's last and on
   its first trip, one cancelled mid-window, a join while a window runs, a
-  deadline inside a window;
+  deadline inside a window; and, since a window runs on while ANY slot is
+  alive (PR 43), a slot whose budget, EOS or cache room ends mid-window
+  while its neighbour runs the window out;
 * after `ModelEntry.warm` a lane that runs windows of 1, 3 and
   `STEP_WINDOW` trips lowers and compiles NOTHING: one step executable a
   slot count, the trips a runtime argument of it.
@@ -19,6 +21,7 @@ CPU-safe under JAX_PLATFORMS=cpu.
 import threading
 import time
 
+import numpy as np
 import pytest
 
 from paddle_tpu.flags import set_flags
@@ -46,9 +49,10 @@ PROMPTS = ([5, 9, 3], [7, 2], [1, 2, 3, 4], [11, 6, 8, 2, 9])
 
 @pytest.fixture(autouse=True)
 def _quiet():
+    was = obs_tracing.enabled()
     yield
     set_dispatch_delay(0.0)
-    set_flags({"trace": False})
+    set_flags({"trace": was})
 
 
 @pytest.fixture(scope="module", params=sorted(BLOCKS))
@@ -143,7 +147,9 @@ def _traced():
 
 CASES = ["eos_mid_window", "budget_ends_on_last_trip",
          "budget_ends_on_first_trip", "cancel_mid_window",
-         "join_while_a_window_runs", "deadline_inside_a_window"]
+         "join_while_a_window_runs", "deadline_inside_a_window",
+         "budgets_3_and_2W", "eos_in_one_slot_the_other_runs_on",
+         "cache_room_ends_mid_window"]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -159,9 +165,10 @@ def test_streams_under_windows_equal_one_trip_dispatches(models, case):
 
 
 def _case_eos_mid_window(cap, endless, with_eos, prompt, eos_at):
-    """One slot, so every dispatch is a window: the window in which EOS
-    lands ends in-graph with that trip, and the terminal frame counts the
-    tokens that reached the client."""
+    """One slot, so every dispatch is a window: the slot stops in-graph
+    with the trip in which EOS lands, and with no slot left alive the
+    window ends there; the terminal frame counts the tokens that reached
+    the client."""
     with _Served(with_eos, 1, cap) as s:
         toks, reason, n = s.stream(prompt, 4 * W)
     assert (reason, n, len(toks)) == ("eos", eos_at + 1, eos_at + 1)
@@ -180,6 +187,8 @@ def _case_budget_ends_on_last_trip(cap, endless, *_):
 
 
 def _case_budget_ends_on_first_trip(cap, endless, *_):
+    """The lane asks for its largest live budget: one slot with one token
+    left gets a window of one trip."""
     with _Served(endless, 1, cap) as s:
         out = s.stream(PROMPTS[0], 2 + 2 * W)
     assert out[1:] == ("length", 2 + 2 * W)
@@ -214,7 +223,9 @@ def _case_cancel_mid_window(cap, endless, *_):
 
 def _case_join_while_a_window_runs(cap, endless, *_):
     """Two slots, three streams: the third arrives while the full lane
-    runs a window and is admitted when the first slot ends."""
+    runs a window and is admitted at the end of the window in which the
+    first slot ends.  While two streams are live every window is full:
+    the shorter one's end does not cut it."""
     set_dispatch_delay(0.01)
     with _Served(endless, 2, cap) as s:
         first = [None, None]
@@ -230,7 +241,9 @@ def _case_join_while_a_window_runs(cap, endless, *_):
         third = s.stream(PROMPTS[2], W + 3)     # queues behind a full lane
         t.join(timeout=120)
     if cap is None:
-        assert max(_trips()) > 1
+        # 5W - 1, 2W - 1 and W + 2 decode tokens: only the dispatch in
+        # which the LAST live stream ends is short
+        assert set(_trips()[:-1]) == {W}, _trips()
     else:
         assert set(_trips()) == {1}
     return first, third
@@ -259,6 +272,158 @@ def _case_deadline_inside_a_window(cap, endless, *_):
         assert late < 1.0, late
         after = s.stream(PROMPTS[1], 5)
     return whole, after
+
+
+def _ride_together(s, requests):
+    """`requests` [(prompt, max_new)] admitted by ONE pass of the lane of
+    `s`, the i-th into slot i: ([(tokens, finish_reason)], [each
+    dispatch's (trips, tokens a slot)])."""
+    sess = s.batcher._lanes[0].session
+    fetch, seen = sess.fetch_fused, []
+
+    def fetch_fused():
+        toks, counts, trips = fetch()
+        seen.append((trips, counts.tolist()))
+        return toks, counts, trips
+    sess.fetch_fused = fetch_fused
+    with s.batcher._cv:
+        streams = [s.batcher.submit(p, max_new_tokens=m)
+                   for p, m in requests]
+    outs = [(st.result(timeout=120)[0].tolist(), st.finish_reason)
+            for st in streams]
+    return outs, seen
+
+
+def _windows(lefts, cap):
+    """The dispatches of streams that ride together with `lefts` decode
+    tokens to go each: a dispatch runs min(cap, the LARGEST of them)
+    trips, and a slot its own min(cap, left) of those."""
+    lefts, out = list(lefts), []
+    while any(lefts):
+        counts = [min(n, cap) for n in lefts]
+        out.append((max(counts), counts))
+        lefts = [n - c for n, c in zip(lefts, counts)]
+    return out
+
+
+def _case_budgets_3_and_2W(cap, endless, *_):
+    """Two slots with 3 and 2W decode tokens to go: the first stops at
+    trip 3 of a window that runs on for its neighbour, and the slot is
+    empty in the next."""
+    with _Served(endless, 2, cap) as s:
+        outs, seen = _ride_together(
+            s, [(PROMPTS[0], 1 + 3), (PROMPTS[1], 1 + 2 * W)])
+    assert [o[1] for o in outs] == ["length", "length"]
+    assert [len(o[0]) for o in outs] == [1 + 3, 1 + 2 * W]
+    if cap is None:
+        assert seen == [(W, [3, W]), (W, [0, W])]
+    assert seen == _windows([3, 2 * W], cap or W)
+    return outs
+
+
+def _case_eos_in_one_slot_the_other_runs_on(cap, endless, with_eos, prompt,
+                                            eos_at):
+    """EOS lands in one slot mid-window while its neighbour runs on: the
+    window is not cut, the slot's count is its own, and its EOS is the
+    last token its stream gets."""
+    pred = GenerativePredictor(with_eos)
+    other = next(p for p in PROMPTS if p != prompt)
+    requests = [(prompt, 4 * W), (other, 1 + 4 * W)]
+    refs = [greedy_decode(pred, p, m) for p, m in requests]
+    assert refs[0][1] == "eos" and len(refs[0][0]) == eos_at + 1
+    with _Served(with_eos, 2, cap) as s:
+        outs, seen = _ride_together(s, requests)
+    assert outs == [(list(r[0]), r[1]) for r in refs]
+    assert seen == _windows([len(r[0]) - 1 for r in refs], cap or W)
+    if cap is None:
+        # the window in which the first EOS lands is not cut by it
+        # (unless both streams end in that very window)
+        assert any(0 < min(c) < trips for trips, c in seen) \
+            or len(seen) == 1, seen
+    return outs
+
+
+def _case_cache_room_ends_mid_window(cap, endless, *_):
+    """A slot's cache fills at trip 3 of a window (13 + 14W + 3
+    positions of 128) while its neighbour has budget and room left: the
+    slot stops in-graph at its last position, writes no row past it, and
+    the neighbour's window is whole."""
+    assert 13 + 14 * W + 3 == 128
+    with _Served(endless, 2, cap) as s:
+        outs, seen = _ride_together(
+            s, [(list(range(1, 14)), 400), (PROMPTS[1], 1 + 15 * W)])
+    pred = GenerativePredictor(endless)
+    assert outs[0] == (list(greedy_decode(pred, list(range(1, 14)),
+                                          400)[0]), "length")
+    assert [len(o[0]) for o in outs] == [1 + 14 * W + 3, 1 + 15 * W]
+    assert seen == _windows([14 * W + 3, 15 * W], cap or W)
+    if cap is None:
+        assert seen[-1] == (W, [3, W])
+    return outs
+
+
+def a_slot_that_stops_sits_out_the_window(pred, prompts, j):
+    """Two slots of `pred` through ONE window in which slot 0's budget
+    ends at trip `j` while slot 1 runs all W, against a session that makes
+    the same trips as one-trip dispatches (slot 0 held but not run from
+    trip j on).  After the window every table of the slot state, of
+    whatever kind (K/V or latent rows, conv windows, scanned states), is
+    bit for bit that of the one-trip session: the stopped slot's is its
+    state at its own stop, its neighbour's is unmoved by the stop; lengths,
+    last tokens, routing facts and the streams that follow agree too; and
+    `_kv_stream` counts the stopped slot's blocks over its own j trips.
+    Shared by the suite of each kind of stack."""
+    assert 0 < j < W and len(prompts) == 2
+    win, one = pred.new_session(2), pred.new_session(2)
+    for sess in (win, one):
+        for slot, p in enumerate(prompts):
+            sess.prefill(slot, p)
+    at_launch = win.lengths.copy()
+    # the host's count of what the kernel stages, at a block edge of 4
+    # positions: ceil((length + t + 1) / 4) blocks a layer for a slot's
+    # own trip t, one a layer for a trip it sits out
+    layers, _, S = win._kc.shape[:3]
+    keep, win._kv_block = win._kv_block, 4
+    staged = win._kv_stream(np.asarray([j, W], np.int32), W)
+    win._kv_block = keep
+    own = [sum(-(-(int(n) + t + 1) // 4) for t in range(ran))
+           for n, ran in zip(at_launch, (j, W))]
+    assert staged == {"kv_blocks_live": layers * (sum(own) + (W - j)),
+                      "kv_blocks_total": W * 2 * layers * (S // 4)}
+    toks, counts, trips = win.decode_fused(W, budget=[j, W])
+    assert (trips, counts.tolist()) == (W, [j, W])
+    singles, facts = [], []
+    for t in range(W):
+        if t == j:
+            one.active[0] = False            # holds its state, runs no more
+        singles.append(one.decode())
+        facts.append(one.last_routing)
+    one.active[0] = True
+    singles = np.stack(singles, axis=1)
+    np.testing.assert_array_equal(toks[0, :j], singles[0, :j])
+    np.testing.assert_array_equal(toks[1], singles[1])
+    assert not toks[0, j:].any()
+    for a, b in zip(win._tables(), one._tables()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert np.asarray(a)[:, 0].any() and np.asarray(a)[:, 1].any()
+    assert win.lengths.tolist() == one.lengths.tolist() \
+        == (at_launch + [j, W]).tolist()
+    assert win.last_tokens.tolist() == one.last_tokens.tolist()
+    if win.last_routing is not None:
+        # a slot that sits a trip out is not among the tokens it counts
+        facts = np.stack(facts)                             # [W, L, 2]
+        assert win.last_routing.tolist() == np.stack(
+            [facts[:, :, 0].sum(axis=0), facts[:, :, 1].max(axis=0)],
+            axis=1).tolist()
+    # both slots go on as if nothing had stopped
+    np.testing.assert_array_equal(win.decode_fused(3)[0],
+                                  one.decode_fused(3)[0])
+
+
+@pytest.mark.parametrize("j", [1, 3, W - 1])
+def test_a_slot_that_stops_mid_window_keeps_its_rows(models, j):
+    a_slot_that_stops_sits_out_the_window(
+        GenerativePredictor(models[0]), [PROMPTS[3], PROMPTS[0]], j)
 
 
 def test_a_warm_lane_compiles_nothing_whatever_the_window(models):
@@ -543,13 +708,17 @@ def _stack_artifact(name, root):
         from tests.test_decode_hybrid import LFM2_BLOCK, TINY
         return build_tiny_decode_model(
             str(root / name), block=LFM2_BLOCK, **dict(TINY, eos_id=-1))
+    if name == "ssm":
+        from tests.test_decode_ssm import SSM_BLOCK, TINY
+        return build_tiny_decode_model(
+            str(root / name), block=SSM_BLOCK, **dict(TINY, eos_id=-1))
     from paddle_tpu.inference.decode import save_decode_model
     from tests.test_mla_decode import META, _drawn
     meta = dict(META, eos_id=-1)
     return save_decode_model(str(root / name), _drawn(meta), meta)
 
 
-@pytest.mark.parametrize("stack", ["gpt2", "olmoe", "lfm2", "latent"])
+@pytest.mark.parametrize("stack", ["gpt2", "olmoe", "lfm2", "latent", "ssm"])
 def test_streams_of_the_new_order_equal_the_plain_stream(stack, tmp_path):
     """Token for token: a full lane under windows (launch, deliver, fetch),
     the same lane pinned to one trip a dispatch, and `greedy_decode`, for

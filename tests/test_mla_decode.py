@@ -270,6 +270,16 @@ def test_slots_joining_and_leaving_a_window_keep_every_stream(opened):
     assert got[2] == alone[2][:len(got[2])] and len(got[2]) == 17
 
 
+def test_a_slot_that_stops_mid_window_keeps_its_latent_rows(opened):
+    """Its latent rows after the window are those of its own stop, bit for
+    bit (no row landed in the trips it sat out); the neighbour's stream, the
+    routing facts and the blocks counted are those of the trips each slot
+    ran (`tests/test_decode_window.py`)."""
+    from tests.test_decode_window import a_slot_that_stops_sits_out_the_window
+    a_slot_that_stops_sits_out_the_window(
+        opened[0], [_prompt(9), _prompt(13, seed=5)], 3)
+
+
 def test_a_freed_slot_is_zero_and_its_neighbour_unmoved(opened):
     pred, _ = opened
     sess = pred.new_session(3)

@@ -730,9 +730,10 @@ class TestPhaseSpans:
         pred = GenerativePredictor(tiny_decode_dir)
         b = DecodeBatcher(pred, n_slots=2)
         try:
-            streams = [b.submit([5, 9, 3], max_new_tokens=6,
-                                trace_id="round-a"),
-                       b.submit([7, 2], max_new_tokens=4)]
+            with b._cv:         # both admitted by one pass of the lane
+                streams = [b.submit([5, 9, 3], max_new_tokens=11,
+                                    trace_id="round-a"),
+                           b.submit([7, 2], max_new_tokens=4)]
             for s in streams:
                 s.result(timeout=60)
         finally:
@@ -740,12 +741,14 @@ class TestPhaseSpans:
         spans = obs.recent_spans(kind="serving")
         iters = [s for s in spans if s["name"] == "serving/lane_iter"]
         steps = [s for s in spans if s["name"] == "serving/decode_step"]
-        # both slots assigned: ONE window to the round in which the
-        # shorter stream must end (3 trips), then the other's last two
-        assert iters and [s["attrs"]["trips"] for s in steps] == [3, 2]
+        # both slots assigned: ONE full window, in which the shorter
+        # stream stops at its third trip and sits five out, then the
+        # other's last two
+        assert iters and [(s["attrs"]["trips"], s["attrs"]["tokens"])
+                          for s in steps] == [(8, 8 + 3), (2, 2)]
         assert sum(i["attrs"]["admits"] for i in iters) == 2
         assert sum(i["attrs"]["emitted"] for i in iters) == \
-            sum(s["attrs"]["tokens"] for s in steps) == 6 + 4 - 2
+            sum(s["attrs"]["tokens"] for s in steps) == 11 + 4 - 2
         by_round = {i["attrs"]["round"]: i for i in iters
                     if i["attrs"]["emitted"] or not i["attrs"]["admits"]}
         for step in steps:

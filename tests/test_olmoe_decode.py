@@ -480,6 +480,16 @@ def test_a_window_reports_what_its_trips_touched(opened, trips):
         == int(facts[:, :, 1].max())
 
 
+def test_a_slot_that_stops_mid_window_keeps_its_rows_and_its_experts(opened):
+    """Its K/V rows after the window are those of its own stop, bit for
+    bit; the experts a window counts are those of the slots that ran each
+    trip, and the neighbour's stream is unmoved
+    (`tests/test_decode_window.py`)."""
+    from tests.test_decode_window import a_slot_that_stops_sits_out_the_window
+    a_slot_that_stops_sits_out_the_window(
+        opened[0], _prompts([9, 4], seed=21), 2)
+
+
 def test_benchmark_readers_read_a_lane_of_windows(opened):
     """The rehearsed tiny cell's spans (a closed loop over a full lane,
     so the lane runs windows) through the benchmark's own readers:

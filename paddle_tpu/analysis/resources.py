@@ -829,7 +829,7 @@ def _decode_report(path, meta, decode_slots, device, what,
     import numpy as np
     from ..flags import FLAGS
     from ..inference.decode import (layer_kinds, normalize_kv_dtype,
-                                    slot_state_shapes)
+                                    slot_state_shapes, window_state_shape)
     n_slots = int(decode_slots or FLAGS.serving_decode_slots)
     L = int(meta["n_layers"])
     H = int(meta["n_heads"])
@@ -864,11 +864,15 @@ def _decode_report(path, meta, decode_slots, device, what,
     kv_shape, conv_shape, ssm_shape = slot_state_shapes(meta, n_slots,
                                                         device)
     # (an MLA stack: ONE latent table [layers, n_slots, S, Rp], no V; a
-    # stack with attention+ssm layers: its fp32 scanned-state table too)
+    # stack with attention+ssm layers: its fp32 scanned-state table too;
+    # one with window_attention layers: their fp32 K and V rings)
     n_tables = 1 if layer_kinds(meta)[0][0] == "mla" else 2
+    ring_shape = window_state_shape(meta, n_slots)
     rep.kv_cache_bytes = (n_tables * int(np.prod(kv_shape)) * kv_elem
                           + kv_scales
-                          + (4 * int(np.prod(ssm_shape)) if ssm_shape else 0))
+                          + (4 * int(np.prod(ssm_shape)) if ssm_shape else 0)
+                          + (8 * int(np.prod(ring_shape)) if ring_shape
+                             else 0))
     # decode-step working set: one token's activations per slot, and the
     # conv layers' carried state (K-1 inputs a slot and layer, fp32)
     rep.activation_peak_bytes = n_slots * D * 4 * (L + 2) + (

@@ -17,8 +17,9 @@ TOGETHER.  This module is the inference-side half of the answer
   rotary positions, qk-norm over the projection or per head, multi-head
   | grouped-query | latent attention, a head size of its own
   (`head_dim`), a layer's operator attention | a gated short convolution
-  | attention IN PARALLEL with a Mamba-2 state-space mixer
-  (`layer_types`), fixed muP multipliers, leading dense SwiGLU layers,
+  | attention IN PARALLEL with a Mamba-2 state-space mixer | attention
+  over the last `sliding_window` positions only, rotated alone where
+  `rope_layers` says so (`layer_types`), fixed muP multipliers, leading dense SwiGLU layers,
   ReLU MLP | a dense SwiGLU in every layer | dropless routed SwiGLU
   experts under a softmax or a sigmoid
   router, all of them or the run of them a member of an expert-parallel
@@ -43,9 +44,9 @@ TOGETHER.  This module is the inference-side half of the answer
   cannot hold is refused by a typed error that names the meta key (the
   tensor-parallel lane any block but the default: its grammar has no
   rule for sharding experts; a mesh, a rollback, the speculative
-  phases and an int8 cache a stack with conv layers or with a scanned
-  state; a mesh, the speculative phases and an int8 cache a stack of
-  latent attention);
+  phases and an int8 cache a stack with conv layers, with a scanned
+  state or with window layers' rings; a mesh, the speculative phases and
+  an int8 cache a stack of latent attention);
 * a **prefill / decode phase split** (`GenerativePredictor`): prefill
   runs the whole prompt through the causal forward once per padded
   *prompt bucket* (each bucket's executable rides the persistent
@@ -63,7 +64,11 @@ TOGETHER.  This module is the inference-side half of the answer
   layers their SCANNED state, [ssm layers, n_slots, ssm_heads,
   ssm_head_dim, ssm_state] fp32: a decayed running sum over all a
   slot's positions, a fixed size, read and rewritten whole by every
-  token; a stack of latent attention holds, in the K/V
+  token; a stack with window_attention layers holds their K/V rows as
+  RINGS, [window layers, n_slots, sliding_window, K/V heads * head_dim]
+  beside the full layers' table (`window_state_shape`: two kinds of K/V
+  slot state, position p of a window layer at row p % sliding_window);
+  a stack of latent attention holds, in the K/V
   tables' place and ONCE, [mla layers, n_slots, max_seq_len, row]: one
   row of kv_lora_rank + qk_rope_head_dim values a position, which its
   prefill expands to per-head keys and values and its decode step
@@ -340,6 +345,17 @@ BLOCK_DEFAULTS = (
     ("ssm_multipliers", ()),
     # a dense gated FFN's (gate pre-activation, down's result); () = none
     ("mlp_multipliers", ()),
+    # layer_types "window_attention": grouped-query attention over the last
+    # `sliding_window` positions only, the token's own among them (key j is
+    # seen from position t iff t - sliding_window < j <= t).  Its slot state
+    # is a RING of `sliding_window` K/V rows a slot (`window_state_shape`):
+    # position p's row lies at p % sliding_window, beside the full layers'
+    # rows of every position.  0 = no window layer
+    ("sliding_window", 0),
+    # which attending layers turn q and k under position=rope: "all" |
+    # "window": the window layers alone, the full ones see no position
+    # signal (a stack that has both kinds)
+    ("rope_layers", "all"),
 )
 _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "position": ("learned", "rope"),
@@ -349,11 +365,15 @@ _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "ffn": ("relu_mlp", "moe_swiglu", "swiglu"),
                   "router": ("softmax", "sigmoid_bias", "sigmoid"),
                   "head": ("untied", "tied"),
-                  "weight_dtype": ("float32", "bfloat16")}
-_LAYER_TYPES = ("attention", "conv", "mla", "attention+ssm")
-# the kinds of slot state a layer's operator keeps (`slot_state_shapes`)
+                  "weight_dtype": ("float32", "bfloat16"),
+                  "rope_layers": ("all", "window")}
+_LAYER_TYPES = ("attention", "conv", "mla", "attention+ssm",
+                "window_attention")
+# the kinds of slot state a layer's operator keeps (`slot_state_shapes`;
+# "ring": `window_state_shape`)
 _HOLDS = {"attention": ("kv",), "conv": ("conv",), "mla": ("kv",),
-          "attention+ssm": ("kv", "conv", "ssm")}
+          "attention+ssm": ("kv", "conv", "ssm"),
+          "window_attention": ("ring",)}
 _SSM_DIMS = ("ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups")
 _MLA_DIMS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
              "qk_rope_head_dim", "v_head_dim")
@@ -408,6 +428,25 @@ def block_of(meta):
             "decode meta layer_types=%r needs one of %s for each of the %d "
             "layers, and an attention layer among them"
             % (list(kinds), "|".join(_LAYER_TYPES), n_layers))
+    windowed = "window_attention" in kinds
+    if out["sliding_window"] < 0 or windowed != bool(out["sliding_window"]):
+        raise ValueError(
+            "decode meta sliding_window=%d: >= 1 where layer_types has a "
+            "window_attention layer, which attends over that many "
+            "positions, and 0 where it has none (layer_types=%r)"
+            % (out["sliding_window"], list(kinds)))
+    if windowed and not {"attention", "attention+ssm"} & set(kinds):
+        raise ValueError(
+            "decode meta layer_types=%r: window_attention layers go beside "
+            "layers that attend over every position (a session's length "
+            "and room are its full-length table's; mla keeps no rows a ring "
+            "could hold)" % (list(kinds),))
+    if out["rope_layers"] == "window" and not (
+            windowed and out["position"] == "rope"):
+        raise ValueError(
+            "decode meta rope_layers=window goes with position=rope (%r) "
+            "and a stack with window_attention layers beside its full ones "
+            "(layer_types=%r)" % (out["position"], list(kinds)))
     if "mla" in kinds:
         if set(kinds) != {"mla"}:
             raise ValueError(
@@ -494,9 +533,11 @@ def block_of(meta):
         if out[key] and out["ffn"] != "moe_swiglu":
             raise ValueError("decode meta %s=%r goes with ffn=moe_swiglu"
                              % (key, out[key]))
-    if out["routed_scaling"] != 1.0 and out["router"] != "sigmoid":
+    if out["routed_scaling"] != 1.0 and out["router"] not in (
+            "sigmoid", "sigmoid_bias"):
         raise ValueError("decode meta routed_scaling=%r goes with "
-                         "router=sigmoid" % out["routed_scaling"])
+                         "router=sigmoid|sigmoid_bias"
+                         % out["routed_scaling"])
     held = out["experts_held"]
     if held and (len(held) != 2 or held[0] < 0 or held[1] < 1
                  or held[0] + held[1] > out["n_experts"]):
@@ -512,7 +553,8 @@ def block_of(meta):
 def layer_kinds(meta, blk=None):
     """(operator, FFN) of every layer of the stack `meta` describes
     (`blk`: its `block_of`, where the caller has it): operator
-    "attention" | "conv" | "mla" | "attention+ssm", FFN "dense_swiglu"
+    "attention" | "conv" | "mla" | "attention+ssm" | "window_attention",
+    FFN "dense_swiglu"
     (the first `n_dense_layers`; every layer under ffn=swiglu) or the
     meta's `ffn`."""
     blk = blk or block_of(meta)
@@ -543,8 +585,10 @@ def slot_state_shapes(meta, n_slots, device):
     scanned-state table shape or None):
 
       * [attention layers, N, S, Hc * Dh]: a K (or V) row for every cached
-        position of every layer that ATTENDS (an attention layer, an
-        attention+ssm layer), addressed by the slot's length:
+        position of every layer that ATTENDS OVER ALL OF THEM (an
+        attention layer, an attention+ssm layer; a window_attention
+        layer's rows are a ring of their own, `window_state_shape`),
+        addressed by the slot's length:
         ONE FLAT ROW a position, its Hc K/V heads' Dh features side by
         side, on every placement (below);
       * [conv layers, N, K - 1, C]: the last inputs of the filter of every
@@ -596,6 +640,27 @@ def slot_state_shapes(meta, n_slots, device):
         conv = (holders("conv"), N, blk["conv_kernel"] - 1,
                 int(meta["d_model"]))
     return kv, conv, ssm
+
+
+def window_state_shape(meta, n_slots):
+    """[window layers, N, W, Hc * Dh]: the K (or V) RING of an `n_slots`
+    session of the stack `meta` describes, the slot state of its
+    window_attention layers (W = `sliding_window`); None for a stack with
+    none.  A window layer attends over a position's last W keys and no
+    others, so W rows a slot are all it ever reads: position p's row lies
+    at p % W and is overwritten by position p + W's, where a full layer
+    reserves `max_seq_len` rows (`slot_state_shapes`).  Rows flat, as
+    there, so the ring at rest is the decode kernel's operand too: rows
+    carry their own rotation and a softmax does not care in which order
+    its keys lie, so the kernel runs over a ring UNCHANGED, under the
+    length min(positions, W) (`GenerativePredictor._attend_table`)."""
+    blk = block_of(meta)
+    layers = sum("ring" in _HOLDS[op] for op, _ in layer_kinds(meta, blk))
+    if not layers:
+        return None
+    return (layers, int(n_slots), blk["sliding_window"],
+            (blk["n_kv_heads"] or int(meta["n_heads"])) * _head_dim(meta,
+                                                                    blk))
 
 
 def decode_state_shapes(meta):
@@ -916,7 +981,8 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     weights to sum to 1; without it they are the softmax's own values.
     With `expert_bias` [E] (meta router=sigmoid_bias) the scores are
     sigmoids, the k experts are those of the largest score + bias, their
-    weights the UNBIASED scores, renormalised over (their sum + 1e-6).
+    weights the UNBIASED scores, renormalised over (their sum + 1e-6)
+    and multiplied by `scaling` where that is not 1.
     With `sigmoid` (meta router=sigmoid) the scores are sigmoids, the k
     experts those of the largest scores, renormalised over (their sum +
     1e-20) and multiplied by `scaling`.
@@ -957,6 +1023,8 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
                 w = jnp.take_along_axis(p, idx, axis=-1)
                 if norm_topk_prob:
                     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+                if scaling != 1.0:
+                    w = w * scaling
         if picks is not None:
             picks.append(idx.astype(jnp.int32))
         flat = idx.reshape(T * k)
@@ -1185,6 +1253,78 @@ def _causal_attention(q, k, v, scale):
         / jnp.maximum(jnp.sum(p, axis=-1), 1e-20).transpose(0, 2, 1)[
             ..., None]
     return o
+
+
+# Queries a prefill of a stack with window layers attends at a time
+# (`_blocked_attention`): its scores are [H, block, keys] and never [H, B,
+# B], which at 64 heads and a bucket of 4,096 is 4.3 GB in float32.
+PREFILL_QUERY_BLOCK = 512
+
+
+def _blocked_attention(q, k, v, scale, window=0):
+    """Prefill attention by BLOCKS of queries, a stack with window layers'
+    form of `_causal_attention`: q [1, B, H, Dh], k / v [1, B, Hc, Dh]
+    (grouped-query: query head a reads K/V head a // (H / Hc), no repeat
+    of K or V) -> [1, B, H, Dh], the oracle's finite-mask convention and
+    its softmax, a block of at most `PREFILL_QUERY_BLOCK` queries at a
+    time (`lax.map`: one block's scores live at a time).
+
+    `window` 0: a FULL layer, every block against all B keys under the
+    causal mask, scores [H, block, B].  `window` W >= 1: a WINDOW layer,
+    key j seen from query t iff t - W < j <= t; a block's scores are a
+    BAND, its own keys and the W before them, [H, block, block + W]."""
+    import jax
+    import jax.numpy as jnp
+    _, B, H, Dh = q.shape
+    Hc = k.shape[2]
+    n = -(-B // int(PREFILL_QUERY_BLOCK))
+    Q = -(-B // n)
+    pad = n * Q - B
+    qb = jnp.pad(q[0].astype(jnp.float32).reshape(B, Hc, H // Hc, Dh),
+                 ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(
+                     n, Q, Hc, H // Hc, Dh)
+    kf, vf = k[0].astype(jnp.float32), v[0].astype(jnp.float32)
+    if window:
+        # block i's keys are rows i * Q .. i * Q + Q + window - 1 of K with
+        # `window` rows in front of it (positions below 0: masked)
+        kf, vf = (jnp.pad(t, ((window, pad), (0, 0), (0, 0)))
+                  for t in (kf, vf))
+
+    def one(block):
+        i, qi = block                           # qi [Q, Hc, G, Dh]
+        qpos = (i * Q + jnp.arange(Q))[:, None]
+        if window:
+            kk, vv = (jax.lax.dynamic_slice_in_dim(t, i * Q, Q + window)
+                      for t in (kf, vf))
+            kpos = (i * Q - window + jnp.arange(Q + window))[None]
+            mask = (kpos <= qpos) & (kpos > qpos - window) & (kpos >= 0)
+        else:
+            kk, vv = kf, vf
+            mask = jnp.arange(B)[None] <= qpos
+        s = jnp.einsum("qhgd,khd->hgqk", qi, kk) * scale
+        s = jnp.where(mask[None, None], s, -1e30)
+        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+        o = jnp.einsum("hgqk,khd->qhgd", p, vv)
+        return o / jnp.maximum(jnp.sum(p, axis=-1), 1e-20).transpose(
+            2, 0, 1)[..., None]
+
+    out = jax.lax.map(one, (jnp.arange(n), qb))
+    return out.reshape(n * Q, H, Dh)[:B][None]
+
+
+def _ring_rows(rows, true_len, window):
+    """A prompt's rows of the window layers [L, 1, B, Hc, Dh] as the ring
+    holds them after it, [L, 1, window, Hc, Dh]: ring row r is the row of
+    the LAST position p < true_len with p % window == r (the prompt's last
+    min(true_len, window) positions, each at its own p % window, so the
+    steps that follow overwrite the oldest first), zeros where the prompt
+    has no such position.  Positions at or past `true_len` land nothing."""
+    import jax.numpy as jnp
+    r = jnp.arange(window)
+    have = r < true_len
+    p = jnp.where(have, r + window * ((true_len - 1 - r) // window), 0)
+    return jnp.where(have[None, None, :, None, None],
+                     jnp.take(rows, p, axis=2), 0.0)
 
 
 def _zero_pad_positions(ks, vs, true_len):
@@ -1510,10 +1650,11 @@ class GenerativePredictor:
         return layer_kinds(self.meta, self._block_meta)
 
     def _table_layer(self, i, kind="kv"):
-        """Where layer i's slot state of `kind` ("kv" | "conv" | "ssm")
-        lies in the table of that kind (the K/V tables hold the layers
-        that attend, the conv-state table those that convolve, the
-        scanned-state table the attention+ssm layers): its rank among the
+        """Where layer i's slot state of `kind` ("kv" | "conv" | "ssm" |
+        "ring") lies in the table of that kind (the K/V tables hold the
+        layers that attend over every position, the conv-state table those
+        that convolve, the scanned-state table the attention+ssm layers,
+        the K/V rings the window_attention layers): its rank among the
         layers that keep such state."""
         return sum(kind in _HOLDS[op] for op, _ in self.layer_kinds[:i])
 
@@ -1543,6 +1684,12 @@ class GenerativePredictor:
         operator holds a state-space mixer (attention+ssm)."""
         return sum("ssm" in _HOLDS[op] for op, _ in self.layer_kinds)
 
+    @functools.cached_property
+    def window_layers(self):
+        """Layers whose K/V rows are a ring (`window_state_shape`): those
+        whose operator is window_attention."""
+        return sum("ring" in _HOLDS[op] for op, _ in self.layer_kinds)
+
     @property
     def routed_layers(self):
         """Layers with a routed-expert FFN (under ffn=moe_swiglu, those
@@ -1563,7 +1710,9 @@ class GenerativePredictor:
         rewritten whole by every token, so neither can be undone by
         moving a slot's length back (a rollback, the speculative verify
         and its rejected suffix: that takes a snapshot), and neither the
-        mesh grammar nor the int8 cache's per-head scales know them."""
+        mesh grammar nor the int8 cache's per-head scales know them.
+        Nor for a window layer's RING: the row a new position landed on
+        is gone."""
         if self.conv_layers:
             raise NotImplementedError(
                 "%s has no rule for a recurrent layer's slot state, and "
@@ -1573,6 +1722,16 @@ class GenerativePredictor:
                 "moving a slot's length back undoes neither, and they are "
                 "neither sharded by heads nor scaled a head)"
                 % (what, list(self._block_meta["layer_types"])))
+        if self.window_layers:
+            raise NotImplementedError(
+                "%s has no rule for a ring of K/V rows, and this artifact's "
+                "meta has layer_types=%r (a window_attention layer keeps "
+                "its last sliding_window=%d rows and overwrites the oldest: "
+                "a row that was overwritten is gone, so a slot's length "
+                "cannot move back, and the ring is neither sharded by "
+                "heads nor scaled a head)"
+                % (what, list(self._block_meta["layer_types"]),
+                   self._block_meta["sliding_window"]))
 
     def _require_kv_stack(self, what):
         """Raise for what is written for K and V tables of per-head rows
@@ -1709,12 +1868,20 @@ class GenerativePredictor:
         with no attention+ssm layer."""
         return self._slot_state_shapes(n_slots)[2]
 
+    def window_table_shape(self, n_slots):
+        """[window layers, n_slots, sliding_window, Hc * Dh]: the K (or
+        V) ring of an `n_slots` session (`window_state_shape`); None for
+        a stack with no window_attention layer."""
+        return self._slot_state_shapes(n_slots)[3]
+
     def _slot_state_shapes(self, n_slots):
-        """`slot_state_shapes` of this predictor, kept a slot count."""
+        """`slot_state_shapes` of this predictor and, fourth, its
+        `window_state_shape`, kept a slot count."""
         memo = self.__dict__.setdefault("_slot_shapes", {})
         n = int(n_slots)
         if n not in memo:
-            memo[n] = slot_state_shapes(self.meta, n, self._device)
+            memo[n] = slot_state_shapes(self.meta, n, self._device) + (
+                window_state_shape(self.meta, n),)
         return memo[n]
 
     def kv_cache_bytes(self, n_slots):
@@ -1729,15 +1896,25 @@ class GenerativePredictor:
         check adds per replica; analysis/resources.py's `_decode_report`
         prices the same shape.  A stack with attention+ssm layers adds
         its scanned-state table (`ssm_state_bytes`): it bounds the slots
-        as the rows do.  The conv layers' state is `conv_state_bytes`,
-        apart."""
+        as the rows do; a stack with window_attention layers its K and V
+        rings (`window_kv_bytes`).  The conv layers' state is
+        `conv_state_bytes`, apart."""
         L, H, _, _ = self._dims()
         elem = 1 if self._kv_quant else 4
         scales = 2 * L * H * 4 if self._kv_quant else 0
         # an MLA stack's latent table is held once: it has no V
         return (self._kv_tables
                 * int(np.prod(self.table_shape(n_slots))) * elem + scales
-                + self.ssm_state_bytes(n_slots))
+                + self.ssm_state_bytes(n_slots)
+                + self.window_kv_bytes(n_slots))
+
+    def window_kv_bytes(self, n_slots):
+        """Closed-form footprint of the window_attention layers' K and V
+        rings for an `n_slots` session (fp32; 0 for a stack with none):
+        sliding_window rows a slot and layer, whatever the slot's
+        length."""
+        shape = self.window_table_shape(n_slots)
+        return 2 * 4 * int(np.prod(shape)) if shape else 0
 
     def ssm_state_bytes(self, n_slots):
         """Closed-form footprint of the scanned state for an `n_slots`
@@ -1818,7 +1995,8 @@ class GenerativePredictor:
     def _prefill_math(self, state, tokens, true_len, tp=_OFF_MESH):
         """The traced prefill phase: `_prefill_core` with its K and V as
         a slot table holds a position, [attention layers, 1, B, Hkv * Dh]
-        (one flat row: `slot_state_shapes`), after the int8
+        (one flat row: `slot_state_shapes`; the window layers' rings [window
+        layers, 1, W, Hkv * Dh] likewise), after the int8
         cache-write quantization epilogue (zeros quantize to exact
         int8 zeros, so the zero-slot contract is dtype-blind).  Under
         TP the K/V are this member's head shard, so the scale constant
@@ -1828,7 +2006,7 @@ class GenerativePredictor:
         out = self._prefill_core(state, tokens, true_len, tp=tp)
         if self.latent:
             return out
-        first, kc, vc, *conv = out
+        first, kc, vc, *rest = out
         if self._kv_quant:
             # [2, L, Hl, 1]
             sc = tp.head_scales(self._kv_scales, kc.shape[3])
@@ -1836,8 +2014,11 @@ class GenerativePredictor:
                 kc, sc[0][:, None, None]).astype(jnp.int8)
             vc = self._quantize_kv(
                 vc, sc[1][:, None, None]).astype(jnp.int8)
+        if self.window_layers:
+            # the rings' rows [window layers, 1, W, Hkv, Dh], last
+            rest[-2:] = [t.reshape(t.shape[:3] + (-1,)) for t in rest[-2:]]
         kc, vc = (t.reshape(t.shape[:3] + (-1,)) for t in (kc, vc))
-        return (first, kc, vc, *conv)
+        return (first, kc, vc, *rest)
 
     def _tp_seq_parallel(self, bucket, tp):
         """Does this prompt bucket prefill SEQUENCE-parallel under TP?
@@ -1901,7 +2082,9 @@ class GenerativePredictor:
         K-1, C]: each convolving layer's last K-1 inputs before the TRUE
         prompt end, not the bucket's, zeros where the prompt is shorter]
         [, scanned state [ssm layers, 1, Hs, P, N]: each attention+ssm
-        layer's state after position true_len - 1]).
+        layer's state after position true_len - 1][, the window layers' K
+        and V rings [window layers, 1, W, Hkv, Dh] as the prompt leaves
+        them: `_ring_rows`]).
         Under TP (inside shard_map) weights are local shards:
         the returned K/V carry this member's HEAD block [L, 1, B, H/m,
         Dh] (the cache's at-rest layout), attention is head-parallel
@@ -1931,7 +2114,9 @@ class GenerativePredictor:
         positions = jnp.arange(B)[None]                     # [1, B]
         live = positions[0] < true_len
         ks, vs, facts, conv, rows, scanned = [], [], [], [], [], []
+        ring_k, ring_v = [], []
         group = self._dims()[1] // self._kv_heads()
+        window = self._block_meta["sliding_window"]
 
         def latent(q_nope, q_rope, row, wkv_b):
             rows.append(row)
@@ -1940,10 +2125,22 @@ class GenerativePredictor:
         def attend(q, k, v):
             ks.append(k)
             vs.append(v)
+            if window:
+                # beside window layers a full layer's scores are taken by
+                # blocks of queries too: this stack's buckets are those a
+                # whole [H, B, B] does not fit
+                with jax.named_scope("full_attention"):
+                    return _blocked_attention(q, k, v, scale)
             if group > 1:
                 # query head a reads K/V head a // group
                 k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
             return _causal_attention(q, k, v, scale)
+
+        def attend_window(q, k, v):
+            ring_k.append(k)
+            ring_v.append(v)
+            with jax.named_scope("window_attention"):
+                return _blocked_attention(q, k, v, scale, window=window)
 
         def convolve(z, taps):
             # z [1, B, C], taps [C, K]: position t reads z[t - (K-1) .. t]
@@ -1963,9 +2160,11 @@ class GenerativePredictor:
             return y[None]
 
         for i in range(L):
-            x, f = self._block(state, i, x, positions, attend, live, tp=tp,
-                               convolve=convolve, latent=latent,
-                               ssm=("ssm_scan", scan))
+            x, f = self._block(
+                state, i, x, positions, attend_window
+                if self.layer_kinds[i][0] == "window_attention" else attend,
+                live, tp=tp, convolve=convolve, latent=latent,
+                ssm=("ssm_scan", scan))
             facts.append(f)
         if rows:
             # the latent table's [mla layers, 1, B, R], pads zeroed
@@ -1973,7 +2172,9 @@ class GenerativePredictor:
                                         jnp.stack(rows), 0.0),)
         tables = _zero_pad_positions(ks, vs, true_len)
         return x, facts, tables + tuple(
-            jnp.stack(t) for t in (conv, scanned) if t)
+            jnp.stack(t) for t in (conv, scanned) if t) + tuple(
+            _ring_rows(jnp.stack(t), true_len, window)
+            for t in (ring_k, ring_v) if t)
 
     def _norm(self, x, state, name):
         """The block's norm over the last axis with the weights
@@ -2002,9 +2203,12 @@ class GenerativePredictor:
 
         An ATTENTION layer: `attend(q, k, v)` gets q [..., Hl, Dh] and
         k / v [..., K/V heads, Dh] (normed and rotated where the block
-        says so — the cache holds rotated K) and returns the attention
-        output in q's shape; what it does with k and v (collect them,
-        write them to the slot table) is the phase's.  A CONV layer (a
+        says so — the cache holds rotated K; under meta rope_layers=window
+        a window_attention layer's alone are rotated) and returns the
+        attention output in q's shape; what it does with k and v (collect
+        them, write them to the slot table or, a WINDOW_ATTENTION layer's,
+        to its ring) and which keys a position sees is the phase's, which
+        hands a window layer its own `attend`.  A CONV layer (a
         gated short convolution): B, C, u = split3(h @ conv_in); y = C *
         `convolve(B * u, taps [D, K])` @ conv_out, where `convolve`
         returns, at each position, the taps' sum over that position's
@@ -2063,7 +2267,9 @@ class GenerativePredictor:
                            project("wk", Hkv, "kn_g"), project("wv", Hkv))
                 if blk["key_multiplier"] != 1.0:
                     k = k * blk["key_multiplier"]
-                if blk["position"] == "rope":
+                if blk["position"] == "rope" and (
+                        blk["rope_layers"] == "all"
+                        or op == "window_attention"):
                     q = _rope(q, positions, blk["rope_theta"])
                     k = _rope(k, positions, blk["rope_theta"])
                 att = tp.psum(_mm(
@@ -2320,10 +2526,16 @@ class GenerativePredictor:
         return tuple(_land(t, i, where, r.reshape(r.shape[:-2] + (-1,)))
                      for t, r in ((kc, k_new), (vc, v_new)))
 
-    def _attend_table(self, q, kc, vc, lengths, ahead, i, tp):
+    def _attend_table(self, q, kc, vc, lengths, ahead, i, tp, window=0):
         """The decode kernel over layer i of the carried K/V tables: q
         [N, Hl, Dh], slot n under its first `lengths[n] + ahead`
-        positions -> [N, Hl, Dh].  The kernel reads the layer of the
+        positions -> [N, Hl, Dh].  With `window` W the tables are a window
+        layer's RINGS [window layers, N, W, Hc * Dh] and slot n attends
+        under min(lengths[n] + ahead, W) rows: the kernel as it is, for
+        rows carry their own rotation and a softmax does not care in which
+        order its keys lie; a ring that has not wrapped holds its rows
+        from 0 on, one that has is all live, and a row of the slot's last
+        owner is never under the clamp.  The kernel reads the layer of the
         stacked table through its block index maps
         (`decode_attention(..., layer=i)`): no layer is sliced out.
         Where the table holds fewer heads than q has (grouped-query),
@@ -2334,13 +2546,17 @@ class GenerativePredictor:
         Hl, Dh = q.shape[1:]
         scale = 1.0 / np.sqrt(Dh)
         scales = self._kv_scales[:, i] if self._kv_quant else None
+        seen = lengths + ahead
+        if window:
+            import jax.numpy as jnp
+            seen = jnp.minimum(seen, window)
         if tp.size > 1:
             # each member slices its heads' scales out of the baked full
             # table
             return decode_attention_head_slice(
-                q, kc, vc, lengths + ahead, tp.index() * Hl, Hl,
+                q, kc, vc, seen, tp.index() * Hl, Hl,
                 scale=scale, kv_scales=scales, layer=i)
-        return decode_attention(q, kc, vc, lengths + ahead, scale=scale,
+        return decode_attention(q, kc, vc, seen, scale=scale,
                                 kv_scales=scales, layer=i)
 
     def _step_logits(self, state, *args, tp=_OFF_MESH):
@@ -2371,13 +2587,19 @@ class GenerativePredictor:
     def _step_core(self, state, tables, lengths, last_tokens, active,
                    tp=_OFF_MESH, picks=None):
         """One fixed-shape decode step over the slots' whole state.
-        `tables` = (kc, vc[, cs[, ss]]): the K/V tables [attention layers,
+        `tables` = (kc, vc[, cs][, ss][, kw, vw]) (`_table_names`): the
+        K/V tables [attention layers,
         N, S, Hc * Dh] (fp32, or int8 under the quantized cache), for a
         stack with layers that convolve the conv-state table [conv
-        layers, N, K-1, C] and, for one with attention+ssm layers, the
+        layers, N, K-1, C], for one with attention+ssm layers the
         scanned-state table [ssm layers, N, Hs, P, Ns], of which every
         step reads and rewrites every live slot's whole state
-        (`scan` below); or, for an MLA stack, (rows,): the latent table [mla
+        (`scan` below), and for one with window_attention layers their K
+        and V RINGS [window layers, N, W, Hc * Dh]: a slot's new row
+        lands at `lengths % W` (over the row of position `lengths - W`,
+        which no later position sees) and the slot attends under
+        min(lengths + 1, W) rows of the ring (`_attend_table`);
+        or, for an MLA stack, (rows,): the latent table [mla
         layers, N, S, Rp] alone; lengths [N] i32 (live cached positions),
         last_tokens
         [N] i32, active [N] bool -> (logits [N, vocab] f32, tables',
@@ -2398,26 +2620,33 @@ class GenerativePredictor:
         each call's results.
 
         Writes are gated by `active`: an inactive slot's K/V row goes to
-        position S, out of range, and is DROPPED, as is the row of a
-        slot already at `lengths == S`, and its conv state and its
-        scanned state keep what they held; so a freed (zeroed) slot stays
+        position S (a ring's to W), out of range, and is DROPPED, as is
+        the row of a slot already at `lengths == S`, and its conv state
+        and its scanned state keep what they held; so a freed (zeroed) slot stays
         zero and per-slot independence is exact.
 
         Under TP (inside shard_map) kc/vc are this member's resident
         HEAD shard and weights are local column/row shards — params and
         KV never materialize unsharded, per-step HBM traffic per member
         ~1/mesh_size."""
+        import contextlib
+        import jax
         import jax.numpy as jnp
         L = self._dims()[0]
-        # an MLA stack's one latent table stands where K stands
-        kc, vc = tables[:2] if not self.latent else (tables[0], None)
-        cs = tables[2] if len(tables) > 2 else None
-        ss = tables[3] if len(tables) > 3 else None
+        # (an MLA stack's one latent table stands where K stands)
+        held = dict(zip(self._table_names, tables))
+        kc, vc, cs, ss, kw, vw = (
+            held.get(n) for n in ("kc", "vc", "cs", "ss", "kw", "vw"))
         N, S = kc.shape[1], kc.shape[2]
         x = self._embed(state, last_tokens, lengths, tp)        # [N, D]
         # where a slot's new row lands; S (past the end) = nowhere
         where = (jnp.arange(N),
                  jnp.where(active, lengths, S).astype(jnp.int32))
+        W = self._block_meta["sliding_window"]
+        if W:
+            # ... and in a ring: at lengths % W, W (past its end) = nowhere
+            where_ring = (where[0], jnp.where(
+                active & (lengths < S), lengths % W, W).astype(jnp.int32))
         facts = []
         for i in range(L):
             at = self._table_layer(i)
@@ -2425,7 +2654,18 @@ class GenerativePredictor:
             def attend(q, k_new, v_new, at=at):
                 nonlocal kc, vc
                 kc, vc = self._write(kc, vc, at, where, k_new, v_new, tp)
-                return self._attend_table(q, kc, vc, lengths, 1, at, tp)
+                with (jax.named_scope("full_attention") if W
+                      else contextlib.nullcontext()):
+                    return self._attend_table(q, kc, vc, lengths, 1, at, tp)
+
+            def attend_window(q, k_new, v_new,
+                              at=self._table_layer(i, "ring")):
+                nonlocal kw, vw
+                kw, vw = self._write(kw, vw, at, where_ring, k_new, v_new,
+                                     tp)
+                with jax.named_scope("window_attention"):
+                    return self._attend_table(q, kw, vw, lengths, 1, at, tp,
+                                              window=W)
 
             def convolve(z, taps, at=self._table_layer(i, "conv")):
                 # z [N, C]: the slot's K-1 kept inputs, then this one
@@ -2456,12 +2696,15 @@ class GenerativePredictor:
                 return self._mla_absorbed(q_nope, q_rope, kc, lengths + 1,
                                           at, wkv_b)
 
-            x, f = self._block(state, i, x, lengths, attend, active, tp=tp,
-                               convolve=convolve, picks=picks, latent=latent,
-                               ssm=("ssm_update", scan))
+            x, f = self._block(
+                state, i, x, lengths, attend_window
+                if self.layer_kinds[i][0] == "window_attention" else attend,
+                active, tp=tp, convolve=convolve, picks=picks, latent=latent,
+                ssm=("ssm_update", scan))
             facts.append(f)
         return (self._head(state, x, tp),
-                tuple(t for t in (kc, vc, cs, ss) if t is not None), facts)
+                tuple(t for t in (kc, vc, cs, ss, kw, vw) if t is not None),
+                facts)
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
                      tp=_OFF_MESH):
@@ -2974,18 +3217,27 @@ class GenerativePredictor:
 
     @property
     def _n_tables(self):
-        """Leaves of a session's slot state: the K and V tables, and the
-        conv-state table of a stack with conv layers; the one latent table
-        of an MLA stack.  They lead the
-        arguments of every phase over the slots (`_table_specs`)."""
-        return self._kv_tables + bool(self.conv_layers) \
-            + bool(self.ssm_layers)
+        """Leaves of a session's slot state (`_table_names`).  They lead
+        the arguments of every phase over the slots (`_table_specs`)."""
+        return len(self._table_names)
+
+    @functools.cached_property
+    def _table_names(self):
+        """What the leaves of a session's slot state are, in the order
+        every phase takes and returns them: "kc" (the K table; an MLA
+        stack's latent table), "vc", "cs" (conv state), "ss" (scanned
+        state), "kw" and "vw" (the window layers' K and V rings), those
+        the stack has."""
+        return ("kc",) + (() if self.latent else ("vc",)) \
+            + (("cs",) if self.conv_layers else ()) \
+            + (("ss",) if self.ssm_layers else ()) \
+            + (("kw", "vw") if self.window_layers else ())
 
     def _table_specs(self, n_slots):
-        """(kc, vc[, conv state[, scanned state]], lengths [N] i32, last
-        tokens [N] i32, active [N] bool) (an MLA stack: its latent table where kc, vc
-        stand): what every phase over the slots takes, their state first
-        (`_n_tables` leaves)."""
+        """(kc, vc[, conv state][, scanned state][, K ring, V ring],
+        lengths [N] i32, last tokens [N] i32, active [N] bool) (an MLA
+        stack: its latent table where kc, vc stand): what every phase
+        over the slots takes, their state first (`_table_names`)."""
         import jax
         n = int(n_slots)
         cache = jax.ShapeDtypeStruct(self.table_shape(n),
@@ -2994,6 +3246,7 @@ class GenerativePredictor:
         return (cache,) * self._kv_tables + tuple(
             jax.ShapeDtypeStruct(shape, np.dtype(np.float32))
             for shape in (self.conv_state_shape(n), self.ssm_state_shape(n))
+            + (self.window_table_shape(n),) * 2
             if shape) + (
             jax.ShapeDtypeStruct((n,), i32),
             jax.ShapeDtypeStruct((n,), i32),
@@ -3093,10 +3346,14 @@ class GenerativePredictor:
 
 class DecodeSession:
     """One lane's slots: their state + occupancy bookkeeping.  A slot's
-    state is of three kinds (`slot_state_shapes`): its rows of the K/V
+    state is of three kinds (`slot_state_shapes`), its K/V rows of two:
+    its rows of the K/V
     tables (`_kc`, `_vc`: the attention layers', addressed by the slot's
     length; a stack of latent attention holds ONE table of latent rows,
-    `_kc`, and `_vc` is None); for a stack with layers that convolve, its
+    `_kc`, and `_vc` is None) and, for a stack with window_attention
+    layers, its RINGS of their last `sliding_window` rows (`_kw`, `_vw`:
+    `window_state_shape`; None otherwise); for a stack with layers that
+    convolve, its
     row of the conv-state table (`_cs`: a fixed size, rolled by every
     token; None otherwise); and, for a stack with attention+ssm layers,
     its row of the scanned-state table (`_ss`: a fixed size, a decayed sum
@@ -3167,6 +3424,17 @@ class DecodeSession:
             self._ss = table(scanned, jnp.float32)
             self._stack_attrs.update(
                 ssm_layers=scanned[0], ssm_state_bytes=int(self._ss.nbytes))
+        # the window_attention layers' K and V rings (refused on a mesh
+        # and under an int8 cache)
+        ring = predictor.window_table_shape(self.n_slots)
+        self._kw = self._vw = None
+        if ring:
+            self._kw, self._vw = (table(ring, jnp.float32),
+                                  table(ring, jnp.float32))
+            self._stack_attrs.update(
+                full_layers=shape[0], window_layers=ring[0],
+                full_kv_bytes=int(self._kc.nbytes + self._vc.nbytes),
+                window_kv_bytes=self.window_kv_bytes())
         if predictor.latent:
             self._stack_attrs = {"mla_layers": shape[0],
                                  "latent_cache_bytes": int(self._kc.nbytes)}
@@ -3180,6 +3448,9 @@ class DecodeSession:
         self._kv_block = attention_tuning.get_decode_config(
             shape[2], shape[-1] if predictor.latent
             else predictor._dims()[2], jnp.dtype(dtype).name)
+        # ... and over a ring, which is that many rows long
+        self._ring_block = ring and attention_tuning.get_decode_config(
+            ring[2], predictor._dims()[2], "float32")
         # set when a call failed after its table was donated to it
         # (`_mark_dead`): (phase, error); every later use raises
         self._dead = None
@@ -3223,9 +3494,12 @@ class DecodeSession:
         included) plus the int8 cache's fp32 scale table — what
         bench_serving's --kv_dtype A/B reports against the closed-form
         `GenerativePredictor.kv_cache_bytes`; with it the scanned-state
-        table of a stack with attention+ssm layers (`ssm_state_bytes`).
+        table of a stack with attention+ssm layers (`ssm_state_bytes`)
+        and the K and V rings of one with window_attention layers
+        (`window_kv_bytes`).
         The conv layers' state is `conv_state_bytes`, apart."""
-        n = sum(int(t.nbytes) for t in (self._kc, self._vc, self._ss)
+        n = sum(int(t.nbytes) for t in (self._kc, self._vc, self._ss,
+                                        self._kw, self._vw)
                 if t is not None)
         if self.predictor._kv_quant:
             n += int(np.asarray(self.predictor._kv_scales).nbytes)
@@ -3241,11 +3515,35 @@ class DecodeSession:
         attention+ssm layer)."""
         return 0 if self._ss is None else int(self._ss.nbytes)
 
+    def window_kv_bytes(self):
+        """MEASURED footprint of the window_attention layers' K and V
+        rings (0 for a stack with none): what they RESERVE, which is what
+        they hold once a slot is `sliding_window` positions long."""
+        return 0 if self._kw is None else int(self._kw.nbytes
+                                              + self._vw.nbytes)
+
+    def kv_live_bytes(self):
+        """{"full": bytes, "window": bytes}: the K and V rows the active
+        slots hold NOW, by kind of table: a full layer's table `lengths`
+        rows a slot, a window layer's ring min(lengths, sliding_window);
+        beside what the tables reserve (`cache_bytes`,
+        `window_kv_bytes`)."""
+        def rows(tables, held):
+            return int(sum(held.sum() * t.shape[0] * t.shape[3]
+                           * t.dtype.itemsize
+                           for t in tables if t is not None))
+        held = np.where(self.active, self.lengths, 0).astype(np.int64)
+        ring = 0 if self._kw is None else self._kw.shape[2]
+        return {"full": rows((self._kc, self._vc), held),
+                "window": rows((self._kw, self._vw),
+                               np.minimum(held, ring))}
+
     def _tables(self):
-        """The slots' state as the phases take it: (kc, vc[, cs[, ss]]);
-        an MLA stack's (latent rows,)."""
-        return tuple(t for t in (self._kc, self._vc, self._cs, self._ss)
-                     if t is not None)
+        """The slots' state as the phases take it
+        (`GenerativePredictor._table_names`): (kc, vc[, cs][, ss][, kw,
+        vw]); an MLA stack's (latent rows,)."""
+        return tuple(t for t in (self._kc, self._vc, self._cs, self._ss,
+                                 self._kw, self._vw) if t is not None)
 
     def _keep(self, tables):
         """Replace the slots' state by a phase's results."""
@@ -3257,6 +3555,8 @@ class DecodeSession:
             self._cs = next(tables)
         if self._ss is not None:
             self._ss = next(tables)
+        if self._kw is not None:
+            self._kw, self._vw = next(tables), next(tables)
 
     # -- phases ---------------------------------------------------------
 
@@ -3398,7 +3698,8 @@ class DecodeSession:
         as a slot that never ran does (`_step_math`), in every attention
         layer.  `kv_blocks_live` are the K/V blocks staged,
         `kv_blocks_total` what whole rows would be (trips x slots x
-        layers x S / block)."""
+        layers x S / block); a window layer's call counts among both, a
+        ring being its whole row."""
         if not self._kv_block:
             return {}
         from paddle_tpu.ops.pallas_kernels import kv_last_block
@@ -3407,8 +3708,17 @@ class DecodeSession:
         trip = np.arange(trips)[:, None]
         seen = np.where(trip < counts[None], self.lengths[None] + trip, 0) + 1
         live = kv_last_block(seen, self._kv_block, n_blocks) + 1
-        return {"kv_blocks_live": int(live.sum()) * layers,
-                "kv_blocks_total": trips * n_slots * layers * n_blocks}
+        out = {"kv_blocks_live": int(live.sum()) * layers,
+               "kv_blocks_total": trips * n_slots * layers * n_blocks}
+        if self._ring_block:
+            # the window layers' calls, under min(positions, W) of a ring
+            layers, _, W = self._kw.shape[:3]
+            n_blocks = W // self._ring_block
+            live = kv_last_block(np.minimum(seen, W), self._ring_block,
+                                 n_blocks) + 1
+            out["kv_blocks_live"] += int(live.sum()) * layers
+            out["kv_blocks_total"] += trips * n_slots * layers * n_blocks
+        return out
 
     def _fetch(self, phase, *outs, routed=False, trips_at=None, more=None):
         """`np.asarray` of each result: the wait for the device and the
@@ -3430,7 +3740,11 @@ class DecodeSession:
         table); one with attention+ssm layers `ssm_layers` and
         `ssm_state_bytes` (its scanned-state table) too, and `more` (its
         prefill's `bucket` and `ssm_chunks`, the chunks scanned) goes on
-        the call's launch and fetch spans.  Any other artifact takes the
+        the call's launch and fetch spans; one with window_attention
+        layers `full_layers`, `window_layers`, what the two kinds of K/V
+        table RESERVE (`full_kv_bytes`, `window_kv_bytes`) and what the
+        active slots HOLD of them as the call begins (`full_kv_live_bytes`,
+        `window_kv_live_bytes`: `kv_live_bytes`).  Any other artifact takes the
         path it always took."""
         n_routed = 2 * self._n_routed if routed else 0
         if not (n_routed or obs_tracing.enabled()):
@@ -3448,6 +3762,10 @@ class DecodeSession:
             attrs.update(self._stack_attrs)
         if obs_tracing.enabled():
             t1 = time.monotonic()
+            if routed and self._kw is not None:
+                held = self.kv_live_bytes()
+                attrs.update(full_kv_live_bytes=held["full"],
+                             window_kv_live_bytes=held["window"])
             trips = {}
             if trips_at is not None:
                 trips = {"trips": int(got[0][trips_at])}
@@ -3544,8 +3862,8 @@ class DecodeSession:
         return int(self.predictor.max_seq_len - self.lengths[slot])
 
     def free(self, slot):
-        """Release a slot: its state of EVERY kind (K/V lines, conv
-        state, scanned state) is ZEROED before it can be reused — a later occupant
+        """Release a slot: its state of EVERY kind (K/V lines, the window
+        layers' rings, conv state, scanned state) is ZEROED before it can be reused — a later occupant
         starts from exact zeros, never from a previous request's keys or
         inputs (the no-leakage contract the chaos decode-disconnect
         scenario pins)."""
@@ -3609,7 +3927,7 @@ class DecodeSession:
 
     def slot_is_zero(self, slot):
         """True when the slot's state of every kind (its K and V cache
-        lines, its conv state, its scanned state) is exact zeros — the test hook for the
+        lines and rings, its conv state, its scanned state) is exact zeros — the test hook for the
         zero-before-reuse contract."""
         self._alive()
         return not any(np.asarray(t[:, slot]).any()

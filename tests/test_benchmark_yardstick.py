@@ -85,11 +85,14 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       "pangu_decode_saturated",
                       # PR 42's cell: its K/V rows alone are counted, its
                       # scanned state is no stream of rows
-                      "falconh1_decode_saturated"]}
+                      "falconh1_decode_saturated",
+                      # PR 44's cell: its window layers' calls over their
+                      # rings are counted beside the full layer's
+                      "kexaone_decode_mixed_len"]}
     # appended, not inserted: only PR 35's five readers, PR 38's one,
-    # PR 39's nine and PR 42's six stand behind it
+    # PR 39's nine, PR 42's six and PR 44's five stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 22
+        manifest["per_layer"]) - 27
 
 
 # ---------------------------------------------------------------------------
@@ -133,15 +136,16 @@ def test_decode_early_launch_share_reader(case, spans, want):
 
 def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # PR 39's nine readers and PR 42's six stand behind it
-    assert manifest["per_layer"][-16] == {
+    # PR 39's nine readers, PR 42's six and PR 44's five stand behind it
+    assert manifest["per_layer"][-21] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
         "workloads": ["gpt2s_decode_saturated", "gpt2s_decode_deep",
                       "olmoe_decode_saturated", "lfm2_decode_saturated",
                       "pangu_decode_saturated",
-                      "falconh1_decode_saturated"]}
+                      "falconh1_decode_saturated",
+                      "kexaone_decode_mixed_len"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-16]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-21]["workloads"] == e2e["workloads"]

@@ -39,7 +39,9 @@ DECODE_CELLS = ["gpt2s_decode_saturated", "gpt2s_decode_deep",
                 "olmoe_decode_saturated", "lfm2_decode_saturated",
                 "pangu_decode_saturated",
                 # PR 42's cell joins every list that holds the five
-                "falconh1_decode_saturated"]
+                "falconh1_decode_saturated",
+                # ... and PR 44's every list that holds the six
+                "kexaone_decode_mixed_len"]
 
 
 def reader(name):
@@ -261,8 +263,9 @@ def test_spans_older_than_the_phase_spans_read_as_nothing(name):
 
 def test_the_nine_are_declared_last_for_the_five_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # (last but for the six readers PR 42 appended behind them)
-    last = manifest["per_layer"][-15:-6]
+    # (last but for the six readers PR 42 and the five PR 44 appended
+    # behind them)
+    last = manifest["per_layer"][-20:-11]
     assert [m["name"] for m in last] == list(NINE)
     for m in last:
         assert m["workloads"] == DECODE_CELLS, m["name"]

@@ -42,11 +42,11 @@ _HDR = struct.Struct("<Q")
 _MAX_FRAME = int(os.environ.get("PADDLE_TPU_MAX_RPC_FRAME", 1 << 28))
 
 
-def _send_msg(sock, obj):
+def _frame(obj):
     """Typed native wire frame (native/wire.cc) with a u64 length prefix —
     no pickle anywhere on the socket path (the reference's typed
-    VariableMessage serde, grpc_serde.cc, not arbitrary object streams).
-    Returns the bytes it put on the socket."""
+    VariableMessage serde, grpc_serde.cc, not arbitrary object streams):
+    the bytes of one message as they go on a socket."""
     payload = _wire_encode(obj)
     if len(payload) > _MAX_FRAME:
         # the peer's receive loop enforces the same cap; failing here
@@ -55,8 +55,14 @@ def _send_msg(sock, obj):
             "outgoing frame is %d bytes, above the %d-byte cap; export "
             "PADDLE_TPU_MAX_RPC_FRAME on both ends to raise it"
             % (len(payload), _MAX_FRAME))
-    sock.sendall(_HDR.pack(len(payload)) + payload)
-    return _HDR.size + len(payload)
+    return _HDR.pack(len(payload)) + payload
+
+
+def _send_msg(sock, obj):
+    """Send one `_frame`; returns the bytes it put on the socket."""
+    data = _frame(obj)
+    sock.sendall(data)
+    return len(data)
 
 
 def _recv_exact(sock, n):

@@ -56,6 +56,7 @@ dispatch device-cost stand-in for the replica-scaling lanes.
 
 import binascii
 import collections
+import contextlib
 import os
 import queue as queue_mod
 import threading
@@ -870,12 +871,39 @@ class DynamicBatcher:
 # ---------------------------------------------------------------------------
 
 
+_held = threading.local()
+
+
+@contextlib.contextmanager
+def _one_item():
+    """What the calling thread puts, inside the block, on streams that a
+    writer has taken (`DecodeStream.attach`) reaches that writer as ONE
+    item at the block's end: one wake-up of one thread for a delivery's
+    chunks, not one a chunk.  A block inside a block joins the outer
+    one.  Streams nobody took are put on their own queues at once."""
+    if getattr(_held, "by_sink", None) is not None:
+        yield
+        return
+    _held.by_sink = by_sink = {}
+    try:
+        yield
+    finally:
+        _held.by_sink = None
+        for sink, events in by_sink.items():
+            sink.post(events)
+
+
 class DecodeStream:
     """The caller's handle on one streaming generation: an event queue
     the owning lane feeds (token chunks, then exactly one terminal
     event), iterable as token-chunk lists.  ``result()`` collects the
     whole stream — the Future-shaped surface the server's one-shot
-    `infer` path uses unchanged on decode models."""
+    `infer` path uses unchanged on decode models.
+
+    A stream that a writer has taken (`attach`: the server's
+    `infer_stream` verb) has no consumer of its own: its events go to
+    the writer's one queue, and nobody wakes for one of them but the
+    writer (SERVING.md "Streaming wire protocol")."""
 
     def __init__(self, trace_id, prompt_len, max_new_tokens):
         self.trace_id = trace_id
@@ -891,28 +919,47 @@ class DecodeStream:
         self._done = threading.Event()
         self._cancel = threading.Event()
         self._error = None
+        # (sink, tag) once a writer has taken the stream; the lock
+        # orders `attach` against the lane's puts
+        self._sink = None
+        self._lock = threading.Lock()
 
     # -- lane side ------------------------------------------------------
 
+    def _put(self, kind, payload, stamps=None):
+        with self._lock:
+            taken = self._sink
+            if taken is None:
+                if kind == "tokens":
+                    self._stamps.append(stamps)
+                self._q.put((kind, payload))
+                return
+        sink, tag = taken
+        held = getattr(_held, "by_sink", None)
+        if held is None:
+            sink.post([(tag, kind, payload, stamps)])
+        else:
+            held.setdefault(sink, []).append((tag, kind, payload, stamps))
+
     def _put_tokens(self, toks, stamps=None):
         """`stamps`, with tracing on: (end of the dispatch that made the
-        chunk, now), on time.monotonic(); the stream's handler takes
-        them beside the chunk (`take_stamps`)."""
-        self._tokens.extend(int(t) for t in toks)
-        self._stamps.append(stamps)
-        self._q.put(("tokens", [int(t) for t in toks]))
+        chunk, now), on time.monotonic(); whoever sends the chunk takes
+        them beside it (`take_stamps`, or the writer's event)."""
+        toks = [int(t) for t in toks]
+        self._tokens.extend(toks)
+        self._put("tokens", toks, stamps)
 
     def _finish(self, reason, obs_info=None):
         self.finish_reason = reason
         self.obs_info = obs_info
         self._done.set()
-        self._q.put(("done", reason))
+        self._put("done", reason)
 
     def _fail(self, exc):
         self._error = exc
         self.finish_reason = "error"
         self._done.set()
-        self._q.put(("error", exc))
+        self._put("error", exc)
 
     # -- caller side ----------------------------------------------------
 
@@ -933,6 +980,25 @@ class DecodeStream:
         """Tokens generated so far (grows while streaming)."""
         return list(self._tokens)
 
+    def attach(self, sink, tag):
+        """Hand this stream's events to `sink` from now on:
+        `sink.post([(tag, kind, payload, stamps), ...])` takes a list of
+        events as one item, `stamps` the lane's (t_made, t_put) of a
+        "tokens" event or None.  What the lane put before goes first,
+        in order, as one item; the stream's own queue stays empty from
+        here on."""
+        with self._lock:
+            events = []
+            while not self._q.empty():
+                kind, payload = self._q.get_nowait()
+                events.append((tag, kind, payload,
+                               self._stamps.popleft()
+                               if kind == "tokens" and self._stamps
+                               else None))
+            self._sink = (sink, tag)
+            if events:
+                sink.post(events)
+
     def events(self, timeout=None):
         """Yield ("tokens", [ints]) chunks then one terminal ("done",
         reason) / ("error", exc) event.  `timeout` bounds the wait for
@@ -945,10 +1011,11 @@ class DecodeStream:
 
     def take_stamps(self):
         """The lane's stamps of the "tokens" event `events()` handed out
-        last, for the one consumer that asks after EVERY such event (the
-        server's stream handler): (t_made, t_put), or None where the
-        lane took none."""
-        return self._stamps.popleft() if self._stamps else None
+        last, for a consumer that asks after EVERY such event:
+        (t_made, t_put), or None where the lane took none.  (A stream
+        the server's writer has taken hands them over in its events.)"""
+        with self._lock:
+            return self._stamps.popleft() if self._stamps else None
 
     def __iter__(self):
         """Token-chunk iterator; raises the stream's typed error at the
@@ -1406,37 +1473,41 @@ class DecodeBatcher:
         now = time.monotonic()
         traced = obs_tracing.enabled()
         rnd, order, enders = at or (lane.steps, 0, 1)
-        if req.buf:
-            req.stream._put_tokens(
-                req.buf,
-                (now if made is None else made, now) if traced else None)
-            req.buf = []
-        if slot is not None:
-            t_free = time.monotonic() if traced else None
-            lane.session.free(slot)
-            lane.assigned.pop(slot, None)
+        # the flush and the terminal event: one item for the stream's
+        # writer, handed over after the release (one with those of the
+        # delivery's other enders, inside `_deliver`)
+        with _one_item():
+            if req.buf:
+                req.stream._put_tokens(
+                    req.buf, (now if made is None else made, now)
+                    if traced else None)
+                req.buf = []
+            if slot is not None:
+                t_free = time.monotonic() if traced else None
+                lane.session.free(slot)
+                lane.assigned.pop(slot, None)
+                if traced:
+                    obs_tracing.stamp(
+                        "serving/slot_free", t_free, time.monotonic(),
+                        kind="serving", trace_id=req.trace_id,
+                        parent="serving/finish", slot=slot, round=rnd)
             if traced:
-                obs_tracing.stamp(
-                    "serving/slot_free", t_free, time.monotonic(),
-                    kind="serving", trace_id=req.trace_id,
-                    parent="serving/finish", slot=slot, round=rnd)
-        if traced:
-            self._emit_request_spans(req, lane, now)
-        info = self._obs_info(req, lane, now)
-        info["finish_reason"] = reason
-        if exc is not None:
-            if self.metrics is not None:
-                self.metrics.errors.add()
-                if isinstance(exc, DeadlineExceeded):
-                    self.metrics.deadline_expired.add()
-            req.stream.obs_info = info
-            req.stream._fail(exc)
-        else:
-            if self.metrics is not None and reason != "cancelled":
-                self.metrics.note_completion(
-                    latency_ms=info["server_ms"],
-                    queue_wait_ms=info["queue_wait_ms"])
-            req.stream._finish(reason, obs_info=info)
+                self._emit_request_spans(req, lane, now)
+            info = self._obs_info(req, lane, now)
+            info["finish_reason"] = reason
+            if exc is not None:
+                if self.metrics is not None:
+                    self.metrics.errors.add()
+                    if isinstance(exc, DeadlineExceeded):
+                        self.metrics.deadline_expired.add()
+                req.stream.obs_info = info
+                req.stream._fail(exc)
+            else:
+                if self.metrics is not None and reason != "cancelled":
+                    self.metrics.note_completion(
+                        latency_ms=info["server_ms"],
+                        queue_wait_ms=info["queue_wait_ms"])
+                req.stream._finish(reason, obs_info=info)
         if traced:
             obs_tracing.stamp(
                 "serving/finish", now, time.monotonic(), kind="serving",
@@ -1591,9 +1662,10 @@ class DecodeBatcher:
             error=str(exc))
         if self.metrics is not None and (victims or pend):
             self.metrics.errors.add(len(victims) + len(pend))
-        for req in victims + pend:
-            req.buf = []
-            req.stream._fail(exc)
+        with _one_item():
+            for req in victims + pend:
+                req.buf = []
+                req.stream._fail(exc)
 
     def _lane_loop(self, lane):
         while True:
@@ -1626,7 +1698,7 @@ class DecodeBatcher:
         arguments are known.  The delivery is then HELD (`lane.held`)
         and the next pass launches first, delivers while the device
         runs, then fetches (SERVING.md "Fused multi-step decode").  The
-        streams' handler threads, which a delivery wakes, then run
+        server's writer thread, which a delivery wakes, then runs
         beside the device and not in front of the launch.  In every
         other case (a finisher, a cancellation, an expiry, a free slot,
         a speculative lane, the lane's first dispatch) the pass
@@ -1814,8 +1886,10 @@ class DecodeBatcher:
         dispatch's end, for a delivery made at once) or now (one that
         was held: it then lies inside the NEXT `serving/decode_step`).
         With tracing on every chunk carries the dispatch's end and the
-        moment of its put to its stream's handler (`_put_tokens`), and
-        every ender's `serving/finish` says its place among them."""
+        moment of its put (`_put_tokens`), and every ender's
+        `serving/finish` says its place among them.  Streams that the
+        server's writer has taken get all of it as two items (`_one_item`):
+        a delivery wakes that one thread once or twice."""
         if lane.held is None:
             return
         (rnd, now, trips, emitted, puts, ended, accept), lane.held = \
@@ -1823,16 +1897,21 @@ class DecodeBatcher:
         traced = obs_tracing.enabled()
         if since is None and traced:
             since = time.monotonic()
-        for req in puts:
-            req.stream._put_tokens(
-                req.buf, (now, time.monotonic()) if traced else None)
-            req.buf = []
-        for order, (slot, req, reason) in enumerate(ended):
-            at = (rnd, order, len(ended))
-            if reason == "deadline":
-                self._expire(lane, slot, req, now, made=now, at=at)
-            else:
-                self._finish(lane, slot, req, reason, made=now, at=at)
+        # two items at most for the streams' writer, whatever the number
+        # of streams: the chunks due, then what the enders leave behind
+        with _one_item():
+            for req in puts:
+                req.stream._put_tokens(
+                    req.buf, (now, time.monotonic()) if traced else None)
+                req.buf = []
+        with _one_item():
+            for order, (slot, req, reason) in enumerate(ended):
+                at = (rnd, order, len(ended))
+                if reason == "deadline":
+                    self._expire(lane, slot, req, now, made=now, at=at)
+                else:
+                    self._finish(lane, slot, req, reason, made=now,
+                                 at=at)
         if traced:
             obs_tracing.stamp("serving/emit", since, time.monotonic(),
                               kind="serving", parent="serving/lane_iter",

@@ -42,6 +42,8 @@ request, answer it, then exit — chaos-tested (tools/chaos.py FlakyProxy
 """
 
 import os
+import queue
+import selectors
 import socket
 import socketserver
 import threading
@@ -49,11 +51,12 @@ import time
 
 import numpy as np
 
-from ..distributed.rpc import _recv_msg, _send_msg
+from ..distributed.rpc import _frame, _recv_msg, _send_msg
 from ..flags import FLAGS
 from ..native.wire import WireError
 from ..obs import tracing as obs_tracing
-from .batcher import BatcherClosed, DeadlineExceeded, ServerOverloaded
+from .batcher import (BatcherClosed, DeadlineExceeded, ServerOverloaded,
+                      _guarded)
 from .metrics import ServingMetrics
 from .model_registry import ModelRegistry
 
@@ -167,6 +170,9 @@ class InferenceServer:
         self._draining = False
         self._server = None
         self._thread = None
+        # the one thread that sends every stream's frames (started in
+        # `start`, whatever the number of models, replicas and lanes)
+        self._writer = None
 
     # ------------------------------------------------------------------
 
@@ -226,6 +232,7 @@ class InferenceServer:
 
         self._server = Server(self._addr, Handler)
         self._addr = self._server.server_address
+        self._writer = _StreamWriter().start()
         if self.slo is not None:
             self.slo.name = self.endpoint
             self.slo.start()
@@ -288,6 +295,10 @@ class InferenceServer:
             self.fleet.stop()
             self._obs_registry.detach_fleet(self.fleet)
         self.registry.close_all(drain=drain, timeout=timeout)
+        if self._writer is not None:
+            # every lane has ended, so every stream's terminal event is
+            # queued: the writer sends them all, then joins
+            self._writer.stop()
         self._stopped = True
         if self.slo is not None:
             self.slo.stop()
@@ -599,108 +610,62 @@ class InferenceServer:
             reply["trace_id"] = trace_id
             _send_msg(sock, reply)
             return
-        seq = 0
-        # with tracing on, what each frame waited for is folded into one
-        # `serving/stream_out` span a request (OBSERVABILITY.md); off,
-        # the loop reads no clock
-        out = _StreamOut() if obs_tracing.enabled() else None
-        try:
-            for kind, payload in stream.events():
-                if out is not None:
-                    out.woke(time.monotonic())
-                if kind == "tokens":
-                    sent = _send_msg(sock, {
-                        "chunk": True, "seq": seq,
-                        "tokens": [int(t) for t in payload],
-                        "trace_id": trace_id})
-                    seq += 1
-                    if out is not None:
-                        out.frame(stream.take_stamps(), time.monotonic(),
-                                  len(payload), sent)
-                elif kind == "error":
-                    reply = _error_reply(payload)
-                    reply["done"] = True
-                    reply["trace_id"] = trace_id
-                    reply["new_tokens"] = len(stream.tokens)
-                    _send_msg(sock, reply)
-                else:  # done
-                    final = {"ok": True, "done": True,
-                             "trace_id": trace_id,
-                             "finish_reason": str(payload),
-                             "new_tokens": len(stream.tokens)}
-                    if msg.get("debug"):
-                        final["debug"] = dict(stream.obs_info
-                                              or {"trace_id": trace_id})
-                    _send_msg(sock, final)
-            if out is not None:
-                # the terminal frame is out
-                out.t_sent = time.monotonic()
-        except (ConnectionError, EOFError, OSError, WireError):
-            # client went away mid-stream: evict the request so its
-            # slot is reclaimed for waiting traffic (chaos scenario
-            # decode-disconnect pins the bound: two dispatches)
-            stream.cancel()
-            raise
-        finally:
-            if out is not None:
-                out.land(trace_id, stream)
+        # the server's one writer thread sends the stream's frames; this
+        # thread sleeps until the terminal frame is out or the stream
+        # broke (a dead or stuck peer: the writer has cancelled the
+        # stream, and raising here drops the connection, as ever)
+        out = self._writer.attach(sock, stream, trace_id,
+                                  bool(msg.get("debug")))
+        out.sent.wait()
+        if out.error is not None:
+            raise out.error
 
 
 class _StreamOut:
     """What one request's frames waited for on their way out, folded by
-    the handler thread that sends them (one writer, no lock) and landed
+    the writer thread that sends them (one thread, no lock) and landed
     as ONE `serving/stream_out` span at the request's end: a span a
     frame would overflow the ring.  A frame's way has three parts, on
     time.monotonic(): the dispatch's end to the lane's put (`lane_ms`),
-    the put to this thread's having the chunk (`wake_ms`: the queue and
-    the wait for the interpreter), and the encode and `sendall`
-    (`send_ms`)."""
+    the put to the writer's turning to the chunk (`wake_ms`: the
+    writer's wake-up and the frames of the pass ahead of it), and the
+    encode and the socket write (`send_ms`)."""
 
-    __slots__ = ("t_first", "t_woke", "t_sent", "frames", "tokens",
-                 "bytes", "lane_ms", "wake_ms", "wake_max", "send_ms",
-                 "send_max")
+    __slots__ = ("t_first", "frames", "tokens", "bytes", "lane_ms",
+                 "wake_ms", "wake_max", "send_ms", "send_max")
 
     def __init__(self):
-        self.t_first = self.t_woke = self.t_sent = None
+        self.t_first = None
         self.frames = self.tokens = self.bytes = 0
         self.lane_ms = self.wake_ms = self.wake_max = 0.0
         self.send_ms = self.send_max = 0.0
 
-    def woke(self, now):
-        self.t_woke = now
-        if self.t_first is None:
-            self.t_first = now
-
-    def frame(self, stamps, t_sent, tokens, sent):
+    def frame(self, stamps, t_have, t_sent, tokens, sent):
         """One chunk frame is out: `stamps` the lane's (t_made, t_put)
         of the chunk (None where it took none: the frame then counts
-        with no lane or wake time), `sent` its bytes on the socket."""
-        t_woke = self.t_woke
+        with no lane or wake time), `t_have` when the writer turned to
+        it, `sent` its bytes on the socket."""
         if stamps is not None:
             t_made, t_put = stamps
             if not self.frames:
                 self.t_first = t_put
-            wake = (t_woke - t_put) * 1e3
+            wake = (t_have - t_put) * 1e3
             self.lane_ms += (t_put - t_made) * 1e3
             self.wake_ms += wake
             self.wake_max = max(self.wake_max, wake)
-        send = (t_sent - t_woke) * 1e3
+        send = (t_sent - t_have) * 1e3
         self.send_ms += send
         self.send_max = max(self.send_max, send)
-        self.t_sent = t_sent
         self.frames += 1
         self.tokens += tokens
         self.bytes += sent
 
-    def land(self, trace_id, stream):
-        """The span: from the request's first put (the handler's first
-        wake-up, where it sent no chunk) to its last send's return; a
-        send that failed ends it where it failed."""
+    def land(self, trace_id, stream, end):
+        """The span: from the request's first put (the writer's first
+        look at the stream, where it sent no chunk) to `end`, its last
+        send's return; a send that failed ends it where it failed."""
         if self.t_first is None:
             return
-        end = self.t_sent
-        if end is None or end < self.t_woke:
-            end = time.monotonic()
         attrs = {}
         replica = (stream.obs_info or {}).get("replica")
         if replica is not None:
@@ -712,6 +677,305 @@ class _StreamOut:
             lane_ms_sum=self.lane_ms, wake_ms_sum=self.wake_ms,
             wake_ms_max=self.wake_max, send_ms_sum=self.send_ms,
             send_ms_max=self.send_max, **attrs)
+
+
+def _send_some(sock, data):
+    """As many of `data`'s bytes as `sock` takes without waiting: 0
+    where its buffer is full.  The one place a stream's frame goes on
+    its socket."""
+    try:
+        return sock.send(data, socket.MSG_DONTWAIT)
+    except (BlockingIOError, InterruptedError):
+        return 0
+
+
+class _Taken:
+    """One stream the writer has taken, as the writer sees it (the tag
+    of its events).  The writer thread alone touches it, but for `sent`
+    and `error`: the stream's handler sleeps on the one and reads the
+    other after it."""
+
+    __slots__ = ("sock", "stream", "trace_id", "debug", "rec", "seq",
+                 "unsent", "owed", "sent", "error")
+
+    def __init__(self, sock, stream, trace_id, debug, rec):
+        self.sock, self.stream = sock, stream
+        self.trace_id, self.debug = trace_id, debug
+        self.rec = rec              # _StreamOut, None with tracing off
+        self.seq = 0
+        # a peer that does not read: the bytes its socket has not taken
+        # and the frames they belong to, oldest first
+        self.unsent = bytearray()
+        self.owed = []
+        # set once the terminal frame is out or the stream broke: the
+        # writer is done with the stream and drops its later events
+        self.sent = threading.Event()
+        self.error = None
+
+
+class _StreamWriter:
+    """The ONE thread of a server that encodes and sends the frames of
+    every `infer_stream` request (SERVING.md "Streaming wire protocol").
+    The lanes put their streams' events on its one queue, a delivery's
+    as one or two items (`batcher._one_item`), and a pass of the writer
+    takes everything queued and sends it in order: a dispatch with 96
+    live streams wakes one thread, where a handler thread a stream made
+    96 of them stand in line for the interpreter in front of the lane's
+    next device call (PERF.md section 6, PR 45).
+
+    It never waits for one socket.  A send takes what the socket takes
+    at once (`_send_some`); what is left is kept with its stream, sent
+    when the socket takes bytes again, and a stream with more than
+    `MAX_UNSENT_FRAMES` frames held so is cancelled as a dead client's.
+    A send that fails cancels its stream and wakes its handler, which
+    drops the connection."""
+
+    MAX_UNSENT_FRAMES = 64
+    # shutdown: how long held bytes may still go out (selects of 0.1 s)
+    STOP_GRACE_SELECTS = 50
+
+    def __init__(self):
+        self._items = queue.SimpleQueue()
+        self._lock = threading.Lock()   # `_taken` and `_closed`
+        self._taken = set()
+        self._closed = False
+        self._stopping = False
+        self._thread = None
+        # streams with unsent bytes.  While there are none the writer
+        # sleeps on its queue; with some it sleeps in the selector, on
+        # their sockets and on the wake-up pair, which `post` writes to
+        # only then (`_selecting`): a hand-over is a queue put and no
+        # system call, so the lane does not let go of the interpreter
+        # between handing a delivery over and its own bookkeeping
+        self._held = set()
+        self._selecting = False
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._sel = selectors.DefaultSelector()
+        self._sel.register(self._wake_r, selectors.EVENT_READ)
+
+    def start(self):
+        self._thread = threading.Thread(
+            target=_guarded(self._run, lambda: "", "stream_writer"),
+            name="serving-stream-writer", daemon=True)
+        self._thread.start()
+        return self
+
+    def stop(self, timeout=10.0):
+        """Send everything queued, then end; what a peer has not taken
+        within the grace is dropped with its connection."""
+        self._stopping = True
+        self.post(())
+        if self._thread is not None:
+            self._thread.join(timeout=timeout)
+
+    # -- the lanes' and the handlers' side --------------------------------
+
+    def post(self, events):
+        """One item: a list of (taken, kind, payload, stamps), and one
+        wake-up."""
+        self._items.put(events)
+        if self._selecting:
+            try:
+                self._wake_w.send(b"\0")
+            except OSError:
+                pass    # wake-ups enough are waiting, or the writer is gone
+
+    def attach(self, sock, stream, trace_id, debug):
+        """Take `stream`: its frames go out on `sock` from here on.  The
+        caller sleeps on the result's `sent`."""
+        out = _Taken(sock, stream, trace_id, debug,
+                     _StreamOut() if obs_tracing.enabled() else None)
+        with self._lock:
+            live = not self._closed
+            if live:
+                self._taken.add(out)
+        if not live:
+            out.error = ConnectionError("the server's stream writer "
+                                        "has stopped")
+            stream.cancel()
+            out.sent.set()
+        else:
+            stream.attach(self, out)
+        return out
+
+    # -- the writer thread -------------------------------------------------
+
+    def _wait(self, timeout=None):
+        """Sleep until an item is queued or, with bytes held, a held-up
+        socket takes them again (`timeout` bounds that sleep); returns
+        (everything queued, the streams of such sockets)."""
+        items, ready = [], []
+        if not self._held:
+            items.append(self._items.get())
+        else:
+            # the flag first, the look at the queue second: a `post`
+            # that missed the one is seen by the other
+            self._selecting = True
+            try:
+                if not self._items.empty():
+                    timeout = 0
+                for key, _ in self._sel.select(timeout):
+                    if key.data is not None:
+                        ready.append(key.data)
+                    else:
+                        try:
+                            self._wake_r.recv(4096)
+                        except BlockingIOError:
+                            pass
+            finally:
+                self._selecting = False
+        while not self._items.empty():
+            items.append(self._items.get())
+        return items, ready
+
+    def _run(self):
+        try:
+            while not (self._stopping and self._items.empty()):
+                self._write_pass(*self._wait())
+            for _ in range(self.STOP_GRACE_SELECTS):
+                if not self._held:
+                    break
+                self._write_pass(*self._wait(0.1))
+        finally:
+            with self._lock:
+                self._closed = True
+                left, self._taken = self._taken, set()
+            for out in left:
+                self._end(out, ConnectionError(
+                    "the server's stream writer has stopped"))
+            self._sel.close()
+            self._wake_r.close()
+            self._wake_w.close()
+
+    def _write_pass(self, items, ready):
+        """`items`, in order, after what held-up sockets take again
+        (`ready`).  With tracing on it is one `serving/write_pass` span;
+        off, it reads no clock."""
+        traced = obs_tracing.enabled()
+        t0 = t_have = time.monotonic() if traced else None
+        frames = enders = nbytes = 0
+        streams = set()
+        for out in ready:
+            if not out.sent.is_set():
+                self._retry(out, traced)
+        for events in items:
+            for out, kind, payload, stamps in events:
+                if out.sent.is_set():
+                    continue
+                streams.add(out)
+                rec = out.rec if traced else None
+                if rec is not None and rec.t_first is None:
+                    rec.t_first = t_have
+                try:
+                    data = _frame(self._message(out, kind, payload))
+                    owed = (kind, stamps, t_have,
+                            len(payload) if kind == "tokens" else 0,
+                            len(data))
+                    took = 0 if out.unsent else _send_some(out.sock, data)
+                    if took == len(data):
+                        if traced:
+                            t_have = time.monotonic()
+                        self._out(out, owed, t_have, traced)
+                    else:
+                        self._hold(out, data[took:], owed)
+                except Exception as e:
+                    # a dead peer, an oversize frame, or anything else
+                    # this stream's frame raises costs this stream
+                    # alone; its handler raises it again
+                    self._end(out, e)
+                    continue
+                nbytes += len(data)
+                if kind == "tokens":
+                    frames += 1
+                else:
+                    enders += 1
+        if traced and (streams or ready):
+            obs_tracing.stamp(
+                "serving/write_pass", t0, time.monotonic(),
+                kind="serving", frames=frames, streams=len(streams),
+                enders=enders, bytes=nbytes, backlogged=len(self._held))
+
+    @staticmethod
+    def _message(out, kind, payload):
+        """The frame of one event, as `infer_stream` has always put it
+        on the wire."""
+        if kind == "tokens":
+            msg = {"chunk": True, "seq": out.seq, "tokens": payload,
+                   "trace_id": out.trace_id}
+            out.seq += 1
+        elif kind == "error":
+            msg = _error_reply(payload)
+            msg["done"] = True
+            msg["trace_id"] = out.trace_id
+            msg["new_tokens"] = len(out.stream.tokens)
+        else:  # done
+            msg = {"ok": True, "done": True, "trace_id": out.trace_id,
+                   "finish_reason": str(payload),
+                   "new_tokens": len(out.stream.tokens)}
+            if out.debug:
+                msg["debug"] = dict(out.stream.obs_info
+                                    or {"trace_id": out.trace_id})
+        return msg
+
+    def _out(self, out, owed, t_sent, traced):
+        """One frame is out whole: a chunk counts in its stream's
+        record, the terminal frame ends the stream."""
+        kind, stamps, t_have, tokens, size = owed
+        if kind != "tokens":
+            self._end(out)
+        elif traced and out.rec is not None:
+            out.rec.frame(stamps, t_have, t_sent, tokens, size)
+
+    def _hold(self, out, rest, owed):
+        """`out`'s socket is full: keep the bytes and the frame they end
+        until it takes them (`_retry`), the stream's later frames behind
+        them; past the bound the peer counts as dead."""
+        if not out.unsent:
+            self._sel.register(out.sock, selectors.EVENT_WRITE, out)
+            self._held.add(out)
+        out.unsent += rest
+        out.owed.append(owed)
+        if len(out.owed) > self.MAX_UNSENT_FRAMES:
+            raise ConnectionError(
+                "peer stopped reading: %d frames unsent"
+                % len(out.owed))
+
+    def _retry(self, out, traced):
+        try:
+            took = _send_some(out.sock, out.unsent)
+        except OSError as e:
+            self._end(out, e)
+            return
+        del out.unsent[:took]
+        if out.unsent:
+            return
+        self._release(out)
+        t_sent = time.monotonic() if traced else None
+        owed, out.owed = out.owed, []
+        for one in owed:
+            self._out(out, one, t_sent, traced)
+
+    def _release(self, out):
+        if out in self._held:
+            self._held.discard(out)
+            self._sel.unregister(out.sock)
+
+    def _end(self, out, error=None):
+        """The terminal frame is out, or (`error`) the stream broke: the
+        stream is cancelled then, so its slot frees at the lane's next
+        dispatch boundary.  Either way the handler wakes."""
+        self._release(out)
+        out.unsent, out.owed = bytearray(), []
+        if error is not None:
+            out.error = error
+            out.stream.cancel()
+        with self._lock:
+            self._taken.discard(out)
+        if out.rec is not None:
+            out.rec.land(out.trace_id, out.stream, time.monotonic())
+        out.sent.set()
 
 
 class _FederationLink:

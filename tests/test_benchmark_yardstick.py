@@ -90,9 +90,10 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       # rings are counted beside the full layer's
                       "kexaone_decode_mixed_len"]}
     # appended, not inserted: only PR 35's five readers, PR 38's one,
-    # PR 39's nine, PR 42's six and PR 44's five stand behind it
+    # PR 39's nine, PR 42's six, PR 44's five and PR 45's one stand behind
+    # it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 27
+        manifest["per_layer"]) - 28
 
 
 # ---------------------------------------------------------------------------
@@ -136,8 +137,9 @@ def test_decode_early_launch_share_reader(case, spans, want):
 
 def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # PR 39's nine readers, PR 42's six and PR 44's five stand behind it
-    assert manifest["per_layer"][-21] == {
+    # PR 39's nine readers, PR 42's six, PR 44's five and PR 45's one stand
+    # behind it
+    assert manifest["per_layer"][-22] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -148,4 +150,4 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       "kexaone_decode_mixed_len"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-21]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-22]["workloads"] == e2e["workloads"]

@@ -227,6 +227,41 @@ def test_token_out_is_a_mean_over_the_windows_frames(case, outs, want):
     assert got == tuple(w if w is None else pytest.approx(w) for w in want)
 
 
+def write_pass(at, frames, enders=0, streams=None):
+    return span("serving/write_pass", at, at + 0.01, frames=frames,
+                streams=frames if streams is None else streams,
+                enders=enders, bytes=100 * (frames + enders), backlogged=0)
+
+
+@pytest.mark.parametrize("case,passes,want", [
+    # a delivery of 88 chunks, its enders' 8 flushes, nine prefills' firsts
+    ("a_lane_iteration", [write_pass(1.0, 88), write_pass(1.1, 8, 8)]
+     + [write_pass(2.0 + k, 1) for k in range(9)], (88 + 8 + 9) / 11.0),
+    # a pass that carried terminal frames alone counts, with no frame
+    ("enders_alone", [write_pass(1.0, 4), write_pass(1.5, 0, 2)], 2.0),
+    ("outside_the_window", [write_pass(1.0, 6), write_pass(45.0, 90)], 6.0),
+    # the writer's parent: a handler thread a stream, no such span
+    ("no_pass", [], None)])
+def test_token_out_frames_per_pass_is_a_mean_over_the_windows_passes(
+        case, passes, want):
+    got = reader("token_out_frames_per_pass")(lane_rounds() + passes, None,
+                                              run_facts())
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def test_token_out_frames_per_pass_is_declared_for_the_decode_cells():
+    manifest = bench_run.load_json(bench_run.MANIFEST)
+    (m,) = [m for m in manifest["per_layer"]
+            if m["name"] == "token_out_frames_per_pass"]
+    assert m == {"name": "token_out_frames_per_pass", "unit": "frames",
+                 "better": "higher", "source": "program_span",
+                 "layer": "serving front", "moves": "tokens_per_s",
+                 "workloads": DECODE_CELLS}
+    assert manifest["per_layer"][-1] is m
+    assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
+                                       m["name"] + ".py"))
+
+
 # ---------------------------------------------------------------------------
 # a program without the new spans
 # ---------------------------------------------------------------------------
@@ -263,9 +298,9 @@ def test_spans_older_than_the_phase_spans_read_as_nothing(name):
 
 def test_the_nine_are_declared_last_for_the_five_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # (last but for the six readers PR 42 and the five PR 44 appended
-    # behind them)
-    last = manifest["per_layer"][-20:-11]
+    # (last but for the six readers PR 42, the five PR 44 and the one
+    # PR 45 appended behind them)
+    last = manifest["per_layer"][-21:-12]
     assert [m["name"] for m in last] == list(NINE)
     for m in last:
         assert m["workloads"] == DECODE_CELLS, m["name"]

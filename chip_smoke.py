@@ -78,7 +78,7 @@ DECODE_WIDE = (8, 4096, 16, 16, 128)
 
 # a latent slot table at openPangu-Ultra-MoE's published row: 128 absorbed
 # query heads over ONE row a position of 512 + 64 = 576 values, held as 640
-# lanes (decode.latent_row), the first 512 of them the values; 8 slots of the
+# lanes (slot_state.latent_row), the first 512 of them the values; 8 slots of the
 # cell's 64
 DECODE_LATENT = (8, 4096, 128, 576, 512)
 

@@ -828,11 +828,10 @@ def _decode_report(path, meta, decode_slots, device, what,
     (int8 slots + the per-(layer,head) fp32 scale table)."""
     import numpy as np
     from ..flags import FLAGS
-    from ..inference.decode import (layer_kinds, normalize_kv_dtype,
-                                    slot_state_shapes, window_state_shape)
+    from ..inference import slot_state
+    from ..inference.decode import block_of, normalize_kv_dtype
     n_slots = int(decode_slots or FLAGS.serving_decode_slots)
     L = int(meta["n_layers"])
-    H = int(meta["n_heads"])
     D = int(meta["d_model"])
     kv_dtype = normalize_kv_dtype(
         kv_cache_dtype if kv_cache_dtype is not None
@@ -854,29 +853,16 @@ def _decode_report(path, meta, decode_slots, device, what,
             if os.path.exists(state_path) else 0
         rep.actual_param_bytes = rep.param_bytes
         n_params = rep.param_bytes // 4
-    # K and V, [attention layers, n_slots, S, Hc * Dh] each at the cache
-    # dtype's width (4 B fp32, 1 B int8 + the fp32 scale table), a row the
-    # K/V heads' values and nothing else (`decode.slot_state_shapes`) — must
-    # match GenerativePredictor.kv_cache_bytes exactly (pinned by
-    # tests/test_resources.py)
-    kv_elem = 1 if kv_dtype == "int8" else 4
-    kv_scales = 2 * L * H * 4 if kv_dtype == "int8" else 0
-    kv_shape, conv_shape, ssm_shape = slot_state_shapes(meta, n_slots,
-                                                        device)
-    # (an MLA stack: ONE latent table [layers, n_slots, S, Rp], no V; a
-    # stack with attention+ssm layers: its fp32 scanned-state table too;
-    # one with window_attention layers: their fp32 K and V rings)
-    n_tables = 1 if layer_kinds(meta)[0][0] == "mla" else 2
-    ring_shape = window_state_shape(meta, n_slots)
-    rep.kv_cache_bytes = (n_tables * int(np.prod(kv_shape)) * kv_elem
-                          + kv_scales
-                          + (4 * int(np.prod(ssm_shape)) if ssm_shape else 0)
-                          + (8 * int(np.prod(ring_shape)) if ring_shape
-                             else 0))
+    # the slot state of every kind, priced by the record that shapes it
+    # (`slot_state.state_bytes`): GenerativePredictor.kv_cache_bytes reads
+    # the same numbers (pinned by tests/test_resources.py)
+    _, slot_bytes = slot_state.state_bytes(meta, block_of(meta), n_slots,
+                                           device, kv_dtype)
+    rep.kv_cache_bytes = slot_bytes["kv_cache_bytes"]
     # decode-step working set: one token's activations per slot, and the
-    # conv layers' carried state (K-1 inputs a slot and layer, fp32)
-    rep.activation_peak_bytes = n_slots * D * 4 * (L + 2) + (
-        4 * int(np.prod(conv_shape)) if conv_shape else 0)
+    # conv layers' carried state
+    rep.activation_peak_bytes = n_slots * D * 4 * (L + 2) \
+        + slot_bytes["conv_state_bytes"]
     # one decode step: every weight multiplies once per slot, and the
     # whole KV cache streams through the attention gather; a fused
     # dispatch is N such steps back-to-back at the same peak

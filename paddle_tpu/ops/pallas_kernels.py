@@ -637,7 +637,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     last, positions lie on the sublanes and 768 lanes are six full
     tiles: nothing is padded, for any head size whose Hc * D is a
     multiple of 128 (every stack served), and the table at rest is the
-    kernel's operand (`inference/decode.py::slot_state_shapes`).
+    kernel's operand (`inference/slot_state.py`, the kind `kv`).
 
     THE BODY contracts a [block_kv, Hc * D] tile per head without taking
     the heads apart: the slot's queries go in BLOCK-DIAGONAL, [H, Hc * D]
@@ -1149,7 +1149,7 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
         S <- decay S + dtx (outer) B;    y = sum_n S[.., n] C[n]
 
     ss [L, N, Hs, P, Ns] fp32 is the WHOLE stacked scanned-state table of
-    a decode session (`inference/decode.py::slot_state_shapes`) and
+    a decode session (`inference/slot_state.py`, the kind `ssm`) and
     `layer` (a static int) the layer updated: the kernel reaches it
     through its index maps, (layer, slot, block of heads, 0, 0), and the
     table is aliased to the second result, so with the table donated to

@@ -329,7 +329,8 @@ def test_padded_rows_serve_the_same_streams(artifact, opened, monkeypatch):
     """What one TPU device holds: rows padded to the tile's 128 lanes.  The
     pad is exact zeros and stays so, the tokens are the plain table's."""
     plain, _ = opened
-    monkeypatch.setattr(dec, "_rows_are_tiles", lambda device: True)
+    monkeypatch.setattr(dec.slot_state, "_rows_are_tiles",
+                        lambda device: True)
     pred = GenerativePredictor(artifact)
     assert pred.table_shape(2) == (3, 2, 64, 128)
     assert pred.kv_cache_bytes(2) == 3 * 2 * 64 * 128 * 4

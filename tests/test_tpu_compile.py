@@ -96,7 +96,7 @@ def test_decode_attention_compiles(one_chip, geometry, dtypes):
 
 
 # the decode cells' slot tables as the step holds them (a position one flat
-# row of its K/V heads' values, `decode.slot_state_shapes`): (N, S, query
+# row of its K/V heads' values, `slot_state.KINDS`): (N, S, query
 # heads, K/V heads, D), the table's last axis K/V heads x D
 CELL_TABLES = {"gpt2_small": (32, 1024, 12, 12, 64),
                "olmoe_1b_7b": (8, 4096, 16, 16, 128),
@@ -200,14 +200,14 @@ def test_routed_ffn_compiles_to_grouped_matmul_kernels(one_chip):
 # the decode step as a whole: the slot table is updated in place (PR 27).
 # Every argument is left in the device's OWN layout here, as at run time:
 # jax 0.9 loses a pinned output layout on a persistent-cache hit, so the
-# table must be row-major by the device's choice (`slot_state_shapes`), not
+# table must be row-major by the device's choice (`slot_state.py`), not
 # by a pin
 # ---------------------------------------------------------------------------
 
 STEP_MODELS = {
     # benchmark/configs/gpt2_small.json at its 32 slots and full depth: the
     # depth matters, XLA's rematerialisation pass misjudges the step only
-    # once the tables pass ~5.6 GB (`decode._TPU_PHASE_OPTIONS`)
+    # once the tables pass ~5.6 GB (`slot_state._TPU_PHASE_OPTIONS`)
     "gpt2_small": (dict(vocab_size=50257, d_model=768, n_heads=12,
                         n_layers=12, max_seq_len=1024, eos_id=0), 32),
     # benchmark/configs/olmoe_1b_7b.json at its 8 slots, depth cut to 2

@@ -4,22 +4,28 @@ the decode phases of ONE tree, to be compared with another tree's by `cmp`.
     python tools/decode_hlo_dump.py <tree> <out dir>      # once a tree
     diff -rq <out parent> <out change>
 
-For tiny GPT-2-shaped and OLMoE-shaped artifacts (fp32 and int8 caches; the
-slot tables hold flat rows, `decode.slot_state_shapes`) it writes the jaxpr,
-the StableHLO
-and the CPU's optimized HLO of `step` (the window), `step_logits` and
-`prefill`; and, compiled for a DESCRIBED v5e with the Mosaic kernels forced
-(no chip: the `on-chip-measurement` guide, section 2), the TPU's optimized
-HLO of `step` and `prefill` at the decode cells' widths and slot counts, two
-layers deep - the Mosaic kernel's payload is in that text.  Source locations
-are dropped (they name the tree), those inside a Mosaic payload too: the
-payload is written as its MLIR text.
+For a tiny artifact of each of the six served stacks (GPT-2-, OLMoE-, LFM2-,
+openPangu-, Falcon-H1- and K-EXAONE-shaped: the tests' own, so every kind of
+slot state occurs; fp32 caches and, where the stack takes one, int8) it writes
+the jaxpr, the StableHLO and the CPU's optimized HLO of `step` (the window),
+`step_logits` and `prefill`; and, compiled for a DESCRIBED v5e with the Mosaic
+kernels forced (no chip: the `on-chip-measurement` guide, section 2), the TPU's
+optimized HLO of `step` and `prefill` at the six decode configurations'
+published widths and slot counts, two layers deep - the Mosaic kernel's
+payload is in that text.  Beside each phase's texts goes its compile-cache
+fingerprint (`GenerativePredictor._fingerprint`, as JSON): two trees whose
+fingerprints are equal find each other's stored executables.  Source
+locations are dropped (they name the tree), those inside a Mosaic payload too:
+the payload is written as its MLIR text.
 """
 import base64
+import json
 import os
 import re
 import sys
 import tempfile
+
+import ml_dtypes
 
 root, out = os.path.abspath(sys.argv[1]), sys.argv[2]
 sys.path.insert(0, root)
@@ -42,6 +48,27 @@ CELLS = {"gpt2_small": (dict(vocab_size=50257, d_model=768, n_heads=12,
                               n_layers=2, max_seq_len=4096, eos_id=0,
                               n_experts=64, experts_per_token=8,
                               expert_width=1024, **OLMOE), 8)}
+
+
+def cell_of(config, layer_types):
+    """(meta, slots) of `benchmark/configs/<config>.json` at its published
+    widths and slot count, cut to the two layers `layer_types` (one dense
+    FFN, one of the stack's own), so that every kind of slot state the
+    configuration holds occurs."""
+    with open(os.path.join(root, "benchmark", "configs",
+                           config + ".json")) as f:
+        cfg = json.load(f)
+    meta = dict(cfg["model"], n_layers=2, layer_types=layer_types)
+    return meta, cfg["deployment"]["decode_slots"]
+
+
+CELLS.update(
+    lfm2_24b_a2b=cell_of("lfm2_24b_a2b", ["conv", "attention"]),
+    openpangu_ultra_moe_718b=cell_of("openpangu_ultra_moe_718b",
+                                     ["mla", "mla"]),
+    falcon_h1_34b=cell_of("falcon_h1_34b", ["attention+ssm"] * 2),
+    k_exaone_236b_a23b=cell_of("k_exaone_236b_a23b",
+                               ["window_attention", "attention"]))
 
 
 def mosaic_text(match):
@@ -94,19 +121,37 @@ def _phases(pred, n_slots, bucket):
                          jax.ShapeDtypeStruct((), np.int32)))}
 
 
-for name, block in (("gpt2", None),
-                    ("olmoe", dict(OLMOE, n_experts=8, experts_per_token=2,
-                                   expert_width=32))):
+def write_fingerprint(tag, pred, ph, n_slots, bucket, specs):
+    """The phase's compile-cache fingerprint, under the key its `*_fn`
+    resolves it by."""
+    key = {"step": ("step", n_slots, int(dec.STEP_WINDOW)),
+           "step_logits": ("step_logits", n_slots) + (
+               ("picks",) if pred._step_picks else ()),
+           "prefill": ("prefill", bucket)}[ph]
+    with open(os.path.join(out, tag + ".fingerprint"), "w") as f:
+        json.dump(pred._fingerprint(key, specs), f, sort_keys=True,
+                  indent=1, default=str)
+
+
+# the tests' tiny stacks (`tests/test_decode_sliding.py` extends
+# `tests/test_decode_ssm.py`'s), the tree's own copy of them
+from tests import test_decode_sliding as tiny  # noqa: E402
+
+STACKS = {name: (block, tiny.OLD_TINY)
+          for name, block in tiny.OLD_STACKS.items()}
+STACKS["kexaone"] = (tiny.WINDOW_BLOCK, tiny.TINY)
+for name, (block, size) in sorted(STACKS.items()):
     d = tempfile.mkdtemp()
-    dec.build_tiny_decode_model(
-        d, vocab_size=97, d_model=64, n_heads=4, n_layers=2,
-        max_seq_len=64, prefill_buckets=[16, 32], block=block)
-    for kv in ("float32", "int8"):
+    dec.build_tiny_decode_model(d, block=block, **size)
+    # an int8 cache is the all-attention multi-head stacks'
+    for kv in ("float32", "int8") if name in ("gpt2", "olmoe") else (
+            "float32",):
         pred = dec.load_decode_predictor(d, kv_cache_dtype=kv)
         state = {n: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype)
                  for n, v in pred._state_host.items()}
         for ph, (fn, specs) in phases(pred, 4, 16).items():
             tag = "%s_%s_%s" % (name, kv, ph)
+            write_fingerprint(tag, pred, ph, 4, 16, specs)
             low = jax.jit(fn).lower(state, *specs)
             write(tag + ".stablehlo", low.as_text())
             write(tag + ".hlo", low.compile().as_text())
@@ -125,12 +170,20 @@ for name, (meta, slots) in CELLS.items():
     pred._kv_scales = None
     state = {n: jax.ShapeDtypeStruct(s, np.float32, sharding=on)
              for n, s in dec.decode_state_shapes(meta).items()}
+    if pred._block_meta["weight_dtype"] == "bfloat16":
+        state = {n: jax.ShapeDtypeStruct(
+            s.shape, ml_dtypes.bfloat16 if dec._bf16_at_rest(n, s)
+            else s.dtype, sharding=on) for n, s in state.items()}
+    # what `_fingerprint` reads of an opened artifact
+    pred._model_fp, pred._state_host = "described:" + name, state
     for ph, (fn, specs) in phases(pred, slots, 128).items():
         if ph == "step_logits":
             continue
+        write_fingerprint("v5e_%s_%s" % (name, ph), pred, ph, slots, 128,
+                          specs)
         specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on)
                  for s in specs]
-        donate = (1, 2) if ph == "step" else ()
+        donate = tuple(range(1, 1 + pred._n_tables)) if ph == "step" else ()
         with pk.mosaic_lowering():
             low = jax.jit(fn, donate_argnums=donate,
                           compiler_options=dec._TPU_PHASE_OPTIONS).lower(

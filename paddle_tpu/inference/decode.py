@@ -342,8 +342,50 @@ BLOCK_DEFAULTS = (
     ("sliding_window", 0),
     # which attending layers turn q and k under position=rope: "all" |
     # "window": the window layers alone, the full ones see no position
-    # signal (a stack that has both kinds)
+    # signal (a stack that has both kinds) | "linear": the linear_attention
+    # layers alone, the sparse ones see none
     ("rope_layers", "all"),
+    # layer_types "linear_attention": attention without a softmax, a
+    # RECURRENCE a head: S_t = decay_h S_{t-1} + v_t (outer) k_t, o_t = S_t
+    # q_t / sqrt(ssm_state), over `ssm_heads` heads with values of
+    # `ssm_head_dim` and keys of `ssm_state` features (`ssm_groups` =
+    # `ssm_heads`: a head's k and q are its own), exp(`linear_log_decay`[h])
+    # the fixed decay of head h.  Its slot state [ssm_heads, ssm_head_dim,
+    # ssm_state] fp32 IS the kind `ssm` (a decayed running sum, read and
+    # rewritten whole by every token: `slot_state.KINDS`), its step
+    # `pallas_kernels.ssm_update` and its prefill `ssd_chunked_scan` with
+    # dt = 1, through the same `scan` callback as a state-space mixer's;
+    # no conv, no dt projection, no D
+    ("linear_log_decay", ()),
+    # layer_types "sparse_attention": grouped-query attention over the
+    # SELECTED blocks of `sparse_block` positions only.  Beside its K/V
+    # rows (the kind `kv`, as an attention layer's) it keeps an INDEXER's
+    # cache (the kind `index`): compressed key j of a K/V head is the mean
+    # of keys `sparse_kernel_stride` * j .. + `sparse_kernel_size` - 1,
+    # seen once the last of them is cached.  A position scores the seen
+    # compressed keys (softmax a query head, summed over the K/V head's
+    # group), a block takes the max over the compressed keys that overlap
+    # it, block 0 .. `sparse_init_blocks` - 1 and the blocks that hold the
+    # last `sparse_window` positions are forced, and the `sparse_topk`
+    # highest are attended over (`_sparse_select`).  0 = no sparse layer
+    ("sparse_block", 0),
+    ("sparse_topk", 0),
+    ("sparse_init_blocks", 0),
+    ("sparse_window", 0),
+    ("sparse_kernel_size", 0),
+    ("sparse_kernel_stride", 0),
+    # a mixer's (sparse_attention, linear_attention) output times
+    # sigmoid(h @ wg) before its output projection, and a linear_attention
+    # layer's output RMS-normed a head (gain `on_g`) before that gate
+    ("output_gate", False),
+    ("output_norm", False),
+    # a prefill runs the prompt in CHUNKS of this many positions inside its
+    # one executable (`_prefill_chunks`: the layers' temporaries are a
+    # chunk's, whatever the bucket; what it carries from chunk to chunk is
+    # the slot state itself: the K/V rows and compressed keys written so
+    # far, the linear layers' states); 0 = the bucket whole.  A stack of
+    # sparse_attention / linear_attention layers
+    ("prefill_chunk", 0),
 )
 _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "position": ("learned", "rope"),
@@ -354,12 +396,24 @@ _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "router": ("softmax", "sigmoid_bias", "sigmoid"),
                   "head": ("untied", "tied"),
                   "weight_dtype": ("float32", "bfloat16"),
-                  "rope_layers": ("all", "window")}
+                  "rope_layers": ("all", "window", "linear")}
 # a layer's operators, each with the kinds of slot state it keeps
 _LAYER_TYPES = tuple(slot_state.HOLDS)
 _SSM_DIMS = ("ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups")
 _MLA_DIMS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
              "qk_rope_head_dim", "v_head_dim")
+_SPARSE_DIMS = ("sparse_block", "sparse_topk", "sparse_init_blocks",
+                "sparse_window", "sparse_kernel_size",
+                "sparse_kernel_stride")
+# the operators of a stack that prefills in chunks (`_prefill_chunks`)
+_CHUNKED_OPS = ("sparse_attention", "linear_attention")
+
+
+def _chunk_unit(blk):
+    """What a prefill chunk of a stack of `_CHUNKED_OPS` layers is whole
+    numbers of: a sparse block and a linear layer's scan chunk."""
+    return int(np.lcm(blk["sparse_block"] or 1, blk["ssm_chunk"]
+                      if "linear_attention" in blk["layer_types"] else 1))
 
 
 def block_of(meta):
@@ -376,7 +430,8 @@ def block_of(meta):
             v = tuple(str(t) for t in v)
         elif key == "experts_held":
             v = tuple(int(t) for t in v)
-        elif key in ("ssm_multipliers", "mlp_multipliers"):
+        elif key in ("ssm_multipliers", "mlp_multipliers",
+                     "linear_log_decay"):
             v = tuple(float(t) for t in v)
         else:
             v = type(default)(v)
@@ -406,7 +461,8 @@ def block_of(meta):
                          "head size")
     if kinds and (len(kinds) != n_layers
                   or any(t not in _LAYER_TYPES for t in kinds)
-                  or not {"attention", "mla", "attention+ssm"} & set(kinds)):
+                  or not {"attention", "mla", "attention+ssm",
+                          "sparse_attention"} & set(kinds)):
         raise ValueError(
             "decode meta layer_types=%r needs one of %s for each of the %d "
             "layers, and an attention layer among them"
@@ -430,6 +486,89 @@ def block_of(meta):
             "decode meta rope_layers=window goes with position=rope (%r) "
             "and a stack with window_attention layers beside its full ones "
             "(layer_types=%r)" % (out["position"], list(kinds)))
+    chunked = set(kinds) & set(_CHUNKED_OPS)
+    if chunked and not set(kinds) <= set(_CHUNKED_OPS):
+        raise ValueError(
+            "decode meta layer_types=%r mixes %s with other operators: a "
+            "stack with such layers prefills in chunks, which carries the "
+            "state of these two alone" % (list(kinds), "|".join(sorted(
+                chunked))))
+    sparse = "sparse_attention" in kinds
+    if not (all(out[k] >= 1 for k in _SPARSE_DIMS) if sparse
+            else all(out[k] == 0 for k in _SPARSE_DIMS)):
+        raise ValueError(
+            "decode meta %s: each >= 1 where layer_types has a "
+            "sparse_attention layer and 0 where it has none "
+            "(layer_types=%r)" % (", ".join(
+                "%s=%d" % (k, out[k]) for k in _SPARSE_DIMS), list(kinds)))
+    if sparse and (out["sparse_kernel_size"] % out["sparse_kernel_stride"]
+                   or out["sparse_block"] % out["sparse_kernel_stride"]
+                   or int(meta["max_seq_len"]) % out["sparse_block"]
+                   or out["sparse_topk"] * out["sparse_block"]
+                   < out["sparse_window"] + 2 * out["sparse_block"]):
+        raise ValueError(
+            "decode meta sparse_kernel_size=%d and sparse_block=%d are "
+            "whole strides (sparse_kernel_stride=%d), max_seq_len %d whole "
+            "blocks, and sparse_topk=%d blocks hold the init block and the "
+            "sparse_window=%d forced positions"
+            % (out["sparse_kernel_size"], out["sparse_block"],
+               out["sparse_kernel_stride"], int(meta["max_seq_len"]),
+               out["sparse_topk"], out["sparse_window"]))
+    linear = "linear_attention" in kinds
+    if linear:
+        for key in _SSM_DIMS:
+            if out[key] < 1:
+                raise ValueError("decode meta %s=%d: a linear_attention "
+                                 "layer needs it >= 1" % (key, out[key]))
+        if out["ssm_groups"] != out["ssm_heads"] or out["ssm_conv_kernel"]:
+            raise ValueError(
+                "decode meta ssm_groups=%d, ssm_conv_kernel=%d: a "
+                "linear_attention layer's k and q are a head's own "
+                "(ssm_groups = ssm_heads %d) and it has no conv in front"
+                % (out["ssm_groups"], out["ssm_conv_kernel"],
+                   out["ssm_heads"]))
+        if len(out["linear_log_decay"]) != out["ssm_heads"] or any(
+                a > 0.0 for a in out["linear_log_decay"]):
+            raise ValueError(
+                "decode meta linear_log_decay=%r is not one number <= 0 "
+                "(the log of its fixed decay) for each of the %d heads"
+                % (list(out["linear_log_decay"]), out["ssm_heads"]))
+        if out["position"] == "rope" and out["ssm_state"] % 2:
+            raise ValueError("decode meta position=rope needs an even "
+                             "ssm_state (a linear head's key size)")
+    elif out["linear_log_decay"] or out["output_norm"]:
+        raise ValueError("decode meta linear_log_decay / output_norm go "
+                         "with layer_types linear_attention")
+    if out["rope_layers"] == "linear" and not (
+            linear and out["position"] == "rope"):
+        raise ValueError(
+            "decode meta rope_layers=linear goes with position=rope (%r) "
+            "and a stack with linear_attention layers (layer_types=%r)"
+            % (out["position"], list(kinds)))
+    if out["output_gate"] and not chunked:
+        raise ValueError("decode meta output_gate goes with layer_types "
+                         "sparse_attention|linear_attention")
+    chunk = out["prefill_chunk"]
+    if chunk < 0 or (chunk and not chunked):
+        raise ValueError(
+            "decode meta prefill_chunk=%d: >= 1 for a stack of "
+            "sparse_attention / linear_attention layers, which prefills "
+            "in chunks, 0 = whole (layer_types=%r)" % (chunk, list(kinds)))
+    if chunked:
+        # what a chunk has to be whole numbers of
+        unit = _chunk_unit(out)
+        # max_seq_len among them: a prompt past every bucket prefills in the
+        # next whole number of chunks (`prompt_bucket`), which the cache holds
+        buckets = [int(b) for b in meta.get("prefill_buckets") or (
+            _default_prefill_buckets(int(meta["max_seq_len"])))]
+        if chunk % unit or any(b % (chunk or unit) for b in buckets + [
+                int(meta["max_seq_len"])]):
+            raise ValueError(
+                "decode meta prefill_chunk=%d, prefill_buckets=%r, "
+                "max_seq_len=%d: a bucket and the cache are whole chunks "
+                "and a chunk whole sparse blocks and scan chunks (%d "
+                "positions)" % (chunk, buckets, int(meta["max_seq_len"]),
+                                unit))
     if "mla" in kinds:
         if set(kinds) != {"mla"}:
             raise ValueError(
@@ -474,6 +613,11 @@ def block_of(meta):
                 "decode meta ssm_multipliers=%r is not one number for each "
                 "of the segments z, x, B, C, dt"
                 % (list(out["ssm_multipliers"]),))
+        if linear:
+            raise ValueError(
+                "decode meta layer_types=%r mixes attention+ssm with "
+                "linear_attention layers: a session's scanned-state table "
+                "has one shape" % (list(kinds),))
     elif out["ssm_multipliers"] or any(
             out[k] != 1.0 for k in ("ssm_in_multiplier",
                                     "ssm_out_multiplier")):
@@ -536,8 +680,8 @@ def block_of(meta):
 def layer_kinds(meta, blk=None):
     """(operator, FFN) of every layer of the stack `meta` describes
     (`blk`: its `block_of`, where the caller has it): operator
-    "attention" | "conv" | "mla" | "attention+ssm" | "window_attention",
-    FFN "dense_swiglu"
+    "attention" | "conv" | "mla" | "attention+ssm" | "window_attention" |
+    "sparse_attention" | "linear_attention", FFN "dense_swiglu"
     (the first `n_dense_layers`; every layer under ffn=swiglu) or the
     meta's `ffn`."""
     blk = blk or block_of(meta)
@@ -599,6 +743,18 @@ def decode_state_shapes(meta):
             shapes[p + "conv_in"] = (D, 3 * D)
             shapes[p + "conv_w"] = (D, blk["conv_kernel"])
             shapes[p + "conv_out"] = (D, D)
+        elif op == "linear_attention":
+            Hs, P, Ns = (blk[k] for k in _SSM_DIMS[:3])
+            shapes[p + "wq"] = shapes[p + "wk"] = (D, Hs * Ns)
+            shapes[p + "wv"], shapes[p + "wo"] = (D, Hs * P), (Hs * P, D)
+            if blk["qk_norm"] == "head":
+                shapes[p + "qn_g"] = shapes[p + "kn_g"] = (Ns,)
+            elif blk["qk_norm"]:
+                shapes[p + "qn_g"] = shapes[p + "kn_g"] = (Hs * Ns,)
+            if blk["output_norm"]:
+                shapes[p + "on_g"] = (Hs * P,)
+            if blk["output_gate"]:
+                shapes[p + "wg"] = (D, Hs * P)
         else:
             shapes[p + "wq"], shapes[p + "wo"] = (D, H * Dh), (H * Dh, D)
             shapes[p + "wk"] = shapes[p + "wv"] = (D, kv_width)
@@ -607,6 +763,8 @@ def decode_state_shapes(meta):
             elif blk["qk_norm"]:
                 shapes[p + "qn_g"], shapes[p + "kn_g"] = (H * Dh,), (
                     kv_width,)
+            if blk["output_gate"]:
+                shapes[p + "wg"] = (D, H * Dh)
         if op == "attention+ssm":
             d_ssm, conv, wide = _ssm_widths(blk)
             Hs = blk["ssm_heads"]
@@ -1107,6 +1265,71 @@ def _zero_pad_positions(ks, vs, true_len):
             jnp.where(live, jnp.stack(vs), 0.0))
 
 
+def _sparse_select(s, t, blk, n_blocks):
+    """Stage 1 of a sparse_attention layer, THE selection rule, for the
+    step and the prefill alike: s [..., G, J] the scaled scores of a K/V
+    head's G query heads against its compressed keys 0 .. J - 1, for the
+    query at position t [...] -> (ids [..., k] i32 the blocks chosen,
+    highest score first; count [...] i32 how many of them are in sight;
+    scores [..., n_blocks]; the chosen ones' scores [..., k]), k =
+    min(sparse_topk, n_blocks).
+
+    Compressed key j is SEEN once its last position stride * j + size - 1
+    is cached (<= t).  p = softmax over the seen keys a query head, summed
+    over the G heads; block b (positions block * b .. + block - 1) scores
+    the max of p over the compressed keys that overlap it (an unseen one
+    counts 0); the first `sparse_init_blocks` blocks and those that hold
+    one of the last `sparse_window` positions score +inf, a block past t
+    -1 (never chosen before one in sight); `lax.top_k` takes the highest,
+    ties to the lower index.  All in float32: the caller computes `s` at
+    "highest" precision, as a router's logits are."""
+    import jax
+    import jax.numpy as jnp
+    size, stride, block = (blk[k] for k in (
+        "sparse_kernel_size", "sparse_kernel_stride", "sparse_block"))
+    r, m = block // stride, size // stride
+    J = s.shape[-1]
+    t = jnp.asarray(t)
+    seen = stride * jnp.arange(J) + size - 1 <= t[..., None, None]
+    s = jnp.where(seen, s, -1e30)
+    e = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)) * seen
+    p = jnp.sum(e / jnp.maximum(jnp.sum(e, axis=-1, keepdims=True), 1e-20),
+                axis=-2)                                    # [..., J]
+    # block b reads compressed keys r * b - (m - 1) .. r * b + r - 1:
+    # entries r * b .. r * b + r + m - 2 of p with m - 1 zeros in front
+    pp = jnp.pad(p, [(0, 0)] * (p.ndim - 1)
+                 + [(m - 1, r * n_blocks - J)])
+    score = jnp.max(pp[..., :r * n_blocks].reshape(
+        p.shape[:-1] + (n_blocks, r)), axis=-1)
+    for a in range(r, r + m - 1):
+        score = jnp.maximum(score, pp[..., a::r][..., :n_blocks])
+    first = block * jnp.arange(n_blocks)
+    tb = t[..., None]
+    forced = (jnp.arange(n_blocks) < blk["sparse_init_blocks"]) | (
+        first + block - 1 >= tb - (blk["sparse_window"] - 1))
+    score = jnp.where(first <= tb, jnp.where(forced, jnp.inf, score), -1.0)
+    vals, ids = jax.lax.top_k(score, min(blk["sparse_topk"], n_blocks))
+    return (ids.astype(jnp.int32),
+            jnp.sum(vals >= 0, axis=-1).astype(jnp.int32), score, vals)
+
+
+def _compressed_keys(rows, blk):
+    """rows [..., n * stride, W], n >= m = size / stride: the means of every
+    `sparse_kernel_size` consecutive rows, a stride apart -> [..., n - m +
+    1, W].  A stride's rows are summed first and m such sums added, the
+    step's one key and a prefill chunk's run of them alike."""
+    import jax.numpy as jnp
+    size, stride = blk["sparse_kernel_size"], blk["sparse_kernel_stride"]
+    m = size // stride
+    n = rows.shape[-2] // stride
+    sums = jnp.sum(rows.reshape(rows.shape[:-2] + (n, stride)
+                                + rows.shape[-1:]), axis=-2)
+    out = sums[..., 0:n - m + 1, :]
+    for a in range(1, m):
+        out = out + sums[..., a:a + n - m + 1, :]
+    return out * np.float32(1.0 / size)
+
+
 def ssd_chunked_scan(xs, Bm, Cm, dt, A, chunk, state=None):
     """The state-space recurrence of a Mamba-2 (SSD) mixer over a run of
     positions, in CHUNKS: xs [T, Hs, P] inputs, Bm / Cm [T, G, N] (head h
@@ -1470,9 +1693,15 @@ class GenerativePredictor:
 
     @property
     def _step_picks(self):
-        """Whether `step_logits_fn` hands out the routed layers' picks:
-        a stack with conv layers and routed FFNs."""
-        return bool(self.conv_layers and self.routed_layers)
+        """Whether `step_logits_fn` hands out the layers' picks: a stack
+        with conv layers and routed FFNs (the routed layers' chosen
+        experts), or one with sparse_attention layers (the blocks each
+        selected, [sparse layers, N, K/V heads, k], -1 past a slot's
+        count): there too a near-tie of the selection reaches every later
+        position, through the K/V rows and states the layers behind it
+        write."""
+        return bool(self.conv_layers and self.routed_layers) or bool(
+            self._table_layer(None, "index"))
 
     def _require(self, what, capability):
         """Raise for `what` (a placement, a phase) that needs a
@@ -1548,7 +1777,11 @@ class GenerativePredictor:
         configured bucket but still inside the cache falls through to
         an exact-length one-off prefill compile, warning ONCE per
         overflow size — the same contract as the Predictor batch-bucket
-        overflow path (SERVING.md)."""
+        overflow path (SERVING.md).  A stack that prefills in chunks
+        (`_prefill_chunks`) runs WHOLE chunks: its overflow compile is
+        the next whole number of them, the pads masked by the true
+        length as a bucket's are; the cache is whole chunks too
+        (`block_of`), so it holds the rows."""
         buckets = self.prefill_buckets()
         for b in buckets:
             if prompt_len <= b:
@@ -1557,24 +1790,30 @@ class GenerativePredictor:
             raise ValueError(
                 "prompt of %d tokens exceeds max_seq_len %d"
                 % (prompt_len, self.max_seq_len))
-        if prompt_len not in self._overflow_warned:
+        size = int(prompt_len)
+        if self._chunked:
+            blk = self._block_meta
+            unit = blk["prefill_chunk"] or _chunk_unit(blk)
+            size = -(-size // unit) * unit
+        if size not in self._overflow_warned:
             with self._lock:
                 # concurrent lanes racing the same overflow size must
                 # produce exactly one warning (the PR 5 warn-once race)
-                if prompt_len in self._overflow_warned:
-                    return int(prompt_len)
-                self._overflow_warned.add(prompt_len)
+                if size in self._overflow_warned:
+                    return size
+                self._overflow_warned.add(size)
             from paddle_tpu.inference.predictor import _device_label
             warnings.warn(
                 "prompt of %d tokens exceeds every configured prefill "
                 "bucket %s on replica device [%s] — falling through to "
-                "an unbucketed exact-length prefill compile; extend "
+                "an unbucketed exact-length prefill compile%s; extend "
                 "prefill_buckets to avoid a compile per distinct "
                 "overflow length"
-                % (prompt_len, tuple(buckets),
-                   _device_label(self._device)), RuntimeWarning,
+                % (prompt_len, tuple(buckets), _device_label(self._device),
+                   " (%d positions: whole prefill chunks)" % size
+                   if size != prompt_len else ""), RuntimeWarning,
                 stacklevel=3)
-        return int(prompt_len)
+        return size
 
     def clone_to(self, device):
         return GenerativePredictor(None, device=device, _clone_of=self)
@@ -1785,7 +2024,10 @@ class GenerativePredictor:
 
     def _prefill_core(self, state, tokens, true_len, tp=_OFF_MESH):
         """tokens [1, B] int32, true_len scalar int32 -> (first_token
-        [] int32, then what the prompt leaves of each leaf of
+        [] int32 (a stack with sparse_attention layers: an int32 vector,
+        the token, then the blocks its last position selected [sparse
+        layers, K/V heads, k], -1 past their count), then what the prompt
+        leaves of each leaf of
         `_table_names`, one slot's block of its table: k/v [attention
         layers, 1, B, Hkv, Dh] fp32 with pad positions zeroed; an MLA
         stack's latent rows [mla layers, 1, B, R]; each convolving
@@ -1802,6 +2044,18 @@ class GenerativePredictor:
         if self._tp_seq_parallel(tokens.shape[1], tp):
             return self._prefill_core_seqpar(state, tokens, true_len,
                                              tp)
+        if self._chunked:
+            import jax.numpy as jnp
+            row, picks, tables = self._prefill_chunks(state, tokens,
+                                                      true_len, tp)
+            first = jnp.argmax(self._head(state, row, tp),
+                               axis=-1).astype(jnp.int32)
+            # the blocks the prompt's LAST position selected ride the fetch
+            # that brings the token, as a routed stack's facts do
+            # (`DecodeSession.last_prefill_picks`): a comparison holds the
+            # prefill's selection to a reference's by them
+            return (jnp.concatenate([first.reshape(1),
+                                     picks.reshape(-1)]),) + tables
         x, facts, tables = self._prefill_layers(state, tokens, true_len,
                                                 tp)
         first = self._first_token(state, x, true_len, tp)
@@ -1887,6 +2141,208 @@ class GenerativePredictor:
             (leaves[kind.name](*(kept[leaf] for leaf in kind.leaves))
              for kind, _ in self._kinds), ())
 
+    @functools.cached_property
+    def _chunked(self):
+        """Whether the stack prefills through `_prefill_chunks`: one of
+        sparse_attention / linear_attention layers (`block_of`)."""
+        return bool(set(self._block_meta["layer_types"]) & set(_CHUNKED_OPS))
+
+    def prefill_chunks(self, prompt_len):
+        """Chunks the prefill of a prompt of `prompt_len` tokens runs its
+        bucket in (0: this stack's prefill is one whole pass)."""
+        chunk = self._block_meta["prefill_chunk"]
+        return self.prompt_bucket(prompt_len) // chunk if chunk else 0
+
+    def _prefill_chunks(self, state, tokens, true_len, tp=_OFF_MESH):
+        """`_prefill_layers` for a stack of sparse_attention and
+        linear_attention layers, IN CHUNKS: a `lax.scan` over the bucket's
+        chunks of `prefill_chunk` positions (the bucket whole where that
+        is 0), each chunk through ALL layers, so that no temporary is
+        larger than a chunk's whatever the bucket.  What a chunk hands the
+        next is the slot state the prompt has left so far: the sparse
+        layers' K and V rows and compressed keys (buffers a bucket long,
+        written a chunk at a time), the linear layers' states
+        (`ssd_chunked_scan` from the carried state) and the last layer's x
+        at position true_len - 1 once a chunk holds it.  A chunk wholly
+        past the prompt is skipped.  The result does not depend on the
+        chunk: a position's compressed keys, selection and attention read
+        the rows written so far, which are the rows a whole pass reads.
+        -> (x [D] at the prompt's last position, the blocks that position
+        selected in each sparse layer [sparse layers, K/V heads, k] i32, -1
+        past their count, the slot state as `_prefill_core` returns it)."""
+        import jax
+        import jax.numpy as jnp
+        blk = self._block_meta
+        L, H, Dh, D = self._dims()
+        B = tokens.shape[1]
+        C = blk["prefill_chunk"] or B
+        Hc = self._kv_heads()
+        n_sparse = self._table_layer(None, "index")
+        n_linear = self._table_layer(None, "ssm")
+        stride, size, block = (blk[k] for k in (
+            "sparse_kernel_stride", "sparse_kernel_size", "sparse_block"))
+        # a compressed key reads `front` positions before its chunk: the K
+        # buffer keeps that many rows of zeros before position 0, and the
+        # key buffer's row r holds compressed key r - (m - 1)
+        m = size // stride
+        front = (m - 1) * stride
+        carry = {"kc": jnp.zeros((n_sparse, front + B, Hc, Dh), jnp.float32),
+                 "vc": jnp.zeros((n_sparse, B, Hc, Dh), jnp.float32),
+                 "ki": jnp.zeros((n_sparse, B // stride, Hc * Dh),
+                                 jnp.float32),
+                 "row": jnp.zeros((D,), jnp.float32),
+                 "picks": jnp.full(
+                     (n_sparse, Hc, min(blk["sparse_topk"], B // block)),
+                     -1, jnp.int32)}
+        if n_linear:
+            carry["ss"] = jnp.zeros((n_linear,) + tuple(
+                blk[k] for k in _SSM_DIMS[:3]), jnp.float32)
+
+        def chunk(carry, c):
+            carry = dict(carry)
+            s0 = c * C
+            pos = s0 + jnp.arange(C)
+            live = pos < true_len
+            # where in this chunk the prompt's last position is, if it is
+            last = true_len - 1 - s0
+            here = (last >= 0) & (last < C)
+            x = self._embed(state, jax.lax.dynamic_slice_in_dim(
+                tokens, s0, C, axis=1), pos[None], tp)
+
+            def attend(q, k, v, ls):
+                k, v = (jnp.where(live[:, None, None], t[0], 0.0)
+                        for t in (k, v))
+                carry["kc"] = jax.lax.dynamic_update_slice(
+                    carry["kc"], k[None], (ls, front + s0, 0, 0))
+                carry["vc"] = jax.lax.dynamic_update_slice(
+                    carry["vc"], v[None], (ls, s0, 0, 0))
+                with jax.named_scope("sparse_select"):
+                    # the compressed keys whose last position is in this
+                    # chunk: stride * j + size - 1 in s0 .. s0 + C - 1
+                    rows = jax.lax.dynamic_slice_in_dim(
+                        carry["kc"][ls], s0, C + front, axis=0)
+                    ck = _compressed_keys(rows.reshape(C + front, -1), blk)
+                    j = s0 // stride - (m - 1) + jnp.arange(C // stride)
+                    ck = jnp.where(((j >= 0) & (stride * j + size - 1
+                                                < true_len))[:, None],
+                                   ck, 0.0)
+                    carry["ki"] = jax.lax.dynamic_update_slice(
+                        carry["ki"], ck[None], (ls, s0 // stride, 0))
+                out, picks = self._chunk_attention(
+                    q[0], carry["kc"][ls, front:], carry["vc"][ls],
+                    carry["ki"][ls, m - 1:], c, C)
+                carry["picks"] = carry["picks"].at[ls].set(jnp.where(
+                    here, jax.lax.dynamic_index_in_dim(
+                        picks, jnp.clip(last, 0, C - 1), keepdims=False),
+                    carry["picks"][ls]))
+                return out[None]
+
+            def scan(xs, Bm, Cm, dt, A, ll):
+                y, after = ssd_chunked_scan(
+                    xs[0], Bm[0], Cm[0],
+                    jnp.where(live[:, None], dt[0], 0.0), A,
+                    blk["ssm_chunk"], state=carry["ss"][ll])
+                carry["ss"] = carry["ss"].at[ll].set(after)
+                return y[None]
+
+            for i in range(L):
+                x, _ = self._block(
+                    state, i, x, pos[None], functools.partial(
+                        attend, ls=self._table_layer(i)), live, tp=tp,
+                    ssm=("ssm_scan", functools.partial(
+                        scan, ll=self._table_layer(i, "ssm"))))
+            carry["row"] = jnp.where(
+                here,
+                jax.lax.dynamic_index_in_dim(
+                    x[0], jnp.clip(last, 0, C - 1), keepdims=False),
+                carry["row"])
+            return carry
+
+        def one(carry, c):
+            return jax.lax.cond(c * C < true_len, chunk,
+                                lambda carry, c: carry, carry, c), None
+
+        carry, _ = jax.lax.scan(one, carry, jnp.arange(B // C))
+        left = {"kv": (carry["kc"][:, None, front:], carry["vc"][:, None]),
+                "index": (carry["ki"][:, None, m - 1:],)}
+        if n_linear:
+            left["ssm"] = (carry["ss"][:, None],)
+        return carry["row"], carry["picks"], sum(
+            (left[kind.name] for kind, _ in self._kinds), ())
+
+    def _chunk_attention(self, q, k, v, ck, c, C):
+        """A sparse_attention layer's attention of one prefill chunk: q [C,
+        H, Dh] the queries at positions c * C .., k / v [B, Hc, Dh] the
+        rows written so far (zeros past them), ck [J, Hc * Dh] the
+        compressed keys so far -> ([C, H, Dh], the blocks each query
+        selected [C, Hc, k] i32, -1 past their count).  By blocks of at most
+        `PREFILL_QUERY_BLOCK` queries (`lax.map`): stage 1 against the
+        compressed keys at "highest" precision and the selection
+        (`_sparse_select`, the step's rule) under the scope
+        `sparse_select`; stage 2 under `sparse_attention`, the softmax
+        over the positions j <= t of the SELECTED blocks, as a running
+        softmax over tiles of C keys, 0 .. c: a tile's scores are masked to
+        the selected blocks' positions, which IS attention over the
+        selected blocks, exactly, in plain XLA."""
+        import jax
+        import jax.numpy as jnp
+        blk = self._block_meta
+        block = blk["sparse_block"]
+        _, H, Dh = q.shape
+        B, Hc = k.shape[:2]
+        G = H // Hc
+        Q = min(int(PREFILL_QUERY_BLOCK), C)
+        while C % Q:
+            Q -= 1
+        scale = np.float32(1.0 / np.sqrt(Dh))
+        ckh = ck.reshape(-1, Hc, Dh)
+        tiles = C // block
+
+        def one(args):
+            u, qi = args                            # qi [Q, Hc, G, Dh]
+            qpos = c * C + u * Q + jnp.arange(Q)
+            with jax.named_scope("sparse_select"):
+                s1 = jnp.einsum("qhgd,jhd->qhgj", qi, ckh,
+                                precision="highest") * scale
+                ids, count, score, vals = _sparse_select(
+                    s1, qpos[:, None], blk, B // block)
+                picks = jnp.where(jnp.arange(ids.shape[-1])
+                                  < count[..., None], ids, -1)
+                # the chosen set as a mask over the blocks: the top-k ends
+                # at (vals[-1], ids[-1]), ties having gone to lower indices
+                sel = (score > vals[..., -1:]) | (
+                    (score == vals[..., -1:])
+                    & (jnp.arange(B // block) <= ids[..., -1:]))
+
+            def tile(kt, state):
+                acc, top, norm = state
+                kk, vv = (jax.lax.dynamic_slice_in_dim(t, kt * C, C)
+                          for t in (k, v))
+                seen = jnp.repeat(jax.lax.dynamic_slice_in_dim(
+                    sel, kt * tiles, tiles, axis=2), block, axis=2) & (
+                        (kt * C + jnp.arange(C))[None, None]
+                        <= qpos[:, None, None])     # [Q, Hc, C]
+                s = jnp.einsum("qhgd,khd->qhgk", qi, kk) * scale
+                s = jnp.where(seen[:, :, None], s, -1e30)
+                new = jnp.maximum(top, jnp.max(s, axis=-1))
+                alpha = jnp.exp(top - new)
+                p = jnp.exp(s - new[..., None]) * seen[:, :, None]
+                return (acc * alpha[..., None]
+                        + jnp.einsum("qhgk,khd->qhgd", p, vv), new,
+                        norm * alpha + jnp.sum(p, axis=-1))
+
+            with jax.named_scope("sparse_attention"):
+                acc, _, norm = jax.lax.fori_loop(
+                    0, c + 1, tile,
+                    (jnp.zeros((Q, Hc, G, Dh), jnp.float32),
+                     jnp.full((Q, Hc, G), -1e30, jnp.float32),
+                     jnp.zeros((Q, Hc, G), jnp.float32)))
+                return acc / jnp.maximum(norm, 1e-20)[..., None], picks
+
+        out, picks = jax.lax.map(one, (jnp.arange(C // Q), q.astype(
+            jnp.float32).reshape(C // Q, Q, Hc, G, Dh)))
+        return out.reshape(C, H, Dh), picks.reshape((C,) + picks.shape[2:])
+
     def _norm(self, x, state, name):
         """The block's norm over the last axis with the weights
         `name`_g (and `name`_b under layernorm)."""
@@ -1930,7 +2386,10 @@ class GenerativePredictor:
         the residual stream together; `ssm` = (the phase's scope, its
         `scan(xs, Bm, Cm, dt, A)`: the recurrence's outputs at the
         positions, from wherever the phase keeps the scanned state), and
-        `convolve` finds the mixer's conv its earlier inputs.  `live`
+        `convolve` finds the mixer's conv its earlier inputs.  A
+        LINEAR_ATTENTION layer (`_linear`) runs its recurrence through the
+        same `ssm` callback; a SPARSE_ATTENTION layer is an attention layer
+        whose phase hands it an `attend` that selects blocks.  `live`
         [tokens] marks the rows a routed FFN counts,
         and a list given as `picks` receives its chosen experts.
         Returns (x', routing facts [2] i32 or None).  Under TP each
@@ -1951,13 +2410,13 @@ class GenerativePredictor:
             return self._norm(y, state, p + name) \
                 if blk["sandwich_norm"] else y
 
-        def project(w, heads, gain=None):
+        def project(w, heads, gain=None, size=Dh):
             t = _mm(h if blk["attention_in_multiplier"] == 1.0
                     else h * blk["attention_in_multiplier"], state[p + w])
             if gain and blk["qk_norm"] is True:
                 # over the whole projection, before the split into heads
                 t = _rms(t, state[p + gain], blk["norm_eps"])
-            t = t.reshape(lead + (heads, Dh))
+            t = t.reshape(lead + (heads, size))
             if gain and blk["qk_norm"] == "head":
                 t = _rms(t, state[p + gain], blk["norm_eps"])
             return t
@@ -1970,6 +2429,10 @@ class GenerativePredictor:
                                     axis=-1)
                 x = x + joins(_mm(c * convolve(b * u, state[p + "conv_w"]),
                                   state[p + "conv_out"]), "ln1p")
+        elif op == "linear_attention":
+            with jax.named_scope("linear_attention"):
+                x = x + joins(self._linear(state, p, h, positions, project,
+                                           *ssm), "ln1p")
         else:
             Hkv = self._kv_heads() // tp.size
             with (jax.named_scope("gqa_attention") if Hkv != Hl
@@ -1980,12 +2443,14 @@ class GenerativePredictor:
                     k = k * blk["key_multiplier"]
                 if blk["position"] == "rope" and (
                         blk["rope_layers"] == "all"
-                        or op == "window_attention"):
+                        or (blk["rope_layers"], op) == (
+                            "window", "window_attention")):
                     q = _rope(q, positions, blk["rope_theta"])
                     k = _rope(k, positions, blk["rope_theta"])
-                att = tp.psum(_mm(
-                    attend(q, k, v).reshape(lead + (Hl * Dh,)),
-                    state[p + "wo"]))
+                mixed = attend(q, k, v).reshape(lead + (Hl * Dh,))
+                if blk["output_gate"]:
+                    mixed = mixed * jax.nn.sigmoid(_mm(h, state[p + "wg"]))
+                att = tp.psum(_mm(mixed, state[p + "wo"]))
                 if blk["attention_out_multiplier"] != 1.0:
                     att = att * blk["attention_out_multiplier"]
                 x = x + joins(att, "ln1p")
@@ -2078,6 +2543,50 @@ class GenerativePredictor:
             out = _mm(y, w["out"])
             return out if blk["ssm_out_multiplier"] == 1.0 \
                 else out * blk["ssm_out_multiplier"]
+
+    def _linear(self, state, p, h, positions, project, scope, scan):
+        """A linear_attention layer's operator on the normed input h [...,
+        D] (weights `state[p + name]`) -> [..., D], before the residual
+        sum, under the scope `linear_attention`:
+
+            q, k = (h wq, h wk) -> [Hs, Ns];  v = h wv -> [Hs, P]
+            q, k = rms a head (qk_norm), rotated (rope_layers all|linear)
+            o = scan(v, k, q / sqrt(Ns), dt = 1, A = linear_log_decay):
+                S_t = exp(A) S_{t-1} + v_t (outer) k_t,  o_t = S_t . q_t
+            o = rms(o) a head times on_g (output_norm), times
+                sigmoid(h wg) (output_gate)
+            result = (o wo) * attention_out_multiplier
+
+        `project` is `_block`'s (a projection split into heads, normed as
+        `qk_norm` says); `scan` is the phase's, the one a state-space
+        mixer's recurrence goes through (`_ssm`): a step of
+        `pallas_kernels.ssm_update` on the slots' scanned state,
+        `ssd_chunked_scan` over a prompt's chunk from the carried state;
+        `scope` (`ssm_update` | `ssm_scan`) is around the recurrence
+        alone."""
+        import jax
+        import jax.numpy as jnp
+        blk = self._block_meta
+        Hs, P, N, _ = (blk[k] for k in _SSM_DIMS)
+        lead = h.shape[:-1]
+        q, k, v = (project("wq", Hs, "qn_g", N), project("wk", Hs, "kn_g", N),
+                   project("wv", Hs, size=P))
+        if blk["position"] == "rope" and blk["rope_layers"] in ("all",
+                                                                "linear"):
+            q = _rope(q, positions, blk["rope_theta"])
+            k = _rope(k, positions, blk["rope_theta"])
+        with jax.named_scope(scope):
+            o = scan(v, k, q * np.float32(1.0 / np.sqrt(N)),
+                     jnp.ones(lead + (Hs,), jnp.float32),
+                     jnp.asarray(blk["linear_log_decay"], jnp.float32))
+        if blk["output_norm"]:
+            o = _rms(o, state[p + "on_g"].reshape(Hs, P), blk["norm_eps"])
+        o = o.reshape(lead + (Hs * P,))
+        if blk["output_gate"]:
+            o = o * jax.nn.sigmoid(_mm(h, state[p + "wg"]))
+        out = _mm(o, state[p + "wo"])
+        return out if blk["attention_out_multiplier"] == 1.0 \
+            else out * blk["attention_out_multiplier"]
 
     def _mla(self, state, p, h, positions, latent):
         """An MLA layer's operator on the normed input h [..., D] (weights
@@ -2309,8 +2818,11 @@ class GenerativePredictor:
         ring, an MLA layer's `latent` over its one table of latent rows),
         a conv layer's `convolve` the taps over the slot's conv state and
         the new input, which then roll into the state, a state-space
-        mixer's `scan` one step of the recurrence on every live slot's
-        whole scanned state.
+        mixer's (or a linear_attention layer's) `scan` one step of the
+        recurrence on every live slot's whole scanned state, a
+        sparse_attention layer's `attend` the write of the new row, of the
+        compressed key it completes, the selection and the sparse kernel
+        over the selected blocks.
 
         The tables are CARRIED through the layers and updated IN PLACE:
         attention layer i scatters its N new rows to (i, n, lengths[n])
@@ -2335,7 +2847,8 @@ class GenerativePredictor:
         import contextlib
         import jax
         import jax.numpy as jnp
-        from paddle_tpu.ops.pallas_kernels import ssm_update
+        from paddle_tpu.ops.pallas_kernels import (
+            sparse_decode_attention, ssm_update)
         L = self._dims()[0]
         # each layer's callback below reads and replaces its OWN leaves
         held = dict(zip(self._table_names, tables))
@@ -2368,6 +2881,29 @@ class GenerativePredictor:
                     return self._attend_table(q, held["kw"], held["vw"],
                                               lengths, 1, at, tp, window=W)
 
+            def attend_sparse(q, k_new, v_new, at=self._table_layer(i),
+                              ai=self._table_layer(i, "index")):
+                # the new row lands as an attention layer's; stage 1 picks
+                # the slot's blocks, stage 2 stages those alone
+                held["kc"], held["vc"] = self._write(
+                    held["kc"], held["vc"], at, where, k_new, v_new, tp)
+                with jax.named_scope("sparse_select"):
+                    held["ki"] = self._land_compressed(
+                        held["ki"], held["kc"], ai, at, lengths, active)
+                    ids, counts = self._step_select(q, held["ki"], ai,
+                                                    lengths)
+                if picks is not None:
+                    # the blocks chosen, -1 past their count
+                    picks.append(jnp.where(
+                        jnp.arange(ids.shape[-1]) < counts[..., None], ids,
+                        -1))
+                with jax.named_scope("sparse_attention"):
+                    return sparse_decode_attention(
+                        q, held["kc"], held["vc"], ids,
+                        jnp.where(active[:, None], counts, 0), lengths + 1,
+                        at, self._block_meta["sparse_block"],
+                        scale=1.0 / np.sqrt(q.shape[-1]))
+
             def convolve(z, taps, at=self._table_layer(i, "conv")):
                 # z [N, C]: the slot's K-1 kept inputs, then this one
                 cs = held["cs"]
@@ -2395,12 +2931,50 @@ class GenerativePredictor:
                                           lengths + 1, at, wkv_b)
 
             x, f = self._block(
-                state, i, x, lengths, attend_window
-                if self.layer_kinds[i][0] == "window_attention" else attend,
+                state, i, x, lengths,
+                {"window_attention": attend_window,
+                 "sparse_attention": attend_sparse}.get(
+                     self.layer_kinds[i][0], attend),
                 active, tp=tp, convolve=convolve, picks=picks, latent=latent,
                 ssm=("ssm_update", scan))
             facts.append(f)
         return self._head(state, x, tp), tuple(held.values()), facts
+
+    def _land_compressed(self, ki, kc, ai, at, lengths, active):
+        """The indexer's cache `ki` [sparse layers, N, J, W] with the
+        compressed key that COMPLETES at this step landed in layer `ai`:
+        slot n's new row is position lengths[n]; where that is the last
+        position of compressed key j (stride * j + size - 1), the key is
+        the mean of rows stride * j .. of layer `at` of the K table (the
+        new row among them, already written) and lands at row j; for any
+        other slot, and one that does not run, nowhere (`_land`)."""
+        import jax.numpy as jnp
+        blk = self._block_meta
+        size, stride = blk["sparse_kernel_size"], blk["sparse_kernel_stride"]
+        N, J = ki.shape[1:3]
+        first = lengths - (size - 1)
+        due = active & (first >= 0) & (first % stride == 0)
+        rows = kc[at, jnp.arange(N)[:, None],
+                  jnp.maximum(first, 0)[:, None] + jnp.arange(size)[None]]
+        return _land(ki, ai, (jnp.arange(N), jnp.where(
+            due, first // stride, J).astype(jnp.int32)),
+            _compressed_keys(rows, blk)[:, 0])
+
+    def _step_select(self, q, ki, ai, lengths):
+        """Stage 1 for the step's one query a slot: q [N, H, Dh] at
+        position lengths[n] against layer `ai` of the indexer's cache ->
+        (block ids [N, Hc, k], counts [N, Hc]) (`_sparse_select`)."""
+        import jax.numpy as jnp
+        N, H, Dh = q.shape
+        Hc = self._kv_heads()
+        s1 = jnp.einsum(
+            "nhgd,njhd->nhgj", q.reshape(N, Hc, H // Hc, Dh),
+            ki[ai].reshape(N, -1, Hc, Dh),
+            precision="highest") * np.float32(1.0 / np.sqrt(Dh))
+        ids, counts, _, _ = _sparse_select(
+            s1, lengths[:, None], self._block_meta,
+            self.max_seq_len // self._block_meta["sparse_block"])
+        return ids, counts
 
     def _verify_math(self, state, kc, vc, lengths, tokens, active,
                      tp=_OFF_MESH):
@@ -2644,7 +3218,9 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (10:
+            # rev bumps when the phase math itself changes shape (11: a
+            # prefill in chunks hands out the blocks its last position
+            # selected behind its token; 10:
             # the state-space step's recurrence is one Mosaic call,
             # `pallas_kernels.ssm_update`; 9: a
             # window runs past its first ender, a slot that stops sits
@@ -2662,7 +3238,7 @@ class GenerativePredictor:
             # and routing): equal weight shapes, another function
             "block": [[k, self._block_meta[k]]
                       for k in sorted(self._block_meta)],
-            "rev": 10,
+            "rev": 11,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -3088,6 +3664,9 @@ class DecodeSession:
         if predictor._block_meta["experts_held"]:
             self._stack_attrs["moe_experts_held"] = \
                 predictor._block_meta["experts_held"][1]
+        if "linear_attention" in predictor._block_meta["layer_types"]:
+            self._stack_attrs["linear_layers"] = predictor._block_meta[
+                "layer_types"].count("linear_attention")
         if self._ss is not None:
             # what a STEP's fetch span says of its recurrence: the one-pass
             # Mosaic call, or XLA's form where the TPU's tiles do not hold
@@ -3129,6 +3708,7 @@ class DecodeSession:
         # a hybrid routed stack's newest `decode_logits`: the experts
         # each routed layer chose [routed layers, n_slots, k]
         self.last_picks = None
+        self.last_prefill_picks = None
         self._n_routed = predictor.routed_layers
         # the `decode/put` / `decode/launch` spans of a call whose
         # results are not fetched yet (`_call`, `_fetch`)
@@ -3319,8 +3899,16 @@ class DecodeSession:
             chunk = min(self.predictor._block_meta["ssm_chunk"], int(bucket))
             scanned = {"bucket": int(bucket),
                        "ssm_chunks": -(-int(bucket) // chunk)}
-        tok = int(self._fetch("prefill", first, routed=True,
-                              more=scanned)[0].reshape(-1)[0])
+        chunks = self.predictor.prefill_chunks(n)
+        if chunks:
+            scanned["chunks"] = chunks
+        first = self._fetch("prefill", first, routed=True,
+                            more=scanned)[0].reshape(-1)
+        tok = int(first[0])
+        if self._ki is not None:
+            # [sparse layers, K/V heads, k]: `_prefill_core`
+            self.last_prefill_picks = first[1:].reshape(
+                self._ki.shape[0], self.predictor._kv_heads(), -1)
         self.lengths[slot] = n
         self.last_tokens[slot] = tok
         self.active[slot] = True
@@ -3372,10 +3960,14 @@ class DecodeSession:
         `kv_blocks_total` what whole rows would be (trips x slots x
         layers x S / block); a window layer's call counts among both, a
         ring being its whole row."""
+        trip = np.arange(trips)[:, None]
+        if self._ki is not None:
+            return self._sparse_stream(
+                np.where(trip < counts[None], self.lengths[None] + trip + 1,
+                         0), trips)
         if not self._kv_block:
             return {}
         from paddle_tpu.ops.pallas_kernels import kv_last_block
-        trip = np.arange(trips)[:, None]
         seen = np.where(trip < counts[None], self.lengths[None] + trip, 0) + 1
         out = {"kv_blocks_live": 0, "kv_blocks_total": 0}
         edges = {"full": self._kv_block, "window": self._ring_block}
@@ -3393,6 +3985,31 @@ class DecodeSession:
             out["kv_blocks_live"] += int(live.sum()) * layers
             out["kv_blocks_total"] += trips * self.n_slots * layers * n_blocks
         return out
+
+    def _sparse_stream(self, seen, trips):
+        """`_kv_stream` of a stack with sparse_attention layers, whose
+        kernel stages the SELECTED blocks: `seen` [trips, N] the positions
+        a slot attends under in a trip (0: it does not run, and is not
+        visited).  A running slot's K/V head stages min(sparse_topk, the
+        blocks in sight) tiles of `sparse_block` rows in each sparse layer
+        (`kv_blocks_live`, of `kv_blocks_total` = every block of every
+        slot's row); `selected_blocks` is that count a trip,
+        `selected_rows` / `rows_in_sight` the positions attended over those
+        a dense layer would read (the selection always holds the slot's own
+        last block, the only partial one)."""
+        blk = self.predictor._block_meta
+        block, topk = blk["sparse_block"], blk["sparse_topk"]
+        heads = self.predictor._kv_heads() * self._ki.shape[0]
+        in_sight = -(-seen // block)
+        chosen = np.minimum(in_sight, topk)
+        rows = np.where(in_sight <= topk, seen,
+                        (topk - 1) * block + seen - (in_sight - 1) * block)
+        return {"kv_blocks_live": int(chosen.sum()) * heads,
+                "kv_blocks_total": trips * self.n_slots * heads
+                * (self._kc.shape[2] // block),
+                "selected_blocks": int(chosen.sum()) * heads // max(trips, 1),
+                "selected_rows": int(rows.sum()),
+                "rows_in_sight": int(seen.sum())}
 
     def _fetch(self, phase, *outs, routed=False, trips_at=None, more=None):
         """`np.asarray` of each result: the wait for the device and the

@@ -1,13 +1,13 @@
 """The kinds of state a decode session holds for a slot: ONE record a kind.
 
 A decode session (`decode.DecodeSession`) keeps, for each of its slots, up
-to six device arrays, the LEAVES `kc`, `vc`, `cs`, `ss`, `kw`, `vw`, of
-five KINDS (`KINDS`, in the order every phase takes and returns them).
+to seven device arrays, the LEAVES `kc`, `vc`, `cs`, `ss`, `kw`, `vw`, `ki`,
+of six KINDS (`KINDS`, in the order every phase takes and returns them).
 Which kinds a stack holds follows from its layers' operators (`HOLDS`);
 everything else that has to be known of a kind is a field of its entry
 (`Kind`): the predictor's specs, the session's allocation and release,
 the byte accounting, the resource analysis and the refusals all read it
-from here.  A sixth kind is an entry here and the `attend` / `convolve` /
+from here.  A seventh kind is an entry here and the `attend` / `convolve` /
 `scan` callbacks of its layer in the phases (`decode._step_core`,
 `decode._prefill_layers`), nothing else.
 
@@ -37,7 +37,13 @@ CAPABILITIES = ("rollback", "mesh", "speculative", "int8")
 # the kinds of slot state a layer's operator keeps
 HOLDS = {"attention": ("kv",), "conv": ("conv",), "mla": ("latent",),
          "attention+ssm": ("kv", "conv", "ssm"),
-         "window_attention": ("ring",)}
+         "window_attention": ("ring",),
+         # block-sparse attention: every position's K and V row, as an
+         # attention layer's, and the INDEXER's compressed keys beside them
+         "sparse_attention": ("kv", "index"),
+         # linear attention: its running sum of k (outer) v, decayed a
+         # head, IS a scanned state (`_scanned`), with no conv in front
+         "linear_attention": ("ssm",)}
 
 # name, leaves  what the kind and its device arrays are called, in order
 # slot          (meta, blk, device) -> what ONE slot holds of it in a layer:
@@ -132,7 +138,10 @@ def _conv_window(meta, blk, device):
 def _scanned(meta, blk, device):
     """(ssm_heads, ssm_head_dim, ssm_state): the SCANNED state of an
     attention+ssm layer, a decayed running sum over all the slot's
-    positions, a fixed size, read and rewritten whole by every token."""
+    positions, a fixed size, read and rewritten whole by every token.  A
+    linear_attention layer's state is the same thing, S = decay S + v
+    (outer) k a head (ssm_heads heads of ssm_head_dim values by ssm_state
+    key features, one group a head), so it rides this kind."""
     return tuple(blk[k] for k in ("ssm_heads", "ssm_head_dim", "ssm_state"))
 
 
@@ -147,12 +156,32 @@ def _ring(meta, blk, device):
     return blk["sliding_window"], _kv_rows(meta, blk, device)[1]
 
 
+def index_rows(max_seq_len, blk):
+    """Compressed keys a slot of `max_seq_len` positions can hold: key j
+    covers positions stride * j .. stride * j + size - 1 and exists once
+    the last of them is cached."""
+    return max((int(max_seq_len) - blk["sparse_kernel_size"])
+               // blk["sparse_kernel_stride"] + 1, 1)
+
+
+def _index_rows(meta, blk, device):
+    """(J, Hc * Dh): the INDEXER's cache of a sparse_attention layer, one
+    COMPRESSED key (the mean of `sparse_kernel_size` consecutive keys a
+    K/V head, every `sparse_kernel_stride` positions) a flat row, addressed
+    by position // stride and not by the position: row j is written when
+    position stride * j + size - 1 lands and read by stage 1 of every later
+    token (`decode._sparse_select`)."""
+    return (index_rows(meta["max_seq_len"], blk),
+            _kv_rows(meta, blk, device)[1])
+
+
 # (undoing either would take a snapshot, which no phase keeps)
 _RECURRENT = (
     "a conv window is the layer's last inputs, rolled by every token, a "
-    "scanned state a decayed sum over all of a slot's positions: moving a "
-    "slot's length back undoes neither, and they are neither sharded by "
-    "heads nor scaled a head")
+    "scanned state (a state-space layer's, or a linear-attention layer's "
+    "running sum of k (outer) v) a decayed sum over all of a slot's "
+    "positions: moving a slot's length back undoes neither, and they are "
+    "neither sharded by heads nor scaled a head")
 KINDS = (
     Kind("kv", ("kc", "vc"), _kv_rows, "K and V tables of per-head rows", "",
          "kv_cache_bytes", attrs=(), cached=True, by_length=True,
@@ -186,6 +215,16 @@ KINDS = (
                 ("full_kv_bytes", "kv", "bytes"),
                 ("window_kv_bytes", "ring", "bytes")),
          per_head=True, live="window"),
+    Kind("index", ("ki",), _index_rows, "an indexer's compressed-key cache",
+         "a sparse_attention layer keeps one compressed key every "
+         "%(sparse_kernel_stride)d positions, the mean of "
+         "%(sparse_kernel_size)d keys: a key that covers positions taken "
+         "back would have to be recomputed, the speculative phases' calls "
+         "of the decode kernel select no blocks, and the cache is neither "
+         "sharded by heads nor scaled a head",
+         "kv_cache_bytes",
+         attrs=(("sparse_layers", "index", "layers"),
+                ("index_cache_bytes", "index", "bytes"))),
 )
 # every leaf a session may hold (`DecodeSession` keeps each as `_<leaf>`,
 # None where its stack has none)

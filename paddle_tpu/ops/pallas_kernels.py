@@ -47,7 +47,8 @@ __all__ = ["tiled_contraction", "flash_attention", "decode_attention",
            "fused_bottleneck",
            "bottleneck_reference", "dequant_matmul",
            "dequant_matmul_reference", "mosaic_lowering", "ssm_update",
-           "ssm_update_reference", "ssm_update_block_heads"]
+           "ssm_update_reference", "ssm_update_block_heads",
+           "sparse_decode_attention", "sparse_decode_attention_reference"]
 
 # Finite mask value (not -inf): exp(_NEG_INF - finite) underflows to an
 # exact 0, and the logsumexp of a fully-masked row stays finite, so the
@@ -1317,6 +1318,179 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
         decay.astype(jnp.float32).reshape(N * Hs), ss,
         dtx.astype(jnp.float32), Bm.astype(jnp.float32)[:, :, None],
         Cm.astype(jnp.float32)[:, :, None])
+
+
+def sparse_decode_attention_reference(q, k_cache, v_cache, block_ids,
+                                      counts, lengths, block, scale=None):
+    """Plain-XLA oracle/fallback of `sparse_decode_attention`, the same
+    masking: q [N, H, D], caches [N, S, Hc * D] (ONE layer's), block_ids
+    [N, Hc, K] i32 of which the first counts[n, g] are slot n's SELECTED
+    blocks of `block` positions for K/V head g, lengths [N] -> [N, H, D]:
+    query head a attends over the positions j < lengths[n] of the selected
+    blocks of its K/V head a // (H / Hc); a (slot, K/V head) with count 0
+    reads zeros."""
+    import jax.numpy as jnp
+    N, S = k_cache.shape[:2]
+    H, D = q.shape[1:]
+    Hc = k_cache.shape[2] // D
+    G, K = H // Hc, block_ids.shape[-1]
+    scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
+    kept = jnp.arange(K)[None, None] < counts[..., None]          # [N,Hc,K]
+    sel = jnp.any((block_ids[..., None] == jnp.arange(S // block))
+                  & kept[..., None], axis=2)                      # [N,Hc,NB]
+    mask = jnp.repeat(sel, block, axis=-1) & (
+        jnp.arange(S)[None, None] < jnp.asarray(lengths)[:, None, None])
+    k, v = (t.astype(jnp.float32).reshape(N, S, Hc, D)
+            for t in (k_cache, v_cache))
+    s = jnp.einsum("nhgd,nshd->nhgs",
+                   q.astype(jnp.float32).reshape(N, Hc, G, D), k,
+                   precision="highest") * scale
+    s = jnp.where(mask[:, :, None], s, _NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)) * mask[:, :, None]
+    o = jnp.einsum("nhgs,nshd->nhgd", p, v, precision="highest") \
+        / jnp.maximum(jnp.sum(p, axis=-1), _TINY)[..., None]
+    return o.reshape(N, H, D).astype(q.dtype)
+
+
+def sparse_decode_attention(q, k_cache, v_cache, block_ids, counts, lengths,
+                            layer, block, scale=None, interpret=None):
+    """Block-sparse slot-cache decode attention: `decode_attention`'s
+    online-softmax tile over a slot's SELECTED blocks only.  q [N, H, D]
+    (the one new token of each slot), k_cache / v_cache the STACKED slot
+    tables [L, N, S, Hc * D] fp32 (`inference/slot_state.py`, the kind
+    `kv`; `layer` a static int, reached through the index maps), block_ids
+    [N, Hc, K] i32: the first counts[n, g] entries are the blocks of
+    `block` positions that slot n's queries of K/V head g attend over (the
+    indexer's choice: `inference/decode.py::_sparse_select`), lengths [N]
+    the slot's live positions (the slot's own last block is masked by it)
+    -> [N, H, D] in q's dtype.
+
+    THE GRID is (slot, K/V head, K).  Step j of (n, g) stages ONE tile
+    [block, D] of K and of V: rows block_ids[n, g, j] * block .. of the
+    slot, the 128 lanes of head g out of the flat row (the lane block IS
+    the head, so the G = H / Hc query heads of a K/V head contract against
+    its tile as they are: scores [G, block], values [G, D], no block
+    diagonal), both contractions at Precision.HIGHEST as `decode_attention`'s.
+    The ids, the counts and the lengths are scalar-prefetch operands in the
+    index maps: what is not selected is neither copied nor computed, so a
+    slot costs min(K, blocks in sight) tiles a K/V head whatever its
+    length.  Past its count a (slot, head)'s index map repeats its last
+    block (no copy) and the body is gated off.
+
+    A (SLOT, HEAD) WITH COUNT 0 IS NOT VISITED (an idle slot, one that
+    has stopped): its index map repeats the tile staged last before it
+    (for those ahead of the first running one, that one's first tile,
+    staged early), its body never runs, and its result is zeros.
+
+    `name="sparse_decode_attention"` and a non-empty `metadata`, as
+    `ssm_update` carries and for its reason; interpret emulation off the
+    TPU; the reference where the tiles are not whole (D not a multiple of
+    128, `block` not of 8)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, H, D = q.shape
+    L, _, S, W = k_cache.shape
+    Hc, K = W // D, block_ids.shape[-1]
+    G = H // Hc
+    if k_cache.shape[1] != N or Hc * D != W or G * Hc != H or S % block \
+            or block_ids.shape != (N, Hc, K) or counts.shape != (N, Hc):
+        raise ValueError(
+            "sparse_decode_attention: stacked tables [L, N, S, Hc * D] %s "
+            "go with q [N, H, D] %s, block_ids [N, Hc, K] %s, counts "
+            "[N, Hc] %s and a block (%d) that divides S"
+            % (tuple(k_cache.shape), tuple(q.shape),
+               tuple(block_ids.shape), tuple(counts.shape), block))
+    layer = int(layer)
+    scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
+    lengths = jnp.asarray(lengths).astype(jnp.int32).reshape(N)
+    counts = jnp.asarray(counts).astype(jnp.int32)
+    if interpret is None:
+        interpret = not lowering_for_tpu()
+    if not interpret and (D % 128 or block % 8):
+        return sparse_decode_attention_reference(
+            q, k_cache[layer], v_cache[layer], block_ids, counts, lengths,
+            block, scale)
+    # which tile every grid step stages, worked out once a call: row f =
+    # (slot, head) walks its own ids and then repeats its last; a row that
+    # is not visited repeats the last tile of the nearest visited row
+    # before it (or the first tile of the first visited one)
+    cnt = counts.reshape(N * Hc)
+    rows = jnp.arange(N * Hc, dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(cnt > 0, rows, -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(cnt > 0)).astype(
+        jnp.int32)
+    at = jnp.where((cnt > 0)[:, None],
+                   jnp.minimum(jnp.arange(K)[None], cnt[:, None] - 1),
+                   jnp.where(before >= 0, cnt[src] - 1, 0)[:, None])
+    stage = jnp.take_along_axis(
+        block_ids.reshape(N * Hc, K).astype(jnp.int32)[src],
+        jnp.maximum(at, 0), axis=1).reshape(-1)
+
+    def kv_map(b, g, j, stage_ref, src_ref, cnt_ref, len_ref):
+        f = b * Hc + g
+        return (layer, src_ref[f] // Hc, stage_ref[f * K + j],
+                src_ref[f] % Hc)
+
+    kv_spec = pl.BlockSpec((None, None, block, D), kv_map)
+    q_spec = pl.BlockSpec((None, None, G, D),
+                          lambda b, g, j, *_: (b, g, 0, 0))
+    contract = functools.partial(
+        jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+    def kern(stage_ref, src_ref, cnt_ref, len_ref, q_ref, k_ref, v_ref,
+             o_ref, acc_ref, m_ref, l_ref):
+        b, g, j = (pl.program_id(i) for i in range(3))
+        f = b * Hc + g
+
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        @pl.when(j < cnt_ref[f])
+        def _tile():
+            kb = k_ref[...].astype(jnp.float32)            # [block, D]
+            vb = v_ref[...].astype(jnp.float32)
+            s = contract(q_ref[...].astype(jnp.float32), kb,
+                         (((1,), (1,)), ((), ()))) * scale  # [G, block]
+            kpos = stage_ref[f * K + j] * block \
+                + jax.lax.broadcasted_iota(jnp.int32, (G, block), 1)
+            s = jnp.where(kpos >= len_ref[b], _NEG_INF, s)
+            _online_softmax_tile(
+                s, lambda p: contract(p, vb, (((1,), (0,)), ((), ()))),
+                acc_ref, m_ref, l_ref)
+
+        @pl.when(j == K - 1)
+        def _finalize():
+            o, _ = _softmax_finalize(acc_ref, m_ref, l_ref)
+            o_ref[...] = o.astype(o_ref.dtype)
+
+    _kernel_metadata_on_one_line()
+
+    def call(interp, *ops):
+        return pl.pallas_call(
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(N, Hc, K),
+                in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+                scratch_shapes=[pltpu.VMEM((G, D), jnp.float32),
+                                pltpu.VMEM((G, _MIN_LANES), jnp.float32),
+                                pltpu.VMEM((G, _MIN_LANES), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((N, Hc, G, D), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 3),
+            name="sparse_decode_attention",
+            metadata={"kernel": "sparse_decode_attention"},
+            interpret=interp)(*ops)
+
+    out = _interpret_dispatch(call, interpret, stage, src, cnt, lengths,
+                              q.reshape(N, Hc, G, D), k_cache, v_cache)
+    return out.reshape(N, H, D)
 
 
 # ---------------------------------------------------------------------------

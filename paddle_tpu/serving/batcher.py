@@ -1550,13 +1550,16 @@ class DecodeBatcher:
             return
         sess = lane.session
         slot = sess.free_slots()[0]
+        # a stack that prefills in chunks says how many this prompt takes
+        chunks = sess.predictor.prefill_chunks(len(req.prompt))
         try:
             with obs_tracing.trace("serving/prefill_compute",
                                    kind="serving", trace_id=req.trace_id,
                                    parent="serving/lane_iter",
                                    model=self._model_name,
                                    replica=lane.index,
-                                   prompt=len(req.prompt)):
+                                   prompt=len(req.prompt),
+                                   **({"chunks": chunks} if chunks else {})):
                 first = sess.prefill(slot, req.prompt)
         except BaseException as e:
             self._finish(lane, None, req, "error", exc=e)

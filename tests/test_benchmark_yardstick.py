@@ -88,12 +88,16 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       "falconh1_decode_saturated",
                       # PR 44's cell: its window layers' calls over their
                       # rings are counted beside the full layer's
-                      "kexaone_decode_mixed_len"]}
+                      "kexaone_decode_mixed_len",
+                      # PR 48's cell: the blocks its sparse kernel STAGES
+                      # (the selected ones, a K/V head) of every block of
+                      # the slots' rows: the selection, not the length
+                      "minicpmsala_longdoc_mixed"]}
     # appended, not inserted: only PR 35's five readers, PR 38's one,
-    # PR 39's nine, PR 42's six, PR 44's five and PR 45's one stand behind
-    # it
+    # PR 39's nine, PR 42's six, PR 44's five, PR 45's one and PR 48's
+    # eight stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 28
+        manifest["per_layer"]) - 36
 
 
 # ---------------------------------------------------------------------------
@@ -137,9 +141,9 @@ def test_decode_early_launch_share_reader(case, spans, want):
 
 def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # PR 39's nine readers, PR 42's six, PR 44's five and PR 45's one stand
-    # behind it
-    assert manifest["per_layer"][-22] == {
+    # PR 39's nine readers, PR 42's six, PR 44's five, PR 45's one and
+    # PR 48's eight stand behind it
+    assert manifest["per_layer"][-30] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -147,7 +151,10 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       "olmoe_decode_saturated", "lfm2_decode_saturated",
                       "pangu_decode_saturated",
                       "falconh1_decode_saturated",
-                      "kexaone_decode_mixed_len"]}
+                      "kexaone_decode_mixed_len",
+                      # PR 48's cell: a dispatch launched ahead of the
+                      # delivery before it, as in every lane
+                      "minicpmsala_longdoc_mixed"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-22]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-30]["workloads"] == e2e["workloads"]

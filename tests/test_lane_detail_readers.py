@@ -41,7 +41,10 @@ DECODE_CELLS = ["gpt2s_decode_saturated", "gpt2s_decode_deep",
                 # PR 42's cell joins every list that holds the five
                 "falconh1_decode_saturated",
                 # ... and PR 44's every list that holds the six
-                "kexaone_decode_mixed_len"]
+                "kexaone_decode_mixed_len",
+                # ... and PR 48's every list that holds the seven: the
+                # lane's spans are the same whatever the stack's layers
+                "minicpmsala_longdoc_mixed"]
 
 
 def reader(name):
@@ -257,7 +260,8 @@ def test_token_out_frames_per_pass_is_declared_for_the_decode_cells():
                  "better": "higher", "source": "program_span",
                  "layer": "serving front", "moves": "tokens_per_s",
                  "workloads": DECODE_CELLS}
-    assert manifest["per_layer"][-1] is m
+    # (last but for the eight readers PR 48 appended behind it)
+    assert manifest["per_layer"][-9] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
 
@@ -298,9 +302,9 @@ def test_spans_older_than_the_phase_spans_read_as_nothing(name):
 
 def test_the_nine_are_declared_last_for_the_five_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # (last but for the six readers PR 42, the five PR 44 and the one
-    # PR 45 appended behind them)
-    last = manifest["per_layer"][-21:-12]
+    # (last but for the six readers PR 42, the five PR 44, the one PR 45
+    # and the eight PR 48 appended behind them)
+    last = manifest["per_layer"][-29:-20]
     assert [m["name"] for m in last] == list(NINE)
     for m in last:
         assert m["workloads"] == DECODE_CELLS, m["name"]

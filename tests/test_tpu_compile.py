@@ -662,3 +662,65 @@ def test_state_space_step_updates_its_three_kinds_of_state_in_place(
             "reduce", "dynamic-update-slice", "dynamic-slice")]
     assert not made, made[:8]
     assert text.count(" while(") == 1
+
+
+def test_sparse_linear_step_and_chunked_prefill_compile_for_the_chip(
+        one_chip):
+    """The step window of `minicpm_sala_9b` at the cell's 24 slots and a
+    slot of 32,768 rows, bfloat16 weights at rest: the K/V rows, the linear
+    layers' states and the indexer's cache are donated and aliased, a sparse
+    layer makes ONE Mosaic call (`sparse_decode_attention`, under the scopes
+    `sparse_attention`, beside stage 1's plain XLA under `sparse_select`)
+    and a linear layer one (`ssm_update`), each with metadata of its own,
+    and no table- or layer-sized copy is made.  And the prefill of the
+    largest bucket, 24,576 positions in twelve chunks inside ONE executable:
+    its temporaries are a chunk's (under 2 GB where the bucket's [T, 16384]
+    gate and up alone would be 3.2), with no Mosaic call."""
+    import json
+    import os
+    from benchmark import moe_trace
+    from paddle_tpu.inference import decode as dec
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "minicpm_sala_9b.json")) as f:
+        cfg = json.load(f)
+    meta, slots = cfg["model"], cfg["deployment"]["decode_slots"]
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(meta, device)
+    state = {n: jax.ShapeDtypeStruct(
+        s.shape, jnp.bfloat16 if dec._bf16_at_rest(n, s) else np.float32,
+        sharding=s.sharding) for n, s in state.items()}
+    assert pred._table_names == ("kc", "vc", "ss", "ki")
+    compiled = compile_phase(pred, state, pred._step_math(),
+                             pred._step_specs(slots), tables=range(4))
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    assert not [c for c in calls if "kernel_metadata={}" in c]
+    sparse = [c for c in calls if "sparse_decode_attention" in c]
+    linear = [c for c in calls if "ssm_update" in c]
+    assert (len(sparse), len(linear), len(calls)) == (2, 6, 8)
+    for scope, named in (("sparse_attention", sparse),
+                         ("ssm_update", linear),
+                         ("linear_attention", linear)):
+        assert {c.split(" = ")[0].strip().lstrip("%") for c in named} \
+            <= moe_trace.scope_instruction_names(text, scope), scope
+    assert moe_trace.scope_instruction_names(text, "sparse_select")
+    ma = compiled.memory_analysis()
+    held = pred.kv_cache_bytes(slots)
+    assert held == cfg["deployment"]["kv_table_bytes"] \
+        + cfg["deployment"]["ssm_state_table_bytes"] \
+        + cfg["deployment"]["index_table_bytes"]
+    assert ma.alias_size_in_bytes >= held, (ma.alias_size_in_bytes, held)
+    assert ma.temp_size_in_bytes < 0.1e9, ma.temp_size_in_bytes
+    assert text.count(" while(") == 1
+    bucket = max(meta["prefill_buckets"])
+    compiled = compile_phase(
+        pred, state, pred._prefill_math,
+        (jax.ShapeDtypeStruct((1, bucket), np.int32),
+         jax.ShapeDtypeStruct((), np.int32)), tables=())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    text = compiled.as_text()
+    assert not _custom_calls(text)
+    for scope in ("sparse_select", "sparse_attention", "linear_attention",
+                  "ssm_scan"):
+        assert moe_trace.scope_instruction_names(text, scope), scope

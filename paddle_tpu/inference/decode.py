@@ -3218,7 +3218,9 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (11: a
+            # rev bumps when the phase math itself changes shape (12: the
+            # sparse decode kernel stages T selected tiles a grid step,
+            # `pallas_kernels.sparse_tiles_per_step`; 11: a
             # prefill in chunks hands out the blocks its last position
             # selected behind its token; 10:
             # the state-space step's recurrence is one Mosaic call,
@@ -3238,7 +3240,7 @@ class GenerativePredictor:
             # and routing): equal weight shapes, another function
             "block": [[k, self._block_meta[k]]
                       for k in sorted(self._block_meta)],
-            "rev": 11,
+            "rev": 12,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -3996,7 +3998,11 @@ class DecodeSession:
         slot's row); `selected_blocks` is that count a trip,
         `selected_rows` / `rows_in_sight` the positions attended over those
         a dense layer would read (the selection always holds the slot's own
-        last block, the only partial one)."""
+        last block, the only partial one).  `kv_grid_steps` are the grid
+        steps the kernel staged them in, T tiles a step
+        (`pallas_kernels.sparse_tiles_per_step`): ceil(chosen / T) a
+        running slot's K/V head a sparse layer a trip."""
+        from paddle_tpu.ops.pallas_kernels import sparse_tiles_per_step
         blk = self.predictor._block_meta
         block, topk = blk["sparse_block"], blk["sparse_topk"]
         heads = self.predictor._kv_heads() * self._ki.shape[0]
@@ -4004,7 +4010,12 @@ class DecodeSession:
         chosen = np.minimum(in_sight, topk)
         rows = np.where(in_sight <= topk, seen,
                         (topk - 1) * block + seen - (in_sight - 1) * block)
+        per_step = sparse_tiles_per_step(
+            min(topk, self._kc.shape[2] // block), block,
+            self._kc.shape[3] // self.predictor._kv_heads(),
+            self._kc.dtype.itemsize)
         return {"kv_blocks_live": int(chosen.sum()) * heads,
+                "kv_grid_steps": int((-(-chosen // per_step)).sum()) * heads,
                 "kv_blocks_total": trips * self.n_slots * heads
                 * (self._kc.shape[2] // block),
                 "selected_blocks": int(chosen.sum()) * heads // max(trips, 1),

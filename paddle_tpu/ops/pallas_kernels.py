@@ -48,7 +48,8 @@ __all__ = ["tiled_contraction", "flash_attention", "decode_attention",
            "bottleneck_reference", "dequant_matmul",
            "dequant_matmul_reference", "mosaic_lowering", "ssm_update",
            "ssm_update_reference", "ssm_update_block_heads",
-           "sparse_decode_attention", "sparse_decode_attention_reference"]
+           "sparse_decode_attention", "sparse_decode_attention_reference",
+           "sparse_tiles_per_step"]
 
 # Finite mask value (not -inf): exp(_NEG_INF - finite) underflows to an
 # exact 0, and the logsumexp of a fully-masked row stays finite, so the
@@ -1320,6 +1321,25 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
         Cm.astype(jnp.float32)[:, :, None])
 
 
+# a grid step of `sparse_decode_attention` stages T tiles of K and T of V
+# into a two-deep scratch: 4 T tiles lie in VMEM, under this budget
+_SPARSE_STAGE_BYTES = 4 * 1024 * 1024
+
+
+def sparse_tiles_per_step(topk, block, D, itemsize=4):
+    """T, the selected tiles [block, D] of K (and of V) that ONE grid step
+    of `sparse_decode_attention` stages: the largest power of two that is
+    no more than the `topk` blocks a (slot, head) selects and whose 4 T
+    tiles (K and V, two deep) fit `_SPARSE_STAGE_BYTES` of VMEM.  Worked
+    out from the shapes alone; the kernel and the step's counter
+    (`DecodeSession._sparse_stream`: `kv_grid_steps`) both read it here."""
+    fit = _SPARSE_STAGE_BYTES // (4 * block * D * itemsize)
+    T = 1
+    while 2 * T <= min(fit, topk):
+        T *= 2
+    return T
+
+
 def sparse_decode_attention_reference(q, k_cache, v_cache, block_ids,
                                       counts, lengths, block, scale=None):
     """Plain-XLA oracle/fallback of `sparse_decode_attention`, the same
@@ -1355,32 +1375,42 @@ def sparse_decode_attention_reference(q, k_cache, v_cache, block_ids,
 def sparse_decode_attention(q, k_cache, v_cache, block_ids, counts, lengths,
                             layer, block, scale=None, interpret=None):
     """Block-sparse slot-cache decode attention: `decode_attention`'s
-    online-softmax tile over a slot's SELECTED blocks only.  q [N, H, D]
-    (the one new token of each slot), k_cache / v_cache the STACKED slot
-    tables [L, N, S, Hc * D] fp32 (`inference/slot_state.py`, the kind
-    `kv`; `layer` a static int, reached through the index maps), block_ids
+    online-softmax tile over a slot's SELECTED blocks only, several of them
+    a grid step.  q [N, H, D] (the one new token of each slot), k_cache /
+    v_cache the STACKED slot tables [L, N, S, Hc * D] fp32
+    (`inference/slot_state.py`, the kind `kv`; `layer` a static int, which
+    the kernel's copies address), block_ids
     [N, Hc, K] i32: the first counts[n, g] entries are the blocks of
     `block` positions that slot n's queries of K/V head g attend over (the
     indexer's choice: `inference/decode.py::_sparse_select`), lengths [N]
     the slot's live positions (the slot's own last block is masked by it)
     -> [N, H, D] in q's dtype.
 
-    THE GRID is (slot, K/V head, K).  Step j of (n, g) stages ONE tile
-    [block, D] of K and of V: rows block_ids[n, g, j] * block .. of the
-    slot, the 128 lanes of head g out of the flat row (the lane block IS
-    the head, so the G = H / Hc query heads of a K/V head contract against
-    its tile as they are: scores [G, block], values [G, D], no block
-    diagonal), both contractions at Precision.HIGHEST as `decode_attention`'s.
-    The ids, the counts and the lengths are scalar-prefetch operands in the
-    index maps: what is not selected is neither copied nor computed, so a
-    slot costs min(K, blocks in sight) tiles a K/V head whatever its
-    length.  Past its count a (slot, head)'s index map repeats its last
-    block (no copy) and the body is gated off.
+    THE GRID is (slot, K/V head, ceil(K / T)).  Step j of (n, g) attends
+    over T tiles [block, D] of K and T of V (T = `sparse_tiles_per_step`,
+    from the shapes; a grid step costs ~0.5 us on a v5e whatever it moves,
+    which one 32 KB tile a step paid 3,072 times a layer): tile t is rows
+    block_ids[n, g, j T + t] * block .. of the slot, the 128 lanes of head g
+    out of the flat row (the lane block IS the head, so the G = H / Hc
+    query heads of a K/V head contract against its tiles as they are, no
+    block diagonal).  The stacked tables stay in HBM (`pl.ANY`) and the
+    kernel copies a step's tiles itself, one under another into one half of
+    a two-deep scratch [2, T block, D], started a VISITED step ahead (the
+    tiles' numbers, the counts and the order of the visited steps are
+    scalar-prefetch operands), so the body makes ONE pair of contractions
+    (scores [G, T block], values [G, D], both at Precision.HIGHEST as
+    `decode_attention`'s) and ONE online-softmax update a step.  What is
+    not selected is neither copied nor computed, so a slot costs min(K,
+    blocks in sight) tiles a K/V head whatever its length: a tile past
+    its (slot, head)'s count starts no copy, and its columns (what the
+    scratch held before) are masked, as the positions at and past the
+    slot's length are, by a row of column positions worked out beside the
+    call (the table's rows for a tile past the count: past any length).  K
+    need not be a multiple of T.
 
     A (SLOT, HEAD) WITH COUNT 0 IS NOT VISITED (an idle slot, one that
-    has stopped): its index map repeats the tile staged last before it
-    (for those ahead of the first running one, that one's first tile,
-    staged early), its body never runs, and its result is zeros.
+    has stopped): no copy is started for it or waited for, its body never
+    runs, and its result is zeros.
 
     `name="sparse_decode_attention"` and a non-empty `metadata`, as
     `ssm_update` carries and for its reason; interpret emulation off the
@@ -1413,38 +1443,67 @@ def sparse_decode_attention(q, k_cache, v_cache, block_ids, counts, lengths,
         return sparse_decode_attention_reference(
             q, k_cache[layer], v_cache[layer], block_ids, counts, lengths,
             block, scale)
-    # which tile every grid step stages, worked out once a call: row f =
-    # (slot, head) walks its own ids and then repeats its last; a row that
-    # is not visited repeats the last tile of the nearest visited row
-    # before it (or the first tile of the first visited one)
-    cnt = counts.reshape(N * Hc)
-    rows = jnp.arange(N * Hc, dtype=jnp.int32)
-    before = jax.lax.cummax(jnp.where(cnt > 0, rows, -1))
-    src = jnp.where(before >= 0, before, jnp.argmax(cnt > 0)).astype(
-        jnp.int32)
-    at = jnp.where((cnt > 0)[:, None],
-                   jnp.minimum(jnp.arange(K)[None], cnt[:, None] - 1),
-                   jnp.where(before >= 0, cnt[src] - 1, 0)[:, None])
-    stage = jnp.take_along_axis(
-        block_ids.reshape(N * Hc, K).astype(jnp.int32)[src],
-        jnp.maximum(at, 0), axis=1).reshape(-1)
+    # worked out once a call, beside it: a tile's ONE number, (slot Hc +
+    # head) NB + block; each column's position in its slot's row (S, past
+    # any length, for a tile past its row's count); and the visited steps'
+    # order: nxt[s] the next visited step after s (FJ: none), nxt[FJ] the
+    # first, half[s] the half of the scratch step s's tiles land in
+    T = sparse_tiles_per_step(K, block, D, k_cache.dtype.itemsize)
+    J, F, NB = -(-K // T), N * Hc, S // block
+    FJ = F * J
+    cnt = counts.reshape(F)
+    ids = jnp.pad(block_ids.reshape(F, K).astype(jnp.int32),
+                  ((0, 0), (0, J * T - K)))
+    tile = (jnp.arange(F, dtype=jnp.int32)[:, None] * NB + ids).reshape(-1)
+    live = jnp.arange(J * T)[None] < cnt[:, None]                # [F, J T]
+    pos = (jnp.where(live, ids * block, S)[..., None]
+           + jnp.arange(block, dtype=jnp.int32)).reshape(FJ, 1, T * block)
+    visited = live[:, ::T].reshape(FJ)
+    later = jax.lax.cummin(
+        jnp.where(visited, jnp.arange(FJ, dtype=jnp.int32), FJ), axis=0,
+        reverse=True)
+    nxt = jnp.concatenate([later[1:], jnp.full((1,), FJ, jnp.int32),
+                           later[:1]])
+    half = (jnp.cumsum(visited.astype(jnp.int32)) - 1) % 2
 
-    def kv_map(b, g, j, stage_ref, src_ref, cnt_ref, len_ref):
-        f = b * Hc + g
-        return (layer, src_ref[f] // Hc, stage_ref[f * K + j],
-                src_ref[f] % Hc)
-
-    kv_spec = pl.BlockSpec((None, None, block, D), kv_map)
     q_spec = pl.BlockSpec((None, None, G, D),
                           lambda b, g, j, *_: (b, g, 0, 0))
+    pos_spec = pl.BlockSpec((None, 1, T * block),
+                            lambda b, g, j, *_: ((b * Hc + g) * J + j, 0, 0))
+    table_spec = pl.BlockSpec(memory_space=pl.ANY)
     contract = functools.partial(
         jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
-    def kern(stage_ref, src_ref, cnt_ref, len_ref, q_ref, k_ref, v_ref,
-             o_ref, acc_ref, m_ref, l_ref):
+    def kern(tile_ref, cnt_ref, len_ref, nxt_ref, half_ref, q_ref, pos_ref,
+             k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref):
         b, g, j = (pl.program_id(i) for i in range(3))
         f = b * Hc + g
+        s = f * J + j
+
+        def tiles(step, at, what):
+            """Start, or wait for, the copies of step `step`'s live tiles
+            into half `at` of the scratch."""
+            def one(t, _):
+                v = tile_ref[step * T + t]
+                rows = pl.ds(pl.multiple_of(v % NB * block, block), block)
+                lanes = pl.ds(pl.multiple_of(v // NB % Hc * D, D), D)
+                under = pl.ds(pl.multiple_of(t * block, block), block)
+                for i, (table, buf) in enumerate(((k_hbm, k_buf),
+                                                  (v_hbm, v_buf))):
+                    getattr(pltpu.make_async_copy(
+                        table.at[layer, v // (Hc * NB), rows, lanes],
+                        buf.at[at, under], sem.at[i, at]), what)()
+                return 0
+            jax.lax.fori_loop(
+                0, jnp.minimum(cnt_ref[step // J] - step % J * T, T), one, 0)
+
+        @pl.when(s == 0)
+        def _clean():
+            # a tile past its count is masked by its columns' positions,
+            # and 0 x what the scratch held must be 0
+            k_buf[...] = jnp.zeros_like(k_buf)
+            v_buf[...] = jnp.zeros_like(v_buf)
 
         @pl.when(j == 0)
         def _init():
@@ -1452,20 +1511,31 @@ def sparse_decode_attention(q, k_cache, v_cache, block_ids, counts, lengths,
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        @pl.when(j < cnt_ref[f])
-        def _tile():
-            kb = k_ref[...].astype(jnp.float32)            # [block, D]
-            vb = v_ref[...].astype(jnp.float32)
-            s = contract(q_ref[...].astype(jnp.float32), kb,
-                         (((1,), (1,)), ((), ()))) * scale  # [G, block]
-            kpos = stage_ref[f * K + j] * block \
-                + jax.lax.broadcasted_iota(jnp.int32, (G, block), 1)
-            s = jnp.where(kpos >= len_ref[b], _NEG_INF, s)
+        @pl.when(j * T < cnt_ref[f])
+        def _tiles():
+            at = half_ref[s]
+
+            @pl.when(s == nxt_ref[FJ])
+            def _first():
+                tiles(s, at, "start")
+
+            @pl.when(nxt_ref[s] < FJ)
+            def _ahead():
+                tiles(nxt_ref[s], 1 - at, "start")
+
+            tiles(s, at, "wait")
+            # the T tiles one under another: ONE pair of contractions and
+            # ONE softmax update over their T x block positions
+            kb = k_buf[at].astype(jnp.float32)                # [T block, D]
+            vb = v_buf[at].astype(jnp.float32)
+            sc = contract(q_ref[...].astype(jnp.float32), kb,
+                          (((1,), (1,)), ((), ()))) * scale   # [G, T block]
+            sc = jnp.where(pos_ref[...] >= len_ref[b], _NEG_INF, sc)
             _online_softmax_tile(
-                s, lambda p: contract(p, vb, (((1,), (0,)), ((), ()))),
+                sc, lambda p: contract(p, vb, (((1,), (0,)), ((), ()))),
                 acc_ref, m_ref, l_ref)
 
-        @pl.when(j == K - 1)
+        @pl.when(j == J - 1)
         def _finalize():
             o, _ = _softmax_finalize(acc_ref, m_ref, l_ref)
             o_ref[...] = o.astype(o_ref.dtype)
@@ -1476,11 +1546,16 @@ def sparse_decode_attention(q, k_cache, v_cache, block_ids, counts, lengths,
         return pl.pallas_call(
             kern,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=4, grid=(N, Hc, K),
-                in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
-                scratch_shapes=[pltpu.VMEM((G, D), jnp.float32),
-                                pltpu.VMEM((G, _MIN_LANES), jnp.float32),
-                                pltpu.VMEM((G, _MIN_LANES), jnp.float32)]),
+                num_scalar_prefetch=5, grid=(N, Hc, J),
+                in_specs=[q_spec, pos_spec, table_spec, table_spec],
+                out_specs=q_spec,
+                scratch_shapes=[
+                    pltpu.VMEM((2, T * block, D), k_cache.dtype),
+                    pltpu.VMEM((2, T * block, D), v_cache.dtype),
+                    pltpu.SemaphoreType.DMA((2, 2)),
+                    pltpu.VMEM((G, D), jnp.float32),
+                    pltpu.VMEM((G, _MIN_LANES), jnp.float32),
+                    pltpu.VMEM((G, _MIN_LANES), jnp.float32)]),
             out_shape=jax.ShapeDtypeStruct((N, Hc, G, D), q.dtype),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",) * 3),
@@ -1488,8 +1563,9 @@ def sparse_decode_attention(q, k_cache, v_cache, block_ids, counts, lengths,
             metadata={"kernel": "sparse_decode_attention"},
             interpret=interp)(*ops)
 
-    out = _interpret_dispatch(call, interpret, stage, src, cnt, lengths,
-                              q.reshape(N, Hc, G, D), k_cache, v_cache)
+    out = _interpret_dispatch(
+        call, interpret, tile, cnt, lengths, nxt, half,
+        q.reshape(N, Hc, G, D), pos, k_cache, v_cache)
     return out.reshape(N, H, D)
 
 

@@ -213,40 +213,116 @@ def test_the_selection_is_dense_attention_while_topk_blocks_are_in_sight():
     assert (score[0, :, 1:] == -1).all()
 
 
-def test_sparse_decode_attention_against_its_reference():
-    """The Mosaic kernel's body under interpret emulation: selected blocks
-    only, the slot's own last block masked by its length, grouped-query; a
-    slot with count 0 is not visited and reads zeros."""
-    rng = np.random.default_rng(0)
-    L, N, S, Hc, D, G, K, block = 2, 5, 512, 2, 128, 4, 3, 64
+def _kernel_case(seed, N, S, K, block, lengths, counts, G=4, L=2):
+    """Seeded tables, queries and block ids of `sparse_decode_attention`: a
+    (slot, head)'s ids are its slot's own last block, then distinct others
+    in a drawn order."""
+    rng = np.random.default_rng(seed)
+    Hc, D = 2, 128
     kc, vc = (jnp.asarray(rng.normal(size=(L, N, S, Hc * D)), jnp.float32)
               for _ in range(2))
     q = jnp.asarray(rng.normal(size=(N, Hc * G, D)), jnp.float32)
-    lengths = np.array([0, 200, 512, 70, 300], np.int32)
-    counts = np.array([[0, 0], [3, 2], [3, 3], [1, 2], [0, 0]], np.int32)
+    lengths = np.asarray(lengths, np.int32)
     last = np.maximum(-(-lengths // block), 1) - 1
     ids = np.zeros((N, Hc, K), np.int32)
     for n in range(N):
         for g in range(Hc):
             ids[n, g] = [last[n]] + [b for b in rng.permutation(S // block)
                                      if b != last[n]][:K - 1]
+    return q, kc, vc, ids, np.asarray(counts, np.int32), lengths, last
+
+
+_T64 = pk.sparse_tiles_per_step(64, 64, 128)
+_T12 = pk.sparse_tiles_per_step(12, 64, 128)
+SPARSE_KERNEL_CASES = {
+    # the K = 3 case: K is not a multiple of the tiles a step stages
+    "K=3": dict(N=5, S=512, K=3, lengths=[0, 200, 512, 70, 300],
+                counts=[[0, 0], [3, 2], [3, 3], [1, 2], [0, 0]]),
+    # 64 selected of 64-row blocks; counts at and around whole steps, the
+    # two K/V heads of a slot apart
+    "K=64": dict(N=5, S=8192, K=64,
+                 lengths=[4100, 8192, 7000, 5000, 6000],
+                 counts=[[64, 63], [1, _T64], [_T64 - 1, _T64 + 1],
+                         [_T64 + 1, 64], [63, 1]]),
+    "K=64, a head with count 0": dict(
+        N=3, S=4096, K=64, lengths=[4096, 4000, 3000],
+        counts=[[0, 64], [_T64, 0], [_T64 + 1, _T64 - 1]]),
+    # slots that do not run first, in the middle and last
+    "idle slots": dict(N=7, S=2048, K=12,
+                       lengths=[0, 0, 900, 0, 2048, 1500, 0],
+                       counts=[[0, 0], [0, 0], [12, 5], [0, 0], [1, 12],
+                               [_T12 + 1, _T12], [0, 0]]),
+    "no slot runs": dict(N=2, S=512, K=3, lengths=[0, 0],
+                         counts=[[0, 0], [0, 0]]),
+    # K = 12 where a step stages 8: the last step holds 4 and 4 repeats
+    "K not a multiple of T": dict(N=3, S=2048, K=12,
+                                  lengths=[2048, 1000, 1300],
+                                  counts=[[12, 12], [9, 12], [8, 11]]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SPARSE_KERNEL_CASES))
+def test_sparse_decode_attention_against_its_reference(name):
+    """The Mosaic kernel's body under interpret emulation: selected blocks
+    only, T tiles a grid step (the last step's tiles past the count are
+    repeats, masked), the slot's own last block masked by its length,
+    grouped-query; a (slot, head) with count 0 is not visited and reads
+    zeros."""
+    case = SPARSE_KERNEL_CASES[name]
+    block = 64
+    if name in ("K=3", "K not a multiple of T"):
+        assert case["K"] % pk.sparse_tiles_per_step(case["K"], block, 128)
+    q, kc, vc, ids, counts, lengths, _ = _kernel_case(
+        0, case["N"], case["S"], case["K"], block, case["lengths"],
+        case["counts"])
     out = pk.sparse_decode_attention(q, kc, vc, jnp.asarray(ids),
                                      jnp.asarray(counts), lengths, 1, block)
     want = pk.sparse_decode_attention_reference(
         q, kc[1], vc[1], jnp.asarray(ids), jnp.asarray(counts), lengths,
         block)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-6)
-    assert not np.asarray(out)[[0, 4]].any()
-    # ... and with every block of a slot selected it is `decode_attention`
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
+    G = q.shape[1] // 2
+    idle = np.repeat(counts == 0, G, axis=1)                 # [N, H]
+    assert not np.asarray(out)[idle].any()
+    assert np.asarray(out)[~idle].all()
+
+
+@pytest.mark.parametrize("S,lengths", [
+    (512, [1, 200, 512, 70, 300]),          # 8 blocks: K = 8, one step
+    (2048, [2048, 1, 1100, 65, 1984]),      # 32 blocks: several steps
+])
+def test_sparse_decode_attention_over_every_block_is_decode_attention(
+        S, lengths):
+    """With every block of a slot selected the sparse kernel is
+    `decode_attention`: against ITS reference, whole rows under the
+    lengths."""
+    block = 64
+    q, kc, vc, _, _, lengths, last = _kernel_case(
+        1, len(lengths), S, S // block, block, lengths, [[0, 0]])
+    N = len(lengths)
     every = jnp.broadcast_to(jnp.arange(S // block, dtype=jnp.int32),
-                             (N, Hc, S // block))
+                             (N, 2, S // block))
     dense = pk.decode_attention_reference(q, kc[1], vc[1], lengths)
     out = pk.sparse_decode_attention(
         q, kc, vc, every, jnp.asarray(np.broadcast_to(
-            (last + 1)[:, None], (N, Hc)).astype(np.int32)), lengths, 1,
+            (last + 1)[:, None], (N, 2)).astype(np.int32)), lengths, 1,
         block)
-    np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(dense)[1:],
-                               atol=2e-6)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=1e-5)
+
+
+def test_the_tiles_a_step_stages_come_from_the_shapes():
+    """T is worked out from the shapes alone: a power of two, no more than
+    the blocks selected, 4 T tiles (K and V, double-buffered) inside the
+    VMEM budget; at the cell's shapes it is 8 or more."""
+    assert pk.sparse_tiles_per_step(64, 64, 128) >= 8
+    for topk, block, D, size in ((64, 64, 128, 4), (3, 64, 128, 4),
+                                 (6, 16, 8, 4), (1, 64, 128, 4),
+                                 (64, 128, 256, 4), (64, 64, 128, 2)):
+        T = pk.sparse_tiles_per_step(topk, block, D, size)
+        assert 1 <= T <= topk and T & (T - 1) == 0
+        assert T == 1 or 4 * T * block * D * size <= pk._SPARSE_STAGE_BYTES
+        assert 2 * T > topk \
+            or 8 * T * block * D * size > pk._SPARSE_STAGE_BYTES
 
 
 def test_the_lightning_step_is_ssm_update_and_the_chunked_scan():
@@ -393,6 +469,33 @@ def test_the_step_counts_the_blocks_it_stages(served):
     # 97 positions are 7 blocks: 5 whole ones and the 1 of the last
     assert out["selected_rows"] == 11 + 96 + (5 * 16 + 1) + 12 + 81 + 82
     assert out["selected_blocks"] == out["kv_blocks_live"] // 2
+
+
+def test_the_step_counts_the_grid_steps_it_stages_them_in(served):
+    """`kv_grid_steps` of a sparse stack's `_kv_stream`: the kernel stages T
+    tiles a grid step (`pk.sparse_tiles_per_step`), so a running slot's K/V
+    head takes ceil(min(topk, blocks in sight) / T) steps a sparse layer a
+    trip, and a slot that does not run none."""
+    _, _, _, _, _, _, sess = served
+    T = pk.sparse_tiles_per_step(6, 16, 8)
+    assert T == 4
+    sess.lengths[:] = [10, 47, 48, 250]
+    # one trip of slots 0, 1, 3: 11, 48 and 251 positions are 1, 3 and 16
+    # blocks in sight, of which 1, 3 and 6 are chosen: 1, 1 and 2 steps
+    out = sess._kv_stream(np.array([1, 1, 0, 1]), 1)
+    assert out["kv_blocks_live"] == (1 + 3 + 6) * 2 * 2
+    assert out["kv_grid_steps"] == (1 + 1 + 2) * 2 * 2
+    # two trips of slots 1 and 2: 48, 49 and 49, 50 positions: 3, 4, 4, 4
+    # blocks chosen, a step each
+    out = sess._kv_stream(np.array([0, 2, 2, 0]), 2)
+    assert out["kv_blocks_live"] == (3 + 4 + 4 + 4) * 2 * 2
+    assert out["kv_grid_steps"] == 4 * 2 * 2
+    # slot 2 alone for three trips: 5 blocks in sight from 65 positions on
+    sess.lengths[:] = [10, 47, 63, 250]
+    out = sess._kv_stream(np.array([0, 0, 3, 0]), 3)
+    assert out["kv_blocks_live"] == (4 + 5 + 5) * 2 * 2
+    assert out["kv_grid_steps"] == (1 + 2 + 2) * 2 * 2
+    assert sess._kv_stream(np.zeros(4, np.int64), 1)["kv_grid_steps"] == 0
 
 
 def test_the_counted_blocks_are_those_the_step_hands_its_kernel(served):

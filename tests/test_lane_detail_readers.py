@@ -260,10 +260,67 @@ def test_token_out_frames_per_pass_is_declared_for_the_decode_cells():
                  "better": "higher", "source": "program_span",
                  "layer": "serving front", "moves": "tokens_per_s",
                  "workloads": DECODE_CELLS}
-    # (last but for the eight readers PR 48 appended behind it)
-    assert manifest["per_layer"][-9] is m
+    # (last but for the eight readers PR 48 and the one PR 49 appended
+    # behind it)
+    assert manifest["per_layer"][-10] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
+
+
+def step_fetch(at, **attrs):
+    return span("decode/fetch", at, at + 0.5, phase="step", trips=8, **attrs)
+
+
+@pytest.mark.parametrize("case,fetches,want", [
+    # 24 slots x 2 heads x 2 sparse layers x 8 trips of 64 tiles, 8 a step
+    ("long_contexts", [step_fetch(1.0, kv_blocks_live=49152,
+                                  kv_grid_steps=6144)] * 3, 8.0),
+    # a short stream's last step is part empty: tiles over steps, summed
+    # over the window's dispatches and not a mean of their ratios
+    ("a_short_stream", [step_fetch(1.0, kv_blocks_live=640, kv_grid_steps=80),
+                        step_fetch(2.0, kv_blocks_live=36, kv_grid_steps=12),
+                        step_fetch(3.0, kv_blocks_live=4, kv_grid_steps=4)],
+     680 / 96.0),
+    ("outside_the_window", [
+        step_fetch(1.0, kv_blocks_live=128, kv_grid_steps=16),
+        step_fetch(45.0, kv_blocks_live=5, kv_grid_steps=5)], 8.0),
+    # a prefill's fetch carries no such counters, and is not a step's
+    ("a_prefill", [span("decode/fetch", 1.0, 1.5, phase="prefill",
+                        kv_blocks_live=9, kv_grid_steps=9),
+                   step_fetch(2.0, kv_blocks_live=12, kv_grid_steps=3)], 4.0),
+    # the kernel's parent: one tile a step and no counter of the steps;
+    # a stack without sparse layers: neither counter
+    ("the_parent", [step_fetch(1.0, kv_blocks_live=49152,
+                               kv_blocks_total=393216, selected_blocks=6144)],
+     None),
+    ("no_sparse_layer", [step_fetch(1.0)], None),
+    ("nothing_ran", [step_fetch(1.0, kv_blocks_live=0, kv_grid_steps=0)],
+     None),
+    ("no_fetch", [], None)])
+def test_sparse_tiles_per_grid_step_is_tiles_over_steps(case, fetches, want):
+    got = reader("sparse_tiles_per_grid_step")(lane_rounds() + fetches, None,
+                                               run_facts())
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def test_sparse_tiles_per_grid_step_is_declared_for_its_cell_alone():
+    manifest = bench_run.load_json(bench_run.MANIFEST)
+    (m,) = [m for m in manifest["per_layer"]
+            if m["name"] == "sparse_tiles_per_grid_step"]
+    assert m == {"name": "sparse_tiles_per_grid_step", "unit": "tiles",
+                 "better": "higher", "source": "program_counter",
+                 "layer": "kernels", "moves": "tokens_per_s",
+                 "workloads": ["minicpmsala_longdoc_mixed"]}
+    assert manifest["per_layer"][-1] is m
+    assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
+                                       m["name"] + ".py"))
+    # the one cell whose stack has sparse layers
+    sparse = [w["name"] for w in manifest["workloads"]
+              if "sparse_attention" in bench_run.load_json(os.path.join(
+                  REPO, [c for c in manifest["configs"]
+                         if c["name"] == w["config"]][0]["file"])).get(
+                  "model", {}).get("layer_types", [])]
+    assert sparse == m["workloads"]
 
 
 # ---------------------------------------------------------------------------
@@ -302,9 +359,9 @@ def test_spans_older_than_the_phase_spans_read_as_nothing(name):
 
 def test_the_nine_are_declared_last_for_the_five_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # (last but for the six readers PR 42, the five PR 44, the one PR 45
-    # and the eight PR 48 appended behind them)
-    last = manifest["per_layer"][-29:-20]
+    # (last but for the six readers PR 42, the five PR 44, the one PR 45,
+    # the eight PR 48 and the one PR 49 appended behind them)
+    last = manifest["per_layer"][-30:-21]
     assert [m["name"] for m in last] == list(NINE)
     for m in last:
         assert m["workloads"] == DECODE_CELLS, m["name"]

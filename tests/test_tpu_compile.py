@@ -699,6 +699,38 @@ def test_sparse_linear_step_and_chunked_prefill_compile_for_the_chip(
     sparse = [c for c in calls if "sparse_decode_attention" in c]
     linear = [c for c in calls if "ssm_update" in c]
     assert (len(sparse), len(linear), len(calls)) == (2, 6, 8)
+    # a sparse call's grid is (24, 2, ceil(64 / T)), which the text does not
+    # show: its operands do.  Five scalar-prefetch vectors (the tiles'
+    # numbers one a tile: 24 x 2 x ceil(64 / T) x T of them), q, a row of
+    # column positions a grid step, and the two carried TABLES THEMSELVES,
+    # left in HBM
+    from paddle_tpu.ops import pallas_kernels
+    T = pallas_kernels.sparse_tiles_per_step(
+        meta["sparse_topk"], meta["sparse_block"], meta["head_dim"], 4)
+    assert T >= 8
+    steps = slots * meta["n_kv_heads"] * -(-meta["sparse_topk"] // T)
+    table = "f32[2,%d,%d,%d]" % (slots, meta["max_seq_len"],
+                                 meta["n_kv_heads"] * meta["head_dim"])
+    def made(name):
+        """(shape, opcode) of the instruction that defines `name`."""
+        (m,) = re.findall(r"^\s*%s = (\w+\[[\d,]*\])\S* ([\w\-]+)\("
+                          % re.escape(name), text, re.M)
+        return m
+
+    for c in sparse:
+        ops = re.findall(r"%[\w.\-]+", c.split("custom-call(")[1].split(
+            "), custom_call_target")[0])
+        assert len(ops) == 9, ops
+        assert [made(o)[0] for o in ops[:7]] == [
+            "s32[%d]" % (steps * T), "s32[%d]" % (slots * 2),
+            "s32[%d]" % slots, "s32[%d]" % (steps + 1), "s32[%d]" % steps,
+            "f32[%d,2,16,128]" % slots,
+            "s32[%d,1,%d]" % (steps, T * meta["sparse_block"])], ops
+        # ... each the table as the layer's row write left it (in place:
+        # the temporaries below hold nothing table-sized), never a copy
+        k_op, v_op = (made(o) for o in ops[7:])
+        assert k_op[0] == v_op[0] == table and ops[7] != ops[8], (k_op, v_op)
+        assert "copy" not in (k_op[1], v_op[1]), (k_op, v_op)
     for scope, named in (("sparse_attention", sparse),
                          ("ssm_update", linear),
                          ("linear_attention", linear)):

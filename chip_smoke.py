@@ -87,6 +87,12 @@ DECODE_LATENT = (8, 4096, 128, 576, 512)
 # of 96)
 SSM_TABLE = (2, 24, 32, 128, 256, 2)
 
+# a chunk of a MiniCPM-SALA prefill's stage 2: 2,048 queries of 32 heads over
+# 2 K/V heads of 128, the 8,192 bucket's buffered rows, blocks of 64, chunk 2
+# of 4 with the prompt ending inside it
+SPARSE_PREFILL = dict(C=2048, B=8192, H=32, Hc=2, D=128, block=64, chunk=2,
+                      true_len=5000, topk=64)
+
 # Stated tolerances.
 # serve, the kernel alone: decode_attention (block-diagonal queries against
 # flat K/V rows, both contractions on the MXU at fp32 precision, online
@@ -99,6 +105,12 @@ TOL_DECODE_KERNEL = 5e-5
 # another order of summation (the state it writes back is held to EQUAL the
 # reference's bit for bit)
 TOL_SSM_READOUT_REL = 1e-5
+# sparse_prefill_attention (bf16 operands, fp32 sums and softmax) vs its
+# reference, XLA's form at the default precision, which rounds the same
+# operands to bf16: what is left is the rounding of the probabilities against
+# another running maximum and the order of the sums, on outputs of O(1)
+# (1.7e-4 to 2.2e-4 at the cell's shapes, my chip runs, PR 50)
+TOL_SPARSE_PREFILL_KERNEL = 2e-3
 # latent_decode_attention (both contractions on the MXU, operands rounded to
 # bfloat16 as the default precision rounds every matmul's, fp32 accumulation
 # and softmax) vs its reference at full fp32 precision ON THE SAME
@@ -562,6 +574,57 @@ def check_ssm_kernel(table, seed):
     return err, round((time.perf_counter() - t0) / 8 * 1e6, 1)
 
 
+def check_sparse_prefill_kernel(shape, seed):
+    """sparse_prefill_attention on one chunk of a prefill at `shape`
+    (SPARSE_PREFILL), every query's selection its own block, the first and
+    `topk` - 2 drawn blocks in sight, against its reference (the parent's
+    plain-XLA running softmax) on the prompt's positions: one Mosaic call,
+    the blocks of queries wholly past the prompt zeros.  Returns (largest
+    absolute error, microseconds a call, the reference's)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    C, B, H, Hc, D, block, chunk, n, topk = (shape[k] for k in (
+        "C", "B", "H", "Hc", "D", "block", "chunk", "true_len", "topk"))
+    ks = jax.random.split(jax.random.PRNGKey(seed + 7), 4)
+    q = jax.random.normal(ks[0], (C, H, D), jnp.float32)
+    live = (jnp.arange(B) < n)[:, None]
+    k, v = (jax.random.normal(kk, (B, Hc * D), jnp.float32) * live
+            for kk in ks[1:3])
+    own = (chunk * C + jnp.arange(C)) // block                    # [C]
+    drawn = jax.random.uniform(ks[3], (C, Hc, B // block))
+    blocks = jnp.arange(B // block)
+    drawn = jnp.where(blocks <= own[:, None, None], drawn, 2.0)
+    sel = (drawn <= jnp.sort(drawn, axis=-1)[..., topk - 3:topk - 2]) & (
+        blocks <= own[:, None, None])
+    sel = (sel | (blocks == 0) | (blocks == own[:, None, None])).transpose(
+        1, 2, 0)                                          # [Hc, blocks, C]
+    kernel = jax.jit(lambda *o: pk.sparse_prefill_attention(
+        *o, chunk, n, block, interpret=REQUIRED_PLATFORM != "tpu"))
+    compiled = kernel.lower(q, k, v, sel).compile()
+    require_mosaic(compiled.as_text(), 1, "sparse_prefill_attention")
+    got = jax.block_until_ready(compiled(q, k, v, sel))
+    reference = jax.jit(lambda *o: pk.sparse_prefill_attention_reference(
+        *o, chunk, block)).lower(q, k, v, sel).compile()
+    want = jax.block_until_ready(reference(q, k, v, sel))
+    rows = n - chunk * C
+    err = float(jnp.max(jnp.abs(got[:rows] - want[:rows])))
+    require(err <= TOL_SPARSE_PREFILL_KERNEL and bool(
+        jnp.all(jnp.isfinite(got))),
+        "sparse_prefill_attention error %.4g" % err)
+    Qb = pk.sparse_prefill_tiles(C, B, H // Hc, D, block, mosaic=True)[0]
+    require(not bool(jnp.any(got[-(-rows // Qb) * Qb:])),
+            "sparse_prefill_attention wrote past the prompt's query blocks")
+    us = []
+    for fn in (compiled, reference):
+        t0 = time.perf_counter()
+        for _ in range(4):
+            out = fn(q, k, v, sel)
+        jax.block_until_ready(out)
+        us.append(round((time.perf_counter() - t0) / 4 * 1e6, 1))
+    return err, us[0], us[1]
+
+
 def check_window(pred, n_slots, prompt_list, what):
     """A window of `decode.STEP_WINDOW` trips against as many one-trip
     dispatches of the same step executable, from the same admissions: equal
@@ -922,6 +985,12 @@ def phase_kernels(seed, devs):
                          "groups"), SSM_TABLE)), dtype="float32",
          max_rel_err_readout=float("%.3g" % err), tol=TOL_SSM_READOUT_REL,
          state_equals_reference=True, call_us=us,
+         peak_bytes_in_use=peak_bytes(devs[0]))
+
+    err, us, ref_us = check_sparse_prefill_kernel(SPARSE_PREFILL, seed)
+    emit("kernels", kernel="sparse_prefill_attention", shape=SPARSE_PREFILL,
+         dtype="float32", max_abs_err=float("%.3g" % err),
+         tol=TOL_SPARSE_PREFILL_KERNEL, call_us=us, reference_call_us=ref_us,
          peak_bytes_in_use=peak_bytes(devs[0]))
 
     M, K, N = (DEQUANT[k] for k in "MKN")

@@ -2230,7 +2230,7 @@ class GenerativePredictor:
                         carry["ki"], ck[None], (ls, s0 // stride, 0))
                 out, picks = self._chunk_attention(
                     q[0], carry["kc"][ls, front:], carry["vc"][ls],
-                    carry["ki"][ls, m - 1:], c, C)
+                    carry["ki"][ls, m - 1:], c, C, true_len)
                 carry["picks"] = carry["picks"].at[ls].set(jnp.where(
                     here, jax.lax.dynamic_index_in_dim(
                         picks, jnp.clip(last, 0, C - 1), keepdims=False),
@@ -2270,22 +2270,24 @@ class GenerativePredictor:
         return carry["row"], carry["picks"], sum(
             (left[kind.name] for kind, _ in self._kinds), ())
 
-    def _chunk_attention(self, q, k, v, ck, c, C):
+    def _chunk_attention(self, q, k, v, ck, c, C, true_len):
         """A sparse_attention layer's attention of one prefill chunk: q [C,
         H, Dh] the queries at positions c * C .., k / v [B, Hc, Dh] the
         rows written so far (zeros past them), ck [J, Hc * Dh] the
         compressed keys so far -> ([C, H, Dh], the blocks each query
-        selected [C, Hc, k] i32, -1 past their count).  By blocks of at most
-        `PREFILL_QUERY_BLOCK` queries (`lax.map`): stage 1 against the
-        compressed keys at "highest" precision and the selection
+        selected [C, Hc, k] i32, -1 past their count).  Stage 1 by blocks of
+        at most `PREFILL_QUERY_BLOCK` queries (`lax.map`), against the
+        compressed keys at "highest" precision, and the selection
         (`_sparse_select`, the step's rule) under the scope
         `sparse_select`; stage 2 under `sparse_attention`, the softmax
-        over the positions j <= t of the SELECTED blocks, as a running
-        softmax over tiles of C keys, 0 .. c: a tile's scores are masked to
-        the selected blocks' positions, which IS attention over the
-        selected blocks, exactly, in plain XLA."""
+        over the positions j <= t of the SELECTED blocks, which IS attention
+        over the selected blocks, exactly: ONE flash body for the chunk
+        (`pallas_kernels.sparse_prefill_attention`), whose scores stay in
+        VMEM and whose key tiles end at each query block's causal frontier
+        and at `true_len`."""
         import jax
         import jax.numpy as jnp
+        from ..ops.pallas_kernels import sparse_prefill_attention
         blk = self._block_meta
         block = blk["sparse_block"]
         _, H, Dh = q.shape
@@ -2296,7 +2298,6 @@ class GenerativePredictor:
             Q -= 1
         scale = np.float32(1.0 / np.sqrt(Dh))
         ckh = ck.reshape(-1, Hc, Dh)
-        tiles = C // block
 
         def one(args):
             u, qi = args                            # qi [Q, Hc, G, Dh]
@@ -2313,35 +2314,21 @@ class GenerativePredictor:
                 sel = (score > vals[..., -1:]) | (
                     (score == vals[..., -1:])
                     & (jnp.arange(B // block) <= ids[..., -1:]))
+            # the queries last: XLA then sorts (`top_k`) with the queries
+            # on the lanes, 27 times faster on a v5e than along them (PERF.md
+            # section 6, PR 50), and the kernel takes the blocks as rows
+            return sel.transpose(1, 2, 0), picks
 
-            def tile(kt, state):
-                acc, top, norm = state
-                kk, vv = (jax.lax.dynamic_slice_in_dim(t, kt * C, C)
-                          for t in (k, v))
-                seen = jnp.repeat(jax.lax.dynamic_slice_in_dim(
-                    sel, kt * tiles, tiles, axis=2), block, axis=2) & (
-                        (kt * C + jnp.arange(C))[None, None]
-                        <= qpos[:, None, None])     # [Q, Hc, C]
-                s = jnp.einsum("qhgd,khd->qhgk", qi, kk) * scale
-                s = jnp.where(seen[:, :, None], s, -1e30)
-                new = jnp.maximum(top, jnp.max(s, axis=-1))
-                alpha = jnp.exp(top - new)
-                p = jnp.exp(s - new[..., None]) * seen[:, :, None]
-                return (acc * alpha[..., None]
-                        + jnp.einsum("qhgk,khd->qhgd", p, vv), new,
-                        norm * alpha + jnp.sum(p, axis=-1))
-
-            with jax.named_scope("sparse_attention"):
-                acc, _, norm = jax.lax.fori_loop(
-                    0, c + 1, tile,
-                    (jnp.zeros((Q, Hc, G, Dh), jnp.float32),
-                     jnp.full((Q, Hc, G), -1e30, jnp.float32),
-                     jnp.zeros((Q, Hc, G), jnp.float32)))
-                return acc / jnp.maximum(norm, 1e-20)[..., None], picks
-
-        out, picks = jax.lax.map(one, (jnp.arange(C // Q), q.astype(
-            jnp.float32).reshape(C // Q, Q, Hc, G, Dh)))
-        return out.reshape(C, H, Dh), picks.reshape((C,) + picks.shape[2:])
+        q = q.astype(jnp.float32)
+        sel, picks = jax.lax.map(one, (jnp.arange(C // Q), q.reshape(
+            C // Q, Q, Hc, G, Dh)))
+        with jax.named_scope("sparse_attention"):
+            out = sparse_prefill_attention(
+                q, k.reshape(B, Hc * Dh), v.reshape(B, Hc * Dh),
+                sel.transpose(1, 2, 0, 3).reshape(sel.shape[1:3] + (C,)),
+                c, true_len, block,
+                scale=scale)
+        return out, picks.reshape((C,) + picks.shape[2:])
 
     def _norm(self, x, state, name):
         """The block's norm over the last axis with the weights

@@ -49,7 +49,8 @@ __all__ = ["tiled_contraction", "flash_attention", "decode_attention",
            "dequant_matmul_reference", "mosaic_lowering", "ssm_update",
            "ssm_update_reference", "ssm_update_block_heads",
            "sparse_decode_attention", "sparse_decode_attention_reference",
-           "sparse_tiles_per_step"]
+           "sparse_tiles_per_step", "sparse_prefill_attention",
+           "sparse_prefill_attention_reference", "sparse_prefill_tiles"]
 
 # Finite mask value (not -inf): exp(_NEG_INF - finite) underflows to an
 # exact 0, and the logsumexp of a fully-masked row stays finite, so the
@@ -153,7 +154,8 @@ class _TileCtx(object):
 def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
                       out_specs, out_shape, scratch=(), scratch_fill=(),
                       tile=None, finalize=None, tile_live=None,
-                      scalar_prefetch=(), interpret=None):
+                      scalar_prefetch=(), interpret=None, name=None,
+                      metadata=None, vmem_limit_bytes=None):
     """THE tiled-contraction core every kernel family instantiates.
 
     `grid` runs with "parallel" semantics on every axis except
@@ -180,7 +182,11 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
     nothing else (decode attention: a slot's K/V blocks past its
     length).  With none given the call is the plain grid it always was.
     `interpret=None` resolves interpret-vs-Mosaic at trace time
-    (_interpret_dispatch), like every kernel here always has."""
+    (_interpret_dispatch), like every kernel here always has.  `name` and
+    `metadata` are the Mosaic call's own (a reader of a trace finds the
+    kernel by them: `ssm_update`'s docstring), `vmem_limit_bytes` the
+    compiler's scoped VMEM where a family's tiles need more than its
+    default; all three None leave the call as it always was."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -228,7 +234,9 @@ def tiled_contraction(operands, *, grid, reduce_axis, in_specs,
         return pl.pallas_call(
             kern, out_shape=out_shape,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=sem),
+                dimension_semantics=sem,
+                vmem_limit_bytes=vmem_limit_bytes),
+            name=name, metadata=metadata,
             interpret=interp, **plumbing,
         )(*ops)
 
@@ -1567,6 +1575,290 @@ def sparse_decode_attention(q, k_cache, v_cache, block_ids, counts, lengths,
         call, interpret, tile, cnt, lengths, nxt, half,
         q.reshape(N, Hc, G, D), pos, k_cache, v_cache)
     return out.reshape(N, H, D)
+
+
+# what a query block of `sparse_prefill_attention` keeps in VMEM (its G
+# heads' q, running state and result) stays within this budget, and a key
+# tile within this many rows: (Qb, Tk) = (512, 1024) at G = 16 heads of 128,
+# the fastest of the chip's sweep (PERF.md section 6, PR 50: 0.13 ms a 512
+# x 2,048 x 32-head unit where (128, 512) takes 0.34 and (256, 2048) 0.14;
+# a v5e's VMEM is 128 MiB)
+_PREFILL_BLOCK_BYTES = 32 * 1024 * 1024
+_PREFILL_KEY_TILE = 1024
+
+
+def sparse_prefill_tiles(C, B, G, D, block, mosaic=None):
+    """(Qb, Tk): the queries a grid step of `sparse_prefill_attention`
+    holds and the keys it streams, from the shapes alone.  Tk the largest
+    multiple of `block` that divides the B buffered positions and is at most
+    `_PREFILL_KEY_TILE`; Qb the largest divisor of the chunk's C queries
+    whose G heads' q (two deep, in the contractions' dtype), result (two
+    deep, fp32) and running state (acc [Qb, D], max and sum [Qb, 128] lanes,
+    fp32) fit `_PREFILL_BLOCK_BYTES`.  Under Mosaic (`mosaic`; by default
+    where the trace lowers for a TPU) Qb is whole (16, 128) tiles of a bf16
+    operand and Tk whole lanes and 8 blocks' rows of the selection at a
+    time; None where Mosaic has no such tiles (D not a multiple of 128,
+    `block` not of 8, no such divisor): `sparse_prefill_attention` then IS
+    its reference."""
+    if mosaic is None:
+        mosaic = lowering_for_tpu()
+    if mosaic and (D % 128 or block % 8):
+        return None
+    rows = _PREFILL_BLOCK_BYTES // (G * (16 * D + 8 * _MIN_LANES))
+    qu, ku = (16, int(np.lcm(128, 8 * block))) if mosaic else (1, 1)
+    Qb = max([b for b in range(qu, min(rows, C) + 1, qu) if C % b == 0],
+             default=None)
+    Tk = max([t for t in range(block, min(_PREFILL_KEY_TILE, B) + 1, block)
+              if B % t == 0 and t % ku == 0], default=None)
+    return None if Qb is None or Tk is None else (Qb, Tk)
+
+
+def sparse_prefill_attention_reference(q, k, v, sel, chunk, block,
+                                       scale=None, block_q=512):
+    """Plain-XLA oracle/fallback of `sparse_prefill_attention`, the same
+    masking: q [C, H, D] the queries at positions chunk * C .., k / v [B,
+    Hc * D] the rows buffered so far (zeros past them), sel [Hc, B / block,
+    C] bool the blocks each query selected -> [C, H, D] f32: query t's
+    softmax over the positions j <= chunk * C + t of its K/V head's
+    selected blocks.  By blocks of at most `block_q` queries (`lax.map`),
+    each a running softmax over tiles of C keys, 0 .. chunk (`chunk` may be
+    traced): a tile's scores [block_q, Hc, G, C] are masked to the selected
+    blocks' positions, in HBM.  The contractions at default precision (on
+    a TPU: operands rounded to bf16, fp32 sums), the softmax in fp32."""
+    import jax
+    import jax.numpy as jnp
+    C, H, D = q.shape
+    B = k.shape[0]
+    Hc = k.shape[1] // D
+    G = H // Hc
+    Q = min(int(block_q), C)
+    while C % Q:
+        Q -= 1
+    scale = np.float32(scale if scale is not None else 1.0 / np.sqrt(D))
+    k, v = (t.astype(jnp.float32).reshape(B, Hc, D) for t in (k, v))
+    sel = sel.transpose(2, 0, 1)                    # [C, Hc, B / block]
+    tiles = C // block
+
+    def one(args):
+        u, qi, picked = args                # [Q, Hc, G, D], [Q, Hc, B/block]
+        qpos = chunk * C + u * Q + jnp.arange(Q)
+
+        def tile(kt, state):
+            acc, top, norm = state
+            kk, vv = (jax.lax.dynamic_slice_in_dim(t, kt * C, C)
+                      for t in (k, v))
+            seen = jnp.repeat(jax.lax.dynamic_slice_in_dim(
+                picked, kt * tiles, tiles, axis=2), block, axis=2) & (
+                    (kt * C + jnp.arange(C))[None, None]
+                    <= qpos[:, None, None])         # [Q, Hc, C]
+            s = jnp.einsum("qhgd,khd->qhgk", qi, kk) * scale
+            s = jnp.where(seen[:, :, None], s, _NEG_INF)
+            new = jnp.maximum(top, jnp.max(s, axis=-1))
+            alpha = jnp.exp(top - new)
+            p = jnp.exp(s - new[..., None]) * seen[:, :, None]
+            return (acc * alpha[..., None]
+                    + jnp.einsum("qhgk,khd->qhgd", p, vv), new,
+                    norm * alpha + jnp.sum(p, axis=-1))
+
+        acc, _, norm = jax.lax.fori_loop(
+            0, chunk + 1, tile,
+            (jnp.zeros((Q, Hc, G, D), jnp.float32),
+             jnp.full((Q, Hc, G), _NEG_INF, jnp.float32),
+             jnp.zeros((Q, Hc, G), jnp.float32)))
+        return acc / jnp.maximum(norm, _TINY)[..., None]
+
+    out = jax.lax.map(one, (
+        jnp.arange(C // Q),
+        q.astype(jnp.float32).reshape(C // Q, Q, Hc, G, D),
+        sel.reshape((C // Q, Q) + sel.shape[1:])))
+    return out.reshape(C, H, D)
+
+
+def sparse_prefill_attention(q, k, v, sel, chunk, true_len, block,
+                             scale=None, block_q=None, block_kv=None,
+                             compute_dtype=None, interpret=None):
+    """Stage 2 of a sparse_attention layer's CHUNKED PREFILL as one flash
+    body: q [C, H, D] the queries of chunk number `chunk` (positions chunk
+    * C ..), k / v [B, Hc * D] fp32 the prompt's rows buffered so far as
+    flat rows (the lane block IS the head, as in `sparse_decode_attention`),
+    sel [Hc, B / block, C] bool the blocks of `block` positions each query
+    selected for each K/V head (`inference/decode.py::_sparse_select`; the
+    queries LAST, on the lanes: the layout the selection's sort is fast in
+    on this chip, `inference/decode.py::_chunk_attention`),
+    `chunk` and `true_len` (the prompt's length) traced scalars -> [C, H,
+    D] f32: for query t and K/V head g, the softmax over the positions j <=
+    chunk * C + t of the blocks in sel[g, :, t], for the G = H / Hc query
+    heads of g.  The scores live and die in VMEM.
+
+    THE GRID is (K/V head, block of Qb queries, tile of Tk keys), static
+    while `chunk` is traced (`sparse_prefill_tiles`, from the shapes).  A
+    step holds the G heads' queries of its block [G, Qb, D] (laid out by
+    XLA beside the call), streams one [Tk, D] tile of K and of V, and, a
+    head at a time (`fori_loop`), makes the head's scores [Qb, Tk] and
+    folds them into the head's running max / sum / accumulator
+    (`_online_softmax_tile`); the last tile normalises and writes the
+    block's [Qb, G * D] lanes of the result.  Key tiles past the block's
+    CAUSAL FRONTIER (its last query's position, or the prompt's last:
+    `chunk` and `true_len` are scalar-prefetch operands) start no copy and
+    run no body, as `decode_attention`'s stream past a slot's length: their
+    index maps repeat the last live tile.  A block of queries wholly past
+    `true_len` copies nothing, runs nothing and reads zeros; a query past
+    it in a live block attends like the rest and nobody reads it.
+
+    THE MASK is made once a step for all G heads: the tile's Tk / block
+    rows of the query block's selection [B / block, Qb] (resident in VMEM
+    over the block's key tiles, 0 / 1) are expanded to the tile's Tk
+    positions by a 0 / 1 matmul against an expansion matrix made of iotas
+    (contracted over the rows: [Qb, Tk]), ANDed with the causal test of the
+    positions, and kept as an additive bias (0 or 2 `_NEG_INF`: a masked
+    score lies UNDER the running max's start, so its exponent is an exact
+    0 and a row that sees nothing in a tile leaves its running state as it
+    was, bit for bit).  `sel` is never expanded to positions in HBM.
+
+    ARITHMETIC, the parent's XLA form on the chip exactly: q, K, V and p
+    are rounded to bf16 for the two contractions, which sum in fp32
+    (`preferred_element_type`; what XLA's default precision does to fp32
+    operands on a TPU); the scale, the mask, max / exp / sum / rescale and
+    the division are fp32.  Off the TPU (interpret emulation) the
+    contractions take the fp32 operands, as XLA's default does there
+    (`compute_dtype` overrides: a test's way to the bf16 form on the CPU).
+
+    `name="sparse_prefill_attention"` and a non-empty `metadata`, as
+    `ssm_update` carries and for its reason.  The reference where the
+    tiles are not whole (`sparse_prefill_tiles` is None)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    C, H, D = q.shape
+    B, W = k.shape
+    Hc = W // D
+    G = H // Hc
+    NB = B // block
+    if Hc * D != W or G * Hc != H or v.shape != k.shape or B % block \
+            or sel.shape != (Hc, NB, C):
+        raise ValueError(
+            "sparse_prefill_attention: q [C, H, D] %s goes with k, v [B, "
+            "Hc * D] %s %s, sel [Hc, B / block, C] %s and a block (%d) "
+            "that divides B" % (tuple(q.shape), tuple(k.shape),
+                                tuple(v.shape), tuple(sel.shape), block))
+    scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
+    if interpret is None:
+        interpret = not lowering_for_tpu()
+    tiles = sparse_prefill_tiles(C, B, G, D, block, mosaic=not interpret)
+    if tiles is None:
+        return sparse_prefill_attention_reference(q, k, v, sel, chunk,
+                                                  block, scale)
+    Qb, Tk = (int(given or ours)
+              for given, ours in zip((block_q, block_kv), tiles))
+    if C % Qb or B % Tk or Tk % block:
+        raise ValueError(
+            "sparse_prefill_attention: block_q %d divides C %d, block_kv %d "
+            "divides B %d and is whole blocks of %d" % (Qb, C, Tk, B, block))
+    dt = jnp.dtype(compute_dtype or (jnp.float32 if interpret
+                                     else jnp.bfloat16))
+    nper = Tk // block                      # blocks a key tile
+    at = jnp.stack([jnp.asarray(chunk), jnp.asarray(true_len)]).astype(
+        jnp.int32)
+
+    def frontier(i, at_ref):
+        """Whether query block i holds a position of the prompt, and the
+        last key tile its queries see."""
+        first = at_ref[0] * C + i * Qb
+        return first < at_ref[1], jnp.maximum(
+            jnp.minimum(first + Qb, at_ref[1]) - 1, 0) // Tk
+
+    def q_block(i, at_ref):
+        # a block wholly past the prompt repeats the last live one: no copy
+        return jnp.minimum(i, jnp.maximum(
+            at_ref[1] - 1 - at_ref[0] * C, 0) // Qb)
+
+    def k_tile(i, j, at_ref):
+        live, last = frontier(i, at_ref)
+        return jnp.where(live, jnp.minimum(j, last), last)
+
+    def tile(ctx):
+        q_ref, k_ref, v_ref, sel_ref = ctx.ins
+        acc_ref, m_ref, l_ref, k_buf, v_buf, bias_ref = ctx.scratch
+        _, i, j = ctx.ids
+        k_buf[...] = k_ref[...].astype(dt)
+        v_buf[...] = v_ref[...].astype(dt)
+        # the tile's `nper` rows of the block's selection [NB, Qb], each
+        # spread over its block's positions: row r of the expansion is 1
+        # at positions r * block .. of the tile
+        lo = jax.lax.broadcasted_iota(jnp.int32, (nper, Tk), 0) * block
+        col = jax.lax.broadcasted_iota(jnp.int32, (nper, Tk), 1)
+        expand = jnp.where((col >= lo) & (col < lo + block), 1.0,
+                           0.0).astype(jnp.bfloat16)
+        picked = jax.lax.dot_general(
+            sel_ref[pl.ds(pl.multiple_of(j * nper, nper), nper), :].astype(
+                jnp.bfloat16), expand, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)               # [Qb, Tk]
+        qpos = ctx.scalars[0][0] * C + i * Qb + jax.lax.broadcasted_iota(
+            jnp.int32, (Qb, Tk), 0)
+        kpos = j * Tk + jax.lax.broadcasted_iota(jnp.int32, (Qb, Tk), 1)
+        bias_ref[...] = jnp.where((picked > 0.5) & (kpos <= qpos), 0.0,
+                                  2 * _NEG_INF)
+
+        def head(g, _):
+            s = jax.lax.dot_general(
+                q_ref[g], k_buf[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias_ref[...]
+            _online_softmax_tile(
+                s, lambda p: jax.lax.dot_general(
+                    p.astype(dt), v_buf[...], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32),
+                acc_ref.at[g], m_ref.at[g], l_ref.at[g])
+            return 0
+        jax.lax.fori_loop(0, G, head, 0)
+
+    def finalize(ctx):
+        o_ref, = ctx.outs
+        acc_ref, m_ref, l_ref = ctx.scratch[:3]
+        for g in range(G):
+            o_ref[:, g * D:(g + 1) * D] = _softmax_finalize(
+                acc_ref.at[g], m_ref.at[g], l_ref.at[g])[0]
+
+    def live(ids, at_ref):
+        on, last = frontier(ids[1], at_ref)
+        return on & (ids[2] <= last)
+
+    _kernel_metadata_on_one_line()
+    out = tiled_contraction(
+        (q.reshape(C, Hc, G, D).transpose(1, 2, 0, 3).astype(dt),
+         # fp32: a tile's `nper` rows are then whole (8, 128) tiles of the
+         # resident slab (a bf16 slab's are 16 rows), cast as they are read
+         k, v, sel.astype(jnp.float32)),
+        grid=(Hc, C // Qb, B // Tk), reduce_axis=2,
+        in_specs=[
+            pl.BlockSpec((None, G, Qb, D), lambda g, i, j, at_ref: (
+                g, 0, q_block(i, at_ref), 0)),
+            pl.BlockSpec((Tk, D), lambda g, i, j, at_ref: (
+                k_tile(i, j, at_ref), g)),
+            pl.BlockSpec((Tk, D), lambda g, i, j, at_ref: (
+                k_tile(i, j, at_ref), g)),
+            pl.BlockSpec((None, NB, Qb), lambda g, i, j, at_ref: (
+                g, 0, q_block(i, at_ref))),
+        ],
+        out_specs=[pl.BlockSpec((Qb, G * D),
+                                lambda g, i, j, at_ref: (i, g))],
+        out_shape=[jax.ShapeDtypeStruct((C, H * D), jnp.float32)],
+        scratch=[pltpu.VMEM((G, Qb, D), jnp.float32),
+                 pltpu.VMEM((G, Qb, _MIN_LANES), jnp.float32),
+                 pltpu.VMEM((G, Qb, _MIN_LANES), jnp.float32),
+                 pltpu.VMEM((Tk, D), dt), pltpu.VMEM((Tk, D), dt),
+                 pltpu.VMEM((Qb, Tk), jnp.float32)],
+        scratch_fill=(0.0, _NEG_INF, 0.0),
+        tile=tile, finalize=finalize, tile_live=live,
+        scalar_prefetch=(at,), interpret=interpret,
+        name="sparse_prefill_attention",
+        metadata={"kernel": "sparse_prefill_attention"},
+        # the block's share as `sparse_prefill_tiles` counts it, and room
+        # for the streamed tiles, the mask and the body's temporaries
+        vmem_limit_bytes=None if interpret else (
+            Qb * G * (16 * D + 8 * _MIN_LANES) + 16 * 1024 * 1024))
+    return out[0].reshape(C, H, D)
 
 
 # ---------------------------------------------------------------------------

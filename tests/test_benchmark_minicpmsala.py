@@ -22,8 +22,45 @@ _the_cell_as_pr48_left_it = test_the_cell_is_the_issues          # noqa: F821
 def test_the_cell_is_the_issues(manifest):                     # noqa: F811
     """benchmark/tests/ holds PR 48's eight readers to be the manifest's
     LAST entries, and only a `benchmark` PR may edit that file: behind them
-    stands the one reader PR 49 appended, and the rest is as it was."""
-    assert [m["name"] for m in manifest["per_layer"][-1:]] == [
-        "sparse_tiles_per_grid_step"]
+    stand the one reader PR 49 appended and the one PR 50 did, and the rest
+    is as it was."""
+    assert [m["name"] for m in manifest["per_layer"][-2:]] == [
+        "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill"]
     _the_cell_as_pr48_left_it(dict(
-        manifest, per_layer=manifest["per_layer"][:-1]))
+        manifest, per_layer=manifest["per_layer"][:-2]))
+
+
+# the instruction of stage 2's Mosaic call as a prefill executable's text
+# holds it (the 24,576 bucket compiled for a described v5e, PR 50; the
+# backend_config's payload cut), and a consumer that names it as an operand
+_PREFILL_TEXT = '''
+  %fusion.321 = bf16[2,16,2048,128]{3,2,1,0:T(8,128)(2,1)} fusion(%convert_element_type.433), kind=kLoop, calls=%fused_computation.536, metadata={op_name="jit(_prefill_math)/while/body/closed_call/cond/branch_1_fun/gqa_attention/sparse_attention/transpose" stack_frame_id=19}
+  %sparse_prefill_attention.4 = f32[2048,4096]{1,0:T(8,128)} custom-call(%copy-done.60, %fusion.321, %copy_bitcast_fusion.3, %copy_bitcast_fusion.2, %reshape.1746), custom_call_target="tpu_custom_call", operand_layout_constraints={s32[2]{0}, bf16[2,16,2048,128]{3,2,1,0}, f32[24576,256]{1,0}, f32[24576,256]{1,0}, f32[2,384,2048]{2,1,0}}, frontend_attributes={kernel_metadata={"kernel":"sparse_prefill_attention"}}, metadata={op_name="jit(_prefill_math)/while/body/closed_call/cond/branch_1_fun/gqa_attention/sparse_attention/sparse_prefill_attention/pallas_call" stack_frame_id=19}, backend_config={"flag_configs":[]}
+  %fusion.88 = f32[1,2048,4096]{2,1,0:T(8,128)} fusion(%sparse_prefill_attention.4, %p.3), kind=kLoop, calls=%fused_computation.90, metadata={op_name="jit(_prefill_math)/while/body/closed_call/cond/branch_1_fun/gqa_attention/mul" stack_frame_id=21}
+  %fusion.12 = f32[512,2,384]{2,1,0:T(2,128)} fusion(%p.4), kind=kLoop, calls=%fused_computation.14, metadata={op_name="jit(_prefill_math)/while/body/closed_call/cond/branch_1_fun/gqa_attention/while/body/sparse_select/top_k" stack_frame_id=17}
+'''
+
+
+def test_the_prefills_scope_names_the_flash_body_of_stage_2(config):
+    """`sparse_prefill_ms_per_prefill` times the instructions a bucket's
+    prefill executable holds under `sparse_select` and `sparse_attention`
+    (`serve_decode_ssm.step_scope_ops`): stage 2's Mosaic call is among
+    them, so the reading did not fall by losing sight of stage 2; the new
+    reader finds the same instruction by its name; and the step's kernel's
+    readers (`kernel_trace_match.sparse_attention`, a substring of the
+    event's whole instruction) do not match it."""
+    from benchmark import moe_trace, xplane
+    assert "sparse_attention" in config["prefill_trace_scopes"]
+    names = moe_trace.scope_instruction_names(_PREFILL_TEXT,
+                                              "sparse_attention")
+    assert names == {"fusion.321", "sparse_prefill_attention.4"}
+    assert moe_trace.scope_instruction_names(
+        _PREFILL_TEXT, "sparse_select") == {"fusion.12"}
+    kernel = bench_run.load_reader(                     # noqa: F821
+        "sparse_prefill_kernel_ms_per_prefill").__globals__["KERNEL"]
+    lines = [ln.strip() for ln in _PREFILL_TEXT.strip().splitlines()]
+    assert [xplane.short_name(ln) for ln in lines
+            if kernel in xplane.short_name(ln)] == [
+                "sparse_prefill_attention.4"]
+    step_kernel = config["kernel_trace_match"]["sparse_attention"]
+    assert not [ln for ln in lines if step_kernel in ln]

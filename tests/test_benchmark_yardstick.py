@@ -95,9 +95,9 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       "minicpmsala_longdoc_mixed"]}
     # appended, not inserted: only PR 35's five readers, PR 38's one,
     # PR 39's nine, PR 42's six, PR 44's five, PR 45's one, PR 48's
-    # eight and PR 49's one stand behind it
+    # eight, PR 49's one and PR 50's one stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 37
+        manifest["per_layer"]) - 38
 
 
 # ---------------------------------------------------------------------------
@@ -142,8 +142,8 @@ def test_decode_early_launch_share_reader(case, spans, want):
 def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # PR 39's nine readers, PR 42's six, PR 44's five, PR 45's one,
-    # PR 48's eight and PR 49's one stand behind it
-    assert manifest["per_layer"][-31] == {
+    # PR 48's eight, PR 49's one and PR 50's one stand behind it
+    assert manifest["per_layer"][-32] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -157,4 +157,4 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       "minicpmsala_longdoc_mixed"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-31]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-32]["workloads"] == e2e["workloads"]

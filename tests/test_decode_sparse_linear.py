@@ -325,6 +325,145 @@ def test_the_tiles_a_step_stages_come_from_the_shapes():
             or 8 * T * block * D * size > pk._SPARSE_STAGE_BYTES
 
 
+def _prefill_case(seed, C, B, block, chunk, true_len, G=2, D=8,
+                  picks="drawn", topk=3):
+    """Seeded operands of `sparse_prefill_attention`: chunk `chunk`'s C
+    queries, B buffered rows (zeros at and past `true_len`, as the prefill
+    keeps them) and a selection a (query, K/V head).  `picks`: "drawn" =
+    the query's own block and `topk` drawn of those in sight; "all" = every
+    block in sight; "own" = the query's own block alone (every earlier key
+    tile holds nothing it sees)."""
+    rng = np.random.default_rng(seed)
+    Hc = 2
+    q = jnp.asarray(rng.normal(size=(C, Hc * G, D)), jnp.float32)
+    live = (np.arange(B) < true_len)[:, None]
+    k, v = (jnp.asarray(rng.normal(size=(B, Hc * D)) * live, jnp.float32)
+            for _ in range(2))
+    sel = np.zeros((C, Hc, B // block), bool)
+    for t in range(C):
+        own = min(chunk * C + t, B - 1) // block
+        for g in range(Hc):
+            sel[t, g, own] = True
+            if picks == "all":
+                sel[t, g, :own] = True
+            elif picks == "drawn":
+                sel[t, g, rng.permutation(own + 1)[:topk]] = True
+    return q, k, v, jnp.asarray(sel.transpose(1, 2, 0))
+
+
+# C = 32 queries a chunk, B = 128 buffered rows (4 chunks), blocks of 16
+SPARSE_PREFILL_CASES = {
+    "the first chunk": dict(chunk=0, true_len=32),
+    "a middle chunk": dict(chunk=2, true_len=128),
+    "the bucket's last chunk": dict(chunk=3, true_len=128),
+    "the prompt ends inside the chunk": dict(chunk=2, true_len=77),
+    "the prompt ends inside a query block": dict(
+        chunk=1, true_len=45, block_q=8, block_kv=16),
+    "the prompt ends on the chunk's edge": dict(chunk=1, true_len=64,
+                                                block_q=8, block_kv=16),
+    "the prompt's one position": dict(chunk=0, true_len=1, block_q=8,
+                                      block_kv=16),
+    "every block in sight is selected": dict(chunk=2, true_len=96,
+                                             picks="all", block_q=16,
+                                             block_kv=32),
+    "tiles that hold nothing a query sees": dict(
+        chunk=3, true_len=128, picks="own", block_q=8, block_kv=16),
+    "sixteen query heads a K/V head": dict(chunk=1, true_len=60, G=16,
+                                           block_q=16, block_kv=32),
+    "one query head a K/V head": dict(chunk=3, true_len=120, G=1,
+                                      block_q=16, block_kv=64),
+    # a block of 8 queries ends inside a 48-key tile and a tile inside a
+    # block of queries: neither frontier is the other's multiple
+    "tiles that do not divide each other's frontier": dict(
+        chunk=1, true_len=96, B=96, block_q=8, block_kv=48),
+    "queries past the keys' tile": dict(chunk=1, true_len=64, block_q=32,
+                                        block_kv=16),
+    "bf16 operands": dict(chunk=2, true_len=90, block_q=16, block_kv=32,
+                          compute_dtype="bfloat16", atol=3e-2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SPARSE_PREFILL_CASES))
+def test_sparse_prefill_attention_against_its_reference(name):
+    """The flash body of the chunked prefill's stage 2 under interpret
+    emulation against the parent's form (running softmax over tiles of C
+    keys in plain XLA): the prompt's positions of the chunk are equal, a
+    block of queries wholly past the prompt reads zeros, nothing is NaN
+    whatever a tile holds."""
+    case = dict(SPARSE_PREFILL_CASES[name])
+    C, block = 32, 16
+    B, chunk, true_len = case.pop("B", 128), case["chunk"], case["true_len"]
+    q, k, v, sel = _prefill_case(
+        3, C, B, block, chunk, true_len, G=case.get("G", 2),
+        picks=case.get("picks", "drawn"))
+    Qb, Tk = pk.sparse_prefill_tiles(C, B, q.shape[1] // 2, 8, block)
+    Qb, Tk = case.get("block_q", Qb), case.get("block_kv", Tk)
+    out = np.asarray(pk.sparse_prefill_attention(
+        q, k, v, sel, jnp.int32(chunk), jnp.int32(true_len), block,
+        block_q=Qb, block_kv=Tk, compute_dtype=case.get("compute_dtype")))
+    want = np.asarray(pk.sparse_prefill_attention_reference(
+        q, k, v, sel, chunk, block))
+    n = min(C, true_len - chunk * C)
+    assert n >= 1 and np.isfinite(out).all()
+    np.testing.assert_allclose(out[:n], want[:n],
+                               atol=case.get("atol", 1e-5))
+    assert np.abs(out[:n]).max() > 1e-2
+    dead = -(-n // Qb) * Qb                 # the first wholly dead block
+    assert not out[dead:].any()
+    if case.get("picks") == "all":
+        # dense causal attention over the prompt, computed here
+        H, G = q.shape[1], q.shape[1] // 2
+        kh, vh = (np.repeat(np.asarray(t).reshape(B, 2, 8), G, axis=1)
+                  for t in (k, v))
+        s = np.einsum("qhd,khd->hqk", np.asarray(q), kh) / np.sqrt(8.0)
+        s = np.where(np.arange(B)[None, None]
+                     <= chunk * C + np.arange(C)[None, :, None], s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        dense = np.einsum("hqk,khd->qhd", p / p.sum(-1, keepdims=True), vh)
+        np.testing.assert_allclose(out[:n], dense[:n], atol=1e-5)
+
+
+def test_a_row_that_sees_nothing_in_a_tile_keeps_its_running_state():
+    """With key tiles of one block each, a query of chunk 3 that selected
+    its own block alone walks six tiles in which it sees nothing before the
+    one it does: its result is, bit for bit, what the same rows give as
+    chunk 0's, where its own tile is the first (no rescale by a masked
+    tile's maximum, no exponent of a masked score)."""
+    C, B, block = 32, 128, 16
+    q, k, v, sel = _prefill_case(4, C, B, block, 3, 128, picks="own")
+    late = np.asarray(pk.sparse_prefill_attention(
+        q, k, v, sel, 3, 128, block, block_q=8, block_kv=16))
+    first = np.asarray(pk.sparse_prefill_attention(
+        q, jnp.roll(k, -96, axis=0), jnp.roll(v, -96, axis=0),
+        jnp.roll(sel, -6, axis=1), 0, 32, block, block_q=8, block_kv=16))
+    assert np.isfinite(late).all() and np.abs(late).max() > 1e-2
+    np.testing.assert_array_equal(late, first)
+
+
+def test_the_prefill_tiles_come_from_the_shapes():
+    """(Qb, Tk) from the shapes alone: divisors of the chunk and the
+    buffer, whole tiles under Mosaic and the block's state inside its VMEM
+    budget; the reference where Mosaic has no such tiles."""
+    Qb, Tk = pk.sparse_prefill_tiles(2048, 16384, 16, 128, 64, mosaic=True)
+    assert 2048 % Qb == 0 and Qb % 16 == 0 and Qb >= 128
+    assert 16384 % Tk == 0 and Tk % 128 == 0 and Tk % 64 == 0
+    assert Qb * 16 * (16 * 128 + 8 * 128) <= pk._PREFILL_BLOCK_BYTES
+    assert pk.sparse_prefill_tiles(2048, 16384, 16, 96, 64,
+                                   mosaic=True) is None
+    assert pk.sparse_prefill_tiles(2048, 16384, 16, 128, 12,
+                                   mosaic=True) is None
+    # the tests' stack: head size 8, blocks of 16, interpret emulation
+    assert pk.sparse_prefill_tiles(32, 128, 2, 8, 16, mosaic=False) \
+        == (32, 128)
+    q, k, v, sel = _prefill_case(5, 32, 128, 16, 1, 64)
+    with pk.mosaic_lowering():
+        # no whole tiles at head size 8: the reference, in plain XLA
+        out = pk.sparse_prefill_attention(q, k, v, sel, 1, 64, 16)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(pk.sparse_prefill_attention_reference(
+            q, k, v, sel, 1, 16)))
+
+
 def test_the_lightning_step_is_ssm_update_and_the_chunked_scan():
     """S <- decay S + v (outer) k; o = S . q through `pk.ssm_update` a
     position at a time, against the plain recurrence and against

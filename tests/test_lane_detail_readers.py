@@ -260,9 +260,9 @@ def test_token_out_frames_per_pass_is_declared_for_the_decode_cells():
                  "better": "higher", "source": "program_span",
                  "layer": "serving front", "moves": "tokens_per_s",
                  "workloads": DECODE_CELLS}
-    # (last but for the eight readers PR 48 and the one PR 49 appended
-    # behind it)
-    assert manifest["per_layer"][-10] is m
+    # (last but for the eight readers PR 48, the one PR 49 and the one
+    # PR 50 appended behind it)
+    assert manifest["per_layer"][-11] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
 
@@ -311,7 +311,8 @@ def test_sparse_tiles_per_grid_step_is_declared_for_its_cell_alone():
                  "better": "higher", "source": "program_counter",
                  "layer": "kernels", "moves": "tokens_per_s",
                  "workloads": ["minicpmsala_longdoc_mixed"]}
-    assert manifest["per_layer"][-1] is m
+    # (last but for the one reader PR 50 appended behind it)
+    assert manifest["per_layer"][-2] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
     # the one cell whose stack has sparse layers
@@ -321,6 +322,80 @@ def test_sparse_tiles_per_grid_step_is_declared_for_its_cell_alone():
                          if c["name"] == w["config"]][0]["file"])).get(
                   "model", {}).get("layer_types", [])]
     assert sparse == m["workloads"]
+
+
+# ---------------------------------------------------------------------------
+# PR 50: `sparse_prefill_kernel_ms_per_prefill`, the flash body of a
+# prefill's stage 2 by the NAME of its Mosaic call
+# ---------------------------------------------------------------------------
+
+def _prefill(a, b, prompt=9000):
+    return span("serving/prefill_compute", a, b, prompt=prompt)
+
+
+def _kernel(n, a, b):
+    return ("%%sparse_prefill_attention.%d = f32[2048,4096]{1,0} "
+            "custom-call(%%fusion.321, %%copy.1), custom_call_target="
+            "\"tpu_custom_call\"" % n, a, b)
+
+
+# two prefills inside the sub-window 0..10 (two sparse layers' calls in
+# three chunks of the first, 0.6 s; one chunk of the second, 0.3 s), a
+# third that straddles the sub-window's end, and what must not count: the
+# step's kernel, a consumer that NAMES the call among its operands, the
+# plain-XLA operations of the scope
+_KERNEL_DEVICE = [
+    _kernel(4, 1.0, 1.1), _kernel(5, 1.2, 1.3), _kernel(4, 1.4, 1.5),
+    _kernel(5, 1.6, 1.7), _kernel(4, 1.8, 1.9), _kernel(5, 2.0, 2.1),
+    ("%fusion.701 = f32[2048,4096]{1,0} fusion(%sparse_prefill_attention.4)",
+     2.1, 2.4),
+    ("%sparse_decode_attention.3 = f32[24,2,16,128]{3,2,1,0} custom-call("
+     "%p.1)", 3.0, 3.5),
+    _kernel(4, 5.0, 5.1), _kernel(5, 5.2, 5.4),
+    ("%copy_bitcast_fusion.2 = f32[24576,256]{1,0} fusion(%p.2)", 5.4, 5.6),
+    _kernel(4, 9.5, 9.9), _kernel(5, 10.2, 10.6)]
+
+
+@pytest.mark.parametrize("case,prefills,device,want", [
+    ("two_prefills", [_prefill(0.5, 2.5), _prefill(4.5, 6.0)],
+     _KERNEL_DEVICE, 1e3 * (0.6 + 0.3) / 2),
+    # a prefill that ends past the sub-window is not counted, nor its calls
+    ("one_straddles_the_end", [_prefill(0.5, 2.5), _prefill(4.5, 6.0),
+                               _prefill(9.0, 11.0)],
+     _KERNEL_DEVICE, 1e3 * (0.6 + 0.3) / 2),
+    # a call outside every prefill's span (a warm-up's) is nobody's
+    ("a_call_outside_the_spans", [_prefill(4.5, 6.0)], _KERNEL_DEVICE,
+     1e3 * 0.3),
+    # the parent: stage 2 in plain XLA, no such event -> no reading
+    ("the_parent", [_prefill(0.5, 2.5)],
+     [e for e in _KERNEL_DEVICE
+      if not e[0].startswith("%sparse_prefill_attention")], None),
+    ("no_prefill_in_the_window", [_prefill(12.0, 13.0)], _KERNEL_DEVICE,
+     None),
+    ("nothing", [], [], None)])
+def test_sparse_prefill_kernel_ms_per_prefill_reads_the_call_by_its_name(
+        case, prefills, device, want):
+    run = dict(run_facts(), trace_window=(0.0, 10.0),
+               trace_window_monotonic=(MONO, MONO + 10.0))
+    got = reader("sparse_prefill_kernel_ms_per_prefill")(
+        prefills, trace_of(device), run)
+    assert got == (None if want is None else pytest.approx(want))
+
+
+def test_sparse_prefill_kernel_ms_per_prefill_is_declared_last():
+    manifest = bench_run.load_json(bench_run.MANIFEST)
+    assert manifest["per_layer"][-1] == {
+        "name": "sparse_prefill_kernel_ms_per_prefill", "unit": "ms",
+        "better": "lower", "source": "device_trace", "layer": "kernels",
+        "moves": "tokens_per_s", "workloads": ["minicpmsala_longdoc_mixed"]}
+    assert len(manifest["per_layer"]) == 64
+    assert all(os.path.exists(os.path.join(
+        bench_run.LAYERS_DIR, m["name"] + ".py"))
+        for m in manifest["per_layer"])
+    # its spans and its kernel are those of the reader it stands beside
+    assert manifest["per_layer"][-1]["workloads"] == [
+        m for m in manifest["per_layer"]
+        if m["name"] == "sparse_prefill_ms_per_prefill"][0]["workloads"]
 
 
 # ---------------------------------------------------------------------------
@@ -360,8 +435,8 @@ def test_spans_older_than_the_phase_spans_read_as_nothing(name):
 def test_the_nine_are_declared_last_for_the_five_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # (last but for the six readers PR 42, the five PR 44, the one PR 45,
-    # the eight PR 48 and the one PR 49 appended behind them)
-    last = manifest["per_layer"][-30:-21]
+    # the eight PR 48, the one PR 49 and the one PR 50 appended behind them)
+    last = manifest["per_layer"][-31:-22]
     assert [m["name"] for m in last] == list(NINE)
     for m in last:
         assert m["workloads"] == DECODE_CELLS, m["name"]

@@ -672,10 +672,8 @@ def test_sparse_linear_step_and_chunked_prefill_compile_for_the_chip(
     layer makes ONE Mosaic call (`sparse_decode_attention`, under the scopes
     `sparse_attention`, beside stage 1's plain XLA under `sparse_select`)
     and a linear layer one (`ssm_update`), each with metadata of its own,
-    and no table- or layer-sized copy is made.  And the prefill of the
-    largest bucket, 24,576 positions in twelve chunks inside ONE executable:
-    its temporaries are a chunk's (under 2 GB where the bucket's [T, 16384]
-    gate and up alone would be 3.2), with no Mosaic call."""
+    and no table- or layer-sized copy is made.  (The prefill's buckets:
+    `test_a_chunked_prefill_holds_the_flash_body_of_its_stage_2`.)"""
     import json
     import os
     from benchmark import moe_trace
@@ -745,14 +743,86 @@ def test_sparse_linear_step_and_chunked_prefill_compile_for_the_chip(
     assert ma.alias_size_in_bytes >= held, (ma.alias_size_in_bytes, held)
     assert ma.temp_size_in_bytes < 0.1e9, ma.temp_size_in_bytes
     assert text.count(" while(") == 1
-    bucket = max(meta["prefill_buckets"])
+
+
+@pytest.fixture(scope="module")
+def sparse_linear_predictor(one_chip):
+    """(`minicpm_sala_9b`'s meta, a weightless predictor of it on the
+    described chip, its state's specs with bfloat16 weights at rest)."""
+    import json
+    import os
+    from paddle_tpu.inference import decode as dec
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "minicpm_sala_9b.json")) as f:
+        meta = json.load(f)["model"]
+    pred, state = described_predictor(meta, list(one_chip.device_set)[0])
+    return meta, pred, {n: jax.ShapeDtypeStruct(
+        s.shape, jnp.bfloat16 if dec._bf16_at_rest(n, s) else np.float32,
+        sharding=s.sharding) for n, s in state.items()}
+
+
+# the 24,576 bucket's temporaries with stage 2 in plain XLA (PR 49's
+# executable, compiled here for the same described chip)
+PARENT_PREFILL_TEMPORARIES = 1.4976e9
+
+
+@pytest.mark.parametrize("bucket", [8192, 16384, 24576])
+def test_a_chunked_prefill_holds_the_flash_body_of_its_stage_2(
+        sparse_linear_predictor, bucket):
+    """A prefill bucket of `minicpm_sala_9b`, its chunks of 2,048 positions
+    inside ONE executable: the chunk body makes ONE Mosaic call a sparse
+    layer, `sparse_prefill_attention` (metadata of its own, under the scope
+    `sparse_attention`, where the benchmark's reader of that scope finds it
+    by name; a name the step's kernel's readers do not match), over the
+    chunk's queries as XLA laid them out [K/V heads, G, C, Dh] in bfloat16,
+    the bucket's K and V rows as they are and the selection a BLOCK (never
+    a position) with the queries last; no tile of scores [512, 2, 16, 2048]
+    is a temporary any more, and the temporaries are a chunk's, no larger
+    than they were.  The selection's `top_k` is a sort of [512, 2, blocks]
+    with the QUERIES on the lanes (layout {0,2,1}), as it was before the
+    kernel: sorted along the lanes it took 27 times as long on the chip
+    (PERF.md section 6, PR 50), and which one XLA picks follows from how the
+    selection leaves `_chunk_attention`'s map."""
+    from benchmark import moe_trace
+    meta, pred, state = sparse_linear_predictor
+    assert bucket in meta["prefill_buckets"]
     compiled = compile_phase(
         pred, state, pred._prefill_math,
         (jax.ShapeDtypeStruct((1, bucket), np.int32),
          jax.ShapeDtypeStruct((), np.int32)), tables=())
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
     text = compiled.as_text()
-    assert not _custom_calls(text)
+    calls = _custom_calls(text)
+    names = [c.split(" = ")[0].strip().lstrip("%") for c in calls]
+    assert len(calls) == 2 and not [c for c in calls
+                                    if "kernel_metadata={}" in c]
+    assert all(n.startswith("sparse_prefill_attention") for n in names)
+    assert not [n for n in names if "sparse_decode_attention" in n]
+    assert set(names) <= moe_trace.scope_instruction_names(
+        text, "sparse_attention")
     for scope in ("sparse_select", "sparse_attention", "linear_attention",
                   "ssm_scan"):
         assert moe_trace.scope_instruction_names(text, scope), scope
+    C, Hc, Dh = meta["prefill_chunk"], meta["n_kv_heads"], meta["head_dim"]
+    G = meta["n_heads"] // Hc
+    for c in calls:
+        ops = re.findall(r"%[\w.\-]+", c.split("custom-call(")[1].split(
+            "), custom_call_target")[0])
+        shapes = [re.findall(r"^\s*%s = (\w+\[[\d,]*\])" % re.escape(o),
+                             text, re.M)[0] for o in ops]
+        assert c.split(" = ")[1].startswith(
+            "f32[%d,%d]" % (C, meta["n_heads"] * Dh)), c[:200]
+        assert shapes == [
+            "s32[2]", "bf16[%d,%d,%d,%d]" % (Hc, G, C, Dh),
+            "f32[%d,%d]" % (bucket, Hc * Dh),
+            "f32[%d,%d]" % (bucket, Hc * Dh),
+            "f32[%d,%d,%d]" % (Hc, bucket // meta["sparse_block"], C)], \
+            shapes
+    assert "f32[%d,%d,%d,%d]" % (
+        512, Hc, G, C) not in text
+    sorts = [ln.split(" = (")[1].split(":")[0] for ln in text.splitlines()
+             if " sort(" in ln and "/sparse_select/" in ln]
+    assert sorts == ["f32[512,%d,%d]{0,2,1" % (
+        Hc, bucket // meta["sparse_block"])] * 2, sorts
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries <= PARENT_PREFILL_TEMPORARIES, temporaries

@@ -386,7 +386,33 @@ BLOCK_DEFAULTS = (
     # far, the linear layers' states); 0 = the bucket whole.  A stack of
     # sparse_attention / linear_attention layers
     ("prefill_chunk", 0),
+    # an attending layer's geometry BY KIND and BY LEAF (none named: the one
+    # geometry above).  `window_kv_heads`: the window_attention layers' own
+    # K/V head count (0 = n_kv_heads), so the rings' rows are not the full
+    # tables'.  A VALUE head of `v_head_dim` lanes beside a key head of
+    # `head_dim` (0 = head_dim; the key an mla stack reads for itself): wv
+    # and the V rows are K/V heads x v_head_dim wide, wo takes n_heads x
+    # v_head_dim, the scale stays 1/sqrt(head_dim).  `rotary_dim`: the first
+    # that many lanes of a q / k head turn (half-split among themselves),
+    # the rest pass through (0 = the whole head).  `window_rope_theta`: the
+    # window layers' own theta (0 = rope_theta).  `value_scale`: v times
+    # this, before it is cached.  `window_sink`: a window layer's softmax
+    # has one learned logit a query head more in its denominator, with no
+    # value (weight `sink` [n_heads] f32): p_j = exp(a_j - m) / (exp(s_h -
+    # m) + sum_j exp(a_j - m))
+    ("window_kv_heads", 0),
+    ("rotary_dim", 0),
+    ("window_rope_theta", 0.0),
+    ("value_scale", 1.0),
+    ("window_sink", False),
 )
+# the keys added since the phases' rev 12, with their defaults
+# (`GenerativePredictor._fingerprint`), BY NAME: a key appended to
+# BLOCK_DEFAULTS after them joins this tuple or bumps the rev, and either
+# way moves none of these out
+_LATER_KEYS = {k: dict(BLOCK_DEFAULTS)[k] for k in (
+    "window_kv_heads", "rotary_dim", "window_rope_theta", "value_scale",
+    "window_sink")}
 _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
                   "position": ("learned", "rope"),
                   "qk_norm": (False, True, "head"),
@@ -643,10 +669,45 @@ def block_of(meta):
     if "conv" in kinds and out["conv_kernel"] < 2:
         raise ValueError("decode meta conv_kernel=%d: a conv layer needs "
                          "at least 2 taps" % out["conv_kernel"])
-    if out["n_kv_heads"] and (out["n_kv_heads"] < 0
-                              or n_heads % out["n_kv_heads"]):
-        raise ValueError("decode meta n_kv_heads=%d does not divide "
-                         "n_heads %d" % (out["n_kv_heads"], n_heads))
+    for key in ("n_kv_heads", "window_kv_heads"):
+        if out[key] and (out[key] < 0 or n_heads % out[key]):
+            raise ValueError("decode meta %s=%d does not divide n_heads %d"
+                             % (key, out[key], n_heads))
+    for key in ("window_kv_heads", "window_rope_theta", "window_sink"):
+        if out[key] and not windowed:
+            raise ValueError(
+                "decode meta %s=%r goes with layer_types window_attention "
+                "(layer_types=%r)" % (key, out[key], list(kinds)))
+    _, dk, dv = slot_state.attention_geometry(meta, out)
+    if "mla" not in kinds and (out["v_head_dim"] < 0 or (
+            dv != dk and "sparse_attention" in kinds)):
+        raise ValueError(
+            "decode meta v_head_dim=%d: a value head's size is >= 1 (0 = "
+            "head_dim), and a sparse_attention layer's kernels take values "
+            "as wide as keys (head_dim %d)" % (out["v_head_dim"], dk))
+    if "mla" not in kinds and dv == dk:
+        out["v_head_dim"] = 0       # said or not, one width: one description
+    rotary = out["rotary_dim"]
+    if rotary and (rotary < 0 or rotary % 2 or rotary > dk
+                   or out["position"] != "rope"
+                   or {"mla", "linear_attention"} & set(kinds)):
+        raise ValueError(
+            "decode meta rotary_dim=%d: an even number of an attention "
+            "head's %d lanes under position=rope (%r); mla and "
+            "linear_attention layers size their rotated lanes themselves "
+            "(layer_types=%r)" % (rotary, dk, out["position"], list(kinds)))
+    if out["window_rope_theta"] and (out["window_rope_theta"] < 0.0
+                                     or out["position"] != "rope"
+                                     or out["rope_layers"] == "linear"):
+        raise ValueError(
+            "decode meta window_rope_theta=%r: the window layers' theta "
+            "under position=rope (%r) with rope_layers all|window (%r)"
+            % (out["window_rope_theta"], out["position"],
+               out["rope_layers"]))
+    if out["value_scale"] != 1.0 and "mla" in kinds:
+        raise ValueError("decode meta value_scale=%r does not go with "
+                         "layer_types mla (its values are its latent rows)"
+                         % out["value_scale"])
     if not 0 <= out["n_dense_layers"] <= n_layers or (
             out["n_dense_layers"] and out["dense_width"] < 1):
         raise ValueError(
@@ -715,7 +776,6 @@ def decode_state_shapes(meta):
     V, D, H, S = (int(meta[k]) for k in
                   ("vocab_size", "d_model", "n_heads", "max_seq_len"))
     Dh = _head_dim(meta, blk)
-    kv_width = (blk["n_kv_heads"] or H) * Dh
     norm_bias = blk["norm"] == "layernorm"
     norms = ("ln1", "ln2") + (("ln1p", "ln2p") if blk["sandwich_norm"]
                               else ())
@@ -756,15 +816,21 @@ def decode_state_shapes(meta):
             if blk["output_gate"]:
                 shapes[p + "wg"] = (D, Hs * P)
         else:
-            shapes[p + "wq"], shapes[p + "wo"] = (D, H * Dh), (H * Dh, D)
-            shapes[p + "wk"] = shapes[p + "wv"] = (D, kv_width)
+            # the layer's own geometry: K/V heads by kind, K and V rows
+            # by leaf
+            Hc, _, Dv = slot_state.attention_geometry(
+                meta, blk, window=op == "window_attention")
+            shapes[p + "wq"], shapes[p + "wo"] = (D, H * Dh), (H * Dv, D)
+            shapes[p + "wk"], shapes[p + "wv"] = (D, Hc * Dh), (D, Hc * Dv)
             if blk["qk_norm"] == "head":
                 shapes[p + "qn_g"] = shapes[p + "kn_g"] = (Dh,)
             elif blk["qk_norm"]:
                 shapes[p + "qn_g"], shapes[p + "kn_g"] = (H * Dh,), (
-                    kv_width,)
+                    Hc * Dh,)
             if blk["output_gate"]:
-                shapes[p + "wg"] = (D, H * Dh)
+                shapes[p + "wg"] = (D, H * Dv)
+            if blk["window_sink"] and op == "window_attention":
+                shapes[p + "sink"] = (H,)
         if op == "attention+ssm":
             d_ssm, conv, wide = _ssm_widths(blk)
             Hs = blk["ssm_heads"]
@@ -949,12 +1015,18 @@ def _rms(x, g, eps):
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * g
 
 
-def _rope(x, positions, theta):
+def _rope(x, positions, theta, lanes=0):
     """Rotary position embedding over the whole head, half-split
     convention: x [..., H, Dh] at `positions` [...] (one per leading
     index) -> x * cos + concat(-x2, x1) * sin with the angles
-    position * theta^(-2i/Dh) repeated over both halves."""
+    position * theta^(-2i/Dh) repeated over both halves.  With `lanes`
+    (meta rotary_dim) the head's first `lanes` lanes turn, as a head of
+    that size would, and the rest pass through."""
     import jax.numpy as jnp
+    if lanes and lanes < x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :lanes], positions, theta), x[..., lanes:]],
+            axis=-1)
     half = x.shape[-1] // 2
     inv = jnp.float32(theta) ** (
         jnp.arange(half, dtype=jnp.float32) * (-2.0 / x.shape[-1]))
@@ -1164,9 +1236,11 @@ def _mark_dead(phase, exc, *sessions):
             sess._dead = (phase, "%s: %s" % (type(exc).__name__, exc))
 
 
-def _causal_attention(q, k, v, scale):
+def _causal_attention(q, k, v, scale, sink=None):
     """Prefill attention oracle: [B, T, H, D] causal, same finite-mask
-    convention as the kernels."""
+    convention as the kernels; v [B, T, H, Dv] -> [B, T, H, Dv].  `sink`
+    [H] f32: a logit a head that joins the softmax's denominator and
+    carries no value (`pallas_kernels.decode_attention`)."""
     import jax.numpy as jnp
     T = q.shape[1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -1174,11 +1248,15 @@ def _causal_attention(q, k, v, scale):
     mask = jnp.arange(T)[None, :] < jnp.arange(T)[:, None] + 1
     s = jnp.where(mask[None, None], s, -1e30)
     m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sink = sink.astype(jnp.float32)[None, :, None, None]
+        m = jnp.maximum(m, sink)
     p = jnp.exp(s - m)
-    o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)) \
-        / jnp.maximum(jnp.sum(p, axis=-1), 1e-20).transpose(0, 2, 1)[
-            ..., None]
-    return o
+    o = jnp.einsum("bhqk,bkhe->bqhe", p, v.astype(jnp.float32))
+    l = jnp.sum(p, axis=-1)
+    if sink is not None:
+        l = l + jnp.exp(sink - m)[..., 0]
+    return o / jnp.maximum(l, 1e-20).transpose(0, 2, 1)[..., None]
 
 
 # Queries a prefill of a stack with window layers attends at a time
@@ -1187,13 +1265,14 @@ def _causal_attention(q, k, v, scale):
 PREFILL_QUERY_BLOCK = 512
 
 
-def _blocked_attention(q, k, v, scale, window=0):
+def _blocked_attention(q, k, v, scale, window=0, sink=None):
     """Prefill attention by BLOCKS of queries, a stack with window layers'
-    form of `_causal_attention`: q [1, B, H, Dh], k / v [1, B, Hc, Dh]
-    (grouped-query: query head a reads K/V head a // (H / Hc), no repeat
-    of K or V) -> [1, B, H, Dh], the oracle's finite-mask convention and
-    its softmax, a block of at most `PREFILL_QUERY_BLOCK` queries at a
-    time (`lax.map`: one block's scores live at a time).
+    form of `_causal_attention`: q [1, B, H, Dh], k [1, B, Hc, Dh], v [1,
+    B, Hc, Dv] (grouped-query: query head a reads K/V head a // (H / Hc),
+    no repeat of K or V) -> [1, B, H, Dv], the oracle's finite-mask
+    convention and its softmax (with its `sink` [H] f32, a logit a head in
+    the denominator), a block of at most `PREFILL_QUERY_BLOCK` queries at
+    a time (`lax.map`: one block's scores live at a time).
 
     `window` 0: a FULL layer, every block against all B keys under the
     causal mask, scores [H, block, B].  `window` W >= 1: a WINDOW layer,
@@ -1203,6 +1282,8 @@ def _blocked_attention(q, k, v, scale, window=0):
     import jax.numpy as jnp
     _, B, H, Dh = q.shape
     Hc = k.shape[2]
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(Hc, H // Hc, 1, 1)
     n = -(-B // int(PREFILL_QUERY_BLOCK))
     Q = -(-B // n)
     pad = n * Q - B
@@ -1229,13 +1310,18 @@ def _blocked_attention(q, k, v, scale, window=0):
             mask = jnp.arange(B)[None] <= qpos
         s = jnp.einsum("qhgd,khd->hgqk", qi, kk) * scale
         s = jnp.where(mask[None, None], s, -1e30)
-        p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-        o = jnp.einsum("hgqk,khd->qhgd", p, vv)
-        return o / jnp.maximum(jnp.sum(p, axis=-1), 1e-20).transpose(
-            2, 0, 1)[..., None]
+        m = jnp.max(s, axis=-1, keepdims=True)
+        if sink is not None:
+            m = jnp.maximum(m, sink)
+        p = jnp.exp(s - m)
+        o = jnp.einsum("hgqk,khe->qhge", p, vv)
+        l = jnp.sum(p, axis=-1)
+        if sink is not None:
+            l = l + jnp.exp(sink - m)[..., 0]
+        return o / jnp.maximum(l, 1e-20).transpose(2, 0, 1)[..., None]
 
     out = jax.lax.map(one, (jnp.arange(n), qb))
-    return out.reshape(n * Q, H, Dh)[:B][None]
+    return out.reshape(n * Q, H, -1)[:B][None]
 
 
 def _ring_rows(rows, true_len, window):
@@ -1716,6 +1802,14 @@ class GenerativePredictor:
                     % (what, kind.noun,
                        list(self._block_meta["layer_types"]),
                        kind.why_not % self._block_meta))
+        blk = self._block_meta
+        if capability in ("mesh", "int8") and blk["v_head_dim"] \
+                and "mla" not in blk["layer_types"]:
+            raise NotImplementedError(
+                "%s has no rule for K and V rows of two widths, and this "
+                "artifact's meta has v_head_dim=%d beside its key heads' "
+                "size (a mesh shards, and an int8 cache scales, rows whose "
+                "heads are one size)" % (what, blk["v_head_dim"]))
 
     def _require_default_block(self, what):
         """Raise for a placement that can hold only the GPT-2-shaped
@@ -1903,8 +1997,16 @@ class GenerativePredictor:
         return (int(m["n_layers"]), int(m["n_heads"]),
                 _head_dim(m, self._block_meta), int(m["d_model"]))
 
-    def _kv_heads(self):
-        return self._block_meta["n_kv_heads"] or int(self.meta["n_heads"])
+    def _kv_heads(self, op="attention"):
+        """K/V heads of a layer whose operator is `op`: the window layers
+        may have their own count (`slot_state.attention_geometry`)."""
+        return slot_state.attention_geometry(
+            self.meta, self._block_meta, window=op == "window_attention")[0]
+
+    @functools.cached_property
+    def _v_head_dim(self):
+        """A value head's lanes (a key head's: `_dims`)."""
+        return slot_state.attention_geometry(self.meta, self._block_meta)[2]
 
     # -- int8 KV cache: quantization epilogues --------------------------
 
@@ -2097,11 +2199,17 @@ class GenerativePredictor:
                 k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
             return _causal_attention(q, k, v, scale)
 
-        def attend_window(q, k, v):
+        def attend_window(q, k, v, sink=None):
             kept["kw"].append(k)
             kept["vw"].append(v)
             with jax.named_scope("window_attention"):
-                return _blocked_attention(q, k, v, scale, window=window)
+                # (a stack with no sink calls it as it always was called:
+                # the plants of benchmark/tests/test_kexaone_cell.py and
+                # tests/test_decode_sliding.py's spy stand in for it with
+                # the signature it had)
+                return _blocked_attention(
+                    q, k, v, scale, window=window,
+                    **({} if sink is None else {"sink": sink}))
 
         def convolve(z, taps):
             # z [1, B, C], taps [C, K]: position t reads z[t - (K-1) .. t]
@@ -2355,11 +2463,14 @@ class GenerativePredictor:
         v_head_dim]; whether it expands the rows or absorbs the
         up-projection, and where it keeps the row, is the phase's.
 
-        An ATTENTION layer: `attend(q, k, v)` gets q [..., Hl, Dh] and
-        k / v [..., K/V heads, Dh] (normed and rotated where the block
-        says so — the cache holds rotated K; under meta rope_layers=window
-        a window_attention layer's alone are rotated) and returns the
-        attention output in q's shape; what it does with k and v (collect
+        An ATTENTION layer: `attend(q, k, v)` gets q [..., Hl, Dh], k
+        [..., K/V heads, Dh] and v [..., K/V heads, Dv] (the layer's OWN
+        K/V heads: a window layer may have its own count; normed and rotated
+        where the block says so — the cache holds rotated K; under meta
+        rope_layers=window a window_attention layer's alone are rotated; a
+        window layer of meta window_sink also `sink=` its [Hl] logits) and
+        returns the attention output [..., Hl, Dv]; what it does with k and
+        v (collect
         them, write them to the slot table or, a WINDOW_ATTENTION layer's,
         to its ring) and which keys a position sees is the phase's, which
         hands a window layer its own `attend`.  A CONV layer (a
@@ -2421,20 +2532,36 @@ class GenerativePredictor:
                 x = x + joins(self._linear(state, p, h, positions, project,
                                            *ssm), "ln1p")
         else:
-            Hkv = self._kv_heads() // tp.size
+            Hkv = self._kv_heads(op) // tp.size
+            Dv = self._v_head_dim
             with (jax.named_scope("gqa_attention") if Hkv != Hl
                   else contextlib.nullcontext()):
                 q, k, v = (project("wq", Hl, "qn_g"),
-                           project("wk", Hkv, "kn_g"), project("wv", Hkv))
+                           project("wk", Hkv, "kn_g"),
+                           project("wv", Hkv, size=Dv))
                 if blk["key_multiplier"] != 1.0:
                     k = k * blk["key_multiplier"]
+                if blk["value_scale"] != 1.0:
+                    v = v * blk["value_scale"]
                 if blk["position"] == "rope" and (
                         blk["rope_layers"] == "all"
                         or (blk["rope_layers"], op) == (
                             "window", "window_attention")):
-                    q = _rope(q, positions, blk["rope_theta"])
-                    k = _rope(k, positions, blk["rope_theta"])
-                mixed = attend(q, k, v).reshape(lead + (Hl * Dh,))
+                    # a theta by kind, over the head's rotated lanes
+                    theta = (op == "window_attention"
+                             and blk["window_rope_theta"]) \
+                        or blk["rope_theta"]
+                    # (`_rope` as it always was called where the whole
+                    # head turns: K-EXAONE's plant replaces it)
+                    turn = functools.partial(
+                        _rope, lanes=blk["rotary_dim"]) \
+                        if blk["rotary_dim"] else _rope
+                    q, k = turn(q, positions, theta), turn(k, positions,
+                                                           theta)
+                # a window layer's learned sink, a logit a query head
+                sink = {"sink": state[p + "sink"]} if (
+                    blk["window_sink"] and op == "window_attention") else {}
+                mixed = attend(q, k, v, **sink).reshape(lead + (Hl * Dv,))
                 if blk["output_gate"]:
                     mixed = mixed * jax.nn.sigmoid(_mm(h, state[p + "wg"]))
                 att = tp.psum(_mm(mixed, state[p + "wo"]))
@@ -2733,10 +2860,14 @@ class GenerativePredictor:
         return tuple(_land(t, i, where, r.reshape(r.shape[:-2] + (-1,)))
                      for t, r in ((kc, k_new), (vc, v_new)))
 
-    def _attend_table(self, q, kc, vc, lengths, ahead, i, tp, window=0):
+    def _attend_table(self, q, kc, vc, lengths, ahead, i, tp, window=0,
+                      sinks=None):
         """The decode kernel over layer i of the carried K/V tables: q
         [N, Hl, Dh], slot n under its first `lengths[n] + ahead`
-        positions -> [N, Hl, Dh].  With `window` W the tables are a window
+        positions -> [N, Hl, Dv] (the V table's rows may hold heads of
+        another size than the K table's; `sinks` [Hl] f32: a window
+        layer's learned logit a head in the softmax's denominator, the
+        kernel's).  With `window` W the tables are a window
         layer's RINGS [window layers, N, W, Hc * Dh] and slot n attends
         under min(lengths[n] + ahead, W) rows: the kernel as it is, for
         rows carry their own rotation and a softmax does not care in which
@@ -2757,14 +2888,15 @@ class GenerativePredictor:
         if window:
             import jax.numpy as jnp
             seen = jnp.minimum(seen, window)
+        more = {} if sinks is None else {"sinks": sinks}
         if tp.size > 1:
             # each member slices its heads' scales out of the baked full
             # table
             return decode_attention_head_slice(
                 q, kc, vc, seen, tp.index() * Hl, Hl,
-                scale=scale, kv_scales=scales, layer=i)
+                scale=scale, kv_scales=scales, layer=i, **more)
         return decode_attention(q, kc, vc, seen, scale=scale,
-                                kv_scales=scales, layer=i)
+                                kv_scales=scales, layer=i, **more)
 
     def _step_logits(self, state, *args, tp=_OFF_MESH):
         """`_step_core` without the routing facts, on the flat arguments
@@ -2860,13 +2992,15 @@ class GenerativePredictor:
                                               lengths, 1, at, tp)
 
             def attend_window(q, k_new, v_new,
-                              at=self._table_layer(i, "ring")):
+                              at=self._table_layer(i, "ring"), sink=None):
                 held["kw"], held["vw"] = self._write(
                     held["kw"], held["vw"], at, where_ring, k_new, v_new,
                     tp)
                 with jax.named_scope("window_attention"):
-                    return self._attend_table(q, held["kw"], held["vw"],
-                                              lengths, 1, at, tp, window=W)
+                    return self._attend_table(
+                        q, held["kw"], held["vw"], lengths, 1, at, tp,
+                        window=W,
+                        **({} if sink is None else {"sinks": sink}))
 
             def attend_sparse(q, k_new, v_new, at=self._table_layer(i),
                               ai=self._table_layer(i, "index")):
@@ -3225,8 +3359,17 @@ class GenerativePredictor:
             "kv_dtype": self._kv_dtype,
             # so do the block's keys (norm, position, qk-norm, FFN kind
             # and routing): equal weight shapes, another function
+            # (a key younger than rev 12 is named only where the meta
+            # moves it: an artifact written before it keeps its
+            # fingerprint, and its stored executables; `v_head_dim`, which
+            # attention layers read since, is older than rev 12 and always
+            # named here: a stack without mla that set it, when it was
+            # ignored, no longer matches its weights' shapes and does not
+            # open, so no stored executable answers for another function)
             "block": [[k, self._block_meta[k]]
-                      for k in sorted(self._block_meta)],
+                      for k in sorted(self._block_meta)
+                      if k not in _LATER_KEYS
+                      or self._block_meta[k] != _LATER_KEYS[k]],
             "rev": 12,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
@@ -3649,7 +3792,8 @@ class DecodeSession:
                     table(*leaves[leaf]) if leaf in leaves else None)
         # what a stack's fetch spans say of it
         self._stack_attrs = slot_state.stack_attrs(
-            predictor._kinds, lambda kind: self._kind_bytes(name=kind))
+            predictor._kinds, lambda kind: self._kind_bytes(name=kind),
+            self._kind_lanes)
         if predictor._block_meta["experts_held"]:
             self._stack_attrs["moe_experts_held"] = \
                 predictor._block_meta["experts_held"][1]
@@ -3676,6 +3820,12 @@ class DecodeSession:
         self._ring_block = None if self._kw is None \
             else attention_tuning.get_decode_config(
                 self._kw.shape[2], predictor._dims()[2], "float32")
+        # what a block of each kind of table weighs in `_kv_stream`'s count:
+        # its K and V rows' lanes over the stack's common measure of them
+        lanes = {kind.name: sum(self._kind_lanes(kind))
+                 for kind, _ in predictor._kinds if kind.live}
+        unit = int(np.gcd.reduce(list(lanes.values()) or [1]))
+        self._block_weight = {name: n // unit for name, n in lanes.items()}
         # set when a call failed after its table was donated to it
         # (`_mark_dead`): (phase, error); every later use raises
         self._dead = None
@@ -3723,6 +3873,12 @@ class DecodeSession:
         return sum(int(getattr(self, "_" + leaf).nbytes)
                    for kind, _ in self.predictor._kinds
                    if getattr(kind, field) == value for leaf in kind.leaves)
+
+    def _kind_lanes(self, kind):
+        """(lanes of a row of the kind's first leaf, of its last): a K
+        row's and a V row's of a kind that holds both."""
+        return tuple(int(getattr(self, "_" + leaf).shape[-1])
+                     for leaf in (kind.leaves[0], kind.leaves[-1]))
 
     def cache_bytes(self):
         """MEASURED footprint of the slot state that bounds the slots:
@@ -3948,7 +4104,11 @@ class DecodeSession:
         layer.  `kv_blocks_live` are the K/V blocks staged,
         `kv_blocks_total` what whole rows would be (trips x slots x
         layers x S / block); a window layer's call counts among both, a
-        ring being its whole row."""
+        ring being its whole row.  A block counts its K and its V tile AT
+        THEIR OWN WIDTHS: a kind's block weighs its two rows' lanes over
+        the stack's common measure of those sums (`_block_weight`), which
+        is 1 where every table's rows are one width and makes the share
+        one of bytes where a ring's rows are not a full table's."""
         trip = np.arange(trips)[:, None]
         if self._ki is not None:
             return self._sparse_stream(
@@ -3964,6 +4124,7 @@ class DecodeSession:
             block = kind.live and edges[kind.live]
             if not block:
                 continue
+            layers = layers * self._block_weight[kind.name]
             # a table addressed by the slot's length under the positions
             # themselves, a ring under min(positions, its rows)
             rows = getattr(self, "_" + kind.leaves[0]).shape[2]

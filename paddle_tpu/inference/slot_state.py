@@ -47,12 +47,16 @@ HOLDS = {"attention": ("kv",), "conv": ("conv",), "mla": ("latent",),
 
 # name, leaves  what the kind and its device arrays are called, in order
 # slot          (meta, blk, device) -> what ONE slot holds of it in a layer:
-#               its table is [the layers that hold it, n_slots, *slot]
+#               its table is [the layers that hold it, n_slots, *slot]; a
+#               shape A LEAF, in the leaves' order, where they differ (a K
+#               row of 192 lanes a head beside a V row of 128:
+#               `leaf_slots` reads either)
 # noun, why_not what `GenerativePredictor._require` says where it has no rule
 # total         the total that counts it: "kv_cache_bytes", which bounds the
 #               slots, or "conv_state_bytes", apart
-# attrs         (attribute, kind, "layers" | "bytes"): what a session's fetch
-#               spans carry for it; the benchmark's readers read them by name
+# attrs         (attribute, kind, "layers" | "bytes" | "k_lanes" |
+#               "v_lanes"): what a session's fetch spans carry for it; the
+#               benchmark's readers read them by name
 # and, said of a kind where it is so:
 # cached        the table is at the CACHE dtype (int8 under the quantized
 #               cache), not always float32
@@ -73,6 +77,26 @@ Kind = collections.namedtuple("Kind", (
 def head_dim(meta, blk):
     """A head's size: the meta's `head_dim`, or d_model // n_heads."""
     return blk["head_dim"] or int(meta["d_model"]) // int(meta["n_heads"])
+
+
+def attention_geometry(meta, blk, window=False):
+    """(K/V heads, a key head's lanes, a value head's lanes) of the stack's
+    layers that attend over every position, or with `window` of its
+    window_attention layers, which may have their own K/V head count (meta
+    `window_kv_heads`); a value head is a key head's size unless the meta
+    says otherwise (`v_head_dim`, which an mla stack reads for itself)."""
+    heads = (window and blk["window_kv_heads"]) or blk["n_kv_heads"] \
+        or int(meta["n_heads"])
+    dk = head_dim(meta, blk)
+    dv = dk if "mla" in blk["layer_types"] else blk["v_head_dim"] or dk
+    return heads, dk, dv
+
+
+def _k_and_v(rows, heads, dk, dv):
+    """A K and a V leaf of `rows` flat rows: one shape where a value head
+    is a key head's size, one a leaf where it is not."""
+    return (rows, heads * dk) if dk == dv else ((rows, heads * dk),
+                                                (rows, heads * dv))
 
 
 def ssm_widths(blk):
@@ -112,9 +136,10 @@ def _kv_rows(meta, blk, device):
     """(S, Hc * Dh): a K (or V) row for every cached position of a layer
     that ATTENDS OVER ALL OF THEM (an attention layer, an attention+ssm
     layer), addressed by the slot's length: ONE FLAT ROW a position, its Hc
-    K/V heads' Dh features side by side, on every placement."""
-    return (int(meta["max_seq_len"]),
-            (blk["n_kv_heads"] or int(meta["n_heads"])) * head_dim(meta, blk))
+    K/V heads' Dh features side by side, on every placement.  Where a value
+    head is not a key head's size, (S, Hc * Dk) and (S, Hc * Dv)."""
+    return _k_and_v(int(meta["max_seq_len"]),
+                    *attention_geometry(meta, blk))
 
 
 def _latent_rows(meta, blk, device):
@@ -150,10 +175,11 @@ def _ring(meta, blk, device):
     `sliding_window`).  A window layer attends over a position's last W
     keys and no others, so W rows a slot are all it ever reads: position
     p's row lies at p % W and is overwritten by position p + W's, where a
-    full layer reserves `max_seq_len` rows.  A row is a full layer's flat
-    row, so the ring at rest is the decode kernel's operand too
-    (`GenerativePredictor._attend_table`)."""
-    return blk["sliding_window"], _kv_rows(meta, blk, device)[1]
+    full layer reserves `max_seq_len` rows.  A row is flat as a full
+    layer's is, of the window layers' OWN K/V heads, so the ring at rest is
+    the decode kernel's operand too (`GenerativePredictor._attend_table`)."""
+    return _k_and_v(blk["sliding_window"],
+                    *attention_geometry(meta, blk, window=True))
 
 
 def index_rows(max_seq_len, blk):
@@ -171,8 +197,8 @@ def _index_rows(meta, blk, device):
     by position // stride and not by the position: row j is written when
     position stride * j + size - 1 lands and read by stage 1 of every later
     token (`decode._sparse_select`)."""
-    return (index_rows(meta["max_seq_len"], blk),
-            _kv_rows(meta, blk, device)[1])
+    heads, dk, _ = attention_geometry(meta, blk)
+    return index_rows(meta["max_seq_len"], blk), heads * dk
 
 
 # (undoing either would take a snapshot, which no phase keeps)
@@ -213,7 +239,11 @@ KINDS = (
          attrs=(("full_layers", "kv", "layers"),
                 ("window_layers", "ring", "layers"),
                 ("full_kv_bytes", "kv", "bytes"),
-                ("window_kv_bytes", "ring", "bytes")),
+                ("window_kv_bytes", "ring", "bytes"),
+                ("full_k_lanes", "kv", "k_lanes"),
+                ("full_v_lanes", "kv", "v_lanes"),
+                ("window_k_lanes", "ring", "k_lanes"),
+                ("window_v_lanes", "ring", "v_lanes")),
          per_head=True, live="window"),
     Kind("index", ("ki",), _index_rows, "an indexer's compressed-key cache",
          "a sparse_attention layer keeps one compressed key every "
@@ -240,21 +270,34 @@ def kinds_held(meta, blk):
         if n)
 
 
+def leaf_slots(kind, meta, blk, device):
+    """What ONE slot holds of `kind` in a layer, a shape a leaf in the
+    leaves' order (`Kind.slot` gives one for all, or one each)."""
+    slot = kind.slot(meta, blk, device)
+    return slot if isinstance(slot[0], tuple) else (slot,) * len(kind.leaves)
+
+
 def kind_shapes(meta, blk, n_slots, device):
     """{kind: its table's shape} of an `n_slots` session of the stack on
-    `device`, the kinds it holds."""
-    return {kind.name: (layers, int(n_slots)) + kind.slot(meta, blk, device)
-            for kind, layers in kinds_held(meta, blk)}
+    `device`, the kinds it holds; a tuple of them, one a leaf, for a kind
+    whose leaves differ (K and V rows of two widths)."""
+    out = {}
+    for kind, layers in kinds_held(meta, blk):
+        shapes = tuple((layers, int(n_slots)) + slot
+                       for slot in leaf_slots(kind, meta, blk, device))
+        out[kind.name] = shapes[0] if len(set(shapes)) == 1 else shapes
+    return out
 
 
 def slot_leaves(meta, blk, n_slots, device, kv_dtype="float32"):
     """{leaf: (shape, numpy dtype)} of an `n_slots` session of the stack
     on `device` under the cache dtype `kv_dtype`, in the order every phase
     over the slots takes and returns them."""
-    shapes = kind_shapes(meta, blk, n_slots, device)
-    return {leaf: (shapes[kind.name], np.dtype(
+    return {leaf: ((layers, int(n_slots)) + slot, np.dtype(
         np.int8 if kind.cached and kv_dtype == "int8" else np.float32))
-        for kind, _ in kinds_held(meta, blk) for leaf in kind.leaves}
+        for kind, layers in kinds_held(meta, blk)
+        for leaf, slot in zip(kind.leaves, leaf_slots(kind, meta, blk,
+                                                      device))}
 
 
 def state_bytes(meta, blk, n_slots, device, kv_dtype="float32"):
@@ -279,10 +322,12 @@ def state_bytes(meta, blk, n_slots, device, kv_dtype="float32"):
     return kinds, totals
 
 
-def stack_attrs(held, nbytes):
+def stack_attrs(held, nbytes, lanes):
     """What a session's fetch spans say of its stack: each held kind's
-    `attrs`, the bytes from `nbytes(kind name)`."""
-    sizes = {kind.name: {"layers": n, "bytes": nbytes(kind.name)}
+    `attrs`, the bytes from `nbytes(kind name)`, the lanes of its first and
+    last leaf's row (a K and a V row's) from `lanes(kind)`."""
+    sizes = {kind.name: dict(zip(("k_lanes", "v_lanes"), lanes(kind)),
+                             layers=n, bytes=nbytes(kind.name))
              for kind, n in held}
     return {name: sizes[of][what] for kind, _ in held
             for name, of, what in kind.attrs}

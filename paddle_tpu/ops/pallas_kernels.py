@@ -594,22 +594,26 @@ def kv_last_block(lengths, block_kv, n_blocks, xp=np):
 
 
 def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
-                               kv_scales=None):
+                               kv_scales=None, sinks=None):
     """Plain-XLA oracle/fallback with identical masking semantics:
     q [N, H, D] one new token per slot, k/v caches [N, S, Hc * D] as a
     slot table holds them (a position one flat row, its Hc heads' D
     features side by side), lengths [N] live cached positions per slot
-    -> [N, H, D].
+    -> [N, H, D].  A V row may hold heads of another size than a K row's:
+    v_cache [N, S, Hc * Dv] -> [N, H, Dv] (Hc is the K row's, W / D).
     `kv_scales` [2, H] f32 (required iff the caches are int8) applies
     the same per-head dequant algebra as the kernel: K scale on the
     scores, V scale after the normalizing divide.  Caches of fewer heads
     than q's are grouped-query: query head a reads K/V head a // (H /
-    cache heads), spelled here as a repeat of the K/V heads."""
+    cache heads), spelled here as a repeat of the K/V heads.
+    `sinks` [H] f32: a logit a query head that joins the softmax's
+    denominator and carries no value (an extra key whose value is 0)."""
     import jax.numpy as jnp
     N, S = k_cache.shape[0], k_cache.shape[1]
     H, D = q.shape[1], q.shape[-1]
     scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
-    k_cache, v_cache = (t.reshape(N, S, -1, D) for t in (k_cache, v_cache))
+    k_cache = k_cache.reshape(N, S, -1, D)
+    v_cache = v_cache.reshape(N, S, k_cache.shape[2], -1)
     if k_cache.shape[2] != H:
         k_cache, v_cache = (jnp.repeat(t, H // t.shape[2], axis=2)
                             for t in (k_cache, v_cache))
@@ -622,8 +626,14 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
         jnp.asarray(lengths).astype(jnp.int32)[:, None, None]
     s = jnp.where(mask, _NEG_INF, s)
     m = jnp.max(s, axis=-1)
+    if sinks is not None:
+        sinks = jnp.asarray(sinks, jnp.float32).reshape(1, H)
+        m = jnp.maximum(m, sinks)
     p = jnp.exp(s - m[..., None])
-    l = jnp.maximum(jnp.sum(p, axis=-1), _TINY)
+    l = jnp.sum(p, axis=-1)
+    if sinks is not None:
+        l = l + jnp.exp(sinks - m)
+    l = jnp.maximum(l, _TINY)
     o = jnp.einsum("nhs,nshd->nhd", p,
                    v_cache.astype(jnp.float32)) / l[..., None]
     if kv_scales is not None:
@@ -633,7 +643,7 @@ def decode_attention_reference(q, k_cache, v_cache, lengths, scale=None,
 
 def decode_attention(q, k_cache, v_cache, lengths, scale=None,
                      block_kv=None, interpret=None, kv_scales=None,
-                     layer=None):
+                     layer=None, sinks=None):
     """Slot-cache decode attention: q [N, H, D] (the one new token of
     each of N slots), k_cache/v_cache [N, S, Hc * D] (the slot table's
     cached keys/values, time-major, a position ONE FLAT ROW: its Hc K/V
@@ -667,6 +677,20 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     reading K/V head a // G: its row of the block diagonal lies on that
     head's lanes, so the one resident tile serves all G query heads of a
     K/V head in the same two contractions.  Float caches only.
+
+    A V ROW OF ANOTHER WIDTH: v_cache [N, S, Hc * Dv] beside k_cache [N, S,
+    Hc * D] (keys of 192 beside values of 128) -> [N, H, Dv].  The same
+    body: the block-diagonal query lies on the K row's lanes, the
+    accumulator and the folded result on the V row's; each table streams
+    its own [block_kv, row] tiles.  With Dv = D it is the call it always
+    was.
+
+    `sinks` [H] f32 (None: none): a learned logit a query head that joins
+    the softmax's denominator and carries no value, p_j = exp(a_j - m) /
+    (exp(s_h - m) + sum_j exp(a_j - m)).  It is the running softmax's
+    START: (m, l, acc) = (s_h, 1, 0) where a call without sinks starts at
+    (-inf, 0, 0), set in the slot's first grid step, so it costs no pass
+    and no column.
 
     With `layer` (a static int) k_cache/v_cache are the STACKED slot
     table [L, N, S, Hc * D] and the kernel reaches that layer through its
@@ -716,15 +740,20 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
     N, H, D = q.shape
     stacked = layer is not None
     if k_cache.ndim != 3 + stacked or k_cache.shape[-3] != N \
-            or k_cache.shape[-1] % D:
+            or k_cache.shape[-1] % D \
+            or v_cache.shape[:-1] != k_cache.shape[:-1] \
+            or v_cache.shape[-1] % (k_cache.shape[-1] // D):
         raise ValueError(
             "decode_attention: a stacked table [L, N, S, Hc * D] goes "
             "with a static `layer`, a single layer [N, S, Hc * D] "
-            "without one, N and D the queries' %d and %d (got caches %s, "
-            "layer=%r)" % (N, D, tuple(k_cache.shape), layer))
+            "without one, N and D the queries' %d and %d, the V table's "
+            "rows those Hc heads' too (got caches %s and %s, layer=%r)"
+            % (N, D, tuple(k_cache.shape), tuple(v_cache.shape), layer))
     S, W = k_cache.shape[-2:]
     Hc = W // D
     G = H // Hc
+    Wv = v_cache.shape[-1]
+    Dv = Wv // Hc
     scale = float(scale if scale is not None else 1.0 / np.sqrt(D))
     kv_dtype = jnp.dtype(k_cache.dtype)
     quant = kv_dtype == jnp.dtype(jnp.int8)
@@ -743,7 +772,7 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
             k_cache, v_cache = k_cache[layer], v_cache[layer]
         return decode_attention_reference(q, k_cache, v_cache, lengths,
                                           scale=scale,
-                                          kv_scales=kv_scales)
+                                          kv_scales=kv_scales, sinks=sinks)
     lengths = jnp.asarray(lengths).astype(jnp.int32).reshape(N)
     n_blocks = S // bkv
     # slot b's stream stops at its last live block: past it the index
@@ -755,15 +784,24 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         # the layer's axis is squeezed out of the block: the body sees
         # the (1, bkv, Hc * D) tile of the single-layer form
         layer = int(layer)
-        kv_spec = pl.BlockSpec(
-            (None, 1, bkv, W), lambda b, j, len_ref, last_ref: (
-                layer, b, jnp.minimum(j, last_ref[b]), 0))
+
+        def kv_spec(lanes):
+            return pl.BlockSpec(
+                (None, 1, bkv, lanes), lambda b, j, len_ref, last_ref: (
+                    layer, b, jnp.minimum(j, last_ref[b]), 0))
     else:
-        kv_spec = pl.BlockSpec(
-            (1, bkv, W), lambda b, j, len_ref, last_ref: (
-                b, jnp.minimum(j, last_ref[b]), 0))
-    # own[a, c]: lane c of a row belongs to the K/V head query head a reads
-    own = (jnp.arange(W)[None, :] // D) == (jnp.arange(H)[:, None] // G)
+        def kv_spec(lanes):
+            return pl.BlockSpec(
+                (1, bkv, lanes), lambda b, j, len_ref, last_ref: (
+                    b, jnp.minimum(j, last_ref[b]), 0))
+
+    def owned(lanes, size):
+        # [a, c]: lane c of a row of `lanes` belongs to the K/V head (of
+        # `size` lanes) that query head a reads
+        return (jnp.arange(lanes)[None, :] // size) == (
+            jnp.arange(H)[:, None] // G)
+    own = owned(W, D)
+    own_v = own if Dv == D else owned(Wv, Dv)
     q_bd = jnp.where(own, jnp.tile(q, (1, 1, Hc)), 0)     # [N, H, W]
     contract = functools.partial(
         jax.lax.dot_general, precision=jax.lax.Precision.HIGHEST,
@@ -773,8 +811,15 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         q_ref, k_ref, v_ref = ctx.ins[:3]
         len_ref = ctx.scalars[0]
         acc_ref, m_ref, l_ref = ctx.scratch
+        if sinks is not None:
+            # a slot's first grid step is always live: the softmax starts
+            # at the sink and not at nothing
+            @pl.when(ctx.reduce_id == 0)
+            def _start():
+                m_ref[...] = jnp.broadcast_to(ctx.ins[-1][...], m_ref.shape)
+                l_ref[...] = jnp.ones_like(l_ref)
         kb = _stage_dequant(k_ref[0], jnp.float32)     # [BKV, W]
-        vb = _stage_dequant(v_ref[0], jnp.float32)
+        vb = _stage_dequant(v_ref[0], jnp.float32)     # [BKV, Wv]
         s = contract(q_ref[0].astype(jnp.float32), kb,
                      (((1,), (1,)), ((), ()))) * scale  # [H, BKV]
         if quant:
@@ -797,21 +842,26 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         o_ref[0] = o.astype(o_ref.dtype)
 
     operands = [q_bd, k_cache, v_cache]
-    q_spec = pl.BlockSpec((1, H, W), lambda b, j, *_: (b, 0, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
+
+    def row_spec(lanes):
+        return pl.BlockSpec((1, H, lanes), lambda b, j, *_: (b, 0, 0))
+    in_specs = [row_spec(W), kv_spec(W), kv_spec(Wv)]
     if quant:
         operands.append(jnp.asarray(kv_scales, jnp.float32).reshape(
             2, H, 1))
         in_specs.append(pl.BlockSpec((2, H, 1),
                                      lambda b, j, *_: (0, 0, 0)))
+    if sinks is not None:
+        operands.append(jnp.asarray(sinks, jnp.float32).reshape(H, 1))
+        in_specs.append(pl.BlockSpec((H, 1), lambda b, j, *_: (0, 0)))
     out = tiled_contraction(
         tuple(operands),
         grid=(N, n_blocks),
         reduce_axis=1,
         in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((N, H, W), q.dtype),
-        scratch=[pltpu.VMEM((H, W), jnp.float32),
+        out_specs=row_spec(Wv),
+        out_shape=jax.ShapeDtypeStruct((N, H, Wv), q.dtype),
+        scratch=[pltpu.VMEM((H, Wv), jnp.float32),
                  pltpu.VMEM((H, _MIN_LANES), jnp.float32),
                  pltpu.VMEM((H, _MIN_LANES), jnp.float32)],
         scratch_fill=(0.0, _NEG_INF, 0.0),
@@ -824,8 +874,8 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None,
         interpret=interpret)
     # row a holds head a's result on the lanes of its K/V head, and on
     # the others what it would be against their values: take its own
-    return jnp.sum(jnp.where(own.reshape(H, Hc, D),
-                             out.reshape(N, H, Hc, D), 0), axis=2)
+    return jnp.sum(jnp.where(own_v.reshape(H, Hc, Dv),
+                             out.reshape(N, H, Hc, Dv), 0), axis=2)
 
 
 def latent_decode_attention_reference(q, table, lengths, value_lanes,
@@ -955,7 +1005,7 @@ def latent_decode_attention(q, table, lengths, value_lanes, scale,
 def decode_attention_head_slice(q, k_cache, v_cache, lengths, head_offset,
                                 n_local_heads, scale=None, block_kv=None,
                                 interpret=None, kv_scales=None,
-                                layer=None):
+                                layer=None, sinks=None):
     """Tensor-parallel entry (SERVING.md "Tensor-parallel compute"):
     decode attention over one member's RESIDENT head block of the slot
     table. q/k_cache/v_cache are already the LOCAL head shards
@@ -972,19 +1022,24 @@ def decode_attention_head_slice(q, k_cache, v_cache, lengths, head_offset,
     full table — bit-exact while XLA preserves the compiled reduction
     shape of the head block, ULP-level otherwise (a 1-head-wide block
     schedules the score contraction differently; pinned either way by
-    tests/test_mesh_tp.py)."""
+    tests/test_mesh_tp.py).  `sinks` arrives whole too, [H_total] (a
+    replicated weight), and is sliced the same way."""
     import jax
     import jax.numpy as jnp
     Hl = int(n_local_heads)
+
+    def mine(full, axis):
+        return jax.lax.dynamic_slice_in_dim(
+            full, jnp.asarray(head_offset, jnp.int32), Hl, axis=axis)
     sc = None
     if kv_scales is not None:
-        full = jnp.asarray(kv_scales, jnp.float32)
-        full = full.reshape(2, -1)                  # [2, H_total]
-        sc = jax.lax.dynamic_slice_in_dim(
-            full, jnp.asarray(head_offset, jnp.int32), Hl, axis=1)
+        # [2, H_total]
+        sc = mine(jnp.asarray(kv_scales, jnp.float32).reshape(2, -1), 1)
+    if sinks is not None:
+        sinks = mine(jnp.asarray(sinks, jnp.float32).reshape(-1), 0)
     return decode_attention(q, k_cache, v_cache, lengths, scale=scale,
                             block_kv=block_kv, interpret=interpret,
-                            kv_scales=sc, layer=layer)
+                            kv_scales=sc, layer=layer, sinks=sinks)
 
 
 # ---------------------------------------------------------------------------

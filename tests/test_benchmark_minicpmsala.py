@@ -20,14 +20,28 @@ _the_cell_as_pr48_left_it = test_the_cell_is_the_issues          # noqa: F821
 
 
 def test_the_cell_is_the_issues(manifest):                     # noqa: F811
-    """benchmark/tests/ holds PR 48's eight readers to be the manifest's
-    LAST entries, and only a `benchmark` PR may edit that file: behind them
-    stand the one reader PR 49 appended and the one PR 50 did, and the rest
-    is as it was."""
-    assert [m["name"] for m in manifest["per_layer"][-2:]] == [
-        "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill"]
+    """benchmark/tests/ holds PR 48's cell, configuration and eight readers
+    to be the manifest's LAST entries and its cell's name the last of every
+    list it joined, and only a `benchmark` PR may edit that file: behind
+    them stand the one reader PR 49 appended, the one PR 50 did, and PR
+    51's cell, configuration, three readers and its cell's name in the
+    lists; the rest is as it was."""
+    later = "mimov2flash_reasoning_decode"
+    assert [m["name"] for m in manifest["per_layer"][-5:]] == [
+        "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill",
+        "kinds_attention_roofline", "attention_share_of_trip",
+        "full_kv_bytes_per_slot"]
+    assert manifest["workloads"][-1]["name"] == later
+    assert manifest["configs"][-1]["name"] == "mimo_v2_flash"
+
+    def as_it_was(entries):
+        return [dict(m, workloads=[w for w in m["workloads"] if w != later])
+                if "workloads" in m else m for m in entries]
     _the_cell_as_pr48_left_it(dict(
-        manifest, per_layer=manifest["per_layer"][:-2]))
+        manifest, workloads=manifest["workloads"][:-1],
+        configs=manifest["configs"][:-1],
+        end_to_end=as_it_was(manifest["end_to_end"]),
+        per_layer=as_it_was(manifest["per_layer"][:-5])))
 
 
 # the instruction of stage 2's Mosaic call as a prefill executable's text

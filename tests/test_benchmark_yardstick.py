@@ -92,12 +92,15 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       # PR 48's cell: the blocks its sparse kernel STAGES
                       # (the selected ones, a K/V head) of every block of
                       # the slots' rows: the selection, not the length
-                      "minicpmsala_longdoc_mixed"]}
+                      "minicpmsala_longdoc_mixed",
+                      # PR 51's cell: a kind's block counts at ITS rows'
+                      # widths (a ring's weighs 2 beside a full table's 1)
+                      "mimov2flash_reasoning_decode"]}
     # appended, not inserted: only PR 35's five readers, PR 38's one,
     # PR 39's nine, PR 42's six, PR 44's five, PR 45's one, PR 48's
-    # eight, PR 49's one and PR 50's one stand behind it
+    # eight, PR 49's one, PR 50's one and PR 51's three stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 38
+        manifest["per_layer"]) - 41
 
 
 # ---------------------------------------------------------------------------
@@ -142,8 +145,9 @@ def test_decode_early_launch_share_reader(case, spans, want):
 def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # PR 39's nine readers, PR 42's six, PR 44's five, PR 45's one,
-    # PR 48's eight, PR 49's one and PR 50's one stand behind it
-    assert manifest["per_layer"][-32] == {
+    # PR 48's eight, PR 49's one, PR 50's one and PR 51's three stand
+    # behind it
+    assert manifest["per_layer"][-35] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -154,7 +158,8 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       "kexaone_decode_mixed_len",
                       # PR 48's cell: a dispatch launched ahead of the
                       # delivery before it, as in every lane
-                      "minicpmsala_longdoc_mixed"]}
+                      "minicpmsala_longdoc_mixed",
+                      "mimov2flash_reasoning_decode"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-32]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-35]["workloads"] == e2e["workloads"]

@@ -62,7 +62,11 @@ ATTRS = {
                  "ssm_state_bytes": 3 * N * 4 * 8 * 16 * 4},
     "kexaone": {"full_layers": 1, "window_layers": 4,
                 "full_kv_bytes": 2 * 1 * N * 64 * 16 * 4,
-                "window_kv_bytes": 2 * 4 * N * W * 16 * 4},
+                "window_kv_bytes": 2 * 4 * N * W * 16 * 4,
+                # (PR 51: a row's lanes by kind and by leaf; one geometry
+                # here, so all four are 2 heads of 8)
+                "full_k_lanes": 16, "full_v_lanes": 16,
+                "window_k_lanes": 16, "window_v_lanes": 16},
 }
 # what each stack is refused, as the helpers before the record refused it
 REFUSED = {"gpt2": (), "olmoe": (),

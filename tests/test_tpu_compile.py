@@ -826,3 +826,92 @@ def test_a_chunked_prefill_holds_the_flash_body_of_its_stage_2(
         Hc, bucket // meta["sparse_block"])] * 2, sorts
     temporaries = compiled.memory_analysis().temp_size_in_bytes
     assert temporaries <= PARENT_PREFILL_TEMPORARIES, temporaries
+
+
+# ---------------------------------------------------------------------------
+# a stack whose attending layers have their own geometries by kind and by
+# leaf (PR 51): `mimo_v2_flash` at the published widths
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def kinds_predictor(one_chip):
+    """(`mimo_v2_flash`'s configuration, a weightless predictor of it on the
+    described chip, its state's specs with bfloat16 weights at rest)."""
+    import json
+    from paddle_tpu.inference import decode as dec
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "mimo_v2_flash.json")) as f:
+        cfg = json.load(f)
+    pred, state = described_predictor(cfg["model"],
+                                      list(one_chip.device_set)[0])
+    return cfg, pred, {n: jax.ShapeDtypeStruct(
+        s.shape, jnp.bfloat16 if dec._bf16_at_rest(n, s) else np.float32,
+        sharding=s.sharding) for n, s in state.items()}
+
+
+def test_kinds_step_updates_four_tables_of_four_widths_in_place(
+        kinds_predictor):
+    """The step window of `mimo_v2_flash` at the cell's 96 slots: the full
+    layers' K and V tables (768 and 512 lanes a row) and the window layers'
+    K and V rings (1,536 and 1,024) are four leaves of four shapes, all
+    donated and aliased; a layer makes ONE Mosaic call, the decode kernel
+    (seven a trip: K tiles and V tiles of their own widths, the window
+    layers' with their sinks), and the arguments and temporaries are what
+    `deployment.decode_slots_arithmetic` says."""
+    cfg, pred, state = kinds_predictor
+    slots = cfg["deployment"]["decode_slots"]
+    shapes = [s.shape for s in pred._step_specs(slots)[:4]]
+    assert shapes == [(2, slots, 4096, 768), (2, slots, 4096, 512),
+                      (5, slots, 128, 1536), (5, slots, 128, 1024)]
+    compiled = compile_phase(pred, state, pred._step_math(),
+                             pred._step_specs(slots), tables=range(4))
+    text = compiled.as_text()
+    kernels = [c for c in _custom_calls(text) if "kernel_metadata={}" in c]
+    assert len(kernels) == 7
+    ma = compiled.memory_analysis()
+    held = sum(4 * int(np.prod(s)) for s in shapes)
+    assert held == pred.kv_cache_bytes(slots) == 4655677440
+    assert ma.alias_size_in_bytes >= held, (ma.alias_size_in_bytes, held)
+    # 4.457 GB of weights + 4.656 of tables; nothing table-sized beside them
+    assert 9.10e9 < ma.argument_size_in_bytes < 9.13e9
+    assert ma.temp_size_in_bytes < 0.05e9, ma.temp_size_in_bytes
+    for L, N, S, W in shapes:
+        big = re.compile(r"f32\[(%d,)?%d,%d,%d\]" % (L, N, S, W))
+        made = [(m.group(1), m.group(3)) for m in re.finditer(
+            r"(%[\w.\-]+) = (\S+) ([\w\-]+)\(", text)
+            if big.search(m.group(2).split("{")[0]) and m.group(3) in (
+                "select", "concatenate", "copy", "pad", "transpose")]
+        assert not made, made[:6]
+    assert text.count(" while(") == 1
+    from benchmark import moe_trace
+    names = {c.split(" = ")[0].strip().lstrip("%") for c in kernels}
+    window = moe_trace.scope_instruction_names(text, "window_attention")
+    full = moe_trace.scope_instruction_names(text, "full_attention")
+    assert len(names & window) == 5 and len(names & full) == 2
+
+
+@pytest.mark.parametrize("bucket,temporaries", [(512, 0.35e9),
+                                                (1024, 0.68e9)])
+def test_kinds_prefill_compiles_within_its_temporaries(kinds_predictor,
+                                                       bucket, temporaries):
+    """A prefill bucket of `mimo_v2_flash`: its attention plain XLA (no call
+    of the decode kernel; the grouped matmuls of the routed FFN are XLA's), its
+    rows returned as the four tables hold a position, temporaries what the
+    configuration's arithmetic counts (0.326 / 0.643 GB)."""
+    cfg, pred, state = kinds_predictor
+    assert bucket in cfg["model"]["prefill_buckets"]
+    on = jax.sharding.SingleDeviceSharding(pred._device)
+    specs = (jax.ShapeDtypeStruct((1, bucket), np.int32, sharding=on),
+             jax.ShapeDtypeStruct((), np.int32, sharding=on))
+    with pk.mosaic_lowering():
+        compiled = pred._phase_jit(pred._prefill_math, ()).lower(
+            state, *specs).compile()
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < temporaries, ma.temp_size_in_bytes
+    out = jax.eval_shape(pred._prefill_math, state, *specs)
+    assert [o.shape for o in out[1:]] == [
+        (2, 1, bucket, 768), (2, 1, bucket, 512), (5, 1, 128, 1536),
+        (5, 1, 128, 1024)]
+    assert not [c for c in _custom_calls(compiled.as_text())
+                if "kernel_metadata={}" in c]

@@ -138,6 +138,7 @@ streams are those of one-trip dispatches token for token: a window
 moves slot joins/leaves to its boundaries without moving a token.
 """
 
+import collections
 import functools
 import hashlib
 import json
@@ -3855,11 +3856,19 @@ class DecodeSession:
         # the step dispatch `launch_fused` left for `fetch_fused`: (its
         # result vector, still on the device; the steps it was asked)
         self._in_flight = None
+        # the prefills `launch_prefill` left for `fetch_prefill`, oldest
+        # first: (slot, first-token vector still on the device, prompt
+        # length, the span facts `scanned`, the call's `_launched`)
+        self._prefills = collections.deque()
 
     # -- occupancy ------------------------------------------------------
 
     def free_slots(self):
-        return [i for i in range(self.n_slots) if not self.active[i]]
+        """The slots no stream holds and no unfetched prefill is landing
+        in (`launch_prefill` reserves its slot)."""
+        landing = {p[0] for p in self._prefills}
+        return [i for i in range(self.n_slots)
+                if not self.active[i] and i not in landing]
 
     def occupancy(self):
         return int(self.active.sum())
@@ -3963,8 +3972,13 @@ class DecodeSession:
         DONATED to the call wherever the placement allows
         (`_phase_jit`; not on a gather-mode mesh): the caller replaces
         `_kc`/`_vc` by the results.  A call that raises after consuming
-        them leaves this session dead (`_mark_dead`)."""
+        them leaves this session dead (`_mark_dead`).  A call that takes
+        the tables (a step of any form) is refused while a prefill is
+        unfetched: its slot is reserved, not active yet."""
         self._alive()
+        if cache and self._prefills:
+            raise RuntimeError("a prefill is not fetched yet: fetch_prefill "
+                               "comes before a step")
         state = self.predictor._state
         try:
             if not obs_tracing.enabled():
@@ -4020,11 +4034,30 @@ class DecodeSession:
         """Run the prompt through the bucketed prefill, land its K/V
         (and the conv layers' state at the prompt's end) in `slot`, and
         return the first generated token (greedy).  The slot must be
-        free (and therefore zeroed)."""
+        free (and therefore zeroed).  It is `launch_prefill` followed at
+        once by `fetch_prefill`: a caller with another prompt to queue
+        while the device runs this one calls the two apart."""
+        self.launch_prefill(slot, tokens)
+        return self.fetch_prefill()
+
+    def launch_prefill(self, slot, tokens):
+        """The first half of `prefill`: the executable call and the
+        landing of its rows in `slot`, both queued on the device when
+        this returns (`_call`, `_write_slot`).  The slot is RESERVED
+        (`free_slots` leaves it out) and becomes active with
+        `fetch_prefill`, which takes the launched prefills oldest first.
+        A prefill reads no table and its landing chains on the tables by
+        donation, in program order, so another may be launched behind
+        one that is not fetched yet; a step may not (`_call`).  Returns
+        whether it was: True where an earlier prefill was still
+        unfetched when this one was queued."""
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
-        if self.active[slot]:
+        if slot not in self.free_slots():
             raise ValueError("slot %d is occupied" % slot)
+        if self._in_flight is not None:
+            raise RuntimeError("a step dispatch is in flight: fetch_fused "
+                               "comes before a prefill")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         n = tokens.size
         if n < 1:
@@ -4034,6 +4067,9 @@ class DecodeSession:
         padded[0, :n] = tokens
         fn = self.predictor.prefill_fn(bucket)
         first, *new = self._call("prefill", fn, (), (padded, np.int32(n)))
+        # this call's `decode/put` / `decode/launch` stamps wait for its
+        # own fetch: the next launch must not take their place
+        launched, self._launched = self._launched, ()
         # land the bucket-length rows (and the state of a fixed size) at
         # the slot; positions past the bucket are already zero (the slot
         # was zeroed on free)
@@ -4047,6 +4083,19 @@ class DecodeSession:
         chunks = self.predictor.prefill_chunks(n)
         if chunks:
             scanned["chunks"] = chunks
+        behind = bool(self._prefills)
+        self._prefills.append((slot, first, n, scanned, launched))
+        return behind
+
+    def fetch_prefill(self):
+        """The second half of `prefill`, for the OLDEST prefill
+        `launch_prefill` left unfetched: the wait for the device and the
+        copy of its first token (`_fetch`), then the slot's length, last
+        token and occupancy.  Returns the token."""
+        if not self._prefills:
+            raise RuntimeError("no prefill in flight: launch_prefill "
+                               "comes first")
+        slot, first, n, scanned, self._launched = self._prefills.popleft()
         first = self._fetch("prefill", first, routed=True,
                             more=scanned)[0].reshape(-1)
         tok = int(first[0])
@@ -4424,6 +4473,8 @@ class SpeculativeDecodeSession:
         self.accepted = 0        # draft tokens accepted
         self.last_spec = False   # did the latest round verify?
         self.last_draft_end = None   # monotonic draft->verify boundary
+        # first tokens `launch_prefill` keeps for `fetch_prefill`
+        self._firsts = collections.deque()
 
     # -- DecodeSession surface (the batcher's contract) -----------------
 
@@ -4456,6 +4507,17 @@ class SpeculativeDecodeSession:
         """Prefill BOTH tables; the draft's own first-token prediction
         is discarded — its pending token is re-pinned to the target's
         (the committed stream is always the target's)."""
+        self.launch_prefill(slot, tokens)
+        return self.fetch_prefill()
+
+    def launch_prefill(self, slot, tokens):
+        """`DecodeSession.launch_prefill`'s name for the lane's one
+        admission path: the whole of `prefill` (the draft's pending
+        token is the target's first, which must be on the host), the
+        token kept for `fetch_prefill`.  The lane launches prompt i+1
+        before it takes prompt i's token, so two may be kept.  Returns
+        False: nothing is left on the device, so nothing was queued
+        behind anything."""
         first = self.session.prefill(slot, tokens)
         if not self._degraded:
             try:
@@ -4464,7 +4526,15 @@ class SpeculativeDecodeSession:
                 self.draft_session.last_tokens[slot] = np.int32(first)
             except BaseException as e:
                 self._degrade(e)
-        return first
+        self._firsts.append(first)
+        return False
+
+    def fetch_prefill(self):
+        """The first token of the oldest `launch_prefill` not taken yet."""
+        if not self._firsts:
+            raise RuntimeError("no prefill in flight: launch_prefill "
+                               "comes first")
+        return self._firsts.popleft()
 
     def free(self, slot):
         self.session.free(slot)

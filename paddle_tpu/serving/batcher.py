@@ -1062,6 +1062,21 @@ class _DecodeRequest:
         self.gen = []
 
 
+class _Prefill:
+    """An admit whose prefill is launched and not fetched yet
+    (`DecodeBatcher._admit`): its slot, whether the session queued it
+    behind one still unfetched, where its `serving/prefill_compute`
+    starts."""
+
+    __slots__ = ("req", "slot", "ahead", "t0")
+
+    def __init__(self, req, slot, ahead, t0):
+        self.req = req
+        self.slot = slot
+        self.ahead = ahead
+        self.t0 = t0
+
+
 class _DecodeLane:
     """One replica's decode lane: its slot-table session plus the
     slot -> request assignment the continuous loop walks.  With a
@@ -1071,7 +1086,7 @@ class _DecodeLane:
 
     __slots__ = ("index", "predictor", "session", "assigned", "steps",
                  "tokens", "spec", "degraded_noted", "last_step_t",
-                 "step_ewma", "dead", "tp", "held", "early")
+                 "step_ewma", "dead", "tp", "held", "early", "ahead")
 
     def __init__(self, index, predictor, n_slots, draft=None, spec_k=0):
         # error string once a mesh member died under this lane
@@ -1104,6 +1119,8 @@ class _DecodeLane:
         # many dispatches were launched ahead of one
         self.held = None
         self.early = 0
+        # prefills launched behind one not fetched yet (`_admit`)
+        self.ahead = 0
 
 
 class DecodeBatcher:
@@ -1313,6 +1330,7 @@ class DecodeBatcher:
                             "queue": 0,
                             "batches": l.steps,
                             "early_launches": l.early,
+                            "prefills_ahead": l.ahead,
                             "rows": l.tokens})
             return out
 
@@ -1537,38 +1555,122 @@ class DecodeBatcher:
             "deadline passed after %.1f ms (%d tokens generated)"
             % ((now - req.enqueued) * 1e3, len(req.gen))), **place)
 
-    def _prefill(self, lane, req):
-        """Admit one request into a free slot: prefill the prompt,
-        stream the first token (the TTFT instant)."""
+    def _admit(self, lane, admits):
+        """Prefill the requests this pass admits, as a pipeline ONE
+        deep: each prompt's prefill is launched before the one ahead of
+        it is fetched (`DecodeSession.launch_prefill` /
+        `fetch_prefill`), so the device finds the next prefill in its
+        queue when it ends one and does not wait for the host to copy a
+        first token back, do the request's bookkeeping and pad the next
+        prompt.  At most one prefill is queued behind the one the
+        device runs: a first token waits for one launch, and two
+        prefills' temporaries are outstanding at most.  With one admit
+        the device calls are those of `prefill`, call for call.
+
+        The `serving/prefill_compute` spans of an admission TILE:
+        request i's runs from the end of request i-1's fetch (from its
+        own launch, where nothing was in flight) to the end of its own
+        fetch, and carries `ahead` = 1 where the session queued it
+        behind an unfetched one (`launch_prefill` says; a speculative
+        session's launch half is a whole prefill and says no).
+
+        A member of the lane's mesh lost under a launch or a fetch: the
+        request fails typed, what is in flight is fetched (or fails
+        with its own error), the admits not launched yet never touched
+        the mesh and go back to the queue for a surviving lane (if none
+        survives, `_lane_dead` fails the whole queue typed), and the
+        loop's member-loss handler retires the lane whole."""
+        flying = None
+        try:
+            for i, req in enumerate(admits):
+                nxt = self._launch_prefill(lane, req, flying is not None)
+                if nxt is not None:
+                    prev, flying = flying, nxt
+                    if prev is not None:
+                        nxt.t0 = self._land_prefill(lane, prev)
+            prev, flying = flying, None
+            if prev is not None:
+                self._land_prefill(lane, prev)
+        except MeshMemberLost:
+            with self._cv:
+                for rem in reversed(admits[i + 1:]):
+                    self._pending.appendleft(rem)
+                self._cv.notify_all()
+            if flying is not None:
+                try:
+                    self._land_prefill(lane, flying)
+                except MeshMemberLost:
+                    pass
+            raise
+
+    def _prefill_span(self, lane, p, t1, **more):
+        """One `serving/prefill_compute` of an admission (`_admit`)."""
+        req = p.req
+        # a stack that prefills in chunks says how many this prompt takes
+        chunks = lane.session.predictor.prefill_chunks(len(req.prompt))
+        if chunks:
+            more["chunks"] = chunks
+        obs_tracing.stamp("serving/prefill_compute", p.t0, t1,
+                          kind="serving", trace_id=req.trace_id,
+                          parent="serving/lane_iter",
+                          model=self._model_name, replica=lane.index,
+                          prompt=len(req.prompt), ahead=int(p.ahead),
+                          **more)
+
+    def _launch_prefill(self, lane, req, behind):
+        """Admit one request into a free slot, first half: queue its
+        prompt's prefill on the device.  Returns the `_Prefill` that
+        `_land_prefill` takes, None for a request dropped (cancelled,
+        expired) or failed here.  `behind`: an earlier request of this
+        admission is not landed yet (its span is open)."""
         now = time.monotonic()
         req.t_admitted = now
         if req.stream.cancelled():
             self._finish(lane, None, req, "cancelled")
-            return
+            return None
         if req.deadline is not None and now > req.deadline:
             self._expire(lane, None, req, now)
-            return
+            return None
         sess = lane.session
-        slot = sess.free_slots()[0]
-        # a stack that prefills in chunks says how many this prompt takes
-        chunks = sess.predictor.prefill_chunks(len(req.prompt))
+        p = _Prefill(req, sess.free_slots()[0], False, now)
         try:
-            with obs_tracing.trace("serving/prefill_compute",
-                                   kind="serving", trace_id=req.trace_id,
-                                   parent="serving/lane_iter",
-                                   model=self._model_name,
-                                   replica=lane.index,
-                                   prompt=len(req.prompt),
-                                   **({"chunks": chunks} if chunks else {})):
-                first = sess.prefill(slot, req.prompt)
+            with obs_tracing.under("serving/prefill_compute",
+                                   trace_id=req.trace_id):
+                p.ahead = bool(sess.launch_prefill(p.slot, req.prompt))
         except BaseException as e:
+            if obs_tracing.enabled() and not behind:
+                # (behind a request not landed yet this time lies in
+                # THAT request's span, which is still open)
+                self._prefill_span(lane, p, time.monotonic(),
+                                   error=type(e).__name__)
             self._finish(lane, None, req, "error", exc=e)
             if isinstance(e, MeshMemberLost):
-                # the request failed typed above; the LANE is dead too —
-                # let the loop's member-loss handler retire it whole
+                # the request failed typed above; the LANE is dead too
                 raise
-            return
+            return None
+        lane.ahead += p.ahead
+        return p
+
+    def _land_prefill(self, lane, p):
+        """Second half: wait for the oldest launched prefill, stream
+        its first token (the TTFT instant).  Returns the end of its
+        `serving/prefill_compute`."""
+        req, slot, sess = p.req, p.slot, lane.session
+        try:
+            with obs_tracing.under("serving/prefill_compute",
+                                   trace_id=req.trace_id):
+                first = sess.fetch_prefill()
+        except BaseException as e:
+            end = time.monotonic()
+            if obs_tracing.enabled():
+                self._prefill_span(lane, p, end, error=type(e).__name__)
+            self._finish(lane, None, req, "error", exc=e)
+            if isinstance(e, MeshMemberLost):
+                raise
+            return end
         req.t_first = time.monotonic()
+        if obs_tracing.enabled():
+            self._prefill_span(lane, p, req.t_first)
         if self.metrics is not None:
             self.metrics.note_prefill(
                 ttft_ms=(req.t_first - req.enqueued) * 1e3)
@@ -1586,6 +1688,7 @@ class DecodeBatcher:
                 req.buf, (req.t_first, time.monotonic())
                 if obs_tracing.enabled() else None)
             req.buf = []
+        return req.t_first
 
     def _emit_step_spans(self, lane, t0, t_draft_end, now, n_slots,
                          rnd, accepted=None, tokens=None, trips=None,
@@ -1689,8 +1792,9 @@ class DecodeBatcher:
                 return
 
     def _lane_iter(self, lane):
-        """One pass of the continuous loop: admit + prefill, one decode
-        dispatch, then DECIDE what each slot got and whether it ends
+        """One pass of the continuous loop: admit + prefill (`_admit`:
+        each prompt launched before the one ahead of it is fetched), one
+        decode dispatch, then DECIDE what each slot got and whether it ends
         (list work: no queue, no lock, no device call) and DELIVER that
         to the streams (`_deliver`).  Returns False to stop.
 
@@ -1729,19 +1833,7 @@ class DecodeBatcher:
                 admits = self._take_admits_locked(lane) \
                     if self._admissible(lane) else []
         # prefill OUTSIDE the lock: other lanes keep decoding
-        for i, req in enumerate(admits):
-            try:
-                self._prefill(lane, req)
-            except MeshMemberLost:
-                # this lane is dying whole; admits not yet prefilled
-                # never touched its mesh — push them back for a
-                # surviving lane (if none survives, _lane_dead fails
-                # the whole queue typed)
-                with self._cv:
-                    for rem in reversed(admits[i + 1:]):
-                        self._pending.appendleft(rem)
-                    self._cv.notify_all()
-                raise
+        self._admit(lane, admits)
         if not lane.assigned:
             self._note_degraded(lane)
             if traced and admits:

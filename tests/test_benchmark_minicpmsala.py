@@ -25,12 +25,12 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
     list it joined, and only a `benchmark` PR may edit that file: behind
     them stand the one reader PR 49 appended, the one PR 50 did, and PR
     51's cell, configuration, three readers and its cell's name in the
-    lists; the rest is as it was."""
+    lists, and the one reader PR 53 appended; the rest is as it was."""
     later = "mimov2flash_reasoning_decode"
-    assert [m["name"] for m in manifest["per_layer"][-5:]] == [
+    assert [m["name"] for m in manifest["per_layer"][-6:]] == [
         "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill",
         "kinds_attention_roofline", "attention_share_of_trip",
-        "full_kv_bytes_per_slot"]
+        "full_kv_bytes_per_slot", "prefill_ahead_share"]
     assert manifest["workloads"][-1]["name"] == later
     assert manifest["configs"][-1]["name"] == "mimo_v2_flash"
 
@@ -41,7 +41,7 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
         manifest, workloads=manifest["workloads"][:-1],
         configs=manifest["configs"][:-1],
         end_to_end=as_it_was(manifest["end_to_end"]),
-        per_layer=as_it_was(manifest["per_layer"][:-5])))
+        per_layer=as_it_was(manifest["per_layer"][:-6])))
 
 
 # the instruction of stage 2's Mosaic call as a prefill executable's text

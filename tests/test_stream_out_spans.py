@@ -287,7 +287,10 @@ def test_tracing_off_lands_nothing_reads_no_clock_same_tokens(
     for name in ("_deliver", "_handle_infer_stream", "land"):
         assert name not in calls, (name, calls)
     assert calls["_finish"] == len(got)
-    assert calls["_prefill"] == 2 * len(got)
+    # an admission reads it twice: as the request is admitted, and for
+    # its first token
+    assert calls["_launch_prefill"] == len(got)
+    assert calls["_land_prefill"] == len(got)
     on, _ = traced
     assert [r["frames"] for r in got] == [r["frames"] for r in on]
     assert [{k: v for k, v in r["info"].items() if k != "trace_id"}

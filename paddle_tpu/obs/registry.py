@@ -233,6 +233,13 @@ class MetricsRegistry(object):
                             (mname, self._model_labels(model, m),
                              m[field]))
             _family(lines, mname, "counter", samples)
+        # tokens whose frame the servers' stream writers put on a socket
+        # (a writer sends every model's streams, so no model label);
+        # serving_decode_tokens_total minus it is what they still hold
+        mname = _PREFIX + "serving_tokens_sent_total"
+        _family(lines, mname, "counter",
+                [(mname, {}, sum(snap["tokens_sent_total"]
+                                 for snap in snaps))] if snaps else [])
         for field in _SERVING_GAUGES:
             mname = _PREFIX + "serving_" + field
             samples = []

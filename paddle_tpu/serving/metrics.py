@@ -465,6 +465,12 @@ class ServingMetrics:
         self._models = {}
         self._lock = threading.Lock()
         self._started = time.monotonic()
+        # the stream writer's count of tokens whose frame is on its
+        # socket (the server sets it at start): beside a lane's
+        # `decode_tokens` (emitted), emitted minus sent is what the
+        # writer still holds, sent minus what a client has counted is
+        # the wire's and the client's
+        self.tokens_sent_fn = None
 
     def model(self, name, precision=None):
         """One ModelMetrics per (name, precision lane).  The fp32 lane
@@ -492,6 +498,8 @@ class ServingMetrics:
             models = dict(self._models)
         out = {
             "uptime_sec": round(time.monotonic() - self._started, 3),
+            "tokens_sent_total": (int(self.tokens_sent_fn())
+                                  if self.tokens_sent_fn else 0),
             "models": {name: m.snapshot() for name, m in models.items()},
         }
         try:

@@ -233,6 +233,7 @@ class InferenceServer:
         self._server = Server(self._addr, Handler)
         self._addr = self._server.server_address
         self._writer = _StreamWriter().start()
+        self.metrics.tokens_sent_fn = lambda: self._writer.tokens_sent
         if self.slo is not None:
             self.slo.name = self.endpoint
             self.slo.start()
@@ -749,6 +750,11 @@ class _StreamWriter:
         # between handing a delivery over and its own bookkeeping
         self._held = set()
         self._selecting = False
+        # the TOKENS whose chunk frame is on its socket whole, over the
+        # writer's life: what left the process (`ServingMetrics`
+        # `tokens_sent_total`, the `tokens_total` of a pass's span).
+        # This thread alone adds to it, tracing on or off
+        self.tokens_sent = 0
         self._wake_r, self._wake_w = socket.socketpair()
         self._wake_r.setblocking(False)
         self._wake_w.setblocking(False)
@@ -852,10 +858,16 @@ class _StreamWriter:
     def _write_pass(self, items, ready):
         """`items`, in order, after what held-up sockets take again
         (`ready`).  With tracing on it is one `serving/write_pass` span;
-        off, it reads no clock."""
+        off, it reads no clock.  The span's `tokens` are those of the
+        chunk frames whose LAST byte went out in this pass (a frame a
+        full socket held back counts where `_retry` finishes it),
+        `tokens_total` the writer's running total after it: on the
+        span's `t0`, time.monotonic(), they are the server's side of
+        what a client's stamps count."""
         traced = obs_tracing.enabled()
         t0 = t_have = time.monotonic() if traced else None
         frames = enders = nbytes = 0
+        sent_before = self.tokens_sent
         streams = set()
         for out in ready:
             if not out.sent.is_set():
@@ -895,7 +907,10 @@ class _StreamWriter:
             obs_tracing.stamp(
                 "serving/write_pass", t0, time.monotonic(),
                 kind="serving", frames=frames, streams=len(streams),
-                enders=enders, bytes=nbytes, backlogged=len(self._held))
+                enders=enders, bytes=nbytes, backlogged=len(self._held),
+                tokens=self.tokens_sent - sent_before,
+                tokens_total=self.tokens_sent,
+                unsent_bytes=sum(len(o.unsent) for o in self._held))
 
     @staticmethod
     def _message(out, kind, payload):
@@ -925,7 +940,9 @@ class _StreamWriter:
         kind, stamps, t_have, tokens, size = owed
         if kind != "tokens":
             self._end(out)
-        elif traced and out.rec is not None:
+            return
+        self.tokens_sent += tokens
+        if traced and out.rec is not None:
             out.rec.frame(stamps, t_have, t_sent, tokens, size)
 
     def _hold(self, out, rest, owed):

@@ -25,12 +25,14 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
     list it joined, and only a `benchmark` PR may edit that file: behind
     them stand the one reader PR 49 appended, the one PR 50 did, and PR
     51's cell, configuration, three readers and its cell's name in the
-    lists, and the one reader PR 53 appended; the rest is as it was."""
+    lists, the one reader PR 53 appended and the three PR 54 did; the rest
+    is as it was."""
     later = "mimov2flash_reasoning_decode"
-    assert [m["name"] for m in manifest["per_layer"][-6:]] == [
+    assert [m["name"] for m in manifest["per_layer"][-9:]] == [
         "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill",
         "kinds_attention_roofline", "attention_share_of_trip",
-        "full_kv_bytes_per_slot", "prefill_ahead_share"]
+        "full_kv_bytes_per_slot", "prefill_ahead_share",
+        "tokens_sent_per_s", "client_read_share", "token_delivery_ms_mean"]
     assert manifest["workloads"][-1]["name"] == later
     assert manifest["configs"][-1]["name"] == "mimo_v2_flash"
 
@@ -41,7 +43,7 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
         manifest, workloads=manifest["workloads"][:-1],
         configs=manifest["configs"][:-1],
         end_to_end=as_it_was(manifest["end_to_end"]),
-        per_layer=as_it_was(manifest["per_layer"][:-6])))
+        per_layer=as_it_was(manifest["per_layer"][:-9])))
 
 
 # the instruction of stage 2's Mosaic call as a prefill executable's text

@@ -932,7 +932,8 @@ class TestCLIs:
         assert proc.returncode == 0, proc.stderr
         reply = json.loads(proc.stdout)
         assert {"ok", "stats", "models"} <= set(reply)
-        assert {"uptime_sec", "models"} <= set(reply["stats"])
+        assert {"uptime_sec", "models", "tokens_sent_total"} \
+            <= set(reply["stats"])
         m = reply["stats"]["models"]["m"]
         missing = SERVING_TOP_MODEL_KEYS - set(m)
         assert not missing, "snapshot keys went missing: %s" % missing

@@ -15,6 +15,11 @@ raw sockets that keep a frame's bytes.
 * (d) one `serving/write_pass` span an item: a delivery's puts, then its
   enders' flushes and terminal frames; tracing off lands nothing and the
   writer reads no clock;
+* (d') a pass's `tokens` are those of the frames whose last byte it put on
+  a socket, `tokens_total` their running sum (PR 54): over a run they sum
+  to what the clients received, a frame a full socket held back counts in
+  the pass that finishes it, the total advances with tracing off, and the
+  `stats` reply and the Prometheus text carry it;
 * (e) `shutdown(drain=True)` sends every queued terminal frame before the
   writer joins;
 * (f) events put before the handler attached the stream arrive in order.
@@ -411,10 +416,9 @@ def test_one_write_pass_an_item_of_a_delivery(artifact):
             [1, W, W, W, 1]
 
 
-def test_tracing_off_the_writer_lands_nothing_and_reads_no_clock(
-        artifact, monkeypatch):
-    obs_tracing.set_enabled(False)
-    obs_tracing.clear()
+def _count_the_servers_clock_reads(monkeypatch):
+    """{function of server.py: its reads of time.monotonic()} from here
+    to `monkeypatch.undo()`."""
     readers = {}
     clock = time.monotonic
 
@@ -424,6 +428,14 @@ def test_tracing_off_the_writer_lands_nothing_and_reads_no_clock(
             readers[code.co_name] = readers.get(code.co_name, 0) + 1
         return clock()
     monkeypatch.setattr(time, "monotonic", counted)
+    return readers
+
+
+def test_tracing_off_the_writer_lands_nothing_and_reads_no_clock(
+        artifact, monkeypatch):
+    obs_tracing.set_enabled(False)
+    obs_tracing.clear()
+    readers = _count_the_servers_clock_reads(monkeypatch)
     with _Served(artifact) as s:
         got = _wave(s, [_request([3 + i, 9], 2 + W, 1, "off-%d" % i)
                         for i in range(SLOTS)])
@@ -432,6 +444,198 @@ def test_tracing_off_the_writer_lands_nothing_and_reads_no_clock(
     assert not readers, readers
     assert not [s for s in obs_tracing.recent_spans() if s["name"] in (
         "serving/write_pass", "serving/stream_out")]
+
+
+# ---------------------------------------------------------------------------
+# (d') the writer counts the tokens it put on the wire
+# ---------------------------------------------------------------------------
+
+def _passes():
+    return sorted(_named(obs_tracing.recent_spans(), "serving/write_pass"),
+                  key=lambda p: p["t0"])
+
+
+def _running_total_holds(passes):
+    total = 0
+    for p in passes:
+        total += p["attrs"]["tokens"]
+        assert p["attrs"]["tokens_total"] == total, passes
+    return total
+
+
+def test_the_passes_tokens_sum_to_what_the_clients_received(artifact):
+    """Two waves of streams of several lengths and chunkings: the
+    `tokens` of the run's passes sum to the tokens on the clients' side
+    of the sockets, `tokens_total` is their running sum on the pass's
+    own clock, and nothing is owed at the end."""
+    obs_tracing.set_enabled(True)
+    obs_tracing.clear()
+    with _Served(artifact) as s:
+        set_dispatch_delay(0.002)
+        got = _wave(s, [_request([3 + i, 9], 2 + (i + 1) * W, 1 + i,
+                                 "sum-%d" % i) for i in range(SLOTS)])
+        got += _wave(s, [_request([7, 2 + i], 5 + i, 2, "sum2-%d" % i)
+                         for i in range(SLOTS)])
+        assert _wait(lambda: len(obs_tracing.recent_spans(
+            name="serving/stream_out")) == 2 * SLOTS)
+        writer = s.server._writer
+        received = sum(len(m["tokens"]) for frames in got
+                       for _, m in frames[:-1])
+        assert received == sum(f[-1][1]["new_tokens"] for f in got)
+        assert writer.tokens_sent == received
+    passes = _passes()
+    assert _running_total_holds(passes) == received
+    assert all(p["attrs"]["unsent_bytes"] == 0 for p in passes)
+    # a pass that sent chunk frames sent at least a token a frame
+    assert all(p["attrs"]["tokens"] >= p["attrs"]["frames"]
+               for p in passes)
+    # each request's span counts its own tokens: the two agree
+    assert sum(o["attrs"]["tokens"] for o in _named(
+        obs_tracing.recent_spans(), "serving/stream_out")) == received
+
+
+def _held_back():
+    """A writer of its own, one stream on a socket pair whose sending
+    side holds a few kilobytes, and the stream's reader (not started)."""
+    writer = server_mod._StreamWriter().start()
+    a, b = socket.socketpair()
+    a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4608)
+    stream = DecodeStream("held", 1, 9)
+    frames = []
+
+    def read():
+        b.settimeout(30.0)
+        while not frames or not frames[-1].get("done"):
+            frames.append(_read_frame(b)[1])
+    return writer, (a, b), stream, frames, threading.Thread(target=read)
+
+
+def test_a_held_frame_counts_in_the_pass_that_finishes_it():
+    """A frame larger than its socket's buffer, to a peer that does not
+    read yet: the pass that encoded it sent none of its tokens, nor does
+    the pass of the frame behind it; the pass in which `_retry` puts the
+    last byte out counts them all."""
+    obs_tracing.set_enabled(True)
+    obs_tracing.clear()
+    writer, (a, b), stream, frames, reader = _held_back()
+    big = list(range(1, 8001))
+    try:
+        out = writer.attach(a, stream, "held", False)
+        stream._put_tokens(big)
+        assert _wait(lambda: len(out.owed) == 1)
+        stream._put_tokens([7, 8])
+        assert _wait(lambda: len(out.owed) == 2)
+        assert writer.tokens_sent == 0 and out in writer._held
+        first, second = _passes()
+        for p in (first, second):
+            assert (p["attrs"]["frames"], p["attrs"]["tokens"],
+                    p["attrs"]["tokens_total"],
+                    p["attrs"]["backlogged"]) == (1, 0, 0, 1)
+        # what the held stream still owes: the big frame's rest, then
+        # the whole of the frame behind it
+        assert 0 < first["attrs"]["unsent_bytes"] < first["attrs"]["bytes"]
+        assert second["attrs"]["unsent_bytes"] == \
+            first["attrs"]["unsent_bytes"] + second["attrs"]["bytes"]
+        reader.start()
+        stream._finish("length", obs_info={"replica": 0})
+        assert out.sent.wait(30.0) and out.error is None
+        reader.join(timeout=30)
+    finally:
+        writer.stop()
+        a.close()
+        b.close()
+    assert [list(f["tokens"]) for f in frames[:-1]] == [big, [7, 8]]
+    assert writer.tokens_sent == len(big) + 2
+    passes = _passes()
+    assert _running_total_holds(passes) == len(big) + 2
+    (finishing,) = [p for p in passes if p["attrs"]["tokens"]]
+    # both frames' last bytes left in ONE pass, after the passes that
+    # encoded them
+    assert finishing["attrs"]["tokens"] == len(big) + 2
+    assert finishing["t0"] > second["t0"]
+    assert passes[-1]["attrs"]["unsent_bytes"] == 0
+    assert passes[-1]["attrs"]["backlogged"] == 0
+    # the stream's own span agrees
+    (so,) = _named(obs_tracing.recent_spans(), "serving/stream_out")
+    assert (so["attrs"]["frames"], so["attrs"]["tokens"]) == \
+        (2, len(big) + 2)
+
+
+def test_tracing_off_the_total_advances_and_the_pass_reads_no_clock(
+        monkeypatch):
+    """The held-back stream again with `FLAGS.trace` off: the same
+    total, no span, and nothing of the writer's reads the clock."""
+    obs_tracing.set_enabled(False)
+    obs_tracing.clear()
+    writer, (a, b), stream, frames, reader = _held_back()
+    big = list(range(1, 8001))
+    readers = _count_the_servers_clock_reads(monkeypatch)
+    try:
+        out = writer.attach(a, stream, "held", False)
+        stream._put_tokens(big)
+        stream._put_tokens([7, 8])
+        stream._put_tokens([9])
+        # tokens of a frame that never went out whole are never counted
+        assert writer.tokens_sent == 0
+        reader.start()
+        stream._finish("length", obs_info={"replica": 0})
+        assert out.sent.wait(30.0) and out.error is None
+        reader.join(timeout=30)
+    finally:
+        monkeypatch.undo()
+        writer.stop()
+        a.close()
+        b.close()
+    assert sum(len(f["tokens"]) for f in frames[:-1]) == len(big) + 3
+    assert writer.tokens_sent == len(big) + 3
+    assert not readers, readers
+    assert not obs_tracing.recent_spans()
+
+
+def test_a_broken_streams_unsent_tokens_are_never_counted():
+    """A peer that goes away with frames held: what had not gone out
+    whole stays out of the total."""
+    obs_tracing.set_enabled(True)
+    obs_tracing.clear()
+    writer, (a, b), stream, frames, reader = _held_back()
+    try:
+        out = writer.attach(a, stream, "held", False)
+        stream._put_tokens([5])
+        assert _wait(lambda: writer.tokens_sent == 1)
+        stream._put_tokens(list(range(1, 8001)))
+        assert _wait(lambda: len(out.owed) == 1)
+        b.close()
+        assert out.sent.wait(30.0)
+        assert isinstance(out.error, OSError) and stream.cancelled()
+    finally:
+        writer.stop()
+        a.close()
+    assert writer.tokens_sent == 1
+    passes = _passes()
+    assert _running_total_holds(passes) == 1
+    assert passes[-1]["attrs"]["unsent_bytes"] == 0
+
+
+def test_the_stats_reply_and_the_metrics_text_carry_the_writers_total(
+        artifact):
+    """`tokens_sent_total` beside the lane's emitted tokens: equal once
+    every frame is out, on the `stats` verb and as a Prometheus family,
+    with tracing off as with it on."""
+    obs_tracing.set_enabled(False)
+    with _Served(artifact) as s:
+        assert s.cli.stats()["stats"]["tokens_sent_total"] == 0
+        frames = _raw_stream(s.server.endpoint,
+                             _request([5, 9, 3], 3 + W, 2, "stats"))
+        assert frames[-1][1]["new_tokens"] == 3 + W
+        stats = s.cli.stats()["stats"]
+        assert stats["tokens_sent_total"] == 3 + W
+        assert stats["models"]["lm"]["decode_tokens"] == 3 + W
+        text = s.cli.metrics_text()
+    (line,) = [ln for ln in text.splitlines() if ln.startswith(
+        "paddle_tpu_serving_tokens_sent_total")]
+    # the registry sums the process's servers: this one's are in it
+    assert int(line.split()[-1]) >= 3 + W
+    assert "# TYPE paddle_tpu_serving_tokens_sent_total counter" in text
 
 
 # ---------------------------------------------------------------------------

@@ -162,8 +162,9 @@ def render(reply, health=None, fleet=None):
     stats = reply.get("stats", {})
     models = stats.get("models", {})
     desc = reply.get("models", {})
-    banner = "server uptime %.0fs, %d model(s)" \
-        % (stats.get("uptime_sec", 0.0), len(models))
+    banner = "server uptime %.0fs, %d model(s), %d tokens sent" \
+        % (stats.get("uptime_sec", 0.0), len(models),
+           stats.get("tokens_sent_total", 0))
     if health is not None and health.get("accepting") is False:
         # the drain-vs-dead disambiguation the health verb carries:
         # this server answers but refuses new admissions

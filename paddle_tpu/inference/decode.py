@@ -139,6 +139,7 @@ moves slot joins/leaves to its boundaries without moving a token.
 """
 
 import collections
+import contextlib
 import functools
 import hashlib
 import json
@@ -1132,7 +1133,12 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     a dead slot's or a pad position's row is computed but not counted.
     A list given as `picks` receives the chosen experts [T, k] i32 (at
     trace time): what a comparison with a reference needs to tell the
-    router's near-ties from a fault (`_step_logits`)."""
+    router's near-ties from a fault (`_step_logits`).
+
+    Traced under `_prompts_share_experts` and a `vmap` over prompts (a
+    group's prefill), the router, the weights and the facts stay a
+    prompt's own and the sort and the grouped matmuls are the GROUP's: one
+    `ragged_dot` a projection over all the prompts' pairs."""
     import jax
     import jax.numpy as jnp
     T, E, k = h.shape[0], router.shape[1], int(k)
@@ -1175,42 +1181,101 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
             dtype=jnp.int32)
         facts = jnp.stack([jnp.sum(counted > 0, dtype=jnp.int32),
                            jnp.max(counted)])
-        order = jnp.argsort(flat)           # stable: pairs by expert
 
-        def grouped(x, w):
-            return _contract(x, w, functools.partial(
-                jax.lax.ragged_dot, group_sizes=sizes))
+        def through_experts(h, flat, sizes, w_gate, w_up, w_down):
+            """Each pair's row through its expert -> [T * k, D], token t's
+            j-th pair at t * k + j."""
+            order = jnp.argsort(flat)       # stable: pairs by expert
 
-        def experts(m=None):
-            # the sorted pairs' rows (the first m of them; all if None)
-            # through their experts
-            at = order // k
-            rows = h[at if m is None else at[:m]]               # [m, D]
-            act = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
-            return grouped(act, w_down)                         # [m, D]
-        if held is None:
-            out = experts()                                     # [T*k, D]
-        else:
-            # the pairs that stay sort FIRST, and they are about count / E
-            # of the T * k: the grouped matmuls run over the first `cap`
-            # sorted rows, four times that share (a grouped-matmul kernel
-            # computes whole row tiles, so a dead row behind the groups is
-            # not free), and over all T * k only in a dispatch whose
-            # routing crowds more than `cap` pairs onto this member:
-            # dropless and exact either way
-            stay, pairs = jnp.sum(sizes), T * k
-            cap = min(pairs, max(64, 4 * -(-pairs * E // router.shape[1])))
-            out = experts() if cap == pairs else jax.lax.cond(
-                stay <= cap,
-                lambda: jnp.pad(experts(cap), ((0, pairs - cap), (0, 0))),
-                experts)
-            # a row behind the last group is no expert's: whatever the
-            # grouped matmul left there, it is nothing
-            out = jnp.where((jnp.arange(pairs) < stay)[:, None], out, 0.0)
-        # back to token order by a gather (the inverse permutation), then
-        # a fixed-order sum over each token's k experts
-        out = out[jnp.argsort(order)].reshape(T, k, -1)
+            def grouped(x, w):
+                return _contract(x, w, functools.partial(
+                    jax.lax.ragged_dot, group_sizes=sizes))
+
+            def experts(m=None):
+                # the sorted pairs' rows (the first m of them; all if None)
+                # through their experts
+                at = order // k
+                rows = h[at if m is None else at[:m]]           # [m, D]
+                act = jax.nn.silu(grouped(rows, w_gate)) \
+                    * grouped(rows, w_up)
+                return grouped(act, w_down)                     # [m, D]
+            if held is None:
+                out = experts()                                 # [T*k, D]
+            else:
+                # the pairs that stay sort FIRST, and they are about count /
+                # E of the T * k: the grouped matmuls run over the first
+                # `cap` sorted rows, four times that share (a grouped-matmul
+                # kernel computes whole row tiles, so a dead row behind the
+                # groups is not free), and over all T * k only in a dispatch
+                # whose routing crowds more than `cap` pairs onto this
+                # member: dropless and exact either way
+                stay, pairs = jnp.sum(sizes), flat.shape[0]
+                cap = min(pairs,
+                          max(64, 4 * -(-pairs * E // router.shape[1])))
+                out = experts() if cap == pairs else jax.lax.cond(
+                    stay <= cap,
+                    lambda: jnp.pad(experts(cap),
+                                    ((0, pairs - cap), (0, 0))),
+                    experts)
+                # a row behind the last group is no expert's: whatever the
+                # grouped matmul left there, it is nothing
+                out = jnp.where((jnp.arange(pairs) < stay)[:, None], out,
+                                0.0)
+            # back to token order by a gather (the inverse permutation)
+            return out[jnp.argsort(order)]
+
+        if getattr(_TRACE, "prompts_share_experts", False):
+            # under the `vmap` over a group's prompts
+            # (`GenerativePredictor._prefill_group_math`) the prompts' pairs
+            # are sorted TOGETHER into one grouped matmul: an expert's
+            # weights are read once a group, and a `ragged_dot` a prompt,
+            # which is what the primitive's own rule leaves, reads them once
+            # a prompt
+            through_experts = jax.custom_batching.custom_vmap(through_experts)
+
+            @through_experts.def_vmap
+            def _(rows, batched, h, flat, sizes, *weights):
+                if not all(batched[:3]) or any(batched[3:]):
+                    raise NotImplementedError(
+                        "moe_ffn under a vmap over the tokens alone, the "
+                        "experts' weights shared: got %r" % (batched,))
+                out = through_experts(
+                    h.reshape(-1, h.shape[-1]), flat.reshape(-1),
+                    jnp.sum(sizes, axis=0), *weights)
+                return out.reshape(flat.shape + out.shape[-1:]), True
+
+        # ... then a fixed-order sum over each token's k experts
+        out = through_experts(h, flat, sizes, w_gate, w_up,
+                              w_down).reshape(T, k, -1)
         return jnp.sum(out * w[:, :, None], axis=1), facts
+
+
+def prefill_group(width, waiting):
+    """How many of `waiting` prompts of one bucket the next prefill call
+    takes, the bucket's group executable being `width` prompts wide
+    (`GenerativePredictor.prefill_width`): a whole group; or what is left,
+    padded with dead rows, where that is more than half of one (the padded
+    call computes `width` prompts' rows whatever it holds, a call a prompt
+    reads the weights each time); else one prompt, through the one-prompt
+    executable."""
+    n = min(int(waiting), int(width))
+    return n if 2 * n > width else 1
+
+
+_TRACE = threading.local()
+
+
+@contextlib.contextmanager
+def _prompts_share_experts():
+    """Around the trace of a group's prefill, in this thread: `moe_ffn`
+    gives its grouped matmuls the batching rule that sorts the prompts of
+    the `vmap` together.  Every other trace (a step, a one-prompt prefill)
+    stays the jaxpr it was."""
+    _TRACE.prompts_share_experts = True
+    try:
+        yield
+    finally:
+        _TRACE.prompts_share_experts = False
 
 
 def _pack_routing(tokens, facts):
@@ -1264,6 +1329,17 @@ def _causal_attention(q, k, v, scale, sink=None):
 # (`_blocked_attention`): its scores are [H, block, keys] and never [H, B,
 # B], which at 64 heads and a bucket of 4,096 is 4.3 GB in float32.
 PREFILL_QUERY_BLOCK = 512
+
+# Prompt positions ONE prefill call takes (`GenerativePredictor.
+# prefill_width`): the same-bucket prompts of an admission run as one call
+# over tokens [P, bucket], P = min(8, this // bucket) of them, so that a
+# layer's weights and the vocabulary head are read once a group and not once
+# a prompt.  A group's temporaries are P times a prompt's.  Settled on the
+# chip (PERF.md section 6, PR 55): a call is compute-bound from about a
+# thousand rows on, so 2,048 shared nothing more and filled its wider groups
+# less often (Falcon-H1's cell +8.2% at 1,024, +4.3% at 2,048), and at 4,096
+# a group's temporaries did not fit beside two cells' weights.
+PREFILL_GROUP_TOKENS = 1024
 
 
 def _blocked_attention(q, k, v, scale, window=0, sink=None):
@@ -2070,6 +2146,37 @@ class GenerativePredictor:
                     t = held[leaf]
                     held[leaf] = t.reshape(t.shape[:3] + (-1,))
         return (first, *held.values())
+
+    def _prefill_group_math(self, state, tokens, true_len):
+        """The traced prefill phase of a GROUP of prompts of one bucket:
+        tokens [P, B], true_len [P] -> `_prefill_math`'s results with a
+        leading P, row p what `_prefill_math` makes of prompt p alone.  It
+        is that function under a `vmap` over the prompts with the weights
+        shared, so everything a prompt has of its own stays its own (its
+        length, its masks, its pad positions, its rings, its conv tail, its
+        scanned state, its routing facts) and every matmul against a weight
+        takes the P * B rows as ONE operand: a layer's weights are read
+        once a group, the head runs over the P last rows in one [P, D] x
+        [D, V], and a routed layer sorts the group's tokens by expert
+        together (`moe_ffn`)."""
+        import jax
+        with _prompts_share_experts():
+            return jax.vmap(
+                lambda t, n: self._prefill_math(state, t[None], n))(
+                    tokens, true_len)
+
+    def prefill_width(self, bucket):
+        """Prompts ONE prefill call of `bucket` takes: min(8,
+        `PREFILL_GROUP_TOKENS` // bucket), at least 1.  A stack that
+        prefills in chunks runs a prompt a call (a chunk is its unit of
+        work already: `_prefill_chunks`), and so do a lane on a mesh (its
+        prefill is the partitioned or the sequence-parallel program) and a
+        length past every configured bucket (its one-off executable
+        compiles under traffic as it is)."""
+        if self._chunked or self._mesh_group() is not None \
+                or int(bucket) not in self.prefill_buckets():
+            return 1
+        return max(1, min(8, int(PREFILL_GROUP_TOKENS) // int(bucket)))
 
     def _tp_seq_parallel(self, bucket, tp):
         """Does this prompt bucket prefill SEQUENCE-parallel under TP?
@@ -3609,11 +3716,21 @@ class GenerativePredictor:
         return self._phase_jit(math_fn, tables).lower(
             state_spec, *arg_specs).compile()
 
-    def prefill_fn(self, bucket):
+    def prefill_fn(self, bucket, prompts=1):
+        """The prefill executable of a bucket: of one prompt (tokens [1,
+        B], its length a scalar), or with `prompts` P > 1 of a group
+        (tokens [P, B], lengths [P]: `_prefill_group_math`; off a mesh
+        only, `prefill_width`)."""
         import jax
-        bucket = int(bucket)
-        specs = (jax.ShapeDtypeStruct((1, bucket), np.dtype(np.int32)),
-                 jax.ShapeDtypeStruct((), np.dtype(np.int32)))
+        bucket, P = int(bucket), int(prompts)
+        i32 = np.dtype(np.int32)
+        if P > 1:
+            return self._resolve(
+                ("prefill", bucket, P), self._prefill_group_math,
+                (jax.ShapeDtypeStruct((P, bucket), i32),
+                 jax.ShapeDtypeStruct((P,), i32)))
+        specs = (jax.ShapeDtypeStruct((1, bucket), i32),
+                 jax.ShapeDtypeStruct((), i32))
         return self._resolve(("prefill", bucket), self._prefill_math,
                              specs,
                              tp_math=self._tp_math(self._prefill_math))
@@ -3857,8 +3974,9 @@ class DecodeSession:
         # result vector, still on the device; the steps it was asked)
         self._in_flight = None
         # the prefills `launch_prefill` left for `fetch_prefill`, oldest
-        # first: (slot, first-token vector still on the device, prompt
-        # length, the span facts `scanned`, the call's `_launched`)
+        # first: (slot, or a group's slots as a tuple; first-token vector
+        # still on the device; the prompts' lengths; the span facts
+        # `scanned`; the call's `_launched`)
         self._prefills = collections.deque()
 
     # -- occupancy ------------------------------------------------------
@@ -3866,7 +3984,7 @@ class DecodeSession:
     def free_slots(self):
         """The slots no stream holds and no unfetched prefill is landing
         in (`launch_prefill` reserves its slot)."""
-        landing = {p[0] for p in self._prefills}
+        landing = {s for p in self._prefills for s in np.atleast_1d(p[0])}
         return [i for i in range(self.n_slots)
                 if not self.active[i] and i not in landing]
 
@@ -4011,6 +4129,13 @@ class DecodeSession:
         tables = self._tables()
         if self._inplace:
             write_rows, zero_slot, clear_rows = _slot_writers()
+            if isinstance(slot, tuple):
+                # a group's prefill: its members' rows, a slot each, the
+                # dead rows behind them dropped
+                at = np.zeros(rows[0].shape[0], np.int32)
+                at[:len(slot)] = slot
+                return self._keep(write_rows(tables, tuple(rows), at,
+                                             np.int32(len(slot))))
             at = self._slot_ids[slot]
             if rows is not None:
                 return self._keep(write_rows(tables, tuple(rows), at))
@@ -4050,63 +4175,98 @@ class DecodeSession:
         donation, in program order, so another may be launched behind
         one that is not fetched yet; a step may not (`_call`).  Returns
         whether it was: True where an earlier prefill was still
-        unfetched when this one was queued."""
+        unfetched when this one was queued.
+
+        A GROUP: `slot` a list of free slots and `tokens` as many prompts
+        of ONE bucket, 2 to `prefill_width(bucket)` of them.  They run as
+        one call of the bucket's group executable (`prefill_fn(bucket,
+        width)`: the weights and the head read once for all of them), a
+        short group padded with dead rows of one pad token whose results
+        are dropped, and land in one write; `fetch_prefill` then brings the
+        group's first tokens in one copy."""
         from paddle_tpu.parallel.mesh import check_member_poison
         check_member_poison(self.predictor.device)
-        if slot not in self.free_slots():
-            raise ValueError("slot %d is occupied" % slot)
+        group = isinstance(slot, (list, tuple))
+        slots = tuple(int(i) for i in slot) if group else (slot,)
+        free = self.free_slots()
+        for i in slots:
+            if i not in free:
+                raise ValueError("slot %d is occupied" % i)
         if self._in_flight is not None:
             raise RuntimeError("a step dispatch is in flight: fetch_fused "
                                "comes before a prefill")
-        tokens = np.asarray(tokens, np.int32).reshape(-1)
-        n = tokens.size
-        if n < 1:
+        prompts = [np.asarray(t, np.int32).reshape(-1)
+                   for t in (tokens if group else [tokens])]
+        lens = [t.size for t in prompts]
+        if min(lens) < 1:
             raise ValueError("empty prompt")
-        bucket = self.predictor.prompt_bucket(n)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n] = tokens
-        fn = self.predictor.prefill_fn(bucket)
-        first, *new = self._call("prefill", fn, (), (padded, np.int32(n)))
+        bucket = self.predictor.prompt_bucket(lens[0])
+        width = self.predictor.prefill_width(bucket) if group else 1
+        if group and not (2 <= len(slots) == len(set(slots)) == len(prompts)
+                          <= width and all(self.predictor.prompt_bucket(n)
+                                           == bucket for n in lens)):
+            raise ValueError(
+                "a group is 2 to %d prompts of one bucket, a free slot "
+                "each: got %d prompts of %s tokens for slots %s"
+                % (width, len(prompts), lens, list(slots)))
+        padded = np.zeros((width, bucket), np.int32)
+        for row, t in zip(padded, prompts):
+            row[:t.size] = t
+        fn = self.predictor.prefill_fn(bucket, width)
+        first, *new = self._call("prefill", fn, (), (
+            padded, np.int32(lens + [1] * (width - len(lens))) if group
+            else np.int32(lens[0])))
         # this call's `decode/put` / `decode/launch` stamps wait for its
         # own fetch: the next launch must not take their place
         launched, self._launched = self._launched, ()
         # land the bucket-length rows (and the state of a fixed size) at
         # the slot; positions past the bucket are already zero (the slot
         # was zeroed on free)
-        self._write_slot(slot, new)
+        self._write_slot(slots if group else slot, new)
         # a scanning stack's prefill spans say what was scanned
         scanned = {}
         if self._ss is not None:
             chunk = min(self.predictor._block_meta["ssm_chunk"], int(bucket))
             scanned = {"bucket": int(bucket),
                        "ssm_chunks": -(-int(bucket) // chunk)}
-        chunks = self.predictor.prefill_chunks(n)
+        chunks = self.predictor.prefill_chunks(lens[0])
         if chunks:
             scanned["chunks"] = chunks
+        if group:
+            scanned["prompts"] = len(slots)
         behind = bool(self._prefills)
-        self._prefills.append((slot, first, n, scanned, launched))
+        self._prefills.append((slots if group else slot, first, lens,
+                               scanned, launched))
         return behind
 
     def fetch_prefill(self):
         """The second half of `prefill`, for the OLDEST prefill
         `launch_prefill` left unfetched: the wait for the device and the
         copy of its first token (`_fetch`), then the slot's length, last
-        token and occupancy.  Returns the token."""
+        token and occupancy.  Returns the token; of a group the list of
+        its first tokens, a prompt each (`last_routing` is then [prompts,
+        routed layers, 2], a prompt's own facts a row)."""
         if not self._prefills:
             raise RuntimeError("no prefill in flight: launch_prefill "
                                "comes first")
-        slot, first, n, scanned, self._launched = self._prefills.popleft()
-        first = self._fetch("prefill", first, routed=True,
-                            more=scanned)[0].reshape(-1)
-        tok = int(first[0])
+        slot, first, lens, scanned, self._launched = self._prefills.popleft()
+        group = isinstance(slot, tuple)
+        first = self._fetch("prefill", first, routed=True, more=scanned)[0]
+        # a row a prompt (a group's dead rows behind them): its token first
+        first = first.reshape(first.shape[0] if group else 1, -1)
         if self._ki is not None:
             # [sparse layers, K/V heads, k]: `_prefill_core`
-            self.last_prefill_picks = first[1:].reshape(
+            self.last_prefill_picks = first[0, 1:].reshape(
                 self._ki.shape[0], self.predictor._kv_heads(), -1)
-        self.lengths[slot] = n
-        self.last_tokens[slot] = tok
-        self.active[slot] = True
-        return tok
+        slots = slot if group else (slot,)
+        if group and self.last_routing is not None:
+            self.last_routing = self.last_routing[:len(slots)]
+        toks = [int(t) for t in first[:len(slots), 0]]
+        for i, n, tok in zip(slots, lens, toks):
+            self.lengths[i] = n
+            self.last_tokens[i] = tok
+            self.active[i] = True
+        return toks if group else toks[0]
 
     def decode(self):
         """ONE fixed-shape step over the whole slot table: a one-trip
@@ -4251,11 +4411,13 @@ class DecodeSession:
         got = [np.asarray(o) for o in outs]
         d2h, attrs = _nbytes(got), {}
         if n_routed:
-            facts = got[0][-n_routed:].reshape(-1, 2)
-            got[0] = got[0][:-n_routed]
+            # (a group's prefill: a row of them a prompt, [P, ..])
+            facts = got[0][..., -n_routed:].reshape(
+                got[0].shape[:-1] + (-1, 2))
+            got[0] = got[0][..., :-n_routed]
             self.last_routing = facts
-            attrs = {"moe_experts_touched": int(facts[:, 0].sum()),
-                     "moe_tokens_per_expert_max": int(facts[:, 1].max())}
+            attrs = {"moe_experts_touched": int(facts[..., 0].sum()),
+                     "moe_tokens_per_expert_max": int(facts[..., 1].max())}
         if routed:
             attrs.update(self._stack_attrs)
             if phase == "step" and self._ss is not None:

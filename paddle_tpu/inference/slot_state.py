@@ -404,7 +404,9 @@ def _slot_writers():
     `write_rows(tables, rows, slot)` puts, table by table, `rows` ([L, 1,
     B, H * Dh]; padded to the table's row where that is, a latent
     table's) at `slot` from position 0 (a prefill's K and V, its conv
-    state [L, 1, K-1, C] and its scanned state whole);
+    state [L, 1, K-1, C] and its scanned state whole; with `n`, the first
+    n prompts' rows of a group's prefill, each leaf with a leading P, at
+    the slots `slot` [P] names);
     `zero_slot(tables, slot)` zeroes the slot's whole row of every table
     (its release).  Both take ALL of a session's tables in ONE call: a
     jitted call costs the lane's thread 1.75 ms with the streams' handlers
@@ -425,7 +427,15 @@ def _slot_writers():
             return jax.lax.dynamic_update_slice(
                 table, rows, (0, slot) + (0,) * (table.ndim - 2))
 
-        def write_rows(tables, rows, slot):
+        def write_rows(tables, rows, slot, n=None):
+            if n is not None:
+                # a group's prefill: `rows` [P, L, 1, ..] a table, `slot`
+                # [P] i32; the first `n` land, row j at slot[j] (n traced:
+                # one executable whatever part of a group is dead rows)
+                return jax.lax.fori_loop(0, n, lambda j, tables: write_rows(
+                    tables, [jax.lax.dynamic_index_in_dim(
+                        r, j, keepdims=False) for r in rows], slot[j]),
+                    tuple(tables))
             return tuple(at_slot(t, _pad_rows(r, t.shape[3:]), slot)
                          for t, r in zip(tables, rows))
 

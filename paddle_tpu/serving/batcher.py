@@ -1063,16 +1063,16 @@ class _DecodeRequest:
 
 
 class _Prefill:
-    """An admit whose prefill is launched and not fetched yet
-    (`DecodeBatcher._admit`): its slot, whether the session queued it
-    behind one still unfetched, where its `serving/prefill_compute`
-    starts."""
+    """A prefill CALL of an admission, launched and not fetched yet
+    (`DecodeBatcher._admit`): its requests (one, or a group's in arrival
+    order) and their slots, whether the session queued it behind a call
+    still unfetched, where its `serving/prefill_compute` spans start."""
 
-    __slots__ = ("req", "slot", "ahead", "t0")
+    __slots__ = ("reqs", "slots", "ahead", "t0")
 
-    def __init__(self, req, slot, ahead, t0):
-        self.req = req
-        self.slot = slot
+    def __init__(self, reqs, slots, ahead, t0):
+        self.reqs = reqs
+        self.slots = slots
         self.ahead = ahead
         self.t0 = t0
 
@@ -1556,34 +1556,42 @@ class DecodeBatcher:
             % ((now - req.enqueued) * 1e3, len(req.gen))), **place)
 
     def _admit(self, lane, admits):
-        """Prefill the requests this pass admits, as a pipeline ONE
-        deep: each prompt's prefill is launched before the one ahead of
-        it is fetched (`DecodeSession.launch_prefill` /
-        `fetch_prefill`), so the device finds the next prefill in its
-        queue when it ends one and does not wait for the host to copy a
-        first token back, do the request's bookkeeping and pad the next
-        prompt.  At most one prefill is queued behind the one the
-        device runs: a first token waits for one launch, and two
-        prefills' temporaries are outstanding at most.  With one admit
-        the device calls are those of `prefill`, call for call.
+        """Prefill the requests this pass admits: the same-bucket prompts
+        of the pass as ONE call a group (`_prefill_calls`), the calls as a
+        pipeline ONE deep: each is launched before the one ahead of it is
+        fetched (`DecodeSession.launch_prefill` / `fetch_prefill`), so the
+        device finds the next prefill in its queue when it ends one and
+        does not wait for the host to copy first tokens back, do the
+        requests' bookkeeping and pad the next prompts.  At most one call
+        is queued behind the one the device runs: a first token waits for
+        one launch, and two calls' temporaries are outstanding at most.
+        With one admit the device calls are those of `prefill`, call for
+        call.  A cancelled or expired admit is dropped BEFORE the grouping;
+        everybody else lands in this pass.
 
-        The `serving/prefill_compute` spans of an admission TILE:
-        request i's runs from the end of request i-1's fetch (from its
+        The `serving/prefill_compute` spans of an admission TILE, one a
+        request: call i's run from the end of call i-1's fetch (from its
         own launch, where nothing was in flight) to the end of its own
-        fetch, and carries `ahead` = 1 where the session queued it
-        behind an unfetched one (`launch_prefill` says; a speculative
-        session's launch half is a whole prefill and says no).
+        fetch, its members' an equal share of that each in arrival order,
+        and carry `prompts`, the members of the call the request rode (1
+        alone), and `ahead` = 1 where the session queued the CALL behind an
+        unfetched one (`launch_prefill` says; a speculative session's
+        launch half is a whole prefill and says no).
 
-        A member of the lane's mesh lost under a launch or a fetch: the
-        request fails typed, what is in flight is fetched (or fails
+        A call that raises fails its own members typed and no others.  A
+        member of the lane's mesh lost under a launch or a fetch: the
+        call's requests fail typed, what is in flight is fetched (or fails
         with its own error), the admits not launched yet never touched
-        the mesh and go back to the queue for a surviving lane (if none
-        survives, `_lane_dead` fails the whole queue typed), and the
-        loop's member-loss handler retires the lane whole."""
+        the mesh and go back to the queue, in arrival order, for a
+        surviving lane (if none survives, `_lane_dead` fails the whole
+        queue typed), and the loop's member-loss handler retires the lane
+        whole."""
+        calls = self._prefill_calls(
+            lane, [req for req in admits if self._still_wanted(lane, req)])
         flying = None
         try:
-            for i, req in enumerate(admits):
-                nxt = self._launch_prefill(lane, req, flying is not None)
+            for i, reqs in enumerate(calls):
+                nxt = self._launch_prefill(lane, reqs, flying is not None)
                 if nxt is not None:
                     prev, flying = flying, nxt
                     if prev is not None:
@@ -1592,9 +1600,11 @@ class DecodeBatcher:
             if prev is not None:
                 self._land_prefill(lane, prev)
         except MeshMemberLost:
+            left = {id(req) for reqs in calls[i + 1:] for req in reqs}
             with self._cv:
-                for rem in reversed(admits[i + 1:]):
-                    self._pending.appendleft(rem)
+                for rem in reversed(admits):
+                    if id(rem) in left:
+                        self._pending.appendleft(rem)
                 self._cv.notify_all()
             if flying is not None:
                 try:
@@ -1603,92 +1613,139 @@ class DecodeBatcher:
                     pass
             raise
 
-    def _prefill_span(self, lane, p, t1, **more):
-        """One `serving/prefill_compute` of an admission (`_admit`)."""
-        req = p.req
-        # a stack that prefills in chunks says how many this prompt takes
-        chunks = lane.session.predictor.prefill_chunks(len(req.prompt))
-        if chunks:
-            more["chunks"] = chunks
-        obs_tracing.stamp("serving/prefill_compute", p.t0, t1,
-                          kind="serving", trace_id=req.trace_id,
-                          parent="serving/lane_iter",
-                          model=self._model_name, replica=lane.index,
-                          prompt=len(req.prompt), ahead=int(p.ahead),
-                          **more)
-
-    def _launch_prefill(self, lane, req, behind):
-        """Admit one request into a free slot, first half: queue its
-        prompt's prefill on the device.  Returns the `_Prefill` that
-        `_land_prefill` takes, None for a request dropped (cancelled,
-        expired) or failed here.  `behind`: an earlier request of this
-        admission is not landed yet (its span is open)."""
-        now = time.monotonic()
-        req.t_admitted = now
+    def _still_wanted(self, lane, req):
+        """Whether an admit is to be prefilled at all: one cancelled or
+        past its deadline ends here, before it can enter a group."""
+        req.t_admitted = now = time.monotonic()
         if req.stream.cancelled():
             self._finish(lane, None, req, "cancelled")
-            return None
+            return False
         if req.deadline is not None and now > req.deadline:
             self._expire(lane, None, req, now)
-            return None
+            return False
+        return True
+
+    def _prefill_calls(self, lane, reqs):
+        """The prefill calls of an admission, each a list of requests.
+        The admits sorted by prompt bucket (stably: arrival order inside a
+        bucket), each bucket's run is cut into groups of the bucket's width
+        (`GenerativePredictor.prefill_width`: what ONE call of the bucket's
+        group executable takes); what is left of a run rides that
+        executable too, padded, or goes a prompt a call (`decode.
+        prefill_group`: PERF.md section 6, PR 55).  A pass in which nothing
+        groups keeps the arrival order: every pass of a stack that prefills
+        in chunks, of a lane on a mesh and of a speculative lane (its launch
+        half is a whole blocking prefill of two sessions)."""
+        from ..inference.decode import prefill_group
+        pred = lane.session.predictor
+        runs = {}
+        for req in reqs:
+            runs.setdefault(pred.prompt_bucket(len(req.prompt)),
+                            []).append(req)
+        calls = []
+        for bucket in sorted(runs):
+            run = runs[bucket]
+            width = 1 if lane.spec else pred.prefill_width(bucket)
+            while run:
+                n = prefill_group(width, len(run))
+                calls.append(run[:n])
+                run = run[n:]
+        if len(calls) == len(reqs):
+            return [[req] for req in reqs]
+        return calls
+
+    def _prefill_spans(self, lane, p, t1, **more):
+        """The `serving/prefill_compute` spans of one call of an admission
+        (`_admit`), one a request: equal shares of p.t0 .. t1."""
+        n = len(p.reqs)
+        share = (t1 - p.t0) / n
+        for j, req in enumerate(p.reqs):
+            # a stack that prefills in chunks says how many a prompt takes
+            chunks = lane.session.predictor.prefill_chunks(len(req.prompt))
+            attrs = dict(more, chunks=chunks) if chunks else more
+            obs_tracing.stamp("serving/prefill_compute", p.t0 + j * share,
+                              t1 if j == n - 1 else p.t0 + (j + 1) * share,
+                              kind="serving", trace_id=req.trace_id,
+                              parent="serving/lane_iter",
+                              model=self._model_name, replica=lane.index,
+                              prompt=len(req.prompt), prompts=n,
+                              ahead=int(p.ahead), **attrs)
+
+    def _fail_prefill(self, lane, p, exc, span):
+        """A call's launch or fetch raised: its spans (where `span`), its
+        requests failed typed.  A lost mesh member is the lane's death
+        too: raised on."""
+        if span and obs_tracing.enabled():
+            self._prefill_spans(lane, p, time.monotonic(),
+                                error=type(exc).__name__)
+        for req in p.reqs:
+            self._finish(lane, None, req, "error", exc=exc)
+        if isinstance(exc, MeshMemberLost):
+            raise exc
+
+    def _launch_prefill(self, lane, reqs, behind):
+        """Admit one call's requests into free slots, first half: queue
+        their prompts' prefill on the device (a group's as one call).
+        Returns the `_Prefill` that `_land_prefill` takes, None for a call
+        that failed here.  `behind`: an earlier call of this admission is
+        not landed yet (its spans are open)."""
+        now = time.monotonic()
+        for req in reqs:
+            req.t_admitted = now
         sess = lane.session
-        p = _Prefill(req, sess.free_slots()[0], False, now)
+        p = _Prefill(reqs, sess.free_slots()[:len(reqs)], False, now)
         try:
             with obs_tracing.under("serving/prefill_compute",
-                                   trace_id=req.trace_id):
-                p.ahead = bool(sess.launch_prefill(p.slot, req.prompt))
+                                   trace_id=reqs[0].trace_id):
+                p.ahead = bool(
+                    sess.launch_prefill(p.slots[0], reqs[0].prompt)
+                    if len(reqs) == 1 else
+                    sess.launch_prefill(p.slots, [r.prompt for r in reqs]))
         except BaseException as e:
-            if obs_tracing.enabled() and not behind:
-                # (behind a request not landed yet this time lies in
-                # THAT request's span, which is still open)
-                self._prefill_span(lane, p, time.monotonic(),
-                                   error=type(e).__name__)
-            self._finish(lane, None, req, "error", exc=e)
-            if isinstance(e, MeshMemberLost):
-                # the request failed typed above; the LANE is dead too
-                raise
+            # (behind a call not landed yet this time lies in THAT call's
+            # spans, which are still open)
+            self._fail_prefill(lane, p, e, span=not behind)
             return None
-        lane.ahead += p.ahead
+        lane.ahead += p.ahead * len(reqs)
         return p
 
     def _land_prefill(self, lane, p):
-        """Second half: wait for the oldest launched prefill, stream
-        its first token (the TTFT instant).  Returns the end of its
-        `serving/prefill_compute`."""
-        req, slot, sess = p.req, p.slot, lane.session
+        """Second half: wait for the oldest launched call, stream its
+        requests' first tokens (the TTFT instant), in arrival order.
+        Returns the end of its `serving/prefill_compute` spans."""
+        sess = lane.session
         try:
             with obs_tracing.under("serving/prefill_compute",
-                                   trace_id=req.trace_id):
-                first = sess.fetch_prefill()
+                                   trace_id=p.reqs[0].trace_id):
+                firsts = sess.fetch_prefill()
         except BaseException as e:
             end = time.monotonic()
-            if obs_tracing.enabled():
-                self._prefill_span(lane, p, end, error=type(e).__name__)
-            self._finish(lane, None, req, "error", exc=e)
-            if isinstance(e, MeshMemberLost):
-                raise
+            self._fail_prefill(lane, p, e, span=True)
             return end
-        req.t_first = time.monotonic()
+        end = time.monotonic()
         if obs_tracing.enabled():
-            self._prefill_span(lane, p, req.t_first)
-        if self.metrics is not None:
-            self.metrics.note_prefill(
-                ttft_ms=(req.t_first - req.enqueued) * 1e3)
-            self.metrics.note_tokens(1)
-        lane.tokens += 1
-        req.gen.append(first)
-        req.buf.append(first)
-        lane.assigned[slot] = req
-        if first == self.predictor.eos_id:
-            self._finish(lane, slot, req, "eos", made=req.t_first)
-        elif req.max_new <= 1 or sess.room(slot) <= 0:
-            self._finish(lane, slot, req, "length", made=req.t_first)
-        elif len(req.buf) >= req.chunk:
-            req.stream._put_tokens(
-                req.buf, (req.t_first, time.monotonic())
-                if obs_tracing.enabled() else None)
-            req.buf = []
-        return req.t_first
+            self._prefill_spans(lane, p, end)
+        for req, slot, first in zip(
+                p.reqs, p.slots, firsts if len(p.reqs) > 1 else [firsts]):
+            req.t_first = end
+            if self.metrics is not None:
+                self.metrics.note_prefill(
+                    ttft_ms=(req.t_first - req.enqueued) * 1e3)
+                self.metrics.note_tokens(1)
+            lane.tokens += 1
+            req.gen.append(first)
+            req.buf.append(first)
+            lane.assigned[slot] = req
+            if first == self.predictor.eos_id:
+                self._finish(lane, slot, req, "eos", made=req.t_first)
+            elif req.max_new <= 1 or sess.room(slot) <= 0:
+                self._finish(lane, slot, req, "length", made=req.t_first)
+            elif len(req.buf) >= req.chunk:
+                req.stream._put_tokens(
+                    req.buf, (req.t_first, time.monotonic())
+                    if obs_tracing.enabled() else None)
+                req.buf = []
+        return end
 
     def _emit_step_spans(self, lane, t0, t_draft_end, now, n_slots,
                          rnd, accepted=None, tokens=None, trips=None,

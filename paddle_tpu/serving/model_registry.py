@@ -296,9 +296,11 @@ class ModelEntry:
         plus the fixed-shape slot-table decode step (one executable
         for every window of trips the lane dispatches), on a scratch
         session per replica (the lane sessions share the resolved
-        executables, so the first real stream pays no compile)."""
+        executables, so the first real stream pays no compile), and
+        every bucket's GROUP prefill a lane of this many slots can call
+        (`DecodeBatcher._prefill_calls`), with the write that lands it."""
         if self.is_decode:
-            from ..inference.decode import STEP_WINDOW
+            from ..inference.decode import STEP_WINDOW, prefill_group
             n_slots = self.batcher.n_slots
             spec_k = getattr(self.batcher, "spec_k", 0)
             drafts = getattr(self.batcher, "draft_replicas", None)
@@ -319,6 +321,17 @@ class ModelEntry:
                     sess.decode()
                     sess.decode_fused(STEP_WINDOW)
                     sess.free(0)
+                    # as many same-bucket prompts as the lane can admit
+                    # in a pass, where that makes a group (a speculative
+                    # lane prefills a prompt a call)
+                    members = 1 if drafts and spec_k else prefill_group(
+                        pred.prefill_width(bucket), n_slots)
+                    if members > 1:
+                        sess.launch_prefill(list(range(members)),
+                                            [[0] * n] * members)
+                        sess.fetch_prefill()
+                        for slot in range(members):
+                            sess.free(slot)
                 if drafts and spec_k:
                     # spec lanes: force-resolve the verify executable
                     # plus the draft's phases so the first real stream

@@ -25,10 +25,10 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
     list it joined, and only a `benchmark` PR may edit that file: behind
     them stand the one reader PR 49 appended, the one PR 50 did, and PR
     51's cell, configuration, three readers and its cell's name in the
-    lists, the one reader PR 53 appended and the three PR 54 did; the rest
-    is as it was."""
+    lists, the one reader PR 53 appended, the three PR 54 did and the one
+    PR 55 did; the rest is as it was."""
     later = "mimov2flash_reasoning_decode"
-    assert [m["name"] for m in manifest["per_layer"][-9:]] == [
+    assert [m["name"] for m in manifest["per_layer"][-10:-1]] == [
         "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill",
         "kinds_attention_roofline", "attention_share_of_trip",
         "full_kv_bytes_per_slot", "prefill_ahead_share",
@@ -43,7 +43,7 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
         manifest, workloads=manifest["workloads"][:-1],
         configs=manifest["configs"][:-1],
         end_to_end=as_it_was(manifest["end_to_end"]),
-        per_layer=as_it_was(manifest["per_layer"][:-9])))
+        per_layer=as_it_was(manifest["per_layer"][:-10])))
 
 
 # the instruction of stage 2's Mosaic call as a prefill executable's text

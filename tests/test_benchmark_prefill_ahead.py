@@ -53,8 +53,9 @@ def test_prefill_ahead_share_reader(case, spans, want):
 
 def test_prefill_ahead_share_is_declared_last_for_the_six_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # (last but for the three readers PR 54 appended behind it)
-    assert manifest["per_layer"][-4] == {
+    # (last but for the three readers PR 54 and the one PR 55 appended
+    # behind it)
+    assert manifest["per_layer"][-5] == {
         "name": "prefill_ahead_share", "unit": "%", "better": "higher",
         "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -69,8 +70,8 @@ def test_prefill_ahead_share_is_declared_last_for_the_six_cells():
     # each of them reports what it moves, and the layer is one the manifest
     # already names
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert set(manifest["per_layer"][-4]["workloads"]) < set(e2e["workloads"])
-    assert "scheduler" in {m["layer"] for m in manifest["per_layer"][:-4]}
+    assert set(manifest["per_layer"][-5]["workloads"]) < set(e2e["workloads"])
+    assert "scheduler" in {m["layer"] for m in manifest["per_layer"][:-5]}
 
 
 @pytest.mark.parametrize("cell,listed", [

@@ -98,10 +98,10 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       "mimov2flash_reasoning_decode"]}
     # appended, not inserted: only PR 35's five readers, PR 38's one,
     # PR 39's nine, PR 42's six, PR 44's five, PR 45's one, PR 48's
-    # eight, PR 49's one, PR 50's one, PR 51's three, PR 53's one and
-    # PR 54's three stand behind it
+    # eight, PR 49's one, PR 50's one, PR 51's three, PR 53's one,
+    # PR 54's three and PR 55's one stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 45
+        manifest["per_layer"]) - 46
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +148,7 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     # PR 39's nine readers, PR 42's six, PR 44's five, PR 45's one,
     # PR 48's eight, PR 49's one, PR 50's one, PR 51's three, PR 53's
     # one and PR 54's three stand behind it
-    assert manifest["per_layer"][-39] == {
+    assert manifest["per_layer"][-40] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -163,4 +163,4 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       "mimov2flash_reasoning_decode"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-39]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-40]["workloads"] == e2e["workloads"]

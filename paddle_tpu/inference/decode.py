@@ -255,7 +255,9 @@ BLOCK_DEFAULTS = (
     ("norm", "layernorm"),        # | "rmsnorm" (gain only, no bias)
     ("norm_eps", 1e-5),
     ("position", "learned"),      # | "rope" (half-split rotary over the
-    ("rope_theta", 10000.0),      #   whole head, no position table)
+    ("rope_theta", 10000.0),      #   whole head, no position table) | "none"
+                                  #   (no table and no rotation: causality
+                                  #   and the recurrent layers order it)
     ("qk_norm", False),           # True: RMSNorm of the whole q / k
                                   # projection | "head": of each head (a
                                   # gain of the head size), before RoPE
@@ -407,6 +409,16 @@ BLOCK_DEFAULTS = (
     ("window_rope_theta", 0.0),
     ("value_scale", 1.0),
     ("window_sink", False),
+    # layer_types "ssm": a state-space (Mamba-2) mixer as the layer's ONLY
+    # operator (`GenerativePredictor._ssm`, the one an attention+ssm layer
+    # runs beside its attention; the `ssm_*` keys above size it): its slot
+    # state is the conv's last inputs and the scanned state, no K/V rows.
+    # `attention_multiplier`: the softmax scale of the attending layers, q . k
+    # times this (0 = 1/sqrt(head_dim)).  `residual_multiplier`: what every
+    # sublayer's result is multiplied by as it joins the residual stream
+    # (1 = none)
+    ("attention_multiplier", 0.0),
+    ("residual_multiplier", 1.0),
 )
 # the keys added since the phases' rev 12, with their defaults
 # (`GenerativePredictor._fingerprint`), BY NAME: a key appended to
@@ -414,9 +426,9 @@ BLOCK_DEFAULTS = (
 # way moves none of these out
 _LATER_KEYS = {k: dict(BLOCK_DEFAULTS)[k] for k in (
     "window_kv_heads", "rotary_dim", "window_rope_theta", "value_scale",
-    "window_sink")}
+    "window_sink", "attention_multiplier", "residual_multiplier")}
 _BLOCK_CHOICES = {"norm": ("layernorm", "rmsnorm"),
-                  "position": ("learned", "rope"),
+                  "position": ("learned", "rope", "none"),
                   "qk_norm": (False, True, "head"),
                   # "swiglu": ONE dense gated FFN of `dense_width` in every
                   # layer
@@ -618,20 +630,21 @@ def block_of(meta):
                     "decode meta %s=%r does not go with layer_types mla "
                     "(its one shared row has no heads to norm or group)"
                     % (key, out[key]))
-    if "attention+ssm" in kinds:
+    mixers = "|".join(t for t in slot_state.SSM_OPS if t in kinds)
+    if mixers:
         if "conv" in kinds:
             raise ValueError(
-                "decode meta layer_types=%r mixes conv with attention+ssm "
+                "decode meta layer_types=%r mixes conv with %s "
                 "layers: a session's conv-state table has one width"
-                % (list(kinds),))
+                % (list(kinds), mixers))
         for key in _SSM_DIMS + ("ssm_chunk",):
             if out[key] < 1:
-                raise ValueError("decode meta %s=%d: an attention+ssm layer "
-                                 "needs it >= 1" % (key, out[key]))
+                raise ValueError("decode meta %s=%d: an %s layer "
+                                 "needs it >= 1" % (key, out[key], mixers))
         if out["ssm_conv_kernel"] < 2:
             raise ValueError("decode meta ssm_conv_kernel=%d: an "
-                             "attention+ssm layer's conv needs at least 2 "
-                             "taps" % out["ssm_conv_kernel"])
+                             "%s layer's conv needs at least 2 "
+                             "taps" % (out["ssm_conv_kernel"], mixers))
         if out["ssm_heads"] % out["ssm_groups"]:
             raise ValueError("decode meta ssm_groups=%d does not divide "
                              "ssm_heads %d" % (out["ssm_groups"],
@@ -643,14 +656,27 @@ def block_of(meta):
                 % (list(out["ssm_multipliers"]),))
         if linear:
             raise ValueError(
-                "decode meta layer_types=%r mixes attention+ssm with "
+                "decode meta layer_types=%r mixes %s with "
                 "linear_attention layers: a session's scanned-state table "
-                "has one shape" % (list(kinds),))
+                "has one shape" % (list(kinds), mixers))
     elif out["ssm_multipliers"] or any(
             out[k] != 1.0 for k in ("ssm_in_multiplier",
                                     "ssm_out_multiplier")):
         raise ValueError("decode meta ssm_*multiplier* goes with "
-                         "layer_types attention+ssm")
+                         "layer_types attention+ssm|ssm")
+    if out["attention_multiplier"] < 0.0 or (
+            out["attention_multiplier"] and ("mla" in kinds or chunked)):
+        raise ValueError(
+            "decode meta attention_multiplier=%r: the softmax scale of "
+            "attention | attention+ssm | window_attention layers, > 0 (0 = "
+            "1/sqrt(head_dim)); mla, sparse_attention and linear_attention "
+            "layers scale their scores themselves (layer_types=%r)"
+            % (out["attention_multiplier"], list(kinds)))
+    if out["residual_multiplier"] != 1.0 and out["ffn"] == "relu_mlp":
+        raise ValueError(
+            "decode meta residual_multiplier=%r goes with ffn=swiglu|"
+            "moe_swiglu (the GPT-2-shaped block's MLP joins the residual "
+            "stream with its bias, unscaled)" % out["residual_multiplier"])
     if out["ffn"] == "swiglu" and (out["dense_width"] < 1
                                    or out["n_dense_layers"]):
         raise ValueError(
@@ -744,7 +770,7 @@ def layer_kinds(meta, blk=None):
     """(operator, FFN) of every layer of the stack `meta` describes
     (`blk`: its `block_of`, where the caller has it): operator
     "attention" | "conv" | "mla" | "attention+ssm" | "window_attention" |
-    "sparse_attention" | "linear_attention", FFN "dense_swiglu"
+    "sparse_attention" | "linear_attention" | "ssm", FFN "dense_swiglu"
     (the first `n_dense_layers`; every layer under ffn=swiglu) or the
     meta's `ffn`."""
     blk = blk or block_of(meta)
@@ -817,7 +843,7 @@ def decode_state_shapes(meta):
                 shapes[p + "on_g"] = (Hs * P,)
             if blk["output_gate"]:
                 shapes[p + "wg"] = (D, Hs * P)
-        else:
+        elif op != "ssm":
             # the layer's own geometry: K/V heads by kind, K and V rows
             # by leaf
             Hc, _, Dv = slot_state.attention_geometry(
@@ -833,7 +859,7 @@ def decode_state_shapes(meta):
                 shapes[p + "wg"] = (D, H * Dv)
             if blk["window_sink"] and op == "window_attention":
                 shapes[p + "sink"] = (H,)
-        if op == "attention+ssm":
+        if op in slot_state.SSM_OPS:
             d_ssm, conv, wide = _ssm_widths(blk)
             Hs = blk["ssm_heads"]
             shapes[p + "ssm_in"], shapes[p + "ssm_out"] = (D, wide), (d_ssm,
@@ -1096,7 +1122,7 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     """Dropless, exact top-k routed SwiGLU experts: h [T, D], router
     [D, E], w_gate / w_up [E, D, F], w_down [E, F, D] ->
     (sum over each token's k experts of p_e * ((silu(h @ w_gate[e]) *
-    (h @ w_up[e])) @ w_down[e]) [T, D], facts [2] i32).  No capacity: every
+    (h @ w_up[e])) @ w_down[e]) [T, D], facts [3] i32).  No capacity: every
     (token, expert) pair is computed, whatever the routing.
 
     ONE form for both regimes: the T * k pairs are sorted by expert and
@@ -1129,7 +1155,8 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     result is this member's PART of the layer's.
 
     facts = (experts HELD HERE that received a token, most tokens one of
-    them received), counted over the tokens `live` [T] marks (all if None):
+    them received, the (token, expert) pairs that STAYED here: all T * k
+    without `held`), counted over the tokens `live` [T] marks (all if None):
     a dead slot's or a pad position's row is computed but not counted.
     A list given as `picks` receives the chosen experts [T, k] i32 (at
     trace time): what a comparison with a reference needs to tell the
@@ -1180,7 +1207,7 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
             onehot & jnp.repeat(live, k)[:, None], axis=0,
             dtype=jnp.int32)
         facts = jnp.stack([jnp.sum(counted > 0, dtype=jnp.int32),
-                           jnp.max(counted)])
+                           jnp.max(counted), jnp.sum(counted)])
 
         def through_experts(h, flat, sizes, w_gate, w_up, w_down):
             """Each pair's row through its expert -> [T * k, D], token t's
@@ -1209,6 +1236,10 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
                 # groups is not free), and over all T * k only in a dispatch
                 # whose routing crowds more than `cap` pairs onto this
                 # member: dropless and exact either way
+                # (a member that holds a quarter of the experts or more, 18
+                # of 72 say, has cap == pairs: no `cond` is built and the
+                # grouped matmuls run over every pair's row, three quarters
+                # of them dead rows behind the groups; ROADMAP S6)
                 stay, pairs = jnp.sum(sizes), flat.shape[0]
                 cap = min(pairs,
                           max(64, 4 * -(-pairs * E // router.shape[1])))
@@ -1280,8 +1311,9 @@ def _prompts_share_experts():
 
 def _pack_routing(tokens, facts):
     """A routed-expert phase's first result: its tokens, then each
-    ROUTED layer's (experts touched, most tokens on one expert; a layer
-    with a dense FFN has `None` for its facts), as ONE int32
+    ROUTED layer's (experts touched, most tokens on one expert, pairs that
+    stayed on this member; a layer with a dense FFN has `None` for its
+    facts), as ONE int32
     vector, so the routing facts ride the fetch that brings the tokens
     (`DecodeSession._fetch` splits them off again)."""
     import jax.numpy as jnp
@@ -1866,6 +1898,18 @@ class GenerativePredictor:
         return bool(self.conv_layers and self.routed_layers) or bool(
             self._table_layer(None, "index"))
 
+    @property
+    def _prefill_picks(self):
+        """Whether a prefill hands out the experts its routed layers chose
+        at EVERY position of the bucket ([routed layers, B, k] i32, behind
+        its first token: `DecodeSession.last_prefill_picks`): a stack with
+        routed FFNs behind state-space layers.  A scanned state carries a
+        prompt position's routing to every later position with no horizon
+        (a conv layer's taps end after K - 1), so a comparison with a
+        reference can tell a near-tie at a prompt's position from a fault
+        only by the prefill's own picks there."""
+        return bool(self.ssm_layers and self.routed_layers)
+
     def _require(self, what, capability):
         """Raise for `what` (a placement, a phase) that needs a
         `capability` (of `slot_state.CAPABILITIES`) a kind of slot state
@@ -2081,6 +2125,13 @@ class GenerativePredictor:
             self.meta, self._block_meta, window=op == "window_attention")[0]
 
     @functools.cached_property
+    def _attention_scale(self):
+        """What an attending layer's q . k is multiplied by: the meta's
+        `attention_multiplier`, or 1/sqrt(head_dim)."""
+        return self._block_meta["attention_multiplier"] \
+            or 1.0 / np.sqrt(self._dims()[2])
+
+    @functools.cached_property
     def _v_head_dim(self):
         """A value head's lanes (a key head's: `_dims`)."""
         return slot_state.attention_geometry(self.meta, self._block_meta)[2]
@@ -2266,22 +2317,30 @@ class GenerativePredictor:
             # prefill's selection to a reference's by them
             return (jnp.concatenate([first.reshape(1),
                                      picks.reshape(-1)]),) + tables
+        picks = [] if self._prefill_picks else None
         x, facts, tables = self._prefill_layers(state, tokens, true_len,
-                                                tp)
+                                                tp, picks=picks)
         first = self._first_token(state, x, true_len, tp)
+        if picks:
+            # [routed layers, B, k] behind the token, in front of the facts
+            import jax.numpy as jnp
+            first = jnp.concatenate([first.reshape(1),
+                                     jnp.stack(picks).reshape(-1)])
         if self.routed_layers:
             first = _pack_routing(first, facts)
         return (first,) + tables
 
-    def _prefill_layers(self, state, tokens, true_len, tp=_OFF_MESH):
+    def _prefill_layers(self, state, tokens, true_len, tp=_OFF_MESH,
+                        picks=None):
         """`_prefill_core` up to the head: (the last layer's x [1, B, D],
         the layers' routing facts, the slot state the prompt leaves, as
-        `_prefill_core` returns it)."""
+        `_prefill_core` returns it).  A list given as `picks` receives each
+        routed layer's chosen experts [B, k]."""
         import jax
         import jax.numpy as jnp
-        L, _, Dh, _ = self._dims()
+        L = self._dims()[0]
         B = tokens.shape[1]
-        scale = 1.0 / np.sqrt(Dh)
+        scale = self._attention_scale
         x = self._embed(state, tokens, slice(B), tp)
         positions = jnp.arange(B)[None]                     # [1, B]
         live = positions[0] < true_len
@@ -2340,7 +2399,7 @@ class GenerativePredictor:
             x, f = self._block(
                 state, i, x, positions, attend_window
                 if self.layer_kinds[i][0] == "window_attention" else attend,
-                live, tp=tp, convolve=convolve, latent=latent,
+                live, tp=tp, convolve=convolve, picks=picks, latent=latent,
                 ssm=("ssm_scan", scan))
             facts.append(f)
         # what the prompt leaves of each kind, from its layers' rows
@@ -2592,13 +2651,17 @@ class GenerativePredictor:
         the residual stream together; `ssm` = (the phase's scope, its
         `scan(xs, Bm, Cm, dt, A)`: the recurrence's outputs at the
         positions, from wherever the phase keeps the scanned state), and
-        `convolve` finds the mixer's conv its earlier inputs.  A
+        `convolve` finds the mixer's conv its earlier inputs; an SSM layer
+        is that mixer ALONE (no attention, no K/V rows).  With meta
+        residual_multiplier every sublayer's result is multiplied by it as
+        it joins the residual stream; with position=none nothing tells an
+        attending layer a position but causality.  A
         LINEAR_ATTENTION layer (`_linear`) runs its recurrence through the
         same `ssm` callback; a SPARSE_ATTENTION layer is an attention layer
         whose phase hands it an `attend` that selects blocks.  `live`
         [tokens] marks the rows a routed FFN counts,
         and a list given as `picks` receives its chosen experts.
-        Returns (x', routing facts [2] i32 or None).  Under TP each
+        Returns (x', routing facts [3] i32 or None).  Under TP each
         column->row pair closes with one psum."""
         import contextlib
         import jax
@@ -2611,10 +2674,14 @@ class GenerativePredictor:
         lead = x.shape[:-1]
         h = self._norm(x, state, p + "ln1")
 
+        def scaled(y):
+            return y if blk["residual_multiplier"] == 1.0 \
+                else y * blk["residual_multiplier"]
+
         def joins(y, name):
             # a sublayer's result on its way into the residual stream
-            return self._norm(y, state, p + name) \
-                if blk["sandwich_norm"] else y
+            return scaled(self._norm(y, state, p + name)
+                          if blk["sandwich_norm"] else y)
 
         def project(w, heads, gain=None, size=Dh):
             t = _mm(h if blk["attention_in_multiplier"] == 1.0
@@ -2639,6 +2706,8 @@ class GenerativePredictor:
             with jax.named_scope("linear_attention"):
                 x = x + joins(self._linear(state, p, h, positions, project,
                                            *ssm), "ln1p")
+        elif op == "ssm":
+            x = x + joins(self._ssm(state, p, h, convolve, *ssm), "ln1p")
         else:
             Hkv = self._kv_heads(op) // tp.size
             Dv = self._v_head_dim
@@ -2677,7 +2746,7 @@ class GenerativePredictor:
                     att = att * blk["attention_out_multiplier"]
                 x = x + joins(att, "ln1p")
             if op == "attention+ssm":
-                x = x + self._ssm(state, p, h, convolve, *ssm)
+                x = x + scaled(self._ssm(state, p, h, convolve, *ssm))
         h2 = self._norm(x, state, p + "ln2")
         if ffn == "dense_swiglu":
             with jax.named_scope("dense_ffn"):
@@ -2710,8 +2779,9 @@ class GenerativePredictor:
         return x + tp.psum(mlp) + state[p + "b2"], None
 
     def _ssm(self, state, p, h, convolve, scope, scan):
-        """An attention+ssm layer's state-space mixer (Mamba-2 / SSD) on
-        the normed input h [..., D] (weights `state[p + "ssm_" + name]`)
+        """An attention+ssm or an ssm layer's state-space mixer (Mamba-2 /
+        SSD) on the normed input h [..., D] (weights `state[p + "ssm_" +
+        name]`)
         -> [..., D], before the residual sum:
 
             [z | xBC | dt] = ((h * ssm_in_multiplier) ssm_in) * the
@@ -2917,9 +2987,9 @@ class GenerativePredictor:
         from paddle_tpu.parallel.mesh import tp_param_pspec
         from paddle_tpu.parallel.ulysses import (heads_to_seq,
                                                  seq_to_heads)
-        L, _, Dh, _ = self._dims()
+        L = self._dims()[0]
         Bl = tokens.shape[1] // tp.size
-        scale = 1.0 / np.sqrt(Dh)
+        scale = self._attention_scale
         at = tp.index() * Bl
         positions = (at + jnp.arange(Bl))[None]              # [1, Bl]
         x = self._embed(
@@ -2989,8 +3059,8 @@ class GenerativePredictor:
         heads."""
         from paddle_tpu.ops.pallas_kernels import (
             decode_attention, decode_attention_head_slice)
-        Hl, Dh = q.shape[1:]
-        scale = 1.0 / np.sqrt(Dh)
+        Hl = q.shape[1]
+        scale = self._attention_scale
         scales = self._kv_scales[:, i] if self._kv_quant else None
         seen = lengths + ahead
         if window:
@@ -3298,7 +3368,8 @@ class GenerativePredictor:
         [N] (a slot's OWN count: the trips it ran, at most the window's),
         the trips run, and for a routed-expert artifact each layer's
         (experts touched SUMMED over the trips, most tokens on one
-        expert, the LARGEST over the trips), which is what
+        expert, the LARGEST over the trips, the pairs that stayed on this
+        member SUMMED over the trips), which is what
         `_pack_routing` carries for a prefill.  Its arguments are those
         of `_step_specs`, flat."""
         import jax
@@ -3333,7 +3404,8 @@ class GenerativePredictor:
                     f = jnp.stack([r for r in f if r is not None])
                     facts = jnp.stack(
                         [facts[:, 0] + f[:, 0],
-                         jnp.maximum(facts[:, 1], f[:, 1])], axis=1)
+                         jnp.maximum(facts[:, 1], f[:, 1]),
+                         facts[:, 2] + f[:, 2]], axis=1)
                 last = jnp.where(alive, tok, last)
                 emitted = emitted + alive.astype(jnp.int32)
                 alive = (alive & (tok != jnp.int32(eos))
@@ -3343,7 +3415,7 @@ class GenerativePredictor:
 
             carry = (jnp.int32(0), tables, last_tokens,
                      jnp.zeros((N, W), jnp.int32),
-                     jnp.zeros((routed, 2), jnp.int32),
+                     jnp.zeros((routed, 3), jnp.int32),
                      alive, jnp.zeros((N,), jnp.int32))
             i, tables, _last, toks, facts, _, emitted = jax.lax.while_loop(
                 cond, body, carry)
@@ -3447,7 +3519,10 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (12: the
+            # rev bumps when the phase math itself changes shape (13: a
+            # routed layer's phases hand out a third fact, the pairs that
+            # stayed here, and a prefill of routed FFNs behind state-space
+            # layers its picks; 12: the
             # sparse decode kernel stages T selected tiles a grid step,
             # `pallas_kernels.sparse_tiles_per_step`; 11: a
             # prefill in chunks hands out the blocks its last position
@@ -3478,7 +3553,7 @@ class GenerativePredictor:
                       for k in sorted(self._block_meta)
                       if k not in _LATER_KEYS
                       or self._block_meta[k] != _LATER_KEYS[k]],
-            "rev": 12,
+            "rev": 13,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -3962,6 +4037,7 @@ class DecodeSession:
         # (experts touched, most tokens on one expert) of each routed
         # layer [routed layers, 2]
         self.last_routing = None
+        self.last_pairs_held = None
         # a hybrid routed stack's newest `decode_logits`: the experts
         # each routed layer chose [routed layers, n_slots, k]
         self.last_picks = None
@@ -4245,7 +4321,10 @@ class DecodeSession:
         copy of its first token (`_fetch`), then the slot's length, last
         token and occupancy.  Returns the token; of a group the list of
         its first tokens, a prompt each (`last_routing` is then [prompts,
-        routed layers, 2], a prompt's own facts a row)."""
+        routed layers, 2], a prompt's own facts a row).  A stack with
+        routed FFNs behind state-space layers keeps the experts each
+        routed layer chose at every position of the bucket as
+        `last_prefill_picks`."""
         if not self._prefills:
             raise RuntimeError("no prefill in flight: launch_prefill "
                                "comes first")
@@ -4259,8 +4338,16 @@ class DecodeSession:
             self.last_prefill_picks = first[0, 1:].reshape(
                 self._ki.shape[0], self.predictor._kv_heads(), -1)
         slots = slot if group else (slot,)
+        if self.predictor._prefill_picks:
+            # [routed layers, bucket, k], a prompt's own ([prompts, ..] of
+            # a group): `GenerativePredictor._prefill_picks`
+            picks = first[:len(slots), 1:].reshape(
+                len(slots), self._n_routed, -1,
+                self.predictor._block_meta["experts_per_token"])
+            self.last_prefill_picks = picks if group else picks[0]
         if group and self.last_routing is not None:
             self.last_routing = self.last_routing[:len(slots)]
+            self.last_pairs_held = self.last_pairs_held[:len(slots)]
         toks = [int(t) for t in first[:len(slots), 0]]
         for i, n, tok in zip(slots, lens, toks):
             self.lengths[i] = n
@@ -4386,9 +4473,11 @@ class DecodeSession:
         here too.  `routed` marks a step's or a prefill's result: for a
         routed-expert artifact the call's routing facts sit behind its
         tokens in that one vector (`_pack_routing`, `_step_math`) and
-        are split off here, kept as `last_routing` and given to the
-        fetch span as `moe_experts_touched` (summed over the layers and
-        a step's trips) and `moe_tokens_per_expert_max`.  `trips_at`
+        are split off here, kept as `last_routing` (and `last_pairs_held`)
+        and given to the fetch span as `moe_experts_touched` and
+        `moe_pairs_held` (the live pairs that stayed on this member; both
+        summed over the layers and a step's trips) and
+        `moe_tokens_per_expert_max`.  `trips_at`
         is where a step's vector holds the trips it ran, behind each
         slot's emitted count: all three spans carry them as `trips`,
         and the fetch span what the decode kernel streamed in them
@@ -4404,7 +4493,7 @@ class DecodeSession:
         slots HOLD of the two kinds of K/V table as the call begins
         (`full_kv_live_bytes`, `window_kv_live_bytes`: `kv_live_bytes`).
         Any other artifact takes the path it always took."""
-        n_routed = 2 * self._n_routed if routed else 0
+        n_routed = 3 * self._n_routed if routed else 0
         if not (n_routed or obs_tracing.enabled()):
             return [np.asarray(o) for o in outs]
         t0 = time.monotonic()
@@ -4413,11 +4502,13 @@ class DecodeSession:
         if n_routed:
             # (a group's prefill: a row of them a prompt, [P, ..])
             facts = got[0][..., -n_routed:].reshape(
-                got[0].shape[:-1] + (-1, 2))
+                got[0].shape[:-1] + (-1, 3))
             got[0] = got[0][..., :-n_routed]
-            self.last_routing = facts
+            self.last_routing = facts[..., :2]
+            self.last_pairs_held = facts[..., 2]
             attrs = {"moe_experts_touched": int(facts[..., 0].sum()),
-                     "moe_tokens_per_expert_max": int(facts[..., 1].max())}
+                     "moe_tokens_per_expert_max": int(facts[..., 1].max()),
+                     "moe_pairs_held": int(facts[..., 2].sum())}
         if routed:
             attrs.update(self._stack_attrs)
             if phase == "step" and self._ss is not None:

@@ -34,9 +34,16 @@ import numpy as np
 # what a placement or a phase may ask of a stack's slot state
 CAPABILITIES = ("rollback", "mesh", "speculative", "int8")
 
+# the operators that run a state-space mixer (`decode._ssm`), beside an
+# attention or alone
+SSM_OPS = ("attention+ssm", "ssm")
+
 # the kinds of slot state a layer's operator keeps
 HOLDS = {"attention": ("kv",), "conv": ("conv",), "mla": ("latent",),
          "attention+ssm": ("kv", "conv", "ssm"),
+         # a state-space (Mamba-2) mixer ALONE: its conv's last inputs and
+         # its scanned state, no K/V rows
+         "ssm": ("conv", "ssm"),
          "window_attention": ("ring",),
          # block-sparse attention: every position's K and V row, as an
          # attention layer's, and the INDEXER's compressed keys beside them
@@ -100,8 +107,9 @@ def _k_and_v(rows, heads, dk, dv):
 
 
 def ssm_widths(blk):
-    """(d_ssm, conv channels, ssm_in's outputs) of an attention+ssm
-    layer: the heads' features; those and the groups' B and C, which the
+    """(d_ssm, conv channels, ssm_in's outputs) of a layer with a
+    state-space mixer (`SSM_OPS`): the heads' features; those and the
+    groups' B and C, which the
     conv runs over; and z, the conv's channels and a dt a head."""
     d_ssm = blk["ssm_heads"] * blk["ssm_head_dim"]
     conv = d_ssm + 2 * blk["ssm_groups"] * blk["ssm_state"]
@@ -152,17 +160,18 @@ def _latent_rows(meta, blk, device):
 def _conv_window(meta, blk, device):
     """(K - 1, C): the last inputs of the filter of a layer that
     CONVOLVES, a fixed size whatever the slot's length: a conv layer's (K =
-    conv_kernel, C = D) or an attention+ssm layer's (K = ssm_conv_kernel,
-    C = the heads' features and the groups' B and C; the two do not mix:
-    `block_of`)."""
-    if "attention+ssm" in blk["layer_types"]:
+    conv_kernel, C = D) or a state-space mixer's (`SSM_OPS`; K =
+    ssm_conv_kernel, C = the heads' features and the groups' B and C; the
+    two do not mix: `block_of`)."""
+    if set(SSM_OPS) & set(blk["layer_types"]):
         return blk["ssm_conv_kernel"] - 1, ssm_widths(blk)[1]
     return blk["conv_kernel"] - 1, int(meta["d_model"])
 
 
 def _scanned(meta, blk, device):
-    """(ssm_heads, ssm_head_dim, ssm_state): the SCANNED state of an
-    attention+ssm layer, a decayed running sum over all the slot's
+    """(ssm_heads, ssm_head_dim, ssm_state): the SCANNED state of a
+    state-space mixer (an attention+ssm or an ssm layer's), a decayed
+    running sum over all the slot's
     positions, a fixed size, read and rewritten whole by every token.  A
     linear_attention layer's state is the same thing, S = decay S + v
     (outer) k a head (ssm_heads heads of ssm_head_dim values by ssm_state

@@ -48,6 +48,7 @@ __all__ = ["tiled_contraction", "flash_attention", "decode_attention",
            "bottleneck_reference", "dequant_matmul",
            "dequant_matmul_reference", "mosaic_lowering", "ssm_update",
            "ssm_update_reference", "ssm_update_block_heads",
+           "ssm_update_heads_per_row",
            "sparse_decode_attention", "sparse_decode_attention_reference",
            "sparse_tiles_per_step", "sparse_prefill_attention",
            "sparse_prefill_attention_reference", "sparse_prefill_tiles"]
@@ -1186,22 +1187,35 @@ def ssm_update_reference(ss, decay, dtx, Bm, Cm, active, layer):
     return jnp.where(run[:, None, None], jnp.sum(new * Ch, axis=-1), 0), ss
 
 
+def ssm_update_heads_per_row(heads, head_dim):
+    """Heads whose dt x (and y) `ssm_update` holds side by side in ONE
+    128-lane row: 128 // head_dim for a head of 64, 32, 16 or 8 features
+    (Granite-4.0-H's 64: two), where the heads come in whole rows; 1 for
+    every other size (a head of 128 or more fills its rows alone)."""
+    per_row = 128 // head_dim if head_dim < 128 else 1
+    return per_row if (per_row > 1 and per_row * head_dim == 128
+                       and head_dim % 8 == 0 and heads % per_row == 0) else 1
+
+
 def ssm_update_block_heads(heads, head_dim, state, mosaic=None):
     """Heads of one slot's scanned state a grid step of `ssm_update`
     stages, from the shape alone: the most that divide `heads` and keep a
     block [block, head_dim, state] fp32 within `_SSM_BLOCK_BYTES`; under
     Mosaic (`mosaic`; by default where the trace lowers for a TPU) whole
-    groups of `_SSM_HEAD_GROUP`, since a group's dt x and y are one
-    (8, 128) tile each.  None where Mosaic has no such block (the heads
-    make no group, a group is past the block's bytes) or a head's
-    [head_dim, state] is not whole tiles: `ssm_update` then IS its
-    reference."""
+    groups of `_SSM_HEAD_GROUP` lane rows of dt x and y (a group's are one
+    (8, 128) tile each: 8 heads of 128 features or more, 16 of 64:
+    `ssm_update_heads_per_row`).  None where Mosaic has no such block (the
+    heads make no group, a group is past the block's bytes) or a head's
+    [head_dim, state] is not whole tiles (the state not whole lanes, the
+    head's features neither whole lane rows nor an even part of one):
+    `ssm_update` then IS its reference."""
     if mosaic is None:
         mosaic = lowering_for_tpu()
-    if mosaic and (head_dim % 128 or state % 128):
+    per_row = ssm_update_heads_per_row(heads, head_dim)
+    if mosaic and (state % 128 or (head_dim % 128 and per_row == 1)):
         return None
     fit = max(1, _SSM_BLOCK_BYTES // (4 * head_dim * state))
-    unit = _SSM_HEAD_GROUP if mosaic else 1
+    unit = (_SSM_HEAD_GROUP if mosaic else 1) * per_row
     blocks = [b for b in range(unit, heads + 1, unit)
               if heads % b == 0 and b <= fit]
     return max(blocks) if blocks else None
@@ -1252,6 +1266,18 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
     5.15 and this body 5.15: PERF.md section 6, PR 46).  decay is a
     scalar-prefetch operand (one SMEM scalar a head, no broadcast tile).
 
+    A HEAD OF 64 FEATURES (Granite-4.0-H: 128 heads of 64 by 128) has a
+    state of eight whole (8, 128) tiles, and a dt x and a y of half a lane
+    row.  The kernel is handed dt x as [N, Hs / 2, 128], TWO HEADS A LANE
+    ROW (the same bytes: a reshape of [N, Hs, 64] moves nothing), so a
+    group's one transpose gives a column a pair of heads, head 2r down
+    sublanes 0-63 and head 2r + 1 down 64-127; the pair's two products new
+    * C lie one under the other as one [128, Ns] tile, whose one transpose
+    and sum leave the pair's y in one lane row, and y goes out as it came
+    in.  Nothing is padded, at rest or in VMEM; a head of 32, 16 or 8
+    features rides the same body, four, eight or sixteen a row
+    (`ssm_update_heads_per_row`).
+
     A SLOT THAT DOES NOT RUN IS NOT VISITED.  `active` becomes two
     scalar-prefetch vectors: for a slot that does not run, the state's
     index map repeats the block the grid staged LAST (the last block of
@@ -1296,6 +1322,9 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
     bh = ssm_update_block_heads(Hs, P, Ns, mosaic=not interpret)
     if bh is None:
         return ssm_update_reference(ss, decay, dtx, Bm, Cm, active, layer)
+    # heads a lane row of dt x and y, the rows a block, a row's lanes
+    hr = ssm_update_heads_per_row(Hs, P)
+    br, Pr = bh // hr, P * hr
     n_blocks, per_group = Hs // bh, Hs // G
     run = jnp.asarray(active).astype(bool).reshape(N)
     slots = jnp.arange(N, dtype=jnp.int32)
@@ -1312,10 +1341,11 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
         return (layer, slot_ref[b], jnp.where(at < 0, j, at), 0, 0)
 
     state_spec = pl.BlockSpec((None, None, bh, P, Ns), state_map)
-    head_spec = pl.BlockSpec((None, bh, P), lambda b, j, *_: (b, j, 0))
+    head_spec = pl.BlockSpec((None, br, Pr), lambda b, j, *_: (b, j, 0))
     group_spec = pl.BlockSpec((None, G, 1, Ns),
                               lambda b, j, *_: (b, 0, 0, 0))
-    hg = _SSM_HEAD_GROUP if bh % _SSM_HEAD_GROUP == 0 else bh
+    # the lane rows the body takes together
+    hg = _SSM_HEAD_GROUP if br % _SSM_HEAD_GROUP == 0 else br
     lanes = 128 if Ns % 128 == 0 else Ns
 
     def kern(slot_ref, fixed_ref, decay_ref, s_ref, dtx_ref, b_ref, c_ref,
@@ -1326,29 +1356,37 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
         @pl.when(runs)
         def _update():
             def group(q, _):
-                h0 = pl.multiple_of(q * hg, hg)
+                r0 = pl.multiple_of(q * hg, hg)
                 # the group's dt x as columns: ONE transpose, a head's P
                 # values then go down the sublanes as its state's rows do
-                cols = jnp.transpose(dtx_ref[pl.ds(h0, hg), :])   # [P, hg]
+                # (a row's `hr` heads one under the other)
+                cols = jnp.transpose(dtx_ref[pl.ds(r0, hg), :])  # [Pr, hg]
                 ys = []
                 for k in range(hg):
-                    a = j * bh + h0 + k         # the head among the slot's
-                    g = a // per_group
-                    new = (decay_ref[b * Hs + a] * s_ref[h0 + k]
-                           + cols[:, k:k + 1] * b_ref[g])         # [P, Ns]
-                    o_ref[h0 + k] = new
-                    # y = sum_n new C: the 128-lane tiles of a row added
-                    # on the VPU, one transpose, then a sum down the
-                    # sublanes leaves P on the lanes, as y is held
-                    prod = new * c_ref[g]
-                    tile = prod[:, :lanes]
-                    for at in range(lanes, Ns, lanes):
-                        tile = tile + prod[:, at:at + lanes]
+                    tiles = []
+                    for m in range(hr):
+                        # row k's m-th head: among the block's, the slot's
+                        at = (r0 + k) * hr + m
+                        a = j * bh + at
+                        g = a // per_group
+                        new = (decay_ref[b * Hs + a] * s_ref[at]
+                               + cols[m * P:(m + 1) * P, k:k + 1]
+                               * b_ref[g])                        # [P, Ns]
+                        o_ref[at] = new
+                        # y = sum_n new C: the 128-lane tiles of a row
+                        # added on the VPU, one transpose, then a sum down
+                        # the sublanes leaves P on the lanes, as y is held
+                        prod = new * c_ref[g]
+                        tile = prod[:, :lanes]
+                        for n in range(lanes, Ns, lanes):
+                            tile = tile + prod[:, n:n + lanes]
+                        tiles.append(tile)
+                    tile = jnp.concatenate(tiles, axis=0)     # [Pr, lanes]
                     ys.append(jnp.sum(jnp.transpose(tile), axis=0,
-                                      keepdims=True))             # [1, P]
-                y_ref[pl.ds(h0, hg), :] = jnp.concatenate(ys, axis=0)
+                                      keepdims=True))            # [1, Pr]
+                y_ref[pl.ds(r0, hg), :] = jnp.concatenate(ys, axis=0)
                 return 0
-            jax.lax.fori_loop(0, bh // hg, group, 0)
+            jax.lax.fori_loop(0, br // hg, group, 0)
 
         @pl.when(jnp.logical_not(runs))
         def _idle():
@@ -1367,7 +1405,7 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
                 num_scalar_prefetch=3, grid=(N, n_blocks),
                 in_specs=[state_spec, head_spec, group_spec, group_spec],
                 out_specs=[head_spec, state_spec]),
-            out_shape=[jax.ShapeDtypeStruct((N, Hs, P), jnp.float32),
+            out_shape=[jax.ShapeDtypeStruct((N, Hs // hr, Pr), jnp.float32),
                        jax.ShapeDtypeStruct(ss.shape, ss.dtype)],
             # the table (operand 3, behind the three scalar-prefetch
             # vectors) IS the second result
@@ -1377,11 +1415,13 @@ def ssm_update(ss, decay, dtx, Bm, Cm, active, layer, interpret=None):
             name="ssm_update", metadata={"kernel": "ssm_update"},
             interpret=interp)(*ops)
 
-    return _interpret_dispatch(
+    y, ss = _interpret_dispatch(
         call, interpret, slot_of, fixed,
         decay.astype(jnp.float32).reshape(N * Hs), ss,
-        dtx.astype(jnp.float32), Bm.astype(jnp.float32)[:, :, None],
+        dtx.astype(jnp.float32).reshape(N, Hs // hr, Pr),
+        Bm.astype(jnp.float32)[:, :, None],
         Cm.astype(jnp.float32)[:, :, None])
+    return y.reshape(N, Hs, P), ss
 
 
 # a grid step of `sparse_decode_attention` stages T tiles of K and T of V

@@ -25,25 +25,28 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
     list it joined, and only a `benchmark` PR may edit that file: behind
     them stand the one reader PR 49 appended, the one PR 50 did, and PR
     51's cell, configuration, three readers and its cell's name in the
-    lists, the one reader PR 53 appended, the three PR 54 did and the one
-    PR 55 did; the rest is as it was."""
-    later = "mimov2flash_reasoning_decode"
-    assert [m["name"] for m in manifest["per_layer"][-10:-1]] == [
+    lists, the one reader PR 53 appended, the three PR 54 did, the one
+    PR 55 did, and PR 56's cell, configuration and two readers; the rest is
+    as it was."""
+    later = ("mimov2flash_reasoning_decode", "granite4hs_decode_saturated")
+    assert [m["name"] for m in manifest["per_layer"][-12:-3]] == [
         "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill",
         "kinds_attention_roofline", "attention_share_of_trip",
         "full_kv_bytes_per_slot", "prefill_ahead_share",
         "tokens_sent_per_s", "client_read_share", "token_delivery_ms_mean"]
-    assert manifest["workloads"][-1]["name"] == later
-    assert manifest["configs"][-1]["name"] == "mimo_v2_flash"
+    assert [w["name"] for w in manifest["workloads"][-2:]] == list(later)
+    assert [c["name"] for c in manifest["configs"][-2:]] == [
+        "mimo_v2_flash", "granite_4_0_h_small"]
 
     def as_it_was(entries):
-        return [dict(m, workloads=[w for w in m["workloads"] if w != later])
+        return [dict(m, workloads=[w for w in m["workloads"]
+                                   if w not in later])
                 if "workloads" in m else m for m in entries]
     _the_cell_as_pr48_left_it(dict(
-        manifest, workloads=manifest["workloads"][:-1],
-        configs=manifest["configs"][:-1],
+        manifest, workloads=manifest["workloads"][:-2],
+        configs=manifest["configs"][:-2],
         end_to_end=as_it_was(manifest["end_to_end"]),
-        per_layer=as_it_was(manifest["per_layer"][:-10])))
+        per_layer=as_it_was(manifest["per_layer"][:-12])))
 
 
 # the instruction of stage 2's Mosaic call as a prefill executable's text

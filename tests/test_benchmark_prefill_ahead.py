@@ -51,31 +51,34 @@ def test_prefill_ahead_share_reader(case, spans, want):
     assert got == (want if want is None else pytest.approx(want, rel=1e-12))
 
 
-def test_prefill_ahead_share_is_declared_last_for_the_six_cells():
+def test_prefill_ahead_share_is_declared_last_for_the_seven_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
-    # (last but for the three readers PR 54 and the one PR 55 appended
-    # behind it)
-    assert manifest["per_layer"][-5] == {
+    # (last but for the three readers PR 54, the one PR 55 and the two
+    # PR 56 appended behind it)
+    assert manifest["per_layer"][-7] == {
         "name": "prefill_ahead_share", "unit": "%", "better": "higher",
         "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
-        # the cells whose admissions hold several prompts; an admission of
-        # OLMoE's, MiMo's or MiniCPM's cell is one prompt
+        # the cells whose admissions hold several prompts (PR 56's, under
+        # Falcon's traffic, the seventh); an admission of OLMoE's, MiMo's
+        # or MiniCPM's cell is one prompt
         "workloads": ["gpt2s_decode_saturated", "gpt2s_decode_deep",
                       "lfm2_decode_saturated", "pangu_decode_saturated",
                       "falconh1_decode_saturated",
-                      "kexaone_decode_mixed_len"]}
+                      "kexaone_decode_mixed_len",
+                      "granite4hs_decode_saturated"]}
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        "prefill_ahead_share.py"))
     # each of them reports what it moves, and the layer is one the manifest
     # already names
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert set(manifest["per_layer"][-5]["workloads"]) < set(e2e["workloads"])
-    assert "scheduler" in {m["layer"] for m in manifest["per_layer"][:-5]}
+    assert set(manifest["per_layer"][-7]["workloads"]) < set(e2e["workloads"])
+    assert "scheduler" in {m["layer"] for m in manifest["per_layer"][:-7]}
 
 
 @pytest.mark.parametrize("cell,listed", [
     ("falconh1_decode_saturated", True), ("gpt2s_decode_deep", True),
+    ("granite4hs_decode_saturated", True),
     ("olmoe_decode_saturated", False), ("resnet50_feed_b256", False)])
 def test_the_harness_finds_the_reader_in_the_cells_that_list_it(cell, listed):
     manifest = bench_run.load_json(bench_run.MANIFEST)

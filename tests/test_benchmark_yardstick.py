@@ -95,13 +95,16 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
                       "minicpmsala_longdoc_mixed",
                       # PR 51's cell: a kind's block counts at ITS rows'
                       # widths (a ring's weighs 2 beside a full table's 1)
-                      "mimov2flash_reasoning_decode"]}
+                      "mimov2flash_reasoning_decode",
+                      # PR 56's cell: its ONE attention layer's rows are
+                      # counted, nine layers of ten keep none
+                      "granite4hs_decode_saturated"]}
     # appended, not inserted: only PR 35's five readers, PR 38's one,
     # PR 39's nine, PR 42's six, PR 44's five, PR 45's one, PR 48's
     # eight, PR 49's one, PR 50's one, PR 51's three, PR 53's one,
-    # PR 54's three and PR 55's one stand behind it
+    # PR 54's three, PR 55's one and PR 56's two stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 46
+        manifest["per_layer"]) - 48
 
 
 # ---------------------------------------------------------------------------
@@ -147,8 +150,8 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # PR 39's nine readers, PR 42's six, PR 44's five, PR 45's one,
     # PR 48's eight, PR 49's one, PR 50's one, PR 51's three, PR 53's
-    # one and PR 54's three stand behind it
-    assert manifest["per_layer"][-40] == {
+    # one, PR 54's three, PR 55's one and PR 56's two stand behind it
+    assert manifest["per_layer"][-42] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -160,7 +163,8 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       # PR 48's cell: a dispatch launched ahead of the
                       # delivery before it, as in every lane
                       "minicpmsala_longdoc_mixed",
-                      "mimov2flash_reasoning_decode"]}
+                      "mimov2flash_reasoning_decode",
+                      "granite4hs_decode_saturated"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-40]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-42]["workloads"] == e2e["workloads"]

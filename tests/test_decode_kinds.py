@@ -203,10 +203,13 @@ def test_a_planted_fault_moves_the_logits(state, artifact, monkeypatch,
     assert worst > 0.02, worst
 
 
-def test_the_shares_add_up_to_the_uncut_layer(state):
-    """The guide's section 4: the parts of a routed layer that all four
-    members' shares of 4 experts give add up to the layer with all 16 held
-    (there is no shared expert to count once)."""
+@pytest.mark.parametrize("count", [4, 8, 16], ids=[
+    "four_members", "two_members", "one_member"])
+def test_the_shares_add_up_to_the_uncut_layer(state, count):
+    """The guide's section 4: the parts of a routed layer that the members'
+    shares of `count` experts give (four members of 4, two of 8, one of
+    all 16) add up to the layer with all 16 held (there is no shared expert
+    to count once)."""
     whole = dict(META, experts_held=[0, 16])
     i = 2                                       # a routed window layer
     w = reference.layer_weights(whole, SEED, i)
@@ -215,15 +218,15 @@ def test_the_shares_add_up_to_the_uncut_layer(state):
         g = reference._rms(x, w["ln2_g"], META["norm_eps"])
         uncut, gap = reference.ffn_parts(g, w, whole)
         parts = []
-        for first in range(0, 16, 4):
-            mine = dict(w, **{n: w[n][first:first + 4]
+        for first in range(0, 16, count):
+            mine = dict(w, **{n: w[n][first:first + count]
                               for n in ("w_gate", "w_up", "w_down")})
             part, gap_i = reference.ffn_parts(
-                g, mine, dict(META, experts_held=[first, 4]))
+                g, mine, dict(META, experts_held=[first, count]))
             parts.append(part)
             np.testing.assert_array_equal(gap_i, gap)
     np.testing.assert_allclose(sum(parts), uncut, rtol=1e-5, atol=1e-6)
-    assert float(jnp.max(jnp.abs(parts[1]))) > 1e-3
+    assert float(jnp.max(jnp.abs(parts[-1]))) > 1e-3
     # ... and the program's member holds the run the meta names
     assert reference.tensor_shapes(META)["l2_w_gate"] == (4, 48, 32)
     held = np.asarray(reference.draw_tensor(

@@ -513,12 +513,16 @@ def test_the_router_selects_by_bias_weighs_without_it_and_scales(opened):
     assert np.abs(np.asarray(plain) - np.asarray(routed)).max() > 100 * TOL
 
 
-def test_the_members_shares_add_up_to_the_uncut_layer(opened):
+@pytest.mark.parametrize("count", [4, 8, 16], ids=[
+    "four_members", "two_members", "one_member"])
+def test_the_members_shares_add_up_to_the_uncut_layer(opened, count):
     """THE SHARE TEST (the `model-configs` guide, section 4): the routed
-    parts of the 4 members that hold 4 of the 16 experts each, with what
-    every member computes alike (the shared expert) counted ONCE, add up to
-    the uncut layer of the reference, with this router (bias-selected,
-    scaled 2.5); and the program's member computes its own part."""
+    parts of the members that hold `count` of the 16 experts each (four of
+    4; two of 8, a half, where `moe_ffn`'s held branch takes every pair's
+    row; one of all 16), with what every member computes alike (the shared
+    expert) counted ONCE, add up to the uncut layer of the reference, with
+    this router (bias-selected, scaled 2.5); and the program's member
+    computes its own part."""
     pred, state = opened
     rng = np.random.RandomState(2)
     g = jnp.asarray(rng.randn(11, 24), jnp.float32)
@@ -526,10 +530,11 @@ def test_the_members_shares_add_up_to_the_uncut_layer(opened):
     model = dict(pred.meta)
     whole_routed, whole_shared, _ = reference.ffn_parts(g, w, model)
     total = np.zeros_like(np.asarray(whole_routed))
-    for first in range(0, 16, 4):
-        held = dict(model, experts_held=[first, 4])
-        part = {n: (v[first:first + 4] if n in ("w_gate", "w_up", "w_down")
-                    else v) for n, v in w.items()}
+    for first in range(0, 16, count):
+        held = dict(model, experts_held=[first, count])
+        part = {n: (v[first:first + count]
+                    if n in ("w_gate", "w_up", "w_down") else v)
+                for n, v in w.items()}
         routed, shared, _ = reference.ffn_parts(g, part, held)
         np.testing.assert_allclose(np.asarray(shared),
                                    np.asarray(whole_shared), rtol=0,
@@ -537,7 +542,7 @@ def test_the_members_shares_add_up_to_the_uncut_layer(opened):
         got, _ = dec.moe_ffn(g, part["router"], part["w_gate"], part["w_up"],
                              part["w_down"], 4, True,
                              expert_bias=part["expert_bias"], scaling=2.5,
-                             held=(first, 4))
+                             held=(first, count))
         np.testing.assert_allclose(np.asarray(got), np.asarray(routed),
                                    rtol=0, atol=TOL)
         total += np.asarray(routed)

@@ -317,7 +317,7 @@ def test_a_mesh_refuses_by_name(artifact):
 
 @pytest.mark.parametrize("key,value,match", [
     ("layer_types", ["attention+ssm", "conv"], "one width"),
-    ("layer_types", ["attention+ssm", "ssm"], "layer_types"),
+    ("layer_types", ["attention+ssm", "mamba"], "layer_types"),
     ("ssm_heads", 0, "ssm_heads"),
     ("ssm_state", 0, "ssm_state"),
     ("ssm_groups", 3, "ssm_groups"),

@@ -438,6 +438,61 @@ def test_ssm_update_against_the_reference(active, heads, block,
         assert abs(got[1, n, h, p, k] - want) < 1e-5
 
 
+@pytest.mark.parametrize("heads,P,Ns,groups,block", [
+    (32, 64, 128, 1, 32), (16, 64, 128, 2, 16), (8, 32, 128, 1, 8),
+    (16, 8, 16, 2, 16)], ids=["granite", "two_groups", "four_a_row",
+                              "sixteen_a_row"])
+@pytest.mark.parametrize("active", ["idle_ahead_and_behind", "none",
+                                    "first_only"])
+def test_ssm_update_with_heads_side_by_side_in_a_lane_row(
+        active, heads, P, Ns, groups, block, monkeypatch):
+    """A head of 64 features (Granite-4.0-H: two heads a 128-lane row of dt
+    x and y; 32, 16 and 8 features ride the same body, four, eight and
+    sixteen a row) in interpret mode against `ssm_update_reference`: the
+    state bit for bit under one jit each (both compute decay * S + dt x
+    (outer) B in fp32, element by element), the read-out to rounding, a
+    slot that does not run keeps its state and reads y = 0, the other layer
+    untouched, the table taken in place (aliased)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    run = np.asarray(_SSM_ACTIVE[active], bool)
+    assert pk.ssm_update_heads_per_row(heads, P) == 128 // P
+    assert pk.ssm_update_block_heads(heads, P, Ns, mosaic=False) == block
+    if active == "none" and heads == 32:
+        # two blocks a slot: the grid walks a running slot's blocks
+        monkeypatch.setattr(pk, "_SSM_BLOCK_BYTES", 4 * 16 * P * Ns)
+        assert pk.ssm_update_block_heads(heads, P, Ns, mosaic=False) == 16
+    ss, decay, dtx, Bm, Cm = _ssm_operands(2, len(run), heads, P, Ns,
+                                           groups)
+    kernel = jax.jit(lambda t, *o: pk.ssm_update(t, *o, 1),
+                     donate_argnums=(0,))
+    oracle = jax.jit(lambda t, *o: pk.ssm_update_reference(t, *o, 1))
+    ops = (decay, dtx, Bm, Cm, jnp.asarray(run))
+    y0, table0 = oracle(ss, *ops)
+    was = np.asarray(ss)
+    held = ss + 0.0
+    y, table = kernel(held, *ops)
+    assert held.is_deleted()                    # the table, in place
+    assert "input_output_aliases" in str(jax.make_jaxpr(
+        lambda t, *o: pk.ssm_update(t, *o, 1))(ss, *ops))
+    assert y.shape == (len(run), heads, P) and table.shape == was.shape
+    np.testing.assert_array_equal(np.asarray(table), np.asarray(table0))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y0), rtol=1e-5,
+                               atol=1e-5)
+    got = np.asarray(table)
+    assert np.array_equal(got[0], was[0])              # the other layer
+    assert np.array_equal(got[1][~run], was[1][~run])  # slots that sat out
+    assert not np.asarray(y)[~run].any()
+    if run.any():
+        n = int(np.flatnonzero(run)[0])
+        h, p, k = heads - 1, P - 3, 5          # the LAST head of a row
+        g = h // (heads // groups)
+        want = (float(decay[n, h]) * was[1, n, h, p, k]
+                + float(dtx[n, h, p]) * float(Bm[n, g, k]))
+        assert abs(got[1, n, h, p, k] - want) < 1e-5
+
+
 def test_ssm_update_block_follows_the_shape_and_refuses_by_name():
     """The block of heads is the shape's (what fits `_SSM_BLOCK_BYTES` and
     divides the heads), not a flag: Falcon-H1's 32 heads of [128, 256]
@@ -452,6 +507,15 @@ def test_ssm_update_block_follows_the_shape_and_refuses_by_name():
     assert pk.ssm_update_block_heads(8, 256, 512, mosaic=True) is None
     assert pk.ssm_update_block_heads(12, 128, 256, mosaic=True) is None
     assert pk.ssm_update_block_heads(8, 64, 128, mosaic=True) is None
+    # heads of 64 go two a lane row: whole groups of 8 rows are 16 heads,
+    # Granite-4.0-H's 128 heads of [64, 128] go 32 a step (1 MiB)
+    assert pk.ssm_update_block_heads(128, 64, 128, mosaic=True) == 32
+    assert pk.ssm_update_block_heads(64, 32, 128, mosaic=True) == 64
+    assert pk.ssm_update_block_heads(128, 64, 64, mosaic=True) is None
+    assert pk.ssm_update_block_heads(128, 48, 128, mosaic=True) is None
+    assert pk.ssm_update_heads_per_row(128, 64) == 2
+    assert pk.ssm_update_heads_per_row(32, 128) == 1
+    assert pk.ssm_update_heads_per_row(5, 64) == 1     # no whole rows
     assert pk.ssm_update_block_heads(4, 8, 16, mosaic=False) == 4
     assert pk.ssm_update_block_heads(3, 1024, 1024, mosaic=False) == 1
     ss, decay, dtx, Bm, Cm = _ssm_operands(1, 2, 4, 8, 16, 2)

@@ -46,7 +46,9 @@ DECODE_CELLS = ["gpt2s_decode_saturated", "gpt2s_decode_deep",
                 # lane's spans are the same whatever the stack's layers
                 "minicpmsala_longdoc_mixed",
                 # ... and PR 51's every list that holds the eight
-                "mimov2flash_reasoning_decode"]
+                "mimov2flash_reasoning_decode",
+                # ... and PR 56's every list that holds the nine
+                "granite4hs_decode_saturated"]
 
 
 def reader(name):
@@ -263,9 +265,9 @@ def test_token_out_frames_per_pass_is_declared_for_the_decode_cells():
                  "layer": "serving front", "moves": "tokens_per_s",
                  "workloads": DECODE_CELLS}
     # (last but for the eight readers PR 48, the one PR 49, the one
-    # PR 50, the three PR 51, the one PR 53, the three PR 54 and the one
-    # PR 55 appended behind it)
-    assert manifest["per_layer"][-19] is m
+    # PR 50, the three PR 51, the one PR 53, the three PR 54, the one
+    # PR 55 and the two PR 56 appended behind it)
+    assert manifest["per_layer"][-21] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
 
@@ -315,8 +317,9 @@ def test_sparse_tiles_per_grid_step_is_declared_for_its_cell_alone():
                  "layer": "kernels", "moves": "tokens_per_s",
                  "workloads": ["minicpmsala_longdoc_mixed"]}
     # (last but for the one reader PR 50, the three PR 51, the one
-    # PR 53, the three PR 54 and the one PR 55 appended behind it)
-    assert manifest["per_layer"][-10] is m
+    # PR 53, the three PR 54, the one PR 55 and the two PR 56 appended
+    # behind it)
+    assert manifest["per_layer"][-12] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
     # the one cell whose stack has sparse layers
@@ -389,17 +392,17 @@ def test_sparse_prefill_kernel_ms_per_prefill_reads_the_call_by_its_name(
 def test_sparse_prefill_kernel_ms_per_prefill_is_declared_last():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # (last but for the three readers PR 51, the one PR 53, the three
-    # PR 54 and the one PR 55 appended behind it)
-    assert manifest["per_layer"][-9] == {
+    # PR 54, the one PR 55 and the two PR 56 appended behind it)
+    assert manifest["per_layer"][-11] == {
         "name": "sparse_prefill_kernel_ms_per_prefill", "unit": "ms",
         "better": "lower", "source": "device_trace", "layer": "kernels",
         "moves": "tokens_per_s", "workloads": ["minicpmsala_longdoc_mixed"]}
-    assert len(manifest["per_layer"]) == 72
+    assert len(manifest["per_layer"]) == 74
     assert all(os.path.exists(os.path.join(
         bench_run.LAYERS_DIR, m["name"] + ".py"))
         for m in manifest["per_layer"])
     # its spans and its kernel are those of the reader it stands beside
-    assert manifest["per_layer"][-9]["workloads"] == [
+    assert manifest["per_layer"][-11]["workloads"] == [
         m for m in manifest["per_layer"]
         if m["name"] == "sparse_prefill_ms_per_prefill"][0]["workloads"]
 
@@ -442,8 +445,9 @@ def test_the_nine_are_declared_last_for_the_five_decode_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # (last but for the six readers PR 42, the five PR 44, the one PR 45,
     # the eight PR 48, the one PR 49, the one PR 50, the three PR 51, the
-    # one PR 53, the three PR 54 and the one PR 55 appended behind them)
-    last = manifest["per_layer"][-39:-30]
+    # one PR 53, the three PR 54, the one PR 55 and the two PR 56 appended
+    # behind them)
+    last = manifest["per_layer"][-41:-32]
     assert [m["name"] for m in last] == list(NINE)
     for m in last:
         assert m["workloads"] == DECODE_CELLS, m["name"]

@@ -246,8 +246,9 @@ def test_routed_ffn_is_dropless_and_exact(tokens, one_expert, norm):
     assert int(per_expert.sum()) == tokens * k          # none dropped
     if one_expert:
         assert per_expert[5] == tokens
+    # (the third: the pairs that stayed here, all of them without `held`)
     assert facts.tolist() == [int((per_expert > 0).sum()),
-                              int(per_expert.max())]
+                              int(per_expert.max()), tokens * k]
 
 
 # (d) the routing counters against counts made by hand ---------------------
@@ -290,10 +291,11 @@ def test_routing_counters_ride_the_fetch(opened):
     assert by_phase["step"]["moe_experts_touched"] == k * L
     assert by_phase["step"]["moe_tokens_per_expert_max"] == 1
     # everything a dispatch returns came in ONE int32 vector: the step
-    # window's token block, 3 counts, the trips, 2 facts per layer
+    # window's token block, 3 counts, the trips, 3 facts per layer
     from paddle_tpu.inference.decode import STEP_WINDOW
     assert by_phase["step"]["d2h_bytes"] \
-        == 4 * (3 * STEP_WINDOW + 3 + 1 + 2 * L)
+        == 4 * (3 * STEP_WINDOW + 3 + 1 + 3 * L)
+    assert by_phase["step"]["moe_pairs_held"] == k * L
     assert by_phase["step"]["trips"] == 1
 
 

@@ -664,6 +664,125 @@ def test_state_space_step_updates_its_three_kinds_of_state_in_place(
     assert text.count(" while(") == 1
 
 
+# benchmark/configs/granite_4_0_h_small.json at its 96 slots: the
+# scanned-state table of nine ssm layers, 128 heads of [64, 128] fp32 in one
+# group, 3.62 GB.  A head's dt x and y are HALF a lane row: two heads a row
+GRANITE_TABLE = (9, 96, 128, 64, 128)
+
+
+def _mosaic_module(call):
+    """A Mosaic call's payload as MLIR text, without source locations."""
+    import base64
+    import json
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib.mlir import ir
+    body = json.loads(re.search(r"backend_config=(.*)$", call).group(1))[
+        "custom_call_config"]["body"]
+    ctx = jmlir.make_ir_context()
+    with ctx:
+        ctx.allow_unregistered_dialects = True
+        return ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+
+
+@pytest.mark.parametrize("table,groups,block", [
+    (GRANITE_TABLE, 1, 32), (SSM_TABLE, SSM_GROUPS, 8)],
+    ids=["heads_of_64", "heads_of_128"])
+def test_ssm_update_lowers_to_one_mosaic_call_at_both_head_sizes(
+        one_chip, table, groups, block):
+    """`pk.ssm_update` at a head of 64 features (128 x 64 x 128, the shape
+    `ssm_update_block_heads` answered None for: the three-pass fallback) is
+    ONE Mosaic call, the table aliased, nothing table- or layer-sized made
+    around it; a head of 128 (32 x 128 x 256) lowers as before: one head a
+    lane row, blocks of 8, no packed row anywhere in its module."""
+    L, N, Hs, P, Ns = table
+    assert pk.ssm_update_block_heads(Hs, P, Ns, mosaic=True) == block
+    assert pk.ssm_update_heads_per_row(Hs, P) == 128 // min(P, 128)
+    specs = [(table, "float32"), ((N, Hs), "float32"),
+             ((N, Hs, P), "float32"), ((N, groups, Ns), "float32"),
+             ((N, groups, Ns), "float32"), ((N,), "bool")]
+    args = [jax.ShapeDtypeStruct(s, np.dtype(dt), sharding=one_chip)
+            for s, dt in specs]
+    with pk.mosaic_lowering():
+        compiled = jax.jit(
+            lambda ss, *rest: pk.ssm_update(ss, *rest, layer=L - 1),
+            donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and "ssm_update" in calls[0]
+    assert "kernel_metadata={}" not in calls[0]
+    size = 4 * int(np.prod(table))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= size, ma.alias_size_in_bytes
+    assert ma.temp_size_in_bytes < size / 1000, ma.temp_size_in_bytes
+    big = re.compile(r"f32\[(%d,)?%d,%d,%d,%d\]" % table)
+    made = [m.group(0) for m in re.finditer(
+        r"(%[\w.\-]+) = (\S+) ([\w\-]+)\(", text)
+        if big.search(m.group(2)) and m.group(3) not in (
+            "custom-call", "parameter", "get-tuple-element")]
+    assert not made, made[:6]
+    module = _mosaic_module(calls[0])
+    # the dt x block a grid step stages: [rows of the block, 128 lanes]
+    rows = "memref<1x%dx128xf32" % (block * P // 128)
+    assert rows in module, module[:400]
+    # a head's state is [64, 128] there and [128, 256] here
+    assert ("vector<64x128xf32>" in module) == (P == 64)
+
+
+def test_recurrent_moe_step_holds_nine_ssm_updates_and_no_fallback(
+        one_chip):
+    """The step window of `granite_4_0_h_small` at its 96 slots, bfloat16
+    weights at rest: the attention layer's K/V rows, nine conv windows and
+    nine scanned states are donated and aliased; the step holds ONE Mosaic
+    call without metadata (the attention layer's decode kernel) and NINE
+    named `ssm_update`, one a Mamba layer, and no instruction but those
+    makes a table- or layer-sized scanned state (the three-pass fallback
+    made a reduce over it and select-and-update fusions)."""
+    import json
+    import os
+    from paddle_tpu.inference import decode as dec
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "granite_4_0_h_small.json")) as f:
+        cfg = json.load(f)
+    meta, slots = cfg["model"], cfg["deployment"]["decode_slots"]
+    device = list(one_chip.device_set)[0]
+    pred, state = described_predictor(meta, device)
+    state = {n: jax.ShapeDtypeStruct(
+        s.shape, jnp.bfloat16 if dec._bf16_at_rest(n, s) else np.float32,
+        sharding=s.sharding) for n, s in state.items()}
+    table = (9, slots) + GRANITE_TABLE[2:]
+    assert pred.ssm_state_shape(slots) == table
+    assert pred.table_shape(slots) == (1, slots, 1024, 1024)
+    compiled = compile_phase(pred, state, pred._step_math(),
+                             pred._step_specs(slots), tables=range(4))
+    text = compiled.as_text()
+    calls = _custom_calls(text)
+    assert len([c for c in calls if "kernel_metadata={}" in c
+                and "ragged" not in c.split(" = ")[0]]) >= 1
+    named = [c for c in calls if "ssm_update" in c
+             and "kernel_metadata={}" not in c]
+    assert len(named) == 9
+    from benchmark import moe_trace
+    scoped = moe_trace.scope_instruction_names(text, "ssm_update")
+    assert {c.split(" = ")[0].strip().lstrip("%") for c in named} <= scoped
+    assert moe_trace.scope_instruction_names(text, "moe_ffn", "ragged-dot")
+    ma = compiled.memory_analysis()
+    held = sum(4 * int(np.prod(s)) for s in (
+        pred.table_shape(slots), pred.table_shape(slots),
+        pred.conv_state_shape(slots), table))
+    assert ma.alias_size_in_bytes >= held, (ma.alias_size_in_bytes, held)
+    assert ma.temp_size_in_bytes < 0.2e9, ma.temp_size_in_bytes
+    big = re.compile(r"f32\[(%d,)?%d,%d,%d,%d\]" % table)
+    made = [(m.group(1), m.group(3)) for m in re.finditer(
+        r"(%[\w.\-]+) = (\S+) ([\w\-]+)\(", text)
+        if big.search(m.group(2).split("{")[0]) and m.group(3) in (
+            "select", "concatenate", "copy", "pad", "transpose", "fusion",
+            "reduce", "dynamic-update-slice", "dynamic-slice")]
+    assert not made, made[:8]
+    assert text.count(" while(") == 1
+
+
 def test_sparse_linear_step_and_chunked_prefill_compile_for_the_chip(
         one_chip):
     """The step window of `minicpm_sala_9b` at the cell's 24 slots and a

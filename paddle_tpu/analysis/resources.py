@@ -54,7 +54,7 @@ from .verifier import AnalysisPass
 __all__ = [
     "ResourceReport", "ResourceFitError", "analyze_program",
     "analyze_artifact", "check_fit", "device_memory_bytes",
-    "device_peaks", "RESOURCE_PASSES",
+    "device_peaks", "rows_a_weight_read", "RESOURCE_PASSES",
 ]
 
 
@@ -101,6 +101,17 @@ def device_peaks(device=None):
     raise ValueError(
         "no peaks known for device kind %r — add its published numbers "
         "(with their source) to analysis/resources.py" % kind)
+
+
+def rows_a_weight_read(kind="v5e"):
+    """The rows of a bfloat16 matmul whose product takes the TPU `kind` as
+    long as the read of the matrix they multiply: peak FLOP/s over HBM
+    bytes/s (2 FLOPs a multiply-add, 2 bytes a weight), 240 on a v5e.  What
+    a kernel's shape is chosen by at trace time, where the program is
+    traced for a chip this process may not hold
+    (`inference.decode.held_cap`)."""
+    flops, bw = next(row[1:3] for row in _TPU_PEAKS if row[0] == kind)
+    return flops / bw
 
 
 def device_memory_bytes(device=None):

@@ -143,6 +143,7 @@ import contextlib
 import functools
 import hashlib
 import json
+import math
 import os
 import threading
 import time
@@ -1122,8 +1123,8 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     """Dropless, exact top-k routed SwiGLU experts: h [T, D], router
     [D, E], w_gate / w_up [E, D, F], w_down [E, F, D] ->
     (sum over each token's k experts of p_e * ((silu(h @ w_gate[e]) *
-    (h @ w_up[e])) @ w_down[e]) [T, D], facts [3] i32).  No capacity: every
-    (token, expert) pair is computed, whatever the routing.
+    (h @ w_up[e])) @ w_down[e]) [T, D], facts [3] i32, [4] with `held`).  No
+    capacity: every (token, expert) pair is computed, whatever the routing.
 
     ONE form for both regimes: the T * k pairs are sorted by expert and
     the three expert matmuls run as grouped matmuls over the sorted rows
@@ -1150,22 +1151,33 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
     hold the experts first .. first + count - 1 of the router's E and no
     others.  The router and its top-k are those of all E; a pair routed to
     an expert held elsewhere leaves BEFORE the sort (it joins no group of
-    the grouped matmuls, which run over the head of the sorted pairs,
-    where those that stay lie) and adds nothing to its token's sum: the
-    result is this member's PART of the layer's.
+    the grouped matmuls) and adds nothing to its token's sum: the result
+    is this member's PART of the layer's.  The device's work follows the
+    rows that STAY: the grouped matmuls run over the first `held_cap`
+    sorted rows, where the pairs that stay lie, and each pair reads its row
+    of that result; only a call whose routing crowds more pairs than that
+    onto this member runs them over every pair's row (a `cond`; the fourth
+    fact says which branch ran; none is built, and every call runs every
+    row, where `held_cap` finds that fewer would not pay), with the same
+    numbers: bit for bit where a
+    grouped matmul's rows do not depend on how many it is given (the CPU),
+    to its tiles' rounding on the TPU.
 
     facts = (experts HELD HERE that received a token, most tokens one of
     them received, the (token, expert) pairs that STAYED here: all T * k
     without `held`), counted over the tokens `live` [T] marks (all if None):
-    a dead slot's or a pad position's row is computed but not counted.
+    a dead slot's or a pad position's row is computed but not counted; with
+    `held` a fourth, 1 where the call ran full size (more pairs stayed,
+    counted or not, than `held_cap` rows).
     A list given as `picks` receives the chosen experts [T, k] i32 (at
     trace time): what a comparison with a reference needs to tell the
     router's near-ties from a fault (`_step_logits`).
 
     Traced under `_prompts_share_experts` and a `vmap` over prompts (a
-    group's prefill), the router, the weights and the facts stay a
-    prompt's own and the sort and the grouped matmuls are the GROUP's: one
-    `ragged_dot` a projection over all the prompts' pairs."""
+    group's prefill), the router, the weights and the first three facts
+    stay a prompt's own and the sort, the grouped matmuls, the cap and the
+    fourth fact are the GROUP's: one `ragged_dot` a projection over all the
+    prompts' pairs."""
     import jax
     import jax.numpy as jnp
     T, E, k = h.shape[0], router.shape[1], int(k)
@@ -1209,9 +1221,10 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
         facts = jnp.stack([jnp.sum(counted > 0, dtype=jnp.int32),
                            jnp.max(counted), jnp.sum(counted)])
 
-        def through_experts(h, flat, sizes, w_gate, w_up, w_down):
-            """Each pair's row through its expert -> [T * k, D], token t's
-            j-th pair at t * k + j."""
+        def sorted_pairs(h, flat, sizes, w_gate, w_up, w_down):
+            """(the pairs' order by expert; experts(m): the sorted pairs'
+            rows, the first m of them or all if None, through their
+            experts -> [m, D])."""
             order = jnp.argsort(flat)       # stable: pairs by expert
 
             def grouped(x, w):
@@ -1219,66 +1232,142 @@ def moe_ffn(h, router, w_gate, w_up, w_down, k, norm_topk_prob=False,
                     jax.lax.ragged_dot, group_sizes=sizes))
 
             def experts(m=None):
-                # the sorted pairs' rows (the first m of them; all if None)
-                # through their experts
                 at = order // k
                 rows = h[at if m is None else at[:m]]           # [m, D]
                 act = jax.nn.silu(grouped(rows, w_gate)) \
                     * grouped(rows, w_up)
                 return grouped(act, w_down)                     # [m, D]
-            if held is None:
-                out = experts()                                 # [T*k, D]
-            else:
-                # the pairs that stay sort FIRST, and they are about count /
-                # E of the T * k: the grouped matmuls run over the first
-                # `cap` sorted rows, four times that share (a grouped-matmul
-                # kernel computes whole row tiles, so a dead row behind the
-                # groups is not free), and over all T * k only in a dispatch
-                # whose routing crowds more than `cap` pairs onto this
-                # member: dropless and exact either way
-                # (a member that holds a quarter of the experts or more, 18
-                # of 72 say, has cap == pairs: no `cond` is built and the
-                # grouped matmuls run over every pair's row, three quarters
-                # of them dead rows behind the groups; ROADMAP S6)
-                stay, pairs = jnp.sum(sizes), flat.shape[0]
-                cap = min(pairs,
-                          max(64, 4 * -(-pairs * E // router.shape[1])))
-                out = experts() if cap == pairs else jax.lax.cond(
-                    stay <= cap,
-                    lambda: jnp.pad(experts(cap),
-                                    ((0, pairs - cap), (0, 0))),
-                    experts)
-                # a row behind the last group is no expert's: whatever the
-                # grouped matmul left there, it is nothing
-                out = jnp.where((jnp.arange(pairs) < stay)[:, None], out,
-                                0.0)
+            return order, experts
+
+        def through_experts(h, flat, sizes, w_gate, w_up, w_down):
+            """Each pair's row through its expert -> [T * k, D], token t's
+            j-th pair at t * k + j."""
+            order, experts = sorted_pairs(h, flat, sizes, w_gate, w_up,
+                                          w_down)
             # back to token order by a gather (the inverse permutation)
-            return out[jnp.argsort(order)]
+            return experts()[jnp.argsort(order)]
 
-        if getattr(_TRACE, "prompts_share_experts", False):
-            # under the `vmap` over a group's prompts
-            # (`GenerativePredictor._prefill_group_math`) the prompts' pairs
-            # are sorted TOGETHER into one grouped matmul: an expert's
-            # weights are read once a group, and a `ragged_dot` a prompt,
-            # which is what the primitive's own rule leaves, reads them once
-            # a prompt
-            through_experts = jax.custom_batching.custom_vmap(through_experts)
+        def through_held(h, flat, sizes, w, w_gate, w_up, w_down):
+            """This member's part of each token's sum -> ([T, D], whether
+            the call ran full size).  The pairs that stay sort FIRST, and
+            the grouped matmuls run over the first `cap` sorted rows
+            (`held_cap`: twice the member's expected share, or every row
+            where fewer would not pay); token t's j-th pair then reads its
+            row of that [cap, D] result by its place in the sorted order,
+            the one [pairs, D] gather of this branch.  A call whose routing
+            crowds more than `cap` pairs onto this member takes the
+            `cond`'s other branch, the grouped matmuls over every pair's
+            row: dropless and exact either way, and a live row's number is
+            the same in both."""
+            order, experts = sorted_pairs(h, flat, sizes, w_gate, w_up,
+                                          w_down)
+            stay, pairs = jnp.sum(sizes), flat.shape[0]
+            cap = held_cap(pairs, E, router.shape[1])
+            pos = jnp.argsort(order)        # a pair's place in the order
+            if cap == pairs:
+                out, full_size = experts()[pos], jnp.int32(0)
+            else:
+                fits = stay <= cap
+                out = jax.lax.cond(
+                    fits, lambda: experts(cap)[jnp.minimum(pos, cap - 1)],
+                    lambda: experts()[pos])
+                full_size = 1 - fits.astype(jnp.int32)
+            # (behind the `cond`, not in its branches: there Granite's
+            # cell read 3.7% more tokens/s and MiMo's prefill held 27 / 73
+            # MB more temporaries than its bound, PERF.md section 6, PR 57)
+            # a row behind the last group is no expert's: whatever the
+            # grouped matmul left there, it is nothing ...
+            out = jnp.where((pos < stay)[:, None], out, 0.0)
+            # ... then a fixed-order sum over each token's k experts
+            return jnp.sum(out.reshape(w.shape + (-1,)) * w[:, :, None],
+                           axis=1), full_size
 
-            @through_experts.def_vmap
-            def _(rows, batched, h, flat, sizes, *weights):
-                if not all(batched[:3]) or any(batched[3:]):
+        def for_a_group(fn, own):
+            """`fn(h, flat, sizes, ...)` whose first `own` arguments are a
+            prompt's and whose others are the experts' weights: under the
+            `vmap` over a group's prompts
+            (`GenerativePredictor._prefill_group_math`) the prompts' pairs
+            are sorted TOGETHER into one grouped matmul: an expert's
+            weights are read once a group, and a `ragged_dot` a prompt,
+            which is what the primitive's own rule leaves, reads them once
+            a prompt."""
+            if not getattr(_TRACE, "prompts_share_experts", False):
+                return fn
+            fn = jax.custom_batching.custom_vmap(fn)
+
+            @fn.def_vmap
+            def _(n, batched, h, flat, sizes, *rest):
+                if not all(batched[:own]) or any(batched[own:]):
                     raise NotImplementedError(
                         "moe_ffn under a vmap over the tokens alone, the "
                         "experts' weights shared: got %r" % (batched,))
-                out = through_experts(
-                    h.reshape(-1, h.shape[-1]), flat.reshape(-1),
-                    jnp.sum(sizes, axis=0), *weights)
-                return out.reshape(flat.shape + out.shape[-1:]), True
+                # (h, flat and what else is a prompt's: the prompts' rows
+                # in one run; the groups' sizes add up)
+                h, flat, *more = [t.reshape((-1,) + t.shape[2:])
+                                  for t in (h, flat) + rest[:own - 3]]
+                out = fn(h, flat, jnp.sum(sizes, axis=0), *more,
+                         *rest[own - 3:])
+                # the rows go back to their prompts; a fact of the call is
+                # every prompt's
+                return jax.tree.map(
+                    lambda t: t.reshape((n, -1) + t.shape[1:])
+                    if t.ndim else jnp.broadcast_to(t, (n,)), out), \
+                    jax.tree.map(lambda t: True, out)
+            return fn
 
-        # ... then a fixed-order sum over each token's k experts
-        out = through_experts(h, flat, sizes, w_gate, w_up,
-                              w_down).reshape(T, k, -1)
-        return jnp.sum(out * w[:, :, None], axis=1), facts
+        if held is None:
+            # ... then a fixed-order sum over each token's k experts
+            out = for_a_group(through_experts, 3)(
+                h, flat, sizes, w_gate, w_up, w_down).reshape(T, k, -1)
+            return jnp.sum(out * w[:, :, None], axis=1), facts
+        out, full_size = for_a_group(through_held, 4)(
+            h, flat, sizes, w, w_gate, w_up, w_down)
+        return out, jnp.concatenate([facts, full_size[None]])
+
+
+def _row_tile(rows):
+    """The row tile the TPU's compiler gives a grouped matmul of `rows`
+    rows: the largest power of two, 512 at most, that divides them
+    (`ragged_dot_tiling` in the compiled text; pinned by
+    tests/test_tpu_compile.py)."""
+    return min(512, rows & -rows)
+
+
+def held_cap(pairs, count, of):
+    """The sorted rows `moe_ffn`'s grouped matmuls run over where a member
+    holds `count` of a router's `of` experts, of `pairs` (token, expert)
+    pairs: TWICE the member's expected share m = pairs * count / of (m + 6
+    sqrt(m) where that is more: a uniform router keeps within sqrt(m) of m,
+    but a prompt's positions crowd, and of the prefills of Granite's and
+    K-EXAONE's cells a quarter to a half kept more than 1.25 m, one in
+    sixty more than 1.5 m, none of 1,270 more than 2 m), in whole row
+    tiles; or `pairs`, and then no `cond` is built, where fewer rows would
+    not pay.  A call that keeps more than the cap takes the full-size
+    branch (`moe_cap_overflows` counts them), so a margin too small costs
+    time and never a result.
+
+    What fewer rows buy is their ROW TILE (`_row_tile`): the kernel visits
+    every (row tile, expert) pair that holds a live row, about m / tile +
+    count of them, and each visit reads the expert's matrix and multiplies
+    the whole tile; the tiles behind the live rows are nearly free.  Few
+    rows a tile re-read the weights, many multiply dead rows (a tile of 512
+    costs three weight reads, `resources.rows_a_weight_read`).  So the cap
+    is an ODD number of tiles of 64, 128 or 256 rows, whichever makes the
+    visits cheapest, and it stands only where they are cheaper than at the
+    tile of all `pairs`: Granite's decode trip (960 pairs, tiles of 64
+    either way) keeps every row and its parent's step (my chip runs, PR 57:
+    PERF.md section 6)."""
+    from paddle_tpu.analysis.resources import rows_a_weight_read
+    m = -(-pairs * count // of)
+    rows = math.ceil(max(2.0 * m, m + 6.0 * math.sqrt(m)))
+    a_read = rows_a_weight_read()
+
+    def visits(tile):
+        return (m / tile + count) * (a_read + tile)
+    tile = min((64, 128, 256), key=visits)
+    cap = tile * (-(-rows // tile) | 1)
+    return cap if cap < pairs and visits(tile) < visits(_row_tile(pairs)) \
+        else pairs
 
 
 def prefill_group(width, waiting):
@@ -1885,6 +1974,13 @@ class GenerativePredictor:
         past the leading dense ones).  The step and the prefill of such
         an artifact return their routing facts behind their tokens."""
         return [ffn for _, ffn in self.layer_kinds].count("moe_swiglu")
+
+    @property
+    def _routing_facts(self):
+        """How many facts a routed layer hands out (`moe_ffn`): a member
+        that holds some of the experts has a fourth, whether the call ran
+        its grouped matmuls full size."""
+        return 4 if self._block_meta["experts_held"] else 3
 
     @property
     def _step_picks(self):
@@ -3369,7 +3465,9 @@ class GenerativePredictor:
         the trips run, and for a routed-expert artifact each layer's
         (experts touched SUMMED over the trips, most tokens on one
         expert, the LARGEST over the trips, the pairs that stayed on this
-        member SUMMED over the trips), which is what
+        member SUMMED over the trips; of a member that holds some of the
+        experts also the trips that ran the layer's grouped matmuls full
+        size), which is what
         `_pack_routing` carries for a prefill.  Its arguments are those
         of `_step_specs`, flat."""
         import jax
@@ -3404,8 +3502,9 @@ class GenerativePredictor:
                     f = jnp.stack([r for r in f if r is not None])
                     facts = jnp.stack(
                         [facts[:, 0] + f[:, 0],
-                         jnp.maximum(facts[:, 1], f[:, 1]),
-                         facts[:, 2] + f[:, 2]], axis=1)
+                         jnp.maximum(facts[:, 1], f[:, 1])]
+                        + [facts[:, c] + f[:, c]
+                           for c in range(2, facts.shape[1])], axis=1)
                 last = jnp.where(alive, tok, last)
                 emitted = emitted + alive.astype(jnp.int32)
                 alive = (alive & (tok != jnp.int32(eos))
@@ -3415,7 +3514,7 @@ class GenerativePredictor:
 
             carry = (jnp.int32(0), tables, last_tokens,
                      jnp.zeros((N, W), jnp.int32),
-                     jnp.zeros((routed, 3), jnp.int32),
+                     jnp.zeros((routed, self._routing_facts), jnp.int32),
                      alive, jnp.zeros((N,), jnp.int32))
             i, tables, _last, toks, facts, _, emitted = jax.lax.while_loop(
                 cond, body, carry)
@@ -3519,7 +3618,10 @@ class GenerativePredictor:
             # write epilogues, baked dequant scales) without changing
             # the prefill arg specs — fingerprinting it keeps fp32 and
             # int8 executables from ever colliding (COMPILE_CACHE.md);
-            # rev bumps when the phase math itself changes shape (13: a
+            # rev bumps when the phase math itself changes shape (14: a
+            # member that holds some of the experts runs its grouped
+            # matmuls over `held_cap` rows and hands out a fourth fact;
+            # 13: a
             # routed layer's phases hand out a third fact, the pairs that
             # stayed here, and a prefill of routed FFNs behind state-space
             # layers its picks; 12: the
@@ -3553,7 +3655,7 @@ class GenerativePredictor:
                       for k in sorted(self._block_meta)
                       if k not in _LATER_KEYS
                       or self._block_meta[k] != _LATER_KEYS[k]],
-            "rev": 13,
+            "rev": 14,
             "state": cc._spec_sig(self._state_host),
             "args": [self._argsig(s) for s in arg_specs],
             "env": cc.environment_fingerprint(self._device),
@@ -4043,6 +4145,7 @@ class DecodeSession:
         self.last_picks = None
         self.last_prefill_picks = None
         self._n_routed = predictor.routed_layers
+        self._n_facts = predictor._routing_facts
         # the `decode/put` / `decode/launch` spans of a call whose
         # results are not fetched yet (`_call`, `_fetch`)
         self._launched = ()
@@ -4476,8 +4579,11 @@ class DecodeSession:
         are split off here, kept as `last_routing` (and `last_pairs_held`)
         and given to the fetch span as `moe_experts_touched` and
         `moe_pairs_held` (the live pairs that stayed on this member; both
-        summed over the layers and a step's trips) and
-        `moe_tokens_per_expert_max`.  `trips_at`
+        summed over the layers and a step's trips),
+        `moe_tokens_per_expert_max` and, of a member that holds some of
+        the experts, `moe_cap_overflows` (the (layer, trip) calls whose
+        routing kept more pairs here than `held_cap` rows and ran the
+        grouped matmuls full size).  `trips_at`
         is where a step's vector holds the trips it ran, behind each
         slot's emitted count: all three spans carry them as `trips`,
         and the fetch span what the decode kernel streamed in them
@@ -4493,7 +4599,8 @@ class DecodeSession:
         slots HOLD of the two kinds of K/V table as the call begins
         (`full_kv_live_bytes`, `window_kv_live_bytes`: `kv_live_bytes`).
         Any other artifact takes the path it always took."""
-        n_routed = 3 * self._n_routed if routed else 0
+        width = self._n_facts
+        n_routed = width * self._n_routed if routed else 0
         if not (n_routed or obs_tracing.enabled()):
             return [np.asarray(o) for o in outs]
         t0 = time.monotonic()
@@ -4502,13 +4609,17 @@ class DecodeSession:
         if n_routed:
             # (a group's prefill: a row of them a prompt, [P, ..])
             facts = got[0][..., -n_routed:].reshape(
-                got[0].shape[:-1] + (-1, 3))
+                got[0].shape[:-1] + (-1, width))
             got[0] = got[0][..., :-n_routed]
             self.last_routing = facts[..., :2]
             self.last_pairs_held = facts[..., 2]
             attrs = {"moe_experts_touched": int(facts[..., 0].sum()),
                      "moe_tokens_per_expert_max": int(facts[..., 1].max()),
                      "moe_pairs_held": int(facts[..., 2].sum())}
+            if width == 4:
+                # (a group's prefill: the call's own, on every prompt's row)
+                attrs["moe_cap_overflows"] = int(
+                    facts[..., 3].reshape(-1, self._n_routed).max(0).sum())
         if routed:
             attrs.update(self._stack_attrs)
             if phase == "step" and self._ss is not None:

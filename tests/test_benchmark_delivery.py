@@ -252,7 +252,7 @@ def test_the_three_are_declared_last_for_the_nine_decode_cells(
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
     # (PR 56's cell is the tenth, and its two readers stand behind PR 55's)
     assert len(e2e["workloads"]) == 10
-    assert [m["name"] for m in manifest["per_layer"][-6:-3]] == list(READERS)
+    assert [m["name"] for m in manifest["per_layer"][-7:-4]] == list(READERS)
     (m,) = [m for m in manifest["per_layer"] if m["name"] == name]
     assert m == {"name": name, "unit": unit, "better": better,
                  "source": "program_counter", "layer": layer,

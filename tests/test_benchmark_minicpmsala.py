@@ -26,10 +26,10 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
     them stand the one reader PR 49 appended, the one PR 50 did, and PR
     51's cell, configuration, three readers and its cell's name in the
     lists, the one reader PR 53 appended, the three PR 54 did, the one
-    PR 55 did, and PR 56's cell, configuration and two readers; the rest is
-    as it was."""
+    PR 55 did, PR 56's cell, configuration and two readers, and the one
+    reader PR 57 appended; the rest is as it was."""
     later = ("mimov2flash_reasoning_decode", "granite4hs_decode_saturated")
-    assert [m["name"] for m in manifest["per_layer"][-12:-3]] == [
+    assert [m["name"] for m in manifest["per_layer"][-13:-4]] == [
         "sparse_tiles_per_grid_step", "sparse_prefill_kernel_ms_per_prefill",
         "kinds_attention_roofline", "attention_share_of_trip",
         "full_kv_bytes_per_slot", "prefill_ahead_share",
@@ -46,7 +46,7 @@ def test_the_cell_is_the_issues(manifest):                     # noqa: F811
         manifest, workloads=manifest["workloads"][:-2],
         configs=manifest["configs"][:-2],
         end_to_end=as_it_was(manifest["end_to_end"]),
-        per_layer=as_it_was(manifest["per_layer"][:-12])))
+        per_layer=as_it_was(manifest["per_layer"][:-13])))
 
 
 # the instruction of stage 2's Mosaic call as a prefill executable's text

@@ -55,7 +55,7 @@ def test_prefill_ahead_share_is_declared_last_for_the_seven_cells():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # (last but for the three readers PR 54, the one PR 55 and the two
     # PR 56 appended behind it)
-    assert manifest["per_layer"][-7] == {
+    assert manifest["per_layer"][-8] == {
         "name": "prefill_ahead_share", "unit": "%", "better": "higher",
         "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -72,7 +72,7 @@ def test_prefill_ahead_share_is_declared_last_for_the_seven_cells():
     # each of them reports what it moves, and the layer is one the manifest
     # already names
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert set(manifest["per_layer"][-7]["workloads"]) < set(e2e["workloads"])
+    assert set(manifest["per_layer"][-8]["workloads"]) < set(e2e["workloads"])
     assert "scheduler" in {m["layer"] for m in manifest["per_layer"][:-7]}
 
 

@@ -19,9 +19,9 @@ def test_prefill_prompts_per_call_is_declared_last_for_the_decode_cells():  # no
     cell, which reports `tokens_per_s`, is in its list."""
     manifest = bench_run.load_json(bench_run.MANIFEST)          # noqa: F405
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert [m["name"] for m in manifest["per_layer"][-2:]] == [
+    assert [m["name"] for m in manifest["per_layer"][-3:-1]] == [
         "ssm_share_of_trip", "held_pairs_per_expert"]
-    assert manifest["per_layer"][-3] == {
+    assert manifest["per_layer"][-4] == {
         "name": "prefill_prompts_per_call", "unit": "prompts",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s", "workloads": e2e["workloads"]}

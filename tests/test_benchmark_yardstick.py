@@ -104,7 +104,7 @@ def test_decode_kv_stream_share_is_declared_for_the_decode_cells():
     # eight, PR 49's one, PR 50's one, PR 51's three, PR 53's one,
     # PR 54's three, PR 55's one and PR 56's two stand behind it
     assert manifest["per_layer"].index(entry) == len(
-        manifest["per_layer"]) - 48
+        manifest["per_layer"]) - 49
 
 
 # ---------------------------------------------------------------------------
@@ -151,7 +151,7 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
     # PR 39's nine readers, PR 42's six, PR 44's five, PR 45's one,
     # PR 48's eight, PR 49's one, PR 50's one, PR 51's three, PR 53's
     # one, PR 54's three, PR 55's one and PR 56's two stand behind it
-    assert manifest["per_layer"][-42] == {
+    assert manifest["per_layer"][-43] == {
         "name": "decode_early_launch_share", "unit": "%",
         "better": "higher", "source": "program_span", "layer": "scheduler",
         "moves": "tokens_per_s",
@@ -167,4 +167,4 @@ def test_decode_early_launch_share_is_declared_for_the_decode_cells():
                       "granite4hs_decode_saturated"]}
     # the cells that report it are those that report what it moves
     e2e, = [m for m in manifest["end_to_end"] if m["name"] == "tokens_per_s"]
-    assert manifest["per_layer"][-42]["workloads"] == e2e["workloads"]
+    assert manifest["per_layer"][-43]["workloads"] == e2e["workloads"]

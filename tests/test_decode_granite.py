@@ -365,11 +365,11 @@ def test_the_new_keys_are_named_only_where_the_meta_moves_them(pred,
     assert named["attention_multiplier"] == 0.0078125
     assert named["residual_multiplier"] == 0.22
     assert named["position"] == "none"
-    assert pred._fingerprint(("step", 2), ())["rev"] == 13
+    assert pred._fingerprint(("step", 2), ())["rev"] == 14
     old = GenerativePredictor(dec.build_tiny_decode_model(
         str(tmp_path / "old"), n_layers=1))
     fp = old._fingerprint(("step", 2), ())
-    assert fp["rev"] == 13
+    assert fp["rev"] == 14
     assert not {"attention_multiplier", "residual_multiplier"} \
         & {k for k, _ in fp["block"]}
 
@@ -436,26 +436,228 @@ def test_the_four_members_shares_add_up_to_the_uncut_layer():
     assert float(jnp.max(jnp.abs(shared))) > 1e-3
 
 
-def test_a_quarter_held_runs_the_grouped_matmuls_over_every_pair():
-    """`moe_ffn`'s held branch with 18 of 72 held: cap = max(64, 4 * pairs
-    * 18 / 72) IS the pairs, so no `cond` is built and the grouped matmuls
-    take every pair's row (dropless and exact: ROADMAP S6 has the price);
-    with 8 of 128 held the first `cap` rows are taken under a `cond`."""
-    rng = np.random.RandomState(4)
+SHARES = [(72, 10, 18), (128, 8, 8), (256, 8, 8)]        # (E, k, count)
+HELD_D, HELD_F, HELD_FIRST = 16, 8, 3
 
-    def text(E, k, first, count, T=96):
-        D, F = 16, 8
-        args = (jnp.asarray(rng.randn(T, D), jnp.float32),
-                jnp.asarray(rng.randn(D, E), jnp.float32)) + tuple(
-            jnp.asarray(rng.randn(*s), jnp.float32)
-            for s in ((count, D, F), (count, D, F), (count, F, D)))
-        return str(jax.make_jaxpr(lambda *a: dec.moe_ffn(
-            *a, k, True, held=(first, count))[0])(*args))
-    quarter = text(72, 10, 0, 18)
-    assert "cond[" not in quarter
-    assert "960,16" in quarter.replace(" ", "")     # all T * k rows
-    sixteenth = text(128, 8, 48, 8)
-    assert "cond[" in sixteenth
+
+def _held_weights(rng, E, count, T):
+    D, F = HELD_D, HELD_F
+    return [jnp.asarray(rng.randn(*s), jnp.float32) for s in (
+        (T, D), (D, E), (count, D, F), (count, D, F), (count, F, D))]
+
+
+def _walk(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs its equations hold, a
+    `cond`'s branches left to the caller."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "cond":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub)
+
+
+@pytest.mark.parametrize("prompts", [1, 2], ids=["one_prompt", "group"])
+@pytest.mark.parametrize("T", [96, 512])
+@pytest.mark.parametrize("E,k,count", SHARES)
+def test_the_held_branch_works_over_the_rows_that_stay(E, k, count, T,
+                                                       prompts):
+    """`moe_ffn` with `held`, at a quarter, a sixteenth and a thirty-second
+    of the experts, one prompt's pairs or a group's merged ones: ONE `cond`;
+    its fast branch's three grouped matmuls take `held_cap` rows (twice the
+    member's share in row tiles, not every pair's row: ROADMAP S6(e)), pads
+    nothing, and holds one [pairs, D] gather, the one that reads the [cap, D]
+    result; the other branch is the full-size form.  Granite's decode trip
+    (960 pairs, a quarter held: the cap's row tile is the 64 that all 960
+    get) is the one case where fewer rows would buy nothing: no `cond`, the
+    grouped matmuls over every pair's row as the parent ran them."""
+    h, router, *w = _held_weights(np.random.RandomState(4), E, count,
+                                  T * prompts)
+
+    def part(h):
+        return dec.moe_ffn(h, router, *w, k, True,
+                           held=(HELD_FIRST, count))[0]
+    if prompts == 1:
+        jaxpr = jax.make_jaxpr(part)(h)
+    else:
+        with dec._prompts_share_experts():
+            jaxpr = jax.make_jaxpr(jax.vmap(part))(
+                h.reshape(prompts, T, HELD_D))
+    pairs, D = prompts * T * k, HELD_D
+    cap = dec.held_cap(pairs, count, E)
+    conds = [e for e in _walk(jaxpr.jaxpr) if e.primitive.name == "cond"]
+    if (E, T, prompts) == (72, 96, 1):
+        assert cap == pairs == 960 and not conds
+        assert [e.invars[0].aval.shape[0] for e in _walk(jaxpr.jaxpr)
+                if e.primitive.name == "ragged_dot_general"] == [pairs] * 3
+        return
+    assert 64 <= cap < pairs and cap % 64 == 0 and cap % 512
+    assert len(conds) == 1
+    assert not [e for e in _walk(jaxpr.jaxpr)
+                if e.primitive.name == "ragged_dot_general"]
+
+    def rows(branch):
+        return [e.invars[0].aval.shape[0] for e in _walk(branch.jaxpr)
+                if e.primitive.name == "ragged_dot_general"]
+    full, fast = sorted(conds[0].params["branches"],
+                        key=lambda b: -rows(b)[0])
+    assert rows(full) == [pairs] * 3 and rows(fast) == [cap] * 3
+    eqns = list(_walk(fast.jaxpr))
+    assert not [e for e in eqns if e.primitive.name == "pad"]
+    wide = [e for e in eqns if e.primitive.name == "gather"
+            and e.outvars[0].aval.shape == (pairs, D)]
+    assert [e.invars[0].aval.shape for e in wide] == [(cap, D)]
+    # nothing is gathered FROM, or multiplied at, [pairs, .] rows
+    assert not [e for e in eqns
+                if e.primitive.name in ("gather", "ragged_dot_general",
+                                        "dot_general")
+                and e.invars[0].aval.shape[:1] == (pairs,)
+                and len(e.invars[0].aval.shape) == 2]
+
+
+def test_the_cap_is_twice_the_members_share_in_odd_tiles():
+    """`held_cap`: m = pairs * count / E rows are expected; the cap is
+    max(2 m, m + 6 sqrt(m)) rounded up to an ODD number of row tiles of 64,
+    128 or 256 (the TPU's compiler tiles the rows by the largest power of
+    two that divides them, `_row_tile`; the tile nearest sqrt(240 x rows an
+    expert) costs the kernel's visits least), or all the pairs where that
+    tile's visits cost no less than at the tile of all the pairs.  The cells'
+    own shapes: Granite's decode trip (96 slots x top-10, 18 of 72) all 960
+    as the parent (9 x 64 rows would be tiled by 64 like the 960), its 256
+    and 512 buckets 1,408 / 2,688 of 2,560 / 5,120, its groups 5,376 of
+    10,240; K-EXAONE's trip 192 of 768 (as the parent), its 4,096 bucket
+    4,352 of 32,768 (the parent: 8,192); MiMo's trip 64 (the parent: 96),
+    pangu's 64."""
+    assert [dec._row_tile(r) for r in (960, 576, 2560, 1408, 5376, 4096)] \
+        == [64, 64, 512, 128, 256, 512]
+    assert dec.held_cap(960, 18, 72) == 960         # tiles of 64 either way
+    assert dec.held_cap(1920, 18, 72) == 960                # 15 x 64
+    assert dec.held_cap(2560, 18, 72) == 1408               # 11 x 128
+    assert dec.held_cap(5120, 18, 72) == 2688               # 21 x 128
+    assert dec.held_cap(10240, 18, 72) == 5376              # 21 x 256
+    assert dec.held_cap(768, 8, 128) == 192                 # 3 x 64
+    assert dec.held_cap(4096 * 8, 8, 128) == 4352           # 17 x 256
+    assert dec.held_cap(768, 8, 256) == 64
+    assert dec.held_cap(512, 8, 256) == 64
+    assert dec.held_cap(40, 1, 4) == 40             # never over the pairs
+    assert dec.held_cap(960, 72, 72) == 960         # a member with them all
+    for pairs in range(64, 40000, 97):
+        for count, of in ((18, 72), (8, 128), (8, 256), (1, 2)):
+            cap = dec.held_cap(pairs, count, of)
+            m = -(-pairs * count // of)
+            assert cap == pairs or (
+                2 * m <= cap < pairs and cap >= m + 6 * m ** 0.5
+                and cap % 64 == 0 and cap % 512 != 0)
+
+
+def _parent_part(h, router, w_gate, w_up, w_down, k, first, count):
+    """The parent's held branch at full size (PR 56's `moe_ffn` where no
+    `cond` was built): every pair's row sorted by its place here, through
+    the grouped matmuls, the rows behind the groups zeroed, back to token
+    order, a fixed-order sum over k.  -> ([T, D], the pairs that stayed)."""
+    T = h.shape[0]
+    logits = jnp.dot(h, router, precision=jax.lax.Precision.HIGHEST)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    here = (idx >= first) & (idx < first + count)
+    flat = jnp.where(here, idx - first, count).reshape(T * k)
+    w = jnp.where(here, w, 0.0)
+    sizes = jnp.sum(flat[:, None] == jnp.arange(count)[None], axis=0,
+                    dtype=jnp.int32)
+    order = jnp.argsort(flat)
+    rows = h[order // k]
+
+    def grouped(x, m):
+        return jax.lax.ragged_dot(x, m, group_sizes=sizes)
+    out = grouped(jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up),
+                  w_down)
+    out = jnp.where((jnp.arange(T * k) < jnp.sum(sizes))[:, None], out, 0.0)
+    out = out[jnp.argsort(order)].reshape(T, k, -1)
+    return jnp.sum(out * w[:, :, None], axis=1), jnp.sum(sizes)
+
+
+def _dense_part(h, router, w_gate, w_up, w_down, k, first, count):
+    """The same part as a plain masked dense sum: every held expert's FFN
+    of every token, a token's picks taken in pick order."""
+    logits = jnp.dot(h, router, precision=jax.lax.Precision.HIGHEST)
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    w = w / jnp.sum(w, axis=-1, keepdims=True)
+    y = jnp.einsum("etf,efd->etd", jax.nn.silu(
+        jnp.einsum("td,edf->etf", h, w_gate))
+        * jnp.einsum("td,edf->etf", h, w_up), w_down)
+    here = (idx >= first) & (idx < first + count)
+    rows = y[jnp.clip(idx - first, 0, count - 1),
+             jnp.arange(h.shape[0])[:, None]]
+    return jnp.sum(jnp.where(here, w, 0.0)[..., None]
+                   * jnp.where(here[..., None], rows, 0.0), axis=1)
+
+
+def _planted(E, k, count, T, stay, rng):
+    """(h, router) whose routing keeps exactly `stay` of the T * k pairs on
+    the member that holds HELD_FIRST .. HELD_FIRST + count - 1: feature 0
+    votes for k experts inside, feature 1 for k outside, each in a fixed
+    order of preference; a token is all inside, all outside, or the one
+    mixture that keeps `stay % k`."""
+    inside = np.arange(HELD_FIRST, HELD_FIRST + k)
+    outside = np.arange(HELD_FIRST + count, HELD_FIRST + count + k)
+    router = rng.randn(HELD_D, E) * 1e-3
+    router[:2] = 0.0
+    router[0, inside] = router[1, outside] = 3.0 + np.arange(k, 0, -1)
+    h = rng.randn(T, HELD_D)
+    h[:, :2] = (0.0, 1.0)
+    h[:stay // k, :2] = (1.0, 0.0)
+    if stay % k:
+        for c in np.linspace(0.31, 3.1, 400):       # no ties: c is no ratio
+            top = np.argsort(-(np.array([1.0, c]) @ router[:2]))[:k]
+            if np.isin(top, inside).sum() == stay % k:
+                h[stay // k] = 0.0      # (no third feature tips a vote)
+                h[stay // k, :2] = (1.0, c)
+                break
+        else:
+            raise AssertionError("no mixture keeps %d" % (stay % k))
+    return jnp.asarray(h, jnp.float32), jnp.asarray(router, jnp.float32)
+
+
+@pytest.mark.parametrize("routing", ["random", "all_stay", "none_stays",
+                                     "at_the_cap", "one_over_the_cap"])
+@pytest.mark.parametrize("E,k,count", SHARES)
+def test_the_held_part_is_the_parents_under_every_routing(E, k, count,
+                                                          routing):
+    """Dropless and exact whatever the router does (CPU, float32): the
+    member's part is the parent's full-size form bit for bit, and the plain
+    dense sum to rounding; the fourth fact is 1 exactly where more pairs
+    stayed than `held_cap` rows (the full-size branch ran)."""
+    T = 192         # (at 96 Granite's share builds no `cond`: 960 pairs)
+    rng = np.random.RandomState(E + len(routing))
+    cap = dec.held_cap(T * k, count, E)
+    assert cap < T * k
+    h, router, *w = _held_weights(rng, E, count, T)
+    stay = {"all_stay": T * k, "none_stays": 0, "at_the_cap": cap,
+            "one_over_the_cap": cap + 1}.get(routing)
+    if stay is not None:
+        h, router = _planted(E, k, count, T, stay, rng)
+    got, facts = jax.jit(lambda h, r, *w: dec.moe_ffn(
+        h, r, *w, k, True, held=(HELD_FIRST, count)))(h, router, *w)
+    want, stayed = jax.jit(lambda *a: _parent_part(
+        *a, k, HELD_FIRST, count))(h, router, *w)
+    if stay is None:
+        assert 0 < int(stayed) <= cap
+    else:
+        assert int(stayed) == stay
+    assert int(facts[2]) == int(stayed)
+    assert int(facts[3]) == int(int(stayed) > cap)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_dense_part(h, router, *w, k,
+                                                HELD_FIRST, count)),
+        rtol=0, atol=1e-4)
+    if routing == "none_stays":
+        assert not np.asarray(got).any()
+    else:
+        assert np.abs(np.asarray(got)).max() > 1e-3
 
 
 def test_spans_carry_the_stack_and_the_pairs_that_stayed(pred):
@@ -486,6 +688,61 @@ def test_spans_carry_the_stack_and_the_pairs_that_stayed(pred):
     assert step["moe_experts_touched"] <= step["moe_pairs_held"]
     pre = [a for a in fetch if a["phase"] == "prefill"][-1]
     assert pre["moe_pairs_held"] > 0
+    # 6 and 24-48 pairs a call: under `held_cap`'s smallest tile, no `cond`
+    assert step["moe_cap_overflows"] == pre["moe_cap_overflows"] == 0
+
+
+@pytest.mark.parametrize("router", ["seeded", "planted"])
+def test_a_crowded_member_counts_its_full_size_calls(tmp_path, state, router,
+                                                     monkeypatch):
+    """`moe_cap_overflows` on a step's and a group prefill's `decode/fetch`
+    span.  The tiny stack's calls are under `held_cap`'s smallest tile, so
+    the rule is rebound to three quarters of the pairs and `moe_ffn` builds
+    its `cond` (40 slots x top-3 = 120 pairs a trip over 90 rows; 8 prompts
+    x 16 positions x 3 = 384 over 288; the member holds experts 0..2 of 6):
+    0 with the seeded router, which keeps about half the pairs here; with
+    one planted so that EVERY pair stays (a zero gain in front of the
+    router: all logits tie, the top-3 are experts 0, 1, 2) every routed
+    layer of every trip, and of the prefill call, runs full size."""
+    from paddle_tpu.flags import FLAGS, set_flags
+    monkeypatch.setattr(dec, "held_cap",
+                        lambda pairs, count, of: pairs * 3 // 4)
+    meta = dict(META, experts_held=[0, 3])
+    mine = dict(state)
+    if router == "planted":
+        for i in range(META["n_layers"]):
+            mine["l%d_ln2_g" % i] = np.zeros_like(state["l%d_ln2_g" % i])
+    # (the executable store keys a phase by the artifact and the meta, not
+    # by the code: a rebound rule must neither leave nor load a phase there)
+    was_store, was = FLAGS.compile_cache, obs_tracing.enabled()
+    set_flags({"compile_cache": False})
+    obs_tracing.set_enabled(True)
+    try:
+        pred = GenerativePredictor(dec.save_decode_model(
+            str(tmp_path / "lm"), mine, meta))
+        sess = pred.new_session(40)
+        obs_tracing.clear()
+        sess.launch_prefill([0, 1], [_prompt(12, 2), _prompt(15, 3)])
+        sess.fetch_prefill()
+        sess.decode_fused(3)
+        spans = obs_tracing.recent_spans()
+    finally:
+        obs_tracing.set_enabled(was)
+        set_flags({"compile_cache": was_store})
+    fetch = [s["attrs"] for s in spans if s["name"] == "decode/fetch"]
+    step = [a for a in fetch if a["phase"] == "step"][-1]
+    pre = [a for a in fetch if a["phase"] == "prefill"][-1]
+    assert step["trips"] == 3
+    if router == "planted":
+        assert step["moe_cap_overflows"] == 3 * 3      # layers x trips
+        assert pre["moe_cap_overflows"] == 3           # the call's, a layer
+        # every live position's three picks, in three layers (the group's
+        # dead rows count a position each)
+        assert pre["moe_pairs_held"] >= (12 + 15) * 3 * 3
+        assert step["moe_pairs_held"] == 2 * 3 * 3 * 3
+    else:
+        assert step["moe_cap_overflows"] == pre["moe_cap_overflows"] == 0
+        assert 0 < step["moe_pairs_held"] < 2 * 3 * 3 * 3
 
 
 def test_device_scopes_name_the_new_layers_work(pred):

@@ -267,7 +267,7 @@ def test_token_out_frames_per_pass_is_declared_for_the_decode_cells():
     # (last but for the eight readers PR 48, the one PR 49, the one
     # PR 50, the three PR 51, the one PR 53, the three PR 54, the one
     # PR 55 and the two PR 56 appended behind it)
-    assert manifest["per_layer"][-21] is m
+    assert manifest["per_layer"][-22] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
 
@@ -319,7 +319,7 @@ def test_sparse_tiles_per_grid_step_is_declared_for_its_cell_alone():
     # (last but for the one reader PR 50, the three PR 51, the one
     # PR 53, the three PR 54, the one PR 55 and the two PR 56 appended
     # behind it)
-    assert manifest["per_layer"][-12] is m
+    assert manifest["per_layer"][-13] is m
     assert os.path.exists(os.path.join(bench_run.LAYERS_DIR,
                                        m["name"] + ".py"))
     # the one cell whose stack has sparse layers
@@ -393,16 +393,16 @@ def test_sparse_prefill_kernel_ms_per_prefill_is_declared_last():
     manifest = bench_run.load_json(bench_run.MANIFEST)
     # (last but for the three readers PR 51, the one PR 53, the three
     # PR 54, the one PR 55 and the two PR 56 appended behind it)
-    assert manifest["per_layer"][-11] == {
+    assert manifest["per_layer"][-12] == {
         "name": "sparse_prefill_kernel_ms_per_prefill", "unit": "ms",
         "better": "lower", "source": "device_trace", "layer": "kernels",
         "moves": "tokens_per_s", "workloads": ["minicpmsala_longdoc_mixed"]}
-    assert len(manifest["per_layer"]) == 74
+    assert len(manifest["per_layer"]) == 75
     assert all(os.path.exists(os.path.join(
         bench_run.LAYERS_DIR, m["name"] + ".py"))
         for m in manifest["per_layer"])
     # its spans and its kernel are those of the reader it stands beside
-    assert manifest["per_layer"][-11]["workloads"] == [
+    assert manifest["per_layer"][-12]["workloads"] == [
         m for m in manifest["per_layer"]
         if m["name"] == "sparse_prefill_ms_per_prefill"][0]["workloads"]
 
@@ -447,7 +447,7 @@ def test_the_nine_are_declared_last_for_the_five_decode_cells():
     # the eight PR 48, the one PR 49, the one PR 50, the three PR 51, the
     # one PR 53, the three PR 54, the one PR 55 and the two PR 56 appended
     # behind them)
-    last = manifest["per_layer"][-41:-32]
+    last = manifest["per_layer"][-42:-33]
     assert [m["name"] for m in last] == list(NINE)
     for m in last:
         assert m["workloads"] == DECODE_CELLS, m["name"]

@@ -1017,7 +1017,9 @@ def test_kinds_prefill_compiles_within_its_temporaries(kinds_predictor,
     """A prefill bucket of `mimo_v2_flash`: its attention plain XLA (no call
     of the decode kernel; the grouped matmuls of the routed FFN are XLA's), its
     rows returned as the four tables hold a position, temporaries what the
-    configuration's arithmetic counts (0.326 / 0.643 GB)."""
+    configuration's arithmetic counts (0.326 / 0.643 GB; 0.305 / 0.644 since
+    PR 57's routed layers, whose k-sum stays behind their `cond`: inside
+    both branches it read 0.353 / 0.717)."""
     cfg, pred, state = kinds_predictor
     assert bucket in cfg["model"]["prefill_buckets"]
     on = jax.sharding.SingleDeviceSharding(pred._device)
@@ -1034,3 +1036,97 @@ def test_kinds_prefill_compiles_within_its_temporaries(kinds_predictor,
         (5, 1, 128, 1024)]
     assert not [c for c in _custom_calls(compiled.as_text())
                 if "kernel_metadata={}" in c]
+
+
+# --- PR 57: a member's routed FFN works over the rows that stay ------------
+
+@pytest.fixture(scope="module")
+def held_predictor(one_chip):
+    """(`granite_4_0_h_small`'s configuration cut to an ssm and an attention
+    layer, a weightless predictor of it on the described chip, its state's
+    specs with bfloat16 weights at rest)."""
+    import json
+    from paddle_tpu.inference import decode as dec
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "granite_4_0_h_small.json")) as f:
+        cfg = json.load(f)
+    meta = dict(cfg["model"], n_layers=2, layer_types=["ssm", "attention"])
+    pred, state = described_predictor(meta, list(one_chip.device_set)[0])
+    return cfg, pred, {n: jax.ShapeDtypeStruct(
+        s.shape, jnp.bfloat16 if dec._bf16_at_rest(n, s) else np.float32,
+        sharding=s.sharding) for n, s in state.items()}
+
+
+@pytest.mark.parametrize("phase", ["step", "256", "512", "256x4", "512x2"])
+def test_a_held_routed_layer_runs_its_grouped_matmuls_over_cap_rows(
+        held_predictor, phase):
+    """Granite's one-prompt and group prefills of both buckets, 18 of 72
+    experts held: every routed layer is ONE `conditional`; one branch's
+    three `ragged-dot` kernels take `held_cap` rows (1,408 / 2,688 of 2,560
+    / 5,120; 5,376 of 10,240 a group), the other's every pair's row, and
+    the compiler tiles a grouped matmul's rows as `decode._row_tile` says:
+    by the largest power of two that divides them, which `held_cap`'s odd
+    number of tiles keeps at the tile it chose (128 or 256; the full-size
+    branch's rows get 512).  Its step window (96 slots x top-10 = 960 pairs
+    a trip) would get tiles of 64 at 576 rows as at 960: no `conditional`,
+    the six kernels over every pair's row as the parent ran them."""
+    from paddle_tpu.inference import decode as dec
+    cfg, pred, state = held_predictor
+    slots, k = cfg["deployment"]["decode_slots"], 10
+    on = jax.sharding.SingleDeviceSharding(pred._device)
+    if phase == "step":
+        pairs = slots * k
+        compiled = compile_phase(pred, state, pred._step_math(),
+                                 pred._step_specs(slots), tables=range(4))
+    else:
+        bucket, _, prompts = phase.partition("x")
+        bucket, prompts = int(bucket), int(prompts or 1)
+        assert prompts in (1, pred.prefill_width(bucket))
+        pairs = prompts * bucket * k
+        lead = (prompts,) if prompts > 1 else ()
+        specs = (jax.ShapeDtypeStruct((prompts, bucket), np.int32,
+                                      sharding=on),
+                 jax.ShapeDtypeStruct(lead, np.int32, sharding=on))
+        with pk.mosaic_lowering():
+            compiled = pred._phase_jit(
+                pred._prefill_group_math if prompts > 1
+                else pred._prefill_math, ()).lower(state, *specs).compile()
+    text = compiled.as_text()
+    cap = dec.held_cap(pairs, 18, 72)
+    assert cap == pairs if phase == "step" else \
+        pairs / 2 <= cap < 0.61 * pairs
+    assert text.count(" conditional(") == (0 if phase == "step" else 2)
+    rows = re.findall(r"bf16\[(\d+),(?:4096|768)\]\{1,0\}, "
+                      r"bf16\[18,\d+,\d+\]\{2,1,0\}\}, [^\n]*?"
+                      r'ragged_dot_tiling="(\d+),', text)
+    assert sorted(rows) == sorted(
+        [(str(n), str(dec._row_tile(n))) for n in {cap, pairs}] * 6), rows
+    assert dec._row_tile(cap) == {960: 64, 2560: 128, 5120: 128,
+                                  10240: 256}[pairs]
+
+
+# sha256 of the optimized HLO of the PARENT's step (PR 56, b41f14a) at the
+# configuration's widths, two layers, for a described v5e, as
+# tools/decode_hlo_dump.py writes it (`v5e_<config>_step.hlo`)
+PARENT_STEP_HLO = {
+    "olmoe_1b_7b":
+    "1287c90f480da896a538e26033182d91768cc34e3310d133c54f75062020b4a3",
+    "lfm2_24b_a2b":
+    "b1f6e8721adb0bef7622771d526202afab25d497059c26f11ffddec6a2bc48c6"}
+
+
+@pytest.mark.parametrize("config", sorted(PARENT_STEP_HLO))
+def test_a_stack_that_holds_all_its_experts_runs_the_parents_step(
+        one_chip, config):
+    """`held is None` (OLMoE, LFM2) was not touched: the step executable's
+    text is the parent's byte for byte."""
+    import hashlib
+    from paddle_tpu.inference import decode as dec
+    from tools import decode_hlo_dump as dump
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    meta, slots = dump.cells(here)[config]
+    texts = dump.v5e_phases(dec, pk, list(one_chip.device_set)[0], config,
+                            meta, slots, only=("step",))
+    assert hashlib.sha256(texts["step"][2].encode()).hexdigest() \
+        == PARENT_STEP_HLO[config]

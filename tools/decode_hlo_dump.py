@@ -27,18 +27,8 @@ import tempfile
 
 import ml_dtypes
 
-root, out = os.path.abspath(sys.argv[1]), sys.argv[2]
-sys.path.insert(0, root)
-os.makedirs(out, exist_ok=True)
-
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
-
-from paddle_tpu.inference import decode as dec  # noqa: E402
-from paddle_tpu.ops import pallas_kernels as pk  # noqa: E402
-
-assert dec.__file__.startswith(root), dec.__file__
-jax.config.update("jax_enable_compilation_cache", False)
 
 OLMOE = dict(norm="rmsnorm", norm_eps=1e-5, position="rope",
              rope_theta=10000.0, qk_norm=True, ffn="moe_swiglu")
@@ -50,7 +40,7 @@ CELLS = {"gpt2_small": (dict(vocab_size=50257, d_model=768, n_heads=12,
                               expert_width=1024, **OLMOE), 8)}
 
 
-def cell_of(config, layer_types):
+def cell_of(root, config, layer_types):
     """(meta, slots) of `benchmark/configs/<config>.json` at its published
     widths and slot count, cut to the two layers `layer_types` (one dense
     FFN, one of the stack's own), so that every kind of slot state the
@@ -62,13 +52,18 @@ def cell_of(config, layer_types):
     return meta, cfg["deployment"]["decode_slots"]
 
 
-CELLS.update(
-    lfm2_24b_a2b=cell_of("lfm2_24b_a2b", ["conv", "attention"]),
-    openpangu_ultra_moe_718b=cell_of("openpangu_ultra_moe_718b",
-                                     ["mla", "mla"]),
-    falcon_h1_34b=cell_of("falcon_h1_34b", ["attention+ssm"] * 2),
-    k_exaone_236b_a23b=cell_of("k_exaone_236b_a23b",
-                               ["window_attention", "attention"]))
+def cells(root):
+    """{configuration: (meta, slots)}: the six decode configurations the
+    described-v5e texts are written for."""
+    return dict(
+        CELLS,
+        lfm2_24b_a2b=cell_of(root, "lfm2_24b_a2b", ["conv", "attention"]),
+        openpangu_ultra_moe_718b=cell_of(root, "openpangu_ultra_moe_718b",
+                                         ["mla", "mla"]),
+        falcon_h1_34b=cell_of(root, "falcon_h1_34b",
+                              ["attention+ssm"] * 2),
+        k_exaone_236b_a23b=cell_of(root, "k_exaone_236b_a23b",
+                                   ["window_attention", "attention"]))
 
 
 def mosaic_text(match):
@@ -90,17 +85,15 @@ def mosaic_text(match):
     return "MOSAIC<<%s>>" % text
 
 
-def write(tag, text):
+def stripped(text):
     """`text` without what names the tree: the instructions' metadata, the
     tables of file and function names and the stack frames at the end of an
     optimized module, the locations inside a Mosaic payload."""
     text = re.sub(r", metadata=\{[^}]*\}", "", text)
     text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames"
                   r"|\d+ [\"{].*)\n", "", text)
-    text = re.sub(r'\\?["2]{1,2}body\\?["2]{1,2}: ?\\?["2]{1,2}'
+    return re.sub(r'\\?["2]{1,2}body\\?["2]{1,2}: ?\\?["2]{1,2}'
                   r'([A-Za-z0-9+/=]+)\\?["2]{1,2}', mosaic_text, text)
-    with open(os.path.join(out, tag), "w") as f:
-        f.write(text)
 
 
 def phases(pred, n_slots, bucket):
@@ -121,49 +114,30 @@ def _phases(pred, n_slots, bucket):
                          jax.ShapeDtypeStruct((), np.int32)))}
 
 
-def write_fingerprint(tag, pred, ph, n_slots, bucket, specs):
+def fingerprint(dec, pred, ph, n_slots, bucket, specs):
     """The phase's compile-cache fingerprint, under the key its `*_fn`
-    resolves it by."""
+    resolves it by, as JSON."""
     key = {"step": ("step", n_slots, int(dec.STEP_WINDOW)),
            "step_logits": ("step_logits", n_slots) + (
                ("picks",) if pred._step_picks else ()),
            "prefill": ("prefill", bucket)}[ph]
-    with open(os.path.join(out, tag + ".fingerprint"), "w") as f:
-        json.dump(pred._fingerprint(key, specs), f, sort_keys=True,
-                  indent=1, default=str)
+    return json.dumps(pred._fingerprint(key, specs), sort_keys=True,
+                      indent=1, default=str)
 
 
-# the tests' tiny stacks (`tests/test_decode_sliding.py` extends
-# `tests/test_decode_ssm.py`'s), the tree's own copy of them
-from tests import test_decode_sliding as tiny  # noqa: E402
+def described_v5e():
+    """The first device of a described v5e 2x2 (no chip)."""
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
 
-STACKS = {name: (block, tiny.OLD_TINY)
-          for name, block in tiny.OLD_STACKS.items()}
-STACKS["kexaone"] = (tiny.WINDOW_BLOCK, tiny.TINY)
-for name, (block, size) in sorted(STACKS.items()):
-    d = tempfile.mkdtemp()
-    dec.build_tiny_decode_model(d, block=block, **size)
-    # an int8 cache is the all-attention multi-head stacks'
-    for kv in ("float32", "int8") if name in ("gpt2", "olmoe") else (
-            "float32",):
-        pred = dec.load_decode_predictor(d, kv_cache_dtype=kv)
-        state = {n: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype)
-                 for n, v in pred._state_host.items()}
-        for ph, (fn, specs) in phases(pred, 4, 16).items():
-            tag = "%s_%s_%s" % (name, kv, ph)
-            write_fingerprint(tag, pred, ph, 4, 16, specs)
-            low = jax.jit(fn).lower(state, *specs)
-            write(tag + ".stablehlo", low.as_text())
-            write(tag + ".hlo", low.compile().as_text())
-            write(tag + ".jaxpr", str(jax.make_jaxpr(fn)(state, *specs)))
 
-# the cells' widths on a described v5e, Mosaic kernels in the text
-from jax.experimental import topologies  # noqa: E402
-
-device = topologies.get_topology_desc(platform="tpu",
-                                      topology_name="v5e:2x2").devices[0]
-on = jax.sharding.SingleDeviceSharding(device)
-for name, (meta, slots) in CELLS.items():
+def v5e_phases(dec, pk, device, name, meta, slots, only=None):
+    """{phase: (fingerprint, StableHLO, the TPU's optimized HLO)} of `step`
+    and `prefill` (or the phases `only` names) of a weightless predictor of
+    `meta` at `slots` slots, compiled for the described `device` with the
+    Mosaic kernels forced; the texts as `stripped` leaves them."""
+    on = jax.sharding.SingleDeviceSharding(device)
     pred = object.__new__(dec.GenerativePredictor)
     pred.meta, pred._block_meta = meta, dec.block_of(meta)
     pred._kv_dtype, pred._tp_size, pred._device = "float32", 0, device
@@ -176,11 +150,11 @@ for name, (meta, slots) in CELLS.items():
             else s.dtype, sharding=on) for n, s in state.items()}
     # what `_fingerprint` reads of an opened artifact
     pred._model_fp, pred._state_host = "described:" + name, state
+    texts = {}
     for ph, (fn, specs) in phases(pred, slots, 128).items():
-        if ph == "step_logits":
+        if ph == "step_logits" or (only and ph not in only):
             continue
-        write_fingerprint("v5e_%s_%s" % (name, ph), pred, ph, slots, 128,
-                          specs)
+        fp = fingerprint(dec, pred, ph, slots, 128, specs)
         specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=on)
                  for s in specs]
         donate = tuple(range(1, 1 + pred._n_tables)) if ph == "step" else ()
@@ -188,6 +162,60 @@ for name, (meta, slots) in CELLS.items():
             low = jax.jit(fn, donate_argnums=donate,
                           compiler_options=dec._TPU_PHASE_OPTIONS).lower(
                               state, *specs)
-            write("v5e_%s_%s.stablehlo" % (name, ph), low.as_text())
-            write("v5e_%s_%s.hlo" % (name, ph), low.compile().as_text())
-print("dumped", len(os.listdir(out)))
+            texts[ph] = (fp, stripped(low.as_text()),
+                         stripped(low.compile().as_text()))
+    return texts
+
+
+def main(root, out):
+    root = os.path.abspath(root)
+    sys.path.insert(0, root)
+    os.makedirs(out, exist_ok=True)
+    from paddle_tpu.inference import decode as dec
+    from paddle_tpu.ops import pallas_kernels as pk
+    assert dec.__file__.startswith(root), dec.__file__
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def write(tag, text):
+        with open(os.path.join(out, tag), "w") as f:
+            f.write(text)
+
+    # the tests' tiny stacks (`tests/test_decode_sliding.py` extends
+    # `tests/test_decode_ssm.py`'s), the tree's own copy of them
+    from tests import test_decode_sliding as tiny
+    stacks = {name: (block, tiny.OLD_TINY)
+              for name, block in tiny.OLD_STACKS.items()}
+    stacks["kexaone"] = (tiny.WINDOW_BLOCK, tiny.TINY)
+    for name, (block, size) in sorted(stacks.items()):
+        d = tempfile.mkdtemp()
+        dec.build_tiny_decode_model(d, block=block, **size)
+        # an int8 cache is the all-attention multi-head stacks'
+        for kv in ("float32", "int8") if name in ("gpt2", "olmoe") else (
+                "float32",):
+            pred = dec.load_decode_predictor(d, kv_cache_dtype=kv)
+            state = {n: jax.ShapeDtypeStruct(np.shape(v),
+                                             np.asarray(v).dtype)
+                     for n, v in pred._state_host.items()}
+            for ph, (fn, specs) in phases(pred, 4, 16).items():
+                tag = "%s_%s_%s" % (name, kv, ph)
+                write(tag + ".fingerprint",
+                      fingerprint(dec, pred, ph, 4, 16, specs))
+                low = jax.jit(fn).lower(state, *specs)
+                write(tag + ".stablehlo", stripped(low.as_text()))
+                write(tag + ".hlo", stripped(low.compile().as_text()))
+                write(tag + ".jaxpr", stripped(str(
+                    jax.make_jaxpr(fn)(state, *specs))))
+
+    # the cells' widths on a described v5e, Mosaic kernels in the text
+    device = described_v5e()
+    for name, (meta, slots) in cells(root).items():
+        for ph, (fp, stablehlo, hlo) in v5e_phases(
+                dec, pk, device, name, meta, slots).items():
+            write("v5e_%s_%s.fingerprint" % (name, ph), fp)
+            write("v5e_%s_%s.stablehlo" % (name, ph), stablehlo)
+            write("v5e_%s_%s.hlo" % (name, ph), hlo)
+    print("dumped", len(os.listdir(out)))
+
+
+if __name__ == "__main__":
+    main(sys.argv[1], sys.argv[2])
